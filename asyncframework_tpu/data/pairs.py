@@ -236,7 +236,7 @@ class PairOpsMixin:
           one ``lax.all_to_all`` exchange, jitted segment reduces
           (ops/shuffle.py -- the SortShuffleManager-role data plane);
         - CPU backend -> the vectorized HOST shuffle (numpy
-          bincount/sort+reduceat).  Rig measurements (ROUND5.md): on 10M
+          bincount/sort+reduceat).  CPU-rig measurements: on 10M
           pairs the host-vectorized path is ~10x the driver-routed dict
           path, while the device path's collective is EMULATED on CPU and
           loses to both -- so ``auto`` only takes the device route when a

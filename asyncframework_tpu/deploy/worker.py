@@ -20,6 +20,7 @@ from typing import Dict, List, Optional
 
 from asyncframework_tpu.net import RetryPolicy
 from asyncframework_tpu.net import frame as _frame
+from asyncframework_tpu.utils import devices as _devices
 from asyncframework_tpu.utils.threads import guarded
 from asyncframework_tpu.net.frame import recv_msg as _recv_msg
 from asyncframework_tpu.net.frame import send_msg as _send_msg
@@ -37,7 +38,14 @@ class Worker:
         heartbeat_s: float = 1.0,
         launch_env_extra: Optional[Dict[str, str]] = None,
         standby_masters: Optional[List[str]] = None,
+        chips: int = 0,
     ):
+        """``chips``: how many of this host's TPU chips the daemon may
+        hand to the executors it launches, one each (``utils/devices.py``;
+        the daemon itself never imports JAX).  A worker-role executor
+        takes a free chip; with none free -- always, at the default 0 --
+        and for a DCN server role, the executor runs on the CPU backend
+        by assignment, which is logged and stamped in its role record."""
         # HA: the reference's workers take every master URL
         # (spark://h1:7077,h2:7077) and talk to whichever is leader; here
         # the list is [primary] + standby_masters and _master_call rotates
@@ -60,6 +68,7 @@ class Worker:
         self._procs_lock = threading.Lock()
         self._killed: set = set()  # apps killed by order: never supervise
         self._launch_env_extra = dict(launch_env_extra or {})
+        self._chips = _devices.ChipPool(chips)
         self.max_supervised_restarts = 3
         # master RPCs ride the shared retry policy; rotation across the HA
         # master list is the per-attempt body, so "no active master" is a
@@ -167,6 +176,16 @@ class Worker:
     def _launch(self, order: dict) -> None:
         env = dict(os.environ)
         env.update(order.get("env") or {})
+        role = _devices.process_roles(
+            order["argv"][0] if order["argv"] else "",
+            int(env.get("ASYNCTPU_NUM_PROCESSES", "1")),
+        )[int(env.get("ASYNCTPU_PROCESS_ID", "0"))]
+        chip = self._chips.take() if role == "worker" else None
+        assigned = _devices.CPU if chip is None else f"tpu:{chip}"
+        sys.stderr.write(
+            f"[{self.worker_id}] app {order['app_id']} proc "
+            f"{order['proc_id']} ({role}) -> {assigned}\n")
+        env = _devices.child_env(env, assigned)
         env.update(self._launch_env_extra)
         proc = subprocess.Popen(
             [sys.executable, "-m", "asyncframework_tpu.cli", *order["argv"]],
@@ -181,6 +200,8 @@ class Worker:
             # NOTE: output is buffered until exit (fine for the batch apps
             # this layer schedules; a log-streaming executor is future work)
             out, err = proc.communicate()
+            if chip is not None:
+                self._chips.release(chip)
             with self._procs_lock:
                 ps = self._procs.get(order["app_id"], [])
                 if proc in ps:
@@ -257,6 +278,9 @@ def main(argv: Optional[List[str]] = None) -> int:  # pragma: no cover
     p.add_argument("master", help="master address(es) host:port[,host:port]"
                                   " -- first is primary, rest standbys")
     p.add_argument("--cores", type=int, default=1)
+    p.add_argument("--chips", type=int, default=0,
+                   help="TPU chips this daemon may hand to executors, one "
+                        "each (0 = executors run on the CPU backend)")
     p.add_argument("--worker-id", default=None)
     args = p.parse_args(argv)
     from asyncframework_tpu.net import faults
@@ -268,7 +292,8 @@ def main(argv: Optional[List[str]] = None) -> int:  # pragma: no cover
     primary, *standbys = args.master.split(",")
     host, port = primary.rsplit(":", 1)
     w = Worker(host, int(port), worker_id=args.worker_id,
-               cores=args.cores, standby_masters=standbys).start()
+               cores=args.cores, standby_masters=standbys,
+               chips=args.chips).start()
     print(f"worker {w.worker_id} on {w.host}:{w.port} -> {args.master}",
           flush=True)
     try:

@@ -34,9 +34,14 @@ def mm_f32(A: jax.Array, v: jax.Array) -> jax.Array:
 
     The bf16 data path: shards stored bfloat16 hit the MXU at native rate
     while partial sums accumulate in float32 (``preferred_element_type``) --
-    the standard mixed-precision recipe.  For f32 ``A`` this is exactly the
-    plain matmul, so every gradient below is dtype-polymorphic over the
-    shard's storage dtype; ``w``/``y``/gradients stay f32 throughout.
+    the standard mixed-precision recipe.  For f32 ``A`` this is the plain
+    matmul at the backend's DEFAULT precision (no ``precision=`` is set),
+    so every gradient below is dtype-polymorphic over the shard's storage
+    dtype; ``w``/``y``/gradients stay f32 throughout.  On the v5e that
+    default is exact for the matrix-VECTOR products this module makes
+    (chip_smoke.py phase D: 0.0 from precision "highest" on a 50,000 x
+    2,000 shard) but rounds operands to bf16 for matrix-matrix products
+    (e.g. the (n, S) trajectory evaluation).
     Casting ``v`` down to ``A.dtype`` (rather than promoting ``A`` up) is
     what keeps an (n, d) bf16 shard from being materialized in f32.
     """

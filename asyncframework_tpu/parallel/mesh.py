@@ -11,7 +11,6 @@ replicated, but the axis is wired through so the same code scales).
 
 from __future__ import annotations
 
-import functools
 import logging
 from typing import Optional, Sequence, Tuple
 
@@ -23,57 +22,11 @@ logger = logging.getLogger(__name__)
 
 
 def resolve_shard_map():
-    """The one shard_map entry point for the whole repo.
-
-    ``shard_map`` moved across jax releases: new jax exposes
-    ``jax.shard_map`` (keyword-only ``mesh``/``in_specs``/``out_specs``,
-    ``check_vma=``), older installs only have
-    ``jax.experimental.shard_map.shard_map`` (``check_rep=`` instead of
-    ``check_vma=``, no varying-manual-axes tracking).  Every call site
-    routes through this resolver so one install difference is absorbed in
-    one place.  The returned callable always speaks the NEW surface --
-    ``check_vma=`` is accepted (and honored natively); the fallback runs
-    with ``check_rep=False`` unconditionally -- the old checker's
-    replication inference has known false positives the new API fixed
-    (scan carries whose rep sets converge only after a fixed point, e.g.
-    "Scan carry input and output got mismatched replication types ...
-    as a temporary workaround pass the check_rep=False argument", and
-    reductions of ``all_gather`` outputs).  Both flags are trace-time
-    diagnostics only; disabling one never changes numerics, and the
-    new-API path keeps full vma checking wherever it exists.
-    """
-    native = getattr(jax, "shard_map", None)
-    if native is not None:
-        return native
-    from jax.experimental.shard_map import shard_map as _legacy
-
-    def _compat(f=None, *, mesh, in_specs, out_specs, check_vma=True, **kw):
-        del check_vma  # legacy check_rep: known false positives (above)
-        if f is None:
-            return functools.partial(
-                _compat, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                **kw,
-            )
-        return _legacy(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False, **kw,
-        )
-
-    return _compat
-
-
-def pcast_varying(x, axis: str):
-    """``jax.lax.pcast(x, axis, to="varying")`` where available.
-
-    Legacy jax (the ``jax.experimental.shard_map`` era) has no
-    varying-manual-axes tracking, so there is nothing to cast -- the
-    value is returned unchanged and ``check_rep`` does its own (coarser)
-    replication inference.
-    """
-    pc = getattr(jax.lax, "pcast", None)
-    if pc is None:
-        return x
-    return pc(x, (axis,), to="varying")
+    """The one shard_map entry point for the whole repo: ``jax.shard_map``
+    (keyword ``mesh``/``in_specs``/``out_specs``, ``check_vma=``).  Every
+    call site routes through here so the next move of that API is absorbed
+    in one place."""
+    return jax.shard_map
 
 
 def make_mesh(

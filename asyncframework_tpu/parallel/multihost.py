@@ -26,19 +26,10 @@ import numpy as np
 _initialized = False
 
 
-def _jax_distributed_active() -> bool:
-    """True when jax.distributed was initialized (by us or by a launcher
-    calling ``jax.distributed.initialize()`` directly)."""
-    try:
-        from jax._src import distributed
-
-        return distributed.global_state.client is not None
-    except Exception:  # noqa: BLE001 - internals moved; assume inactive
-        return False
-
-
 def is_initialized() -> bool:
-    return _initialized or _jax_distributed_active()
+    """True when ``jax.distributed`` was initialized, by us or by a
+    launcher calling ``jax.distributed.initialize()`` directly."""
+    return _initialized or jax.distributed.is_initialized()
 
 
 def ensure_initialized(
@@ -59,7 +50,7 @@ def ensure_initialized(
     launcher already called ``jax.distributed.initialize()`` itself.
     """
     global _initialized
-    if _initialized or _jax_distributed_active():
+    if is_initialized():
         _initialized = True
         return jax.process_count() > 1
     coordinator_address = coordinator_address or os.environ.get(

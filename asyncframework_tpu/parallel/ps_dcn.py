@@ -716,6 +716,11 @@ class ParameterServer:
         self._ckpt_thread: Optional[threading.Thread] = None
         self._ckpt_trigger = threading.Event()
         self._eval_results: Dict[int, np.ndarray] = {}
+        # every process token that ever said HELLO: lets the launcher-side
+        # role tell a worker that is still BOOTING (chip init, data
+        # generation and compile take tens of seconds on a cold chip) from
+        # one that came and died
+        self.hello_procs: set = set()
         self._eval_cv = threading.Condition()
         self._stop = threading.Event()
 
@@ -1138,6 +1143,7 @@ class ParameterServer:
                     # a worker process introducing itself (elastic plane):
                     # proc token + logical worker ids + pid/host (+ the
                     # pid's /proc start time, pid-reuse protection)
+                    self.hello_procs.add(str(header.get("proc")))
                     if self.supervisor is not None:
                         self.supervisor.register(
                             str(header.get("proc")),
@@ -2597,8 +2603,8 @@ class ParameterServer:
         W = np.stack([w for (_t, w) in snaps])
         return times, W
 
-    def collect_eval(self, num_worker_procs: int, timeout_s: float
-                     ) -> Optional[np.ndarray]:
+    def collect_eval(self, num_worker_procs: int, timeout_s: float,
+                     await_all: bool = False) -> Optional[np.ndarray]:
         """Sum per-process snapshot losses pushed via EVAL_RESULT.
 
         With the supervisor, the expected count is clamped to processes
@@ -2606,12 +2612,13 @@ class ParameterServer:
         EVAL never comes, but its adopted shards are scored by their
         adopter -- the union still covers the full dataset, so waiting
         for the dead process would only trade the objective for a
-        timeout."""
+        timeout.  ``await_all`` skips the clamp (a process that joined
+        after DONE is alive but was not in the frozen roster)."""
         deadline = time.monotonic() + timeout_s
         with self._eval_cv:
             while True:
                 expected = num_worker_procs
-                if self.supervisor is not None:
+                if self.supervisor is not None and not await_all:
                     # clamp only when processes actually registered (an
                     # unelastic client set leaves the roster empty)
                     live = self.supervisor.live_proc_count()

@@ -35,7 +35,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from asyncframework_tpu.parallel.mesh import pcast_varying, resolve_shard_map
+from asyncframework_tpu.parallel.mesh import resolve_shard_map
 
 _NEG = -1e30  # mask fill / softmax-max init: finite so (-inf) - (-inf) never NaNs
 
@@ -96,7 +96,7 @@ def _merge_stats(m, l, o, m_b, l_b, o_b):
 
 def ring_attention(
     q, k, v, mesh: Mesh, axis: str = "sp", causal: bool = False,
-    block_kernel: str = "xla",
+    block_kernel: str = "xla", interpret: bool = False,
 ):
     """Exact attention over a sequence-sharded mesh axis via a K/V ring.
 
@@ -109,8 +109,9 @@ def ring_attention(
     ``block_kernel``: "xla" runs the per-step block attention as fused XLA
     (:func:`_block_accumulate`); "pallas" offloads it to the hand-tiled
     :func:`~asyncframework_tpu.ops.pallas_kernels.chunk_attention` kernel
-    (two MXU matmuls + exp entirely in VMEM, interpret-mode on CPU) and
-    merges the returned (o, m, l) stats with the same flash rescale.
+    and merges the returned (o, m, l) stats with the same flash rescale.
+    ``interpret=True`` runs that kernel in the Pallas interpreter (the CPU
+    tests pass it); it is never inferred from the backend.
     """
     if block_kernel not in ("xla", "pallas"):
         raise ValueError("block_kernel must be 'xla' or 'pallas'")
@@ -142,7 +143,7 @@ def ring_attention(
     )
     def ring(ql, kl, vl):
         p_idx = jax.lax.axis_index(axis)
-        P_sz = n_dev  # static mesh axis size (jax.lax.axis_size is new-API)
+        P_sz = n_dev  # static mesh axis size
         b, tq, h, d = ql.shape
         t_local = kl.shape[1]
         # pcast to varying: the accumulators become device-varying on the sp
@@ -151,7 +152,7 @@ def ring_attention(
         def varying(x):
             if not use_vma:
                 return x  # vma tracking off: pcast is meaningless
-            return pcast_varying(x, axis)
+            return jax.lax.pcast(x, (axis,), to="varying")
 
         m0 = varying(jnp.full((b, h, tq), _NEG, jnp.float32))
         l0 = varying(jnp.zeros((b, h, tq), jnp.float32))
@@ -165,8 +166,7 @@ def ring_attention(
                 )
 
                 o_b, m_b, l_b = chunk_attention(
-                    ql, kb, vb, mask,
-                    interpret=jax.default_backend() != "tpu",
+                    ql, kb, vb, mask, interpret=interpret,
                 )
                 return _merge_stats(m, l, o, m_b, l_b, o_b)
             return _block_accumulate(ql, kb, vb, m, l, o, mask)
@@ -210,14 +210,16 @@ def ring_attention(
 def ulysses_attention(
     q, k, v, mesh: Mesh, axis: str = "sp", causal: bool = False,
     block_kernel: str = "xla", pallas_block: int = 512,
+    interpret: bool = False,
 ):
     """All-to-all sequence parallelism (Ulysses-style): reshard seq->heads,
     attend over the full sequence per local head group, reshard back.
 
     ``block_kernel="pallas"`` folds the full-sequence attention through
     :func:`~asyncframework_tpu.ops.pallas_kernels.chunk_attention` in
-    ``pallas_block``-sized K/V blocks (VMEM-bounded) merged by the shared
-    flash rescale, instead of the XLA reference path.
+    ``pallas_block``-sized K/V blocks merged by the shared flash rescale,
+    instead of the XLA reference path; ``interpret`` as in
+    :func:`ring_attention`.
     """
     if block_kernel not in ("xla", "pallas"):
         raise ValueError("block_kernel must be 'xla' or 'pallas'")
@@ -274,7 +276,6 @@ def ulysses_attention(
             nb = (tk + pad_k) // blk
             b, _, hl, dh = qh.shape
             q_pos = jnp.arange(tq)
-            interp = jax.default_backend() != "tpu"
 
             def fold_block(carry, i):
                 m, l, o = carry
@@ -287,7 +288,7 @@ def ulysses_attention(
                 else:
                     mask_b = jnp.broadcast_to(valid, (tq, blk))
                 o_b, m_b, l_b = chunk_attention(
-                    qh, kb, vb, mask_b, interpret=interp
+                    qh, kb, vb, mask_b, interpret=interpret
                 )
                 return _merge_stats(m, l, o, m_b, l_b, o_b), None
 

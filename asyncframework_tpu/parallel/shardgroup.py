@@ -81,6 +81,7 @@ import numpy as np
 from asyncframework_tpu.metrics import flightrec as _flight
 from asyncframework_tpu.net import frame as _frame
 from asyncframework_tpu.parallel import supervisor as supervisor_mod
+from asyncframework_tpu.utils import devices as _devices
 
 # ------------------------------------------------------------- group totals
 # Process-global shard-group counters (metrics/registry.py family
@@ -921,7 +922,14 @@ class ShardGroup:
                               else range(self.shards))
         self.fixed_entries = dict(fixed_entries or {})
         self.conf_overlays = dict(conf_overlays or {})
-        self.env = dict(env if env is not None else os.environ)
+        # Shard processes run on the CPU backend BY ASSIGNMENT: the
+        # process that owns this group (the cluster CLI's primary PS, a
+        # bench arm) has usually initialised a JAX backend and so holds
+        # every chip it can see; a child that asked for one would fail or
+        # hang.  A shard is a d-vector axpy server; the chips belong to
+        # the workers (utils/devices.py).
+        self.env = _devices.child_env(
+            env if env is not None else os.environ, _devices.CPU)
         self.worker_procs = int(worker_procs)
         self.elastic = bool(elastic)
         self.stderr_dir = stderr_dir
@@ -1955,8 +1963,12 @@ def _child_main() -> int:
     overlays = os.environ.get("ASYNC_SHARD_CONF")
     if overlays:
         set_global_conf(AsyncConf(json.loads(overlays)))
-    import jax  # after conf: platform pins ride the child env
+    from asyncframework_tpu.utils.devices import (
+        device_stamp,
+        setup_compile_cache,
+    )
 
+    setup_compile_cache()  # after conf: platform pins ride the child env
     from asyncframework_tpu.parallel.ps_dcn import ParameterServer
     from asyncframework_tpu.solvers import SolverConfig
 
@@ -2037,9 +2049,12 @@ def _child_main() -> int:
         # has no controller to send it): installs the map and starts
         # this primary's replication stream
         ps.set_standby_map(json.loads(sbs_env))
+    stamp = device_stamp()
     print(json.dumps({"port": ps.port, "shard": index, "role": role,
-                      "resumed_from": ps.resumed_from_k}), flush=True)
-    print(f"shard {index} ({role}) serving on {ps.port}",
+                      "resumed_from": ps.resumed_from_k, **stamp}),
+          flush=True)
+    print(f"shard {index} ({role}) serving on {ps.port}, device "
+          f"{stamp['platform']} (assigned: {stamp['assigned']})",
           file=sys.stderr, flush=True)
     ok = ps.wait_done(timeout_s=cfg.run_timeout_s)
     result = {

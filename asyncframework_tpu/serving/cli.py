@@ -74,10 +74,6 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main(argv: Optional[List[str]] = None) -> int:
-    if os.environ.get("ASYNCTPU_FORCE_CPU"):
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
     args = build_parser().parse_args(argv)
     from asyncframework_tpu.cli import parse_conf_overlays
 
@@ -97,7 +93,9 @@ def main(argv: Optional[List[str]] = None) -> int:
         start_telemetry_from_conf("frontend")
     if args.role == "replica":
         from asyncframework_tpu.serving.replica import serve_replica
+        from asyncframework_tpu.utils.devices import setup_compile_cache
 
+        setup_compile_cache()  # the replica role is the one that uses JAX
         rid, relay_parent = args.rid, args.relay_parent
         if args.relay_auto:
             # StatefulSet convention: hostname "async-serve-replica-3"

@@ -527,8 +527,12 @@ def serve_replica(ps: str, rid: int = 0, host: str = "0.0.0.0",
         threading.Thread(target=guarded(hello_loop, f"replica-{rid}-hello"),
                          name=f"replica-{rid}-hello",
                          daemon=True).start()
+    from asyncframework_tpu.utils.devices import device_stamp
+
+    # the role record names the device this replica runs on and what its
+    # launcher assigned (replicas usually get the CPU backend by assignment)
     line = {"role": "replica", "rid": rid, "port": rep.port,
-            "pid": os.getpid()}
+            "pid": os.getpid(), **device_stamp()}
     if rep._relay_node is not None:
         # the node bound in __init__, so an ephemeral ask announces the
         # real port and launchers learn the tree endpoint here

@@ -438,7 +438,7 @@ class ColumnarFrame:
 def _gather(src, idx):
     """Row gather routed by backend: ``jnp.take`` keeps device columns on
     an accelerator; on the CPU backend numpy fancy indexing is 4-6x faster
-    (measured, ROUND5.md) and the frame constructor re-stages the result."""
+    (measured on the CPU rig) and the frame constructor re-stages the result."""
     if isinstance(src, jnp.ndarray):
         import jax
 
@@ -452,7 +452,7 @@ def _match_table(rk_sorted: np.ndarray, rk: np.ndarray, lk: np.ndarray):
     """(start, count) of each left key's match run in the sorted right
     keys.  Dense-enough integer keys take the O(1)-per-probe bincount
     table (two binary-search passes over 2M probes cost ~1.3 s; the table
-    lookups ~70 ms -- ROUND5.md); anything else binary-searches."""
+    lookups ~70 ms on the CPU rig); anything else binary-searches."""
     if (
         lk.dtype.kind in "iu" and rk.dtype.kind in "iu"
         and lk.size and rk.size
@@ -644,7 +644,7 @@ class GroupedFrame:
     segment ops on device (one fused scatter-add per aggregate, data never
     leaves HBM); on the CPU backend the same reductions run as host
     ``bincount``/``reduceat`` -- a jax dispatch per aggregate costs more
-    than the reduction itself there (ROUND3.md's 17x gap to pandas was
+    than the reduction itself there (the CPU rig's 17x gap to pandas was
     coding + CPU-backend dispatch overhead, not the math).
     """
 

@@ -162,7 +162,7 @@ def _raise_ragged(path, text, delimiter, header, want_count):
 
 def _read_csv_fast(path, header, columns, delimiter, select, where):
     """pandas-C-parser fast path (~7x the python csv module at 1M rows,
-    ROUND5.md): clean numeric columns parse typed in C
+    CPU rig): clean numeric columns parse typed in C
     (``keep_default_na=False`` keeps empty cells as '' so mixed/missing
     columns arrive as exact strings and run through the same inference).
     Restricted to quote-free single-char delimiters.  Ragged rows keep the

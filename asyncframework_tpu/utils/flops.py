@@ -22,27 +22,34 @@ from __future__ import annotations
 
 from typing import Optional
 
-#: dense-matmul peak FLOP/s per chip by device_kind substring (public specs)
-_PEAK_BF16 = (
-    ("v6", 918e12),        # Trillium / v6e
-    ("v5p", 459e12),
-    ("v5", 197e12),        # v5e / "TPU v5 lite"
-    ("v4", 275e12),
-    ("v3", 123e12),
-    ("v2", 45e12),
-)
+#: dense-matmul bf16 peak FLOP/s per chip (public specs), keyed by the
+#: ``device_kind`` string JAX reports.  "TPU v5 lite" is what a v5e chip
+#: answers (chip_smoke.py on the v5e, PR 21); the other spellings are from
+#: the JAX sources and have not met this repo's code.
+_PEAK_BF16 = {
+    "TPU v2": 45e12,
+    "TPU v3": 123e12,
+    "TPU v4": 275e12,
+    "TPU v5 lite": 197e12,  # v5e
+    "TPU v5p": 459e12,
+    "TPU v6 lite": 918e12,  # Trillium / v6e
+}
 
 
 def chip_peak_flops(device) -> Optional[float]:
-    """Best-effort bf16 dense-matmul peak for ``device``; None if unknown
-    (CPU backends have no meaningful MXU peak -- MFU is reported null)."""
-    kind = str(getattr(device, "device_kind", "")).lower()
-    if "tpu" not in kind and getattr(device, "platform", "") != "tpu":
+    """bf16 dense-matmul peak for ``device``.  None on the CPU platform
+    (no MXU peak; MFU is reported null).  A TPU whose ``device_kind`` is
+    not in the table is an error, not a default: a utilization divided by
+    a guessed peak is a wrong number."""
+    if getattr(device, "platform", "") != "tpu":
         return None
-    for tag, peak in _PEAK_BF16:
-        if tag in kind:
-            return peak
-    return 197e12 if kind else None  # unknown TPU: assume the v5e floor
+    kind = str(getattr(device, "device_kind", ""))
+    if kind not in _PEAK_BF16:
+        raise ValueError(
+            f"no peak FLOP/s on record for device_kind {kind!r}; add it to "
+            f"utils/flops._PEAK_BF16 with its source"
+        )
+    return _PEAK_BF16[kind]
 
 
 def dense_task_flops(n_rows: int, d: int) -> float:

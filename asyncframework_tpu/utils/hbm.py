@@ -17,8 +17,8 @@ import numpy as np
 
 import jax
 
-#: conservative default per-chip budget when the runtime reports nothing
-DEFAULT_HBM_BYTES = 16 * 1024**3
+#: planning budget for the CPU platform only, whose runtime reports no limit
+CPU_PLAN_BYTES = 16 * 1024**3
 
 
 def nbytes(shape: Sequence[int], dtype=np.float32) -> int:
@@ -26,16 +26,20 @@ def nbytes(shape: Sequence[int], dtype=np.float32) -> int:
 
 
 def device_hbm_bytes(device=None) -> int:
-    """Best-effort total HBM of a device; falls back to a conservative
-    default (CPU/interpret backends report nothing useful)."""
+    """Total memory of a device as its runtime reports it.  The CPU
+    platform reports nothing and plans against ``CPU_PLAN_BYTES`` (tests
+    exercise the planner there); an accelerator that reports nothing is an
+    error -- planning 16 GiB for an unknown chip would hide it."""
     dev = device or jax.devices()[0]
-    stats = {}
-    try:
-        stats = dev.memory_stats() or {}
-    except (AttributeError, NotImplementedError, jax.errors.JaxRuntimeError):
-        pass
-    limit = stats.get("bytes_limit")
-    return int(limit) if limit else DEFAULT_HBM_BYTES
+    if dev.platform == "cpu":
+        return CPU_PLAN_BYTES
+    limit = (dev.memory_stats() or {}).get("bytes_limit")
+    if not limit:
+        raise RuntimeError(
+            f"{dev.platform} device {dev.device_kind!r} reports no "
+            f"bytes_limit in memory_stats(); cannot plan its memory"
+        )
+    return int(limit)
 
 
 def device_hbm_in_use(device=None) -> Optional[int]:

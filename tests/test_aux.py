@@ -26,10 +26,12 @@ from asyncframework_tpu.utils import hbm
 
 
 class TestFusedMaskedGrad:
-    """interpret=True: the Pallas kernel runs on the CPU interpreter here
-    and compiles natively on TPU (same code path; bench covers that)."""
+    """interpret=True: the Pallas kernel runs on the CPU interpreter here;
+    chip_smoke.py phase E compiles it natively on the chip.  Shapes cover
+    one exact tile, tiles plus a ragged XLA tail, and a tail alone."""
 
-    @pytest.mark.parametrize("n,d", [(256, 128), (300, 100), (64, 17)])
+    @pytest.mark.parametrize("n,d", [(256, 128), (300, 100), (64, 17),
+                                     (700, 200)])
     def test_matches_oracle(self, rng, n, d):
         X = rng.normal(size=(n, d)).astype(np.float32)
         y = rng.normal(size=(n,)).astype(np.float32)
@@ -40,6 +42,22 @@ class TestFusedMaskedGrad:
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-3
         )
+
+    def test_bf16_shard_read_in_storage_dtype(self, rng):
+        """bf16 shards follow mm_f32 (bf16 operands, f32 accumulation):
+        the kernel must agree with the main path's own contraction."""
+        import jax.numpy as jnp
+
+        from asyncframework_tpu.ops.gradients import least_squares_grad_sum
+
+        X = jnp.asarray(rng.normal(size=(600, 96)), jnp.bfloat16)
+        y = rng.normal(size=(600,)).astype(np.float32)
+        w = rng.normal(size=(96,)).astype(np.float32)
+        mask = (rng.random(600) < 0.5).astype(np.float32)
+        got = fused_masked_grad(X, y, w, mask, interpret=True)
+        want = least_squares_grad_sum(X, y, w, mask)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-3)
 
     def test_no_mask_means_all_rows(self, rng):
         X = rng.normal(size=(64, 32)).astype(np.float32)

@@ -6,6 +6,10 @@ modulo the jar/class prefix.
 """
 
 import json
+import os
+import subprocess
+import sys
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -25,7 +29,13 @@ def run_cli(capsys, argv):
     rc = cli.main(argv)
     assert rc == 0
     out = capsys.readouterr().out.strip().splitlines()
-    return json.loads(out[-1]), out[:-1]
+    summary = json.loads(out[-1])
+    # every summary names the device it ran on, so that a run can always
+    # be told apart from one on another platform
+    assert summary["platform"] == "cpu" and summary["device_kind"] == "cpu"
+    assert summary["n_devices"] == 8
+    assert "assigned" in summary  # what a launcher decided (none here)
+    return summary, out[:-1]
 
 
 class TestDrivers:
@@ -145,3 +155,102 @@ class TestObservabilityFlags:
             "asgd-sync", iters=10, extra=("--quiet", "--speculation"),
         ))
         assert summary["accepted"] == 10 * 8
+
+
+class TestIncompleteRunsExitNonZero:
+    """A run that did not do what was asked must not exit 0."""
+
+    def test_async_run_cut_short_by_run_timeout(self, capsys, monkeypatch):
+        """ASAGA keeps the reference's filter ``k - staleness <= taw``, so
+        with a finite taw nothing is accepted once k passes it: the
+        submitter loop ends at run_timeout_s with a NORMAL TrainResult
+        (accepted < requested).  The summary still prints; the exit code
+        says the run is incomplete."""
+        import functools
+
+        import asyncframework_tpu.solvers as solvers
+
+        monkeypatch.setattr(
+            solvers, "SolverConfig",
+            functools.partial(solvers.SolverConfig, run_timeout_s=1.0))
+        rc = cli.main(recipe("asaga", iters=400, taw=16,
+                             extra=("--quiet",)))
+        captured = capsys.readouterr()
+        summary = json.loads(captured.out.strip().splitlines()[-1])
+        assert summary["requested"] == 400
+        assert summary["accepted"] < 400
+        assert rc != 0
+        assert "run incomplete" in captured.err
+
+    def test_dcn_server_that_never_reports_done(self, capsys, monkeypatch):
+        from asyncframework_tpu.net.frame import free_port
+        from asyncframework_tpu.parallel import ps_dcn
+
+        monkeypatch.setenv("ASYNCTPU_COORDINATOR",
+                           f"127.0.0.1:{free_port()}")
+        monkeypatch.setenv("ASYNCTPU_NUM_PROCESSES", "2")
+        monkeypatch.setenv("ASYNCTPU_PROCESS_ID", "0")
+        # no worker process ever connects: the wait times out
+        monkeypatch.setattr(
+            ps_dcn.ParameterServer, "wait_done",
+            lambda self, timeout_s, **kw: ps_dcn.WaitDone(
+                False, "no worker contacted the PS"))
+        monkeypatch.setattr(ps_dcn.ParameterServer, "collect_eval",
+                            lambda self, n, timeout_s: None)
+        try:
+            rc = cli.main(recipe("asgd", iters=20, extra=("--quiet",)))
+        finally:
+            from asyncframework_tpu.conf import set_global_conf
+
+            set_global_conf(None)  # run_async_cluster's cluster defaults
+        captured = capsys.readouterr()
+        summary = json.loads(captured.out.strip().splitlines()[-1])
+        assert summary["driver"] == "asgd-dcn-ps"
+        assert summary["done"] is False and summary["accepted"] == 0
+        assert summary["platform"] == "cpu"
+        assert rc != 0
+
+
+class TestLateBootingWorkerProcess:
+    def test_server_outlives_a_worker_still_booting_at_done(self):
+        """On a cold chip a worker process needs tens of seconds to reach
+        its device, generate data and compile, with seconds of skew
+        between processes; a short run can be DONE before the last one
+        has said HELLO.  The server must still be there to tell it DONE
+        (a worker that finds the server gone retries HELLO for the whole
+        run timeout).  Here process 2 is started only after process 1 --
+        and so the run -- has finished."""
+        from asyncframework_tpu.net.frame import free_port
+
+        repo = Path(__file__).parent.parent
+        env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=str(repo),
+                   ASYNCTPU_COORDINATOR=f"127.0.0.1:{free_port()}",
+                   ASYNCTPU_NUM_PROCESSES="3")
+        argv = recipe("asgd", iters=60, extra=(
+            "--quiet", "--conf", "async.elastic.boot.grace.s=1",
+            "--conf", "async.elastic.dead.after.s=1"))
+
+        def spawn(pid):
+            return subprocess.Popen(
+                [sys.executable, "-m", "asyncframework_tpu.cli", *argv],
+                env=dict(env, ASYNCTPU_PROCESS_ID=str(pid)), text=True,
+                stdout=subprocess.PIPE, stderr=subprocess.PIPE)
+
+        procs = [spawn(0), spawn(1)]
+        try:
+            out1, _ = procs[1].communicate(timeout=120)  # run is DONE now
+            procs.append(spawn(2))
+            out2, err2 = procs[2].communicate(timeout=120)
+            out0, err0 = procs[0].communicate(timeout=120)
+        finally:
+            for p in procs:
+                if p.poll() is None:
+                    p.kill()
+        assert [p.returncode for p in procs] == [0, 0, 0], (err0[-2000:],
+                                                             err2[-2000:])
+        summary = json.loads(out0.strip().splitlines()[-1])
+        assert summary["done"] is True and summary["accepted"] == 60
+        assert "still booting at DONE" in err0
+        late = json.loads(out2.strip().splitlines()[-1])
+        assert late["process_id"] == 2 and late["gradients"] == 0
+        assert json.loads(out1.strip().splitlines()[-1])["gradients"] >= 60

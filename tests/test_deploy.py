@@ -25,7 +25,7 @@ _SPMD_CPU_REASON = None  # session cache: None = not probed, '' = capable
 
 def cpu_spmd_capability() -> str:
     """Probed capability (ISSUE 12 deflake): can THIS rig's jax run a
-    2-process SPMD computation on the CPU backend?  jax 0.4.37 without
+    2-process SPMD computation on the CPU backend?  A jax build without
     gloo-capable CPU collectives raises "Multiprocess computations
     aren't implemented on the CPU backend" -- the same class as the
     documented tests/test_multihost.py baseline failures, but here it
@@ -82,8 +82,7 @@ def rig(tmp_path):
     workers = [
         Worker("127.0.0.1", m.port, worker_id=f"w{i}",
                heartbeat_s=0.3,
-               launch_env_extra={"ASYNCTPU_FORCE_CPU": "1",
-                                 "JAX_PLATFORMS": "cpu"}).start()
+               launch_env_extra={"JAX_PLATFORMS": "cpu"}).start()
         for i in range(2)
     ]
     yield m, workers
@@ -399,8 +398,7 @@ class TestStandbyFailover:
                 Worker(a_host, int(a_port), worker_id=f"w{i}",
                        heartbeat_s=0.3,
                        standby_masters=[f"127.0.0.1:{standby.port}"],
-                       launch_env_extra={"ASYNCTPU_FORCE_CPU": "1",
-                                         "JAX_PLATFORMS": "cpu"}).start()
+                       launch_env_extra={"JAX_PLATFORMS": "cpu"}).start()
                 for i in range(2)
             ]
             ha_addr = f"{active_addr},127.0.0.1:{standby.port}"

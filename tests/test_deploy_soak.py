@@ -79,8 +79,7 @@ class TestClusterSoak:
                 Worker(a_host, int(a_port), worker_id=f"w{i}",
                        heartbeat_s=0.3,
                        standby_masters=[f"127.0.0.1:{standby.port}"],
-                       launch_env_extra={"ASYNCTPU_FORCE_CPU": "1",
-                                         "JAX_PLATFORMS": "cpu"}).start()
+                       launch_env_extra={"JAX_PLATFORMS": "cpu"}).start()
                 for i in range(3)
             ]
             ha_addr = f"{active_addr},127.0.0.1:{standby.port}"

@@ -89,19 +89,15 @@ def run_dcn(devices, cfg, conf, nw=None, n=1024, d=16, seed=11,
         ps.stop()
 
 
-# ----------------------------------------------------------- compat shim
+# ------------------------------------------------- the shard_map entry point
 class TestResolveShardMap:
     def test_resolves_on_this_install(self):
-        """The shim must hand back a WORKING shard_map on whatever jax
-        the container has -- native ``jax.shard_map`` or the
-        ``jax.experimental.shard_map`` fallback with ``check_vma``
-        translated away."""
-        smap = resolve_shard_map()
-        assert callable(smap)
-        if hasattr(jax, "shard_map"):
-            assert smap is jax.shard_map
+        """One entry point for the whole repo, and it is ``jax.shard_map``."""
+        assert resolve_shard_map() is jax.shard_map
 
     def test_shimmed_psum_program_runs(self, devices8):
+        """(The name predates the removal of the legacy shim; kept so the
+        test's id is stable.)  A rank-0 output takes ``P()``."""
         import functools
 
         from jax.sharding import PartitionSpec as P
@@ -110,7 +106,7 @@ class TestResolveShardMap:
 
         @functools.partial(
             resolve_shard_map(), mesh=mesh, in_specs=P("dp"),
-            out_specs=P(None), check_vma=True,
+            out_specs=P(), check_vma=True,
         )
         def total(x):
             return jax.lax.psum(jnp.sum(x), "dp")

@@ -25,7 +25,7 @@ CHILD = Path(__file__).parent / "dcn_child.py"
 def _require_cpu_spmd() -> None:
     """Probed-capability gate (ISSUE 13 tier-1 deflake): cross-process
     SPMD on the CPU backend is a jax-build capability, not a property of
-    this repo's code -- jax 0.4.37 without gloo-capable CPU collectives
+    this repo's code -- a jax build without gloo-capable CPU collectives
     raises "Multiprocess computations aren't implemented on the CPU
     backend".  The session-cached 2-process probe (tests/test_deploy.py,
     ISSUE 12) runs the repo's own bring-up once; on incapable rigs these

@@ -635,7 +635,6 @@ class TestInteriorKillAcceptance:
         try:
             env = dict(os.environ)
             env["JAX_PLATFORMS"] = "cpu"
-            env["ASYNCTPU_FORCE_CPU"] = "1"
             env["PYTHONPATH"] = str(REPO)
             env["ASYNCTPU_ASYNC_SERVE_REFRESH_INTERVAL_S"] = "0.02"
             env["ASYNCTPU_ASYNC_RELAY_PARENT_RETRY_S"] = "1.0"

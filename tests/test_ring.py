@@ -147,7 +147,8 @@ class TestPallasBlockKernel:
             for _ in range(3)
         )
         got = ring_attention(
-            q, k, v, sp_mesh, causal=causal, block_kernel="pallas"
+            q, k, v, sp_mesh, causal=causal, block_kernel="pallas",
+            interpret=True,
         )
         want = reference_attention(q, k, v, causal=causal)
         np.testing.assert_allclose(
@@ -161,18 +162,22 @@ class TestPallasBlockKernel:
 
 
 class TestChunkAttentionKernel:
-    def test_stats_match_oracle(self, rng):
+    # the second shape is past the kernel's 512 block edge on both axes:
+    # several query blocks, several key blocks folded through the running
+    # (m, l, acc) scratch, ragged padding on each
+    @pytest.mark.parametrize("B,T,Tk,H,D", [(2, 24, 18, 3, 20),
+                                            (1, 520, 1030, 2, 8)])
+    def test_stats_match_oracle(self, rng, B, T, Tk, H, D):
         import math
 
         import jax.numpy as jnp
 
         from asyncframework_tpu.ops.pallas_kernels import chunk_attention
 
-        B, T, H, D = 2, 24, 3, 20
         q = rng.normal(size=(B, T, H, D)).astype(np.float32)
-        k = rng.normal(size=(B, 18, H, D)).astype(np.float32)
-        v = rng.normal(size=(B, 18, H, D)).astype(np.float32)
-        mask = rng.random((T, 18)) > 0.3
+        k = rng.normal(size=(B, Tk, H, D)).astype(np.float32)
+        v = rng.normal(size=(B, Tk, H, D)).astype(np.float32)
+        mask = rng.random((T, Tk)) > 0.3
         o, m, l = chunk_attention(q, k, v, mask, interpret=True)
         s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / math.sqrt(D)
         s = jnp.where(jnp.asarray(mask)[None, None], s, -1e30)
@@ -199,7 +204,8 @@ class TestUlyssesPallas:
             for _ in range(3)
         )
         got = ulysses_attention(
-            q, k, v, sp_mesh, causal=causal, block_kernel="pallas"
+            q, k, v, sp_mesh, causal=causal, block_kernel="pallas",
+            interpret=True,
         )
         want = reference_attention(q, k, v, causal=causal)
         np.testing.assert_allclose(
@@ -218,7 +224,7 @@ class TestUlyssesPallas:
         )
         got = ulysses_attention(
             q, k, v, sp_mesh, causal=causal, block_kernel="pallas",
-            pallas_block=8,
+            pallas_block=8, interpret=True,
         )
         want = reference_attention(q, k, v, causal=causal)
         np.testing.assert_allclose(

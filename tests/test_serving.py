@@ -429,7 +429,6 @@ class TestKillNineAcceptance:
                                                        host="127.0.0.1")
             env = dict(os.environ)
             env["JAX_PLATFORMS"] = "cpu"
-            env["ASYNCTPU_FORCE_CPU"] = "1"
             env["PYTHONPATH"] = str(REPO)
             env["ASYNCTPU_ASYNC_SERVE_REFRESH_INTERVAL_S"] = "0.02"
             for rid in range(2):

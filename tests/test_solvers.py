@@ -373,7 +373,14 @@ class TestBF16AndFlops:
             platform = "cpu"
             device_kind = "cpu"
 
+        class UnknownTPU:
+            platform = "tpu"
+            device_kind = "TPU v99"
+
         assert chip_peak_flops(FakeTPU()) == 197e12
         assert chip_peak_flops(FakeCPU()) is None
+        # a chip that is not in the table is an error, never a default
+        with pytest.raises(ValueError, match="TPU v99"):
+            chip_peak_flops(UnknownTPU())
         assert mfu(197e12, 1.0, FakeTPU()) == pytest.approx(1.0)
         assert mfu(1e9, 1.0, FakeCPU()) is None

@@ -1,6 +1,6 @@
 """Sparse (rcv1-class) end-to-end tests.
 
-Round-2 requirement (VERDICT.md item 4): CSR shards resident on device in a
+Round-2 requirement: CSR shards resident on device in a
 static-shape form, the worker step computing sparse gradients without ever
 densifying the data, and an ASGD recipe on a 47k-dim ~0.2%-dense problem
 converging -- through the CLI as well.

@@ -273,7 +273,9 @@ class TestElasticInProcess:
         clamped to live membership."""
         sup = ElasticSupervisor(4, dead_after_s=0.5, check_interval_s=0.1,
                                 boot_grace_s=30.0)
-        cfg = make_cfg(num_iterations=600, printer_freq=200)
+        # long enough that the run outlasts the 0.5 s death verdict on its
+        # own, not because it happens to compile first
+        cfg = make_cfg(num_iterations=1200, printer_freq=400)
         n, d = 1024, 16
         ds = ShardedDataset.generate_on_device(n, d, 4, devices=devices8[:4],
                                                seed=11, noise=0.01)

@@ -1062,7 +1062,6 @@ class TestAcceptance:
             fe_mport, rep_mport = free_port(), free_port()
             senv = dict(os.environ)
             senv["JAX_PLATFORMS"] = "cpu"
-            senv["ASYNCTPU_FORCE_CPU"] = "1"
             senv["PYTHONPATH"] = str(REPO)
             senv["ASYNCTPU_ASYNC_METRICS_INTERVAL_S"] = "0.2"
             serve_procs.append(subprocess.Popen(
@@ -1168,7 +1167,6 @@ class TestAcceptance:
         def spawn_replica():
             env = dict(os.environ)
             env["JAX_PLATFORMS"] = "cpu"
-            env["ASYNCTPU_FORCE_CPU"] = "1"
             env["PYTHONPATH"] = str(REPO)
             env["ASYNCTPU_ASYNC_SERVE_REFRESH_INTERVAL_S"] = "0.02"
             return subprocess.Popen(

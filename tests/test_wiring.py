@@ -1,6 +1,6 @@
 """End-to-end tests that the sidecar subsystems are wired INTO solver runs.
 
-Round-2 requirement (VERDICT.md item 3): event log + metrics emitted by real
+Round-2 requirement: event log + metrics emitted by real
 runs, heartbeat-driven executor replacement DURING a run, shard re-homing on
 repeated loss, speculation in sync mode, and the versioned-store stale-read
 experiment -- each exercised through an actual training run, not a unit
@@ -82,6 +82,7 @@ class TestEventLogWiring:
         assert summary["rounds"] > 0
 
 
+@pytest.mark.usefixtures("no_compile_cache")  # kills are timed in seconds
 class TestFaultToleranceWiring:
     def _run_async_with_kills(self, devices8, problem, kills, cfg):
         """Start an async ASGD run, kill executor 3 `kills` times, return res."""
