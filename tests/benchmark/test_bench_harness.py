@@ -1,0 +1,349 @@
+"""CPU rehearsal of the benchmark harness (``benchmark/run.py``).
+
+Everything the manifest names loads by name; every kind of cell the harness
+takes as data (dense f32, dense bf16, padded ELL, ASAGA, the synchronous
+barrier, four devices) runs at a tiny size and prints the contract's last
+line.  The platform check is relaxed HERE, by patching the module, not by
+an option of ``run.py``: the command the driver runs has no such switch.
+"""
+
+import json
+import os
+import re
+import subprocess
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmark import manifest as manifest_mod  # noqa: E402
+from benchmark import plan as plan_mod, roofline, run, target  # noqa: E402
+
+MANIFEST = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+E2E = [m["name"] for m in MANIFEST["end_to_end"]]
+PER_LAYER = [m["name"] for m in MANIFEST["per_layer"]]
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+DEVICE_KEYS = {"platform", "kind", "count", "memory_peak_bytes"}
+
+# ------------------------------------------------------------ the manifest
+
+
+def test_manifest_has_exactly_the_contracts_keys():
+    assert set(MANIFEST) == {
+        "command", "paths", "run_seconds", "configs", "workloads",
+        "end_to_end", "per_layer",
+    }
+    assert MANIFEST["command"] == ["python3", "benchmark/run.py"]
+    for p in MANIFEST["paths"]:
+        assert os.path.isdir(os.path.join(ROOT, p))
+    cells = MANIFEST["workloads"]
+    assert 2 <= len(cells) <= 24
+    pairs = [(c["config"], c["traffic"]) for c in cells]
+    assert len(set(pairs)) == len(pairs)
+    assert sum(c["chips"] == 4 for c in cells) <= max(1, len(cells) // 4)
+    assert {c["config"] for c in cells} == {c["name"] for c in MANIFEST["configs"]}
+    assert "setup_s" in E2E
+    for m in MANIFEST["end_to_end"]:
+        assert set(m) - {"workloads"} == {"name", "unit", "better", "bound", "source"}
+        assert 0.01 <= m["bound"] <= 0.1
+        assert m["source"] in ("host_clock", "device_trace")
+    for m in MANIFEST["per_layer"]:
+        assert set(m) - {"workloads"} == {
+            "name", "unit", "better", "source", "layer", "moves"
+        }
+        assert m["moves"] in E2E
+    names = [e["name"] for k in ("configs", "workloads", "end_to_end", "per_layer")
+             for e in MANIFEST[k]]
+    assert all(NAME.match(n) for n in names)
+    assert len(set(E2E + PER_LAYER)) == len(E2E + PER_LAYER)
+
+
+def test_a_full_check_fits_the_drivers_budget_with_24_cells():
+    rs = MANIFEST["run_seconds"]
+    assert isinstance(rs, int) and 1 <= rs <= 51
+    runs = 2 + 14 * 24
+    assert runs * (rs + 60) + 24 * 2 * 90 + 1200 <= 43200
+
+
+@pytest.mark.parametrize("cell", [c["name"] for c in MANIFEST["workloads"]])
+def test_cell_resolves_to_a_plan_from_its_files(cell):
+    man = manifest_mod.Manifest()
+    entry = man.workload(cell)
+    config = man.config(entry["config"])
+    mix = man.traffic(entry["traffic"])
+    plan = plan_mod.resolve(config, mix)
+    assert set(plan) == set(plan_mod.RUN_KEYS)
+    centry = [c for c in MANIFEST["configs"] if c["name"] == entry["config"]][0]
+    assert centry["file"].startswith(tuple(p + "/" for p in MANIFEST["paths"]))
+    assert config["reduced"] == centry["reduced"]
+    assert config["source"] == centry["source"]
+    assert {"gamma", "data", "noise"} <= set(config["assumed"])
+    # the published shape: no width is cut
+    assert (config["n"], config["d"]) == (8_100_000, 784)
+    kw = plan_mod.solver_config_kwargs(plan, seed=3, seconds=20, trace=False)
+    from asyncframework_tpu.solvers.base import SolverConfig
+
+    cfg = SolverConfig(**kw)
+    assert cfg.run_timeout_s == 20 and cfg.trace_sample is None
+    assert cfg.drain_batch == 1  # left at the program's default
+
+
+@pytest.mark.parametrize("kind,name", [("end_to_end", n) for n in E2E]
+                         + [("per_layer", n) for n in PER_LAYER])
+def test_metric_file_states_what_the_manifest_states(kind, name):
+    entry = [m for m in MANIFEST[kind] if m["name"] == name][0]
+    mod = manifest_mod.Manifest().metric_reader(name)
+    assert mod.NAME == name
+    assert mod.UNIT == entry["unit"]
+    assert mod.SOURCE == entry["source"]
+    if kind == "per_layer":
+        assert mod.LAYER == entry["layer"]
+        assert mod.MOVES == entry["moves"]
+    assert callable(mod.read)
+
+
+# ----------------------------------------------------------- the yardstick
+
+
+def test_snapshot_updates_follow_the_solvers_cadence():
+    # w=0, after updates 1, 11, 21 (printer_freq 10), then the final model
+    assert target.snapshot_updates(5, 10, 27) == [0, 1, 11, 21, 27]
+    # synchronous mode counts rounds of num_workers gradients
+    assert target.snapshot_updates(4, 2, 40, per_snapshot=8) == [0, 8, 24, 40]
+
+
+def test_updates_to_target_interpolates_log_linearly():
+    ks, fs = [0, 1, 11, 21], [1.0, 0.5, 0.01, 0.0001]
+    # 0.001 is half way between 0.01 and 0.0001 in the logarithm
+    assert target.updates_to_target(ks, fs, 0.001) == pytest.approx(16.0)
+    assert target.updates_to_target(ks, fs, 0.01) == pytest.approx(11.0)
+    assert target.updates_to_target(ks, fs, 1e-9) is None
+    assert target.time_to_target_s(16.0, 200, 4.0) == pytest.approx(0.32)
+
+
+def test_peaks_table_refuses_an_unknown_device_kind():
+    v5e = roofline.peaks("TPU v5 lite")
+    assert v5e["hbm_bytes_per_s"] == 819e9 and v5e["bf16_flops_per_s"] == 197e12
+    with pytest.raises(KeyError):
+        roofline.peaks("cpu")
+
+
+def test_step_bytes_count_each_sampled_row_once():
+    # mnist8m bf16 shard: 101,250 sampled rows of 1,568 bytes dominate
+    b = roofline.dense_step_bytes(1_012_500, 784, 2, 0.1)
+    assert b == pytest.approx(101_250 * 784 * 2 + 1_012_500 + 101_250 * 4 + 2 * 784 * 4)
+    # rcv1: 4,360 sampled rows of 80 slots, cols+vals 8 bytes a slot; w and g
+    # are touched at no more than d entries each
+    s = roofline.sparse_step_bytes(87_206, 80, 47_236, 0.05)
+    assert s == pytest.approx(4360.3 * 80 * 8 + 87_206 + 4360.3 * 4 + 2 * 47_236 * 4)
+    data = {"kind": "dense", "shard_rows": [10, 12], "d": 4, "itemsize": 4}
+    assert roofline.step_bytes(data, 0.5) == roofline.dense_step_bytes(12, 4, 4, 0.5)
+
+
+# ------------------------------------------------------------ the rehearsal
+
+TINY_CELLS = {
+    # name: (config, traffic, chips)
+    "tiny-dense-f32.steady": ("tiny-dense-f32", "steady", 1),
+    "tiny-dense-bf16.steady": ("tiny-dense-bf16", "steady", 1),
+    "tiny-sparse.steady": ("tiny-sparse", "steady", 1),
+    "tiny-asaga.steady": ("tiny-asaga", "steady", 1),
+    "tiny-dense-f32.tiny-sync": ("tiny-dense-f32", "tiny-sync", 1),
+    "tiny-dense-f32.four": ("tiny-dense-f32", "steady", 4),
+}
+
+
+@pytest.fixture(scope="module")
+def tiny_manifest(tmp_path_factory):
+    """The real manifest's metrics over tiny configurations: new cells are
+    new entries and new files (``tests/benchmark/configs``, ``traffic``)
+    and no edit to the harness."""
+    doc = dict(MANIFEST)
+    configs = sorted({c for c, _t, _n in TINY_CELLS.values()})
+    doc["configs"] = [
+        {"name": c, "source": "rehearsal", "reduced": [], "why": "rehearsal",
+         "file": f"tests/benchmark/configs/{c}.json"} for c in configs
+    ]
+    doc["workloads"] = [
+        {"name": n, "config": c, "traffic": t, "chips": k, "why": "rehearsal"}
+        for n, (c, t, k) in TINY_CELLS.items()
+    ]
+    path = tmp_path_factory.mktemp("bench") / "BENCHMARK.json"
+    path.write_text(json.dumps(doc))
+    return str(path)
+
+
+@pytest.fixture()
+def on_cpu(monkeypatch, tmp_path):
+    """Relax the platform check for the rehearsal and keep the profiler's
+    files out of the tree."""
+    import jax
+
+    monkeypatch.setattr(run, "REQUIRED_PLATFORM", "cpu")
+    monkeypatch.setattr(run, "OUT_DIR", str(tmp_path / "out"))
+    monkeypatch.setattr(run, "TRACE_RUN_S", 1.0)
+    monkeypatch.setattr(run, "TRACE_EDGE_S", 0.2)
+
+    def use(chips):
+        monkeypatch.setattr(run, "_devices", lambda: jax.devices()[:chips])
+
+    return use
+
+
+def _run(capsys, manifest, cell, trace=0, seconds=1.5, seed=5):
+    rc = run.main(
+        ["--workload", cell, "--seed", str(seed), "--seconds", str(seconds),
+         "--trace", str(trace)], manifest_path=manifest,
+    )
+    lines = [ln for ln in capsys.readouterr().out.splitlines() if ln.strip()]
+    return rc, lines
+
+
+@pytest.mark.parametrize("cell", sorted(TINY_CELLS))
+def test_rehearsal_cell_prints_the_contracts_last_line(
+        cell, tiny_manifest, on_cpu, capsys):
+    chips = TINY_CELLS[cell][2]
+    on_cpu(chips)
+    rc, lines = _run(capsys, tiny_manifest, cell)
+    assert rc == 0
+    last = json.loads(lines[-1])
+    assert set(last) == RESULT_KEYS
+    assert set(last["device"]) == DEVICE_KEYS
+    assert last["device"]["count"] == chips
+    assert set(last["metrics"]) == set(E2E)
+    for name, m in last["metrics"].items():
+        assert set(m) == {"value", "unit"} and m["value"] > 0, name
+    assert last["correct"] is True, lines[-2]
+    assert last["failed"] == 0 and last["attempted"] > 0
+    # everything before the last line is an info line
+    assert all(set(json.loads(ln)) == {"info"} for ln in lines[:-1])
+    record = [json.loads(ln)["info"] for ln in lines[:-1]
+              if "checks" in json.loads(ln)["info"]][0]
+    assert record["checks"]["no_compile_in_window"]
+    if chips == 4:
+        per_dev = record["memory_peak_by_chip"]
+        assert len(per_dev) == 4
+
+
+@pytest.mark.parametrize("cell", ["tiny-dense-f32.steady", "tiny-dense-f32.four"])
+def test_traced_rehearsal_reports_per_layer_metrics(
+        cell, tiny_manifest, on_cpu, capsys):
+    on_cpu(TINY_CELLS[cell][2])
+    rc, lines = _run(capsys, tiny_manifest, cell, trace=1)
+    assert rc == 0
+    last = json.loads(lines[-1])
+    assert RESULT_KEYS <= set(last) <= RESULT_KEYS | {"breakdown"}
+    # on the CPU there is no device plane: readers of the device trace find
+    # nothing and are left out; the program's spans and counters are read
+    got = set(last["metrics"])
+    assert got <= set(PER_LAYER)
+    assert {"data_gen_s", "warmup_s", "task_p50_ms", "merge_queue_p50_ms",
+            "staleness_mean", "updates_to_target"} <= got
+    assert not got & {"step_device_ms", "step_roofline", "device_idle"}
+    assert "busy_s" not in last["device"]
+    # the profiler ran around a short run of its own, after the checked one
+    infos = [json.loads(ln)["info"] for ln in lines[:-1]]
+    prof = [i for i in infos if "profiled_run" in i][0]
+    assert prof["profiled_run"]["accepted"] > 0
+    assert prof["profiled_run"]["compiles"] == 0
+    lo, hi = prof["window"]
+    assert 0.2 <= lo < hi <= prof["profiled_run"]["elapsed_s"] + 1.0
+    record = [i for i in infos if "checks" in i][0]
+    assert all(record["checks"].values()), record["checks"]
+    assert last["correct"] is True
+
+
+def test_a_host_that_holds_every_thread_costs_the_run_no_worker(
+        tiny_manifest, on_cpu, capsys, monkeypatch):
+    """The program's heartbeat monitor declares an idle executor lost after
+    2 s of silence and is itself a Python thread, so a host that holds every
+    Python thread that long inside a run makes the run report workers lost
+    whenever the monitor wakes before the executors do: the driver's first
+    check of PR 22 met it on the chip.  Here the monitor's clock runs 3 s
+    ahead of the executors', which is that hold as the monitor sees it: at
+    the program's 2 s every idle executor is lost at the first scan.  The
+    cells run with the reference's 120 s (``plan.DEFAULTS``); a mix that
+    exercises failure detection brings its own value."""
+    import asyncframework_tpu.engine.heartbeat as hb
+
+    class Late(hb.SystemClock):
+        def now_ms(self):
+            return super().now_ms() + 3000.0
+
+    real_init = hb.HeartbeatMonitor.__init__
+
+    def init(self, *a, **kw):
+        real_init(self, *a, **kw)
+        self._clock = Late()
+
+    monkeypatch.setattr(hb.HeartbeatMonitor, "__init__", init)
+    on_cpu(1)
+    rc, lines = _run(capsys, tiny_manifest, "tiny-dense-f32.steady")
+    assert rc == 0
+    last = json.loads(lines[-1])
+    record = [json.loads(ln)["info"] for ln in lines[:-1]
+              if "checks" in json.loads(ln)["info"]][0]
+    assert "workers_lost" not in record["result"]["extras"]
+    assert last["correct"] is True and last["failed"] == 0
+
+    config = {"name": "c", "solver": "asgd", "num_workers": 8,
+              "batch_rate": 0.1, "bucket_ratio": 0.7, "gamma": 1.0,
+              "printer_freq": 10, "target_fraction": 0.001}
+    assert plan_mod.resolve(config, {"name": "m"})[
+        "heartbeat_timeout_ms"] == 120_000.0
+    tight = plan_mod.resolve(config, {"name": "m", "heartbeat_timeout_ms": 2000})
+    kw = plan_mod.solver_config_kwargs(tight, seed=1, seconds=5, trace=False)
+    assert kw["heartbeat_timeout_ms"] == 2000.0
+
+
+def test_traced_rehearsal_of_the_barrier_sizes_its_profiled_run(
+        tiny_manifest, on_cpu, capsys):
+    on_cpu(1)
+    rc, lines = _run(capsys, tiny_manifest, "tiny-dense-f32.tiny-sync", trace=1)
+    assert rc == 0
+    infos = [json.loads(ln)["info"] for ln in lines[:-1]]
+    prof = [i for i in infos if "profiled_run" in i][0]["profiled_run"]
+    main = [i for i in infos if "checks" in i][0]["result"]
+    # run_sync has no deadline: both round counts come from the warm-up's rate
+    assert 0 < prof["accepted"] < main["accepted"]
+    assert json.loads(lines[-1])["correct"] is True
+
+
+def test_wrong_platform_or_chip_count_exits_nonzero_with_no_result(
+        tiny_manifest, monkeypatch, capsys):
+    import jax
+
+    # the check as the driver meets it: a CPU where a TPU is asked for
+    monkeypatch.setattr(run, "_devices", lambda: jax.devices()[:1])
+    rc, lines = _run(capsys, tiny_manifest, "tiny-dense-f32.steady")
+    assert rc != 0 and lines == []
+    # the right platform with too few devices
+    monkeypatch.setattr(run, "REQUIRED_PLATFORM", "cpu")
+    rc, lines = _run(capsys, tiny_manifest, "tiny-dense-f32.four")
+    assert rc != 0 and lines == []
+
+
+def test_benchmark_alone_without_the_program_exits_nonzero(tmp_path):
+    """In a directory that holds only BENCHMARK.json and the files under
+    ``paths`` there is no system under test: no result, non-zero exit."""
+    import shutil
+
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp_path)
+    for p in MANIFEST["paths"]:
+        shutil.copytree(
+            os.path.join(ROOT, p), tmp_path / p,
+            ignore=shutil.ignore_patterns("__pycache__", "fixtures"),
+        )
+    cell = MANIFEST["workloads"][0]["name"]
+    env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
+    p = subprocess.run(
+        [sys.executable, "benchmark/run.py", "--workload", cell, "--seed", "1",
+         "--seconds", "1", "--trace", "0"],
+        cwd=tmp_path, env=env, capture_output=True, text=True, timeout=120,
+    )
+    assert p.returncode != 0
+    assert p.stdout.strip() == ""
