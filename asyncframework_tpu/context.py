@@ -41,6 +41,9 @@ class PartialResult(Generic[T]):
     staleness: int
     batch_size: int
     worker_id: int
+    #: the update's trace handle (``metrics.trace.UpdateTrace``) when it was
+    #: sampled at submit; None otherwise.  Not part of the result's value.
+    trace: Optional[object] = field(default=None, compare=False, repr=False)
 
     # Reference getter names, kept for drop-in familiarity.
     def get_task_result(self) -> T:
@@ -211,6 +214,7 @@ class AsyncContext(Generic[T]):
         submit_clock: int,
         elapsed_ms: float,
         batch_size: int,
+        trace: Optional[object] = None,
     ) -> PartialResult[T]:
         """Record a finished task: push result, update STAT, bump the clock.
 
@@ -236,7 +240,7 @@ class AsyncContext(Generic[T]):
             ) / (ws.num_tasks + 1)
             ws.available = True
             ws.num_tasks += 1
-            res = PartialResult(data, staleness, batch_size, worker_id)
+            res = PartialResult(data, staleness, batch_size, worker_id, trace)
             self._clock += 1
         self._results.put(res)
         return res

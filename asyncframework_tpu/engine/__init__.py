@@ -1,5 +1,5 @@
 from asyncframework_tpu.engine.job import Job, JobWaiter, TaskSpec
-from asyncframework_tpu.engine.executor import DeviceExecutor, ExecutorPool, TaskMetrics
+from asyncframework_tpu.engine.executor import DeviceExecutor, ExecutorPool
 from asyncframework_tpu.engine.scheduler import JobScheduler
 from asyncframework_tpu.engine.barrier import partial_barrier
 from asyncframework_tpu.engine.straggler import DelayModel, build_cloud_stragglers
@@ -31,7 +31,6 @@ __all__ = [
     "TaskSpec",
     "DeviceExecutor",
     "ExecutorPool",
-    "TaskMetrics",
     "JobScheduler",
     "partial_barrier",
     "DelayModel",

@@ -2,8 +2,10 @@
 
 Parity: ``executor/Executor.scala:53`` (``TaskRunner.run`` 290: run task,
 report status) + ``executor/CoarseGrainedExecutorBackend.scala:40``
-(``LaunchTask`` inbox) + per-task ``TaskMetrics``
-(``executor/TaskMetrics.scala:45``) + executor heartbeats (``Executor.scala:814``).
+(``LaunchTask`` inbox) + executor heartbeats (``Executor.scala:814``).  The
+reference's per-task metrics are the ``task.*`` spans of a sampled update
+here (``metrics/trace.py``), recorded by the task closure: the executor
+knows nothing of tracing.
 
 TPU mapping: an executor is a daemon thread bound to one *logical worker*.
 Each worker owns a jax device slot -- on an 8-device mesh that is one chip per
@@ -24,27 +26,10 @@ from __future__ import annotations
 
 import queue
 import threading
-from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional
 
 from asyncframework_tpu.engine.job import TaskSpec
 from asyncframework_tpu.utils.clock import Clock, SystemClock
-
-
-@dataclass
-class TaskMetrics:
-    """Per-task observability record (TaskMetrics parity, trimmed to what a
-    host-dispatched XLA task actually has)."""
-
-    job_id: int
-    worker_id: int
-    attempt: int
-    launch_ms: float
-    finish_ms: float = 0.0
-    run_ms: float = 0.0
-    injected_delay_ms: float = 0.0
-    succeeded: bool = False
-    error: Optional[str] = None
 
 
 class DeviceExecutor:
@@ -73,7 +58,6 @@ class DeviceExecutor:
         self.current_task: Optional[TaskSpec] = None
         self.busy_since_ms = 0.0
         self.last_heartbeat_ms = self._clock.now_ms()
-        self.metrics: List[TaskMetrics] = []
         self._thread = threading.Thread(
             target=self._run, name=f"executor-{worker_id}", daemon=True
         )
@@ -148,23 +132,12 @@ class DeviceExecutor:
             self.busy = True
             self.current_task = task
             self.busy_since_ms = self.last_heartbeat_ms
-            m = TaskMetrics(
-                job_id=task.job_id,
-                worker_id=self.worker_id,
-                attempt=task.attempt,
-                launch_ms=self._clock.now_ms(),
-            )
             try:
                 result = task.fn()
-                m.succeeded = True
                 exc: Optional[BaseException] = None
             except BaseException as e:  # noqa: BLE001 - report, don't die
                 result = None
                 exc = e
-                m.error = repr(e)
-            m.finish_ms = self._clock.now_ms()
-            m.run_ms = m.finish_ms - m.launch_ms
-            self.metrics.append(m)
             self.busy = False
             self.current_task = None
             self._inbox.task_done()
@@ -208,8 +181,6 @@ class ExecutorPool:
         # allocation manager (dynamic allocation); distinct from one-shot
         # speculation spares
         self._siblings: Dict[int, List[DeviceExecutor]] = {}
-        # TaskMetrics of retired siblings: their tasks must stay accounted
-        self._retired_metrics: List[TaskMetrics] = []
 
     def get(self, worker_id: int) -> DeviceExecutor:
         with self._lock:
@@ -242,7 +213,6 @@ class ExecutorPool:
             for i, ex in enumerate(sibs):
                 if ex.idle():
                     del sibs[i]
-                    self._retired_metrics.extend(ex.metrics)
                     break
             else:
                 return False
@@ -277,15 +247,14 @@ class ExecutorPool:
 
     def drop_sibling(self, worker_id: int, ex: DeviceExecutor):
         """Remove a dead/hung sibling (failure path -- contrast the
-        scale-down path ``remove_idle_sibling``); its metrics are retained
-        and it is killed, not drained.  Returns ``(queued, running)``: the
+        scale-down path ``remove_idle_sibling``); it is killed, not
+        drained.  Returns ``(queued, running)``: the
         never-started tasks recovered from its inbox (relaunchable at the
         SAME attempt) and the task it was running when it died, if any
         (failed once -- relaunch bumps the attempt)."""
         with self._lock:
             sibs = self._siblings.get(worker_id, [])
             self._siblings[worker_id] = [s for s in sibs if s is not ex]
-            self._retired_metrics.extend(ex.metrics)
         running = ex.current_task
         ex.kill()
         queued = []
@@ -337,7 +306,6 @@ class ExecutorPool:
         """One-shot spares are shut down and dropped after their task."""
         with self._lock:
             self._spares = [s for s in self._spares if s is not ex]
-            self._retired_metrics.extend(ex.metrics)
         ex.shutdown()
 
     def replace(self, worker_id: int) -> DeviceExecutor:
@@ -347,7 +315,6 @@ class ExecutorPool:
                 raise RuntimeError("pool is shut down; cannot replace executor")
             old = self.executors.get(worker_id)
             if old is not None:
-                self._retired_metrics.extend(old.metrics)
                 if old.alive:
                     old.shutdown()
             ex = DeviceExecutor(
@@ -370,24 +337,9 @@ class ExecutorPool:
             for ex in self.executors.values():
                 ex.shutdown()
             for ex in self._spares:
-                self._retired_metrics.extend(ex.metrics)
                 ex.shutdown()
             self._spares = []
             for sibs in self._siblings.values():
                 for ex in sibs:
-                    self._retired_metrics.extend(ex.metrics)
                     ex.shutdown()
             self._siblings = {}
-
-    def all_metrics(self) -> List[TaskMetrics]:
-        with self._lock:
-            out: List[TaskMetrics] = []
-            for ex in self.executors.values():
-                out.extend(ex.metrics)
-            for sibs in self._siblings.values():
-                for ex in sibs:
-                    out.extend(ex.metrics)
-            for ex in self._spares:
-                out.extend(ex.metrics)
-            out.extend(self._retired_metrics)
-            return out

@@ -19,6 +19,7 @@ the reference warming its block/broadcast caches).
 from __future__ import annotations
 
 import threading
+import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from asyncframework_tpu.engine.blacklist import BlacklistTracker
@@ -60,6 +61,10 @@ class JobScheduler:
         self._launch_ms: Dict[Tuple[int, int], float] = {}
         self._finished_ms: Dict[int, List[float]] = {}
         self._spec_wins = 0  # speculative copies that beat their primary
+        self.task_retries = 0  # raised tasks launched again (TrainResult)
+        #: time run_job spent blocked on a waiter (SYNC mode, first job):
+        #: the submitter counts it as waiting, not as work
+        self.blocked_ns = 0
         self.blacklist = blacklist
         self.pool = pool or ExecutorPool(
             num_workers, self._status_update, devices=devices, clock=self._clock
@@ -100,7 +105,11 @@ class JobScheduler:
         block = self._mode == SYNC or self._first_iter
         self._first_iter = False
         if block:
-            job.waiter.await_result(timeout=timeout)
+            t0 = time.perf_counter_ns()
+            try:
+                job.waiter.await_result(timeout=timeout)
+            finally:
+                self.blocked_ns += time.perf_counter_ns() - t0
             with self._lock:
                 self._active_jobs.pop(job.job_id, None)
         return job.waiter
@@ -200,6 +209,8 @@ class JobScheduler:
             fn=task.fn,
             attempt=task.attempt + 1,
         )
+        with self._lock:
+            self.task_retries += 1
         self._launch(task.worker_id, retry)
 
     # ------------------------------------------------------------ speculation

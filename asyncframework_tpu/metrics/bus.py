@@ -113,6 +113,7 @@ class TraceSpan(Event):
     staleness_ms: Optional[float] = None
     accepted: Optional[bool] = None
     bytes: Optional[int] = None  # wire bytes of the RPC the span covers
+    batch: Optional[int] = None  # updates the timed piece of work served
 
 
 EVENT_TYPES: Dict[str, Type[Event]] = {

@@ -11,7 +11,8 @@ black hole.  This module makes one update's life observable end to end:
   framing choke point (``net/frame.send_msg`` consults the thread-local
   context installed here) -- so PULL/PUSH/PULL_SAGA/PUSH_SAGA, topic, and
   master ops are all covered without per-callsite plumbing;
-- **lifecycle spans** decompose an update's wall-clock:
+- **lifecycle spans** decompose an update's wall-clock.  Over the DCN
+  plane (``parallel/ps_dcn.py``):
 
   ========== =======================================================
   stage      measured where
@@ -24,6 +25,47 @@ black hole.  This module makes one update's life observable end to end:
   merge.queue PS: PUSH decode + wait for the model lock
   merge.apply PS: time under the lock (tau filter + apply dispatch)
   ========== =======================================================
+
+  In the in-process engine (``ASGD.run`` / ``ASAGA.run``: a submitter
+  thread, one executor thread per worker, an updater thread) there is no
+  wire, and every stage is recorded where it happens through ONE call,
+  :func:`span`.  *Work* stages are what a host thread was doing; they
+  also open a ``jax.profiler.TraceAnnotation("async.<stage>")``, so a
+  device trace taken around the run shows them in its ``/host:CPU`` plane
+  on the device's own clock and an idle gap of the chip can be put down
+  to a phase of the program.  *Wait* stages are a thread blocked on a
+  queue or on the device: recorded as spans, never annotated (32
+  executors blocked in ``block_until_ready`` would own every gap).
+
+  ================ ==== ========= ==================================== =======
+  stage            kind thread    from -> to                           parent
+  ================ ==== ========= ==================================== =======
+  submit           work submitter cohort chosen -> ``run_job`` returned -
+  compute          -    -         submit -> drained by the updater     submit
+  task.inbox       wait sub -> ex ``compute``'s start -> ``fn()`` in   compute
+                                  (the rest of the submit, the inbox)
+  task.dispatch    work executor  ``fn()`` entered -> step returned    compute
+  task.model_copy  work executor  ``device_put`` of w/key to the       (annotation
+                                  worker's chip, inside task.dispatch  only)
+  task.device_wait wait executor  ``block_until_ready`` in -> out      compute
+  result.queue     wait ex -> upd ``merge_result`` put -> drained      compute
+  merge.queue      work updater   drained -> apply starts (state lock, compute
+                                  tau filter, cross-chip ``g`` copy)
+  merge.apply      work updater   the stack/apply dispatch of a drain; compute
+                                  ``batch`` = results accepted in it
+  snapshot,        work updater   annotation only                      -
+  checkpoint
+  ================ ==== ========= ==================================== =======
+
+  ``task.inbox + task.dispatch + task.device_wait + result.queue`` cover
+  ``compute`` from its first instant; what is left (an injected straggler
+  delay, the scheduler's status update, the handler, the key lock, GIL
+  hand-offs) is ``compute``'s self time.  Only the first copy of a task
+  to run records the task stages: a retry or a speculative copy finds
+  ``task.inbox`` closed and records nothing.  ``task.dispatch`` is work by
+  kind, but the step's enqueue blocks inside the call while the device's
+  queue is full (32 workers on one chip): its tail is then a wait on the
+  device, which PJRT's own ``PjitFunction(step)`` event shows the same.
 
 - workers record completed spans into a bounded **lock-light ring buffer**
   (sampled at ``async.trace.sample``, default 1/64, counter-based so the
@@ -70,8 +112,33 @@ PUSH_RTT = "push.rtt"
 MERGE_QUEUE = "merge.queue"
 MERGE_APPLY = "merge.apply"
 
-STAGES = (PULL_WAIT, PULL_RTT, PIPELINE, COMPUTE, PUSH_WAIT, PUSH_RTT,
+# in-process engine stages (module docstring: the second table)
+SUBMIT = "submit"
+TASK_INBOX = "task.inbox"
+TASK_DISPATCH = "task.dispatch"
+TASK_MODEL_COPY = "task.model_copy"
+TASK_DEVICE_WAIT = "task.device_wait"
+RESULT_QUEUE = "result.queue"
+SNAPSHOT = "snapshot"
+CHECKPOINT = "checkpoint"
+
+STAGES = (PULL_WAIT, PULL_RTT, PIPELINE, SUBMIT, COMPUTE, TASK_INBOX,
+          TASK_DISPATCH, TASK_DEVICE_WAIT, RESULT_QUEUE, PUSH_WAIT, PUSH_RTT,
           MERGE_QUEUE, MERGE_APPLY)
+#: engine stages in which a host thread WORKS: :func:`span` annotates
+#: these on the profiler's clock.  Everything else is a wait (or spans
+#: threads, like ``compute``) and is never annotated.
+WORK_STAGES = frozenset((SUBMIT, TASK_DISPATCH, TASK_MODEL_COPY, MERGE_QUEUE,
+                         MERGE_APPLY, SNAPSHOT, CHECKPOINT))
+#: the four children that must cover ``compute``
+COMPUTE_CHILDREN = (TASK_INBOX, TASK_DISPATCH, TASK_DEVICE_WAIT,
+                    RESULT_QUEUE)
+#: a span's parent, by stage (engine spans; the DCN plane's have none)
+PARENT = {COMPUTE: SUBMIT, MERGE_QUEUE: COMPUTE, MERGE_APPLY: COMPUTE,
+          **{st: COMPUTE for st in COMPUTE_CHILDREN}}
+#: what a work stage is called in a profiler trace
+ANNOTATION_PREFIX = "async."
+_ANNOTATION_NAME = {st: ANNOTATION_PREFIX + st for st in WORK_STAGES}
 #: stages recorded client-side (worker process) vs server-side (PS)
 CLIENT_STAGES = (PULL_RTT, PIPELINE, COMPUTE, PUSH_WAIT, PUSH_RTT)
 SERVER_STAGES = (PULL_WAIT, MERGE_QUEUE, MERGE_APPLY)
@@ -120,12 +187,17 @@ class Span:
     #: bytes both directions, counted at the net/frame.py choke point) --
     #: latency AND volume decompose per stage
     bytes: Optional[int] = None
+    #: how many updates the piece of work this span times served: the
+    #: cohort of a ``submit``, the accepted results of the drain behind a
+    #: ``merge.apply`` (its duration is the whole drain's, undivided)
+    batch: Optional[int] = None
 
     # wire format: short keys, Nones omitted -- spans ride PUSH headers
     _WIRE = (("s", "stage"), ("t", "trace_id"), ("i", "span_id"),
              ("p", "parent_id"), ("w", "worker_id"), ("v", "model_version"),
              ("b", "start_ms"), ("d", "dur_ms"), ("st", "staleness"),
-             ("sm", "staleness_ms"), ("ac", "accepted"), ("by", "bytes"))
+             ("sm", "staleness_ms"), ("ac", "accepted"), ("by", "bytes"),
+             ("n", "batch"))
 
     def to_wire(self) -> dict:
         out = {}
@@ -206,15 +278,40 @@ def wire_header() -> Optional[list]:
 
 # ------------------------------------------------------------- worker side
 class UpdateTrace:
-    """One sampled update's in-progress trace on the worker: collects its
-    client-side spans and hands the ambient context to the RPCs."""
+    """One sampled update's in-progress trace: collects its spans and, on
+    the DCN worker, hands the ambient context to the RPCs.  In the engine
+    it is the handle that rides the task closure, the executor, the handler
+    and ``PartialResult`` from the submitter to the updater; every span of
+    the update is recorded against it by :func:`span`."""
 
-    __slots__ = ("ctx", "_sink", "spans")
+    __slots__ = ("ctx", "_sink", "spans", "born_ms", "ids", "_open")
 
     def __init__(self, ctx: TraceContext, sink: Callable[[Span], None]):
         self.ctx = ctx
         self._sink = sink
         self.spans: List[Span] = []
+        #: when the sampling decision fell (the engine: at submit)
+        self.born_ms = now_ms()
+        #: stage -> span id of the newest span begun for it (what a child
+        #: stage names as its parent, see PARENT)
+        self.ids: Dict[str, str] = {}
+        self._open: Dict[str, "_Span"] = {}
+
+    # ---- a stage that begins on one thread and ends on another (a wait
+    # in a queue, ``compute``): its open span rides this handle
+    def begin(self, stage: str) -> None:
+        self._open[stage] = span(stage, self).begin()
+
+    def end(self, stage: str) -> bool:
+        """Close what :meth:`begin` opened.  False when there was nothing
+        open: the stage was never begun or another thread closed it first
+        (a retried or speculative copy of a task finds its ``task.inbox``
+        closed by the first copy to run, and records nothing)."""
+        sp = self._open.pop(stage, None)
+        if sp is None:
+            return False
+        sp.end()
+        return True
 
     def set_model_version(self, mv: int) -> None:
         """Learned from the pull reply; back-fills spans recorded before
@@ -225,10 +322,12 @@ class UpdateTrace:
                 sp.model_version = int(mv)
 
     def add(self, stage: str, start_ms: float, end_ms: float,
+            span_id: Optional[str] = None, parent_id: Optional[str] = None,
             **attrs) -> Span:
         sp = Span(
-            stage=stage, trace_id=self.ctx.trace_id, span_id=_new_id(8),
-            parent_id=None, worker_id=self.ctx.worker_id,
+            stage=stage, trace_id=self.ctx.trace_id,
+            span_id=span_id or _new_id(8),
+            parent_id=parent_id, worker_id=self.ctx.worker_id,
             model_version=self.ctx.model_version, start_ms=start_ms,
             dur_ms=max(0.0, end_ms - start_ms), **attrs,
         )
@@ -257,6 +356,131 @@ class UpdateTrace:
         self.spans.append(sp)
         self._sink(sp)
         return sp
+
+
+_trace_me = None
+
+
+def _profiling() -> bool:
+    """Whether a ``jax.profiler`` session is open.  JAX is imported on the
+    first call (this module is also imported by launchers that never load
+    it); from then on the name is bound to the profiler's own static
+    check, 20 ns a call."""
+    global _trace_me, _profiling
+    from jax.profiler import TraceAnnotation
+
+    _trace_me = TraceAnnotation
+    _profiling = TraceAnnotation.is_enabled
+    return _profiling()
+
+
+class _NoSpan:
+    """What :func:`span` hands out where there is nothing to do (a wait
+    stage of an update that is not sampled): one shared object, no
+    allocation, no clock."""
+
+    __slots__ = ()
+    start_ms = 0.0
+
+    def begin(self) -> "_NoSpan":
+        return self
+
+    def end(self) -> None:
+        pass
+
+    __enter__ = begin
+
+    def __exit__(self, *exc) -> None:
+        pass
+
+
+_NO_SPAN = _NoSpan()
+
+
+def span(stage: str, ut=None, **attrs):
+    """THE span call of the in-process engine: one stage of the update
+    loop, timed where it happens.
+
+        with span(TASK_DISPATCH, ut):
+            g, key = step(X, y, w, key)
+
+    - a *work* stage (``WORK_STAGES``) opens a profiler annotation
+      ``async.<stage>`` whenever a ``jax.profiler`` session is open, so
+      the stage shows on the device trace's clock; a wait stage never
+      does.  With no session open (one static call to find out, 20 ns)
+      there is no annotation;
+    - with ``ut`` it also records a real :class:`Span` per update: start
+      and end read here, ``parent_id`` from ``PARENT``, one ``trace_id``
+      per update.  ``ut`` is the update's :class:`UpdateTrace`, or several
+      where one piece of work serves several updates (a cohort's submit, a
+      drain's apply): an iterable of them, or a mapping from each to the
+      attributes that are its own (its staleness in the drain).  With
+      ``ut`` None or empty (tracing off, nothing sampled) it records
+      nothing and reads no clock.
+
+    With neither to do, which is every call of an untraced run outside a
+    profiler session, it hands out one shared no-op and allocates nothing.
+
+    Integer ``attrs`` go on the annotation (they show as the event's
+    arguments in XProf/Perfetto) and on every span.  ``with`` and
+    ``begin()`` / ``end()`` are the same pair; a stage that ends on
+    another thread than it began on rides the handle
+    (:meth:`UpdateTrace.begin`)."""
+    name = _ANNOTATION_NAME.get(stage)
+    if name is not None and not _profiling():
+        name = None  # no profiler session is open: nothing to annotate
+    if not ut:
+        if name is None:
+            return _NO_SPAN
+        return _Span(stage, name, None, attrs)
+    if isinstance(ut, UpdateTrace):
+        ut = {ut: None}
+    elif not isinstance(ut, dict):
+        ut = dict.fromkeys(ut)
+    return _Span(stage, name, ut, attrs)
+
+
+class _Span:
+    __slots__ = ("stage", "start_ms", "_name", "_uts", "_attrs", "_ann")
+
+    def __init__(self, stage: str, name: Optional[str],
+                 uts: Optional[dict], attrs: dict):
+        self.stage = stage
+        self._name = name
+        self._uts = uts
+        self._attrs = attrs
+        self._ann = None
+        self.start_ms = 0.0
+
+    def begin(self) -> "_Span":
+        if self._name is not None:
+            self._ann = _trace_me(self._name, **self._attrs)
+            self._ann.__enter__()
+        if self._uts:
+            for ut in self._uts:
+                ut.ids[self.stage] = _new_id(8)
+            self.start_ms = now_ms()
+        return self
+
+    def end(self) -> None:
+        if self._uts:
+            end_ms = now_ms()
+            parent = PARENT.get(self.stage)
+            for ut, own in self._uts.items():
+                ut.add(
+                    self.stage, self.start_ms, end_ms,
+                    span_id=ut.ids[self.stage],
+                    parent_id=ut.ids.get(parent) if parent else None,
+                    **self._attrs, **(own or {}),
+                )
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+
+    __enter__ = begin
+
+    def __exit__(self, *exc) -> None:
+        self.end()
 
 
 class TraceRecorder:
@@ -466,7 +690,7 @@ def span_event(span: Span, time_ms: float) -> "object":
         worker_id=span.worker_id, model_version=span.model_version,
         start_ms=span.start_ms, dur_ms=span.dur_ms,
         staleness=span.staleness, staleness_ms=span.staleness_ms,
-        accepted=span.accepted, bytes=span.bytes,
+        accepted=span.accepted, bytes=span.bytes, batch=span.batch,
     )
 
 
@@ -606,8 +830,11 @@ def chrome_trace(spans) -> dict:
     stages from the PS-side stages of its updates."""
     events = []
     for sp in spans:
-        client = sp.stage in CLIENT_STAGES
+        client = sp.stage in CLIENT_STAGES or sp.stage in COMPUTE_CHILDREN
         args = {"trace_id": sp.trace_id, "model_version": sp.model_version}
+        if sp.parent_id:
+            args["parent_id"] = sp.parent_id
+            args["span_id"] = sp.span_id
         if sp.staleness is not None:
             args["staleness"] = sp.staleness
         if sp.staleness_ms is not None:

@@ -63,8 +63,10 @@ def least_squares_grad_sum(
     ``mask`` is {0,1} (or weights) of shape ``(n,)``; equivalent to the
     reference's sample-then-map-then-reduce with vector-add comOp.
     """
-    r = mm_f32(X, w) - y
-    return mm_f32(X.T, mask * r)
+    with jax.named_scope("residual"):
+        r = mm_f32(X, w) - y
+    with jax.named_scope("grad"):
+        return mm_f32(X.T, mask * r)
 
 
 @jax.jit
@@ -88,9 +90,11 @@ def logistic_grad_sum(
     Parity: ``LogisticGradient`` (binary case) -- labels in {0,1};
     ``grad_i = (sigmoid(x_i.w) - y_i) x_i``.
     """
-    margin = mm_f32(X, w)
-    p = jax.nn.sigmoid(margin)
-    return mm_f32(X.T, mask * (p - y))
+    with jax.named_scope("residual"):
+        margin = mm_f32(X, w)
+        p = jax.nn.sigmoid(margin)
+    with jax.named_scope("grad"):
+        return mm_f32(X.T, mask * (p - y))
 
 
 @jax.jit
@@ -123,8 +127,10 @@ def saga_shard_step(
     (:func:`saga_commit_history`) issued by the updater only for *accepted*
     (non-stale) results -- the reference's driver-side ScalarMap merge.
     """
-    diff = mm_f32(X, w) - y
-    g = mm_f32(X.T, mask * (diff - alpha))
+    with jax.named_scope("residual"):
+        diff = mm_f32(X, w) - y
+    with jax.named_scope("grad"):
+        g = mm_f32(X.T, mask * (diff - alpha))
     return g, diff
 
 
@@ -153,12 +159,13 @@ def make_sparse_grad_sum(d: int):
 
     @jax.jit
     def grad_sum(cols, vals, coeff):
-        contrib = (vals * coeff[:, None]).ravel()
-        flat = cols.ravel()
-        order = jnp.argsort(flat)
-        return jnp.zeros(d, vals.dtype).at[flat[order]].add(
-            contrib[order], indices_are_sorted=True, mode="drop"
-        )
+        with jax.named_scope("grad"):
+            contrib = (vals * coeff[:, None]).ravel()
+            flat = cols.ravel()
+            order = jnp.argsort(flat)
+            return jnp.zeros(d, vals.dtype).at[flat[order]].add(
+                contrib[order], indices_are_sorted=True, mode="drop"
+            )
 
     return grad_sum
 

@@ -22,6 +22,13 @@ Parity notes per builder:
 - ``make_saga_worker_step`` / ``make_saga_apply`` / ``saga_commit_history``:
   the ASAGA decomposition (``SparkASAGAThread.scala:199-213,369-380``) with
   the per-sample scalar history table resident in HBM, sharded by worker.
+- Every worker step and apply names its phases for the device trace with
+  ``jax.named_scope`` (``sample``, ``compact``, ``gather``, ``residual``,
+  ``grad``; ``apply``): an HLO op's ``op_name`` then says which phase it
+  belongs to when a trace is opened in XProf or Perfetto.  Metadata only:
+  the compiled program and its compile-cache key are unchanged, and the
+  jitted functions keep their Python names (the benchmark matches
+  ``jit_step``).
 - ``make_trajectory_loss_eval``: the drivers' final one-pass objective
   evaluation over all snapshots (``SparkASGDThread.scala:386-401``) -- all
   snapshots stacked into one (S, d) matrix so a shard's whole trajectory
@@ -106,10 +113,11 @@ def make_asgd_worker_step(batch_rate: float, loss: str = "least_squares"):
         # near-full gather copy would
         @jax.jit
         def step(X, y, w, key):
-            key, sub = jax.random.split(key)
-            mask = jax.random.bernoulli(
-                sub, batch_rate, (X.shape[0],)
-            ).astype(jnp.float32)
+            with jax.named_scope("sample"):
+                key, sub = jax.random.split(key)
+                mask = jax.random.bernoulli(
+                    sub, batch_rate, (X.shape[0],)
+                ).astype(jnp.float32)
             return grad_sum(X, y, w, mask), key
 
         return step
@@ -118,12 +126,15 @@ def make_asgd_worker_step(batch_rate: float, loss: str = "least_squares"):
     def step(X, y, w, key):
         n_rows = X.shape[0]  # static at trace time
         cap = sparse_step_capacity(batch_rate, n_rows)
-        key, sub = jax.random.split(key)
-        mask = jax.random.bernoulli(sub, batch_rate, (n_rows,))
-        (idx,) = jnp.nonzero(mask, size=cap, fill_value=0)
-        valid = (jnp.arange(cap) < jnp.sum(mask)).astype(jnp.float32)
-        Xs = X[idx]
-        return grad_sum(Xs, y[idx], w, valid), key
+        with jax.named_scope("sample"):
+            key, sub = jax.random.split(key)
+            mask = jax.random.bernoulli(sub, batch_rate, (n_rows,))
+        with jax.named_scope("compact"):
+            (idx,) = jnp.nonzero(mask, size=cap, fill_value=0)
+            valid = (jnp.arange(cap) < jnp.sum(mask)).astype(jnp.float32)
+        with jax.named_scope("gather"):
+            Xs, ys = X[idx], y[idx]
+        return grad_sum(Xs, ys, w, valid), key
 
     return _prof.wrap_dispatch(step, "kernel.dispatch", "asgd_worker_step")
 
@@ -141,8 +152,9 @@ def make_asgd_apply(gamma: float, batch_rate: float, n: int, num_workers: int):
 
     @functools.partial(jax.jit, donate_argnums=(1, 2))
     def apply(w, g, k):
-        lr = gamma / jnp.sqrt(k / num_workers + 1.0)
-        return w - (lr / par_recs) * g, k + 1.0
+        with jax.named_scope("apply"):
+            lr = gamma / jnp.sqrt(k / num_workers + 1.0)
+            return w - (lr / par_recs) * g, k + 1.0
 
     return _prof.wrap_dispatch(apply, "kernel.dispatch", "asgd_apply")
 
@@ -156,8 +168,9 @@ def make_sync_apply(gamma: float, batch_rate: float, n: int):
 
     @functools.partial(jax.jit, donate_argnums=(1, 2))
     def apply(w, acc_g, k):
-        lr = gamma / jnp.sqrt(k + 1.0)
-        return w - (lr / (batch_rate * n)) * acc_g, k + 1.0
+        with jax.named_scope("apply"):
+            lr = gamma / jnp.sqrt(k + 1.0)
+            return w - (lr / (batch_rate * n)) * acc_g, k + 1.0
 
     return _prof.wrap_dispatch(apply, "kernel.dispatch", "sync_apply")
 
@@ -171,12 +184,15 @@ def make_saga_worker_step(batch_rate: float):
 
     @jax.jit
     def step(X, y, w, alpha, key):
-        key, sub = jax.random.split(key)
-        mask = jax.random.bernoulli(sub, batch_rate, (X.shape[0],)).astype(
-            jnp.float32
-        )
-        diff = least_squares_residual(X, y, w)
-        g = mm_f32(X.T, mask * (diff - alpha))
+        with jax.named_scope("sample"):
+            key, sub = jax.random.split(key)
+            mask = jax.random.bernoulli(
+                sub, batch_rate, (X.shape[0],)
+            ).astype(jnp.float32)
+        with jax.named_scope("residual"):
+            diff = least_squares_residual(X, y, w)
+        with jax.named_scope("grad"):
+            g = mm_f32(X.T, mask * (diff - alpha))
         return g, diff, mask, key
 
     return _prof.wrap_dispatch(step, "kernel.dispatch", "saga_worker_step")
@@ -208,9 +224,10 @@ def make_saga_apply(
 
     @functools.partial(jax.jit, donate_argnums=donate)
     def apply(w, alpha_bar, g, delta):
-        w2 = w - (gamma / par_recs) * g - gamma * alpha_bar
-        ab2 = alpha_bar + delta / n
-        return w2, ab2
+        with jax.named_scope("apply"):
+            w2 = w - (gamma / par_recs) * g - gamma * alpha_bar
+            ab2 = alpha_bar + delta / n
+            return w2, ab2
 
     return _prof.wrap_dispatch(apply, "kernel.dispatch", "saga_apply")
 
@@ -258,11 +275,13 @@ def make_asgd_apply_batch(
     # would just emit unusable-buffer warnings
     @functools.partial(jax.jit, donate_argnums=(3,))
     def apply_batch(w, G, mask, k):
-        accepted_before = jnp.cumsum(mask) - mask  # per-slot accepted count
-        kk = k + accepted_before
-        lr = gamma / jnp.sqrt(kk / num_workers + 1.0)
-        coeff = (lr / par_recs) * mask
-        return w - coeff @ G, k + jnp.sum(mask)
+        with jax.named_scope("apply"):
+            # per-slot accepted count
+            accepted_before = jnp.cumsum(mask) - mask
+            kk = k + accepted_before
+            lr = gamma / jnp.sqrt(kk / num_workers + 1.0)
+            coeff = (lr / par_recs) * mask
+            return w - coeff @ G, k + jnp.sum(mask)
 
     del m  # shape is carried by G itself; kept in the signature for intent
     return _prof.wrap_dispatch(apply_batch, "kernel.dispatch", "asgd_apply_batch")
@@ -320,7 +339,8 @@ def make_asgd_apply_merge(
             keep = a > 0
             return (jnp.where(keep, w2, w), jnp.where(keep, k + 1.0, k)), None
 
-        (w, k), _ = jax.lax.scan(body, (w, k), (G, mask))
+        with jax.named_scope("apply"):
+            (w, k), _ = jax.lax.scan(body, (w, k), (G, mask))
         return w, k
 
     return _prof.wrap_dispatch(apply_merge, "kernel.dispatch", "asgd_apply_merge")
@@ -344,8 +364,9 @@ def make_asgd_apply_damped(gamma: float, batch_rate: float, n: int,
 
     @functools.partial(jax.jit, donate_argnums=(1, 2))
     def apply(w, g, k, a):
-        lr = gamma / jnp.sqrt(k / num_workers + 1.0)
-        return w - (a * (lr / par_recs)) * g, k + 1.0
+        with jax.named_scope("apply"):
+            lr = gamma / jnp.sqrt(k / num_workers + 1.0)
+            return w - (a * (lr / par_recs)) * g, k + 1.0
 
     return _prof.wrap_dispatch(apply, "kernel.dispatch", "asgd_apply_damped")
 
@@ -378,7 +399,10 @@ def make_saga_apply_merge(
             keep = a > 0
             return (jnp.where(keep, w2, w), jnp.where(keep, ab2, ab)), None
 
-        (w, alpha_bar), _ = jax.lax.scan(body, (w, alpha_bar), (G, mask))
+        with jax.named_scope("apply"):
+            (w, alpha_bar), _ = jax.lax.scan(
+                body, (w, alpha_bar), (G, mask)
+            )
         return w, alpha_bar
 
     return _prof.wrap_dispatch(apply_merge, "kernel.dispatch", "saga_apply_merge")
@@ -439,15 +463,18 @@ def make_mesh_asgd_worker_step(
         out_specs=(P(None), P(None)),
     )
     def _step(Xl, yl, vl, w, key):
-        key2, sub = jax.random.split(key)
         n_l = Xl.shape[0]  # static local block length
         p = jax.lax.axis_index(axis)
-        # replicated full-length draw, then slice my block: the mask is
-        # identical on every device and invariant to the mesh size
-        mask_full = jax.random.bernoulli(sub, batch_rate, (n_l * n_dev,))
-        ml = jax.lax.dynamic_slice_in_dim(
-            mask_full.astype(jnp.float32), p * n_l, n_l
-        ) * vl
+        with jax.named_scope("sample"):
+            key2, sub = jax.random.split(key)
+            # replicated full-length draw, then slice my block: the mask
+            # is identical on every device and invariant to the mesh size
+            mask_full = jax.random.bernoulli(
+                sub, batch_rate, (n_l * n_dev,)
+            )
+            ml = jax.lax.dynamic_slice_in_dim(
+                mask_full.astype(jnp.float32), p * n_l, n_l
+            ) * vl
         g_local = grad_sum(Xl, yl, w, ml)
         return jax.lax.psum(g_local, axis), key2
 
@@ -488,9 +515,12 @@ def make_mesh_saga_dcn_worker_step(mesh, axis: str = "dp"):
         mine = valid & (local >= 0) & (local < n_l)
         li = jnp.clip(local, 0, n_l - 1)
         vm = mine.astype(jnp.float32)
-        Xs_ = Xl[li]  # (cap, d) LOCAL gather -- only my rows are real
-        diff_l = (mm_f32(Xs_, w) - yl[li]) * vm
-        g_l = mm_f32(Xs_.T, (diff_l - alpha_sel) * vm)
+        with jax.named_scope("gather"):
+            Xs_ = Xl[li]  # (cap, d) LOCAL gather -- only my rows are real
+        with jax.named_scope("residual"):
+            diff_l = (mm_f32(Xs_, w) - yl[li]) * vm
+        with jax.named_scope("grad"):
+            g_l = mm_f32(Xs_.T, (diff_l - alpha_sel) * vm)
         # each slot has exactly one owner: the psums add zeros to the
         # owner's value (slot-exact) and fold the per-device gradient
         # partials (device-order, like the ASGD mesh step)
@@ -524,12 +554,16 @@ def _sparse_compacted_gradient(cols, vals, y, w, sub, batch_rate, grad_sum):
     bit-identical."""
     n_rows = y.shape[0]  # static at trace time
     cap = sparse_step_capacity(batch_rate, n_rows)
-    mask = jax.random.bernoulli(sub, batch_rate, (n_rows,))
-    (idx,) = jnp.nonzero(mask, size=cap, fill_value=0)
-    valid = (jnp.arange(cap) < jnp.sum(mask)).astype(vals.dtype)
-    c_sel = cols[idx]
-    v_sel = vals[idx] * valid[:, None]  # unfilled slots contribute 0
-    r = jnp.sum(v_sel * w[c_sel], axis=1) - y[idx] * valid
+    with jax.named_scope("sample"):
+        mask = jax.random.bernoulli(sub, batch_rate, (n_rows,))
+    with jax.named_scope("compact"):
+        (idx,) = jnp.nonzero(mask, size=cap, fill_value=0)
+        valid = (jnp.arange(cap) < jnp.sum(mask)).astype(vals.dtype)
+    with jax.named_scope("gather"):
+        c_sel = cols[idx]
+        v_sel = vals[idx] * valid[:, None]  # unfilled slots contribute 0
+    with jax.named_scope("residual"):
+        r = jnp.sum(v_sel * w[c_sel], axis=1) - y[idx] * valid
     return grad_sum(c_sel, v_sel, r)
 
 
@@ -573,12 +607,16 @@ def _sparse_saga_compacted(cols, vals, y, w, alpha, sub, batch_rate,
     """
     n_rows = y.shape[0]  # static at trace time
     cap = sparse_step_capacity(batch_rate, n_rows)
-    mask = jax.random.bernoulli(sub, batch_rate, (n_rows,))
-    (idx,) = jnp.nonzero(mask, size=cap, fill_value=0)
-    valid = (jnp.arange(cap) < jnp.sum(mask)).astype(vals.dtype)
-    c_sel = cols[idx]
-    v_sel = vals[idx] * valid[:, None]  # unfilled slots contribute 0
-    diff_sel = jnp.sum(v_sel * w[c_sel], axis=1) - y[idx] * valid
+    with jax.named_scope("sample"):
+        mask = jax.random.bernoulli(sub, batch_rate, (n_rows,))
+    with jax.named_scope("compact"):
+        (idx,) = jnp.nonzero(mask, size=cap, fill_value=0)
+        valid = (jnp.arange(cap) < jnp.sum(mask)).astype(vals.dtype)
+    with jax.named_scope("gather"):
+        c_sel = cols[idx]
+        v_sel = vals[idx] * valid[:, None]  # unfilled slots contribute 0
+    with jax.named_scope("residual"):
+        diff_sel = jnp.sum(v_sel * w[c_sel], axis=1) - y[idx] * valid
     g = grad_sum(c_sel, v_sel, diff_sel - alpha[idx])
     return g, diff_sel, idx, valid, c_sel, v_sel
 
@@ -731,15 +769,20 @@ def make_fused_asgd_rounds(
         X, y = shard
         n_rows = X.shape[0]
         if batch_rate > 0.5:
-            mask = jax.random.bernoulli(
-                sub, batch_rate, (n_rows,)
-            ).astype(jnp.float32)
+            with jax.named_scope("sample"):
+                mask = jax.random.bernoulli(
+                    sub, batch_rate, (n_rows,)
+                ).astype(jnp.float32)
             return grad_sum(X, y, w, mask), key
         cap = sparse_step_capacity(batch_rate, n_rows)
-        mask = jax.random.bernoulli(sub, batch_rate, (n_rows,))
-        (idx,) = jnp.nonzero(mask, size=cap, fill_value=0)
-        valid = (jnp.arange(cap) < jnp.sum(mask)).astype(jnp.float32)
-        return grad_sum(X[idx], y[idx], w, valid), key
+        with jax.named_scope("sample"):
+            mask = jax.random.bernoulli(sub, batch_rate, (n_rows,))
+        with jax.named_scope("compact"):
+            (idx,) = jnp.nonzero(mask, size=cap, fill_value=0)
+            valid = (jnp.arange(cap) < jnp.sum(mask)).astype(jnp.float32)
+        with jax.named_scope("gather"):
+            Xs, ys = X[idx], y[idx]
+        return grad_sum(Xs, ys, w, valid), key
 
     def round_fn(carry, _x):
         w, k, keys = carry
@@ -749,10 +792,11 @@ def make_fused_asgd_rounds(
             g, nk = one_gradient(shard, w, keys[i])
             gs.append(g)
             new_keys.append(nk)
-        G = jnp.stack(gs)
-        kk = k + jnp.arange(nw, dtype=jnp.float32)
-        lr = gamma / jnp.sqrt(kk / nw + 1.0)
-        w2 = w - (lr / par_recs) @ G
+        with jax.named_scope("apply"):
+            G = jnp.stack(gs)
+            kk = k + jnp.arange(nw, dtype=jnp.float32)
+            lr = gamma / jnp.sqrt(kk / nw + 1.0)
+            w2 = w - (lr / par_recs) @ G
         return (w2, k + float(nw), jnp.stack(new_keys)), w2
 
     @jax.jit
@@ -838,9 +882,10 @@ def make_fused_saga_rounds(
             new_keys.append(key)
         # sequential accept fold (ab advances between the nw applies)
         w2, ab2 = w, ab
-        for g in gs:
-            w2 = w2 - (gamma / par_recs) * g - gamma * ab2
-            ab2 = ab2 + g / n
+        with jax.named_scope("apply"):
+            for g in gs:
+                w2 = w2 - (gamma / par_recs) * g - gamma * ab2
+                ab2 = ab2 + g / n
         return (w2, ab2, tuple(new_alphas), jnp.stack(new_keys)), w2
 
     @jax.jit
@@ -872,9 +917,12 @@ def make_saga_dcn_worker_step():
     def step(X, y, w, idx, alpha_sel, n_valid):
         cap = idx.shape[0]
         valid = (jnp.arange(cap) < n_valid).astype(jnp.float32)
-        Xs = X[idx]
-        diff = (mm_f32(Xs, w) - y[idx]) * valid
-        g = mm_f32(Xs.T, (diff - alpha_sel) * valid)
+        with jax.named_scope("gather"):
+            Xs = X[idx]
+        with jax.named_scope("residual"):
+            diff = (mm_f32(Xs, w) - y[idx]) * valid
+        with jax.named_scope("grad"):
+            g = mm_f32(Xs.T, (diff - alpha_sel) * valid)
         return g, diff
 
     return _prof.wrap_dispatch(step, "kernel.dispatch", "saga_dcn_worker_step")
@@ -897,9 +945,11 @@ def make_saga_dcn_sparse_worker_step(d: int):
     def step(cols, vals, y, w, idx, alpha_sel, n_valid):
         cap = idx.shape[0]
         valid = (jnp.arange(cap) < n_valid).astype(vals.dtype)
-        c_sel = cols[idx]
-        v_sel = vals[idx] * valid[:, None]
-        diff = (jnp.sum(v_sel * w[c_sel], axis=1) - y[idx]) * valid
+        with jax.named_scope("gather"):
+            c_sel = cols[idx]
+            v_sel = vals[idx] * valid[:, None]
+        with jax.named_scope("residual"):
+            diff = (jnp.sum(v_sel * w[c_sel], axis=1) - y[idx]) * valid
         # invalid rows have v_sel == 0, so their (diff - alpha) is inert
         g = grad_sum(c_sel, v_sel, diff - alpha_sel)
         return g, diff

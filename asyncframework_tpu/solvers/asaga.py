@@ -54,9 +54,12 @@ from asyncframework_tpu.solvers.base import (
     collect_checked,
     resolve_dataset,
 )
+from asyncframework_tpu.metrics import trace
 from asyncframework_tpu.solvers.instrumentation import (
     FaultTolerantRun,
     RunInstruments,
+    on_device,
+    worker_task,
 )
 
 
@@ -198,6 +201,7 @@ class ASAGA(FlopsAccountingMixin):
         stop = threading.Event()
         self._warm_hot_path()
         start_wall = time.monotonic()
+        inst.on_run_start()
         snapshots: List[Tuple[float, jax.Array]] = [(0.0, w)]
 
         def now_ms():
@@ -217,90 +221,111 @@ class ASAGA(FlopsAccountingMixin):
             )
 
         def updater():
+            clock = inst.updater_clock
             while not stop.is_set():
                 with state_lock:
                     if state["k"] >= cfg.num_iterations:
                         break
+                clock.waits()
                 try:
                     res = ctx.collect_all(timeout=cfg.collect_timeout_s)
                 except queue.Empty:
                     continue
+                finally:
+                    clock.works()
+                # a sampled update (metrics/trace.py; () in an untraced
+                # run): its result.queue and compute end here; merge.queue
+                # is the state lock and the tau filter, merge.apply the
+                # accept path's dispatches (history commit, table delta,
+                # cross-chip copies, apply)
+                uts = inst.on_drained((res,))
                 g = res.data[0]
                 task_ms = waiting.on_finish(res.worker_id, now_ms())
                 do_save = False
-                # trace timings (metrics/trace.py): collect -> lock
-                # (merge.queue) -> history-corrected apply (merge.apply)
-                t_drained = now_ms() if inst.tracer is not None else 0.0
-                t_apply0 = t_apply1 = t_drained
+                merge_queue = trace.span(trace.MERGE_QUEUE, uts).begin()
                 with state_lock:
-                    if inst.tracer is not None:
-                        t_apply0 = now_ms()
                     state["flops"] += self._task_flops(res.worker_id)
                     k = state["k"]
                     # ASAGA acceptance quirk: k - staleness <= taw
                     accepted = k - res.staleness <= cfg.taw
-                    if accepted:
-                        shard = self._recovery.shard(res.worker_id)
-                        with hot_lock:
-                            alpha_cur = alpha[res.worker_id]
-                            # a shard re-homed while this result was in
-                            # flight leaves the payload on the old device;
-                            # normalize onto the slice's current home
-                            home = alpha_cur.device
-                            payload = tuple(
-                                jax.device_put(a, home) if a.device != home
-                                else a
-                                for a in res.data[1:]
-                            )
-                            # exact table delta (see make_saga_table_delta)
-                            if self._sparse:
-                                diff, idx, valid, c_sel, v_sel = payload
-                                delta = self._table_delta(
-                                    c_sel, v_sel, diff, alpha_cur, idx
+                    merge_queue.end()
+                    if uts:
+                        uts = inst.apply_attrs(((res, accepted),))
+                    t_apply = time.perf_counter_ns()
+                    # The accept path stays INLINE in this frame: its
+                    # temporaries (payload, delta, g) then live until the
+                    # next result overwrites them.  In a helper they die on
+                    # return, under the state lock, while the dispatches
+                    # that read them are still in flight -- measured on the
+                    # CPU rehearsal at a third of the update rate.
+                    with trace.span(trace.MERGE_APPLY, uts,
+                                    batch=int(accepted)):
+                        if accepted:
+                            shard = self._recovery.shard(res.worker_id)
+                            with hot_lock:
+                                alpha_cur = alpha[res.worker_id]
+                                # a shard re-homed while this result was in
+                                # flight leaves the payload on the old
+                                # device; normalize onto the slice's
+                                # current home
+                                home = alpha_cur.device
+                                payload = tuple(
+                                    jax.device_put(a, home)
+                                    if a.device != home else a
+                                    for a in res.data[1:]
                                 )
-                                alpha[res.worker_id] = self._commit(
-                                    alpha_cur, diff, idx, valid
-                                )
-                            else:
-                                diff, mask = payload
-                                delta = self._table_delta(
-                                    shard.X, diff, mask, alpha_cur
-                                )
-                                alpha[res.worker_id] = (
-                                    steps.saga_commit_history(
-                                        alpha_cur, diff, mask
+                                # exact table delta (see
+                                # make_saga_table_delta)
+                                if self._sparse:
+                                    diff, idx, valid, c_sel, v_sel = payload
+                                    delta = self._table_delta(
+                                        c_sel, v_sel, diff, alpha_cur, idx
                                     )
+                                    alpha[res.worker_id] = self._commit(
+                                        alpha_cur, diff, idx, valid
+                                    )
+                                else:
+                                    diff, mask = payload
+                                    delta = self._table_delta(
+                                        shard.X, diff, mask, alpha_cur
+                                    )
+                                    alpha[res.worker_id] = (
+                                        steps.saga_commit_history(
+                                            alpha_cur, diff, mask
+                                        )
+                                    )
+                            if g.device != self.driver_device:
+                                g = jax.device_put(g, self.driver_device)
+                            if delta.device != self.driver_device:
+                                delta = jax.device_put(
+                                    delta, self.driver_device
                                 )
-                        if g.device != self.driver_device:
-                            g = jax.device_put(g, self.driver_device)
-                        if delta.device != self.driver_device:
-                            delta = jax.device_put(delta, self.driver_device)
-                        state["w"], state["ab"] = self._apply(
-                            state["w"], state["ab"], g, delta
-                        )
+                            state["w"], state["ab"] = self._apply(
+                                state["w"], state["ab"], g, delta
+                            )
+                        else:
+                            state["dropped"] += 1
+                    inst.updater_apply_ns += time.perf_counter_ns() - t_apply
+                    if accepted:
                         state["k"] = k + 1
                         state["accepted"] += 1
                         calibrator.record(k, task_ms)
                         if k % cfg.printer_freq == 0:
-                            snapshots.append((now_ms(), state["w"]))
+                            with trace.span(trace.SNAPSHOT):
+                                snapshots.append((now_ms(), state["w"]))
+                                inst.on_snapshot(state["accepted"])
                         do_save = ckpt.should_save(state["k"])
                         save_k, save_w, save_ab = (
                             state["k"], state["w"], state["ab"]
                         )
-                    else:
-                        state["dropped"] += 1
-                    if inst.tracer is not None:
-                        t_apply1 = now_ms()
-                inst.on_gradient_merged(
-                    res.worker_id, res.staleness, accepted, k,
-                    batch_size=res.batch_size, task_ms=task_ms,
-                    queue_ms=max(0.0, t_apply0 - t_drained),
-                    apply_ms=max(0.0, t_apply1 - t_apply0),
-                )
+                # outside the lock, as ever: the event and the counters
+                inst.on_gradient_merged(res, accepted, k, task_ms)
                 if do_save:
-                    save_checkpoint(save_k, save_w, save_ab)
+                    with trace.span(trace.CHECKPOINT):
+                        save_checkpoint(save_k, save_w, save_ab)
                 if calibrator.maybe_finalize(state["k"]):
                     delay_model.calibrate(calibrator.avg_delay_ms)
+            clock.waits()  # the loop's last busy stretch
             stop.set()
 
         upd = threading.Thread(target=updater, name="saga-updater", daemon=True)
@@ -323,45 +348,61 @@ class ASAGA(FlopsAccountingMixin):
                     ctx, nw, bucket_predicate(ctx, nw, cfg.bucket_ratio)
                 )
                 if not cohort:
+                    inst.submit_empty_polls += 1
+                    inst.submitter_clock.waits()
                     time.sleep(0.001)
+                    inst.submitter_clock.works()
                     continue
-                with state_lock:
-                    w_pub = state["w"]
-                    model_version = state["k"]
-                if store is not None:
-                    # version buffer resolved at submit time: eviction by
-                    # later publishes cannot invalidate an in-flight read
-                    v = store.publish(np.asarray(w_pub))
-                    live = store.live_versions()
-                    tv = max(live[0], v - cfg.stale_read_offset)
-                    w_pub = store.value(self.driver_device, version=tv)
-                    model_version = v
-                ts = ctx.get_current_time()
-                ctx.set_last_time(ts)
-                ctx.mark_busy(cohort)
-                waiting.on_submit(cohort, now_ms())
-                with hot_lock:
-                    captured = {
-                        wid: (worker_keys[wid], alpha[wid]) for wid in cohort
+                # the sampling decision falls here, at submit (see ASGD.run)
+                uts = inst.start_updates(cohort)
+                with trace.span(trace.SUBMIT, uts.values(),
+                                batch=len(cohort)):
+                    with state_lock:
+                        w_pub = state["w"]
+                        model_version = state["k"]
+                    if store is not None:
+                        # version buffer resolved at submit time: eviction
+                        # by later publishes cannot invalidate an in-flight
+                        # read
+                        v = store.publish(np.asarray(w_pub))
+                        live = store.live_versions()
+                        tv = max(live[0], v - cfg.stale_read_offset)
+                        w_pub = store.value(self.driver_device, version=tv)
+                        model_version = v
+                    ts = ctx.get_current_time()
+                    ctx.set_last_time(ts)
+                    ctx.mark_busy(cohort)
+                    waiting.on_submit(cohort, now_ms())
+                    if uts:
+                        inst.begin_compute(uts, model_version)
+                    with hot_lock:
+                        captured = {
+                            wid: (worker_keys[wid], alpha[wid])
+                            for wid in cohort
+                        }
+                    fns = {
+                        wid: self._make_task(
+                            wid, w_pub, captured[wid][0], captured[wid][1],
+                            delay_model, uts.get(wid),
+                        )
+                        for wid in cohort
                     }
-                fns = {
-                    wid: self._make_task(
-                        wid, w_pub, captured[wid][0], captured[wid][1], delay_model
+                    with state_lock:
+                        state["rounds"] += 1
+                        round_idx = state["rounds"]
+                    # post BEFORE launching: a fast worker could otherwise
+                    # merge before its round's RoundSubmitted event exists
+                    inst.on_round_submitted(round_idx, cohort, model_version)
+                    waiter = sched.run_job(
+                        fns,
+                        self._handler(
+                            ctx, ts, now_ms, worker_keys, hot_lock, uts
+                        ),
                     )
-                    for wid in cohort
-                }
-                with state_lock:
-                    state["rounds"] += 1
-                    round_idx = state["rounds"]
-                # post BEFORE launching: a fast worker could otherwise merge
-                # before its round's RoundSubmitted event exists
-                inst.on_round_submitted(round_idx, cohort, model_version)
-                waiter = sched.run_job(
-                    fns, self._handler(ctx, ts, now_ms, worker_keys, hot_lock)
-                )
                 waiters.append(waiter)
             run_ok = True
         finally:
+            inst.submitter_clock.waits()  # the loop's last busy stretch
             stop.set()
             upd.join(timeout=10)
             if ft is not None:
@@ -382,10 +423,14 @@ class ASAGA(FlopsAccountingMixin):
         final_w = np.asarray(final_w_dev)
         elapsed = time.monotonic() - start_wall
         snapshots.append((elapsed * 1e3, final_w_dev))
+        inst.on_snapshot(state["accepted"])
+        inst.submitter_clock.waited(sched.blocked_ns)
+        run_extras = {
+            **inst.engine_counters(sched.task_retries), **inst.extras()
+        }
         if ckpt.enabled:
             save_checkpoint(final_k, final_w_dev, final_ab)
         traj = self._evaluate_trajectory(snapshots)
-        run_extras = inst.extras()
         if spec is not None:
             run_extras["speculated"] = spec.speculated_count()
             run_extras["speculation_wins"] = sched.speculative_wins()
@@ -412,6 +457,8 @@ class ASAGA(FlopsAccountingMixin):
                 "alpha_bar": np.asarray(state["ab"]),
                 **run_extras,
             },
+            snapshot_updates=inst.snapshot_updates,
+            staleness_hist=dict(sorted(inst.staleness_hist.items())),
         )
 
     # ----------------------------------------------------------------- fused
@@ -594,6 +641,7 @@ class ASAGA(FlopsAccountingMixin):
         alloc = make_allocation_manager(cfg, sched)
         self._warm_hot_path(apply=sync_apply, sync=True)
         start_wall = time.monotonic()
+        inst.on_run_start()
         snapshots: List[Tuple[float, jax.Array]] = [(0.0, w)]
 
         def now_ms():
@@ -602,42 +650,57 @@ class ASAGA(FlopsAccountingMixin):
         rounds = 0
         flops = 0.0
         run_ok = False
+        # one driver thread submits and drains (see ASGD.run_sync)
+        clock = inst.updater_clock
         try:
             for k in range(cfg.num_iterations):
                 cohort = list(range(nw))
-                ts = ctx.get_current_time()
-                ctx.mark_busy(cohort)
-                waiting.on_submit(cohort, now_ms())
-                with hot_lock:
-                    captured = {
-                        wid: (worker_keys[wid], alpha[wid]) for wid in cohort
+                uts = inst.start_updates(cohort)
+                with trace.span(trace.SUBMIT, uts.values(), batch=nw):
+                    ts = ctx.get_current_time()
+                    ctx.mark_busy(cohort)
+                    waiting.on_submit(cohort, now_ms())
+                    if uts:
+                        inst.begin_compute(uts, k)
+                    with hot_lock:
+                        captured = {
+                            wid: (worker_keys[wid], alpha[wid])
+                            for wid in cohort
+                        }
+                    fns = {
+                        wid: self._make_task(
+                            wid, w, captured[wid][0], captured[wid][1],
+                            delay_model, uts.get(wid),
+                        )
+                        for wid in cohort
                     }
-                fns = {
-                    wid: self._make_task(
-                        wid, w, captured[wid][0], captured[wid][1], delay_model
+                    inst.on_round_submitted(k, cohort, model_version=k)
+                    waiter = sched.run_job(
+                        fns,
+                        self._handler(
+                            ctx, ts, now_ms, worker_keys, hot_lock, uts
+                        ),
                     )
-                    for wid in cohort
-                }
-                inst.on_round_submitted(k, cohort, model_version=k)
-                waiter = sched.run_job(
-                    fns, self._handler(ctx, ts, now_ms, worker_keys, hot_lock)
-                )
                 acc = None
                 reported = set()
+                drained = []
                 for _ in range(nw):
-                    res = self._collect_checked(
-                        ctx, waiter, cfg.run_timeout_s,
-                        pool=sched.pool, cohort=cohort, collected=reported,
-                    )
+                    clock.waits()
+                    try:
+                        res = self._collect_checked(
+                            ctx, waiter, cfg.run_timeout_s, pool=sched.pool,
+                            cohort=cohort, collected=reported,
+                        )
+                    finally:
+                        clock.works()
+                    inst.on_drained((res,))
+                    drained.append((res, True))
                     reported.add(res.worker_id)
                     g = res.data[0]
                     flops += self._task_flops(res.worker_id)
                     task_ms = waiting.on_finish(res.worker_id, now_ms())
                     calibrator.record(k, task_ms)
-                    inst.on_gradient_merged(
-                        res.worker_id, res.staleness, True, k,
-                        batch_size=res.batch_size, task_ms=task_ms,
-                    )
+                    inst.on_gradient_merged(res, True, k, task_ms)
                     with hot_lock:
                         alpha_cur = alpha[res.worker_id]
                         # a shard re-homed mid-round leaves this result's
@@ -668,14 +731,20 @@ class ASAGA(FlopsAccountingMixin):
                         g = jax.device_put(g, self.driver_device)
                     acc = g if acc is None else steps.add_grads(acc, g)
                 # sync drain has no dispatch overlap: table delta == g
-                w, alpha_bar = sync_apply(w, alpha_bar, acc, acc)
+                with trace.span(trace.MERGE_APPLY,
+                                inst.apply_attrs(drained) if uts else None,
+                                batch=nw):
+                    w, alpha_bar = sync_apply(w, alpha_bar, acc, acc)
                 rounds += 1
                 if k % cfg.printer_freq == 0:
-                    snapshots.append((now_ms(), w))
+                    with trace.span(trace.SNAPSHOT):
+                        snapshots.append((now_ms(), w))
+                        inst.on_snapshot(rounds * nw)
                 if calibrator.maybe_finalize(k):
                     delay_model.calibrate(calibrator.avg_delay_ms)
             run_ok = True
         finally:
+            clock.waits()  # the loop's last busy stretch
             if ft is not None:
                 ft.stop()
             if spec is not None:
@@ -689,8 +758,13 @@ class ASAGA(FlopsAccountingMixin):
         final_w = np.asarray(w)  # fence: see the async path's comment
         elapsed = time.monotonic() - start_wall
         snapshots.append((elapsed * 1e3, w))
+        inst.on_snapshot(rounds * nw)
+        clock.waited(sched.blocked_ns)
+        extras = {
+            **inst.engine_counters(sched.task_retries, one_thread=True),
+            **inst.extras(),
+        }
         traj = self._evaluate_trajectory(snapshots)
-        extras = inst.extras()
         if spec is not None:
             extras["speculated"] = spec.speculated_count()
             extras["speculation_wins"] = sched.speculative_wins()
@@ -711,6 +785,8 @@ class ASAGA(FlopsAccountingMixin):
             total_flops=flops,
             waiting_time_ms=waiting.snapshot(),
             extras=extras,
+            snapshot_updates=inst.snapshot_updates,
+            staleness_hist=dict(sorted(inst.staleness_hist.items())),
         )
 
     # ---------------------------------------------------------------- helpers
@@ -779,44 +855,29 @@ class ASAGA(FlopsAccountingMixin):
             wd, ab = apply(wd, ab, g, delta)
         wd.block_until_ready()
 
-    def _make_task(self, wid, w_pub, key, alpha_slice, delay_model: DelayModel):
+    def _make_task(self, wid, w_pub, key, alpha_slice,
+                   delay_model: DelayModel, ut=None):
         shard = self._recovery.shard(wid)  # follows re-homed shards
-        delay_ms = delay_model.delay_ms(wid)
         dev = shard.device
         step = self._step
         sparse = self._sparse
-        # injected delay fires once: a speculative copy / replacement
-        # executor is a healthy host path and bypasses the straggler
-        delay_fired = threading.Event()
 
-        def fn():
-            if delay_ms > 0 and not delay_fired.is_set():
-                delay_fired.set()
-                time.sleep(delay_ms / 1e3)
-            w_local = w_pub
-            if w_local.device != dev:
-                w_local = jax.device_put(w_local, dev)
+        def dispatch():
             # a slice/key captured around a concurrent shard re-home may
             # still live on the old device; normalize onto the shard's home
-            a_local = alpha_slice
-            if a_local.device != dev:
-                a_local = jax.device_put(a_local, dev)
-            key_local = key
-            if key_local.device != dev:
-                key_local = jax.device_put(key_local, dev)
-            if sparse:
-                out = step(
-                    shard.cols, shard.vals, shard.y, w_local, a_local, key_local
-                )
-            else:
-                out = step(shard.X, shard.y, w_local, a_local, key_local)
-            out[0].block_until_ready()
+            w_local = on_device(w_pub, dev)
+            a_local = on_device(alpha_slice, dev)
+            key_local = on_device(key, dev)
             # (g, ...payload..., new_key) -- the payload arity differs
             # between the dense (diff, mask) and compacted sparse
             # (diff_sel, idx, valid, c_sel, v_sel) steps
-            return out
+            if sparse:
+                return step(
+                    shard.cols, shard.vals, shard.y, w_local, a_local, key_local
+                )
+            return step(shard.X, shard.y, w_local, a_local, key_local)
 
-        return fn
+        return worker_task(dispatch, delay_model.delay_ms(wid), ut)
 
     def _collect_checked(self, ctx: AsyncContext, waiter, timeout_s: float,
                          pool=None, cohort=None, collected=None):
@@ -834,7 +895,8 @@ class ASAGA(FlopsAccountingMixin):
         )
 
     def _handler(
-        self, ctx: AsyncContext, submit_clock: int, now_ms, worker_keys, key_lock
+        self, ctx: AsyncContext, submit_clock: int, now_ms, worker_keys,
+        key_lock, uts,
     ):
         submit_wall = now_ms()
         par_recs = int(self.cfg.batch_rate * self.ds.n / self.cfg.num_workers)
@@ -845,12 +907,16 @@ class ASAGA(FlopsAccountingMixin):
             # available (see ASGD._handler for why)
             with key_lock:
                 worker_keys[wid] = new_key
+            ut = uts.get(wid) if uts else None
+            if ut is not None:
+                ut.begin(trace.RESULT_QUEUE)
             ctx.merge_result(
                 wid,
                 tuple(data),
                 submit_clock=submit_clock,
                 elapsed_ms=now_ms() - submit_wall,
                 batch_size=par_recs,
+                trace=ut,
             )
 
         return handler

@@ -52,9 +52,12 @@ from asyncframework_tpu.solvers.base import (
     collect_checked,
     resolve_dataset,
 )
+from asyncframework_tpu.metrics import trace
 from asyncframework_tpu.solvers.instrumentation import (
     FaultTolerantRun,
     RunInstruments,
+    on_device,
+    worker_task,
 )
 
 
@@ -192,6 +195,7 @@ class ASGD(FlopsAccountingMixin):
         )
         self._warm_hot_path(apply_batch, max(cfg.drain_batch, 1))
         start_wall = time.monotonic()
+        inst.on_run_start()
         snapshots: List[Tuple[float, jax.Array]] = [(0.0, w)]
 
         def now_ms() -> float:
@@ -221,14 +225,18 @@ class ASGD(FlopsAccountingMixin):
 
         def updater():
             max_drain = max(cfg.drain_batch, 1)
+            clock = inst.updater_clock
             while not stop.is_set():
                 with state_lock:
                     if state["k"] >= cfg.num_iterations:
                         break
+                clock.waits()
                 try:
                     results = [ctx.collect_all(timeout=cfg.collect_timeout_s)]
                 except queue.Empty:
                     continue
+                finally:
+                    clock.works()
                 # opportunistic drain: everything already queued, up to the
                 # batch cap, folds into one device dispatch below
                 while len(results) < max_drain:
@@ -237,11 +245,12 @@ class ASGD(FlopsAccountingMixin):
                     except queue.Empty:
                         break
                 do_save = False
-                # trace timings (metrics/trace.py): drained -> lock+filter
-                # (merge.queue) -> device apply (merge.apply); only paid
-                # when a tracer is sampling this run
-                t_drained = now_ms() if inst.tracer is not None else 0.0
-                t_apply0 = t_apply1 = t_drained
+                # the drain's sampled updates (metrics/trace.py; () in an
+                # untraced run): their result.queue and compute end here;
+                # merge.queue is the lock and the filter, merge.apply the
+                # dispatch below
+                uts = inst.on_drained(results)
+                merge_queue = trace.span(trace.MERGE_QUEUE, uts).begin()
                 with state_lock:
                     k = state["k"]
                     # never apply past the iteration budget: trim the batch
@@ -251,58 +260,60 @@ class ASGD(FlopsAccountingMixin):
                     for res in results:
                         state["flops"] += self._task_flops(res.worker_id)
                         task_ms = waiting.on_finish(res.worker_id, now_ms())
-                        if res.staleness > cfg.taw:
-                            state["dropped"] += 1
-                            merged.append(
-                                (res, False, task_ms, k + len(accepted_g))
-                            )
-                        elif len(accepted_g) < room:
+                        accepted = res.staleness <= cfg.taw
+                        if accepted and len(accepted_g) >= room:
+                            # beyond the iteration budget: ignored, like
+                            # the old per-result loop's break-at-limit
+                            continue
+                        merged.append(
+                            (res, accepted, k + len(accepted_g), task_ms)
+                        )
+                        if accepted:
                             g = res.data
                             if g.device != self.driver_device:
                                 g = jax.device_put(g, self.driver_device)
+                            calibrator.record(k + len(accepted_g), task_ms)
                             accepted_g.append(g)
-                            calibrator.record(
-                                k + len(accepted_g) - 1, task_ms
+                        else:
+                            state["dropped"] += 1
+                    merge_queue.end()
+                    if uts:
+                        # what each sampled update's merge.apply carries
+                        uts = inst.apply_attrs((m[0], m[1]) for m in merged)
+                    t_apply = time.perf_counter_ns()
+                    with trace.span(trace.MERGE_APPLY, uts,
+                                    batch=len(accepted_g)):
+                        if len(accepted_g) >= BATCH_DRAIN_MIN:
+                            # stack+apply = 2 dispatches replacing m.  The
+                            # list is padded with the cached zero handle to
+                            # the fixed max_drain length and masked, so
+                            # stack AND apply_batch each compile ONCE,
+                            # never per drained batch size.
+                            mcount = len(accepted_g)
+                            padded = accepted_g + [_zero_g] * (
+                                max_drain - mcount
                             )
-                            merged.append(
-                                (res, True, task_ms, k + len(accepted_g) - 1)
+                            G = jnp.stack(padded)
+                            mask = _mask_cache.get(mcount)
+                            if mask is None:
+                                mask = jax.device_put(
+                                    jnp.asarray(
+                                        [1.0] * mcount
+                                        + [0.0] * (max_drain - mcount),
+                                        jnp.float32,
+                                    ),
+                                    self.driver_device,
+                                )
+                                _mask_cache[mcount] = mask
+                            state["w"], state["k_dev"] = apply_batch(
+                                state["w"], G, mask, state["k_dev"]
                             )
-                        # else: beyond the iteration budget -- ignored, like
-                        # the old per-result loop's break-at-limit
-                    if inst.tracer is not None:
-                        t_apply0 = now_ms()
-                    if len(accepted_g) >= BATCH_DRAIN_MIN:
-                        # stack+apply = 2 dispatches replacing m.  The list
-                        # is padded with the cached zero handle to the fixed
-                        # max_drain length and masked, so stack AND
-                        # apply_batch each compile ONCE, never per drained
-                        # batch size.
-                        mcount = len(accepted_g)
-                        padded = accepted_g + [_zero_g] * (
-                            max_drain - mcount
-                        )
-                        G = jnp.stack(padded)
-                        mask = _mask_cache.get(mcount)
-                        if mask is None:
-                            mask = jax.device_put(
-                                jnp.asarray(
-                                    [1.0] * mcount
-                                    + [0.0] * (max_drain - mcount),
-                                    jnp.float32,
-                                ),
-                                self.driver_device,
-                            )
-                            _mask_cache[mcount] = mask
-                        state["w"], state["k_dev"] = apply_batch(
-                            state["w"], G, mask, state["k_dev"]
-                        )
-                    else:
-                        for g in accepted_g:
-                            state["w"], state["k_dev"] = self._apply(
-                                state["w"], g, state["k_dev"]
-                            )
-                    if inst.tracer is not None:
-                        t_apply1 = now_ms()
+                        else:
+                            for g in accepted_g:
+                                state["w"], state["k_dev"] = self._apply(
+                                    state["w"], g, state["k_dev"]
+                                )
+                    inst.updater_apply_ns += time.perf_counter_ns() - t_apply
                     if accepted_g:
                         k_new = k + len(accepted_g)
                         state["k"] = k_new
@@ -314,24 +325,22 @@ class ASGD(FlopsAccountingMixin):
                             (k + j) % cfg.printer_freq == 0
                             for j in range(len(accepted_g))
                         ):
-                            snapshots.append((now_ms(), state["w"]))
+                            with trace.span(trace.SNAPSHOT):
+                                snapshots.append((now_ms(), state["w"]))
+                                inst.on_snapshot(state["accepted"])
                         # range check: a batch jumping over a checkpoint
                         # boundary must still save
                         do_save = ckpt.should_save_range(k, k_new)
                         save_k, save_w = state["k"], state["w"]
-                q_ms = max(0.0, t_apply0 - t_drained)
-                a_ms = (max(0.0, t_apply1 - t_apply0)
-                        / max(1, len(accepted_g)))
-                for res, accepted, task_ms, at_k in merged:
-                    inst.on_gradient_merged(
-                        res.worker_id, res.staleness, accepted, at_k,
-                        batch_size=res.batch_size, task_ms=task_ms,
-                        queue_ms=q_ms, apply_ms=a_ms if accepted else 0.0,
-                    )
+                # outside the lock, as ever: the events and the counters
+                for res, accepted, at_k, task_ms in merged:
+                    inst.on_gradient_merged(res, accepted, at_k, task_ms)
                 if do_save:
-                    save_checkpoint(save_k, save_w)
+                    with trace.span(trace.CHECKPOINT):
+                        save_checkpoint(save_k, save_w)
                 if calibrator.maybe_finalize(state["k"]):
                     delay_model.calibrate(calibrator.avg_delay_ms)
+            clock.waits()  # the loop's last busy stretch
             stop.set()
 
         upd = threading.Thread(target=updater, name="ps-updater", daemon=True)
@@ -358,47 +367,65 @@ class ASGD(FlopsAccountingMixin):
                     ctx, nw, bucket_predicate(ctx, nw, cfg.bucket_ratio)
                 )
                 if not cohort:
+                    inst.submit_empty_polls += 1
+                    inst.submitter_clock.waits()
                     time.sleep(0.001)
+                    inst.submitter_clock.works()
                     continue
-                with state_lock:
-                    w_pub = state["w"]  # immutable handle = model version
-                    model_version = state["k"]
-                if store is not None:
-                    # ASYNCbroadcast parity: publish this round's model as a
-                    # new version, then point workers at (latest - offset).
-                    # The version's device buffer is resolved HERE, at submit
-                    # time: a straggling worker must not re-query the store
-                    # later (the version may have been evicted by newer
-                    # publishes); the captured handle keeps the array alive
-                    # regardless of store eviction.
-                    v = store.publish(np.asarray(w_pub))
-                    live = store.live_versions()
-                    tv = max(live[0], v - cfg.stale_read_offset)
-                    w_pub = store.value(self.driver_device, version=tv)
-                    model_version = v
-                ts = ctx.get_current_time()
-                ctx.set_last_time(ts)
-                ctx.mark_busy(cohort)
-                waiting.on_submit(cohort, now_ms())
-                with key_lock:
-                    keys = {wid: worker_keys[wid] for wid in cohort}
-                fns = {
-                    wid: self._make_task(wid, w_pub, keys[wid], delay_model)
-                    for wid in cohort
-                }
-                with state_lock:
-                    state["rounds"] += 1
-                    round_idx = state["rounds"]
-                # post BEFORE launching: a fast worker could otherwise merge
-                # (and the live UI could observe accepted>0) before its
-                # round's RoundSubmitted event exists
-                inst.on_round_submitted(round_idx, cohort, model_version)
-                waiter = sched.run_job(
-                    fns, self._handler(ctx, ts, now_ms, worker_keys, key_lock)
-                )
+                # the sampling decision falls here, at submit: a sampled
+                # update's handle rides its task closure, the handler and
+                # the PartialResult to the updater
+                uts = inst.start_updates(cohort)
+                with trace.span(trace.SUBMIT, uts.values(),
+                                batch=len(cohort)):
+                    with state_lock:
+                        w_pub = state["w"]  # immutable handle = model version
+                        model_version = state["k"]
+                    if store is not None:
+                        # ASYNCbroadcast parity: publish this round's model
+                        # as a new version, then point workers at (latest -
+                        # offset).  The version's device buffer is resolved
+                        # HERE, at submit time: a straggling worker must not
+                        # re-query the store later (the version may have
+                        # been evicted by newer publishes); the captured
+                        # handle keeps the array alive regardless of store
+                        # eviction.
+                        v = store.publish(np.asarray(w_pub))
+                        live = store.live_versions()
+                        tv = max(live[0], v - cfg.stale_read_offset)
+                        w_pub = store.value(self.driver_device, version=tv)
+                        model_version = v
+                    ts = ctx.get_current_time()
+                    ctx.set_last_time(ts)
+                    ctx.mark_busy(cohort)
+                    waiting.on_submit(cohort, now_ms())
+                    if uts:
+                        inst.begin_compute(uts, model_version)
+                    with key_lock:
+                        keys = {wid: worker_keys[wid] for wid in cohort}
+                    fns = {
+                        wid: self._make_task(
+                            wid, w_pub, keys[wid], delay_model, uts.get(wid)
+                        )
+                        for wid in cohort
+                    }
+                    with state_lock:
+                        state["rounds"] += 1
+                        round_idx = state["rounds"]
+                    # post BEFORE launching: a fast worker could otherwise
+                    # merge (and the live UI could observe accepted>0)
+                    # before its round's RoundSubmitted event exists
+                    inst.on_round_submitted(round_idx, cohort, model_version)
+                    waiter = sched.run_job(
+                        fns,
+                        self._handler(
+                            ctx, ts, now_ms, worker_keys, key_lock, uts
+                        ),
+                    )
                 waiters.append(waiter)
             run_ok = True
         finally:
+            inst.submitter_clock.waits()  # the loop's last busy stretch
             stop.set()
             upd.join(timeout=10)
             if ft is not None:
@@ -421,10 +448,12 @@ class ASGD(FlopsAccountingMixin):
         final_w = np.asarray(final_w_dev)
         elapsed = time.monotonic() - start_wall
         snapshots.append((elapsed * 1e3, final_w_dev))
+        inst.on_snapshot(state["accepted"])
+        inst.submitter_clock.waited(sched.blocked_ns)
+        extras = {**inst.engine_counters(sched.task_retries), **inst.extras()}
         if ckpt.enabled:
             save_checkpoint(final_k, final_w_dev)
         traj = self._evaluate_trajectory(snapshots)
-        extras = inst.extras()
         if spec is not None:
             extras["speculated"] = spec.speculated_count()
             extras["speculation_wins"] = sched.speculative_wins()
@@ -446,6 +475,8 @@ class ASGD(FlopsAccountingMixin):
             total_flops=state["flops"],
             waiting_time_ms=waiting.snapshot(),
             extras=extras,
+            snapshot_updates=inst.snapshot_updates,
+            staleness_hist=dict(sorted(inst.staleness_hist.items())),
         )
 
     # ----------------------------------------------------------------- fused
@@ -602,6 +633,7 @@ class ASGD(FlopsAccountingMixin):
         }
         self._warm_hot_path(sync=True)
         start_wall = time.monotonic()
+        inst.on_run_start()
         snapshots: List[Tuple[float, jax.Array]] = [(0.0, w)]
 
         def now_ms():
@@ -610,48 +642,71 @@ class ASGD(FlopsAccountingMixin):
         rounds = 0
         flops = 0.0
         run_ok = False
+        # one driver thread submits and drains: its time outside the
+        # blocking collect is the barrier's host work
+        clock = inst.updater_clock
         try:
             for k in range(cfg.num_iterations):
                 cohort = list(range(nw))
-                ts = ctx.get_current_time()
-                ctx.mark_busy(cohort)
-                waiting.on_submit(cohort, now_ms())
-                key_lock = threading.Lock()
-                fns = {
-                    wid: self._make_task(wid, w, worker_keys[wid], delay_model)
-                    for wid in cohort
-                }
-                inst.on_round_submitted(k, cohort, model_version=k)
-                waiter = sched.run_job(
-                    fns, self._handler(ctx, ts, now_ms, worker_keys, key_lock)
-                )
+                uts = inst.start_updates(cohort)
+                with trace.span(trace.SUBMIT, uts.values(), batch=nw):
+                    ts = ctx.get_current_time()
+                    ctx.mark_busy(cohort)
+                    waiting.on_submit(cohort, now_ms())
+                    if uts:
+                        inst.begin_compute(uts, k)
+                    key_lock = threading.Lock()
+                    fns = {
+                        wid: self._make_task(
+                            wid, w, worker_keys[wid], delay_model,
+                            uts.get(wid),
+                        )
+                        for wid in cohort
+                    }
+                    inst.on_round_submitted(k, cohort, model_version=k)
+                    waiter = sched.run_job(
+                        fns,
+                        self._handler(
+                            ctx, ts, now_ms, worker_keys, key_lock, uts
+                        ),
+                    )
                 acc = None
                 reported = set()
+                drained = []
                 for _ in range(nw):
-                    res = self._collect_checked(
-                        ctx, waiter, cfg.run_timeout_s,
-                        pool=sched.pool, cohort=cohort, collected=reported,
-                    )
+                    clock.waits()
+                    try:
+                        res = self._collect_checked(
+                            ctx, waiter, cfg.run_timeout_s, pool=sched.pool,
+                            cohort=cohort, collected=reported,
+                        )
+                    finally:
+                        clock.works()
+                    inst.on_drained((res,))
+                    drained.append((res, True))
                     reported.add(res.worker_id)
                     g = res.data
                     flops += self._task_flops(res.worker_id)
                     task_ms = waiting.on_finish(res.worker_id, now_ms())
                     calibrator.record(k, task_ms)
-                    inst.on_gradient_merged(
-                        res.worker_id, res.staleness, True, k,
-                        batch_size=res.batch_size, task_ms=task_ms,
-                    )
+                    inst.on_gradient_merged(res, True, k, task_ms)
                     if g.device != self.driver_device:
                         g = jax.device_put(g, self.driver_device)
                     acc = g if acc is None else steps.add_grads(acc, g)
-                w, k_dev = self._sync_apply(w, acc, k_dev)
+                with trace.span(trace.MERGE_APPLY,
+                                inst.apply_attrs(drained) if uts else None,
+                                batch=nw):
+                    w, k_dev = self._sync_apply(w, acc, k_dev)
                 rounds += 1
                 if k % cfg.printer_freq == 0:
-                    snapshots.append((now_ms(), w))
+                    with trace.span(trace.SNAPSHOT):
+                        snapshots.append((now_ms(), w))
+                        inst.on_snapshot(rounds * nw)
                 if calibrator.maybe_finalize(k):
                     delay_model.calibrate(calibrator.avg_delay_ms)
             run_ok = True
         finally:
+            clock.waits()  # the loop's last busy stretch
             if ft is not None:
                 ft.stop()
             if spec is not None:
@@ -665,8 +720,13 @@ class ASGD(FlopsAccountingMixin):
         final_w = np.asarray(w)  # fence: see the async path's comment
         elapsed = time.monotonic() - start_wall
         snapshots.append((elapsed * 1e3, w))
+        inst.on_snapshot(rounds * nw)
+        clock.waited(sched.blocked_ns)
+        extras = {
+            **inst.engine_counters(sched.task_retries, one_thread=True),
+            **inst.extras(),
+        }
         traj = self._evaluate_trajectory(snapshots)
-        extras = inst.extras()
         if spec is not None:
             extras["speculated"] = spec.speculated_count()
             extras["speculation_wins"] = sched.speculative_wins()
@@ -687,6 +747,8 @@ class ASGD(FlopsAccountingMixin):
             total_flops=flops,
             waiting_time_ms=waiting.snapshot(),
             extras=extras,
+            snapshot_updates=inst.snapshot_updates,
+            staleness_hist=dict(sorted(inst.staleness_hist.items())),
         )
 
     # ---------------------------------------------------------------- helpers
@@ -770,40 +832,27 @@ class ASGD(FlopsAccountingMixin):
                 wd, kd = apply_batch(wd, G, mask, kd)
         wd.block_until_ready()
 
-    def _make_task(self, wid: int, w_pub, key, delay_model: DelayModel):
+    def _make_task(self, wid: int, w_pub, key, delay_model: DelayModel,
+                   ut=None):
         # recovery view: a re-homed shard is transparently computed on its
         # new device; w and the PRNG chain follow the shard's home
         shard = self._recovery.shard(wid)
-        delay_ms = delay_model.delay_ms(wid)
         dev = shard.device
         step = self._step
         sparse = self._sparse
-        # The injected delay models a slow *machine*: only the first body to
-        # run it sleeps -- a speculative copy or a replacement executor is a
-        # different (healthy) host path and must bypass the straggler.
-        delay_fired = threading.Event()
 
-        def fn():
-            if delay_ms > 0 and not delay_fired.is_set():
-                delay_fired.set()
-                time.sleep(delay_ms / 1e3)
-            w_local = w_pub
-            if w_local.device != dev:
-                w_local = jax.device_put(w_local, dev)
-            key_local = key
-            if key_local.device != dev:
-                key_local = jax.device_put(key_local, dev)
+        def dispatch():
+            w_local = on_device(w_pub, dev)
+            key_local = on_device(key, dev)
             if sparse:
-                g, new_key = step(shard.cols, shard.vals, shard.y, w_local, key_local)
-            else:
-                g, new_key = step(shard.X, shard.y, w_local, key_local)
-            g.block_until_ready()  # completion only; data stays in HBM
-            return g, new_key
+                return step(shard.cols, shard.vals, shard.y, w_local, key_local)
+            return step(shard.X, shard.y, w_local, key_local)
 
-        return fn
+        return worker_task(dispatch, delay_model.delay_ms(wid), ut)
 
     def _handler(
-        self, ctx: AsyncContext, submit_clock: int, now_ms, worker_keys, key_lock
+        self, ctx: AsyncContext, submit_clock: int, now_ms, worker_keys,
+        key_lock, uts,
     ):
         submit_wall = now_ms()
         par_recs = int(self.cfg.batch_rate * self.ds.n / self.cfg.num_workers)
@@ -815,12 +864,16 @@ class ASGD(FlopsAccountingMixin):
             # this worker with its previous key and replay the same mask.
             with key_lock:
                 worker_keys[wid] = new_key
+            ut = uts.get(wid) if uts else None
+            if ut is not None:
+                ut.begin(trace.RESULT_QUEUE)
             ctx.merge_result(
                 wid,
                 g,
                 submit_clock=submit_clock,
                 elapsed_ms=now_ms() - submit_wall,
                 batch_size=par_recs,
+                trace=ut,
             )
 
         return handler

@@ -417,6 +417,13 @@ class TrainResult:
     total_flops: float = 0.0
     waiting_time_ms: Dict[int, float] = field(default_factory=dict)
     extras: Dict[str, object] = field(default_factory=dict)
+    #: accepted updates behind every ``trajectory`` entry (0 for ``w = 0``,
+    #: ``accepted`` for the final model); empty where a run mode does not
+    #: record it
+    snapshot_updates: List[int] = field(default_factory=list)
+    #: staleness (model versions) -> count, over EVERY result the server
+    #: merged: sums to ``accepted + dropped``
+    staleness_hist: Dict[int, int] = field(default_factory=dict)
 
     @property
     def final_objective(self) -> float:

@@ -22,6 +22,8 @@ import threading
 import time
 from typing import Callable, Dict, List, Optional, Tuple
 
+import jax
+
 from asyncframework_tpu.metrics.bus import (
     Event,
     GradientMerged,
@@ -39,20 +41,122 @@ from asyncframework_tpu.metrics.system import CsvSink, JsonlSink, MetricsSystem
 
 class _GlobalTraceFold:
     """Bus listener folding TraceSpan events into the process-global
-    aggregator (bench.py / tools read it) -- on the dispatch thread, so
-    the solver's updater never pays for histogram updates."""
+    aggregator (the benchmark and tools read it) -- on the dispatch thread,
+    so the solver's hot threads never pay for histogram updates.  The
+    event carries every field the aggregator reads: it is folded as it is."""
 
     def on_trace_span(self, ev) -> None:
-        trace_mod.aggregator().add(trace_mod.Span(
-            stage=ev.stage, trace_id=ev.trace_id, span_id=ev.span_id,
-            parent_id=ev.parent_id, worker_id=ev.worker_id,
-            model_version=ev.model_version, start_ms=ev.start_ms,
-            dur_ms=ev.dur_ms, staleness=ev.staleness,
-            staleness_ms=ev.staleness_ms, accepted=ev.accepted,
-        ))
+        trace_mod.aggregator().add(ev)
 
     def on_event(self, event) -> None:
         pass
+
+
+#: JAX's monitoring event around every executable it builds or loads
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compiles = 0
+_compiles_lock = threading.Lock()
+_compiles_hooked = False
+
+
+def _on_jax_duration(name, _seconds, **_kw) -> None:
+    global _compiles
+    if name == _COMPILE_EVENT:
+        with _compiles_lock:
+            _compiles += 1
+
+
+def compiles_so_far() -> int:
+    """Executables JAX has built or loaded in this process since the first
+    call of this function, which registers the hook (JAX offers no way to
+    take one listener back, so there is one per process, never one per
+    run).  A run reports the difference across itself."""
+    global _compiles_hooked
+    with _compiles_lock:
+        if not _compiles_hooked:
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_jax_duration
+            )
+            _compiles_hooked = True
+        return _compiles
+
+
+class BusyClock:
+    """One serial thread's time, split into busy and waiting: the thread
+    calls :meth:`waits` before it blocks on its queue (or sleeps) and
+    :meth:`works` when it is back.  Two ``perf_counter_ns`` reads a turn,
+    always on.  *Busy* is everything else the thread's clock saw: its
+    Python, but also lock waits, GIL hand-offs and dispatches that block
+    while the device's queue is full.  It bounds the thread's own work
+    from above, so ``accepted / busy`` bounds the rate at which the
+    thread alone saturates from below."""
+
+    __slots__ = ("busy_ns", "wait_ns", "_mark")
+
+    def __init__(self):
+        self.start()
+
+    def start(self) -> None:
+        self.busy_ns = 0
+        self.wait_ns = 0
+        self._mark = time.perf_counter_ns()
+
+    def waits(self) -> None:
+        now = time.perf_counter_ns()
+        self.busy_ns += now - self._mark
+        self._mark = now
+
+    def works(self) -> None:
+        now = time.perf_counter_ns()
+        self.wait_ns += now - self._mark
+        self._mark = now
+
+    def waited(self, ns: int) -> None:
+        """``ns`` of what was counted as busy was spent blocked in a callee
+        that keeps its own account (``JobScheduler.blocked_ns``)."""
+        self.busy_ns -= ns
+        self.wait_ns += ns
+
+
+def on_device(arr, device):
+    """``arr`` on the worker's chip; a copy from another chip is the
+    ``task.model_copy`` stage (an annotation inside ``task.dispatch``)."""
+    if arr.device != device:
+        with trace_mod.span(trace_mod.TASK_MODEL_COPY):
+            arr = jax.device_put(arr, device)
+    return arr
+
+
+def worker_task(dispatch: Callable[[], tuple], delay_ms: float = 0.0,
+                ut: Optional["trace_mod.UpdateTrace"] = None):
+    """The closure every worker task is (ASGD and ASAGA, ``run`` and
+    ``run_sync``): ``dispatch()`` moves what the step needs to the worker's
+    chip and dispatches the step, returning its outputs, gradient first
+    (``task.dispatch``); then the executor thread waits for the gradient
+    (``task.device_wait``; completion only, the data stays in HBM).  A
+    sampled update's ``task.inbox`` was begun by the submitter and ends
+    here, on entry; only the first copy of the task to run finds it open
+    and records the task stages (the engine itself knows nothing of
+    tracing: a retry or a speculative copy runs this same closure).
+
+    The injected delay models a slow *machine*: only the first body to run
+    it sleeps -- a speculative copy or a replacement executor is a
+    different (healthy) host path and must bypass the straggler.  It lies
+    outside the stages, in ``compute``'s self time."""
+    delay_fired = threading.Event()
+
+    def fn():
+        mine = ut if ut is not None and ut.end(trace_mod.TASK_INBOX) else None
+        if delay_ms > 0 and not delay_fired.is_set():
+            delay_fired.set()
+            time.sleep(delay_ms / 1e3)
+        with trace_mod.span(trace_mod.TASK_DISPATCH, mine):
+            out = dispatch()
+        with trace_mod.span(trace_mod.TASK_DEVICE_WAIT, mine):
+            out[0].block_until_ready()
+        return out
+
+    return fn
 
 
 class RunInstruments:
@@ -72,6 +176,23 @@ class RunInstruments:
         self.workers_lost = 0
         self.shards_moved = 0
         self._lock = threading.Lock()
+        # the engine's always-on counters (extras(), TrainResult): plain
+        # sums and integers, each written by ONE thread.  The updater and
+        # the submitter are the two serial resources every update passes.
+        self.updater_clock = BusyClock()
+        self.submitter_clock = BusyClock()
+        #: the part of the updater's busy time spent inside its apply
+        #: dispatches, which block while the device's queue is full
+        self.updater_apply_ns = 0
+        self.submit_empty_polls = 0   # submitter turns that found no cohort
+        self.drains = 0               # updater wakes that merged something
+        self.drain_items_max = 0
+        #: staleness -> count over EVERY merged result (not a sample)
+        self.staleness_hist: Dict[int, int] = {}
+        #: accepted updates behind every trajectory entry
+        self.snapshot_updates: List[int] = [0]
+        self.monitor = None           # the run's HeartbeatMonitor, if any
+        self._compiles0 = compiles_so_far()
 
         event_log = getattr(cfg, "event_log", None)
         if event_log:
@@ -164,45 +285,83 @@ class RunInstruments:
         # thread via _GlobalTraceFold / LiveStateListener
         self.bus.post(trace_mod.span_event(span, self.now_ms()))
 
-    def on_gradient_merged(
-        self,
-        worker_id: int,
-        staleness: int,
-        accepted: bool,
-        iteration: int,
-        batch_size: int = 0,
-        task_ms: float = 0.0,
-        queue_ms: float = 0.0,
-        apply_ms: float = 0.0,
-    ) -> None:
+    def start_updates(self, cohort) -> Dict[int, "trace_mod.UpdateTrace"]:
+        """The sampling decision, at submit: the handles of the cohort's
+        sampled updates by worker id.  Empty when tracing is off."""
+        if self.tracer is None:
+            return {}
+        out = {}
+        for wid in cohort:
+            ut = self.tracer.start_update(wid)
+            if ut is not None:
+                out[wid] = ut
+        return out
+
+    @staticmethod
+    def begin_compute(uts, model_version: int) -> None:
+        """The cohort's sampled updates leave the submitter: ``compute``
+        starts and, in the same instant, its first child ``task.inbox``
+        (ended by the task closure on entry, :func:`worker_task`)."""
+        for ut in uts.values():
+            ut.ctx.model_version = model_version
+            ut.begin(trace_mod.COMPUTE)
+            ut.begin(trace_mod.TASK_INBOX)
+
+    def on_run_start(self) -> None:
+        """The run's clock starts (after the solver's warm-up): so do the
+        two threads' clocks and the count of compilations."""
+        self.updater_clock.start()
+        self.submitter_clock.start()
+        self._compiles0 = compiles_so_far()
+
+    def on_drained(self, results) -> tuple:
+        """A drain reached the updater: count it and, in a traced run,
+        close ``result.queue`` and ``compute`` of its sampled updates and
+        return their handles.  Untraced: two counters and ``()``."""
+        self.drains += 1
+        if len(results) > self.drain_items_max:
+            self.drain_items_max = len(results)
+        if self.tracer is None:
+            return ()
+        uts = tuple(r.trace for r in results if r.trace is not None)
+        for ut in uts:
+            ut.end(trace_mod.RESULT_QUEUE)
+            ut.end(trace_mod.COMPUTE)
+        return uts
+
+    @staticmethod
+    def apply_attrs(merged) -> Dict["trace_mod.UpdateTrace", dict]:
+        """What the ``merge.apply`` span of a drain carries for each of its
+        sampled updates, from ``(result, accepted)`` pairs past the tau
+        filter.  Staleness in TIME is how old the worker's model basis is
+        at merge: since its submit."""
+        now = trace_mod.now_ms()
+        return {
+            res.trace: {
+                "staleness": int(res.staleness),
+                "staleness_ms": now - res.trace.born_ms,
+                "accepted": bool(accepted),
+            }
+            for res, accepted in merged if res.trace is not None
+        }
+
+    def on_gradient_merged(self, res, accepted: bool, iteration: int,
+                           task_ms: float = 0.0) -> None:
+        """One result (a ``PartialResult``) passed the tau filter, either
+        way.  Called by the updater after it has let go of its state lock;
+        no part of tracing (a sampled update's spans are recorded where
+        its stages happen).  ``task_ms`` (submit to drain) feeds the
+        metrics sink's ``task.ms`` column only."""
+        staleness = res.staleness
+        self.staleness_hist[staleness] = (
+            self.staleness_hist.get(staleness, 0) + 1
+        )
         self.bus.post(
             GradientMerged(
-                self.now_ms(), worker_id, staleness, accepted, iteration,
-                batch_size,
+                self.now_ms(), res.worker_id, staleness, accepted, iteration,
+                res.batch_size,
             )
         )
-        if self.tracer is not None:
-            ut = self.tracer.start_update(worker_id)
-            if ut is not None:
-                # the stages ran back-to-back and just ended: reconstruct
-                # their starts from the measured durations
-                ut.ctx.model_version = int(iteration)
-                t_now = trace_mod.now_ms()
-                t_apply0 = t_now - apply_ms
-                t_queue0 = t_apply0 - queue_ms
-                t_comp0 = t_queue0 - task_ms
-                if task_ms:
-                    ut.add(trace_mod.COMPUTE, t_comp0, t_queue0)
-                if queue_ms:
-                    ut.add(trace_mod.MERGE_QUEUE, t_queue0, t_apply0)
-                # staleness in TIME: how old the worker's model basis was
-                # at merge = its task wall-clock + result-queue wait
-                ut.add(
-                    trace_mod.MERGE_APPLY, t_apply0, t_now,
-                    staleness=int(staleness),
-                    staleness_ms=float(task_ms + queue_ms),
-                    accepted=bool(accepted),
-                )
         if self.metrics is not None:
             (self._c_accepted if accepted else self._c_dropped).inc()
             self._h_staleness.update(float(staleness))
@@ -211,6 +370,9 @@ class RunInstruments:
             el = time.monotonic() - self._t0
             if el > 0:
                 self._g_updates_per_sec.set(self._c_accepted.value / el)
+
+    def on_snapshot(self, accepted: int) -> None:
+        self.snapshot_updates.append(int(accepted))
 
     def on_worker_lost(self, worker_id: int, reason: str) -> None:
         with self._lock:
@@ -277,6 +439,35 @@ class RunInstruments:
             out["ui_port"] = self.ui.port
         return out
 
+    def engine_counters(self, task_retries: int,
+                        one_thread: bool = False) -> Dict[str, object]:
+        """The always-on counters, as scalars (every run's info line
+        carries them, traced or not).  Call after the fence:
+        ``compiles_in_run`` counts from :meth:`on_run_start` to here.
+        ``one_thread``: the synchronous drivers submit and drain on one
+        thread, whose clock is reported as the updater's."""
+        out: Dict[str, object] = {
+            "updater_busy_s": self.updater_clock.busy_ns * 1e-9,
+            "updater_wait_s": self.updater_clock.wait_ns * 1e-9,
+            "updater_apply_s": self.updater_apply_ns * 1e-9,
+        }
+        if not one_thread:
+            out.update({
+                "submitter_busy_s": self.submitter_clock.busy_ns * 1e-9,
+                "submitter_wait_s": self.submitter_clock.wait_ns * 1e-9,
+                "submit_empty_polls": self.submit_empty_polls,
+            })
+        out.update({
+            "drains": self.drains,
+            "drain_items_max": self.drain_items_max,
+            "task_retries": int(task_retries),
+            "compiles_in_run": compiles_so_far() - self._compiles0,
+        })
+        if self.monitor is not None:
+            out["host_stall_max_ms"] = self.monitor.stall_max_ms
+            out["host_stalls"] = self.monitor.stalls
+        return out
+
 
 def log_trajectory(path, trajectory, printer_freq: int = 1) -> None:
     """Write a bare trajectory as ModelSnapshot events (for runs that have no
@@ -335,7 +526,10 @@ class FaultTolerantRun:
             timeout_ms=heartbeat_timeout_ms,
             check_interval_s=check_interval_s,
             on_sibling_lost=scheduler.on_sibling_lost,
+            # only a traced run asks for the stacks of the next hold
+            dump_on_stall=instruments.tracer is not None,
         )
+        instruments.monitor = self.monitor
 
     def _on_lost(self, worker_id: int) -> None:
         with self._lock:

@@ -1,0 +1,430 @@
+"""The engine's spans and counters (ISSUE 23): one span call
+(``metrics.trace.span``), the sampling decision at submit, every stage
+recorded where it happens, work stages on the profiler's clock and wait
+stages not, the always-on counters and the two exact records on
+``TrainResult``."""
+
+import glob
+import os
+import time
+
+import numpy as np
+import pytest
+
+from asyncframework_tpu.engine.executor import ExecutorPool
+from asyncframework_tpu.engine.heartbeat import HeartbeatMonitor
+from asyncframework_tpu.metrics import trace
+from asyncframework_tpu.solvers import ASAGA, ASGD, SolverConfig
+from asyncframework_tpu.solvers.instrumentation import RunInstruments
+from asyncframework_tpu.utils.clock import SystemClock
+
+EPS_MS = 0.05  # float noise of epoch milliseconds, not a tolerance of order
+
+
+@pytest.fixture(scope="module")
+def problem():
+    rng = np.random.default_rng(7)
+    X = rng.normal(size=(2048, 16)).astype(np.float32)
+    X /= np.linalg.norm(X, axis=1, keepdims=True)
+    w = rng.normal(size=16).astype(np.float32)
+    return X, X @ w
+
+
+def _cfg(**kw):
+    base = dict(
+        num_workers=4, num_iterations=48, gamma=0.4, taw=2**31 - 1,
+        batch_rate=0.3, bucket_ratio=0.5, printer_freq=10, seed=3,
+        calibration_iters=8, run_timeout_s=60.0,
+    )
+    base.update(kw)
+    return SolverConfig(**base)
+
+
+def _run(solver_cls, mode, problem, **kw):
+    X, y = problem
+    solver = solver_cls(X, y, _cfg(**kw))
+    return getattr(solver, mode)()
+
+
+def _traces(log):
+    spans, _ = trace.load_trace_events(log)
+    return trace.build_traces(spans)
+
+
+# ------------------------------------------------------------ the span tree
+@pytest.mark.parametrize("solver_cls,mode", [
+    (ASGD, "run"), (ASAGA, "run"), (ASGD, "run_sync"), (ASAGA, "run_sync"),
+])
+def test_every_update_is_one_trace_whose_compute_has_four_children(
+        solver_cls, mode, problem, tmp_path):
+    log = tmp_path / "run.jsonl"
+    gamma = 0.4 if solver_cls is ASGD else 0.05
+    res = _run(solver_cls, mode, problem, trace_sample=1.0, gamma=gamma,
+               num_iterations=48 if mode == "run" else 12,
+               event_log=str(log))
+    traces = _traces(log)
+    complete = 0
+    for spans in traces.values():
+        by_stage = {}
+        for sp in spans:
+            by_stage.setdefault(sp.stage, []).append(sp)
+        if trace.MERGE_APPLY not in by_stage:
+            continue  # still in flight when the run stopped
+        complete += 1
+        (submit,) = by_stage[trace.SUBMIT]
+        (compute,) = by_stage[trace.COMPUTE]
+        assert submit.parent_id is None
+        assert compute.parent_id == submit.span_id
+        assert submit.batch >= 1
+        children = []
+        for st in trace.COMPUTE_CHILDREN:
+            (child,) = by_stage[st]
+            assert child.parent_id == compute.span_id, st
+            # inside the parent
+            assert child.start_ms >= compute.start_ms - EPS_MS, st
+            assert (child.start_ms + child.dur_ms
+                    <= compute.start_ms + compute.dur_ms + EPS_MS), st
+            children.append(child)
+        starts = [c.start_ms for c in children]
+        assert starts == sorted(starts)
+        # each child ends before the next begins: they cover, not overlap
+        for a, b in zip(children, children[1:]):
+            assert a.start_ms + a.dur_ms <= b.start_ms + EPS_MS
+        (apply_span,) = by_stage[trace.MERGE_APPLY]
+        assert apply_span.parent_id == compute.span_id
+        assert apply_span.staleness is not None
+        assert apply_span.accepted is not None
+        assert apply_span.batch is not None
+        # age of the model basis at merge: at least the task's own time
+        assert apply_span.staleness_ms >= compute.dur_ms - EPS_MS
+        if mode == "run":
+            (queue_span,) = by_stage[trace.MERGE_QUEUE]
+            assert queue_span.parent_id == compute.span_id
+            # merge.queue starts where compute ends
+            assert queue_span.start_ms >= (
+                compute.start_ms + compute.dur_ms - EPS_MS
+            )
+    assert complete >= res.accepted - 8  # all but the last in flight
+
+
+def test_a_drain_records_one_apply_span_per_update_with_the_batch(
+        problem, tmp_path):
+    """With ``drain_batch`` the updater applies several results in one
+    dispatch: every sampled update of the drain gets the drain's
+    ``merge.apply`` (same start and duration, ``batch`` = accepted in
+    it), never its duration divided by the batch."""
+    log = tmp_path / "drain.jsonl"
+    res = _run(ASGD, "run", problem, trace_sample=1.0, drain_batch=4,
+               num_workers=8, num_iterations=96, event_log=str(log))
+    spans, _ = trace.load_trace_events(log)
+    applies = [s for s in spans if s.stage == trace.MERGE_APPLY]
+    assert applies and all(1 <= s.batch <= 4 for s in applies if s.accepted)
+    assert res.extras["drain_items_max"] <= 4
+    by_start = {}
+    for s in applies:
+        by_start.setdefault((s.start_ms, s.dur_ms), []).append(s)
+    shared = [g for g in by_start.values() if len(g) > 1]
+    if res.extras["drain_items_max"] > 1:
+        assert shared  # a drain of several results shares one interval
+    for group in shared:
+        assert len({s.trace_id for s in group}) == len(group)
+        assert len({s.batch for s in group}) == 1
+
+
+# ------------------------------------------- the stages on the profiler's clock
+def _host_events(trace_dir):
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(
+        os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb")
+    )
+    names = set()
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            names.update(ev.name for ev in line.events
+                         if ev.name.startswith(trace.ANNOTATION_PREFIX))
+    return names
+
+
+@pytest.mark.parametrize("solver_cls", [ASGD, ASAGA])
+def test_work_stages_land_in_the_host_plane_and_wait_stages_do_not(
+        solver_cls, problem, tmp_path):
+    import jax
+
+    trace_dir = str(tmp_path / "xplane")
+    gamma = 0.4 if solver_cls is ASGD else 0.05
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(trace_dir, profiler_options=opts)
+    try:
+        # no recorder: the annotations need no handle
+        res = _run(solver_cls, "run", problem, trace_sample=None, gamma=gamma)
+    finally:
+        jax.profiler.stop_trace()
+    assert res.accepted == 48
+    names = _host_events(trace_dir)
+    for stage in (trace.SUBMIT, trace.TASK_DISPATCH, trace.MERGE_QUEUE,
+                  trace.MERGE_APPLY, trace.SNAPSHOT):
+        assert trace.ANNOTATION_PREFIX + stage in names, (stage, sorted(names))
+    for stage in (trace.TASK_INBOX, trace.TASK_DEVICE_WAIT,
+                  trace.RESULT_QUEUE, trace.COMPUTE):
+        assert trace.ANNOTATION_PREFIX + stage not in names, stage
+    assert names <= {
+        trace.ANNOTATION_PREFIX + st for st in trace.WORK_STAGES
+    }
+
+
+def test_span_records_nothing_and_reads_no_clock_without_a_handle(
+        monkeypatch):
+    def boom():
+        raise AssertionError("a span without a handle read the clock")
+
+    monkeypatch.setattr(trace, "now_ms", boom)
+    for stage in (trace.TASK_DISPATCH, trace.TASK_DEVICE_WAIT):
+        with trace.span(stage, None, batch=2) as sp:
+            pass
+        assert sp.start_ms == 0.0
+    with trace.span(trace.MERGE_APPLY, []):  # a drain with nothing sampled
+        pass
+
+
+# --------------------------------------------------------- tracing off is off
+def test_trace_sample_none_builds_no_recorder_and_no_handle(problem):
+    inst = RunInstruments(_cfg(trace_sample=None), 4)
+    try:
+        assert inst.tracer is None
+        assert inst.start_updates([0, 1, 2, 3]) == {}
+    finally:
+        inst.close()
+    handles = []
+    real = RunInstruments.on_drained
+
+    def spy(self, results):
+        handles.extend(r.trace for r in results)
+        return real(self, results)
+
+    trace.reset_aggregator()
+    RunInstruments.on_drained = spy
+    try:
+        res = _run(ASGD, "run", problem, trace_sample=None)
+    finally:
+        RunInstruments.on_drained = real
+    assert res.accepted == 48 and len(handles) >= 48
+    assert all(h is None for h in handles)
+    assert trace.aggregator().snapshot()["spans"] == 0
+    # the counters are there all the same
+    for key in ("updater_busy_s", "updater_wait_s", "submitter_busy_s",
+                "submitter_wait_s", "submit_empty_polls", "drains",
+                "drain_items_max", "task_retries", "compiles_in_run",
+                "host_stall_max_ms", "host_stalls"):
+        assert isinstance(res.extras[key], (int, float)), key
+
+
+def test_sampling_falls_at_submit_once_per_interval(problem):
+    inst = RunInstruments(_cfg(trace_sample=0.25), 4)
+    try:
+        got = [sorted(inst.start_updates([0, 1])) for _ in range(8)]
+    finally:
+        inst.close()
+    # counter-based per worker: the first of every four, first included
+    assert got == [[0, 1], [], [], [], [0, 1], [], [], []]
+
+
+# --------------------------------------------------------------- the counters
+@pytest.mark.parametrize("solver_cls,mode", [
+    (ASGD, "run"), (ASAGA, "run"), (ASGD, "run_sync"),
+])
+def test_counters_add_up(solver_cls, mode, problem):
+    gamma = 0.4 if solver_cls is ASGD else 0.05
+    t0 = time.monotonic()
+    res = _run(solver_cls, mode, problem, gamma=gamma,
+               num_iterations=48 if mode == "run" else 12)
+    wall = time.monotonic() - t0
+    ex = res.extras
+    for thread in ("updater",) + (("submitter",) if mode == "run" else ()):
+        busy, wait = ex[thread + "_busy_s"], ex[thread + "_wait_s"]
+        assert busy >= 0 and wait >= 0
+        # a thread's busy and waiting time lie inside the call
+        assert busy + wait <= wall
+        assert busy + wait >= 0.5 * res.elapsed_s
+    if mode != "run":
+        assert "submitter_busy_s" not in ex
+    assert ex["drains"] >= res.accepted / max(1, ex["drain_items_max"])
+    assert ex["task_retries"] == 0
+    assert ex["compiles_in_run"] == 0  # the solver's warm-up came before
+    assert ex["host_stalls"] >= 0 and ex["host_stall_max_ms"] >= 0.0
+
+
+@pytest.mark.parametrize("ran_first", [False, True])
+def test_a_raised_task_is_counted_as_a_retry(ran_first, problem, tmp_path):
+    """``ran_first``: the first copy of the task runs the closure to its
+    end and then raises, so the retry enters the same closure again.  Only
+    the first copy to run records the task stages."""
+    X, y = problem
+    log = tmp_path / "retry.jsonl"
+    solver = ASGD(X, y, _cfg(heartbeat=False, trace_sample=1.0,
+                             event_log=str(log)))
+    real = solver._make_task
+    failed = []
+
+    def flaky(wid, *a, **kw):
+        fn = real(wid, *a, **kw)
+        if wid == 2 and not failed:
+            failed.append(wid)
+
+            def once():
+                if len(failed) == 1:
+                    failed.append("raised")
+                    if ran_first:
+                        fn()
+                    raise RuntimeError("injected task failure")
+                return fn()
+
+            return once
+        return fn
+
+    solver._make_task = flaky
+    res = solver.run()
+    assert res.accepted == 48
+    assert res.extras["task_retries"] == 1
+    for spans in _traces(log).values():
+        stages = [sp.stage for sp in spans]
+        for st in trace.COMPUTE_CHILDREN:
+            assert stages.count(st) <= 1, stages
+
+
+@pytest.mark.parametrize("solver_cls,taw", [(ASGD, 1), (ASAGA, 20)])
+def test_staleness_hist_counts_every_merged_result(solver_cls, taw, problem):
+    gamma = 0.4 if solver_cls is ASGD else 0.05
+    # ASAGA's filter (k - staleness <= taw) stops accepting for good once
+    # k passes taw: that run ends at its deadline, all the rest dropped
+    res = _run(solver_cls, "run", problem, taw=taw, gamma=gamma,
+               run_timeout_s=2.0)
+    assert res.dropped > 0  # the filter fired: both kinds are counted
+    assert sum(res.staleness_hist.values()) == res.accepted + res.dropped
+    assert list(res.staleness_hist) == sorted(res.staleness_hist)
+    if solver_cls is ASGD:
+        assert sum(n for s, n in res.staleness_hist.items()
+                   if s > taw) == res.dropped
+
+
+@pytest.mark.parametrize("drain_batch", [1, 4])
+def test_snapshot_updates_are_the_accepted_counts_behind_the_trajectory(
+        drain_batch, problem):
+    res = _run(ASGD, "run", problem, drain_batch=drain_batch, num_workers=8,
+               num_iterations=64, printer_freq=5)
+    ups = res.snapshot_updates
+    assert len(ups) == len(res.trajectory)
+    assert ups[0] == 0 and ups[-1] == res.accepted == 64
+    assert ups == sorted(ups)
+    if drain_batch == 1:
+        assert ups[1:-1] == [j * 5 + 1 for j in range(len(ups) - 2)]
+
+
+# ------------------------------------------------------------- the host gauge
+class _JumpingClock(SystemClock):
+    """A host that stands still: every clock read after ``jump()`` is 3 s
+    later than it would have been."""
+
+    def __init__(self):
+        self.offset_ms = 0.0
+
+    def now_ms(self):
+        return super().now_ms() + self.offset_ms
+
+
+def _monitor(clock, **kw):
+    pool = ExecutorPool(2, lambda *a: None, clock=clock)
+    mon = HeartbeatMonitor(
+        pool, lambda wid: None, timeout_ms=120_000.0,
+        check_interval_s=0.05, clock=clock, **kw,
+    )
+    return pool, mon
+
+
+def test_a_clock_that_jumps_three_seconds_is_a_host_stall():
+    clock = _JumpingClock()
+    pool, mon = _monitor(clock)
+    mon.start()
+    try:
+        time.sleep(0.2)
+        quiet = mon.stalls  # a loaded test host may already have stalled
+        clock.offset_ms = 3000.0
+        time.sleep(0.2)
+    finally:
+        mon.stop()
+        pool.shutdown()
+    assert 2900.0 <= mon.stall_max_ms <= 3000.0 + 150.0
+    assert mon.stalls >= quiet + 1
+
+
+def test_the_stall_dump_is_armed_only_with_a_recorder_and_cancelled_at_stop(
+        monkeypatch, problem):
+    import faulthandler
+
+    calls = []
+    monkeypatch.setattr(faulthandler, "dump_traceback_later",
+                        lambda *a, **kw: calls.append(("arm", a)))
+    monkeypatch.setattr(faulthandler, "cancel_dump_traceback_later",
+                        lambda: calls.append(("cancel",)))
+    res = _run(ASGD, "run", problem, trace_sample=None)
+    assert res.accepted == 48 and calls == []
+    res = _run(ASGD, "run", problem, trace_sample=0.5)
+    assert res.accepted == 48
+    assert calls[0] == ("arm", (1.0,)) and calls[-1] == ("cancel",)
+    assert calls.count(("cancel",)) == 1
+
+
+def test_the_stall_dump_has_one_owner_in_a_process(monkeypatch):
+    """``faulthandler``'s watchdog is process-wide: of two monitors that
+    ask for it, the first to arm it owns it, and only the owner cancels."""
+    import faulthandler
+
+    from asyncframework_tpu.engine import heartbeat
+
+    calls = []
+    monkeypatch.setattr(faulthandler, "dump_traceback_later",
+                        lambda *a, **kw: calls.append("arm"))
+    monkeypatch.setattr(faulthandler, "cancel_dump_traceback_later",
+                        lambda: calls.append("cancel"))
+    clock = SystemClock()
+    pool_a, first = _monitor(clock, dump_on_stall=True)
+    pool_b, second = _monitor(clock, dump_on_stall=True)
+    first.start()
+    try:
+        time.sleep(0.12)
+        assert heartbeat._dump_owner is first
+        second.start()
+        time.sleep(0.12)
+        second.stop()
+        assert "cancel" not in calls and heartbeat._dump_owner is first
+    finally:
+        first.stop()
+        second.stop()
+        pool_a.shutdown()
+        pool_b.shutdown()
+    assert calls.count("cancel") == 1 and heartbeat._dump_owner is None
+
+
+def test_the_monitors_own_scan_is_not_a_host_stall():
+    """How late a scan woke counts from the end of the last scan: a scan
+    that itself takes long (an executor replaced) is no stall."""
+    clock = SystemClock()
+    pool, mon = _monitor(clock)
+    real = mon.check_once
+
+    def slow_scan():
+        time.sleep(0.3)
+        return real()
+
+    mon.check_once = slow_scan
+    mon.start()
+    try:
+        time.sleep(0.9)
+    finally:
+        mon.stop()
+        pool.shutdown()
+    assert mon.stall_max_ms < 250.0

@@ -547,14 +547,20 @@ class TestRunSyncFailFast:
         t.start()
         deadline = time.monotonic() + 60
         killed = False
+        first_job = None
         while time.monotonic() < deadline and not killed:
             sched = getattr(solver, "scheduler", None)
             if sched is not None:
                 ex = sched.pool.executors.get(2)
-                # kill mid-task but only from round 1 on: the scheduler's
-                # FIRST job blocks inside run_job (first-iteration warm-up
-                # semantics) before the drain loop ever runs
-                if ex is not None and ex.busy and len(ex.metrics) >= 1:
+                task = ex.current_task if ex is not None else None
+                if task is not None and first_job is None:
+                    first_job = task.job_id
+                # kill mid-task but only from round 1 on (a later job than
+                # the first one seen): the scheduler's FIRST job blocks
+                # inside run_job (first-iteration warm-up semantics)
+                # before the drain loop ever runs
+                if (task is not None and ex.busy
+                        and task.job_id != first_job):
                     ex.kill()   # mid-task: its result will never report
                     killed = True
             time.sleep(0.01)

@@ -356,26 +356,72 @@ class TestTruncatedEventLog:
 # ---------------------------------------------- single-process solver tracing
 class TestSingleProcessTracing:
     def test_run_instruments_emits_lifecycle_spans(self, tmp_path):
-        from asyncframework_tpu.solvers.instrumentation import RunInstruments
+        """The hooks of one sampled update, driven by hand in the order the
+        engine's threads call them: the sampling decision falls at submit,
+        every span is recorded where its stage happens (no start time is
+        reconstructed), what ``merge.apply`` carries for the update goes
+        through the span call, and ``on_gradient_merged`` is no part of
+        tracing."""
+        from asyncframework_tpu.context import PartialResult
+        from asyncframework_tpu.solvers.instrumentation import (
+            RunInstruments,
+            worker_task,
+        )
 
         log = tmp_path / "sp.jsonl"
         cfg = SolverConfig(num_workers=2, trace_sample=1.0,
                            event_log=str(log))
         inst = RunInstruments(cfg, 2)
-        inst.on_gradient_merged(0, staleness=2, accepted=True, iteration=7,
-                                task_ms=3.0, queue_ms=1.0, apply_ms=0.5)
+        uts = inst.start_updates([0])           # submitter: at submit
+        (ut,) = uts.values()
+        with trace.span(trace.SUBMIT, uts.values(), batch=1):
+            inst.begin_compute(uts, 7)
+
+        class _Ready:
+            def block_until_ready(self):
+                time.sleep(0.004)
+
+        task = worker_task(lambda: (_Ready(),), 0.0, ut)
+        task()                                  # executor: the closure
+        task()                                  # a retry records nothing
+        ut.begin(trace.RESULT_QUEUE)            # executor: the handler
+        res = PartialResult(None, 2, 10, 0, ut)
+        drained = inst.on_drained((res,))       # updater
+        assert drained == (ut,)
+        with trace.span(trace.MERGE_QUEUE, drained):
+            pass
+        with trace.span(trace.MERGE_APPLY,
+                        inst.apply_attrs([(res, True)]), batch=3):
+            pass
+        inst.on_gradient_merged(res, accepted=True, iteration=9)
         inst.close()
         spans, _ = trace.load_trace_events(log)
-        stages = {s.stage for s in spans}
-        assert stages == {trace.COMPUTE, trace.MERGE_QUEUE,
-                          trace.MERGE_APPLY}
-        (apply_span,) = [s for s in spans if s.stage == trace.MERGE_APPLY]
+        by_stage = {s.stage: s for s in spans}
+        assert sorted(s.stage for s in spans) == sorted(
+            (trace.SUBMIT, trace.COMPUTE, *trace.COMPUTE_CHILDREN,
+             trace.MERGE_QUEUE, trace.MERGE_APPLY))
+        apply_span = by_stage[trace.MERGE_APPLY]
         assert apply_span.staleness == 2
-        assert apply_span.staleness_ms == pytest.approx(4.0)
         assert apply_span.accepted is True
-        assert apply_span.model_version == 7
-        # all three share one trace
+        assert apply_span.batch == 3
+        # staleness in time: since the submit, so at least the sleep
+        assert apply_span.staleness_ms >= 4.0
+        assert by_stage[trace.COMPUTE].dur_ms >= 4.0
+        # the version the worker read, stamped at submit
+        assert {s.model_version for s in spans} == {7}
+        # one trace, parents set: submit <- compute <- the rest
         assert len({s.trace_id for s in spans}) == 1
+        assert by_stage[trace.SUBMIT].parent_id is None
+        assert (by_stage[trace.COMPUTE].parent_id
+                == by_stage[trace.SUBMIT].span_id)
+        for st in (*trace.COMPUTE_CHILDREN, trace.MERGE_QUEUE,
+                   trace.MERGE_APPLY):
+            assert by_stage[st].parent_id == by_stage[trace.COMPUTE].span_id
+        # the first child starts in the instant its parent does
+        assert (by_stage[trace.TASK_INBOX].start_ms
+                - by_stage[trace.COMPUTE].start_ms) < 0.5
+        assert by_stage[trace.TASK_DEVICE_WAIT].dur_ms >= 4.0
+        assert inst.staleness_hist == {2: 1}
 
     def test_asgd_run_traced_end_to_end(self, tiny_problem, tmp_path):
         from asyncframework_tpu.solvers import ASGD
