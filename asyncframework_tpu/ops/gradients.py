@@ -13,11 +13,15 @@ per-sample JNI BLAS):
   squares the gradient is ``scalar * x`` with ``scalar = x . w - y``, so the
   history table stores one scalar per sample.
 
-TPU mapping: a whole shard's sampled mini-batch gradient is two matmuls --
-``r = X @ w - y`` then ``g = X^T @ (mask * r)`` -- which XLA fuses and tiles
-onto the MXU.  Sampling is a Bernoulli *mask* (static shapes; no dynamic
-gather), so a "sampled subset" costs one elementwise multiply instead of a
-shape-changing filter.
+TPU mapping: a whole shard's sampled mini-batch gradient is two products --
+``r = X @ w - y`` then ``g = X^T @ (mask * r)`` -- each one streaming read of
+the shard (XLA runs a matrix-VECTOR product as a multiply-reduce fusion on
+the vector unit at HBM bandwidth, about 755 GB/s of the v5e's 819; the MXU
+would round f32 operands to bf16).  Sampling is a Bernoulli *mask* (static
+shapes; no dynamic gather), so a "sampled subset" costs one elementwise
+multiply instead of a shape-changing filter -- and on the TPU the filter
+would be no saving at all: a dense shard is stored column-major there (rows
+minor, PERF.md section 3), so picking rows means relaying all of it.
 """
 
 from __future__ import annotations
@@ -48,10 +52,36 @@ def mm_f32(A: jax.Array, v: jax.Array) -> jax.Array:
     return jnp.matmul(A, v.astype(A.dtype), preferred_element_type=jnp.float32)
 
 
+#: rows a block of :func:`shard_matvec` is a multiple of: 16 lane tiles
+_ROW_BLOCK = 16 * 128
+
+
+def shard_matvec(X: jax.Array, w: jax.Array) -> jax.Array:
+    """``X w`` over a whole ``(n, d)`` shard -> ``(n,)`` f32, the ragged
+    tail (``n`` mod 2,048 rows) as a product of its own.
+
+    Row by row the same sums as ``mm_f32(X, w)``.  The split is for the TPU
+    compiler: it tiles the reduce fusion's output rows into windows of
+    128-row lane tiles, and where the shard's tile count has no small
+    divisor (253,125 rows are 1,978 = 2 x 23 x 43 tiles) it falls back to
+    one sublane group a window and runs at half the bandwidth (1.05 ms
+    against 0.53 ms for ``X^T v`` over the same bytes, v5e, PERF.md section
+    6, PR 24).  A main block of a multiple of 16 tiles has divisors at any
+    size; both slices start on a tile edge of the stored shard (rows are
+    minor there), so they are fused into the reads and nothing is copied.
+    Elsewhere the split costs 0.0-0.2% of the step.
+    """
+    n = X.shape[0]
+    k = n - n % _ROW_BLOCK
+    if k in (0, n):
+        return mm_f32(X, w)
+    return jnp.concatenate([mm_f32(X[:k], w), mm_f32(X[k:], w)])
+
+
 @jax.jit
 def least_squares_residual(X: jax.Array, y: jax.Array, w: jax.Array) -> jax.Array:
     """Per-sample scalar ``x_i . w - y_i`` (the ASAGA 'scalar' form)."""
-    return mm_f32(X, w) - y
+    return shard_matvec(X, w) - y
 
 
 @jax.jit
@@ -64,7 +94,7 @@ def least_squares_grad_sum(
     reference's sample-then-map-then-reduce with vector-add comOp.
     """
     with jax.named_scope("residual"):
-        r = mm_f32(X, w) - y
+        r = shard_matvec(X, w) - y
     with jax.named_scope("grad"):
         return mm_f32(X.T, mask * r)
 
@@ -91,7 +121,7 @@ def logistic_grad_sum(
     ``grad_i = (sigmoid(x_i.w) - y_i) x_i``.
     """
     with jax.named_scope("residual"):
-        margin = mm_f32(X, w)
+        margin = shard_matvec(X, w)
         p = jax.nn.sigmoid(margin)
     with jax.named_scope("grad"):
         return mm_f32(X.T, mask * (p - y))
@@ -128,7 +158,7 @@ def saga_shard_step(
     (non-stale) results -- the reference's driver-side ScalarMap merge.
     """
     with jax.named_scope("residual"):
-        diff = mm_f32(X, w) - y
+        diff = shard_matvec(X, w) - y
     with jax.named_scope("grad"):
         g = mm_f32(X.T, mask * (diff - alpha))
     return g, diff

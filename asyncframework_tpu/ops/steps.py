@@ -23,9 +23,10 @@ Parity notes per builder:
   the ASAGA decomposition (``SparkASAGAThread.scala:199-213,369-380``) with
   the per-sample scalar history table resident in HBM, sharded by worker.
 - Every worker step and apply names its phases for the device trace with
-  ``jax.named_scope`` (``sample``, ``compact``, ``gather``, ``residual``,
-  ``grad``; ``apply``): an HLO op's ``op_name`` then says which phase it
-  belongs to when a trace is opened in XProf or Perfetto.  Metadata only:
+  ``jax.named_scope`` (``sample``, ``residual``, ``grad``; the sparse
+  steps also ``compact`` and ``gather``; ``apply``): an HLO op's
+  ``op_name`` then says which phase it belongs to when a trace is opened
+  in XProf or Perfetto.  Metadata only:
   the compiled program and its compile-cache key are unchanged, and the
   jitted functions keep their Python names (the benchmark matches
   ``jit_step``).
@@ -88,55 +89,68 @@ def make_pipelined_transfer(device) -> Tuple[Callable, Callable]:
     return stage, readback
 
 
+def _grad_sum_for(loss: str):
+    """The masked gradient sum ``(X, y, w, mask) -> g`` of a dense loss."""
+    if loss == "least_squares":
+        return least_squares_grad_sum
+    if loss == "logistic":
+        return logistic_grad_sum
+    raise ValueError(f"unknown loss {loss!r}")
+
+
+def _counts_rows(step, task_rows):
+    """Attach ``step.task_rows(n_rows)``: how many rows of an ``n_rows``
+    shard the step's products run over.  The solvers' flop accounting
+    (``solvers/base.py``) asks the step it runs, so the count cannot drift
+    from what the builder decided."""
+    step.task_rows = task_rows
+    return step
+
+
+def _dense_sampled_gradient(X, y, w, key, batch_rate, grad_sum):
+    """``(g_sum, new_key)``: the dense worker computation -- advance the
+    key chain, draw the Bernoulli(b) mask over the shard's rows, sum the
+    masked per-sample gradients over the WHOLE shard.  ONE definition,
+    used by the engine worker step AND the fused rounds -- the fused
+    path's sampling-parity claim depends on these staying bit-identical
+    (same discipline as :func:`_sparse_compacted_gradient`)."""
+    with jax.named_scope("sample"):
+        key, sub = jax.random.split(key)
+        mask = jax.random.bernoulli(
+            sub, batch_rate, (X.shape[0],)
+        ).astype(jnp.float32)
+    return grad_sum(X, y, w, mask), key
+
+
 def make_asgd_worker_step(batch_rate: float, loss: str = "least_squares"):
     """jit (X, y, w, key) -> (g_sum, new_key); mask drawn on device.
 
-    For ``batch_rate <= 0.5`` the sampled rows are **compacted** first
-    (``jnp.nonzero(size=...)`` -- static capacity = E[count] + 6 sigma, see
-    :func:`sparse_step_capacity`): the two matmuls then touch only ~b of
-    the shard instead of streaming all of it through a mask.  The full-shard
-    step is HBM-bandwidth-bound (an mnist8m shard is 1.6 GB bf16 read twice
-    per task), so at b=0.1 compaction cuts per-task traffic ~5x.  The
-    gradient is the reference's sampled-sum exactly, up to the vanishing
-    (~1e-9/step) chance of the draw exceeding capacity, where the excess
-    rows are dropped for that step.
+    The gradient is the reference's sampled sum exactly: two products over
+    the whole shard, ``r = X w - y`` (``gradients.shard_matvec``) then
+    ``X^T (mask * r)``, each one streaming read of the shard WHERE IT LIES,
+    at about 755 GB/s of the v5e's 819.  The TPU stores a dense
+    ``(n, d)`` shard whose ``d * itemsize`` is not a multiple of the 128-lane
+    tile (784, 2000: every recipe this repo has) column-major, rows minor,
+    so that no row is padded (PERF.md section 3, "how the shard is
+    stored").  Reading a sampled tenth of the rows is therefore not a tenth
+    of the traffic: a row gather first relays the whole shard (read and
+    written once, every step), and packing the sampled row ids costs a
+    serial scatter on top.  The compacted dense step this one replaced took
+    16.6 ms on a 1.0M x 784 bf16 shard where the two streaming reads take a
+    quarter of that (PERF.md section 6, PR 24); with this storage the
+    floor of any step is one read of the shard.  The sparse (padded-ELL)
+    step does compact: its gather saves real traffic.
     """
-    if loss == "least_squares":
-        grad_sum = least_squares_grad_sum
-    elif loss == "logistic":
-        grad_sum = logistic_grad_sum
-    else:
-        raise ValueError(f"unknown loss {loss!r}")
-
-    if batch_rate > 0.5:
-        # dense sampling: masking the full shard moves less data than a
-        # near-full gather copy would
-        @jax.jit
-        def step(X, y, w, key):
-            with jax.named_scope("sample"):
-                key, sub = jax.random.split(key)
-                mask = jax.random.bernoulli(
-                    sub, batch_rate, (X.shape[0],)
-                ).astype(jnp.float32)
-            return grad_sum(X, y, w, mask), key
-
-        return step
+    grad_sum = _grad_sum_for(loss)
 
     @jax.jit
     def step(X, y, w, key):
-        n_rows = X.shape[0]  # static at trace time
-        cap = sparse_step_capacity(batch_rate, n_rows)
-        with jax.named_scope("sample"):
-            key, sub = jax.random.split(key)
-            mask = jax.random.bernoulli(sub, batch_rate, (n_rows,))
-        with jax.named_scope("compact"):
-            (idx,) = jnp.nonzero(mask, size=cap, fill_value=0)
-            valid = (jnp.arange(cap) < jnp.sum(mask)).astype(jnp.float32)
-        with jax.named_scope("gather"):
-            Xs, ys = X[idx], y[idx]
-        return grad_sum(Xs, ys, w, valid), key
+        return _dense_sampled_gradient(X, y, w, key, batch_rate, grad_sum)
 
-    return _prof.wrap_dispatch(step, "kernel.dispatch", "asgd_worker_step")
+    return _counts_rows(
+        _prof.wrap_dispatch(step, "kernel.dispatch", "asgd_worker_step"),
+        lambda n_rows: n_rows,
+    )
 
 
 def make_asgd_apply(gamma: float, batch_rate: float, n: int, num_workers: int):
@@ -195,7 +209,10 @@ def make_saga_worker_step(batch_rate: float):
             g = mm_f32(X.T, mask * (diff - alpha))
         return g, diff, mask, key
 
-    return _prof.wrap_dispatch(step, "kernel.dispatch", "saga_worker_step")
+    return _counts_rows(
+        _prof.wrap_dispatch(step, "kernel.dispatch", "saga_worker_step"),
+        lambda n_rows: n_rows,
+    )
 
 
 def make_saga_apply(
@@ -439,17 +456,9 @@ def make_mesh_asgd_worker_step(
     single-device step runs on its rows; ``lax.psum`` folds the partials
     (on this rig's CPU backend the all-reduce is a sequential
     device-order fold -- the oracle tests/test_meshgrad.py pins bit-for-
-    bit).  The mesh path always uses the masked full-block compute: the
-    single-device step's sparse-compaction shortcut would need a
-    per-device capacity draw and buys nothing once the rows are already
-    split P ways.
+    bit).
     """
-    if loss == "least_squares":
-        grad_sum = least_squares_grad_sum
-    elif loss == "logistic":
-        grad_sum = logistic_grad_sum
-    else:
-        raise ValueError(f"unknown loss {loss!r}")
+    grad_sum = _grad_sum_for(loss)
     from jax.sharding import PartitionSpec as P
 
     from asyncframework_tpu.parallel.mesh import resolve_shard_map
@@ -594,7 +603,9 @@ def make_sparse_asgd_worker_step(batch_rate: float, d: int):
         )
         return g, key
 
-    return step
+    return _counts_rows(
+        step, lambda n_rows: sparse_step_capacity(batch_rate, n_rows)
+    )
 
 
 def _sparse_saga_compacted(cols, vals, y, w, alpha, sub, batch_rate,
@@ -657,7 +668,9 @@ def make_sparse_saga_worker_step(batch_rate: float, d: int):
         )
         return g, diff_sel, idx, valid, c_sel, v_sel, key
 
-    return step
+    return _counts_rows(
+        step, lambda n_rows: sparse_step_capacity(batch_rate, n_rows)
+    )
 
 
 def make_sparse_saga_commit():
@@ -738,12 +751,7 @@ def make_fused_asgd_rounds(
     device (the PS chip); per-worker PRNG chains ride in ``keys`` exactly
     as the engine keeps them, so sampling parity per worker is preserved.
     """
-    if loss == "least_squares":
-        grad_sum = least_squares_grad_sum
-    elif loss == "logistic":
-        grad_sum = logistic_grad_sum
-    else:
-        raise ValueError(f"unknown loss {loss!r}")
+    grad_sum = _grad_sum_for(loss)
     nw = len(shards)
     par_recs = batch_rate * n / nw
     sp_grad_sum = None
@@ -758,31 +766,16 @@ def make_fused_asgd_rounds(
         sp_grad_sum = make_sparse_grad_sum(sparse_d)
 
     def one_gradient(shard, w, key):
-        key, sub = jax.random.split(key)
+        # the SAME cores the engine worker steps run
         if sparse_d is not None:
-            # the SAME compacted core the engine worker step runs
+            key, sub = jax.random.split(key)
             cols, vals, y = shard
             g = _sparse_compacted_gradient(
                 cols, vals, y, w, sub, batch_rate, sp_grad_sum
             )
             return g, key
         X, y = shard
-        n_rows = X.shape[0]
-        if batch_rate > 0.5:
-            with jax.named_scope("sample"):
-                mask = jax.random.bernoulli(
-                    sub, batch_rate, (n_rows,)
-                ).astype(jnp.float32)
-            return grad_sum(X, y, w, mask), key
-        cap = sparse_step_capacity(batch_rate, n_rows)
-        with jax.named_scope("sample"):
-            mask = jax.random.bernoulli(sub, batch_rate, (n_rows,))
-        with jax.named_scope("compact"):
-            (idx,) = jnp.nonzero(mask, size=cap, fill_value=0)
-            valid = (jnp.arange(cap) < jnp.sum(mask)).astype(jnp.float32)
-        with jax.named_scope("gather"):
-            Xs, ys = X[idx], y[idx]
-        return grad_sum(Xs, ys, w, valid), key
+        return _dense_sampled_gradient(X, y, w, key, batch_rate, grad_sum)
 
     def round_fn(carry, _x):
         w, k, keys = carry

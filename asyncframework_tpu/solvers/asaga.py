@@ -88,7 +88,6 @@ class ASAGA(FlopsAccountingMixin):
             self._step = steps.make_sparse_saga_worker_step(
                 config.batch_rate, self.ds.d
             )
-            self._sparse_compact = True  # flops = compacted rows, not n_p
             self._commit = steps.make_sparse_saga_commit()
             self._table_delta = steps.make_sparse_table_delta(self.ds.d)
             self._eval = steps.make_sparse_trajectory_loss_eval()
@@ -96,6 +95,7 @@ class ASAGA(FlopsAccountingMixin):
             self._step = steps.make_saga_worker_step(config.batch_rate)
             self._table_delta = steps.make_saga_table_delta()
             self._eval = steps.make_trajectory_loss_eval("least_squares")
+        self._task_rows = self._step.task_rows  # flop accounting
         self._apply = steps.make_saga_apply(
             config.gamma, config.batch_rate, self.ds.n, config.num_workers
         )

@@ -92,15 +92,13 @@ class ASGD(FlopsAccountingMixin):
             self._step = steps.make_sparse_asgd_worker_step(
                 config.batch_rate, self.ds.d
             )
-            self._sparse_compact = True  # flops = compacted rows, not n_p
             self._eval = steps.make_sparse_trajectory_loss_eval()
         else:
             self._step = steps.make_asgd_worker_step(
                 config.batch_rate, config.loss
             )
-            # flops accounting mirrors the step's row compaction gate
-            self._dense_compact = config.batch_rate <= 0.5
             self._eval = steps.make_trajectory_loss_eval(config.loss)
+        self._task_rows = self._step.task_rows  # flop accounting
         self._apply = steps.make_asgd_apply(
             config.gamma, config.batch_rate, self.ds.n, config.num_workers
         )

@@ -182,28 +182,26 @@ def run_fused_plan(make_runner, carry, total_rounds: int, nw: int,
 class FlopsAccountingMixin:
     """Shared counted-flops accounting for the async solvers.
 
-    Hosts expect ``self._recovery`` (shard view), ``self._sparse`` and
-    ``self.ds`` -- both ASGD and ASAGA provide them.  One implementation so
-    a flop-model change can never make the two solvers disagree.
+    Hosts expect ``self._recovery`` (shard view), ``self._sparse``,
+    ``self.ds`` and ``self._task_rows`` (their worker step's ``task_rows``,
+    read once where the step is built) -- both ASGD and ASAGA provide
+    them.  One implementation so a flop-model change can never make the two
+    solvers disagree.
     """
 
     def _task_flops(self, wid: int) -> float:
         """Counted flops of one worker gradient (utils/flops.py model);
-        cached per worker -- re-homed shards keep their shapes.  A solver
-        whose sparse step compacts masked rows (ASGD) sets
-        ``_sparse_compact`` so only the compacted rows count."""
+        cached per worker -- re-homed shards keep their shapes.  The rows
+        that count are the step's own answer (``task_rows``, set by its
+        builder in ops/steps.py): the whole shard for a dense step, the
+        compacted capacity for a sparse one."""
         cache = self.__dict__.setdefault("_flops_cache", {})
         cached = cache.get(wid)
         if cached is None:
             from asyncframework_tpu.utils import flops as _fl
 
             shard = self._recovery.shard(wid)
-            rows = shard.size
-            if getattr(self, "_sparse_compact" if self._sparse
-                       else "_dense_compact", False):
-                from asyncframework_tpu.ops.steps import sparse_step_capacity
-
-                rows = sparse_step_capacity(self.cfg.batch_rate, shard.size)
+            rows = self._task_rows(shard.size)
             cached = (
                 _fl.sparse_task_flops(rows, shard.cols.shape[1])
                 if self._sparse

@@ -336,30 +336,32 @@ class TestBF16AndFlops:
         assert all(ds.shard(w).X.dtype == jnp.bfloat16 for w in range(8))
 
     def test_flops_counted_async(self, devices8, problem):
-        from asyncframework_tpu.ops.steps import sparse_step_capacity
         from asyncframework_tpu.utils import flops as fl
 
         X, y, _ = problem
         cfg = small_cfg(num_iterations=50)
-        res = ASGD(X, y, cfg, devices=devices8).run()
-        # b=0.3 <= 0.5: the step compacts sampled rows, so the flop model
-        # counts the static capacity, not the full shard
-        cap = sparse_step_capacity(cfg.batch_rate, X.shape[0] // 8)
-        per_task = fl.dense_task_flops(cap, X.shape[1])
+        solver = ASGD(X, y, cfg, devices=devices8)
+        res = solver.run()
+        # the rows that count are the step's own answer: the dense step's
+        # two products run over the whole shard at any batch_rate
+        rows = solver._task_rows(X.shape[0] // 8)
+        assert rows == X.shape[0] // 8
+        per_task = fl.dense_task_flops(rows, X.shape[1])
         # every merged gradient (accepted or dropped) was computed
         assert res.total_flops >= (res.accepted + res.dropped) * per_task
         # and no more than the number of submitted rounds could produce
         assert res.total_flops <= res.rounds * 8 * per_task * 1.01 + per_task
 
     def test_flops_counted_sync(self, devices8, problem):
-        from asyncframework_tpu.ops.steps import sparse_step_capacity
         from asyncframework_tpu.utils import flops as fl
 
         X, y, _ = problem
         cfg = small_cfg(num_iterations=20)
-        res = ASGD(X, y, cfg, devices=devices8).run_sync()
-        cap = sparse_step_capacity(cfg.batch_rate, X.shape[0] // 8)
-        per_task = fl.dense_task_flops(cap, X.shape[1])
+        solver = ASGD(X, y, cfg, devices=devices8)
+        res = solver.run_sync()
+        per_task = fl.dense_task_flops(
+            solver._task_rows(X.shape[0] // 8), X.shape[1]
+        )
         assert res.total_flops == pytest.approx(20 * 8 * per_task, rel=0.01)
 
     def test_chip_peak_lookup(self):

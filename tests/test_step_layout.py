@@ -1,0 +1,133 @@
+"""The dense worker step reads the shard where it lies -- checked on the
+COMPILED program, not on a clock.
+
+The TPU stores a dense ``(n, d)`` shard whose ``d * itemsize`` is not a
+multiple of the 128-lane tile column-major (``{0,1}``: rows minor), so that
+no row is padded (PERF.md section 3, "how the shard is stored").  A step
+that gathers sampled rows makes XLA relay the whole shard to ``{1,0}`` first
+-- a read and a write of all of it, every step -- and one that packs the
+sampled row ids adds a serial scatter.  These tests compile the step for a
+v5e with the TPU compiler that is installed here (no chip is needed, nothing
+runs) and read the program: its shard parameter keeps the layout the device
+gave it, nothing copies, gathers or scatters, and it needs no temporary worth
+the name.
+
+All TPU compiles of the suite live in THIS file and describe the topology
+inside a fixture: one process at a time may load libtpu, and a worker that
+only collects the file must not.
+"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from asyncframework_tpu.ops import steps
+
+_INSTR = re.compile(
+    r"^\s*(?:ROOT )?%(?P<name>[\w.\-]+) = (?P<type>.*?) "
+    r"(?P<op>[a-z][a-z0-9\-]*)\((?P<operands>[^)]*)\)"
+)
+_MOVES_DATA = {"copy", "transpose", "gather", "scatter", "dynamic-slice",
+               "dynamic-update-slice", "sort"}
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no libtpu, or another process holds its lock
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile_step(one_chip, n, d, dtype, batch_rate, loss="least_squares"):
+    def spec(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    step = steps.make_asgd_worker_step(batch_rate, loss)
+    return step.lower(
+        spec((n, d), dtype), spec((n,), jnp.float32),
+        spec((d,), jnp.float32), spec((2,), jnp.uint32),
+    ).compile()
+
+
+def _instructions(hlo_text):
+    """(name, result type with layout, opcode, operand names) of every
+    instruction of every computation (fused bodies included)."""
+    out = []
+    for line in hlo_text.splitlines():
+        m = _INSTR.match(line)
+        if m:
+            operands = re.findall(r"%([\w.\-]+)", m.group("operands"))
+            out.append((m.group("name"), m.group("type"), m.group("op"),
+                        operands))
+    return out
+
+
+@pytest.mark.parametrize(
+    "n,d,dtype",
+    [
+        (40000, 784, jnp.bfloat16),   # the mnist8m cells' rows, stored bf16
+        (40000, 784, jnp.float32),    # the four-chip cell's
+        (8192, 2000, jnp.float32),    # epsilon's
+    ],
+    ids=["bf16-784", "f32-784", "f32-2000"],
+)
+def test_dense_step_reads_the_shard_in_its_stored_layout(
+    one_chip, no_compile_cache, n, d, dtype
+):
+    compiled = _compile_step(one_chip, n, d, dtype, batch_rate=0.1)
+    text = compiled.as_text()
+    instrs = _instructions(text)
+    prefix = {jnp.bfloat16: "bf16", jnp.float32: "f32"}[dtype]
+    shard, shard_t = f"{prefix}[{n},{d}]", f"{prefix}[{d},{n}]"
+
+    entry = text[text.index("ENTRY"):]
+    param0 = [
+        t for name, t, op, _ in _instructions(entry)
+        if op == "parameter" and t.startswith(shard)
+    ]
+    assert len(param0) == 1, entry[:2000]
+    # the device's own layout for this shape: rows minor (column-major),
+    # because 784 and 2000 are no multiples of the 128-lane tile
+    layout = re.match(re.escape(shard) + r"\{([\d,]+)", param0[0]).group(1)
+    assert layout == "0,1", (
+        f"the compiler now stores {shard} as {{{layout}}}: the byte model "
+        f"in make_asgd_worker_step's docstring and PERF.md section 3 "
+        f"rests on {{0,1}}; re-derive it"
+    )
+
+    whole = {}  # name -> type, of everything as large as the shard
+    for name, t, _op, _ in instrs:
+        if shard in t or shard_t in t:
+            whole[name] = t
+    for name, t in whole.items():
+        for m in re.finditer(
+            "(" + re.escape(shard) + "|" + re.escape(shard_t) + r")\{([\d,]+)",
+            t,
+        ):
+            want = layout if m.group(1) == shard else "1,0"
+            assert m.group(2) == want, (
+                f"%{name} holds the shard relaid: {t[:120]}"
+            )
+    for name, t, op, operands in instrs:
+        if op in _MOVES_DATA:
+            touched = [o for o in operands if o in whole]
+            assert name not in whole and not touched, (
+                f"%{name} = {op}(...) moves the whole shard "
+                f"({t[:100]}, operands {touched})"
+            )
+    # the step has no reason to pack row ids or to pick rows at all (the
+    # compaction was a custom fusion: only its op_name said "scatter-add")
+    assert not [i for i in instrs if i[2] in ("gather", "scatter")]
+    assert "scatter-add" not in text and "/gather" not in text
+
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 1 << 20, f"{temp} bytes of temporaries"
