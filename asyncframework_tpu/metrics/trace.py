@@ -53,6 +53,8 @@ black hole.  This module makes one update's life observable end to end:
                                   tau filter, cross-chip ``g`` copy)
   merge.apply      work updater   the stack/apply dispatch of a drain; compute
                                   ``batch`` = results accepted in it
+  merge.history    work updater   ASAGA, an accepted result: table     merge.apply
+                                  delta + history commit dispatched
   snapshot,        work updater   annotation only                      -
   checkpoint
   ================ ==== ========= ==================================== =======
@@ -111,6 +113,9 @@ PUSH_WAIT = "push.wait"
 PUSH_RTT = "push.rtt"
 MERGE_QUEUE = "merge.queue"
 MERGE_APPLY = "merge.apply"
+#: ASAGA's engine only: inside ``merge.apply``, the history path of an
+#: accepted update (table delta + commit dispatches)
+MERGE_HISTORY = "merge.history"
 
 # in-process engine stages (module docstring: the second table)
 SUBMIT = "submit"
@@ -124,17 +129,18 @@ CHECKPOINT = "checkpoint"
 
 STAGES = (PULL_WAIT, PULL_RTT, PIPELINE, SUBMIT, COMPUTE, TASK_INBOX,
           TASK_DISPATCH, TASK_DEVICE_WAIT, RESULT_QUEUE, PUSH_WAIT, PUSH_RTT,
-          MERGE_QUEUE, MERGE_APPLY)
+          MERGE_QUEUE, MERGE_APPLY, MERGE_HISTORY)
 #: engine stages in which a host thread WORKS: :func:`span` annotates
 #: these on the profiler's clock.  Everything else is a wait (or spans
 #: threads, like ``compute``) and is never annotated.
 WORK_STAGES = frozenset((SUBMIT, TASK_DISPATCH, TASK_MODEL_COPY, MERGE_QUEUE,
-                         MERGE_APPLY, SNAPSHOT, CHECKPOINT))
+                         MERGE_APPLY, MERGE_HISTORY, SNAPSHOT, CHECKPOINT))
 #: the four children that must cover ``compute``
 COMPUTE_CHILDREN = (TASK_INBOX, TASK_DISPATCH, TASK_DEVICE_WAIT,
                     RESULT_QUEUE)
 #: a span's parent, by stage (engine spans; the DCN plane's have none)
 PARENT = {COMPUTE: SUBMIT, MERGE_QUEUE: COMPUTE, MERGE_APPLY: COMPUTE,
+          MERGE_HISTORY: MERGE_APPLY,
           **{st: COMPUTE for st in COMPUTE_CHILDREN}}
 #: what a work stage is called in a profiler trace
 ANNOTATION_PREFIX = "async."

@@ -47,7 +47,13 @@ def mm_f32(A: jax.Array, v: jax.Array) -> jax.Array:
     2,000 shard) but rounds operands to bf16 for matrix-matrix products
     (e.g. the (n, S) trajectory evaluation).
     Casting ``v`` down to ``A.dtype`` (rather than promoting ``A`` up) is
-    what keeps an (n, d) bf16 shard from being materialized in f32.
+    what keeps an (n, d) bf16 shard from being materialized in f32.  One
+    product promotes ``A`` on purpose and does not come through here:
+    ASAGA's ``X^T (mask * (diff - alpha))`` over a dense shard
+    (``steps.make_saga_table_delta`` and the ``g`` of every dense ASAGA
+    step), whose f32 vector is what keeps ``alpha_bar`` the mean of the
+    history table (the compiler fuses that promotion into the read, so it
+    copies nothing either).
     """
     return jnp.matmul(A, v.astype(A.dtype), preferred_element_type=jnp.float32)
 
@@ -211,4 +217,5 @@ def saga_commit_history(
     is NOT donated: an in-flight worker task dispatched before this commit may
     still hold the old slice's handle (routine under async overlap).
     """
-    return jnp.where(mask > 0, diff, alpha)
+    with jax.named_scope("history.commit"):
+        return jnp.where(mask > 0, diff, alpha)

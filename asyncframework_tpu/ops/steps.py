@@ -194,6 +194,11 @@ def make_saga_worker_step(batch_rate: float):
 
     ``g = X^T (mask * (diff - alpha))`` is the history-corrected gradient sum;
     ``diff`` are candidate new history scalars (committed only on accept).
+    ``g`` keeps its vector in f32 and promotes the shard, exactly as
+    :func:`make_saga_table_delta` does and for its reason: while the slice
+    is unchanged between dispatch and accept, ``g`` IS the table's change,
+    on every backend and for every storage dtype, which is what lets the
+    sync drain, ``run_fused`` and the DCN plane take ``delta == g``.
     """
 
     @jax.jit
@@ -206,7 +211,7 @@ def make_saga_worker_step(batch_rate: float):
         with jax.named_scope("residual"):
             diff = least_squares_residual(X, y, w)
         with jax.named_scope("grad"):
-            g = mm_f32(X.T, mask * (diff - alpha))
+            g = X.T @ (mask * (diff - alpha))
         return g, diff, mask, key
 
     return _counts_rows(
@@ -254,21 +259,39 @@ def make_saga_table_delta():
 
     The exact change the commit makes to the mean history gradient.  The
     reference advances ``alphaBar`` by the *worker-computed* ``g``, which was
-    built against the history as of dispatch time; when a worker is
-    re-dispatched before the updater committed its previous result (routine
-    here -- device turnaround is microseconds), ``alphaBar`` then drifts away
-    from the table's true mean and constant-step ASAGA destabilizes over long
-    runs (measured: diverges after ~500 accepted updates at overlap 0.5).
+    evaluated against the history at *dispatch* time; with asynchronous overlap
+    the same worker's earlier result may commit in between, so the reference's
+    ``alphaBar`` drifts from the true table mean (benign there: 6 s task
+    latencies make overlapped same-worker dispatch rare; on a TPU with fast
+    overlapped rounds the drift diverges constant-step ASAGA in ~500 updates).
     Recomputing the delta against the *current* table slice at commit time
     keeps the ``alpha_bar == mean(table)`` invariant exact at the cost of one
     extra matvec per accepted update.
+
+    The vector stays f32 and the SHARD is promoted (``X.T @ v``, not
+    ``mm_f32``, which would round ``v`` to a bf16 shard's dtype): the
+    commit writes the f32 ``diff`` into the table, so only a delta of f32
+    terms keeps ``alpha_bar`` the mean of what the table holds; rounding
+    ``mask * (diff - alpha_cur)`` to bf16 would put a relative 2^-9 error a
+    row into ``alpha_bar`` on every accept, which never leaves it.  That is
+    the guarantee, not a speed-up to take.  The promotion costs no copy:
+    the TPU compiler fuses the ``convert`` into the reduce that reads the
+    shard where it lies (``{0,1}``, PERF.md section 3), 0 bytes of
+    temporaries at ``bf16[1012500,784]``; ``tests/test_step_layout.py``
+    checks the compiled program.  The worker's ``g``
+    (:func:`make_saga_worker_step`) is the same product over the history at
+    dispatch time.
+
+    The jitted function is named for a device trace: its XLA module is
+    ``jit_saga_table_delta`` (the benchmark's ``history_device_ms``).
     """
 
     @jax.jit
-    def delta(X, diff, mask, alpha_cur):
-        return X.T @ (mask * (diff - alpha_cur))
+    def saga_table_delta(X, diff, mask, alpha_cur):
+        with jax.named_scope("history.delta"):
+            return X.T @ (mask * (diff - alpha_cur))
 
-    return delta
+    return saga_table_delta
 
 
 def make_asgd_apply_batch(
@@ -529,7 +552,7 @@ def make_mesh_saga_dcn_worker_step(mesh, axis: str = "dp"):
         with jax.named_scope("residual"):
             diff_l = (mm_f32(Xs_, w) - yl[li]) * vm
         with jax.named_scope("grad"):
-            g_l = mm_f32(Xs_.T, (diff_l - alpha_sel) * vm)
+            g_l = Xs_.T @ ((diff_l - alpha_sel) * vm)
         # each slot has exactly one owner: the psums add zeros to the
         # owner's value (slot-exact) and fold the per-device gradient
         # partials (device-order, like the ASGD mesh step)
@@ -697,10 +720,11 @@ def make_sparse_table_delta(d: int):
     grad_sum = make_sparse_grad_sum(d)
 
     @jax.jit
-    def delta(c_sel, v_sel, diff_sel, alpha_cur, idx):
-        return grad_sum(c_sel, v_sel, diff_sel - alpha_cur[idx])
+    def sparse_saga_table_delta(c_sel, v_sel, diff_sel, alpha_cur, idx):
+        with jax.named_scope("history.delta"):
+            return grad_sum(c_sel, v_sel, diff_sel - alpha_cur[idx])
 
-    return delta
+    return sparse_saga_table_delta
 
 
 def make_sparse_trajectory_loss_eval():
@@ -868,7 +892,7 @@ def make_fused_saga_rounds(
                 sub, batch_rate, (X.shape[0],)
             ).astype(jnp.float32)
             diff = least_squares_residual(X, y, w)
-            g = mm_f32(X.T, mask * (diff - alphas[i]))
+            g = X.T @ (mask * (diff - alphas[i]))
             gs.append(g)
             # commit the wave's candidate scalars into the slice
             new_alphas.append(jnp.where(mask > 0, diff, alphas[i]))
@@ -915,7 +939,7 @@ def make_saga_dcn_worker_step():
         with jax.named_scope("residual"):
             diff = (mm_f32(Xs, w) - y[idx]) * valid
         with jax.named_scope("grad"):
-            g = mm_f32(Xs.T, (diff - alpha_sel) * valid)
+            g = Xs.T @ ((diff - alpha_sel) * valid)
         return g, diff
 
     return _prof.wrap_dispatch(step, "kernel.dispatch", "saga_dcn_worker_step")

@@ -42,6 +42,7 @@ from asyncframework_tpu.engine.scheduler import ASYNC, JobScheduler
 from asyncframework_tpu.engine.speculation import SpeculationMonitor
 from asyncframework_tpu.engine.straggler import DelayModel
 from asyncframework_tpu.ops import steps
+from asyncframework_tpu.ops.gradients import make_sparse_grad_sum
 from asyncframework_tpu.solvers.base import (
     DelayCalibrator,
     FlopsAccountingMixin,
@@ -90,6 +91,8 @@ class ASAGA(FlopsAccountingMixin):
             )
             self._commit = steps.make_sparse_saga_commit()
             self._table_delta = steps.make_sparse_table_delta(self.ds.d)
+            # X^T alpha over a whole padded-ELL shard (_history_drift)
+            self._table_mean_grad = make_sparse_grad_sum(self.ds.d)
             self._eval = steps.make_sparse_trajectory_loss_eval()
         else:
             self._step = steps.make_saga_worker_step(config.batch_rate)
@@ -196,7 +199,10 @@ class ASAGA(FlopsAccountingMixin):
         )
 
         state = {"w": w, "ab": alpha_bar, "k": k0, "accepted": 0, "dropped": 0,
-                 "rounds": 0, "flops": 0.0}
+                 "rounds": 0, "flops": 0.0,
+                 # the updater's time inside the history path's dispatches
+                 # (a part of updater_apply_s)
+                 "history_ns": 0}
         state_lock = threading.Lock()
         stop = threading.Event()
         self._warm_hot_path()
@@ -236,8 +242,8 @@ class ASAGA(FlopsAccountingMixin):
                 # a sampled update (metrics/trace.py; () in an untraced
                 # run): its result.queue and compute end here; merge.queue
                 # is the state lock and the tau filter, merge.apply the
-                # accept path's dispatches (history commit, table delta,
-                # cross-chip copies, apply)
+                # accept path's dispatches: merge.history (table delta,
+                # history commit), cross-chip copies, apply
                 uts = inst.on_drained((res,))
                 g = res.data[0]
                 task_ms = waiting.on_finish(res.worker_id, now_ms())
@@ -262,7 +268,9 @@ class ASAGA(FlopsAccountingMixin):
                                     batch=int(accepted)):
                         if accepted:
                             shard = self._recovery.shard(res.worker_id)
-                            with hot_lock:
+                            t_hist = time.perf_counter_ns()
+                            with trace.span(trace.MERGE_HISTORY,
+                                            tuple(uts)), hot_lock:
                                 alpha_cur = alpha[res.worker_id]
                                 # a shard re-homed while this result was in
                                 # flight leaves the payload on the old
@@ -294,6 +302,9 @@ class ASAGA(FlopsAccountingMixin):
                                             alpha_cur, diff, mask
                                         )
                                     )
+                            state["history_ns"] += (
+                                time.perf_counter_ns() - t_hist
+                            )
                             if g.device != self.driver_device:
                                 g = jax.device_put(g, self.driver_device)
                             if delta.device != self.driver_device:
@@ -454,7 +465,9 @@ class ASAGA(FlopsAccountingMixin):
             waiting_time_ms=waiting.snapshot(),
             extras={
                 "alpha": {wid: np.asarray(a) for wid, a in alpha.items()},
-                "alpha_bar": np.asarray(state["ab"]),
+                "alpha_bar": np.asarray(final_ab),
+                "updater_history_s": state["history_ns"] * 1e-9,
+                "history_drift": self._history_drift(alpha, final_ab),
                 **run_extras,
             },
             snapshot_updates=inst.snapshot_updates,
@@ -772,6 +785,10 @@ class ASAGA(FlopsAccountingMixin):
             extras["executors_added"], extras["executors_removed"] = (
                 alloc.counts()
             )
+        # the final history state, as run() and run_fused() expose it
+        extras["alpha"] = {wid: np.asarray(a) for wid, a in alpha.items()}
+        extras["alpha_bar"] = np.asarray(alpha_bar)
+        extras["history_drift"] = self._history_drift(alpha, alpha_bar)
         inst.close(traj, cfg.printer_freq)
         return TrainResult(
             final_w=final_w,
@@ -792,6 +809,39 @@ class ASAGA(FlopsAccountingMixin):
     # ---------------------------------------------------------------- helpers
     def _shard_device(self, wid: int):
         return self.devices[wid % len(self.devices)]
+
+    def _history_drift(self, alpha: Dict[int, jax.Array], alpha_bar) -> float:
+        """``max |alpha_bar - sum_i alpha_i x_i / n|`` over ``max |sum_i y_i
+        x_i / n|``: how far the running mean history gradient is from the
+        table it summarises, in units of the mean gradient at ``w = 0``.
+        The exact table delta keeps it at f32 rounding (1e-7 to 1e-6); a
+        delta that rounds its vector reads 1e-4 and more, the reference's
+        ``delta == g`` grows with every overlapped dispatch.  The scale is
+        the data's, not ``max |alpha_bar|``: ``alpha_bar`` is a mean
+        gradient and goes to zero as the run converges, while what rounding
+        left in it early stays, so that ratio climbs to 1e-3 by itself.
+        Two passes over every shard on its device (dense: the table
+        delta's own executable, with the whole slice, then the labels, as
+        ``diff``: nothing new is compiled), summed on the host in float64.
+        Call after the run's clock has stopped."""
+        mean = np.zeros(self.ds.d, np.float64)
+        scale = np.zeros(self.ds.d, np.float64)
+        for wid, a in alpha.items():
+            shard = self._recovery.shard(wid)
+            if self._sparse:
+                parts = [self._table_mean_grad(shard.cols, shard.vals, v)
+                         for v in (a, shard.y)]
+            else:
+                one, zero = jnp.ones_like(a), jnp.zeros_like(a)
+                parts = [self._table_delta(shard.X, v, one, zero)
+                         for v in (a, shard.y)]
+            mean += np.asarray(parts[0], np.float64)
+            scale += np.asarray(parts[1], np.float64)
+        unit = float(np.max(np.abs(scale)))
+        if unit == 0.0:
+            return 0.0
+        ab = np.asarray(alpha_bar, np.float64)
+        return float(np.max(np.abs(ab * self.ds.n - mean))) / unit
 
     def _warm_hot_path(self, apply=None, sync: bool = False) -> None:
         """Compile this mode's hot-path executables before the trajectory

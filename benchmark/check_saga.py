@@ -1,0 +1,189 @@
+"""An ASAGA cell's history path against the plain reference, at the cell's size.
+
+    python3 benchmark/check_saga.py --workload <name> --seed <n> --seconds <s> [--round-delta]
+
+The comparison ``benchmark/run.py: verify`` does not make yet (it reads the
+final objective only): the cell's solver is built, warmed and run for
+``--seconds`` exactly as ``run.py`` does it, and then, outside any timed
+window, the state the run left is held to ``reference_saga`` and
+``reference`` (float32 ``jax.numpy`` at precision "highest", no program
+code):
+
+- ``history``: ``alpha_bar`` against ``reference_saga.history_mean`` of the
+  table the run left, over ``max |X^T y / n|`` (the mean gradient at ``w =
+  0``: the unit of the program's own ``history_drift``, which is reported
+  beside it).  Limit ``DRIFT_LIMIT``.
+- ``objective``: the trajectory's last value against
+  ``reference.objective`` of the final model, by ``run.py``'s own limits.
+- ``task``: one step + table delta + commit on one whole shard, seeded
+  ``w`` and history, a third of the slice moved on between dispatch and
+  accept, against ``reference_saga.task``.  Limit ``TASK_LIMIT``, over the
+  largest entry of each vector.
+
+The last stdout line is ``{"check_saga": {..., "correct": bool}}`` and the
+exit code is 0 only where ``correct``.  ``--round-delta`` is the negative
+control: the run is made with a table delta whose vector is rounded to
+bf16 (``lax.reduce_precision``, an op no compiler may drop), which has to
+come out as NOT correct.  A ``benchmark`` PR can call :func:`compare` from
+``verify``; until then the builder runs this file on the chip (PERF.md
+section 6, PR 25).
+"""
+
+import argparse
+import dataclasses
+import json
+import os
+import sys
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(HERE)
+if sys.path and os.path.abspath(sys.path[0] or ".") == HERE:
+    sys.path[0] = ROOT  # run as a script: import the package, not siblings
+elif ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+import numpy as np  # noqa: E402
+
+from benchmark import manifest as manifest_mod  # noqa: E402
+from benchmark import plan as plan_mod, reference, reference_saga  # noqa: E402
+from benchmark import run as bench_run  # noqa: E402
+
+#: ``alpha_bar`` off the table's mean, in units of ``max |X^T y / n|``.  Set
+#: from two readings at 8,100,000 x 784 bf16 on the v5e (PR 25): the largest
+#: the program gave over fourteen seeds, 5.3e-7 (f32 sums of 1M terms in
+#: another order), and runs with the delta's vector rounded to bf16, 7.1e-6
+#: and 1.09e-5.
+DRIFT_LIMIT = 2e-6
+#: one task's ``g``, ``delta``, ``diff`` and committed slice off the
+#: reference's, over the largest entry: 1.2e-6 at most on a 1,012,500-row
+#: shard (PR 25); a vector rounded to bf16 reads 1e-3.
+TASK_LIMIT = 5e-6
+
+
+def _rel(got, want) -> float:
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
+
+
+def history(shards, res, n: int) -> dict:
+    alphas = [res.extras["alpha"][w] for w in range(len(shards))]
+    mean = reference_saga.history_mean(shards, alphas, n)
+    unit = float(np.max(np.abs(reference_saga.history_mean(
+        shards, [s.y for s in shards], n))))
+    ab = np.asarray(res.extras["alpha_bar"], np.float64)
+    err = float(np.max(np.abs(ab - mean)))
+    return {"abs_err": err, "unit": unit, "drift": err / unit,
+            "program_history_drift": res.extras.get("history_drift"),
+            "limit": DRIFT_LIMIT, "within": err <= DRIFT_LIMIT * unit}
+
+
+def objective(shards, res, d: int, loss: str) -> dict:
+    f0, f_final = res.trajectory[0][1], res.trajectory[-1][1]
+    f_ref = reference.objective(shards, res.final_w, d, loss)
+    off = abs(f_final - f_ref)
+    return {"trajectory": f_final, "reference": f_ref, "off_over_f0": off / f0,
+            "within": off <= (bench_run.FINAL_REL * f_ref
+                              + bench_run.FINAL_ABS_OF_F0 * f0)}
+
+
+def task(solver, shard, d: int, seed: int) -> dict:
+    import jax
+    import jax.numpy as jnp
+
+    from asyncframework_tpu.ops import steps
+
+    rows = int(shard.X.shape[0])
+    rs = np.random.default_rng(seed)
+    w = jnp.asarray(0.05 * rs.standard_normal(d), jnp.float32)
+    read = rs.standard_normal(rows)
+    a_read = jnp.asarray(read, jnp.float32)
+    a_cur = jnp.asarray(
+        np.where(rs.random(rows) < 0.3, rs.standard_normal(rows), read),
+        jnp.float32)
+    g, diff, mask, _key = solver._step(
+        shard.X, shard.y, w, a_read, jax.random.PRNGKey(seed % 1000))
+    delta = solver._table_delta(shard.X, diff, mask, a_cur)
+    diff_h = np.asarray(diff)  # the commit donates ``diff``
+    committed = steps.saga_commit_history(a_cur, diff, mask)
+    ref = reference_saga.task(shard, w, a_read, a_cur, np.asarray(mask))
+    out = {"rows": rows, "sampled": int(np.asarray(mask).sum()),
+           "g": _rel(g, ref["g"]), "delta": _rel(delta, ref["delta"]),
+           "diff": _rel(diff_h, ref["diff"]),
+           "committed": _rel(committed, ref["alpha"]), "limit": TASK_LIMIT}
+    out["within"] = max(out[k] for k in ("g", "delta", "diff", "committed")
+                        ) <= TASK_LIMIT
+    return out
+
+
+def compare(ds, solver, res, loss: str, seed: int) -> dict:
+    """The three comparisons on the state ``res`` left; ``correct`` is all
+    of them."""
+    shards = [ds.shard(w) for w in range(ds.num_workers)]
+    out = {"history": history(shards, res, ds.n),
+           "objective": objective(shards, res, ds.d, loss),
+           "task": task(solver, shards[seed % len(shards)], ds.d, seed)}
+    out["correct"] = all(part["within"] for part in out.values())
+    return out
+
+
+def _round_the_deltas_vector() -> None:
+    import jax
+
+    from asyncframework_tpu.ops import steps
+
+    def rounding_delta():
+        @jax.jit
+        def saga_table_delta(X, diff, mask, alpha_cur):
+            v = mask * (diff - alpha_cur)
+            return X.T @ jax.lax.reduce_precision(v, 8, 7)
+
+        return saga_table_delta
+
+    steps.make_saga_table_delta = rounding_delta
+
+
+def main(argv=None, manifest_path=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--seconds", type=float, required=True)
+    ap.add_argument("--round-delta", action="store_true")
+    args = ap.parse_args(argv)
+    man = manifest_mod.Manifest(manifest_path or manifest_mod.MANIFEST)
+    cell = man.workload(args.workload)
+    config = man.config(cell["config"])
+    plan = plan_mod.resolve(config, man.traffic(cell["traffic"]))
+    if plan["solver"] != "asaga" or config["kind"] != "dense":
+        raise ValueError(f"{args.workload}: no dense ASAGA cell")
+
+    from asyncframework_tpu.utils import devices as prog_devices
+
+    prog_devices.setup_compile_cache()
+    devs = bench_run._devices()
+    if args.round_delta:
+        _round_the_deltas_vector()
+    ds = bench_run.build_dataset(config, plan["num_workers"], devs, args.seed)
+
+    from asyncframework_tpu import solvers
+    from asyncframework_tpu.solvers.base import SolverConfig
+
+    cfg = SolverConfig(**plan_mod.solver_config_kwargs(
+        plan, args.seed, args.seconds, False
+    ))
+    solver = solvers.ASAGA(ds, None, cfg, devices=devs)
+    solver.cfg = dataclasses.replace(
+        cfg, num_iterations=2 * plan["num_workers"]
+    )
+    solver.run()  # the warm-up, as run.py makes it
+    solver.cfg = cfg
+    res = solver.run()
+    out = {"workload": args.workload, "seed": args.seed,
+           "device": devs[0].device_kind, "rounded_delta": args.round_delta,
+           "accepted": res.accepted, "elapsed_s": res.elapsed_s,
+           **compare(ds, solver, res, plan["loss"], args.seed)}
+    print(json.dumps({"check_saga": out}), flush=True)
+    return 0 if out["correct"] else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())

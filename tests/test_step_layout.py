@@ -1,4 +1,4 @@
-"""The dense worker step reads the shard where it lies -- checked on the
+"""The dense worker steps read the shard where it lies -- checked on the
 COMPILED program, not on a clock.
 
 The TPU stores a dense ``(n, d)`` shard whose ``d * itemsize`` is not a
@@ -10,7 +10,11 @@ sampled row ids adds a serial scatter.  These tests compile the step for a
 v5e with the TPU compiler that is installed here (no chip is needed, nothing
 runs) and read the program: its shard parameter keeps the layout the device
 gave it, nothing copies, gathers or scatters, and it needs no temporary worth
-the name.
+the name.  The same holds for every program that walks a whole shard on the
+main path: the ASGD step, the ASAGA step, and ASAGA's table delta.  The
+last two promote a bf16 shard to f32 on purpose (``X.T @ v`` with an f32
+vector, ``make_saga_table_delta``) and must do so inside the fusion that
+reads it, not into a 3.2 GB copy.
 
 All TPU compiles of the suite live in THIS file and describe the topology
 inside a fixture: one process at a time may load libtpu, and a worker that
@@ -47,15 +51,26 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compile_step(one_chip, n, d, dtype, batch_rate, loss="least_squares"):
+def _compile(one_chip, program, n, d, dtype, batch_rate=0.1):
+    """``program`` of the main path, compiled for one described v5e chip
+    over an ``(n, d)`` shard of ``dtype``."""
     def spec(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
-    step = steps.make_asgd_worker_step(batch_rate, loss)
-    return step.lower(
-        spec((n, d), dtype), spec((n,), jnp.float32),
-        spec((d,), jnp.float32), spec((2,), jnp.uint32),
-    ).compile()
+    X, rows, vec = spec((n, d), dtype), spec((n,), jnp.float32), spec(
+        (d,), jnp.float32)
+    key = spec((2,), jnp.uint32)
+    if program == "asgd-step":
+        lowered = steps.make_asgd_worker_step(batch_rate).lower(
+            X, rows, vec, key)
+    elif program == "saga-step":
+        lowered = steps.make_saga_worker_step(batch_rate).lower(
+            X, rows, vec, rows, key)
+    elif program == "saga-delta":
+        lowered = steps.make_saga_table_delta().lower(X, rows, rows, rows)
+    else:
+        raise ValueError(program)
+    return lowered.compile()
 
 
 def _instructions(hlo_text):
@@ -72,18 +87,23 @@ def _instructions(hlo_text):
 
 
 @pytest.mark.parametrize(
-    "n,d,dtype",
+    "program,n,d,dtype",
     [
-        (40000, 784, jnp.bfloat16),   # the mnist8m cells' rows, stored bf16
-        (40000, 784, jnp.float32),    # the four-chip cell's
-        (8192, 2000, jnp.float32),    # epsilon's
+        ("asgd-step", 40000, 784, jnp.bfloat16),  # the mnist8m cells' rows
+        ("asgd-step", 40000, 784, jnp.float32),   # the four-chip cell's
+        ("asgd-step", 8192, 2000, jnp.float32),   # epsilon's
+        ("saga-step", 40000, 784, jnp.bfloat16),  # mnist8m-asaga's
+        ("saga-step", 40000, 784, jnp.float32),
+        ("saga-delta", 40000, 784, jnp.bfloat16),
+        ("saga-delta", 40000, 784, jnp.float32),
     ],
-    ids=["bf16-784", "f32-784", "f32-2000"],
+    ids=["bf16-784", "f32-784", "f32-2000", "saga-step-bf16-784",
+         "saga-step-f32-784", "saga-delta-bf16-784", "saga-delta-f32-784"],
 )
 def test_dense_step_reads_the_shard_in_its_stored_layout(
-    one_chip, no_compile_cache, n, d, dtype
+    one_chip, no_compile_cache, program, n, d, dtype
 ):
-    compiled = _compile_step(one_chip, n, d, dtype, batch_rate=0.1)
+    compiled = _compile(one_chip, program, n, d, dtype)
     text = compiled.as_text()
     instrs = _instructions(text)
     prefix = {jnp.bfloat16: "bf16", jnp.float32: "f32"}[dtype]
