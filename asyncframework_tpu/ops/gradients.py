@@ -13,15 +13,27 @@ per-sample JNI BLAS):
   squares the gradient is ``scalar * x`` with ``scalar = x . w - y``, so the
   history table stores one scalar per sample.
 
-TPU mapping: a whole shard's sampled mini-batch gradient is two products --
-``r = X @ w - y`` then ``g = X^T @ (mask * r)`` -- each one streaming read of
-the shard (XLA runs a matrix-VECTOR product as a multiply-reduce fusion on
-the vector unit at HBM bandwidth, about 755 GB/s of the v5e's 819; the MXU
-would round f32 operands to bf16).  Sampling is a Bernoulli *mask* (static
+TPU mapping: a whole shard's sampled mini-batch gradient is ``r = X @ w - y``
+then ``g = X^T @ (mask * r)``.  Sampling is a Bernoulli *mask* (static
 shapes; no dynamic gather), so a "sampled subset" costs one elementwise
 multiply instead of a shape-changing filter -- and on the TPU the filter
-would be no saving at all: a dense shard is stored column-major there (rows
-minor, PERF.md section 3), so picking rows means relaying all of it.
+would be no saving at all: a dense shard whose ``d`` is no multiple of 128
+is stored column-major there (rows minor, PERF.md section 3), so picking
+rows means relaying all of it and no step reads less than the whole shard.
+
+The byte model: ONE read of the shard a step where :func:`dense_step_path`
+says ``"onepass"`` (a TPU, a column-major shard): the Pallas kernel
+``pallas_kernels.dense_onepass`` takes ``X.T`` (a free ``bitcast`` there)
+block by block and computes both products from the block in VMEM, at
+740-748 GB/s of the v5e's 819.  TWO reads elsewhere (``"two_products"``):
+each product is one XLA multiply-reduce fusion over the shard (755 GB/s on
+the v5e; the MXU would round f32 operands to bf16), and the second cannot
+start before ``r`` is complete.  That path is the CPU's, the one for
+lane-aligned widths (``d % 128 == 0``: the shard is stored row-major and
+``X.T`` would be a real transpose), and the oracle the kernel is tested
+against.  Callers that need a bare ``X w`` (the losses, the trajectory
+evaluation) and ASAGA's table delta (``steps.make_saga_table_delta``: one
+product, one read) stay XLA's.
 """
 
 from __future__ import annotations
@@ -84,10 +96,69 @@ def shard_matvec(X: jax.Array, w: jax.Array) -> jax.Array:
     return jnp.concatenate([mm_f32(X[:k], w), mm_f32(X[k:], w)])
 
 
-@jax.jit
-def least_squares_residual(X: jax.Array, y: jax.Array, w: jax.Array) -> jax.Array:
-    """Per-sample scalar ``x_i . w - y_i`` (the ASAGA 'scalar' form)."""
-    return shard_matvec(X, w) - y
+def _on_tpu() -> bool:
+    """Whether this process computes on a TPU.  A function of its own so
+    that a test which compiles for a DESCRIBED chip on the CPU
+    (``tests/test_step_layout.py``) can steer the choice from the test."""
+    return jax.default_backend() == "tpu"
+
+
+def _kernels():
+    """``ops/pallas_kernels.py``, imported where a TPU first asks for it:
+    a process without one never pays for ``jax.experimental.pallas`` (0.9 s
+    here), and one with one has it loaded already
+    (``utils/devices._preload_kernels``)."""
+    from asyncframework_tpu.ops import pallas_kernels
+
+    return pallas_kernels
+
+
+def dense_step_path(X) -> str:
+    """``"onepass"`` or ``"two_products"``: which program
+    :func:`dense_masked_grad` traces for the shard ``X`` (an array, a
+    tracer or a ``ShapeDtypeStruct``), from what can be observed when the
+    step is built -- the backend, the rank, the width and the dtype.
+
+    The one-pass kernel is right only where ``X.T`` is a ``bitcast``: on
+    the TPU, whose compiler stores an ``(n, d)`` array with ``d % 128 !=
+    0`` column-major (784, 2000, 64: ``{0,1}``) and one with ``d % 128 ==
+    0`` row-major (``tests/test_step_layout.py`` reads both off compiled
+    programs).  What the kernel itself takes (f32 or bf16, ``d`` a whole
+    number of sublane tiles, a block that fits VMEM) is
+    ``pallas_kernels.onepass_takes``.  On the v5e it won at every shape timed
+    (PERF.md section 6, PR 26: 1.0M and 253k x 784 bf16, 1.0M x 784 and
+    50k x 2,000 f32), so nothing else is asked.
+    """
+    if (_on_tpu() and len(X.shape) == 2 and X.shape[1] % 128 != 0
+            and _kernels().onepass_takes(X.shape[1], X.dtype)):
+        return "onepass"
+    return "two_products"
+
+
+def dense_masked_grad(X, y, w, mask, alpha=None, logistic: bool = False):
+    """``(g, diff)`` of a dense worker step over a whole shard: ``diff =
+    link(X w) - y`` (``link`` the identity, or the sigmoid with
+    ``logistic``) and ``g = X^T (mask * (diff [- alpha]))``.
+
+    THE definition behind both dense losses' gradient sums and the ASAGA
+    step, and the ONE place the program is chosen (:func:`dense_step_path`):
+    one read of the shard through the Pallas kernel, or the two XLA
+    products.  With ``alpha`` (ASAGA: ``diff`` are the candidate history
+    scalars) ``g`` keeps its f32 vector and promotes the shard on either
+    path, as ``steps.make_saga_table_delta`` does and for its reason;
+    without it the two-product path is ``mm_f32``'s, as ever.
+    """
+    if dense_step_path(X) == "onepass":
+        with jax.named_scope("grad"):
+            return _kernels().dense_onepass(
+                X, y, w, mask, alpha, logistic=logistic)
+    with jax.named_scope("residual"):
+        margin = shard_matvec(X, w)
+        diff = (jax.nn.sigmoid(margin) if logistic else margin) - y
+    with jax.named_scope("grad"):
+        if alpha is None:
+            return mm_f32(X.T, mask * diff), diff
+        return X.T @ (mask * (diff - alpha)), diff
 
 
 @jax.jit
@@ -99,10 +170,7 @@ def least_squares_grad_sum(
     ``mask`` is {0,1} (or weights) of shape ``(n,)``; equivalent to the
     reference's sample-then-map-then-reduce with vector-add comOp.
     """
-    with jax.named_scope("residual"):
-        r = shard_matvec(X, w) - y
-    with jax.named_scope("grad"):
-        return mm_f32(X.T, mask * r)
+    return dense_masked_grad(X, y, w, mask)[0]
 
 
 @jax.jit
@@ -126,11 +194,7 @@ def logistic_grad_sum(
     Parity: ``LogisticGradient`` (binary case) -- labels in {0,1};
     ``grad_i = (sigmoid(x_i.w) - y_i) x_i``.
     """
-    with jax.named_scope("residual"):
-        margin = shard_matvec(X, w)
-        p = jax.nn.sigmoid(margin)
-    with jax.named_scope("grad"):
-        return mm_f32(X.T, mask * (p - y))
+    return dense_masked_grad(X, y, w, mask, logistic=True)[0]
 
 
 @jax.jit
@@ -163,11 +227,7 @@ def saga_shard_step(
     (:func:`saga_commit_history`) issued by the updater only for *accepted*
     (non-stale) results -- the reference's driver-side ScalarMap merge.
     """
-    with jax.named_scope("residual"):
-        diff = shard_matvec(X, w) - y
-    with jax.named_scope("grad"):
-        g = mm_f32(X.T, mask * (diff - alpha))
-    return g, diff
+    return dense_masked_grad(X, y, w, mask, alpha=alpha)
 
 
 # ------------------------------------------------------------------ sparse

@@ -3,30 +3,43 @@
 The reference's compute hot loop bottoms out in native BLAS through JNI
 (``LeastSquaresGradient.compute`` -> ``BLAS.axpy/dot`` ->
 ``mllib-local/.../BLAS.scala:20-35`` netlib).  The TPU equivalent is mostly
-*just XLA* -- the fused sample+gradient jit already runs on the MXU.  This
-module is the layer below that for cases XLA's fusion does not cover:
+*just XLA*.  This module is the layer below that, for what XLA's fusions
+cannot do:
 
-- :func:`fused_masked_grad` -- one-pass tiled kernel for
-  ``g = X^T (mask * (X w - y))``: streams X through VMEM row-tiles, keeps
-  the residual entirely on-chip (never materialized in HBM), accumulates
-  ``g`` in a VMEM-resident f32 block across grid steps.  This is the ASGD
-  worker step's core contraction with the HBM round-trip for the
-  n-vector residual removed -- exactly the kind of fusion worth hand-
-  scheduling when ``n`` is millions of rows (mnist8m).
+- :func:`dense_onepass` -- the dense worker step's two products from ONE
+  read of the shard.  XLA runs ``r = X w - y`` and ``g = X^T (mask * r)``
+  as two multiply-reduce fusions, each at 755 GB/s of the v5e's 819, and
+  the second cannot start before ``r`` is complete: the shard crosses the
+  HBM bus twice a step.  The kernel walks ``X.T`` -- a free ``bitcast`` of a
+  shard the device stores column-major, so nothing is relaid -- in ``(d,
+  block)`` blocks and computes, without leaving VMEM, the margins of the
+  block's columns, the masked per-row scalar ``v`` and ``g``'s partial
+  sums.  The byte model: one read of the shard (1.59 GB of bf16 at 1.0M x
+  784: 2.14 ms at 740 GB/s against 4.24 ms), plus 4 bytes a row for each of
+  ``y``, ``mask`` and, in ASAGA's form, ``alpha`` in and ``diff`` out.
+  Both products run on the vector unit in f32, whatever the storage dtype
+  (Mosaic's default f32 ``dot`` would round the shard to bf16, and the MXU
+  forms that were timed beside it were no faster: the kernel is bound by
+  the read, PERF.md section 6, PR 26).  Where two reads remain: every path
+  ``gradients.dense_step_path`` sends to the two XLA products (the CPU,
+  lane-aligned widths), and ASAGA's table delta, a product of its own on
+  the accept path.
 - :func:`chunk_attention` -- block attention with local softmax stats for
   the long-context path: a flash-style forward tiled over (query block,
   key block) with the running (m, l, o) in VMEM scratch, returning the
   (o, m, l) triple so ``parallel/ring.py`` can merge ring steps with the
   cheap rescale (``ring_attention(..., block_kernel="pallas")``).
 - For rcv1-style sparse data the SURVEY-prescribed alternative (densify
-  per batch, then this kernel) lives in the data layer; a scatter/gather
-  CSR kernel is deliberately NOT attempted -- vector gather does not map
-  onto the VPU's strided units, padding to blocked-ELL densifies anyway.
+  per batch, then a dense kernel) lives in the data layer; a
+  scatter/gather CSR kernel is deliberately NOT attempted -- vector gather
+  does not map onto the VPU's strided units, padding to blocked-ELL
+  densifies anyway.
 
 ``interpret`` is an explicit argument everywhere: the CPU tests pass
 ``interpret=True``, every other caller gets the Mosaic-compiled kernel
-(``chip_smoke.py`` phase E compiles both at full shapes on the chip and
-checks them against the references below).
+(``chip_smoke.py`` phase E runs both at full shapes on the chip against
+precision "highest"; ``tests/test_step_layout.py`` compiles the steps that
+hold :func:`dense_onepass` for a described v5e).
 """
 
 from __future__ import annotations
@@ -40,102 +53,206 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from asyncframework_tpu.ops.gradients import mm_f32
+
+#: lanes of a vector register: the unit the kernel's loops walk a block in
+_LANE = 128
+#: feature rows a loop body holds in registers at once (16 f32 registers)
+_ROW_GROUP = 128
+#: bytes of the shard one grid step takes (4,096 columns of a bf16
+#: 784-row block, 2,048 of an f32 one: the sizes timed on the v5e)
+_ONEPASS_BLOCK_BYTES = 13 << 19
 
 
-def _grad_kernel(x_ref, y_ref, m_ref, w_ref, g_ref):
-    """One row-tile step: r = mask*(X_t w - y_t); g += X_t^T r.
+#: most bytes one block may take (a very wide shard's 512 columns): two
+#: such buffers must fit VMEM with room
+_ONEPASS_MAX_BLOCK_BYTES = 16 << 20
 
-    Every vector rides as a lane-dense ROW -- ``w``/``g`` (1, d),
-    ``y``/``mask``/``r`` (1, T) -- so nothing is padded from one column
-    to 128 lanes, and both contractions are plain row-by-matrix products
-    with no transpose of the X tile.  Operands follow
-    ``ops.gradients.mm_f32``: the shard's storage dtype, f32 accumulation.
-    """
-    @pl.when(pl.program_id(0) == 0)
+
+def onepass_block(d: int, itemsize: int) -> int:
+    """Columns of ``X.T`` a grid step of :func:`dense_onepass` takes: a
+    multiple of 512 lanes, at least 512."""
+    return max(512, _ONEPASS_BLOCK_BYTES // (d * itemsize) // 512 * 512)
+
+
+def onepass_takes(d: int, dtype) -> bool:
+    """Whether :func:`dense_onepass` takes shards of width ``d`` and this
+    storage dtype: f32 or bf16, ``d`` a whole number of sublane tiles (8
+    rows of f32, 16 of bf16: the row groups are sliced on tile edges), and
+    a block that fits VMEM twice."""
+    dtype = jnp.dtype(dtype)
+    if dtype not in (jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16)):
+        return False
+    if d % (32 // dtype.itemsize) != 0:
+        return False
+    block_bytes = onepass_block(d, dtype.itemsize) * d * dtype.itemsize
+    return block_bytes <= _ONEPASS_MAX_BLOCK_BYTES
+
+
+def _row_groups(d: int):
+    return [(lo, min(_ROW_GROUP, d - lo)) for lo in range(0, d, _ROW_GROUP)]
+
+
+def _lanes(c):
+    return pl.ds(pl.multiple_of(c * _LANE, _LANE), _LANE)
+
+
+def _margins(xt_ref, wb_ref, r_ref, cols: int):
+    """``r = w . Xb``, one 128-lane chunk at a time: the products of a row
+    group are added register by register down to one ``(8, 128)``
+    register, so a chunk costs ONE cross-sublane reduction.  Columns
+    beyond ``cols`` in the last chunk give garbage the caller selects
+    out: a column's margin depends on that column alone."""
+    groups = _row_groups(xt_ref.shape[0])
+
+    def chunk(c, carry):
+        lanes = _lanes(c)
+        acc = None
+        for lo, size in groups:
+            p = (xt_ref[lo:lo + size, lanes].astype(jnp.float32)
+                 * wb_ref[lo:lo + size, :])
+            for k in range(0, size, 8):
+                acc = p[k:k + 8] if acc is None else acc + p[k:k + 8]
+        r_ref[:, lanes] = jnp.sum(acc, axis=0, keepdims=True)
+        return carry
+
+    jax.lax.fori_loop(0, pl.cdiv(cols, _LANE), chunk, 0)
+
+
+def _accumulate_grad(xt_ref, v_ref, g_ref, cols: int):
+    """``g += Xb . v``: a row group's ``(rows, 128)`` partial sums stay in
+    registers across the block's chunks, and lanes are reduced once, after
+    the call.  Columns at and beyond ``cols`` (the ragged tail of the last
+    block) are not read, but in the chunk that straddles ``cols``, where
+    they are selected out of ``Xb``: ``0 * NaN`` is ``NaN``."""
+    n_full, tail = divmod(cols, _LANE)
+    for lo, size in _row_groups(xt_ref.shape[0]):
+        rows = slice(lo, lo + size)
+
+        def chunk(c, acc, rows=rows):
+            lanes = _lanes(c)
+            return acc + (xt_ref[rows, lanes].astype(jnp.float32)
+                          * v_ref[:, lanes])
+
+        acc = jax.lax.fori_loop(
+            0, n_full, chunk, jnp.zeros((size, _LANE), jnp.float32))
+        if tail:
+            lanes = slice(n_full * _LANE, (n_full + 1) * _LANE)
+            lane = jax.lax.broadcasted_iota(jnp.int32, (size, _LANE), 1)
+            x = jnp.where(lane < tail,
+                          xt_ref[rows, lanes].astype(jnp.float32), 0.0)
+            acc = acc + x * v_ref[:, lanes]
+        g_ref[rows, :] += acc
+
+
+def _onepass_kernel(*refs, n: int, block: int, logistic: bool, saga: bool):
+    """One grid step over ``Xb = X.T[:, i*block:(i+1)*block]``: the
+    margins of its columns, the masked per-row scalar, and ``g``'s partial
+    sums, all from the block in VMEM.  ``r`` and ``v`` live in ``(1,
+    block)`` scratch; ``g_ref`` is the ``(d, 128)`` output block every grid
+    step revisits."""
+    if saga:
+        xt_ref, wb_ref, y_ref, m_ref, a_ref, g_ref, diff_ref, r_ref, v_ref = refs
+    else:
+        xt_ref, wb_ref, y_ref, m_ref, g_ref, r_ref, v_ref = refs
+    i = pl.program_id(0)
+    last = pl.num_programs(0) - 1
+    rem = n - (pl.cdiv(n, block) - 1) * block  # columns of the last block
+
+    @pl.when(i == 0)
     def _():
         g_ref[:] = jnp.zeros_like(g_ref)
 
-    x = x_ref[:]                                     # (T, d)
-    r = jax.lax.dot_general(
-        w_ref[:].astype(x.dtype), x, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )                                                # (1, T)
-    r = (r - y_ref[:]) * m_ref[:]
-    g_ref[:] += jnp.dot(
-        r.astype(x.dtype), x, preferred_element_type=jnp.float32
-    )                                                # (1, d)
+    def body(cols: int):
+        _margins(xt_ref, wb_ref, r_ref, cols)
+        r = r_ref[:]
+        diff = (jax.nn.sigmoid(r) if logistic else r) - y_ref[:]
+        if saga:
+            diff_ref[:] = diff
+            diff = diff - a_ref[:]
+        v = m_ref[:] * diff
+        if cols < block:
+            # select, never multiply: what lies beyond n may be NaN
+            col = jax.lax.broadcasted_iota(jnp.int32, v.shape, 1)
+            v = jnp.where(col < cols, v, 0.0)
+        v_ref[:] = v
+        _accumulate_grad(xt_ref, v_ref, g_ref, cols)
+
+    if rem == block:
+        body(block)
+    else:
+        pl.when(i < last)(lambda: body(block))
+        pl.when(i == last)(lambda: body(rem))
 
 
-@functools.partial(jax.jit, static_argnames=("row_tile", "interpret"))
-def _fused_masked_grad_tiles(X, y2, m2, w2, row_tile: int, interpret: bool):
-    """The kernel over the first ``(n // row_tile) * row_tile`` rows of
-    ``X``: the grid stops short of the ragged tail, so ``X`` is read in
-    place -- never padded, never cast."""
-    n, d = X.shape
-    return pl.pallas_call(
-        _grad_kernel,
-        grid=(n // row_tile,),
-        in_specs=[
-            pl.BlockSpec((row_tile, d), lambda i: (i, 0)),
-            pl.BlockSpec((1, row_tile), lambda i: (0, i)),
-            pl.BlockSpec((1, row_tile), lambda i: (0, i)),
-            pl.BlockSpec((1, d), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, d), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, d), jnp.float32),
-        interpret=interpret,
-    )(X, y2, m2, w2)
+def dense_onepass(X, y, w, mask, alpha=None, *, logistic: bool = False,
+                  block: Optional[int] = None, interpret=False):
+    """``(g, diff)`` of one dense worker step from ONE read of the shard.
 
+    ``diff = link(X w) - y`` and ``g = X^T (mask * (diff [- alpha]))``,
+    ``link`` the identity or, with ``logistic``, the sigmoid; ``diff`` is
+    returned only with ``alpha`` (ASAGA's candidate scalars), else
+    ``None``.  ``X``: ``(n, d)`` f32 or bf16 with ``d`` a multiple of the
+    dtype's sublane tile, read in its storage dtype through ``X.T`` -- a
+    free ``bitcast`` where the device stores the shard column-major
+    (PERF.md section 3), which is the only place this is worth calling:
+    ``gradients.dense_step_path`` decides.  ``y``, ``mask``, ``alpha``:
+    ``(n,)``; ``w``: ``(d,)``.  Every vector and both accumulations are
+    f32 and both products run on the vector unit, so nothing is rounded,
+    neither an f32 shard nor a vector, whatever the shard's dtype; the
+    sums differ from two XLA products by their order alone.
 
-def fused_masked_grad(
-    X,
-    y,
-    w,
-    mask: Optional[jax.Array] = None,
-    row_tile: int = 256,
-    interpret: bool = False,
-):
-    """``g = X^T (mask * (X w - y))`` in one pass over ``X``.
-
-    ``X``: (n, d) f32 or bf16, read in its storage dtype; ``y``/``mask``:
-    (n,); ``w``: (d,).  Any shape is accepted without copying ``X``: the
-    feature dim rides as one full-width block (Mosaic pads it to lanes in
-    VMEM), the kernel covers the row tiles that fit, and the ragged tail
-    (fewer than ``row_tile`` rows) goes through the same contraction in
-    plain XLA.  ``row_tile`` is rounded down to a multiple of the
-    128-lane tile (the row tile is the lane dim of the y/mask blocks).
+    The grid walks ``X.T`` in ``(d, block)`` blocks; a block's columns
+    beyond ``n`` (the last one's, where ``n`` is no multiple of ``block``)
+    hold whatever the padding holds and are selected out inside the
+    kernel.  On the v5e this runs at 740-748 GB/s of the chip's 819
+    (bf16 and f32, 1.0M x 784; 700 at 253k rows), where each of the two XLA
+    fusions it replaces ran at 755 (PERF.md section 6, PR 26).
     """
-    X = jnp.asarray(X)
     n, d = X.shape
-    y = jnp.asarray(y, jnp.float32)
-    w = jnp.asarray(w, jnp.float32)
-    m = (
-        jnp.ones(n, jnp.float32)
-        if mask is None
-        else jnp.asarray(mask, jnp.float32)
+    f32 = jnp.float32
+    itemsize = jnp.dtype(X.dtype).itemsize
+    block = onepass_block(d, itemsize) if block is None else block
+    block = min(block, _LANE * pl.cdiv(n, _LANE))
+    saga = alpha is not None
+    vma = getattr(jax.typeof(X), "vma", None)
+    kw = {"vma": vma} if vma else {}
+    row = pl.BlockSpec((1, block), lambda i: (0, i))
+    resident = pl.BlockSpec((d, _LANE), lambda i: (0, 0))
+    # the kernel takes the (n,) vectors as (1, n) rows: a relayout of 4 MB
+    # each, 0.01 ms a step (1-D blocks reshaped INSIDE the kernel were
+    # timed too: 0.05 ms a vector).  The barrier keeps their producers out
+    # of that layout: fused into it, the Bernoulli mask's fusion ran at an
+    # eighth of its rate (0.17 ms a step; v5e, PERF.md section 6, PR 26)
+    vectors = jax.lax.optimization_barrier(
+        [y, mask] + ([alpha] if saga else []))
+    out_shape = [jax.ShapeDtypeStruct((d, _LANE), f32, **kw)]
+    out_specs = [resident]
+    if saga:
+        out_shape.append(jax.ShapeDtypeStruct((1, n), f32, **kw))
+        out_specs.append(row)
+    out = pl.pallas_call(
+        functools.partial(_onepass_kernel, n=n, block=block,
+                          logistic=logistic, saga=saga),
+        grid=(pl.cdiv(n, block),),
+        in_specs=[pl.BlockSpec((d, block), lambda i: (0, i)), resident]
+        + [row] * len(vectors),
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((1, block), f32)] * 2,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            # two buffers of the block; the vectors' blocks, w, g and the
+            # loops' temporaries are small beside them
+            vmem_limit_bytes=2 * d * block * itemsize + (8 << 20),
+        ),
+        name="dense_onepass",
+        interpret=interpret,
+    )(
+        X.T,
+        jnp.broadcast_to(w.astype(f32)[:, None], (d, _LANE)),
+        *(v.astype(f32)[None, :] for v in vectors),
     )
-    row_tile = 128 * max(row_tile // 128, 1)
-    n_main = (n // row_tile) * row_tile
-    g = jnp.zeros(d, jnp.float32)
-    if n_main:
-        g = _fused_masked_grad_tiles(
-            X, y[None, :], m[None, :], w[None, :], row_tile, interpret
-        )[0]
-    if n_main < n:
-        Xt = X[n_main:]
-        r = (mm_f32(Xt, w) - y[n_main:]) * m[n_main:]
-        g = g + mm_f32(Xt.T, r)
-    return g
-
-
-def reference_masked_grad(X, y, w, mask=None):
-    """Plain-XLA oracle for the fused kernel."""
-    X = jnp.asarray(X, jnp.float32)
-    r = X @ jnp.asarray(w, jnp.float32) - jnp.asarray(y, jnp.float32)
-    if mask is not None:
-        r = r * jnp.asarray(mask, jnp.float32)
-    return X.T @ r
+    return out[0].sum(axis=1), (out[1].reshape(n) if saga else None)
 
 
 # --------------------------------------------------------------- attention

@@ -26,7 +26,9 @@ Parity notes per builder:
   ``jax.named_scope`` (``sample``, ``residual``, ``grad``; the sparse
   steps also ``compact`` and ``gather``; ``apply``): an HLO op's
   ``op_name`` then says which phase it belongs to when a trace is opened
-  in XProf or Perfetto.  Metadata only:
+  in XProf or Perfetto.  Where the dense step is the one-pass kernel
+  (``gradients.dense_step_path``) ``residual`` and ``grad`` are one
+  custom call, named ``dense_onepass`` under ``grad``.  Metadata only:
   the compiled program and its compile-cache key are unchanged, and the
   jitted functions keep their Python names (the benchmark matches
   ``jit_step``).
@@ -47,8 +49,8 @@ import numpy as np
 
 from asyncframework_tpu.metrics import profiler as _prof
 from asyncframework_tpu.ops.gradients import (
+    dense_masked_grad,
     least_squares_grad_sum,
-    least_squares_residual,
     logistic_grad_sum,
     mm_f32,
     saga_commit_history,  # re-exported: the solvers' committed-history op
@@ -125,21 +127,25 @@ def _dense_sampled_gradient(X, y, w, key, batch_rate, grad_sum):
 def make_asgd_worker_step(batch_rate: float, loss: str = "least_squares"):
     """jit (X, y, w, key) -> (g_sum, new_key); mask drawn on device.
 
-    The gradient is the reference's sampled sum exactly: two products over
-    the whole shard, ``r = X w - y`` (``gradients.shard_matvec``) then
-    ``X^T (mask * r)``, each one streaming read of the shard WHERE IT LIES,
-    at about 755 GB/s of the v5e's 819.  The TPU stores a dense
-    ``(n, d)`` shard whose ``d * itemsize`` is not a multiple of the 128-lane
-    tile (784, 2000: every recipe this repo has) column-major, rows minor,
-    so that no row is padded (PERF.md section 3, "how the shard is
-    stored").  Reading a sampled tenth of the rows is therefore not a tenth
-    of the traffic: a row gather first relays the whole shard (read and
-    written once, every step), and packing the sampled row ids costs a
-    serial scatter on top.  The compacted dense step this one replaced took
-    16.6 ms on a 1.0M x 784 bf16 shard where the two streaming reads take a
-    quarter of that (PERF.md section 6, PR 24); with this storage the
-    floor of any step is one read of the shard.  The sparse (padded-ELL)
-    step does compact: its gather saves real traffic.
+    The gradient is the reference's sampled sum exactly, over the whole
+    shard WHERE IT LIES: ``r = X w - y`` then ``X^T (mask * r)``
+    (``gradients.dense_masked_grad``).  The TPU stores a dense ``(n, d)``
+    shard whose ``d`` is no multiple of the 128-lane tile (784, 2000: every
+    recipe this repo has) column-major, rows minor, so that no row is
+    padded (PERF.md section 3, "how the shard is stored").  Reading a
+    sampled tenth of the rows is therefore not a tenth of the traffic: a
+    row gather first relays the whole shard, and packing the sampled row
+    ids costs a serial scatter on top (the compacted dense step took 16.6
+    ms on a 1.0M x 784 bf16 shard, PERF.md section 6, PR 24).  With this
+    storage the floor of any step is ONE read of the shard, and on the TPU
+    that is what this step does: the one-pass Pallas kernel
+    (``pallas_kernels.dense_onepass``) computes both products from each
+    block of ``X.T`` in VMEM, 2.14 ms at 740 GB/s where XLA's two fusions
+    took 4.24 ms at 755 GB/s each (v5e, PERF.md section 6, PR 26).  Two
+    reads remain where ``gradients.dense_step_path`` says
+    ``"two_products"``: off the TPU, and at lane-aligned widths, where the
+    shard is stored row-major and ``X.T`` would be a real transpose.  The
+    sparse (padded-ELL) step does compact: its gather saves real traffic.
     """
     grad_sum = _grad_sum_for(loss)
 
@@ -199,6 +205,13 @@ def make_saga_worker_step(batch_rate: float):
     is unchanged between dispatch and accept, ``g`` IS the table's change,
     on every backend and for every storage dtype, which is what lets the
     sync drain, ``run_fused`` and the DCN plane take ``delta == g``.
+
+    The byte model is :func:`make_asgd_worker_step`'s: ONE read of the
+    shard on the TPU (the one-pass kernel takes ``alpha`` in and writes
+    ``diff`` out beside ``g``: 12 bytes a row on top of the shard), two
+    where ``gradients.dense_step_path`` says so.  An accepted update still
+    pays a second read on the updater's side: the table delta is a product
+    against the history AT COMMIT, which no worker step can know.
     """
 
     @jax.jit
@@ -208,10 +221,7 @@ def make_saga_worker_step(batch_rate: float):
             mask = jax.random.bernoulli(
                 sub, batch_rate, (X.shape[0],)
             ).astype(jnp.float32)
-        with jax.named_scope("residual"):
-            diff = least_squares_residual(X, y, w)
-        with jax.named_scope("grad"):
-            g = X.T @ (mask * (diff - alpha))
+        g, diff = dense_masked_grad(X, y, w, mask, alpha=alpha)
         return g, diff, mask, key
 
     return _counts_rows(
@@ -891,8 +901,7 @@ def make_fused_saga_rounds(
             mask = jax.random.bernoulli(
                 sub, batch_rate, (X.shape[0],)
             ).astype(jnp.float32)
-            diff = least_squares_residual(X, y, w)
-            g = X.T @ (mask * (diff - alphas[i]))
+            g, diff = dense_masked_grad(X, y, w, mask, alpha=alphas[i])
             gs.append(g)
             # commit the wave's candidate scalars into the slice
             new_alphas.append(jnp.where(mask > 0, diff, alphas[i]))

@@ -42,7 +42,10 @@ from asyncframework_tpu.engine.scheduler import ASYNC, JobScheduler
 from asyncframework_tpu.engine.speculation import SpeculationMonitor
 from asyncframework_tpu.engine.straggler import DelayModel
 from asyncframework_tpu.ops import steps
-from asyncframework_tpu.ops.gradients import make_sparse_grad_sum
+from asyncframework_tpu.ops.gradients import (
+    dense_step_path,
+    make_sparse_grad_sum,
+)
 from asyncframework_tpu.solvers.base import (
     DelayCalibrator,
     FlopsAccountingMixin,
@@ -99,6 +102,11 @@ class ASAGA(FlopsAccountingMixin):
             self._table_delta = steps.make_saga_table_delta()
             self._eval = steps.make_trajectory_loss_eval("least_squares")
         self._task_rows = self._step.task_rows  # flop accounting
+        # which program a dense step is here, for every result's extras:
+        # every shard has one width and dtype, so shard 0 speaks for all
+        self._path_extras = {} if self._sparse else {
+            "dense_step_path": dense_step_path(self.ds.shard(0).X)
+        }
         self._apply = steps.make_saga_apply(
             config.gamma, config.batch_rate, self.ds.n, config.num_workers
         )
@@ -355,7 +363,9 @@ class ASAGA(FlopsAccountingMixin):
                 with state_lock:
                     if state["k"] >= cfg.num_iterations:
                         break
-                cohort = partial_barrier(
+                # as ASGD's loop: nothing is submitted while the updater
+                # is a whole fleet of queued results behind
+                cohort = [] if ctx.size() >= nw else partial_barrier(
                     ctx, nw, bucket_predicate(ctx, nw, cfg.bucket_ratio)
                 )
                 if not cohort:
@@ -437,7 +447,8 @@ class ASAGA(FlopsAccountingMixin):
         inst.on_snapshot(state["accepted"])
         inst.submitter_clock.waited(sched.blocked_ns)
         run_extras = {
-            **inst.engine_counters(sched.task_retries), **inst.extras()
+            **inst.engine_counters(sched.task_retries), **inst.extras(),
+            **self._path_extras,
         }
         if ckpt.enabled:
             save_checkpoint(final_k, final_w_dev, final_ab)
@@ -579,6 +590,7 @@ class ASAGA(FlopsAccountingMixin):
                 "alpha": {
                     wid: np.asarray(a) for wid, a in enumerate(alphas)
                 },
+                **self._path_extras,
             },
         )
 

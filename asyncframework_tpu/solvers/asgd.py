@@ -40,6 +40,7 @@ from asyncframework_tpu.engine.scheduler import ASYNC, JobScheduler
 from asyncframework_tpu.engine.speculation import SpeculationMonitor
 from asyncframework_tpu.engine.straggler import DelayModel
 from asyncframework_tpu.ops import steps
+from asyncframework_tpu.ops.gradients import dense_step_path
 from asyncframework_tpu.solvers.base import (
     DelayCalibrator,
     FlopsAccountingMixin,
@@ -99,6 +100,11 @@ class ASGD(FlopsAccountingMixin):
             )
             self._eval = steps.make_trajectory_loss_eval(config.loss)
         self._task_rows = self._step.task_rows  # flop accounting
+        # which program a dense step is here, for every result's extras:
+        # every shard has one width and dtype, so shard 0 speaks for all
+        self._path_extras = {} if self._sparse else {
+            "dense_step_path": dense_step_path(self.ds.shard(0).X)
+        }
         self._apply = steps.make_asgd_apply(
             config.gamma, config.batch_rate, self.ds.n, config.num_workers
         )
@@ -360,8 +366,14 @@ class ASGD(FlopsAccountingMixin):
                         break
                 # cold workers (no STAT entry) always selected; warm workers
                 # only when the availability threshold is met (the reference's
-                # wait loop + ASYNCbarrier combination)
-                cohort = partial_barrier(
+                # wait loop + ASYNCbarrier combination).  Nothing is
+                # submitted while the updater is a whole fleet of results
+                # behind: a worker is available again the moment its result
+                # is QUEUED, so a device that outruns the updater (32
+                # workers at 0.6 ms a step, PERF.md section 6, PR 26) would
+                # otherwise fill the queue without bound, with gradients
+                # seconds old whose recorded staleness still reads under nw
+                cohort = [] if ctx.size() >= nw else partial_barrier(
                     ctx, nw, bucket_predicate(ctx, nw, cfg.bucket_ratio)
                 )
                 if not cohort:
@@ -448,7 +460,8 @@ class ASGD(FlopsAccountingMixin):
         snapshots.append((elapsed * 1e3, final_w_dev))
         inst.on_snapshot(state["accepted"])
         inst.submitter_clock.waited(sched.blocked_ns)
-        extras = {**inst.engine_counters(sched.task_retries), **inst.extras()}
+        extras = {**inst.engine_counters(sched.task_retries), **inst.extras(),
+                  **self._path_extras}
         if ckpt.enabled:
             save_checkpoint(final_k, final_w_dev)
         traj = self._evaluate_trajectory(snapshots)
@@ -576,7 +589,8 @@ class ASGD(FlopsAccountingMixin):
             total_flops=flops,
             waiting_time_ms={},
             extras={"fused": True,
-                    "rounds_per_call": min(16, total_rounds)},
+                    "rounds_per_call": min(16, total_rounds),
+                    **self._path_extras},
         )
 
     # ------------------------------------------------------------------ sync

@@ -20,6 +20,7 @@ local device 0).
 
 from __future__ import annotations
 
+import importlib
 import os
 import re
 import threading
@@ -60,7 +61,33 @@ def setup_compile_cache() -> str:
     if not os.environ.get(CACHE_ENV):
         jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    _preload_kernels()
     return path
+
+
+def _preload_kernels() -> None:
+    """Start importing ``ops/pallas_kernels.py`` on a background thread,
+    in a process that may hold a TPU.
+
+    The dense worker step is a Pallas kernel there, and importing
+    ``jax.experimental.pallas`` costs 1.3-1.9 s on the chip's host (most of
+    it the GPU back end's modules, which JAX imports with it).  Every entry
+    point calls :func:`setup_compile_cache` just before it attaches the
+    chip, which takes 8-12 s in PJRT with the interpreter lock released:
+    the import finishes inside that wait, and the program's own import
+    after it drops from 1.41 s to 0.19 s (v5e, PERF.md section 6, PR 26).
+    A process held to the CPU (``JAX_PLATFORMS=cpu``: every test, every role
+    without a chip) never runs the kernel and imports nothing here.
+    """
+    if "tpu" not in os.environ.get("JAX_PLATFORMS", "tpu"):
+        return
+    from asyncframework_tpu.utils.threads import guarded
+
+    threading.Thread(
+        target=guarded(importlib.import_module, "preload-kernels"),
+        args=("asyncframework_tpu.ops.pallas_kernels",),
+        name="preload-kernels", daemon=True,
+    ).start()
 
 
 def cache_entries(path: Optional[str] = None) -> int:

@@ -17,65 +17,105 @@ from asyncframework_tpu.metrics import (
     WorkerLost,
     render_report,
 )
-from asyncframework_tpu.ops.pallas_kernels import (
-    fused_masked_grad,
-    reference_masked_grad,
-)
+from asyncframework_tpu.ops import gradients, pallas_kernels
 from asyncframework_tpu.parallel import multihost
 from asyncframework_tpu.utils import hbm
 
 
-class TestFusedMaskedGrad:
-    """interpret=True: the Pallas kernel runs on the CPU interpreter here;
-    chip_smoke.py phase E compiles it natively on the chip.  Shapes cover
-    one exact tile, tiles plus a ragged XLA tail, and a tail alone."""
+def _onepass_case(rng, n, d, dtype, saga):
+    X = jnp.asarray(rng.normal(size=(n, d)), dtype)
+    y = rng.normal(size=n).astype(np.float32)
+    w = rng.normal(size=d).astype(np.float32)
+    mask = (rng.random(n) < 0.3).astype(np.float32)
+    alpha = rng.normal(size=n).astype(np.float32) if saga else None
+    return X, y, w, mask, alpha
 
-    @pytest.mark.parametrize("n,d", [(256, 128), (300, 100), (64, 17),
-                                     (700, 200)])
-    def test_matches_oracle(self, rng, n, d):
-        X = rng.normal(size=(n, d)).astype(np.float32)
-        y = rng.normal(size=(n,)).astype(np.float32)
-        w = rng.normal(size=(d,)).astype(np.float32)
-        mask = (rng.random(n) < 0.5).astype(np.float32)
-        got = fused_masked_grad(X, y, w, mask, interpret=True)
-        want = reference_masked_grad(X, y, w, mask)
-        np.testing.assert_allclose(
-            np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-3
+
+class TestDenseOnepass:
+    """interpret=True: the one-pass kernel of the dense worker step runs on
+    the CPU interpreter here (``chip_smoke.py`` phase E runs it compiled on
+    the chip, ``tests/test_step_layout.py`` compiles it for a v5e).  The
+    oracle is NumPy float64 over the rows the mask selects, from the
+    shard's stored values.  The interpreter fills what a block holds beyond
+    the array with NaN (``test_the_padding_is_poisoned`` keeps that true),
+    so every ragged case also shows the tail selected out of ``Xb`` and
+    of ``v``."""
+
+    @pytest.mark.parametrize(
+        "n,d,dtype,logistic,saga,block",
+        [
+            (2048, 32, jnp.float32, False, False, 1024),  # whole blocks
+            (2500, 48, jnp.float32, False, False, 1024),  # ragged, n % 128
+            (100, 16, jnp.float32, False, False, 1024),   # under one block
+            (2500, 48, jnp.bfloat16, False, False, 1024),
+            (2048, 32, jnp.float32, True, False, 1024),
+            (2500, 48, jnp.bfloat16, True, False, 1024),
+            (2500, 48, jnp.float32, False, True, 1024),   # ASAGA's form
+            (2500, 48, jnp.bfloat16, False, True, 1024),
+            (100, 16, jnp.bfloat16, False, True, 1024),
+            (1500, 304, jnp.bfloat16, False, True, None),  # 3 row groups
+        ],
+        ids=["f32-blocks", "f32-ragged", "f32-small", "bf16-ragged",
+             "f32-logistic", "bf16-logistic-ragged", "f32-saga-ragged",
+             "bf16-saga-ragged", "bf16-saga-small", "bf16-saga-d304"],
+    )
+    def test_matches_float64_over_the_sampled_rows(
+        self, rng, n, d, dtype, logistic, saga, block
+    ):
+        X, y, w, mask, alpha = _onepass_case(rng, n, d, dtype, saga)
+        g, diff = pallas_kernels.dense_onepass(
+            X, y, w, mask, alpha, logistic=logistic, block=block,
+            interpret=True,
         )
+        X64 = np.asarray(X.astype(jnp.float32), np.float64)
+        r = X64 @ w
+        want_diff = (1.0 / (1.0 + np.exp(-r)) if logistic else r) - y
+        rows = mask > 0
+        v = want_diff[rows] - (alpha[rows] if saga else 0.0)
+        want = X64[rows].T @ v
+        assert g.dtype == jnp.float32 and g.shape == (d,)
+        assert np.max(np.abs(np.asarray(g) - want)) <= 2e-6 * np.max(
+            np.abs(want))
+        if saga:
+            assert diff.dtype == jnp.float32 and diff.shape == (n,)
+            np.testing.assert_allclose(np.asarray(diff), want_diff,
+                                       rtol=0, atol=2e-6 * np.sqrt(d) * 4)
+        else:
+            assert diff is None
 
-    def test_bf16_shard_read_in_storage_dtype(self, rng):
-        """bf16 shards follow mm_f32 (bf16 operands, f32 accumulation):
-        the kernel must agree with the main path's own contraction."""
-        import jax.numpy as jnp
+    def test_the_padding_is_poisoned(self, rng, monkeypatch):
+        """The ragged cases above mean something only while the interpreter
+        hands the kernel NaN beyond the array: read the straddling chunk
+        unselected and the gradient must not survive."""
+        X, y, w, mask, _ = _onepass_case(rng, 2500, 48, jnp.float32, False)
+        real = pallas_kernels._accumulate_grad
+        monkeypatch.setattr(
+            pallas_kernels, "_accumulate_grad",
+            lambda xt, v, g, cols: real(xt, v, g, -(-cols // 128) * 128))
+        g, _ = pallas_kernels.dense_onepass(X, y, w, mask, block=1024,
+                                            interpret=True)
+        assert not np.isfinite(np.asarray(g)).all()
 
-        from asyncframework_tpu.ops.gradients import least_squares_grad_sum
-
-        X = jnp.asarray(rng.normal(size=(600, 96)), jnp.bfloat16)
-        y = rng.normal(size=(600,)).astype(np.float32)
-        w = rng.normal(size=(96,)).astype(np.float32)
-        mask = (rng.random(600) < 0.5).astype(np.float32)
-        got = fused_masked_grad(X, y, w, mask, interpret=True)
-        want = least_squares_grad_sum(X, y, w, mask)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=1e-5, atol=1e-3)
-
-    def test_no_mask_means_all_rows(self, rng):
-        X = rng.normal(size=(64, 32)).astype(np.float32)
-        y = rng.normal(size=(64,)).astype(np.float32)
-        w = rng.normal(size=(32,)).astype(np.float32)
-        got = fused_masked_grad(X, y, w, interpret=True)
-        want = reference_masked_grad(X, y, w)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=1e-4, atol=1e-3)
-
-    def test_row_tile_bigger_than_n(self, rng):
-        X = rng.normal(size=(16, 8)).astype(np.float32)
-        y = rng.normal(size=(16,)).astype(np.float32)
-        w = rng.normal(size=(8,)).astype(np.float32)
-        got = fused_masked_grad(X, y, w, row_tile=4096, interpret=True)
-        want = reference_masked_grad(X, y, w)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=1e-4, atol=1e-3)
+    @pytest.mark.parametrize(
+        "on_tpu,d,dtype,want",
+        [
+            (False, 784, jnp.bfloat16, "two_products"),  # this backend
+            (True, 784, jnp.bfloat16, "onepass"),
+            (True, 2000, jnp.float32, "onepass"),
+            (True, 1024, jnp.float32, "two_products"),   # stored row-major
+            (True, 100, jnp.bfloat16, "two_products"),   # 100 % 16 != 0
+            (True, 784, jnp.float16, "two_products"),
+        ],
+    )
+    def test_the_path_is_chosen_from_backend_width_and_dtype(
+        self, monkeypatch, on_tpu, d, dtype, want
+    ):
+        if on_tpu:
+            monkeypatch.setattr(gradients, "_on_tpu", lambda: True)
+        X = jax.ShapeDtypeStruct((4096, d), dtype)
+        assert gradients.dense_step_path(X) == want
+        assert gradients.dense_step_path(
+            jax.ShapeDtypeStruct((4096,), dtype)) == "two_products"
 
 
 class TestMultihost:
@@ -165,3 +205,57 @@ class TestHtmlReport:
         log.write_text("")
         doc = render_report(log)
         assert "not enough data" in doc
+
+
+@pytest.mark.parametrize("solver", ["asgd", "asaga"])
+def test_a_result_says_which_dense_step_it_timed(tiny_problem, devices8,
+                                                 solver):
+    """``TrainResult.extras["dense_step_path"]`` of ``run()`` and
+    ``run_fused()``: decided where the step is built, from the shard the
+    solver holds; on this backend the two XLA products."""
+    from asyncframework_tpu.solvers import ASAGA, ASGD, SolverConfig
+
+    X, y, _ = tiny_problem
+    cfg = SolverConfig(
+        num_workers=4, num_iterations=40, gamma=0.5, taw=2**31 - 1,
+        batch_rate=0.3, bucket_ratio=0.5, printer_freq=20, coeff=0.0,
+        seed=1, calibration_iters=4, run_timeout_s=60.0,
+    )
+    cls = {"asgd": ASGD, "asaga": ASAGA}[solver]
+    engine = cls(X, y, cfg, devices=devices8[:1])
+    assert gradients.dense_step_path(engine.ds.shard(0).X) == "two_products"
+    assert engine.run().extras["dense_step_path"] == "two_products"
+    fused = cls(X, y, cfg, devices=devices8[:1]).run_fused()
+    assert fused.extras["dense_step_path"] == "two_products"
+
+
+@pytest.mark.parametrize("platforms,starts", [("cpu", False),
+                                              ("tpu,cpu", True),
+                                              (None, True)])
+def test_kernels_are_preloaded_only_where_a_tpu_may_be_held(
+    monkeypatch, platforms, starts
+):
+    """``setup_compile_cache`` starts the Pallas import on a background
+    thread, to finish inside the chip's attach; a process held to the CPU
+    starts nothing."""
+    import threading
+
+    from asyncframework_tpu.utils import devices
+
+    if platforms is None:
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    else:
+        monkeypatch.setenv("JAX_PLATFORMS", platforms)
+    started = []
+    real_start = threading.Thread.start
+
+    def spy(self):
+        started.append(self.name)
+        real_start(self)
+
+    monkeypatch.setattr(threading.Thread, "start", spy)
+    devices._preload_kernels()
+    assert ("preload-kernels" in started) == starts
+    for t in threading.enumerate():
+        if t.name == "preload-kernels":
+            t.join(timeout=30)

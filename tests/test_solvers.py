@@ -386,3 +386,42 @@ class TestBF16AndFlops:
             chip_peak_flops(UnknownTPU())
         assert mfu(197e12, 1.0, FakeTPU()) == pytest.approx(1.0)
         assert mfu(1e9, 1.0, FakeCPU()) is None
+
+
+@pytest.mark.parametrize("solver", [ASGD, ASAGA], ids=["asgd", "asaga"])
+def test_submitter_does_not_outrun_the_updater(devices8, problem,
+                                               monkeypatch, solver):
+    """A worker is available again the moment its result is QUEUED, so with
+    steps faster than the updater's applies the queue would grow without
+    bound (and every gradient in it would be older than its recorded
+    staleness says).  The submitter holds back while a whole fleet of
+    results is queued: at most ``nw`` wait there, plus those in flight when
+    the gate closed."""
+    import time
+
+    from asyncframework_tpu.context import AsyncContext
+
+    X, y, _ = problem
+    nw = 8
+    cfg = small_cfg(num_workers=nw, num_iterations=120, printer_freq=40,
+                    calibration_iters=4)
+    engine = solver(X, y, cfg, devices=devices8[:1])
+    real_apply = engine._apply
+
+    def slow_apply(*args):
+        time.sleep(0.004)  # an updater far slower than the steps
+        return real_apply(*args)
+
+    engine._apply = slow_apply
+    sizes = []
+    real_merge = AsyncContext.merge_result
+
+    def spy(self, *a, **k):
+        res = real_merge(self, *a, **k)
+        sizes.append(self.size())
+        return res
+
+    monkeypatch.setattr(AsyncContext, "merge_result", spy)
+    res = engine.run()
+    assert res.accepted == 120
+    assert max(sizes) <= 2 * nw, max(sizes)

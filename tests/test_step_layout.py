@@ -12,9 +12,21 @@ runs) and read the program: its shard parameter keeps the layout the device
 gave it, nothing copies, gathers or scatters, and it needs no temporary worth
 the name.  The same holds for every program that walks a whole shard on the
 main path: the ASGD step, the ASAGA step, and ASAGA's table delta.  The
-last two promote a bf16 shard to f32 on purpose (``X.T @ v`` with an f32
+delta promotes a bf16 shard to f32 on purpose (``X.T @ v`` with an f32
 vector, ``make_saga_table_delta``) and must do so inside the fusion that
 reads it, not into a 3.2 GB copy.
+
+Since PR 26 the two steps read the shard ONCE: on a TPU and a column-major
+shard ``gradients.dense_step_path`` picks the Pallas kernel over ``X.T``,
+which there is a ``bitcast``.  So the step's program must hold exactly one
+instruction that takes the shard (or its ``bitcast``): a second reader is
+the regression.  The choice asks ``gradients._on_tpu``, which sees the CPU
+here, so the ``on_tpu`` fixture answers for it (and clears JAX's trace
+caches around the test: the gradient sums are jitted at module level and
+remember the program they traced for a shape).  At a lane-aligned width
+(``d % 128 == 0``) the device stores the shard row-major and the choice
+falls to the two XLA products: whichever runs there, nothing may copy or
+transpose the shard.
 
 All TPU compiles of the suite live in THIS file and describe the topology
 inside a fixture: one process at a time may load libtpu, and a worker that
@@ -28,7 +40,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from asyncframework_tpu.ops import steps
+from asyncframework_tpu.ops import gradients, steps
 
 _INSTR = re.compile(
     r"^\s*(?:ROOT )?%(?P<name>[\w.\-]+) = (?P<type>.*?) "
@@ -39,16 +51,28 @@ _MOVES_DATA = {"copy", "transpose", "gather", "scatter", "dynamic-slice",
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
 
     try:
-        topo = topologies.get_topology_desc(
+        return topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2"
         )
     except Exception as e:  # no libtpu, or another process holds its lock
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def on_tpu(monkeypatch):
+    jax.clear_caches()
+    monkeypatch.setattr(gradients, "_on_tpu", lambda: True)
+    yield
+    jax.clear_caches()
 
 
 def _compile(one_chip, program, n, d, dtype, batch_rate=0.1):
@@ -94,15 +118,21 @@ def _instructions(hlo_text):
         ("asgd-step", 8192, 2000, jnp.float32),   # epsilon's
         ("saga-step", 40000, 784, jnp.bfloat16),  # mnist8m-asaga's
         ("saga-step", 40000, 784, jnp.float32),
+        ("saga-step", 8192, 2000, jnp.float32),
         ("saga-delta", 40000, 784, jnp.bfloat16),
         ("saga-delta", 40000, 784, jnp.float32),
+        ("asgd-step", 8192, 1024, jnp.float32),   # lane-aligned: row-major
     ],
     ids=["bf16-784", "f32-784", "f32-2000", "saga-step-bf16-784",
-         "saga-step-f32-784", "saga-delta-bf16-784", "saga-delta-f32-784"],
+         "saga-step-f32-784", "saga-step-f32-2000", "saga-delta-bf16-784",
+         "saga-delta-f32-784", "f32-1024-lane-aligned"],
 )
 def test_dense_step_reads_the_shard_in_its_stored_layout(
-    one_chip, no_compile_cache, program, n, d, dtype
+    one_chip, no_compile_cache, on_tpu, program, n, d, dtype
 ):
+    column_major = d % 128 != 0
+    path = gradients.dense_step_path(jax.ShapeDtypeStruct((n, d), dtype))
+    assert path == ("onepass" if column_major else "two_products")
     compiled = _compile(one_chip, program, n, d, dtype)
     text = compiled.as_text()
     instrs = _instructions(text)
@@ -115,13 +145,14 @@ def test_dense_step_reads_the_shard_in_its_stored_layout(
         if op == "parameter" and t.startswith(shard)
     ]
     assert len(param0) == 1, entry[:2000]
-    # the device's own layout for this shape: rows minor (column-major),
-    # because 784 and 2000 are no multiples of the 128-lane tile
+    # the device's own layout for this shape: rows minor (column-major)
+    # where d is no multiple of the 128-lane tile (784, 2000), row-major
+    # where it is (1024): the predicate of gradients.dense_step_path
     layout = re.match(re.escape(shard) + r"\{([\d,]+)", param0[0]).group(1)
-    assert layout == "0,1", (
+    assert layout == ("0,1" if column_major else "1,0"), (
         f"the compiler now stores {shard} as {{{layout}}}: the byte model "
-        f"in make_asgd_worker_step's docstring and PERF.md section 3 "
-        f"rests on {{0,1}}; re-derive it"
+        f"in gradients.py's docstring, dense_step_path's predicate and "
+        f"PERF.md section 3 rest on d % 128; re-derive them"
     )
 
     whole = {}  # name -> type, of everything as large as the shard
@@ -133,7 +164,9 @@ def test_dense_step_reads_the_shard_in_its_stored_layout(
             "(" + re.escape(shard) + "|" + re.escape(shard_t) + r")\{([\d,]+)",
             t,
         ):
-            want = layout if m.group(1) == shard else "1,0"
+            # X.T of a column-major shard is row-major, and of a
+            # row-major one column-major: a bitcast either way
+            want = layout if m.group(1) == shard else layout[::-1]
             assert m.group(2) == want, (
                 f"%{name} holds the shard relaid: {t[:120]}"
             )
@@ -151,3 +184,52 @@ def test_dense_step_reads_the_shard_in_its_stored_layout(
 
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < 1 << 20, f"{temp} bytes of temporaries"
+
+    if program != "saga-delta" and column_major:
+        # ONE read: the kernel, fed the shard's bitcast, and nothing else
+        entry_instrs = _instructions(entry)
+        held = {name for name, t, _op, _ in entry_instrs
+                if shard in t or shard_t in t}
+        readers = [(name, op) for name, _t, op, operands in entry_instrs
+                   if op != "bitcast" and held & set(operands)]
+        assert len(readers) == 1 and readers[0][1] == "custom-call", (
+            f"the step reads the shard {len(readers)} times: {readers}"
+        )
+        assert "dense_onepass" in readers[0][0], readers
+
+
+def test_mesh_step_runs_the_kernel_on_each_device_rows(
+    topo, no_compile_cache, on_tpu
+):
+    """``make_mesh_asgd_worker_step`` calls the same gradient sum per
+    device under ``shard_map``: the kernel's outputs must vary over the
+    mesh axes the shard varies over (its ``vma``), each device reads its
+    own rows through a ``bitcast``, and one all-reduce folds the partial
+    gradients.  (The CPU interpreter cannot run the kernel's loops under
+    ``shard_map``'s varying-axes check, so this is checked where it
+    matters: on the program compiled for four described chips.)"""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.array(topo.devices), ("dp",))
+    n, d = 4 * 40000, 784
+
+    def spec(shape, dt, p):
+        return jax.ShapeDtypeStruct(shape, dt,
+                                    sharding=NamedSharding(mesh, p))
+
+    text = steps.make_mesh_asgd_worker_step(0.1, mesh).lower(
+        spec((n, d), jnp.bfloat16, P("dp")), spec((n,), jnp.float32, P("dp")),
+        spec((n,), jnp.float32, P("dp")), spec((d,), jnp.float32, P()),
+        spec((2,), jnp.uint32, P()),
+    ).compile().as_text()
+    entry = _instructions(text[text.index("ENTRY"):])
+    local, local_t = f"bf16[{n // 4},{d}]", f"bf16[{d},{n // 4}]"
+    held = {name for name, t, _op, _ in entry if local in t or local_t in t}
+    readers = [(name, op) for name, _t, op, operands in entry
+               if op != "bitcast" and held & set(operands)]
+    assert [op for _, op in readers] == ["custom-call"], readers
+    assert "dense_onepass" in readers[0][0]
+    assert not [i for i in entry if i[2] in ("copy", "transpose")
+                and held & set(i[3])]
+    assert [i for i in entry if i[2].startswith("all-reduce")]
