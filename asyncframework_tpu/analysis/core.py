@@ -87,7 +87,6 @@ class SourceFile:
 #: deliberately-bad rule fixtures, so it is out of scope by design;
 #: examples/ are user-facing scripts linted for conf/thread hygiene too)
 LINT_DIRS = ("asyncframework_tpu", "bin", "examples")
-LINT_FILES = ("bench.py",)
 _SKIP_DIRS = {"__pycache__", ".git", "native"}
 
 
@@ -109,9 +108,6 @@ def iter_lint_paths(root: str) -> Iterable[str]:
                         head = f.read(64)
                     if b"python" in head.split(b"\n", 1)[0]:
                         yield rel
-    for fn in LINT_FILES:
-        if os.path.isfile(os.path.join(root, fn)):
-            yield fn
 
 
 class LintContext:

@@ -81,8 +81,7 @@ def _is_retained(sf: SourceFile, call: ast.Call) -> bool:
 
 def _target_guarded(call: ast.Call) -> bool:
     """target=guarded(...) -- the utils/threads.py exception policy (or
-    a local ``_guarded`` copy where importing the package is off-limits,
-    e.g. bench.py's probe path)."""
+    a local ``_guarded`` copy where importing the package is off-limits)."""
     target = _kwarg(call, "target")
     return (isinstance(target, ast.Call)
             and tail_name(target.func).lstrip("_") == "guarded")

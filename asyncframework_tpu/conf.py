@@ -544,7 +544,9 @@ SERVE_MAX_STALE_MS = ConfigEntry(
     "degrade to eventual consistency).")
 SERVE_REPLICAS = ConfigEntry(
     "async.serve.replicas", 2, int,
-    "Replica count launchers (bench --serve, k8s manifests) provision.")
+    "Replica count a launcher provisions.  No reader in the tree since "
+    "the old bench went (PR 27; analysis/allowlist.py): deploy/k8s.py "
+    "takes the count as --serving N.")
 SERVE_MAX_REPLICAS = ConfigEntry(
     "async.serve.max.replicas", 16, int,
     "Registration slots a ServingFrontend allocates (the ElasticSupervisor "

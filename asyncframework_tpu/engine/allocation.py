@@ -141,3 +141,18 @@ class ExecutorAllocationManager:
         if self._thread is not None:
             self._thread.join(timeout=5)
             self._thread = None
+
+
+def make_allocation_manager(cfg, scheduler):
+    """Start a dynamic-allocation manager when the solver config ``cfg``
+    asks for one (``ExecutorAllocationManager`` parity); None otherwise."""
+    if not cfg.dynamic_allocation:
+        return None
+    mgr = ExecutorAllocationManager(
+        scheduler,
+        max_extra_per_slot=cfg.allocation_max_extra,
+        backlog_threshold=cfg.allocation_backlog_threshold,
+        idle_timeout_s=cfg.allocation_idle_timeout_s,
+    )
+    mgr.start()
+    return mgr

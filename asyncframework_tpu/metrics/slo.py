@@ -294,61 +294,3 @@ def reset_engine() -> None:
     global _engine
     with _glock:
         _engine = None
-
-
-def bench_verdicts(updates_per_sec: Optional[float],
-                   trajectory, extra_series=None) -> Dict[str, Dict]:
-    """Static SLO verdicts for a finished benchmark run: evaluate the
-    conf rule set against synthesized series -- ``ps.accepted`` rate =
-    the run's updates/s, ``convergence.loss`` = the trajectory, plus
-    any ``extra_series`` (name -> [(t_ms, value), ...]; the adaptive
-    bench arm feeds ``control.changes`` so ``controller_converged`` is
-    judged on the real decision trace) -- so BENCH_*.json records
-    pass/violated per rule (rules whose series the run never produced
-    report ``no_data``)."""
-    from asyncframework_tpu.conf import SLO_RULES, global_conf
-    from asyncframework_tpu.metrics.timeseries import TimeSeriesStore
-
-    rules = parse_rules(str(global_conf().get(SLO_RULES)))
-    st = TimeSeriesStore(capacity=4096)
-    now = st.now_s()
-    span_ms = float(trajectory[-1][0]) if trajectory else 0.0
-    for pts in (extra_series or {}).values():
-        if pts:
-            span_ms = max(span_ms, float(pts[-1][0]))
-    t0 = now - span_ms / 1e3
-    if trajectory:
-        for (t_ms, loss) in trajectory:
-            st.record("convergence.loss", loss, t_s=t0 + float(t_ms) / 1e3)
-    for name, pts in (extra_series or {}).items():
-        for (t_ms, v) in pts:
-            st.record(name, float(v), t_s=t0 + float(t_ms) / 1e3)
-    extra_names = set(extra_series or ())
-    eng = SLOEngine(rules, store=st)
-    out: Dict[str, Dict] = {}
-    for rule in eng.rules:
-        if rule.series == "ps.accepted" and rule.agg == "rate":
-            value: Optional[float] = updates_per_sec
-        elif rule.agg == "rate" and rule.series in extra_names:
-            # a rate rule over a synthesized counter keeps its DECLARED
-            # window, anchored at run end: "the knob-change rate falls
-            # below threshold within the burn window" is a claim about
-            # the settled tail, not the whole-run average
-            value = eng._aggregate(rule)
-        else:
-            # aggregate over the FULL synthesized span, not the rule's
-            # live window (the run already happened)
-            wide = SLORule(rule.name, rule.agg, rule.series, rule.op,
-                           rule.threshold, window_s=1e9, for_s=0.0)
-            value = eng._aggregate(wide)
-        if value is None:
-            out[rule.name] = {"state": NO_DATA, "value": None,
-                              "threshold": rule.threshold}
-        else:
-            ok = OPS[rule.op](value, rule.threshold)
-            out[rule.name] = {
-                "state": OK if ok else "violated",
-                "value": round(float(value), 6),
-                "threshold": rule.threshold,
-            }
-    return out

@@ -586,7 +586,8 @@ class TraceRecorder:
 class TraceAggregator:
     """Folds spans into per-stage latency histograms + staleness (versions
     AND milliseconds) distributions; the ``trace`` section of the live UI
-    and of ``bench.py --trace-jsonl`` is one :meth:`snapshot` of this."""
+    is one :meth:`snapshot` of this, and ``benchmark/run.py`` reads the
+    process-global one (:func:`aggregator`)."""
 
     def __init__(self, capacity: int = 4096):
         from asyncframework_tpu.metrics.system import Histogram

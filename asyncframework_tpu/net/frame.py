@@ -34,8 +34,8 @@ Data-plane fast paths (the throughput overhaul):
 
 Wire-bytes accounting: every frame sent or received here bumps a per-op
 byte counter (frame bytes: both length prefixes + header + payload).
-``bytes_totals()`` exposes them (live UI ``net.bytes`` section,
-``bench.py`` bytes-per-update); ``metrics.reset_totals()`` zeroes them via
+``bytes_totals()`` exposes them (live UI ``net.bytes`` section);
+``metrics.reset_totals()`` zeroes them via
 ``net.reset_net_totals``.  The per-thread ``last_io_bytes()`` value lets a
 client attach this RPC's wire cost to its pull.rtt/push.rtt trace span.
 """

@@ -270,9 +270,6 @@ class AsyncController:
         self._t0 = now
         self._queue_ewma: Optional[float] = None
         self._last_decision: Optional[Dict[str, object]] = None
-        # bounded decision trace (bench.py --dcn adaptive arm records
-        # it; the flight recorder gets per-change breadcrumbs too)
-        self._decisions: List[Dict[str, object]] = []
         self._lock = threading.Lock()
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
@@ -390,9 +387,6 @@ class AsyncController:
                 self._last_decision = {
                     **changed[-1], "t": record["t"],
                 }
-                for c in changed:
-                    self._decisions.append({**c, "t": record["t"]})
-                del self._decisions[:-256]
         if changed:
             _bump("changes", len(changed))
             reason = "; ".join(str(c["reason"]) for c in changed)
@@ -671,13 +665,6 @@ class AsyncController:
                     and now < k.frozen_until)),
             }
         return out
-
-    def decision_log(self) -> List[Dict[str, object]]:
-        """Every committed knob change this run (bounded at 256): the
-        decision trace bench.py's adaptive arm records in the BENCH
-        payload."""
-        with self._lock:
-            return [dict(d) for d in self._decisions]
 
     def status(self) -> Dict[str, object]:
         """The ``control`` /api/status section (async-top/async-mon

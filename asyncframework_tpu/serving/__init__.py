@@ -16,9 +16,8 @@ to a failover, never an outage.  Every reply carries its freshness lag
 routes around them.
 
 Knobs: ``async.serve.*`` (conf.py).  Entry point: ``bin/async-serve``
-(``python -m asyncframework_tpu.serving.cli``).  Benchmark:
-``bench.py --serve`` (QPS vs freshness lag, with training running and
-with the chaos fabric killing a replica mid-load).
+(``python -m asyncframework_tpu.serving.cli``).  No benchmark cell
+reaches this tier (PERF.md section 7).
 """
 
 from asyncframework_tpu.serving.frontend import PredictError, ServingFrontend

@@ -12,7 +12,7 @@ Two roles::
                          [--conf k=v ...]
 
 Each role prints ONE JSON line on stdout once bound (``{"role": ...,
-"port": ...}``) so launchers (bench.py --serve, tests, k8s readiness
+"port": ...}``) so launchers (tests, k8s readiness
 wrappers) can parse the ephemeral port, then serves until SIGTERM/EOF.
 ``--conf`` overlays any registered ``async.serve.*`` / ``async.net.*``
 knob, same precedence as async-submit.

@@ -25,21 +25,17 @@ Staleness filter quirk preserved: ASAGA accepts iff ``k - staleness <= taw``
 
 from __future__ import annotations
 
+import operator
 import queue
-import threading
 import time
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from asyncframework_tpu.context import AsyncContext
 from asyncframework_tpu.data.sharded import ShardedDataset
-from asyncframework_tpu.engine.barrier import bucket_predicate, partial_barrier
 from asyncframework_tpu.engine.recovery import ShardRecovery
-from asyncframework_tpu.engine.scheduler import ASYNC, JobScheduler
-from asyncframework_tpu.engine.speculation import SpeculationMonitor
 from asyncframework_tpu.engine.straggler import DelayModel
 from asyncframework_tpu.ops import steps
 from asyncframework_tpu.ops.gradients import (
@@ -47,27 +43,18 @@ from asyncframework_tpu.ops.gradients import (
     make_sparse_grad_sum,
 )
 from asyncframework_tpu.solvers.base import (
-    DelayCalibrator,
-    FlopsAccountingMixin,
-    make_allocation_manager,
-    SolverCheckpointer,
     SolverConfig,
     TrainResult,
-    WaitingTimeTable,
     check_hbm_plan,
-    collect_checked,
     resolve_dataset,
+    run_fused_plan,
 )
 from asyncframework_tpu.metrics import trace
-from asyncframework_tpu.solvers.instrumentation import (
-    FaultTolerantRun,
-    RunInstruments,
-    on_device,
-    worker_task,
-)
+from asyncframework_tpu.solvers.engine_loop import EngineRun, EngineSolver
+from asyncframework_tpu.solvers.instrumentation import on_device, worker_task
 
 
-class ASAGA(FlopsAccountingMixin):
+class ASAGA(EngineSolver):
     def __init__(
         self,
         X,
@@ -112,29 +99,22 @@ class ASAGA(FlopsAccountingMixin):
         )
         self._recovery = ShardRecovery(self.ds, self.devices)
 
+    #: a step returns ``(g, ...payload..., new_key)``: all but the key ride
+    #: to the updater, as a tuple
+    _result_payload = staticmethod(operator.itemgetter(slice(None, -1)))
+
     # ------------------------------------------------------------------ async
     def run(self) -> TrainResult:
         cfg = self.cfg
-        nw = cfg.num_workers
-        ctx: AsyncContext = AsyncContext()
-        sched = JobScheduler(num_workers=nw, devices=self.devices)
-        sched.set_mode(ASYNC)
-        self.scheduler = sched  # exposed for fault-injection tests/tools
-        delay_model = DelayModel(cfg.coeff, nw, cfg.seed)
-        calibrator = DelayCalibrator(cfg.effective_calibration_iters())
-        waiting = WaitingTimeTable()
-        inst = RunInstruments(cfg, nw)
-        inst.register_queue_depth(ctx.size)
-
-        d = self.ds.d
-        ckpt = SolverCheckpointer(cfg, "asaga", d, self.ds.n)
-        ck = ckpt.restore()
+        run = EngineRun(self)
+        ck = run.restore("asaga")
+        ctx, inst, waiting = run.ctx, run.inst, run.waiting
+        calibrator, delay_model, ckpt = run.calibrator, run.delay_model, run.ckpt
+        state, state_lock, stop = run.state, run.state_lock, run.stop
+        hot_lock = run.key_lock  # guards the alpha slots too
         if ck is not None:
-            # Resume: model, running history mean, the full per-worker history
-            # table, the accepted counter, logical clock, and PRNG chains.
-            k0 = int(ck["k"])
-            ctx.set_current_time(int(ck["clock"]))
-            w = jax.device_put(jnp.asarray(ck["w"]), self.driver_device)
+            # resume: the running history mean and the full per-worker
+            # history table come back with the run's common fields
             alpha_bar = jax.device_put(
                 jnp.asarray(ck["alpha_bar"]), self.driver_device
             )
@@ -142,97 +122,20 @@ class ASAGA(FlopsAccountingMixin):
                 wid: jax.device_put(jnp.asarray(a), self._shard_device(wid))
                 for wid, a in ck["alpha"].items()
             }
-            worker_keys: Dict[int, jax.Array] = {
-                wid: jax.device_put(jnp.asarray(key), self._shard_device(wid))
-                for wid, key in ck["worker_keys"].items()
-            }
         else:
-            k0 = 0
-            w = jax.device_put(jnp.zeros(d, jnp.float32), self.driver_device)
-            alpha_bar = jax.device_put(jnp.zeros(d, jnp.float32), self.driver_device)
-            # the history table: one slice per worker, resident in its HBM
-            alpha = {
-                wid: jax.device_put(
-                    jnp.zeros(self.ds.shard(wid).size, jnp.float32),
-                    self._shard_device(wid),
-                )
-                for wid in range(nw)
-            }
-            worker_keys = {
-                wid: jax.device_put(
-                    jax.random.fold_in(jax.random.PRNGKey(cfg.seed), wid),
-                    self._shard_device(wid),
-                )
-                for wid in range(nw)
-            }
-        hot_lock = threading.Lock()  # guards alpha/worker_keys handle slots
-
-        def on_shard_moved(shard_id, moved):
-            # the history slice and PRNG chain follow the shard's new home
-            with hot_lock:
-                alpha[shard_id] = jax.device_put(alpha[shard_id], moved.device)
-                worker_keys[shard_id] = jax.device_put(
-                    worker_keys[shard_id], moved.device
-                )
-
-        ft = None
-        if cfg.heartbeat:
-            ft = FaultTolerantRun(
-                sched, self._recovery, inst, nw,
-                heartbeat_timeout_ms=cfg.heartbeat_timeout_ms,
-                check_interval_s=cfg.heartbeat_interval_s,
-                max_slot_failures=cfg.max_slot_failures,
-                on_moved=on_shard_moved,
-            )
-            ft.start()
-        spec = None
-        if cfg.speculation:
-            spec = SpeculationMonitor(
-                sched, quantile=cfg.speculation_quantile,
-                multiplier=cfg.speculation_multiplier,
-                min_time_ms=cfg.speculation_min_ms,
-                on_launch=inst.on_speculative_launch,
-            )
-            spec.start()
-        alloc = make_allocation_manager(cfg, sched)
-        # stale-read experiment: the reference's ASAGA driver is the main
-        # ASYNCbroadcast user (SparkASAGAThread.scala:268); workers read
-        # model version (latest - offset)
-        from asyncframework_tpu.broadcast import VersionedModelStore
-
-        store = (
-            VersionedModelStore(cfg.max_live_versions)
-            if cfg.stale_read_offset is not None
-            else None
-        )
-
-        state = {"w": w, "ab": alpha_bar, "k": k0, "accepted": 0, "dropped": 0,
-                 "rounds": 0, "flops": 0.0,
-                 # the updater's time inside the history path's dispatches
-                 # (a part of updater_apply_s)
-                 "history_ns": 0}
-        state_lock = threading.Lock()
-        stop = threading.Event()
+            alpha_bar, alpha = self._zero_history()
+        # history_ns: the updater's time inside the history path's
+        # dispatches (a part of updater_apply_s)
+        state.update(ab=alpha_bar, history_ns=0)
+        run.start_monitors(self._history_follows(run, alpha))
         self._warm_hot_path()
-        start_wall = time.monotonic()
-        inst.on_run_start()
-        snapshots: List[Tuple[float, jax.Array]] = [(0.0, w)]
+        run.start_clock()
+        snapshots, now_ms = run.snapshots, run.now_ms
 
-        def now_ms():
-            return (time.monotonic() - start_wall) * 1e3
-
-        def save_checkpoint(save_k: int, save_w, save_ab) -> None:
+        def history_fields(ab) -> Dict:
             with hot_lock:
-                keys_h = {wid: np.asarray(kv) for wid, kv in worker_keys.items()}
                 alpha_h = {wid: np.asarray(a) for wid, a in alpha.items()}
-            ckpt.save(
-                save_k,
-                w=np.asarray(save_w),
-                alpha_bar=np.asarray(save_ab),
-                alpha=alpha_h,
-                clock=ctx.get_current_time(),
-                worker_keys=keys_h,
-            )
+            return {"alpha_bar": np.asarray(ab), "alpha": alpha_h}
 
         def updater():
             clock = inst.updater_clock
@@ -341,148 +244,19 @@ class ASAGA(FlopsAccountingMixin):
                 inst.on_gradient_merged(res, accepted, k, task_ms)
                 if do_save:
                     with trace.span(trace.CHECKPOINT):
-                        save_checkpoint(save_k, save_w, save_ab)
+                        run.save(save_k, save_w, **history_fields(save_ab))
                 if calibrator.maybe_finalize(state["k"]):
                     delay_model.calibrate(calibrator.avg_delay_ms)
             clock.waits()  # the loop's last busy stretch
             stop.set()
 
-        upd = threading.Thread(target=updater, name="saga-updater", daemon=True)
-        upd.start()
-
-        from collections import deque
-
-        waiters: deque = deque(maxlen=4 * nw)
-        deadline = time.monotonic() + cfg.run_timeout_s
-        run_ok = False
-        try:
-            while not stop.is_set() and time.monotonic() < deadline:
-                failed = next((x.failed for x in waiters if x.failed), None)
-                if failed is not None:
-                    raise RuntimeError("async job aborted") from failed
-                with state_lock:
-                    if state["k"] >= cfg.num_iterations:
-                        break
-                # as ASGD's loop: nothing is submitted while the updater
-                # is a whole fleet of queued results behind
-                cohort = [] if ctx.size() >= nw else partial_barrier(
-                    ctx, nw, bucket_predicate(ctx, nw, cfg.bucket_ratio)
-                )
-                if not cohort:
-                    inst.submit_empty_polls += 1
-                    inst.submitter_clock.waits()
-                    time.sleep(0.001)
-                    inst.submitter_clock.works()
-                    continue
-                # the sampling decision falls here, at submit (see ASGD.run)
-                uts = inst.start_updates(cohort)
-                with trace.span(trace.SUBMIT, uts.values(),
-                                batch=len(cohort)):
-                    with state_lock:
-                        w_pub = state["w"]
-                        model_version = state["k"]
-                    if store is not None:
-                        # version buffer resolved at submit time: eviction
-                        # by later publishes cannot invalidate an in-flight
-                        # read
-                        v = store.publish(np.asarray(w_pub))
-                        live = store.live_versions()
-                        tv = max(live[0], v - cfg.stale_read_offset)
-                        w_pub = store.value(self.driver_device, version=tv)
-                        model_version = v
-                    ts = ctx.get_current_time()
-                    ctx.set_last_time(ts)
-                    ctx.mark_busy(cohort)
-                    waiting.on_submit(cohort, now_ms())
-                    if uts:
-                        inst.begin_compute(uts, model_version)
-                    with hot_lock:
-                        captured = {
-                            wid: (worker_keys[wid], alpha[wid])
-                            for wid in cohort
-                        }
-                    fns = {
-                        wid: self._make_task(
-                            wid, w_pub, captured[wid][0], captured[wid][1],
-                            delay_model, uts.get(wid),
-                        )
-                        for wid in cohort
-                    }
-                    with state_lock:
-                        state["rounds"] += 1
-                        round_idx = state["rounds"]
-                    # post BEFORE launching: a fast worker could otherwise
-                    # merge before its round's RoundSubmitted event exists
-                    inst.on_round_submitted(round_idx, cohort, model_version)
-                    waiter = sched.run_job(
-                        fns,
-                        self._handler(
-                            ctx, ts, now_ms, worker_keys, hot_lock, uts
-                        ),
-                    )
-                waiters.append(waiter)
-            run_ok = True
-        finally:
-            inst.submitter_clock.waits()  # the loop's last busy stretch
-            stop.set()
-            upd.join(timeout=10)
-            if ft is not None:
-                ft.stop()
-            if spec is not None:
-                spec.stop()
-            if alloc is not None:
-                alloc.stop()
-            sched.shutdown()
-            if not run_ok:
-                inst.close()  # crash path: flush/seal the event log now
-
-        with state_lock:
-            final_k, final_w_dev, final_ab = state["k"], state["w"], state["ab"]
-        # materialize BEFORE taking elapsed: the readback is also the fence,
-        # so elapsed covers work actually done, not merely dispatched (see
-        # ASGD.run)
-        final_w = np.asarray(final_w_dev)
-        elapsed = time.monotonic() - start_wall
-        snapshots.append((elapsed * 1e3, final_w_dev))
-        inst.on_snapshot(state["accepted"])
-        inst.submitter_clock.waited(sched.blocked_ns)
-        run_extras = {
-            **inst.engine_counters(sched.task_retries), **inst.extras(),
-            **self._path_extras,
-        }
-        if ckpt.enabled:
-            save_checkpoint(final_k, final_w_dev, final_ab)
-        traj = self._evaluate_trajectory(snapshots)
-        if spec is not None:
-            run_extras["speculated"] = spec.speculated_count()
-            run_extras["speculation_wins"] = sched.speculative_wins()
-        if alloc is not None:
-            (
-                run_extras["executors_added"],
-                run_extras["executors_removed"],
-            ) = alloc.counts()
-        inst.close(traj, cfg.printer_freq)
-        return TrainResult(
-            final_w=final_w,
-            trajectory=traj,
-            elapsed_s=elapsed,
-            accepted=state["accepted"],
-            dropped=state["dropped"],
-            rounds=state["rounds"],
-            max_staleness=ctx.max_staleness(),
-            avg_delay_ms=calibrator.avg_delay_ms,
-            updates_per_sec=state["accepted"] / elapsed if elapsed > 0 else 0.0,
-            total_flops=state["flops"],
-            waiting_time_ms=waiting.snapshot(),
-            extras={
-                "alpha": {wid: np.asarray(a) for wid, a in alpha.items()},
-                "alpha_bar": np.asarray(final_ab),
+        run.drive(updater, "saga-updater", self._task_maker(run, alpha))
+        return run.result(
+            checkpoint=lambda: history_fields(state["ab"]),
+            more_extras=lambda: {
+                **self._history_extras(alpha, state["ab"]),
                 "updater_history_s": state["history_ns"] * 1e-9,
-                "history_drift": self._history_drift(alpha, final_ab),
-                **run_extras,
             },
-            snapshot_updates=inst.snapshot_updates,
-            staleness_hist=dict(sorted(inst.staleness_hist.items())),
         )
 
     # ----------------------------------------------------------------- fused
@@ -554,8 +328,6 @@ class ASAGA(FlopsAccountingMixin):
             jax.random.fold_in(jax.random.PRNGKey(cfg.seed), wid)
             for wid in range(nw)
         ]), drv)
-        from asyncframework_tpu.solvers.base import run_fused_plan
-
         ((w, ab, alphas, keys), snapshots, start_wall,
          done_rounds) = run_fused_plan(
             make_runner, (w, ab, alphas, keys), total_rounds, nw,
@@ -600,77 +372,22 @@ class ASAGA(FlopsAccountingMixin):
         histories, apply one accumulated update with ``parRecs = b*N``."""
         cfg = self.cfg
         nw = cfg.num_workers
-        ctx: AsyncContext = AsyncContext()
-        sched = JobScheduler(num_workers=nw, devices=self.devices)
-        sched.set_mode(ASYNC)
-        self.scheduler = sched  # exposed for fault-injection tests/tools
-        delay_model = DelayModel(cfg.coeff, nw, cfg.seed)
-        # rounds, not accepted gradients; explicit calibration_iters overrides
-        calibrator = DelayCalibrator(
-            cfg.calibration_iters if cfg.calibration_iters is not None else 100
-        )
-        waiting = WaitingTimeTable()
-        inst = RunInstruments(cfg, nw)
-        inst.register_queue_depth(ctx.size)
+        run = EngineRun(self, sync=True)
+        ctx, sched, inst = run.ctx, run.sched, run.inst
+        waiting, calibrator = run.waiting, run.calibrator
+        hot_lock = run.key_lock  # guards the alpha slots too
         sync_apply = steps.make_saga_apply(
             cfg.gamma, cfg.batch_rate, self.ds.n, 1,  # parRecs = b*N
             donate_g=False,  # the drain passes acc as both g and delta
         )
-
-        w = jax.device_put(jnp.zeros(self.ds.d, jnp.float32), self.driver_device)
-        alpha_bar = jax.device_put(jnp.zeros(self.ds.d, jnp.float32), self.driver_device)
-        alpha = {
-            wid: jax.device_put(
-                jnp.zeros(self.ds.shard(wid).size, jnp.float32),
-                self._shard_device(wid),
-            )
-            for wid in range(nw)
-        }
-        worker_keys = {
-            wid: jax.device_put(
-                jax.random.fold_in(jax.random.PRNGKey(cfg.seed), wid),
-                self._shard_device(wid),
-            )
-            for wid in range(nw)
-        }
-        hot_lock = threading.Lock()  # guards alpha/worker_keys handle slots
-
-        def on_shard_moved(shard_id, moved):
-            # the history slice and PRNG chain follow the shard's new home
-            # (same discipline as the async path)
-            with hot_lock:
-                alpha[shard_id] = jax.device_put(alpha[shard_id], moved.device)
-                worker_keys[shard_id] = jax.device_put(
-                    worker_keys[shard_id], moved.device
-                )
-
-        ft = None
-        if cfg.heartbeat:
-            ft = FaultTolerantRun(
-                sched, self._recovery, inst, nw,
-                heartbeat_timeout_ms=cfg.heartbeat_timeout_ms,
-                check_interval_s=cfg.heartbeat_interval_s,
-                max_slot_failures=cfg.max_slot_failures,
-                on_moved=on_shard_moved,
-            )
-            ft.start()
-        spec = None
-        if cfg.speculation:
-            spec = SpeculationMonitor(
-                sched, quantile=cfg.speculation_quantile,
-                multiplier=cfg.speculation_multiplier,
-                min_time_ms=cfg.speculation_min_ms,
-                on_launch=inst.on_speculative_launch,
-            )
-            spec.start()
-        alloc = make_allocation_manager(cfg, sched)
+        run.cold_start()
+        w = run.state["w"]
+        alpha_bar, alpha = self._zero_history()
+        run.start_monitors(self._history_follows(run, alpha))
+        make_tasks = self._task_maker(run, alpha)
         self._warm_hot_path(apply=sync_apply, sync=True)
-        start_wall = time.monotonic()
-        inst.on_run_start()
-        snapshots: List[Tuple[float, jax.Array]] = [(0.0, w)]
-
-        def now_ms():
-            return (time.monotonic() - start_wall) * 1e3
+        run.start_clock()
+        snapshots, now_ms = run.snapshots, run.now_ms
 
         rounds = 0
         flops = 0.0
@@ -687,25 +404,9 @@ class ASAGA(FlopsAccountingMixin):
                     waiting.on_submit(cohort, now_ms())
                     if uts:
                         inst.begin_compute(uts, k)
-                    with hot_lock:
-                        captured = {
-                            wid: (worker_keys[wid], alpha[wid])
-                            for wid in cohort
-                        }
-                    fns = {
-                        wid: self._make_task(
-                            wid, w, captured[wid][0], captured[wid][1],
-                            delay_model, uts.get(wid),
-                        )
-                        for wid in cohort
-                    }
+                    fns = make_tasks(cohort, w, uts)
                     inst.on_round_submitted(k, cohort, model_version=k)
-                    waiter = sched.run_job(
-                        fns,
-                        self._handler(
-                            ctx, ts, now_ms, worker_keys, hot_lock, uts
-                        ),
-                    )
+                    waiter = sched.run_job(fns, self._handler(run, ts, uts))
                 acc = None
                 reported = set()
                 drained = []
@@ -766,61 +467,79 @@ class ASAGA(FlopsAccountingMixin):
                         snapshots.append((now_ms(), w))
                         inst.on_snapshot(rounds * nw)
                 if calibrator.maybe_finalize(k):
-                    delay_model.calibrate(calibrator.avg_delay_ms)
+                    run.delay_model.calibrate(calibrator.avg_delay_ms)
             run_ok = True
         finally:
             clock.waits()  # the loop's last busy stretch
-            if ft is not None:
-                ft.stop()
-            if spec is not None:
-                spec.stop()
-            if alloc is not None:
-                alloc.stop()
-            sched.shutdown()
-            if not run_ok:
-                inst.close()  # crash path: flush/seal the event log now
-
-        final_w = np.asarray(w)  # fence: see the async path's comment
-        elapsed = time.monotonic() - start_wall
-        snapshots.append((elapsed * 1e3, w))
-        inst.on_snapshot(rounds * nw)
-        clock.waited(sched.blocked_ns)
-        extras = {
-            **inst.engine_counters(sched.task_retries, one_thread=True),
-            **inst.extras(),
-        }
-        traj = self._evaluate_trajectory(snapshots)
-        if spec is not None:
-            extras["speculated"] = spec.speculated_count()
-            extras["speculation_wins"] = sched.speculative_wins()
-        if alloc is not None:
-            extras["executors_added"], extras["executors_removed"] = (
-                alloc.counts()
-            )
-        # the final history state, as run() and run_fused() expose it
-        extras["alpha"] = {wid: np.asarray(a) for wid, a in alpha.items()}
-        extras["alpha_bar"] = np.asarray(alpha_bar)
-        extras["history_drift"] = self._history_drift(alpha, alpha_bar)
-        inst.close(traj, cfg.printer_freq)
-        return TrainResult(
-            final_w=final_w,
-            trajectory=traj,
-            elapsed_s=elapsed,
-            accepted=rounds * nw,
-            rounds=rounds,
-            max_staleness=ctx.max_staleness(),
-            avg_delay_ms=calibrator.avg_delay_ms,
-            updates_per_sec=rounds / elapsed if elapsed > 0 else 0.0,
-            total_flops=flops,
-            waiting_time_ms=waiting.snapshot(),
-            extras=extras,
-            snapshot_updates=inst.snapshot_updates,
-            staleness_hist=dict(sorted(inst.staleness_hist.items())),
+            run.shutdown(run_ok)
+        run.state.update(w=w, accepted=rounds * nw, rounds=rounds, flops=flops)
+        return run.result(
+            more_extras=lambda: self._history_extras(alpha, alpha_bar)
         )
 
     # ---------------------------------------------------------------- helpers
-    def _shard_device(self, wid: int):
-        return self.devices[wid % len(self.devices)]
+    def _zero_history(self):
+        """``(alpha_bar, alpha)`` of a cold start: the mean history gradient
+        on the driver's device, and the table, one slice per worker,
+        resident in its HBM."""
+        alpha_bar = jax.device_put(
+            jnp.zeros(self.ds.d, jnp.float32), self.driver_device
+        )
+        alpha = {
+            wid: jax.device_put(
+                jnp.zeros(self.ds.shard(wid).size, jnp.float32),
+                self._shard_device(wid),
+            )
+            for wid in range(self.cfg.num_workers)
+        }
+        return alpha_bar, alpha
+
+    def _history_extras(self, alpha: Dict[int, jax.Array], alpha_bar) -> Dict:
+        """The final history state, as ``run()`` and ``run_sync()`` expose
+        it in ``extras`` (call after the run's clock has stopped)."""
+        return {
+            "alpha": {wid: np.asarray(a) for wid, a in alpha.items()},
+            "alpha_bar": np.asarray(alpha_bar),
+            "history_drift": self._history_drift(alpha, alpha_bar),
+        }
+
+    def _history_follows(self, run: EngineRun, alpha: Dict[int, jax.Array]):
+        """The run's hook for a re-homed shard: its history slice and PRNG
+        chain follow it to the new device."""
+        hot_lock, worker_keys = run.key_lock, run.worker_keys
+
+        def on_shard_moved(shard_id, moved):
+            with hot_lock:
+                alpha[shard_id] = jax.device_put(alpha[shard_id], moved.device)
+                worker_keys[shard_id] = jax.device_put(
+                    worker_keys[shard_id], moved.device
+                )
+
+        return on_shard_moved
+
+    def _task_maker(self, run: EngineRun, alpha: Dict[int, jax.Array]):
+        """``make_tasks`` of this run (``EngineRun.drive``): a task captures
+        its worker's key and history slice, read under one hold of the
+        lock that guards both."""
+        hot_lock, worker_keys = run.key_lock, run.worker_keys
+        delay_model = run.delay_model
+
+        def make_tasks(cohort, w_pub, uts):
+            with hot_lock:
+                captured = {
+                    wid: (worker_keys[wid], alpha[wid]) for wid in cohort
+                }
+            # _make_task is looked up per cohort: a test may replace it on
+            # the instance
+            return {
+                wid: self._make_task(
+                    wid, w_pub, captured[wid][0], captured[wid][1],
+                    delay_model, uts.get(wid),
+                )
+                for wid in cohort
+            }
+
+        return make_tasks
 
     def _history_drift(self, alpha: Dict[int, jax.Array], alpha_bar) -> float:
         """``max |alpha_bar - sum_i alpha_i x_i / n|`` over ``max |sum_i y_i
@@ -940,67 +659,3 @@ class ASAGA(FlopsAccountingMixin):
             return step(shard.X, shard.y, w_local, a_local, key_local)
 
         return worker_task(dispatch, delay_model.delay_ms(wid), ut)
-
-    def _collect_checked(self, ctx: AsyncContext, waiter, timeout_s: float,
-                         pool=None, cohort=None, collected=None):
-        """Shared fail-fast drain (solvers/base.py): surfaces job aborts,
-        and -- given the pool -- aborts promptly with the per-worker
-        liveness diagnostic when a cohort executor dies unreplaced,
-        instead of hanging for the full run timeout."""
-        grace = (
-            4.0 * self.cfg.heartbeat_interval_s + 2.0
-            if self.cfg.heartbeat else 0.5
-        )
-        return collect_checked(
-            ctx, waiter, timeout_s, pool=pool, cohort=cohort,
-            dead_grace_s=grace, collected=collected,
-        )
-
-    def _handler(
-        self, ctx: AsyncContext, submit_clock: int, now_ms, worker_keys,
-        key_lock, uts,
-    ):
-        submit_wall = now_ms()
-        par_recs = int(self.cfg.batch_rate * self.ds.n / self.cfg.num_workers)
-
-        def handler(wid: int, result):
-            *data, new_key = result
-            # advance the key slot before merge_result marks the worker
-            # available (see ASGD._handler for why)
-            with key_lock:
-                worker_keys[wid] = new_key
-            ut = uts.get(wid) if uts else None
-            if ut is not None:
-                ut.begin(trace.RESULT_QUEUE)
-            ctx.merge_result(
-                wid,
-                tuple(data),
-                submit_clock=submit_clock,
-                elapsed_ms=now_ms() - submit_wall,
-                batch_size=par_recs,
-                trace=ut,
-            )
-
-        return handler
-
-    def _evaluate_trajectory(self, snapshots):
-        W = jnp.stack([h for (_t, h) in snapshots])
-        totals = np.zeros(len(snapshots), np.float64)
-        for wid in range(self.cfg.num_workers):
-            shard = self._recovery.shard(wid)  # follows re-homed shards
-            Wd = W
-            if Wd.device != shard.device:
-                Wd = jax.device_put(W, shard.device)
-            if self._sparse:
-                part = self._eval(shard.cols, shard.vals, shard.y, Wd)
-            else:
-                part = self._eval(shard.X, shard.y, Wd)
-            totals += np.asarray(part, np.float64)
-        totals /= self.ds.n
-        traj = [(t, float(l)) for (t, _), l in zip(snapshots, totals)]
-        # continuous telemetry: fold the run's loss-vs-wallclock curve
-        # into the process-global convergence history (see asgd.py)
-        from asyncframework_tpu.metrics import timeseries as _ts
-
-        _ts.fold_trajectory(traj)
-        return traj

@@ -22,44 +22,30 @@ dispatches, not two transfers.
 
 from __future__ import annotations
 
+import operator
 import queue
-import threading
 import time
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from asyncframework_tpu.broadcast import VersionedModelStore
-from asyncframework_tpu.context import AsyncContext
 from asyncframework_tpu.data.sharded import ShardedDataset
-from asyncframework_tpu.engine.barrier import bucket_predicate, partial_barrier
 from asyncframework_tpu.engine.recovery import ShardRecovery
-from asyncframework_tpu.engine.scheduler import ASYNC, JobScheduler
-from asyncframework_tpu.engine.speculation import SpeculationMonitor
 from asyncframework_tpu.engine.straggler import DelayModel
 from asyncframework_tpu.ops import steps
 from asyncframework_tpu.ops.gradients import dense_step_path
 from asyncframework_tpu.solvers.base import (
-    DelayCalibrator,
-    FlopsAccountingMixin,
-    make_allocation_manager,
-    SolverCheckpointer,
     SolverConfig,
     TrainResult,
-    WaitingTimeTable,
     check_hbm_plan,
-    collect_checked,
     resolve_dataset,
+    run_fused_plan,
 )
 from asyncframework_tpu.metrics import trace
-from asyncframework_tpu.solvers.instrumentation import (
-    FaultTolerantRun,
-    RunInstruments,
-    on_device,
-    worker_task,
-)
+from asyncframework_tpu.solvers.engine_loop import EngineRun, EngineSolver
+from asyncframework_tpu.solvers.instrumentation import on_device, worker_task
 
 
 # minimum drained-batch size for the stacked one-dispatch apply: below
@@ -69,7 +55,7 @@ from asyncframework_tpu.solvers.instrumentation import (
 BATCH_DRAIN_MIN = 3
 
 
-class ASGD(FlopsAccountingMixin):
+class ASGD(EngineSolver):
     def __init__(
         self,
         X,
@@ -115,106 +101,31 @@ class ASGD(FlopsAccountingMixin):
         # shard is transparently picked up by later rounds and by evaluation
         self._recovery = ShardRecovery(self.ds, self.devices)
 
+    #: a step returns ``(g, new_key)``: the gradient rides to the updater
+    _result_payload = staticmethod(operator.itemgetter(0))
+
     # ------------------------------------------------------------------ async
     def run(self) -> TrainResult:
         """Asynchronous mode (SparkASGDThread parity)."""
         cfg = self.cfg
-        nw = cfg.num_workers
-        ctx: AsyncContext = AsyncContext()
-        sched = JobScheduler(num_workers=nw, devices=self.devices)
-        sched.set_mode(ASYNC)
-        self.scheduler = sched  # exposed for fault-injection tests/tools
-        delay_model = DelayModel(cfg.coeff, nw, cfg.seed)
-        calibrator = DelayCalibrator(cfg.effective_calibration_iters())
-        waiting = WaitingTimeTable()
-        inst = RunInstruments(cfg, nw)
-        inst.register_queue_depth(ctx.size)
-        ft = None
-        if cfg.heartbeat:
-            ft = FaultTolerantRun(
-                sched, self._recovery, inst, nw,
-                heartbeat_timeout_ms=cfg.heartbeat_timeout_ms,
-                check_interval_s=cfg.heartbeat_interval_s,
-                max_slot_failures=cfg.max_slot_failures,
-            )
-            ft.start()
-        spec = None
-        if cfg.speculation:
-            spec = SpeculationMonitor(
-                sched, quantile=cfg.speculation_quantile,
-                multiplier=cfg.speculation_multiplier,
-                min_time_ms=cfg.speculation_min_ms,
-                on_launch=inst.on_speculative_launch,
-            )
-            spec.start()
-        alloc = make_allocation_manager(cfg, sched)
-        # stale-read experiment: workers read version (latest - offset)
-        store = (
-            VersionedModelStore(cfg.max_live_versions)
-            if cfg.stale_read_offset is not None
-            else None
-        )
-
+        run = EngineRun(self)
+        run.restore("asgd")
+        ctx, inst, waiting = run.ctx, run.inst, run.waiting
+        calibrator, delay_model, ckpt = run.calibrator, run.delay_model, run.ckpt
+        state, state_lock, stop = run.state, run.state_lock, run.stop
         d = self.ds.d
-        ckpt = SolverCheckpointer(cfg, "asgd", d, self.ds.n)
-        ck = ckpt.restore()
-        if ck is not None:
-            # Resume: model, accepted-update counter, logical clock, and every
-            # worker's PRNG chain come back exactly where they stopped.
-            k0 = int(ck["k"])
-            ctx.set_current_time(int(ck["clock"]))
-            w = jax.device_put(jnp.asarray(ck["w"]), self.driver_device)
-            k_dev = jax.device_put(jnp.float32(k0), self.driver_device)
-            worker_keys: Dict[int, jax.Array] = {
-                wid: jax.device_put(jnp.asarray(key), self._shard_device(wid))
-                for wid, key in ck["worker_keys"].items()
-            }
-        else:
-            k0 = 0
-            w = jax.device_put(jnp.zeros(d, jnp.float32), self.driver_device)
-            k_dev = jax.device_put(jnp.float32(0.0), self.driver_device)
-            # per-worker device-resident PRNG chains
-            worker_keys = {
-                wid: jax.device_put(
-                    jax.random.fold_in(jax.random.PRNGKey(cfg.seed), wid),
-                    self._shard_device(wid),
-                )
-                for wid in range(nw)
-            }
-        key_lock = threading.Lock()
-
-        state = {
-            "w": w,
-            "k_dev": k_dev,
-            "k": k0,
-            "accepted": 0,
-            "dropped": 0,
-            "rounds": 0,
-            "flops": 0.0,
-        }
-        state_lock = threading.Lock()
-        stop = threading.Event()
+        # the on-device iteration counter resumes where k stopped
+        state["k_dev"] = jax.device_put(
+            jnp.float32(state["k"]), self.driver_device
+        )
+        run.start_monitors()
         apply_batch = steps.make_asgd_apply_batch(
-            cfg.gamma, cfg.batch_rate, self.ds.n, nw, cfg.drain_batch
+            cfg.gamma, cfg.batch_rate, self.ds.n, cfg.num_workers,
+            cfg.drain_batch,
         )
         self._warm_hot_path(apply_batch, max(cfg.drain_batch, 1))
-        start_wall = time.monotonic()
-        inst.on_run_start()
-        snapshots: List[Tuple[float, jax.Array]] = [(0.0, w)]
-
-        def now_ms() -> float:
-            return (time.monotonic() - start_wall) * 1e3
-
-        # ---------------------------------------------------- updater thread
-        def save_checkpoint(save_k: int, save_w) -> None:
-            with key_lock:
-                keys_h = {wid: np.asarray(kv) for wid, kv in worker_keys.items()}
-            ckpt.save(
-                save_k,
-                w=np.asarray(save_w),
-                clock=ctx.get_current_time(),
-                worker_keys=keys_h,
-            )
+        run.start_clock()
+        snapshots, now_ms = run.snapshots, run.now_ms
 
         # per-accepted-count mask cache: rebuilt host constants would cost
         # an extra transfer per drain.  Short drains pad the gradient LIST
@@ -341,154 +252,14 @@ class ASGD(FlopsAccountingMixin):
                     inst.on_gradient_merged(res, accepted, at_k, task_ms)
                 if do_save:
                     with trace.span(trace.CHECKPOINT):
-                        save_checkpoint(save_k, save_w)
+                        run.save(save_k, save_w)
                 if calibrator.maybe_finalize(state["k"]):
                     delay_model.calibrate(calibrator.avg_delay_ms)
             clock.waits()  # the loop's last busy stretch
             stop.set()
 
-        upd = threading.Thread(target=updater, name="ps-updater", daemon=True)
-        upd.start()
-
-        # ---------------------------------------------------- submitter loop
-        from collections import deque
-
-        waiters: deque = deque(maxlen=4 * nw)  # recent jobs, failure check
-        deadline = time.monotonic() + cfg.run_timeout_s
-        run_ok = False
-        try:
-            while not stop.is_set() and time.monotonic() < deadline:
-                failed = next((x.failed for x in waiters if x.failed), None)
-                if failed is not None:
-                    raise RuntimeError("async job aborted") from failed
-                with state_lock:
-                    if state["k"] >= cfg.num_iterations:
-                        break
-                # cold workers (no STAT entry) always selected; warm workers
-                # only when the availability threshold is met (the reference's
-                # wait loop + ASYNCbarrier combination).  Nothing is
-                # submitted while the updater is a whole fleet of results
-                # behind: a worker is available again the moment its result
-                # is QUEUED, so a device that outruns the updater (32
-                # workers at 0.6 ms a step, PERF.md section 6, PR 26) would
-                # otherwise fill the queue without bound, with gradients
-                # seconds old whose recorded staleness still reads under nw
-                cohort = [] if ctx.size() >= nw else partial_barrier(
-                    ctx, nw, bucket_predicate(ctx, nw, cfg.bucket_ratio)
-                )
-                if not cohort:
-                    inst.submit_empty_polls += 1
-                    inst.submitter_clock.waits()
-                    time.sleep(0.001)
-                    inst.submitter_clock.works()
-                    continue
-                # the sampling decision falls here, at submit: a sampled
-                # update's handle rides its task closure, the handler and
-                # the PartialResult to the updater
-                uts = inst.start_updates(cohort)
-                with trace.span(trace.SUBMIT, uts.values(),
-                                batch=len(cohort)):
-                    with state_lock:
-                        w_pub = state["w"]  # immutable handle = model version
-                        model_version = state["k"]
-                    if store is not None:
-                        # ASYNCbroadcast parity: publish this round's model
-                        # as a new version, then point workers at (latest -
-                        # offset).  The version's device buffer is resolved
-                        # HERE, at submit time: a straggling worker must not
-                        # re-query the store later (the version may have
-                        # been evicted by newer publishes); the captured
-                        # handle keeps the array alive regardless of store
-                        # eviction.
-                        v = store.publish(np.asarray(w_pub))
-                        live = store.live_versions()
-                        tv = max(live[0], v - cfg.stale_read_offset)
-                        w_pub = store.value(self.driver_device, version=tv)
-                        model_version = v
-                    ts = ctx.get_current_time()
-                    ctx.set_last_time(ts)
-                    ctx.mark_busy(cohort)
-                    waiting.on_submit(cohort, now_ms())
-                    if uts:
-                        inst.begin_compute(uts, model_version)
-                    with key_lock:
-                        keys = {wid: worker_keys[wid] for wid in cohort}
-                    fns = {
-                        wid: self._make_task(
-                            wid, w_pub, keys[wid], delay_model, uts.get(wid)
-                        )
-                        for wid in cohort
-                    }
-                    with state_lock:
-                        state["rounds"] += 1
-                        round_idx = state["rounds"]
-                    # post BEFORE launching: a fast worker could otherwise
-                    # merge (and the live UI could observe accepted>0)
-                    # before its round's RoundSubmitted event exists
-                    inst.on_round_submitted(round_idx, cohort, model_version)
-                    waiter = sched.run_job(
-                        fns,
-                        self._handler(
-                            ctx, ts, now_ms, worker_keys, key_lock, uts
-                        ),
-                    )
-                waiters.append(waiter)
-            run_ok = True
-        finally:
-            inst.submitter_clock.waits()  # the loop's last busy stretch
-            stop.set()
-            upd.join(timeout=10)
-            if ft is not None:
-                ft.stop()
-            if spec is not None:
-                spec.stop()
-            if alloc is not None:
-                alloc.stop()
-            sched.shutdown()
-            if not run_ok:
-                inst.close()  # crash path: flush/seal the event log now
-
-        with state_lock:
-            final_k, final_w_dev = state["k"], state["w"]
-        # materialize BEFORE taking elapsed: the readback of the final
-        # model is also the fence (it waits for every apply before it), so
-        # elapsed/updates_per_sec cover the work actually done, not merely
-        # dispatched.  Whether block_until_ready alone suffices here is
-        # ROADMAP Design 6; the result needs final_w on the host anyway.
-        final_w = np.asarray(final_w_dev)
-        elapsed = time.monotonic() - start_wall
-        snapshots.append((elapsed * 1e3, final_w_dev))
-        inst.on_snapshot(state["accepted"])
-        inst.submitter_clock.waited(sched.blocked_ns)
-        extras = {**inst.engine_counters(sched.task_retries), **inst.extras(),
-                  **self._path_extras}
-        if ckpt.enabled:
-            save_checkpoint(final_k, final_w_dev)
-        traj = self._evaluate_trajectory(snapshots)
-        if spec is not None:
-            extras["speculated"] = spec.speculated_count()
-            extras["speculation_wins"] = sched.speculative_wins()
-        if alloc is not None:
-            extras["executors_added"], extras["executors_removed"] = (
-                alloc.counts()
-            )
-        inst.close(traj, cfg.printer_freq)
-        return TrainResult(
-            final_w=final_w,
-            trajectory=traj,
-            elapsed_s=elapsed,
-            accepted=state["accepted"],
-            dropped=state["dropped"],
-            rounds=state["rounds"],
-            max_staleness=ctx.max_staleness(),
-            avg_delay_ms=calibrator.avg_delay_ms,
-            updates_per_sec=state["accepted"] / elapsed if elapsed > 0 else 0.0,
-            total_flops=state["flops"],
-            waiting_time_ms=waiting.snapshot(),
-            extras=extras,
-            snapshot_updates=inst.snapshot_updates,
-            staleness_hist=dict(sorted(inst.staleness_hist.items())),
-        )
+        run.drive(updater, "ps-updater", self._task_maker(run))
+        return run.result()
 
     # ----------------------------------------------------------------- fused
     def run_fused(self) -> TrainResult:
@@ -562,13 +333,11 @@ class ASGD(FlopsAccountingMixin):
             jax.random.fold_in(jax.random.PRNGKey(cfg.seed), wid)
             for wid in range(nw)
         ]), drv)
-        from asyncframework_tpu.solvers.base import run_fused_plan
-
         (w, k, keys), snapshots, start_wall, done_rounds = run_fused_plan(
             make_runner, (w, k, keys), total_rounds, nw, cfg.printer_freq,
             w_of=lambda c: c[0],
         )
-        final_w = np.asarray(w)  # fence BEFORE elapsed (see run())
+        final_w = np.asarray(w)  # fence BEFORE elapsed (EngineRun.result)
         elapsed = time.monotonic() - start_wall
         accepted = done_rounds * nw
         snapshots.append((elapsed * 1e3, w))
@@ -598,58 +367,17 @@ class ASGD(FlopsAccountingMixin):
         """SparkASGDSync parity: submit to all, drain all, one update/round."""
         cfg = self.cfg
         nw = cfg.num_workers
-        ctx: AsyncContext = AsyncContext()
-        sched = JobScheduler(num_workers=nw, devices=self.devices)
-        sched.set_mode(ASYNC)  # non-blocking submit + driver-side drain
-        self.scheduler = sched  # exposed for fault-injection tests/tools
-        delay_model = DelayModel(cfg.coeff, nw, cfg.seed)
-        # sync counts rounds, not accepted gradients: the reference's
-        # k < 100*numPart window covers the first 100 full-drain rounds.
-        # An explicit calibration_iters overrides (in rounds).
-        calibrator = DelayCalibrator(
-            cfg.calibration_iters if cfg.calibration_iters is not None else 100
-        )
-        waiting = WaitingTimeTable()
-        inst = RunInstruments(cfg, nw)
-        inst.register_queue_depth(ctx.size)
-        ft = None
-        if cfg.heartbeat:
-            ft = FaultTolerantRun(
-                sched, self._recovery, inst, nw,
-                heartbeat_timeout_ms=cfg.heartbeat_timeout_ms,
-                check_interval_s=cfg.heartbeat_interval_s,
-                max_slot_failures=cfg.max_slot_failures,
-            )
-            ft.start()
-        spec = None
-        if cfg.speculation:
-            # the reference runs speculation on its synchronous stages: the
-            # full drain is exactly where one straggler stalls the round
-            spec = SpeculationMonitor(
-                sched, quantile=cfg.speculation_quantile,
-                multiplier=cfg.speculation_multiplier,
-                min_time_ms=cfg.speculation_min_ms,
-                on_launch=inst.on_speculative_launch,
-            )
-            spec.start()
-        alloc = make_allocation_manager(cfg, sched)
-
-        w = jax.device_put(jnp.zeros(self.ds.d, jnp.float32), self.driver_device)
+        run = EngineRun(self, sync=True)
+        ctx, sched, inst = run.ctx, run.sched, run.inst
+        waiting, calibrator = run.waiting, run.calibrator
+        run.cold_start()
+        w = run.state["w"]
         k_dev = jax.device_put(jnp.float32(0.0), self.driver_device)
-        worker_keys = {
-            wid: jax.device_put(
-                jax.random.fold_in(jax.random.PRNGKey(cfg.seed), wid),
-                self._shard_device(wid),
-            )
-            for wid in range(nw)
-        }
+        run.start_monitors()
+        make_tasks = self._task_maker(run)
         self._warm_hot_path(sync=True)
-        start_wall = time.monotonic()
-        inst.on_run_start()
-        snapshots: List[Tuple[float, jax.Array]] = [(0.0, w)]
-
-        def now_ms():
-            return (time.monotonic() - start_wall) * 1e3
+        run.start_clock()
+        snapshots, now_ms = run.snapshots, run.now_ms
 
         rounds = 0
         flops = 0.0
@@ -667,21 +395,9 @@ class ASGD(FlopsAccountingMixin):
                     waiting.on_submit(cohort, now_ms())
                     if uts:
                         inst.begin_compute(uts, k)
-                    key_lock = threading.Lock()
-                    fns = {
-                        wid: self._make_task(
-                            wid, w, worker_keys[wid], delay_model,
-                            uts.get(wid),
-                        )
-                        for wid in cohort
-                    }
+                    fns = make_tasks(cohort, w, uts)
                     inst.on_round_submitted(k, cohort, model_version=k)
-                    waiter = sched.run_job(
-                        fns,
-                        self._handler(
-                            ctx, ts, now_ms, worker_keys, key_lock, uts
-                        ),
-                    )
+                    waiter = sched.run_job(fns, self._handler(run, ts, uts))
                 acc = None
                 reported = set()
                 drained = []
@@ -715,73 +431,15 @@ class ASGD(FlopsAccountingMixin):
                         snapshots.append((now_ms(), w))
                         inst.on_snapshot(rounds * nw)
                 if calibrator.maybe_finalize(k):
-                    delay_model.calibrate(calibrator.avg_delay_ms)
+                    run.delay_model.calibrate(calibrator.avg_delay_ms)
             run_ok = True
         finally:
             clock.waits()  # the loop's last busy stretch
-            if ft is not None:
-                ft.stop()
-            if spec is not None:
-                spec.stop()
-            if alloc is not None:
-                alloc.stop()
-            sched.shutdown()
-            if not run_ok:
-                inst.close()  # crash path: flush/seal the event log now
-
-        final_w = np.asarray(w)  # fence: see the async path's comment
-        elapsed = time.monotonic() - start_wall
-        snapshots.append((elapsed * 1e3, w))
-        inst.on_snapshot(rounds * nw)
-        clock.waited(sched.blocked_ns)
-        extras = {
-            **inst.engine_counters(sched.task_retries, one_thread=True),
-            **inst.extras(),
-        }
-        traj = self._evaluate_trajectory(snapshots)
-        if spec is not None:
-            extras["speculated"] = spec.speculated_count()
-            extras["speculation_wins"] = sched.speculative_wins()
-        if alloc is not None:
-            extras["executors_added"], extras["executors_removed"] = (
-                alloc.counts()
-            )
-        inst.close(traj, cfg.printer_freq)
-        return TrainResult(
-            final_w=final_w,
-            trajectory=traj,
-            elapsed_s=elapsed,
-            accepted=rounds * nw,
-            rounds=rounds,
-            max_staleness=ctx.max_staleness(),
-            avg_delay_ms=calibrator.avg_delay_ms,
-            updates_per_sec=rounds / elapsed if elapsed > 0 else 0.0,
-            total_flops=flops,
-            waiting_time_ms=waiting.snapshot(),
-            extras=extras,
-            snapshot_updates=inst.snapshot_updates,
-            staleness_hist=dict(sorted(inst.staleness_hist.items())),
-        )
+            run.shutdown(run_ok)
+        run.state.update(w=w, accepted=rounds * nw, rounds=rounds, flops=flops)
+        return run.result()
 
     # ---------------------------------------------------------------- helpers
-    def _collect_checked(self, ctx: AsyncContext, waiter, timeout_s: float,
-                         pool=None, cohort=None, collected=None):
-        """Shared fail-fast drain (solvers/base.py): surfaces job aborts,
-        and -- given the pool -- aborts promptly with the per-worker
-        liveness diagnostic when a cohort executor dies unreplaced,
-        instead of hanging for the full run timeout."""
-        grace = (
-            4.0 * self.cfg.heartbeat_interval_s + 2.0
-            if self.cfg.heartbeat else 0.5
-        )
-        return collect_checked(
-            ctx, waiter, timeout_s, pool=pool, cohort=cohort,
-            dead_grace_s=grace, collected=collected,
-        )
-
-    def _shard_device(self, wid: int):
-        return self.devices[wid % len(self.devices)]
-
     def _warm_hot_path(
         self, apply_batch=None, max_drain: int = 0, sync: bool = False
     ) -> None:
@@ -862,57 +520,22 @@ class ASGD(FlopsAccountingMixin):
 
         return worker_task(dispatch, delay_model.delay_ms(wid), ut)
 
-    def _handler(
-        self, ctx: AsyncContext, submit_clock: int, now_ms, worker_keys,
-        key_lock, uts,
-    ):
-        submit_wall = now_ms()
-        par_recs = int(self.cfg.batch_rate * self.ds.n / self.cfg.num_workers)
+    def _task_maker(self, run: EngineRun):
+        """``make_tasks`` of this run (``EngineRun.drive``): a task captures
+        its worker's key, read under the run's key lock."""
+        worker_keys, key_lock = run.worker_keys, run.key_lock
+        delay_model = run.delay_model
 
-        def handler(wid: int, result):
-            g, new_key = result
-            # The key slot MUST advance before merge_result flips the worker
-            # available -- otherwise the spinning submitter can re-dispatch
-            # this worker with its previous key and replay the same mask.
+        def make_tasks(cohort, w_pub, uts):
             with key_lock:
-                worker_keys[wid] = new_key
-            ut = uts.get(wid) if uts else None
-            if ut is not None:
-                ut.begin(trace.RESULT_QUEUE)
-            ctx.merge_result(
-                wid,
-                g,
-                submit_clock=submit_clock,
-                elapsed_ms=now_ms() - submit_wall,
-                batch_size=par_recs,
-                trace=ut,
-            )
+                keys = {wid: worker_keys[wid] for wid in cohort}
+            # _make_task is looked up per cohort: a test may replace it on
+            # the instance
+            return {
+                wid: self._make_task(
+                    wid, w_pub, keys[wid], delay_model, uts.get(wid)
+                )
+                for wid in cohort
+            }
 
-        return handler
-
-    def _evaluate_trajectory(
-        self, snapshots: List[Tuple[float, jax.Array]]
-    ) -> List[Tuple[float, float]]:
-        """One-pass objective evaluation for all snapshots (optVars parity):
-        stack snapshots into (S, d); per shard one matmul gives (S,) losses."""
-        W = jnp.stack([h for (_t, h) in snapshots])
-        totals = np.zeros(len(snapshots), np.float64)
-        for wid in range(self.cfg.num_workers):
-            shard = self._recovery.shard(wid)  # follows re-homed shards
-            Wd = W
-            if Wd.device != shard.device:
-                Wd = jax.device_put(W, shard.device)
-            if self._sparse:
-                part = self._eval(shard.cols, shard.vals, shard.y, Wd)
-            else:
-                part = self._eval(shard.X, shard.y, Wd)
-            totals += np.asarray(part, np.float64)
-        totals /= self.ds.n
-        traj = [(t, float(l)) for (t, _), l in zip(snapshots, totals)]
-        # continuous telemetry: the finished run's loss-vs-wallclock curve
-        # lands in the process-global convergence history (the /api/status
-        # `convergence` section the in-process live UI serves)
-        from asyncframework_tpu.metrics import timeseries as _ts
-
-        _ts.fold_trajectory(traj)
-        return traj
+        return make_tasks

@@ -491,21 +491,3 @@ class DelayCalibrator:
                 self.calibrated = True
                 return True
             return False
-
-
-def make_allocation_manager(cfg: "SolverConfig", scheduler):
-    """Start a dynamic-allocation manager when the config asks for one
-    (``ExecutorAllocationManager`` parity); returns None otherwise.  Shared
-    by every solver run path."""
-    if not cfg.dynamic_allocation:
-        return None
-    from asyncframework_tpu.engine.allocation import ExecutorAllocationManager
-
-    mgr = ExecutorAllocationManager(
-        scheduler,
-        max_extra_per_slot=cfg.allocation_max_extra,
-        backlog_threshold=cfg.allocation_backlog_threshold,
-        idle_timeout_s=cfg.allocation_idle_timeout_s,
-    )
-    mgr.start()
-    return mgr

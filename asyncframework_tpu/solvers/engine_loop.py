@@ -1,0 +1,442 @@
+"""One engine loop: what every run of the engine's solvers does alike.
+
+:class:`EngineRun` is one run: the context, scheduler, delay model,
+calibrator, waiting table and instruments; the heartbeat, speculation and
+allocation monitors; the restore of the checkpoint fields every solver
+saves; the submitter loop; the teardown; the fenced read-back and the
+:class:`~asyncframework_tpu.solvers.base.TrainResult`.  A solver's ``run``
+and ``run_sync`` are what is left: its state, its updater, its extras.
+
+What differs between solvers reaches this module as a callable or a dict
+(the updater, what a cohort's tasks capture, the hook for a re-homed shard,
+the solver's own checkpoint and result fields); nothing here asks which
+solver it serves.  The updater in particular is taken whole and only
+started, joined and clocked: its body stays ONE frame in the solver,
+because an accept path behind a per-result call drops its temporaries under
+the state lock while the dispatches that read them are in flight (PERF.md
+section 6, PR 23 and PR 25: 15% to a third of the update rate on the CPU
+rehearsal).
+"""
+
+from __future__ import annotations
+
+import threading
+import time
+from collections import deque
+from typing import Callable, Dict, List, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from asyncframework_tpu.broadcast import VersionedModelStore
+from asyncframework_tpu.context import AsyncContext
+from asyncframework_tpu.engine.allocation import make_allocation_manager
+from asyncframework_tpu.engine.barrier import bucket_predicate, partial_barrier
+from asyncframework_tpu.engine.scheduler import ASYNC, JobScheduler
+from asyncframework_tpu.engine.speculation import SpeculationMonitor
+from asyncframework_tpu.engine.straggler import DelayModel
+from asyncframework_tpu.metrics import timeseries, trace
+from asyncframework_tpu.solvers.base import (
+    DelayCalibrator,
+    FlopsAccountingMixin,
+    SolverCheckpointer,
+    TrainResult,
+    WaitingTimeTable,
+    collect_checked,
+)
+from asyncframework_tpu.solvers.instrumentation import (
+    FaultTolerantRun,
+    RunInstruments,
+)
+
+
+class EngineSolver(FlopsAccountingMixin):
+    """What the engine's solvers share below their run methods.  Hosts
+    provide ``cfg``, ``devices``, ``ds``, ``_recovery``, ``_sparse``,
+    ``_eval`` (the trajectory's loss evaluation), ``_path_extras`` and
+    ``_result_payload``: what of a worker step's outputs ``(..., new_key)``
+    rides the ``PartialResult`` to the updater (everything but the key)."""
+
+    def _collect_checked(self, ctx: AsyncContext, waiter, timeout_s: float,
+                         pool=None, cohort=None, collected=None):
+        """Shared fail-fast drain (solvers/base.py): surfaces job aborts,
+        and -- given the pool -- aborts promptly with the per-worker
+        liveness diagnostic when a cohort executor dies unreplaced,
+        instead of hanging for the full run timeout."""
+        grace = (
+            4.0 * self.cfg.heartbeat_interval_s + 2.0
+            if self.cfg.heartbeat else 0.5
+        )
+        return collect_checked(
+            ctx, waiter, timeout_s, pool=pool, cohort=cohort,
+            dead_grace_s=grace, collected=collected,
+        )
+
+    def _shard_device(self, wid: int):
+        return self.devices[wid % len(self.devices)]
+
+    def _handler(self, run: "EngineRun", submit_clock: int, uts):
+        """The result handler of one submitted cohort (it runs on the
+        completing executor's thread): advance the worker's key, then
+        queue the step's payload for the updater."""
+        ctx, now_ms = run.ctx, run.now_ms
+        worker_keys, key_lock = run.worker_keys, run.key_lock
+        payload_of = self._result_payload
+        submit_wall = now_ms()
+        par_recs = int(self.cfg.batch_rate * self.ds.n / self.cfg.num_workers)
+
+        def handler(wid: int, result):
+            # The key slot MUST advance before merge_result flips the worker
+            # available -- otherwise the spinning submitter can re-dispatch
+            # this worker with its previous key and replay the same mask.
+            with key_lock:
+                worker_keys[wid] = result[-1]
+            ut = uts.get(wid) if uts else None
+            if ut is not None:
+                ut.begin(trace.RESULT_QUEUE)
+            ctx.merge_result(
+                wid,
+                payload_of(result),
+                submit_clock=submit_clock,
+                elapsed_ms=now_ms() - submit_wall,
+                batch_size=par_recs,
+                trace=ut,
+            )
+
+        return handler
+
+    def _evaluate_trajectory(
+        self, snapshots: List[Tuple[float, jax.Array]]
+    ) -> List[Tuple[float, float]]:
+        """One-pass objective evaluation for all snapshots (optVars parity):
+        stack snapshots into (S, d); per shard one matmul gives (S,) losses."""
+        W = jnp.stack([h for (_t, h) in snapshots])
+        totals = np.zeros(len(snapshots), np.float64)
+        for wid in range(self.cfg.num_workers):
+            shard = self._recovery.shard(wid)  # follows re-homed shards
+            Wd = W
+            if Wd.device != shard.device:
+                Wd = jax.device_put(W, shard.device)
+            if self._sparse:
+                part = self._eval(shard.cols, shard.vals, shard.y, Wd)
+            else:
+                part = self._eval(shard.X, shard.y, Wd)
+            totals += np.asarray(part, np.float64)
+        totals /= self.ds.n
+        traj = [(t, float(l)) for (t, _), l in zip(snapshots, totals)]
+        # continuous telemetry: the finished run's loss-vs-wallclock curve
+        # lands in the process-global convergence history (the /api/status
+        # `convergence` section the in-process live UI serves)
+        timeseries.fold_trajectory(traj)
+        return traj
+
+
+class EngineRun:
+    """One run of an :class:`EngineSolver`, asynchronous or (``sync``) one
+    driver thread that submits to all and drains all each round.
+
+    ``solver.cfg`` is read here, when the run is built: a solver object
+    is reusable, and its ``cfg`` may be re-assigned between runs."""
+
+    def __init__(self, solver: EngineSolver, sync: bool = False):
+        cfg = solver.cfg
+        nw = cfg.num_workers
+        self.solver, self.cfg, self.sync = solver, cfg, sync
+        self.ctx: AsyncContext = AsyncContext()
+        self.sched = JobScheduler(num_workers=nw, devices=solver.devices)
+        # non-blocking submit in both modes: a sync run drains on the driver
+        self.sched.set_mode(ASYNC)
+        solver.scheduler = self.sched  # exposed for fault-injection tests/tools
+        self.delay_model = DelayModel(cfg.coeff, nw, cfg.seed)
+        # sync counts rounds, not accepted gradients: the reference's
+        # k < 100*numPart window covers the first 100 full-drain rounds.
+        # An explicit calibration_iters overrides (in rounds).
+        self.calibrator = DelayCalibrator(
+            100 if sync and cfg.calibration_iters is None
+            else cfg.effective_calibration_iters()
+        )
+        self.waiting = WaitingTimeTable()
+        self.inst = RunInstruments(cfg, nw)
+        self.inst.register_queue_depth(self.ctx.size)
+        self.ckpt: Optional[SolverCheckpointer] = None
+        #: every worker's PRNG chain, on its shard's device; ``key_lock``
+        #: guards the slots (a solver may keep further per-worker handle
+        #: slots under the same lock)
+        self.worker_keys: Dict[int, jax.Array] = {}
+        self.key_lock = threading.Lock()
+        #: the model handle ``w``, the accepted count ``k`` and the run's
+        #: counters, under ``state_lock``; the solver adds its own fields
+        self.state: Dict[str, object] = {}
+        self.state_lock = threading.Lock()
+        self.stop = threading.Event()
+        self._ft = self._spec = self._alloc = None
+
+    # ------------------------------------------------------------ the state
+    def cold_start(self) -> None:
+        """``w = 0`` on the driver's device; every worker's PRNG chain at
+        its start, resident on its shard's device."""
+        solver, cfg = self.solver, self.cfg
+        self._set_state(
+            jnp.zeros(solver.ds.d, jnp.float32), 0,
+            {wid: jax.random.fold_in(jax.random.PRNGKey(cfg.seed), wid)
+             for wid in range(cfg.num_workers)},
+        )
+
+    def restore(self, name: str) -> Optional[Dict]:
+        """Resume from ``cfg.checkpoint_dir``'s latest checkpoint of solver
+        ``name``, or start cold: the model, the accepted-update counter,
+        the logical clock and every worker's PRNG chain come back exactly
+        where they stopped.  Returns the checkpoint (None on a cold start)
+        for the solver's own fields."""
+        solver = self.solver
+        self.ckpt = SolverCheckpointer(
+            self.cfg, name, solver.ds.d, solver.ds.n
+        )
+        ck = self.ckpt.restore()
+        if ck is None:
+            self.cold_start()
+        else:
+            self.ctx.set_current_time(int(ck["clock"]))
+            self._set_state(
+                jnp.asarray(ck["w"]), int(ck["k"]),
+                {wid: jnp.asarray(key)
+                 for wid, key in ck["worker_keys"].items()},
+            )
+        return ck
+
+    def _set_state(self, w, k: int, keys: Dict) -> None:
+        solver = self.solver
+        self.worker_keys.update(
+            (wid, jax.device_put(key, solver._shard_device(wid)))
+            for wid, key in keys.items()
+        )
+        self.state.update(
+            w=jax.device_put(w, solver.driver_device), k=k, accepted=0,
+            dropped=0, rounds=0, flops=0.0,
+        )
+
+    def save(self, k: int, w, **fields) -> None:
+        """Checkpoint the fields every solver saves plus the solver's own
+        ``fields`` (host values)."""
+        with self.key_lock:
+            keys_h = {
+                wid: np.asarray(kv) for wid, kv in self.worker_keys.items()
+            }
+        self.ckpt.save(
+            k, w=np.asarray(w), clock=self.ctx.get_current_time(),
+            worker_keys=keys_h, **fields,
+        )
+
+    # ---------------------------------------------------------- the monitors
+    def start_monitors(self, on_moved: Optional[Callable] = None) -> None:
+        """Heartbeat (executor replacement, shard re-homing), speculation
+        and dynamic allocation, each where ``cfg`` asks for it.
+        ``on_moved(shard_id, moved_shard)``: what of the solver's state
+        follows a re-homed shard to its new device."""
+        cfg, sched, inst = self.cfg, self.sched, self.inst
+        if cfg.heartbeat:
+            self._ft = FaultTolerantRun(
+                sched, self.solver._recovery, inst, cfg.num_workers,
+                heartbeat_timeout_ms=cfg.heartbeat_timeout_ms,
+                check_interval_s=cfg.heartbeat_interval_s,
+                max_slot_failures=cfg.max_slot_failures,
+                on_moved=on_moved,
+            )
+            self._ft.start()
+        if cfg.speculation:
+            # in a sync run too: the reference runs speculation on its
+            # synchronous stages, where one straggler stalls the round
+            self._spec = SpeculationMonitor(
+                sched, quantile=cfg.speculation_quantile,
+                multiplier=cfg.speculation_multiplier,
+                min_time_ms=cfg.speculation_min_ms,
+                on_launch=inst.on_speculative_launch,
+            )
+            self._spec.start()
+        self._alloc = make_allocation_manager(cfg, sched)
+
+    def shutdown(self, run_ok: bool) -> None:
+        """Stop what :meth:`start_monitors` started, and the scheduler."""
+        for monitor in (self._ft, self._spec, self._alloc):
+            if monitor is not None:
+                monitor.stop()
+        self.sched.shutdown()
+        if not run_ok:
+            self.inst.close()  # crash path: flush/seal the event log now
+
+    # -------------------------------------------------------------- the clock
+    def start_clock(self) -> None:
+        """The trajectory's clock starts (after the solver's warm-up)."""
+        self.start_wall = time.monotonic()
+        self.inst.on_run_start()
+        self.snapshots: List[Tuple[float, jax.Array]] = [
+            (0.0, self.state["w"])
+        ]
+
+    def now_ms(self) -> float:
+        return (time.monotonic() - self.start_wall) * 1e3
+
+    # -------------------------------------------------------- submitter loop
+    def drive(self, updater: Callable[[], None], thread_name: str,
+              make_tasks: Callable) -> None:
+        """Start ``updater`` on its thread and submit cohorts from this one
+        until the iteration budget is spent, the updater stops the run or
+        ``run_timeout_s`` passes; then tear the run down.
+
+        ``make_tasks(cohort, w_pub, uts)`` gives the cohort's task closures
+        by worker id: what a task captures (under which lock) is the
+        solver's.  The updater owns ``state`` past ``w`` and ``k``; it ends
+        with ``stop.set()``."""
+        cfg, ctx, sched, inst = self.cfg, self.ctx, self.sched, self.inst
+        solver, waiting, now_ms = self.solver, self.waiting, self.now_ms
+        state, state_lock, stop = self.state, self.state_lock, self.stop
+        clock = inst.submitter_clock
+        nw, budget, ratio = cfg.num_workers, cfg.num_iterations, cfg.bucket_ratio
+        # stale-read experiment (ASYNCbroadcast.value(index) parity; the
+        # reference's main user is SparkASAGAThread.scala:268): workers read
+        # model version (latest - offset)
+        store = (
+            VersionedModelStore(cfg.max_live_versions)
+            if cfg.stale_read_offset is not None
+            else None
+        )
+        upd = threading.Thread(target=updater, name=thread_name, daemon=True)
+        upd.start()
+        waiters: deque = deque(maxlen=4 * nw)  # recent jobs, failure check
+        deadline = time.monotonic() + cfg.run_timeout_s
+        run_ok = False
+        try:
+            while not stop.is_set() and time.monotonic() < deadline:
+                failed = next((x.failed for x in waiters if x.failed), None)
+                if failed is not None:
+                    raise RuntimeError("async job aborted") from failed
+                with state_lock:
+                    if state["k"] >= budget:
+                        break
+                # cold workers (no STAT entry) always selected; warm workers
+                # only when the availability threshold is met (the reference's
+                # wait loop + ASYNCbarrier combination).  Nothing is
+                # submitted while the updater is a whole fleet of results
+                # behind: a worker is available again the moment its result
+                # is QUEUED, so a device that outruns the updater (32
+                # workers at 0.6 ms a step, PERF.md section 6, PR 26) would
+                # otherwise fill the queue without bound, with gradients
+                # seconds old whose recorded staleness still reads under nw
+                cohort = [] if ctx.size() >= nw else partial_barrier(
+                    ctx, nw, bucket_predicate(ctx, nw, ratio)
+                )
+                if not cohort:
+                    inst.submit_empty_polls += 1
+                    clock.waits()
+                    time.sleep(0.001)
+                    clock.works()
+                    continue
+                # the sampling decision falls here, at submit: a sampled
+                # update's handle rides its task closure, the handler and
+                # the PartialResult to the updater
+                uts = inst.start_updates(cohort)
+                with trace.span(trace.SUBMIT, uts.values(),
+                                batch=len(cohort)):
+                    with state_lock:
+                        w_pub = state["w"]  # immutable handle = model version
+                        model_version = state["k"]
+                    if store is not None:
+                        # ASYNCbroadcast parity: publish this round's model
+                        # as a new version, then point workers at (latest -
+                        # offset).  The version's device buffer is resolved
+                        # HERE, at submit time: a straggling worker must not
+                        # re-query the store later (the version may have
+                        # been evicted by newer publishes); the captured
+                        # handle keeps the array alive regardless of store
+                        # eviction.
+                        v = store.publish(np.asarray(w_pub))
+                        live = store.live_versions()
+                        tv = max(live[0], v - cfg.stale_read_offset)
+                        w_pub = store.value(solver.driver_device, version=tv)
+                        model_version = v
+                    ts = ctx.get_current_time()
+                    ctx.set_last_time(ts)
+                    ctx.mark_busy(cohort)
+                    waiting.on_submit(cohort, now_ms())
+                    if uts:
+                        inst.begin_compute(uts, model_version)
+                    fns = make_tasks(cohort, w_pub, uts)
+                    with state_lock:
+                        state["rounds"] += 1
+                        round_idx = state["rounds"]
+                    # post BEFORE launching: a fast worker could otherwise
+                    # merge (and the live UI could observe accepted>0)
+                    # before its round's RoundSubmitted event exists
+                    inst.on_round_submitted(round_idx, cohort, model_version)
+                    waiter = sched.run_job(
+                        fns, solver._handler(self, ts, uts)
+                    )
+                waiters.append(waiter)
+            run_ok = True
+        finally:
+            clock.waits()  # the loop's last busy stretch
+            stop.set()
+            upd.join(timeout=10)
+            self.shutdown(run_ok)
+
+    # ------------------------------------------------------------ the result
+    def result(self, checkpoint: Optional[Callable[[], Dict]] = None,
+               more_extras: Optional[Callable[[], Dict]] = None
+               ) -> TrainResult:
+        """Fence, stop the clock and assemble the result from ``state``.
+        ``checkpoint()``: the solver's own fields of the final checkpoint;
+        ``more_extras()``: its own ``extras``, computed after the clock has
+        stopped and the counters are read."""
+        cfg, inst, sched, state = self.cfg, self.inst, self.sched, self.state
+        with self.state_lock:
+            final_k, final_w_dev = state["k"], state["w"]
+            accepted, rounds = state["accepted"], state["rounds"]
+        # materialize BEFORE taking elapsed: the readback of the final
+        # model is also the fence (it waits for every apply before it), so
+        # elapsed/updates_per_sec cover the work actually done, not merely
+        # dispatched.  Whether block_until_ready alone suffices here is
+        # ROADMAP Design 8; the result needs final_w on the host anyway.
+        final_w = np.asarray(final_w_dev)
+        elapsed = time.monotonic() - self.start_wall
+        self.snapshots.append((elapsed * 1e3, final_w_dev))
+        inst.on_snapshot(accepted)
+        # a sync run's one driver thread is clocked as the updater
+        clock = inst.updater_clock if self.sync else inst.submitter_clock
+        clock.waited(sched.blocked_ns)
+        extras = {
+            **inst.engine_counters(sched.task_retries, one_thread=self.sync),
+            **inst.extras(), **self.solver._path_extras,
+        }
+        if self.ckpt is not None and self.ckpt.enabled:
+            self.save(final_k, final_w_dev,
+                      **(checkpoint() if checkpoint is not None else {}))
+        traj = self.solver._evaluate_trajectory(self.snapshots)
+        if self._spec is not None:
+            extras["speculated"] = self._spec.speculated_count()
+            extras["speculation_wins"] = sched.speculative_wins()
+        if self._alloc is not None:
+            extras["executors_added"], extras["executors_removed"] = (
+                self._alloc.counts()
+            )
+        inst.close(traj, cfg.printer_freq)
+        if more_extras is not None:
+            extras = {**more_extras(), **extras}
+        # one update a round in a sync run, one an accepted gradient else
+        updates = rounds if self.sync else accepted
+        return TrainResult(
+            final_w=final_w,
+            trajectory=traj,
+            elapsed_s=elapsed,
+            accepted=accepted,
+            dropped=state["dropped"],
+            rounds=rounds,
+            max_staleness=self.ctx.max_staleness(),
+            avg_delay_ms=self.calibrator.avg_delay_ms,
+            updates_per_sec=updates / elapsed if elapsed > 0 else 0.0,
+            total_flops=state["flops"],
+            waiting_time_ms=self.waiting.snapshot(),
+            extras=extras,
+            snapshot_updates=inst.snapshot_updates,
+            staleness_hist=dict(sorted(inst.staleness_hist.items())),
+        )

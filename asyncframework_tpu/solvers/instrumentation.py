@@ -221,7 +221,7 @@ class RunInstruments:
         # there is no wire here, so the pull/push stages are the DCN
         # path's).  Sampled spans go to the bus as TraceSpan events (->
         # event log / live UI) and a bus listener folds them into the
-        # process-global aggregator (bench.py --trace-jsonl reads it).
+        # process-global aggregator (benchmark/run.py reads it).
         # EXPLICIT opt-in only (cfg.trace_sample / --trace-sample /
         # --conf async.trace.sample): the conf default governs the DCN
         # plane, where stages are network-dominated -- here the updater

@@ -53,9 +53,9 @@ from asyncframework_tpu.utils import devices as devices_util
 REPO = os.path.dirname(os.path.abspath(__file__))
 TAW_INF = "2147483647"
 
-#: the shapes.  "full": shape, workers, gamma and batch rate of bench.py's
-#: CONFIGS["epsilon"] and CONFIGS["rcv1"].  The sparse gamma is NOT
-#: bench.py's 2361.8 (= 0.05 * d for unit-norm rows): the CLI's synthetic
+#: the shapes.  "full": shape, workers, gamma and batch rate of the
+#: reference's epsilon and rcv1 recipes.  The sparse gamma is NOT
+#: 2361.8 (= 0.05 * d, right for unit-norm rows): the CLI's synthetic
 #: sparse rows carry nnz = int(density * d) = 75 N(0,1) entries, so
 #: E[x x^T] = (nnz/d) I and the same contraction needs
 #: gamma = 0.05 * d / nnz = 31.25 (picked on the CPU at 69,764 x 4,724,

@@ -393,92 +393,3 @@ class TestTwoProcessPipelined:
         pl = ps_dcn.pipeline_totals()
         assert pl.get("pushes_async", 0) >= 400, pl
         assert pl.get("inflight_max", 0) >= 1, pl
-
-
-# --------------------------------------------------------- bench probe
-class TestBenchProbeCache:
-    @staticmethod
-    def _hanging_popen(calls):
-        import bench
-
-        class FakeProc:
-            returncode = None
-
-            def communicate(self, timeout=None):
-                raise subprocess.TimeoutExpired(cmd="probe",
-                                                timeout=timeout or 1)
-
-            def kill(self):
-                pass
-
-        def fake_popen(*a, **kw):
-            calls["n"] += 1
-            return FakeProc()
-
-        return bench, fake_popen
-
-    def test_probe_failure_cached_success_not(self, monkeypatch):
-        calls = {"n": 0}
-        bench, fake_popen = self._hanging_popen(calls)
-        monkeypatch.setattr(bench.subprocess, "Popen", fake_popen)
-        bench._PROBE_FAILURES.clear()
-        try:
-            alive, note = bench.probe_backend({})
-            assert not alive
-            assert calls["n"] == bench.PROBE_ATTEMPTS
-            # second probe for the same platform: answered from cache,
-            # zero new subprocess spend
-            alive2, note2 = bench.probe_backend({})
-            assert not alive2 and note2 == note
-            assert calls["n"] == bench.PROBE_ATTEMPTS
-            # a DIFFERENT platform still probes
-            bench.probe_backend({"BENCH_PLATFORM": "cpu"})
-            assert calls["n"] == 2 * bench.PROBE_ATTEMPTS
-        finally:
-            bench._PROBE_FAILURES.clear()
-
-    def test_probe_budget_hard_bound(self, monkeypatch):
-        """BENCH_PROBE_BUDGET_S caps the WHOLE probe: a hung attempt
-        consumes wall clock, and once the budget is spent no further
-        attempt is launched -- a hung backend init can never wedge the
-        probe itself.  Driven with a fake
-        clock so attempt 1 genuinely RUNS and eats the budget."""
-        import types
-
-        calls = {"n": 0}
-        bench, _unused = self._hanging_popen(calls)
-        clock = {"t": 0.0}
-
-        class FakeProc:
-            returncode = None
-
-            def communicate(self, timeout=None):
-                # a hung child: the wait consumes its whole timeout
-                clock["t"] += float(timeout or 1.0)
-                raise subprocess.TimeoutExpired(cmd="probe",
-                                                timeout=timeout or 1)
-
-            def kill(self):
-                pass
-
-        def fake_popen(*a, **kw):
-            calls["n"] += 1
-            return FakeProc()
-
-        monkeypatch.setattr(bench.subprocess, "Popen", fake_popen)
-        monkeypatch.setattr(
-            bench, "time",
-            types.SimpleNamespace(monotonic=lambda: clock["t"]))
-        monkeypatch.setattr(bench, "PROBE_BUDGET_S", 60.0)
-        monkeypatch.setattr(bench, "_reap_detached", lambda p: None)
-        bench._PROBE_FAILURES.clear()
-        try:
-            alive, note = bench.probe_backend({"BENCH_PLATFORM": "x"})
-            assert not alive and "budget" in note
-            # attempt 1 RAN with its timeout capped to the remaining
-            # budget (min(75, 60) = 60), consumed it all, and attempt 2
-            # was never launched
-            assert calls["n"] == 1
-            assert clock["t"] <= 60.0 + 1e-6
-        finally:
-            bench._PROBE_FAILURES.clear()

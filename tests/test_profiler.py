@@ -483,31 +483,6 @@ class TestCLI:
         loaded = profiler.load_profiles(str(tmp_path))
         assert set(loaded) == {"flight-x", "raw"}
 
-    def test_bench_profile_block_never_dark_and_xcheck(self, rng):
-        import bench
-
-        # no profiler installed: an error record, not an exception
-        profiler.uninstall()
-        profiler._last_final = None
-        blk = bench.profile_block(profiler, {})
-        assert "error" in blk
-        # installed: zone ms + the trace cross-check at the stated tol
-        profiler.install("t-bench", hz=0)
-        _pump_frames(n=2)
-        blk = bench.profile_block(profiler, {})
-        assert blk["zone_ms"].get("wire.encode", 0) > 0
-        assert blk["trace_xcheck"]["ok"] is None  # no stages to check
-        wire_ms = sum(v for z, v in blk["zone_ms"].items()
-                      if z.startswith("wire."))
-        stages = {"push": {"p50": wire_ms, "count": 1}}
-        ok_blk = bench.profile_block(profiler, stages)
-        assert ok_blk["trace_xcheck"]["ok"] is True
-        bad = {"push": {"p50": wire_ms
-                        / (10 * bench.PROFILE_TRACE_TOLERANCE + 1e-9),
-                        "count": 1}}
-        assert bench.profile_block(profiler, bad)["trace_xcheck"]["ok"] \
-            is False
-
 
 # ------------------------------------------------------------- acceptance
 def _make_cfg(**kw):

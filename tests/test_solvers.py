@@ -364,29 +364,6 @@ class TestBF16AndFlops:
         )
         assert res.total_flops == pytest.approx(20 * 8 * per_task, rel=0.01)
 
-    def test_chip_peak_lookup(self):
-        from asyncframework_tpu.utils.flops import chip_peak_flops, mfu
-
-        class FakeTPU:
-            platform = "tpu"
-            device_kind = "TPU v5 lite"
-
-        class FakeCPU:
-            platform = "cpu"
-            device_kind = "cpu"
-
-        class UnknownTPU:
-            platform = "tpu"
-            device_kind = "TPU v99"
-
-        assert chip_peak_flops(FakeTPU()) == 197e12
-        assert chip_peak_flops(FakeCPU()) is None
-        # a chip that is not in the table is an error, never a default
-        with pytest.raises(ValueError, match="TPU v99"):
-            chip_peak_flops(UnknownTPU())
-        assert mfu(197e12, 1.0, FakeTPU()) == pytest.approx(1.0)
-        assert mfu(1e9, 1.0, FakeCPU()) is None
-
 
 @pytest.mark.parametrize("solver", [ASGD, ASAGA], ids=["asgd", "asaga"])
 def test_submitter_does_not_outrun_the_updater(devices8, problem,

@@ -514,6 +514,7 @@ class TestRunSyncFailFast:
         full run timeout; now it aborts within the dead-grace window and
         the error names the dead worker with per-worker liveness."""
         from asyncframework_tpu.solvers import asgd as asgd_mod
+        from asyncframework_tpu.solvers import engine_loop
         from asyncframework_tpu.solvers.base import DeadWorkerError
 
         class SlowW2:
@@ -529,7 +530,7 @@ class TestRunSyncFailFast:
             def calibrate(self, avg_ms):
                 pass
 
-        monkeypatch.setattr(asgd_mod, "DelayModel", SlowW2)
+        monkeypatch.setattr(engine_loop, "DelayModel", SlowW2)
         X = np.random.default_rng(0).normal(size=(256, 8)).astype(np.float32)
         y = X @ np.ones(8, np.float32)
         cfg = make_cfg(num_iterations=50, heartbeat=False,

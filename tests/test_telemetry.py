@@ -400,14 +400,6 @@ class TestSLOStateMachine:
         eng.reset()
         assert eng._states["a"].fired_count == 0
 
-    def test_bench_verdicts(self):
-        out = slo.bench_verdicts(
-            300.0, [(0.0, 1.0), (1000.0, 0.5)])
-        assert out["updates_floor"]["state"] == slo.OK
-        assert out["serve_freshness"]["state"] == slo.NO_DATA
-        out2 = slo.bench_verdicts(0.1, [])
-        assert out2["updates_floor"]["state"] == "violated"
-
 
 # -------------------------------------------------- freshness-lag SLO signal
 class TestFreshnessLagSignal:
