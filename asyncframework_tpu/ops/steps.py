@@ -204,14 +204,18 @@ def make_saga_worker_step(batch_rate: float):
     :func:`make_saga_table_delta` does and for its reason: while the slice
     is unchanged between dispatch and accept, ``g`` IS the table's change,
     on every backend and for every storage dtype, which is what lets the
-    sync drain, ``run_fused`` and the DCN plane take ``delta == g``.
+    sync drain, ``run_fused`` and the DCN plane take ``delta == g``, and
+    the async engine on every accept that finds the slice as the step
+    read it.
 
     The byte model is :func:`make_asgd_worker_step`'s: ONE read of the
     shard on the TPU (the one-pass kernel takes ``alpha`` in and writes
     ``diff`` out beside ``g``: 12 bytes a row on top of the shard), two
-    where ``gradients.dense_step_path`` says so.  An accepted update still
-    pays a second read on the updater's side: the table delta is a product
-    against the history AT COMMIT, which no worker step can know.
+    where ``gradients.dense_step_path`` says so.  An accepted update pays
+    a second read on the updater's side only where the slice moved on
+    while the step was in flight (``ASAGA.run`` counts both kinds): the
+    table delta is a product against the history AT COMMIT, which no
+    worker step can know.
     """
 
     @jax.jit
@@ -245,11 +249,14 @@ def make_saga_apply(
     build distinguishes them).
 
     Donation: ``alpha_bar`` is always donated (its old value is never
-    retained).  ``g`` is donated only when ``donate_g`` -- the sync drain
-    passes the SAME accumulator buffer as both ``g`` and ``delta``, and a
-    buffer may not be donated while also read through another argument, so
-    the sync instance sets ``donate_g=False``.  ``w`` is never donated (old
-    handles are live model versions).
+    retained).  ``g`` is donated only when ``donate_g`` -- a caller that
+    passes the SAME buffer as both ``g`` and ``delta`` sets
+    ``donate_g=False``, since a buffer may not be donated while also read
+    through another argument: the sync drain with its accumulator, and
+    the async engine on an accept whose step read the history slice that
+    still stands (``g`` IS the delta there).  The async engine calls the
+    donating instance on the other accepts, with the delta it recomputed.
+    ``w`` is never donated (old handles are live model versions).
     """
     par_recs = batch_rate * n / num_workers
     donate = (1, 2) if donate_g else (1,)
@@ -275,8 +282,10 @@ def make_saga_table_delta():
     latencies make overlapped same-worker dispatch rare; on a TPU with fast
     overlapped rounds the drift diverges constant-step ASAGA in ~500 updates).
     Recomputing the delta against the *current* table slice at commit time
-    keeps the ``alpha_bar == mean(table)`` invariant exact at the cost of one
-    extra matvec per accepted update.
+    keeps the ``alpha_bar == mean(table)`` invariant exact, at the cost of
+    one extra matvec on every accepted update whose slice was replaced
+    between its dispatch and its accept; on the others the engine takes
+    the worker's ``g``, which is this product already (``ASAGA.run``).
 
     The vector stays f32 and the SHARD is promoted (``X.T @ v``, not
     ``mm_f32``, which would round ``v`` to a bf16 shard's dtype): the

@@ -54,6 +54,17 @@ from asyncframework_tpu.solvers.engine_loop import EngineRun, EngineSolver
 from asyncframework_tpu.solvers.instrumentation import on_device, worker_task
 
 
+#: Every so-many-th accept of ``ASAGA.run`` pays the exact table delta
+#: whether its slice stands or not.  On the chip the slice stands on 99.9%
+#: of the accepts (PERF.md section 6, PR 28), so a profiler window of a few
+#: seconds can hold no ``jit_saga_table_delta`` at all, and what reads that
+#: module's device time would find nothing: the standing sample keeps the
+#: exact path run, and timed, in every window of 256 updates, for 1/256 of
+#: its cost (0.008 ms an update at mnist8m's shape).  On a slice that
+#: stands both sides give the same vector.
+EXACT_DELTA_EVERY = 256
+
+
 class ASAGA(EngineSolver):
     def __init__(
         self,
@@ -97,10 +108,17 @@ class ASAGA(EngineSolver):
         self._apply = steps.make_saga_apply(
             config.gamma, config.batch_rate, self.ds.n, config.num_workers
         )
+        # the accept path's apply where the step's g is the table delta
+        # too: one buffer through two arguments may not be donated
+        self._apply_g_is_delta = steps.make_saga_apply(
+            config.gamma, config.batch_rate, self.ds.n, config.num_workers,
+            donate_g=False,
+        )
         self._recovery = ShardRecovery(self.ds, self.devices)
 
-    #: a step returns ``(g, ...payload..., new_key)``: all but the key ride
-    #: to the updater, as a tuple
+    #: a task returns ``(g, ...payload..., commits, new_key)``, its step's
+    #: outputs around the commit count of the history slice the step read
+    #: (``_make_task``): all but the key ride to the updater, as a tuple
     _result_payload = staticmethod(operator.itemgetter(slice(None, -1)))
 
     # ------------------------------------------------------------------ async
@@ -124,10 +142,15 @@ class ASAGA(EngineSolver):
             }
         else:
             alpha_bar, alpha = self._zero_history()
+        # how often each slot of ``alpha`` was assigned (under hot_lock):
+        # a task carries its slice's count to the updater
+        commits = dict.fromkeys(alpha, 0)
         # history_ns: the updater's time inside the history path's
-        # dispatches (a part of updater_apply_s)
-        state.update(ab=alpha_bar, history_ns=0)
-        run.start_monitors(self._history_follows(run, alpha))
+        # dispatches (a part of updater_apply_s); reused / recomputed: the
+        # accepts that took the step's g for the table delta, and those
+        # that paid the second read of the shard
+        state.update(ab=alpha_bar, history_ns=0, reused=0, recomputed=0)
+        run.start_monitors(self._history_follows(run, alpha, commits))
         self._warm_hot_path()
         run.start_clock()
         snapshots, now_ms = run.snapshots, run.now_ms
@@ -153,8 +176,9 @@ class ASAGA(EngineSolver):
                 # a sampled update (metrics/trace.py; () in an untraced
                 # run): its result.queue and compute end here; merge.queue
                 # is the state lock and the tau filter, merge.apply the
-                # accept path's dispatches: merge.history (table delta,
-                # history commit), cross-chip copies, apply
+                # accept path's dispatches: merge.history (the history
+                # commit, and the table delta where the slice moved),
+                # cross-chip copies, apply
                 uts = inst.on_drained((res,))
                 g = res.data[0]
                 task_ms = waiting.on_finish(res.worker_id, now_ms())
@@ -182,7 +206,8 @@ class ASAGA(EngineSolver):
                             t_hist = time.perf_counter_ns()
                             with trace.span(trace.MERGE_HISTORY,
                                             tuple(uts)), hot_lock:
-                                alpha_cur = alpha[res.worker_id]
+                                wid = res.worker_id
+                                alpha_cur = alpha[wid]
                                 # a shard re-homed while this result was in
                                 # flight leaves the payload on the old
                                 # device; normalize onto the slice's
@@ -191,40 +216,65 @@ class ASAGA(EngineSolver):
                                 payload = tuple(
                                     jax.device_put(a, home)
                                     if a.device != home else a
-                                    for a in res.data[1:]
+                                    for a in res.data[1:-1]
                                 )
-                                # exact table delta (see
-                                # make_saga_table_delta)
+                                # No assignment to the slot since the task
+                                # captured its slice: the step's g IS the
+                                # table's change (make_saga_worker_step)
+                                # and the shard is not read again.  Else
+                                # the worker's last result was committed,
+                                # or its shard re-homed (a payload moved
+                                # above is always on this side), after the
+                                # task was made: the exact delta against
+                                # the slice at commit.  A resumed run
+                                # starts like a cold one: the restored
+                                # slices at count 0, nothing in flight.
+                                # The standing sample is on this side too.
+                                reuse = (
+                                    res.data[-1] == commits[wid]
+                                    and (k + 1) % EXACT_DELTA_EVERY != 0
+                                )
                                 if self._sparse:
                                     diff, idx, valid, c_sel, v_sel = payload
-                                    delta = self._table_delta(
-                                        c_sel, v_sel, diff, alpha_cur, idx
-                                    )
-                                    alpha[res.worker_id] = self._commit(
+                                    if not reuse:
+                                        delta = self._table_delta(
+                                            c_sel, v_sel, diff, alpha_cur,
+                                            idx,
+                                        )
+                                    alpha[wid] = self._commit(
                                         alpha_cur, diff, idx, valid
                                     )
                                 else:
                                     diff, mask = payload
-                                    delta = self._table_delta(
-                                        shard.X, diff, mask, alpha_cur
-                                    )
-                                    alpha[res.worker_id] = (
-                                        steps.saga_commit_history(
-                                            alpha_cur, diff, mask
+                                    if not reuse:
+                                        delta = self._table_delta(
+                                            shard.X, diff, mask, alpha_cur
                                         )
+                                    alpha[wid] = steps.saga_commit_history(
+                                        alpha_cur, diff, mask
                                     )
+                                commits[wid] += 1
                             state["history_ns"] += (
                                 time.perf_counter_ns() - t_hist
                             )
                             if g.device != self.driver_device:
                                 g = jax.device_put(g, self.driver_device)
-                            if delta.device != self.driver_device:
-                                delta = jax.device_put(
-                                    delta, self.driver_device
+                            if reuse:
+                                state["reused"] += 1
+                                state["w"], state["ab"] = (
+                                    self._apply_g_is_delta(
+                                        state["w"], state["ab"], g, g
+                                    )
                                 )
-                            state["w"], state["ab"] = self._apply(
-                                state["w"], state["ab"], g, delta
-                            )
+                            else:
+                                state["recomputed"] += 1
+                                if delta.device != self.driver_device:
+                                    delta = jax.device_put(
+                                        delta, self.driver_device
+                                    )
+                                state["w"], state["ab"] = self._apply(
+                                    state["w"], state["ab"], g, delta
+                                )
                         else:
                             state["dropped"] += 1
                     inst.updater_apply_ns += time.perf_counter_ns() - t_apply
@@ -250,12 +300,15 @@ class ASAGA(EngineSolver):
             clock.waits()  # the loop's last busy stretch
             stop.set()
 
-        run.drive(updater, "saga-updater", self._task_maker(run, alpha))
+        run.drive(updater, "saga-updater",
+                  self._task_maker(run, alpha, commits))
         return run.result(
             checkpoint=lambda: history_fields(state["ab"]),
             more_extras=lambda: {
                 **self._history_extras(alpha, state["ab"]),
                 "updater_history_s": state["history_ns"] * 1e-9,
+                "history_reused": state["reused"],
+                "history_recomputed": state["recomputed"],
             },
         )
 
@@ -383,8 +436,11 @@ class ASAGA(EngineSolver):
         run.cold_start()
         w = run.state["w"]
         alpha_bar, alpha = self._zero_history()
-        run.start_monitors(self._history_follows(run, alpha))
-        make_tasks = self._task_maker(run, alpha)
+        # the tasks' commit counts (run()) have no reader here: a round's
+        # tasks are all made before, and drained after, any of its commits
+        commits = dict.fromkeys(alpha, 0)
+        run.start_monitors(self._history_follows(run, alpha, commits))
+        make_tasks = self._task_maker(run, alpha, commits)
         self._warm_hot_path(apply=sync_apply, sync=True)
         run.start_clock()
         snapshots, now_ms = run.snapshots, run.now_ms
@@ -435,9 +491,7 @@ class ASAGA(EngineSolver):
                         # diff/idx/valid -- never transfer the (cap, K)
                         # c_sel/v_sel arrays it would just discard.
                         home = alpha_cur.device
-                        needed = (
-                            res.data[1:4] if self._sparse else res.data[1:]
-                        )
+                        needed = res.data[1:4 if self._sparse else 3]
                         payload = tuple(
                             jax.device_put(a, home) if a.device != home
                             else a
@@ -503,38 +557,42 @@ class ASAGA(EngineSolver):
             "history_drift": self._history_drift(alpha, alpha_bar),
         }
 
-    def _history_follows(self, run: EngineRun, alpha: Dict[int, jax.Array]):
+    def _history_follows(self, run: EngineRun, alpha: Dict[int, jax.Array],
+                         commits: Dict[int, int]):
         """The run's hook for a re-homed shard: its history slice and PRNG
-        chain follow it to the new device."""
+        chain follow it to the new device.  The slot is assigned, so its
+        count moves on: a result in flight takes the exact table delta."""
         hot_lock, worker_keys = run.key_lock, run.worker_keys
 
         def on_shard_moved(shard_id, moved):
             with hot_lock:
                 alpha[shard_id] = jax.device_put(alpha[shard_id], moved.device)
+                commits[shard_id] += 1
                 worker_keys[shard_id] = jax.device_put(
                     worker_keys[shard_id], moved.device
                 )
 
         return on_shard_moved
 
-    def _task_maker(self, run: EngineRun, alpha: Dict[int, jax.Array]):
+    def _task_maker(self, run: EngineRun, alpha: Dict[int, jax.Array],
+                    commits: Dict[int, int]):
         """``make_tasks`` of this run (``EngineRun.drive``): a task captures
-        its worker's key and history slice, read under one hold of the
-        lock that guards both."""
+        its worker's key, history slice and the slice's commit count, read
+        under one hold of the lock that guards all three."""
         hot_lock, worker_keys = run.key_lock, run.worker_keys
         delay_model = run.delay_model
 
         def make_tasks(cohort, w_pub, uts):
             with hot_lock:
                 captured = {
-                    wid: (worker_keys[wid], alpha[wid]) for wid in cohort
+                    wid: (worker_keys[wid], alpha[wid], commits[wid])
+                    for wid in cohort
                 }
             # _make_task is looked up per cohort: a test may replace it on
             # the instance
             return {
                 wid: self._make_task(
-                    wid, w_pub, captured[wid][0], captured[wid][1],
-                    delay_model, uts.get(wid),
+                    wid, w_pub, *captured[wid], delay_model, uts.get(wid),
                 )
                 for wid in cohort
             }
@@ -545,9 +603,11 @@ class ASAGA(EngineSolver):
         """``max |alpha_bar - sum_i alpha_i x_i / n|`` over ``max |sum_i y_i
         x_i / n|``: how far the running mean history gradient is from the
         table it summarises, in units of the mean gradient at ``w = 0``.
-        The exact table delta keeps it at f32 rounding (1e-7 to 1e-6); a
-        delta that rounds its vector reads 1e-4 and more, the reference's
-        ``delta == g`` grows with every overlapped dispatch.  The scale is
+        The accept path keeps it at f32 rounding (1e-7 to 1e-6): its delta
+        is the step's ``g`` where the slice the step read still stands and
+        the exact table delta where it does not.  A delta that rounds its
+        vector reads 1e-4 and more; the reference's ``delta == g`` taken
+        on EVERY accept grows with every overlapped dispatch.  The scale is
         the data's, not ``max |alpha_bar|``: ``alpha_bar`` is a mean
         gradient and goes to zero as the run converges, while what rounding
         left in it early stays, so that ratio climbs to 1e-3 by itself.
@@ -584,7 +644,8 @@ class ASAGA(EngineSolver):
         jit caches per input SHAPE, so every distinct (shard shape, history
         slice size) pair is warmed -- shards differ by one row/sample when
         ``n % num_workers != 0``.  The async accept path uses the table
-        delta; the sync drain instead accumulates with ``add_grads`` and
+        delta and both instances of the apply (an accept takes either
+        side); the sync drain instead accumulates with ``add_grads`` and
         passes ``acc`` as both g and delta -- each mode warms only what it
         runs.  Dummies are fresh buffers, so donated arguments never touch
         live state."""
@@ -633,11 +694,18 @@ class ASAGA(EngineSolver):
         else:
             if delta.device != drv:
                 delta = jax.device_put(delta, drv)
+            # both instances of the accept path; the donating one last
+            wd, ab = self._apply_g_is_delta(wd, ab, g, g)
             wd, ab = apply(wd, ab, g, delta)
         wd.block_until_ready()
 
-    def _make_task(self, wid, w_pub, key, alpha_slice,
+    def _make_task(self, wid, w_pub, key, alpha_slice, slice_commits: int,
                    delay_model: DelayModel, ut=None):
+        """A worker task: the step against ``alpha_slice``, whose commit
+        count rides the task's own return to the updater (between the
+        step's payload and the key; ``_result_payload``), which learns
+        from it whether the slice still stands when the result is
+        accepted."""
         shard = self._recovery.shard(wid)  # follows re-homed shards
         dev = shard.device
         step = self._step
@@ -653,9 +721,11 @@ class ASAGA(EngineSolver):
             # between the dense (diff, mask) and compacted sparse
             # (diff_sel, idx, valid, c_sel, v_sel) steps
             if sparse:
-                return step(
+                out = step(
                     shard.cols, shard.vals, shard.y, w_local, a_local, key_local
                 )
-            return step(shard.X, shard.y, w_local, a_local, key_local)
+            else:
+                out = step(shard.X, shard.y, w_local, a_local, key_local)
+            return (*out[:-1], slice_commits, out[-1])
 
         return worker_task(dispatch, delay_model.delay_ms(wid), ut)

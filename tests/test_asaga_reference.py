@@ -129,9 +129,9 @@ def test_run_sync_equals_the_sequential_replay(dtype):
 
 def test_a_delta_that_rounds_its_vector_breaks_the_invariant(monkeypatch):
     """The negative control of ``DRIFT_TOL``: with the table delta's vector
-    cast to the shard's bf16 (``mm_f32``) ``alpha_bar`` leaves the table's
-    mean by ten times the tolerance and more, by the reference's count and
-    by the program's own."""
+    cast to the shard's bf16 (``mm_f32``), on every accept, ``alpha_bar``
+    leaves the table's mean by ten times the tolerance and more, by the
+    reference's count and by the program's own."""
     from asyncframework_tpu.ops.gradients import mm_f32
 
     def rounding_delta():
@@ -143,7 +143,13 @@ def test_a_delta_that_rounds_its_vector_breaks_the_invariant(monkeypatch):
 
     monkeypatch.setattr(steps, "make_saga_table_delta", rounding_delta)
     ds, solver = _solve(jnp.bfloat16, num_iterations=300)
+    # every accept on the side that computes the delta (a task whose slice
+    # still stands takes its step's ``g``): no task's commit count matches
+    real = solver._make_task
+    solver._make_task = lambda wid, w, key, a, _commits, *rest: real(
+        wid, w, key, a, -1, *rest)
     res = solver.run()
+    assert res.extras["history_recomputed"] == res.accepted
     shards = [ds.shard(w) for w in range(NW)]
     alphas = [res.extras["alpha"][w] for w in range(NW)]
     mean = reference_saga.history_mean(shards, alphas, N, block_rows=512)

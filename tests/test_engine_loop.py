@@ -142,15 +142,16 @@ def test_the_monitors_are_built_once_for_run_and_run_sync(
     assert engine.scheduler is runs[1].sched
 
 
-def test_asaga_adds_exactly_its_four_history_extras(devices8, problem):
+def test_asaga_adds_exactly_its_six_history_extras(devices8, problem):
     """The shared result assembly: an ASAGA run's ``extras`` are an ASGD
-    run's plus the history table's four."""
+    run's plus the history table's six."""
     keys = {
         solver: set(solver(*problem, _cfg(), devices=devices8[:2])
                     .run().extras)
         for solver in (ASGD, ASAGA)
     }
     assert keys[ASAGA] - keys[ASGD] == {
-        "alpha", "alpha_bar", "updater_history_s", "history_drift"}
+        "alpha", "alpha_bar", "updater_history_s", "history_drift",
+        "history_reused", "history_recomputed"}
     assert keys[ASGD] <= keys[ASAGA]
     assert "dense_step_path" in keys[ASGD]
