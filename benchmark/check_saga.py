@@ -2,31 +2,31 @@
 
     python3 benchmark/check_saga.py --workload <name> --seed <n> --seconds <s> [--round-delta]
 
-The comparison ``benchmark/run.py: verify`` does not make yet (it reads the
-final objective only): the cell's solver is built, warmed and run for
-``--seconds`` exactly as ``run.py`` does it, and then, outside any timed
-window, the state the run left is held to ``reference_saga`` and
-``reference`` (float32 ``jax.numpy`` at precision "highest", no program
-code):
+The builder's tool beside ``benchmark/run.py``: the cell's solver is built,
+warmed and run for ``--seconds`` exactly as ``run.py`` does it, and then,
+outside any timed window, the state the run left is held to
+``reference_saga`` and ``reference`` (float32 ``jax.numpy`` at precision
+"highest", no program code):
 
-- ``history``: ``alpha_bar`` against ``reference_saga.history_mean`` of the
-  table the run left, over ``max |X^T y / n|`` (the mean gradient at ``w =
-  0``: the unit of the program's own ``history_drift``, which is reported
-  beside it).  Limit ``DRIFT_LIMIT``.
+- ``history``: ``alpha_bar`` against the mean of the table the run left
+  (``reference_saga.history_drift``, in units of ``max |X^T y / n|``; the
+  program's own ``history_drift`` is reported beside it).  Limit
+  ``run.DRIFT_LIMIT``.  ``run.py: verify`` makes this comparison in every
+  run of an ASAGA cell (``history_within``); it is here for the control.
 - ``objective``: the trajectory's last value against
   ``reference.objective`` of the final model, by ``run.py``'s own limits.
 - ``task``: one step + table delta + commit on one whole shard, seeded
   ``w`` and history, a third of the slice moved on between dispatch and
   accept, against ``reference_saga.task``.  Limit ``TASK_LIMIT``, over the
-  largest entry of each vector.
+  largest entry of each vector.  Only this file makes it.
 
 The last stdout line is ``{"check_saga": {..., "correct": bool}}`` and the
 exit code is 0 only where ``correct``.  ``--round-delta`` is the negative
-control: the run is made with a table delta whose vector is rounded to
-bf16 (``lax.reduce_precision``, an op no compiler may drop), which has to
-come out as NOT correct.  A ``benchmark`` PR can call :func:`compare` from
-``verify``; until then the builder runs this file on the chip (PERF.md
-section 6, PR 25).
+control of ``history``: the run is made with the vector that advances
+``alpha_bar`` rounded to bf16 on EVERY accept (the ``delta`` operand of the
+apply, whether it is the step's own ``g`` or the recomputed table delta;
+``lax.reduce_precision``, an op no compiler may drop), which has to come
+out as NOT correct.  It is a patch made here, not a switch of the program.
 """
 
 import argparse
@@ -48,12 +48,6 @@ from benchmark import manifest as manifest_mod  # noqa: E402
 from benchmark import plan as plan_mod, reference, reference_saga  # noqa: E402
 from benchmark import run as bench_run  # noqa: E402
 
-#: ``alpha_bar`` off the table's mean, in units of ``max |X^T y / n|``.  Set
-#: from two readings at 8,100,000 x 784 bf16 on the v5e (PR 25): the largest
-#: the program gave over fourteen seeds, 5.3e-7 (f32 sums of 1M terms in
-#: another order), and runs with the delta's vector rounded to bf16, 7.1e-6
-#: and 1.09e-5.
-DRIFT_LIMIT = 2e-6
 #: one task's ``g``, ``delta``, ``diff`` and committed slice off the
 #: reference's, over the largest entry: 1.2e-6 at most on a 1,012,500-row
 #: shard (PR 25); a vector rounded to bf16 reads 1e-3.
@@ -67,19 +61,17 @@ def _rel(got, want) -> float:
 
 def history(shards, res, n: int) -> dict:
     alphas = [res.extras["alpha"][w] for w in range(len(shards))]
-    mean = reference_saga.history_mean(shards, alphas, n)
-    unit = float(np.max(np.abs(reference_saga.history_mean(
-        shards, [s.y for s in shards], n))))
-    ab = np.asarray(res.extras["alpha_bar"], np.float64)
-    err = float(np.max(np.abs(ab - mean)))
-    return {"abs_err": err, "unit": unit, "drift": err / unit,
+    drift = reference_saga.history_drift(
+        shards, alphas, res.extras["alpha_bar"], n)
+    return {"drift": drift,
             "program_history_drift": res.extras.get("history_drift"),
-            "limit": DRIFT_LIMIT, "within": err <= DRIFT_LIMIT * unit}
+            "limit": bench_run.DRIFT_LIMIT,
+            "within": drift <= bench_run.DRIFT_LIMIT}
 
 
-def objective(shards, res, d: int, loss: str) -> dict:
+def objective(shards, res, loss: str) -> dict:
     f0, f_final = res.trajectory[0][1], res.trajectory[-1][1]
-    f_ref = reference.objective(shards, res.final_w, d, loss)
+    f_ref = reference.objective(shards, res.final_w, loss)
     off = abs(f_final - f_ref)
     return {"trajectory": f_final, "reference": f_ref, "off_over_f0": off / f0,
             "within": off <= (bench_run.FINAL_REL * f_ref
@@ -120,26 +112,28 @@ def compare(ds, solver, res, loss: str, seed: int) -> dict:
     of them."""
     shards = [ds.shard(w) for w in range(ds.num_workers)]
     out = {"history": history(shards, res, ds.n),
-           "objective": objective(shards, res, ds.d, loss),
+           "objective": objective(shards, res, loss),
            "task": task(solver, shards[seed % len(shards)], ds.d, seed)}
     out["correct"] = all(part["within"] for part in out.values())
     return out
 
 
-def _round_the_deltas_vector() -> None:
+def _round_what_advances_alpha_bar() -> None:
+    """The control: every apply the solver builds from here on gets its
+    ``delta`` rounded to bf16 first, on either side of the accept path."""
     import jax
 
     from asyncframework_tpu.ops import steps
 
-    def rounding_delta():
-        @jax.jit
-        def saga_table_delta(X, diff, mask, alpha_cur):
-            v = mask * (diff - alpha_cur)
-            return X.T @ jax.lax.reduce_precision(v, 8, 7)
+    make_apply = steps.make_saga_apply
+    to_bf16 = jax.jit(lambda v: jax.lax.reduce_precision(v, 8, 7))
 
-        return saga_table_delta
+    def make_rounding_apply(*args, **kwargs):
+        apply = make_apply(*args, **kwargs)
+        return lambda w, alpha_bar, g, delta: apply(
+            w, alpha_bar, g, to_bf16(delta))
 
-    steps.make_saga_table_delta = rounding_delta
+    steps.make_saga_apply = make_rounding_apply
 
 
 def main(argv=None, manifest_path=None) -> int:
@@ -161,7 +155,7 @@ def main(argv=None, manifest_path=None) -> int:
     prog_devices.setup_compile_cache()
     devs = bench_run._devices()
     if args.round_delta:
-        _round_the_deltas_vector()
+        _round_what_advances_alpha_bar()
     ds = bench_run.build_dataset(config, plan["num_workers"], devs, args.seed)
 
     from asyncframework_tpu import solvers

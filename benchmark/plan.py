@@ -11,6 +11,10 @@ mix that changes the rate changes the ``gamma`` that meets the target and
 the ``printer_freq`` that keeps the snapshot count), so a new mix brings
 them along as data.
 
+What a configuration says about its DATA (kind, shape, storage, and the
+``generator`` mapping that goes to the program's generator as it stands)
+is ``run.py: build_dataset``'s and no mix overrides it.
+
 The delay schedule itself is drawn inside the program (``DelayModel``,
 seeded with the run's seed): a mix with ``coeff != 0`` therefore rests on
 program code, which PERF.md lists under Open questions.

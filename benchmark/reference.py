@@ -40,111 +40,150 @@ def _block(a, start, block):
     return s, live
 
 
-# ------------------------------------------------------------------ dense
-@functools.partial(jax.jit, static_argnames=("block", "loss"))
-def _dense_block(X, y, w, weights, start, block, loss):
-    s, live = _block(X, start, block)
-    Xb = jax.lax.dynamic_slice_in_dim(X, s, block).astype(jnp.float32)
-    yb = jax.lax.dynamic_slice_in_dim(y, s, block)
-    mb = jax.lax.dynamic_slice_in_dim(weights, s, block) * live
-    m = _dot(Xb, w)
+def _loss_terms(m, yb, loss):
+    """A row's loss and its derivative by the margin ``m = x . w``."""
     if loss == "least_squares":
         r = m - yb
-        per_row = r * r
-    else:
-        r = jax.nn.sigmoid(m) - yb
-        per_row = jnp.logaddexp(0.0, m) - yb * m
+        return r * r, r
+    if loss == "logistic":
+        return jnp.logaddexp(0.0, m) - yb * m, jax.nn.sigmoid(m) - yb
+    raise ValueError(f"unknown loss {loss!r}")
+
+
+# Each storage has two block functions.  ``*_sums`` is what the
+# whole-dataset passes after every run need (the objective, the data's
+# moments) and builds no gradient: for padded ELL no scatter and no
+# ``(d,)`` array, for dense no second product.  ``*_grad`` is the gradient
+# alone, for the callers that hold a program's gradient to it.
+
+
+# ------------------------------------------------------------------ dense
+def _dense_rows(X, y, start, block):
+    s, live = _block(X, start, block)
+    Xb = jax.lax.dynamic_slice_in_dim(X, s, block).astype(jnp.float32)
+    return s, live, Xb, jax.lax.dynamic_slice_in_dim(y, s, block)
+
+
+@functools.partial(jax.jit, static_argnames=("block", "loss"))
+def _dense_sums(X, y, w, start, block, loss):
+    _s, live, Xb, yb = _dense_rows(X, y, start, block)
+    per_row, _r = _loss_terms(_dot(Xb, w), yb, loss)
     return (
         jnp.sum(per_row * live),
-        _dot((mb * r)[None, :], Xb)[0],
         jnp.sum(jnp.sum(Xb * Xb, axis=1) * live),
         jnp.sum(yb * yb * live),
     )
 
 
+@functools.partial(jax.jit, static_argnames=("block", "loss"))
+def _dense_grad(X, y, w, weights, start, block, loss):
+    s, live, Xb, yb = _dense_rows(X, y, start, block)
+    mb = jax.lax.dynamic_slice_in_dim(weights, s, block) * live
+    _per_row, r = _loss_terms(_dot(Xb, w), yb, loss)
+    return _dot((mb * r)[None, :], Xb)[0]
+
+
 # -------------------------------------------------------------- padded ELL
-@functools.partial(jax.jit, static_argnames=("block", "d"))
-def _ell_block(cols, vals, y, w, weights, start, block, d):
+def _ell_rows(cols, vals, y, start, block):
     s, live = _block(vals, start, block)
     cb = jax.lax.dynamic_slice_in_dim(cols, s, block)
     vb = jax.lax.dynamic_slice_in_dim(vals, s, block).astype(jnp.float32)
-    yb = jax.lax.dynamic_slice_in_dim(y, s, block)
-    mb = jax.lax.dynamic_slice_in_dim(weights, s, block) * live
-    r = jnp.sum(vb * w[cb], axis=1) - yb
-    g = jnp.zeros(d, jnp.float32).at[cb.ravel()].add(
-        (vb * (mb * r)[:, None]).ravel()
-    )
+    return s, live, cb, vb, jax.lax.dynamic_slice_in_dim(y, s, block)
+
+
+@functools.partial(jax.jit, static_argnames=("block", "loss"))
+def _ell_sums(cols, vals, y, w, start, block, loss):
+    _s, live, cb, vb, yb = _ell_rows(cols, vals, y, start, block)
+    per_row, _r = _loss_terms(jnp.sum(vb * w[cb], axis=1), yb, loss)
     return (
-        jnp.sum(r * r * live),
-        g,
+        jnp.sum(per_row * live),
         jnp.sum(jnp.sum(vb * vb, axis=1) * live),
         jnp.sum(yb * yb * live),
         jnp.sum(jnp.sum(vb != 0, axis=1) * live),
     )
 
 
-def shard_sums(shard, w, d: int, loss: str = "least_squares",
-               weights=None, block_rows: int = BLOCK_ROWS) -> Dict[str, object]:
-    """One shard's sums, on the shard's device: ``loss`` (unnormalised),
-    ``grad`` (sum over rows of ``weights_i * dloss_i/dw``; all rows where
-    ``weights`` is None), ``xx`` (sum of squared entries), ``yy`` (sum of
-    squared labels), ``rows`` and, for padded ELL, ``nnz``."""
-    sparse = hasattr(shard, "cols")
-    lead = shard.vals if sparse else shard.X
-    rows = int(lead.shape[0])
-    dev = lead.device
-    w = jax.device_put(jnp.asarray(w, jnp.float32), dev)
-    if weights is None:
-        weights = jnp.ones(rows, jnp.float32)
-    weights = jax.device_put(jnp.asarray(weights, jnp.float32), dev)
+@functools.partial(jax.jit, static_argnames=("block", "d", "loss"))
+def _ell_grad(cols, vals, y, w, weights, start, block, d, loss):
+    s, live, cb, vb, yb = _ell_rows(cols, vals, y, start, block)
+    mb = jax.lax.dynamic_slice_in_dim(weights, s, block) * live
+    _per_row, r = _loss_terms(jnp.sum(vb * w[cb], axis=1), yb, loss)
+    return jnp.zeros(d, jnp.float32).at[cb.ravel()].add(
+        (vb * (mb * r)[:, None]).ravel()
+    )
+
+
+def _f32(a, device):
+    return jax.device_put(jnp.asarray(a, jnp.float32), device)
+
+
+def _row_blocks(rows: int, block_rows: int):
     block = min(block_rows, rows)
+    return block, range(0, rows, block)
+
+
+def shard_sums(shard, w, loss: str = "least_squares",
+               block_rows: int = BLOCK_ROWS) -> Dict[str, float]:
+    """One shard's sums, on the shard's device: ``loss`` (unnormalised),
+    ``xx`` (sum of squared entries), ``yy`` (sum of squared labels),
+    ``rows`` and, for padded ELL, ``nnz``.  No gradient is built."""
+    sparse = hasattr(shard, "cols")
+    rows = int(shard.y.shape[0])
+    w = _f32(w, shard.y.device)
+    block, starts = _row_blocks(rows, block_rows)
     acc = None
-    for start in range(0, rows, block):
+    for start in starts:
         if sparse:
-            if loss != "least_squares":
-                raise ValueError("padded-ELL reference: least_squares only")
-            part = _ell_block(shard.cols, shard.vals, shard.y, w, weights,
-                              start, block=block, d=d)
+            part = _ell_sums(shard.cols, shard.vals, shard.y, w, start,
+                             block=block, loss=loss)
         else:
-            part = _dense_block(shard.X, shard.y, w, weights, start,
-                                block=block, loss=loss)
+            part = _dense_sums(shard.X, shard.y, w, start, block=block,
+                               loss=loss)
         acc = part if acc is None else tuple(a + b for a, b in zip(acc, part))
-    out = {
-        "loss": float(acc[0]),
-        "grad": np.asarray(acc[1], np.float64),
-        "xx": float(acc[2]),
-        "yy": float(acc[3]),
-        "rows": rows,
-    }
-    if sparse:
-        out["nnz"] = float(acc[4])
+    out = dict(zip(("loss", "xx", "yy", "nnz"), map(float, acc)))
+    out["rows"] = rows
     return out
 
 
-def dataset_sums(shards: Iterable, w, d: int,
+def full_gradient(shard, w, d: int, loss: str = "least_squares",
+                  weights=None, block_rows: int = BLOCK_ROWS) -> np.ndarray:
+    """Unnormalised gradient sum over one shard's rows, each weighed by
+    ``weights`` (every row once where None), float64 on the host."""
+    sparse = hasattr(shard, "cols")
+    rows = int(shard.y.shape[0])
+    dev = shard.y.device
+    w = _f32(w, dev)
+    weights = _f32(np.ones(rows) if weights is None else weights, dev)
+    block, starts = _row_blocks(rows, block_rows)
+    acc = None
+    for start in starts:
+        if sparse:
+            part = _ell_grad(shard.cols, shard.vals, shard.y, w, weights,
+                             start, block=block, d=d, loss=loss)
+        else:
+            part = _dense_grad(shard.X, shard.y, w, weights, start,
+                               block=block, loss=loss)
+        acc = part if acc is None else acc + part
+    return np.asarray(acc, np.float64)
+
+
+def dataset_sums(shards: Iterable, w,
                  loss: str = "least_squares") -> Dict[str, float]:
     """Totals over every shard (float64 on the host): what the objective
     and the data pins are computed from."""
     tot: Dict[str, float] = {"loss": 0.0, "xx": 0.0, "yy": 0.0, "rows": 0,
                              "nnz": 0.0}
     for shard in shards:
-        s = shard_sums(shard, w, d, loss)
+        s = shard_sums(shard, w, loss)
         for key in tot:
             tot[key] += s.get(key, 0.0)
     return tot
 
 
-def objective(shards: Iterable, w, d: int,
-              loss: str = "least_squares") -> float:
+def objective(shards: Iterable, w, loss: str = "least_squares") -> float:
     """Mean loss over the whole dataset at ``w``."""
-    tot = dataset_sums(shards, w, d, loss)
+    tot = dataset_sums(shards, w, loss)
     return tot["loss"] / tot["rows"]
-
-
-def full_gradient(shard, w, d: int, loss: str = "least_squares",
-                  weights=None) -> np.ndarray:
-    """Unnormalised gradient sum over one shard's (weighted) rows."""
-    return shard_sums(shard, w, d, loss, weights)["grad"]
 
 
 def data_pins(shards: Iterable, d: int,
@@ -153,7 +192,7 @@ def data_pins(shards: Iterable, d: int,
     holds for any seed: the rows' second moment ``d * mean(x^2)`` (1 for
     both planted generators), the labels' second moment, the stored
     non-zeros a row (padded ELL), and the objective at ``w = 0``."""
-    tot = dataset_sums(shards, np.zeros(d, np.float32), d, loss)
+    tot = dataset_sums(shards, np.zeros(d, np.float32), loss)
     n = tot["rows"]
     pins = {
         "row_second_moment": tot["xx"] / n,

@@ -37,7 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmark.reference import BLOCK_ROWS, _block, _dot
+from benchmark.reference import BLOCK_ROWS, _block, _dot, _f32
 
 
 def _rows(a, s, block):
@@ -67,15 +67,12 @@ def _task_block(X, y, w, alpha_read, alpha_cur, mask, diff, start, block):
     return g, delta, diff
 
 
-def _f32(a, device):
-    return jax.device_put(jnp.asarray(a, jnp.float32), device)
-
-
 def history_mean(shards: Sequence, alphas: Sequence, n: int,
                  block_rows: int = BLOCK_ROWS) -> np.ndarray:
     """``sum_i alpha_i x_i / n`` over every shard, float64 on the host: what
     ``alpha_bar`` must equal.  ``alphas[k]`` is the history slice of
-    ``shards[k]``."""
+    ``shards[k]``.  Dense shards only: a padded-ELL ``X^T alpha`` is a
+    scatter, which no cell needs yet."""
     total = None
     for shard, alpha in zip(shards, alphas):
         rows = int(shard.X.shape[0])
@@ -86,6 +83,20 @@ def history_mean(shards: Sequence, alphas: Sequence, n: int,
                               np.float64)
             total = part if total is None else total + part
     return total / n
+
+
+def history_drift(shards: Sequence, alphas: Sequence, alpha_bar, n: int,
+                  block_rows: int = BLOCK_ROWS) -> float:
+    """How far ``alpha_bar`` is from the mean of the table it summarises:
+    ``max |alpha_bar - history_mean(table)|`` over ``max |X^T y / n|``, the
+    mean gradient at ``w = 0``.  The data's unit and not ``max
+    |alpha_bar|``: ``alpha_bar`` goes to zero as a run converges while the
+    rounding of its early updates stays."""
+    mean = history_mean(shards, alphas, n, block_rows)
+    unit = np.max(np.abs(
+        history_mean(shards, [s.y for s in shards], n, block_rows)))
+    off = np.max(np.abs(np.asarray(alpha_bar, np.float64) - mean))
+    return float(off / unit)
 
 
 def task(shard, w, alpha_read, alpha_cur, mask,

@@ -40,15 +40,17 @@ def dense_step_bytes(shard_rows: int, d: int, itemsize: int,
     return sampled * d * itemsize + shard_rows + sampled * 4 + 2 * d * 4
 
 
-def sparse_step_bytes(shard_rows: int, width: int, d: int,
-                      batch_rate: float) -> float:
-    """Bytes one padded-ELL worker step needs: the sampled rows' columns
-    (int32) and values (f32) read once, one byte of mask a shard row, the
-    sampled labels, and the touched entries of ``w`` (read) and ``g``
-    (written), at most ``d`` each."""
+def sparse_step_bytes(shard_rows: int, width: int, d: int, batch_rate: float,
+                      itemsize: int, index_itemsize: int) -> float:
+    """Bytes one padded-ELL worker step needs: the sampled rows' values
+    (``itemsize`` each) and columns (``index_itemsize`` each), as the shard
+    stores them, read once, one byte of mask a shard row, the sampled
+    labels, and the touched entries of ``w`` (read) and ``g`` (written), at
+    most ``d`` each."""
     sampled = batch_rate * shard_rows
     touched = min(sampled * width, d)
-    return sampled * width * 8 + shard_rows + sampled * 4 + 2 * touched * 4
+    return (sampled * width * (itemsize + index_itemsize) + shard_rows
+            + sampled * 4 + 2 * touched * 4)
 
 
 def step_bytes(data: Dict[str, object], batch_rate: float) -> float:
@@ -56,5 +58,6 @@ def step_bytes(data: Dict[str, object], batch_rate: float) -> float:
     run record's ``data`` description."""
     rows = max(data["shard_rows"])
     if data["kind"] == "sparse":
-        return sparse_step_bytes(rows, data["width"], data["d"], batch_rate)
+        return sparse_step_bytes(rows, data["width"], data["d"], batch_rate,
+                                 data["itemsize"], data["index_itemsize"])
     return dense_step_bytes(rows, data["d"], data["itemsize"], batch_rate)

@@ -13,6 +13,7 @@ import re
 import subprocess
 import sys
 
+import numpy as np
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -26,7 +27,10 @@ MANIFEST = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 E2E = [m["name"] for m in MANIFEST["end_to_end"]]
 PER_LAYER = [m["name"] for m in MANIFEST["per_layer"]]
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+#: ``compared`` is the harness's own and comes last: every number ``correct``
+#: was decided from, beside its limit (the driver ignores the key)
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+               "compared"}
 DEVICE_KEYS = {"platform", "kind", "count", "memory_peak_bytes"}
 
 # ------------------------------------------------------------ the manifest
@@ -138,10 +142,121 @@ def test_step_bytes_count_each_sampled_row_once():
     assert b == pytest.approx(101_250 * 784 * 2 + 1_012_500 + 101_250 * 4 + 2 * 784 * 4)
     # rcv1: 4,360 sampled rows of 80 slots, cols+vals 8 bytes a slot; w and g
     # are touched at no more than d entries each
-    s = roofline.sparse_step_bytes(87_206, 80, 47_236, 0.05)
+    s = roofline.sparse_step_bytes(87_206, 80, 47_236, 0.05, 4, 4)
     assert s == pytest.approx(4360.3 * 80 * 8 + 87_206 + 4360.3 * 4 + 2 * 47_236 * 4)
     data = {"kind": "dense", "shard_rows": [10, 12], "d": 4, "itemsize": 4}
     assert roofline.step_bytes(data, 0.5) == roofline.dense_step_bytes(12, 4, 4, 0.5)
+
+
+def test_sparse_step_bytes_charge_a_slot_what_the_shard_stores():
+    """f32 values and int32 columns are the 8 bytes a slot the count had as
+    a literal until PR 29; a shard that stores bf16 values is charged 6, so
+    that its share of the roofline cannot be counted too high."""
+    rows, width, d, rate = 525_000, 128, 1_000_000, 0.05
+    sampled = rate * rows
+    rest = rows + sampled * 4 + 2 * d * 4
+    f32 = roofline.sparse_step_bytes(rows, width, d, rate, 4, 4)
+    assert f32 == sampled * width * 8 + rest
+    bf16 = roofline.sparse_step_bytes(rows, width, d, rate, 2, 4)
+    assert bf16 == sampled * width * 6 + rest
+    data = {"kind": "sparse", "shard_rows": [rows - 1, rows], "d": d,
+            "width": width, "itemsize": 2, "index_itemsize": 4}
+    assert roofline.step_bytes(data, rate) == bf16
+
+
+# ------------------------------------------- the configuration's generator
+
+
+def _tiny_config(name):
+    with open(os.path.join(ROOT, "tests", "benchmark", "configs",
+                           name + ".json")) as f:
+        return json.load(f)
+
+
+def _shard_bytes(ds):
+    names = ("cols", "vals", "y") if hasattr(ds.shard(0), "cols") else ("X", "y")
+    return [np.asarray(getattr(ds.shard(w), n)).tobytes()
+            for w in range(ds.num_workers) for n in names]
+
+
+@pytest.mark.parametrize("name", ["tiny-dense-f32", "tiny-dense-bf16",
+                                  "tiny-sparse"])
+def test_a_configuration_without_a_generator_key_builds_todays_arrays(name):
+    """No configuration in the tree carries ``generator``: the call is the
+    one the harness made before the key existed, letter for letter, and the
+    arrays are the same bytes for the same seed."""
+    import jax
+    import jax.numpy as jnp
+
+    from asyncframework_tpu.data.sharded import ShardedDataset
+    from asyncframework_tpu.data.sparse import SparseShardedDataset
+
+    for entry in MANIFEST["configs"]:
+        assert "generator" not in manifest_mod.Manifest().config(entry["name"])
+    config = _tiny_config(name)
+    assert "generator" not in config
+    devs = jax.devices()[:1]
+    got = run.build_dataset(config, 4, devs, seed=2_147_483_659)
+    if config["kind"] == "dense":
+        want = ShardedDataset.generate_on_device(
+            config["n"], config["d"], 4, devs, seed=2_147_483_659,
+            noise=config["noise"], dtype=jnp.dtype(config["storage_dtype"]),
+        )
+    else:
+        want = SparseShardedDataset.generate_on_device(
+            config["n"], config["d"], config["nnz_per_row"], 4, devs,
+            seed=2_147_483_659, noise=config["noise"],
+        )
+    assert _shard_bytes(got) == _shard_bytes(want)
+    data = run.describe_data(got, config)
+    assert data["itemsize"] == jnp.dtype(config["storage_dtype"]).itemsize
+    if config["kind"] == "sparse":
+        assert (data["index_itemsize"], data["width"]) == (4, 16)
+    else:
+        assert "index_itemsize" not in data
+
+
+@pytest.mark.parametrize("name", ["tiny-dense-f32", "tiny-sparse"])
+def test_every_generator_key_reaches_the_programs_generator(name, monkeypatch):
+    """A recording stand-in for the program's generator: the mapping's keys
+    arrive as keyword arguments beside the ones passed without it, and the
+    harness knows none of their names."""
+    import jax
+
+    from asyncframework_tpu.data.sharded import ShardedDataset
+    from asyncframework_tpu.data.sparse import SparseShardedDataset
+
+    cls = SparseShardedDataset if name == "tiny-sparse" else ShardedDataset
+    real = cls.generate_on_device
+    calls = []
+
+    def recording(*args, value_dtype="float32", column_skew=0.0, **kwargs):
+        calls.append({"value_dtype": value_dtype, "column_skew": column_skew,
+                      **kwargs})
+        return real(*args, **kwargs)
+
+    monkeypatch.setattr(cls, "generate_on_device", recording)
+    config = _tiny_config(name)
+    plain = run.build_dataset(config, 4, jax.devices()[:1], seed=7)
+    config["generator"] = {"value_dtype": "bfloat16", "column_skew": 1.1}
+    told = run.build_dataset(config, 4, jax.devices()[:1], seed=7)
+    assert calls[0]["value_dtype"] == "float32" and calls[0]["column_skew"] == 0.0
+    assert calls[1]["value_dtype"] == "bfloat16" and calls[1]["column_skew"] == 1.1
+    # everything else went as it goes without the mapping
+    drop = lambda c: {k: v for k, v in c.items()  # noqa: E731
+                      if k not in ("value_dtype", "column_skew")}
+    assert drop(calls[0]) == drop(calls[1])
+    assert _shard_bytes(plain) == _shard_bytes(told)
+
+
+@pytest.mark.parametrize("name", ["tiny-dense-f32", "tiny-sparse"])
+def test_a_generator_key_the_program_does_not_take_fails_loudly(name):
+    import jax
+
+    config = _tiny_config(name)
+    config["generator"] = {"no_such_argument": 1}
+    with pytest.raises(TypeError, match="no_such_argument"):
+        run.build_dataset(config, 4, jax.devices()[:1], seed=7)
 
 
 # ------------------------------------------------------------ the rehearsal
@@ -219,6 +334,12 @@ def test_rehearsal_cell_prints_the_contracts_last_line(
         assert set(m) == {"value", "unit"} and m["value"] > 0, name
     assert last["correct"] is True, lines[-2]
     assert last["failed"] == 0 and last["attempted"] > 0
+    # each number compared, beside its limit, as the line's last key
+    assert list(last)[-1] == "compared"
+    for name, c in last["compared"].items():
+        assert set(c) == {"value", "limit"} and c["value"] <= c["limit"], name
+    assert ("history_within" in last["compared"]) == (cell == "tiny-asaga.steady")
+    assert ("nnz_per_row" in last["compared"]) == (cell == "tiny-sparse.steady")
     # everything before the last line is an info line
     assert all(set(json.loads(ln)) == {"info"} for ln in lines[:-1])
     record = [json.loads(ln)["info"] for ln in lines[:-1]
@@ -255,6 +376,7 @@ def test_traced_rehearsal_reports_per_layer_metrics(
     record = [i for i in infos if "checks" in i][0]
     assert all(record["checks"].values()), record["checks"]
     assert last["correct"] is True
+    assert list(last)[-1] == "compared"
 
 
 def test_a_host_that_holds_every_thread_costs_the_run_no_worker(

@@ -6,7 +6,9 @@ full-shard gradients (dense in both storage types, padded ELL) and its
 trajectory objective must agree with it at a tiny size on the CPU.
 """
 
+import functools
 import os
+import re
 import sys
 
 import numpy as np
@@ -113,8 +115,8 @@ def test_objective_matches_the_programs_trajectory_eval(kind):
     shards = [ds.shard(i) for i in range(WORKERS)]
     W = jnp.stack([jnp.zeros(d, jnp.float32), jnp.asarray(w)])
     prog = sum(np.asarray(part(sh, W), np.float64) for sh in shards) / N
-    assert abs(reference.objective(shards, np.zeros(d), d) - prog[0]) < 1e-5 * prog[0]
-    assert abs(reference.objective(shards, w, d) - prog[1]) < 1e-5 * prog[1]
+    assert abs(reference.objective(shards, np.zeros(d)) - prog[0]) < 1e-5 * prog[0]
+    assert abs(reference.objective(shards, w) - prog[1]) < 1e-5 * prog[1]
 
 
 def test_data_pins_hold_for_both_generators():
@@ -134,9 +136,192 @@ def test_block_walk_covers_a_ragged_tail():
     """Rows that are no multiple of the block are counted once each."""
     ds = ShardedDataset.generate_on_device(1000, 16, 1, jax.devices()[:1], seed=2)
     sh = ds.shard(0)
-    whole = reference.shard_sums(sh, np.ones(16), 16, block_rows=1000)
-    ragged = reference.shard_sums(sh, np.ones(16), 16, block_rows=384)
+    whole = reference.shard_sums(sh, np.ones(16), block_rows=1000)
+    ragged = reference.shard_sums(sh, np.ones(16), block_rows=384)
     assert ragged["rows"] == whole["rows"] == 1000
     for key in ("loss", "xx", "yy"):
         assert abs(ragged[key] - whole[key]) < 1e-5 * abs(whole[key])
-    assert _rel(ragged["grad"], whole["grad"]) < 1e-5
+    g_whole = reference.full_gradient(sh, np.ones(16), 16, block_rows=1000)
+    g_ragged = reference.full_gradient(sh, np.ones(16), 16, block_rows=384)
+    assert _rel(g_ragged, g_whole) < 1e-5
+
+
+# ------------------------------------------------- sums without a gradient
+#
+# Until PR 29 one block function computed the sums AND the gradient, and the
+# whole-dataset passes after every run threw the gradient away: for padded
+# ELL an unsorted scatter-add of every stored slot into a ``(d,)`` array.
+# The two block functions of the parent commit, verbatim, are the loop
+# version the split is held to.
+
+
+@functools.partial(jax.jit, static_argnames=("block", "loss"))
+def _parent_dense_block(X, y, w, weights, start, block, loss):
+    s, live = reference._block(X, start, block)
+    Xb = jax.lax.dynamic_slice_in_dim(X, s, block).astype(jnp.float32)
+    yb = jax.lax.dynamic_slice_in_dim(y, s, block)
+    mb = jax.lax.dynamic_slice_in_dim(weights, s, block) * live
+    m = reference._dot(Xb, w)
+    if loss == "least_squares":
+        r = m - yb
+        per_row = r * r
+    else:
+        r = jax.nn.sigmoid(m) - yb
+        per_row = jnp.logaddexp(0.0, m) - yb * m
+    return (
+        jnp.sum(per_row * live),
+        reference._dot((mb * r)[None, :], Xb)[0],
+        jnp.sum(jnp.sum(Xb * Xb, axis=1) * live),
+        jnp.sum(yb * yb * live),
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("block", "d"))
+def _parent_ell_block(cols, vals, y, w, weights, start, block, d):
+    s, live = reference._block(vals, start, block)
+    cb = jax.lax.dynamic_slice_in_dim(cols, s, block)
+    vb = jax.lax.dynamic_slice_in_dim(vals, s, block).astype(jnp.float32)
+    yb = jax.lax.dynamic_slice_in_dim(y, s, block)
+    mb = jax.lax.dynamic_slice_in_dim(weights, s, block) * live
+    r = jnp.sum(vb * w[cb], axis=1) - yb
+    g = jnp.zeros(d, jnp.float32).at[cb.ravel()].add(
+        (vb * (mb * r)[:, None]).ravel()
+    )
+    return (
+        jnp.sum(r * r * live),
+        g,
+        jnp.sum(jnp.sum(vb * vb, axis=1) * live),
+        jnp.sum(yb * yb * live),
+        jnp.sum(jnp.sum(vb != 0, axis=1) * live),
+    )
+
+
+def _parent_shard_sums(shard, w, d, loss, weights, block_rows):
+    sparse = hasattr(shard, "cols")
+    rows = int(shard.y.shape[0])
+    w = jnp.asarray(w, jnp.float32)
+    weights = jnp.asarray(weights, jnp.float32)
+    block = min(block_rows, rows)
+    acc = None
+    for start in range(0, rows, block):
+        if sparse:
+            part = _parent_ell_block(shard.cols, shard.vals, shard.y, w,
+                                     weights, start, block=block, d=d)
+        else:
+            part = _parent_dense_block(shard.X, shard.y, w, weights, start,
+                                       block=block, loss=loss)
+        acc = part if acc is None else tuple(a + b for a, b in zip(acc, part))
+    out = {"loss": float(acc[0]), "grad": np.asarray(acc[1], np.float64),
+           "xx": float(acc[2]), "yy": float(acc[3]), "rows": rows}
+    if sparse:
+        out["nnz"] = float(acc[4])
+    return out
+
+
+def _tiny_dataset(name, workers=3):
+    from test_bench_harness import _tiny_config
+
+    from benchmark import run
+
+    config = _tiny_config(name)
+    # three workers: 1,366-row shards, which 384-row blocks do not divide
+    return config, run.build_dataset(config, workers, jax.devices()[:1], seed=17)
+
+
+@pytest.mark.parametrize("name,loss", [
+    ("tiny-dense-f32", "least_squares"), ("tiny-dense-f32", "logistic"),
+    ("tiny-dense-bf16", "least_squares"), ("tiny-dense-bf16", "logistic"),
+    ("tiny-sparse", "least_squares"),
+])
+def test_the_split_paths_equal_the_parents_one_block_to_the_bit(name, loss):
+    """``shard_sums`` (no gradient) and ``full_gradient`` (nothing else)
+    against the parent's one block function, ragged last block included:
+    the same expressions on the same rows in the same order, so the same
+    bits.  The parent had no logistic padded-ELL block: that pairing is held
+    to the dense block in the next test instead."""
+    config, ds = _tiny_dataset(name)
+    d = config["d"]
+    rs = np.random.default_rng(3)
+    w = (0.3 * rs.standard_normal(d)).astype(np.float32)
+    for wid in range(ds.num_workers):
+        sh = ds.shard(wid)
+        rows = int(sh.y.shape[0])
+        assert rows % 384
+        weights = (rs.random(rows) < 0.4).astype(np.float32)
+        want = _parent_shard_sums(sh, w, d, loss, weights, 384)
+        got = reference.shard_sums(sh, w, loss, block_rows=384)
+        assert set(got) == set(want) - {"grad"}
+        for key in got:
+            assert got[key] == want[key], key
+        grad = reference.full_gradient(sh, w, d, loss, weights, block_rows=384)
+        assert np.array_equal(grad, want["grad"])
+    # and over the dataset: the objective and the pins read those sums
+    shards = [ds.shard(i) for i in range(ds.num_workers)]
+    tot = reference.dataset_sums(shards, w, loss)
+    assert tot["rows"] == config["n"]
+    assert reference.objective(shards, w, loss) == tot["loss"] / config["n"]
+
+
+def test_padded_ell_logistic_sums_equal_the_dense_blocks_on_the_same_rows():
+    """The logistic loss the padded-ELL block gained in PR 29 (``log(1 +
+    e^m) - y m``, residual ``sigmoid(m) - y``) against the dense block on
+    ``densify()`` of the same shards, labels cut to {0, 1}."""
+    import types
+
+    config, ds = _tiny_dataset("tiny-sparse")
+    d = config["d"]
+    X, y = densify(ds)
+    y01 = (y > 0).astype(np.float32)
+    w = (2.0 * np.random.default_rng(4).standard_normal(d)).astype(np.float32)
+    for wid in range(ds.num_workers):
+        sh = ds.shard(wid)
+        rows = slice(sh.start, sh.start + sh.size)
+        ell = types.SimpleNamespace(cols=sh.cols, vals=sh.vals,
+                                    y=jnp.asarray(y01[rows]))
+        dense = types.SimpleNamespace(X=jnp.asarray(X[rows], jnp.float32),
+                                      y=ell.y)
+        got = reference.shard_sums(ell, w, "logistic", block_rows=384)
+        want = reference.shard_sums(dense, w, "logistic", block_rows=384)
+        # not ``xx``: a row that draws one column twice stores two squares
+        # where the dense cell holds the square of their sum
+        for key in ("loss", "yy", "rows"):
+            assert abs(got[key] - want[key]) <= 1e-6 * abs(want[key]), key
+        g = reference.full_gradient(ell, w, d, "logistic", block_rows=384)
+        g_dense = reference.full_gradient(dense, w, d, "logistic",
+                                          block_rows=384)
+        assert _rel(g, g_dense) < TOL["float32"]
+    with pytest.raises(ValueError, match="hinge"):
+        reference.shard_sums(ds.shard(0), w, "hinge")
+
+
+_HLO = re.compile(r"^\s*(?:ROOT )?%?[\w.\-]+ = (?P<type>\S+) "
+                  r"(?P<op>[a-z][a-z0-9\-]*)\(", re.M)
+
+
+@pytest.mark.parametrize("loss", ["least_squares", "logistic"])
+def test_the_padded_ell_sums_block_builds_no_gradient(loss):
+    """Read on the COMPILED program (the CPU's here; compiled for a
+    described v5e by hand, PERF.md section 6, PR 29): the sums-only block
+    holds no ``scatter`` and no array of ``d`` elements but ``w`` itself,
+    where the gradient block holds both."""
+    rows, width, d, block = 1366, 16, 1000, 384
+    args = (
+        jax.ShapeDtypeStruct((rows, width), jnp.int32),
+        jax.ShapeDtypeStruct((rows, width), jnp.float32),
+        jax.ShapeDtypeStruct((rows,), jnp.float32),
+        jax.ShapeDtypeStruct((d,), jnp.float32),
+    )
+    start = jax.ShapeDtypeStruct((), jnp.int32)
+    sums = reference._ell_sums.lower(
+        *args, start, block=block, loss=loss).compile().as_text()
+    instrs = [(m["type"], m["op"]) for m in _HLO.finditer(sums)]
+    assert len(instrs) > 10
+    assert not [op for _t, op in instrs if "scatter" in op]
+    assert "scatter" not in sums
+    of_d = [op for typ, op in instrs if re.match(rf"f32\[{d}\]", typ)]
+    assert of_d and set(of_d) == {"parameter"}, of_d
+    weights = jax.ShapeDtypeStruct((rows,), jnp.float32)
+    grad = reference._ell_grad.lower(
+        *args, weights, start, block=block, d=d, loss=loss
+    ).compile().as_text()
+    assert "scatter" in grad
