@@ -11,7 +11,9 @@ and ``run_timeout_s`` is the window; ``TrainResult.elapsed_s`` is taken
 after the final model's read-back, so the rate is fenced.  After the window
 the benchmark checks the run (``correct``) against its own reference and
 prints the contract's object as the last stdout line; everything else goes
-on earlier ``{"info": ...}`` lines.
+on earlier ``{"info": ...}`` lines, the last of which says how long the
+whole process took (``wall_s``) beside the ``seconds + RUN_OVERHEAD_S`` the
+harness's budget reckons a run at.
 
 With ``--trace 1`` the program's span sampling is on in that run, and a
 second, short run of the same solver follows under ``jax.profiler``: a run
@@ -50,6 +52,11 @@ OUT_DIR = os.path.join(ROOT, ".bench_out")
 #: pool's ramp-up, the drain) before the trace is reduced: 3 s stay
 TRACE_RUN_S = 4.0
 TRACE_EDGE_S = 0.5
+#: what a full check allows a run beyond its window (the contract's
+#: ``run_seconds`` + 60): set-up, the evaluation of the trajectory, the
+#: reference's passes, a traced run's second run.  The budget test reads it;
+#: a run that takes longer says so on stderr and is reported as any other.
+RUN_OVERHEAD_S = 60
 #: the program evaluates its trajectory as an (n, S) matrix-matrix product
 #: at default precision, which on the v5e rounds the operands to bf16: its
 #: last objective sat 4.3e-6 of ``f(0)`` above the reference's with bf16
@@ -114,38 +121,46 @@ class CompileLog:
         return sum(1 for start, _s in self.builds if t0 <= start < t1)
 
 
-def build_dataset(data: dict, num_workers: int, devices, seed: int):
-    """The cell's dataset on the device, from the seed, by the program's
-    own generators (what they produce is pinned after the window).  The
-    configuration's ``generator`` mapping, where it has one, goes to the
-    generator as keyword arguments, whatever they are: how the values are
-    stored, how the columns are drawn, what the labels are.  A key the
-    generator does not take is its ``TypeError``."""
-    import jax
+def generator_call(data: dict, num_workers: int, devices, seed: int):
+    """The program's generator for the configuration's ``kind`` and the
+    arguments it is called with.  The configuration's ``generator`` mapping,
+    where it has one, goes to it as keyword arguments, whatever they are:
+    how the values are stored, how the columns are drawn, what the labels
+    are.  A key the generator does not take is its ``TypeError``."""
     import jax.numpy as jnp
 
     extra = data.get("generator", {})
     if data["kind"] == "dense":
         from asyncframework_tpu.data.sharded import ShardedDataset
 
-        ds = ShardedDataset.generate_on_device(
-            data["n"], data["d"], num_workers, devices, seed=seed,
-            noise=data["noise"], dtype=jnp.dtype(data["storage_dtype"]),
-            **extra,
+        return (
+            ShardedDataset.generate_on_device,
+            (data["n"], data["d"], num_workers, devices),
+            dict(seed=seed, noise=data["noise"],
+                 dtype=jnp.dtype(data["storage_dtype"]), **extra),
         )
-        jax.block_until_ready([(s.X, s.y) for s in ds.shards.values()])
-    elif data["kind"] == "sparse":
+    if data["kind"] == "sparse":
         from asyncframework_tpu.data.sparse import SparseShardedDataset
 
-        ds = SparseShardedDataset.generate_on_device(
-            data["n"], data["d"], data["nnz_per_row"], num_workers, devices,
-            seed=seed, noise=data["noise"], **extra,
+        return (
+            SparseShardedDataset.generate_on_device,
+            (data["n"], data["d"], data["nnz_per_row"], num_workers, devices),
+            dict(seed=seed, noise=data["noise"], **extra),
         )
-        jax.block_until_ready(
-            [(s.cols, s.vals, s.y) for s in ds.shards.values()]
-        )
-    else:
-        raise ValueError(f"unknown dataset kind {data['kind']!r}")
+    raise ValueError(f"unknown dataset kind {data['kind']!r}")
+
+
+def build_dataset(data: dict, num_workers: int, devices, seed: int):
+    """The cell's dataset on the device, from the seed, by the program's
+    own generators (what they produce is pinned after the window)."""
+    import jax
+
+    generate, args, kwargs = generator_call(data, num_workers, devices, seed)
+    ds = generate(*args, **kwargs)
+    jax.block_until_ready([
+        (s.cols, s.vals, s.y) if data["kind"] == "sparse" else (s.X, s.y)
+        for s in ds.shards.values()
+    ])
     return ds
 
 
@@ -242,7 +257,7 @@ def verify(ds, data: dict, config: dict, plan: dict, res, f0: float,
     from benchmark import reference, reference_saga
 
     shards = [ds.shard(w) for w in range(ds.num_workers)]
-    pins, f0_ref = reference.data_pins(shards, ds.d, plan["loss"])
+    pins, f0_ref = reference.data_pins(shards, plan["loss"])
     f_final_ref = reference.objective(shards, res.final_w, plan["loss"])
     want = config["pins"]
     tol = want["tolerance"]
@@ -391,6 +406,9 @@ def run_cell(args, man: "manifest_mod.Manifest") -> int:
     # a NaN is at or under no limit
     checks = {name: bool(v <= lim) for name, (v, lim) in compared.items()}
     spans["post_s"] = time.monotonic() - t_post
+    # between the run's fence (``elapsed_s`` ends at the final model's
+    # read-back) and its return the program evaluates its trajectory
+    spans["trajectory_eval_s"] = t_post - t_call - res.elapsed_s
 
     record = {
         "workload": args.workload, "seed": args.seed,
@@ -440,6 +458,15 @@ def run_cell(args, man: "manifest_mod.Manifest") -> int:
         info(profiled_run=ran, window=window,
              profiled_s=time.monotonic() - t_prof)
 
+    spans["wall_s"] = time.monotonic() - T0
+    budget_s = args.seconds + RUN_OVERHEAD_S
+    info(wall_s=spans["wall_s"], budget_s=budget_s,
+         trajectory_eval_s=spans["trajectory_eval_s"])
+    if spans["wall_s"] > budget_s:
+        print(f"benchmark: {args.workload} seed {args.seed} took "
+              f"{spans['wall_s']:.1f} s, over the {budget_s:.1f} s "
+              f"(seconds + RUN_OVERHEAD_S) a full check reckons a run at",
+              file=sys.stderr)
     failing = sorted(k for k, ok in checks.items() if not ok)
     if failing:
         # the reason goes where a reader of a refused run looks first

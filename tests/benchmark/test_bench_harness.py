@@ -70,30 +70,55 @@ def test_a_full_check_fits_the_drivers_budget_with_24_cells():
     rs = MANIFEST["run_seconds"]
     assert isinstance(rs, int) and 1 <= rs <= 51
     runs = 2 + 14 * 24
-    assert runs * (rs + 60) + 24 * 2 * 90 + 1200 <= 43200
+    assert run.RUN_OVERHEAD_S == 60  # what the contract allows a run
+    assert runs * (rs + run.RUN_OVERHEAD_S) + 24 * 2 * 90 + 1200 <= 43200
 
 
-@pytest.mark.parametrize("cell", [c["name"] for c in MANIFEST["workloads"]])
-def test_cell_resolves_to_a_plan_from_its_files(cell):
-    man = manifest_mod.Manifest()
+def _digits(text):
+    return re.sub(r"(?<=\d)[,_](?=\d)", "", text)
+
+
+def _held_to_its_sources_shape(config):
+    """A configuration file states what ITS source publishes (``published``:
+    ``n``, ``d`` and, for padded ELL, ``nnz_per_row``).  No width is cut,
+    ever; the scale ``n`` is cut only where the file lists it in ``reduced``
+    and its ``deployment`` names both row counts beside the reason."""
+    pub = config["published"]
+    assert config["d"] == pub["d"], "d is a width: never cut"
+    if config["kind"] == "sparse":
+        assert config["nnz_per_row"] == pub["nnz_per_row"], \
+            "the non-zeros a row are a width: never cut"
+    if config["n"] != pub["n"]:
+        assert "n" in config["reduced"], "n differs and is not listed"
+        assert config["n"] < pub["n"], "n is over the published n"
+        said = _digits(config.get("deployment", ""))
+        assert str(config["n"]) in said and str(pub["n"]) in said, \
+            "deployment does not say by how much n is cut"
+
+
+def _cell_resolves_to_a_plan_from_its_files(man, cell):
     entry = man.workload(cell)
     config = man.config(entry["config"])
     mix = man.traffic(entry["traffic"])
     plan = plan_mod.resolve(config, mix)
     assert set(plan) == set(plan_mod.RUN_KEYS)
-    centry = [c for c in MANIFEST["configs"] if c["name"] == entry["config"]][0]
-    assert centry["file"].startswith(tuple(p + "/" for p in MANIFEST["paths"]))
+    centry = [c for c in man.doc["configs"] if c["name"] == entry["config"]][0]
+    assert centry["file"].startswith(tuple(p + "/" for p in man.doc["paths"]))
     assert config["reduced"] == centry["reduced"]
     assert config["source"] == centry["source"]
     assert {"gamma", "data", "noise"} <= set(config["assumed"])
-    # the published shape: no width is cut
-    assert (config["n"], config["d"]) == (8_100_000, 784)
+    _held_to_its_sources_shape(config)
     kw = plan_mod.solver_config_kwargs(plan, seed=3, seconds=20, trace=False)
     from asyncframework_tpu.solvers.base import SolverConfig
 
     cfg = SolverConfig(**kw)
     assert cfg.run_timeout_s == 20 and cfg.trace_sample is None
     assert cfg.drain_batch == 1  # left at the program's default
+
+
+@pytest.mark.parametrize("cell", [c["name"] for c in MANIFEST["workloads"]])
+def test_cell_resolves_to_a_plan_from_its_files(cell):
+    _cell_resolves_to_a_plan_from_its_files(manifest_mod.Manifest(), cell)
 
 
 @pytest.mark.parametrize("kind,name", [("end_to_end", n) for n in E2E]
@@ -182,17 +207,16 @@ def _shard_bytes(ds):
 @pytest.mark.parametrize("name", ["tiny-dense-f32", "tiny-dense-bf16",
                                   "tiny-sparse"])
 def test_a_configuration_without_a_generator_key_builds_todays_arrays(name):
-    """No configuration in the tree carries ``generator``: the call is the
-    one the harness made before the key existed, letter for letter, and the
-    arrays are the same bytes for the same seed."""
+    """Without ``generator`` the call is the one the harness made before the
+    key existed, letter for letter, and the arrays are the same bytes for
+    the same seed.  (A configuration WITH the mapping is held to the next
+    test instead.)"""
     import jax
     import jax.numpy as jnp
 
     from asyncframework_tpu.data.sharded import ShardedDataset
     from asyncframework_tpu.data.sparse import SparseShardedDataset
 
-    for entry in MANIFEST["configs"]:
-        assert "generator" not in manifest_mod.Manifest().config(entry["name"])
     config = _tiny_config(name)
     assert "generator" not in config
     devs = jax.devices()[:1]
@@ -214,6 +238,30 @@ def test_a_configuration_without_a_generator_key_builds_todays_arrays(name):
         assert (data["index_itemsize"], data["width"]) == (4, 16)
     else:
         assert "index_itemsize" not in data
+
+
+def _generator_takes_the_configurations_call(config):
+    """Every key of the ``generator`` mapping is a keyword parameter of the
+    program's generator for the configuration's ``kind``, and none is an
+    argument the harness passes itself: the call ``build_dataset`` would
+    make binds to the generator's signature.  Nothing is built, so an 11 GB
+    deployment is held to it on the CPU."""
+    import inspect
+
+    generate, args, kwargs = run.generator_call(config, 8, None, seed=1)
+    sig = inspect.signature(generate)
+    for key in config.get("generator", {}):
+        # by name: a generator that swallows ``**kwargs`` takes none of them
+        assert key in sig.parameters, f"the generator takes no {key!r}"
+    sig.bind(*args, **kwargs)
+    return sorted(config.get("generator", {}))
+
+
+@pytest.mark.parametrize("name", [c["name"] for c in MANIFEST["configs"]])
+def test_a_configurations_generator_keys_are_the_generators_parameters(name):
+    _generator_takes_the_configurations_call(
+        manifest_mod.Manifest().config(name)
+    )
 
 
 @pytest.mark.parametrize("name", ["tiny-dense-f32", "tiny-sparse"])
@@ -257,6 +305,123 @@ def test_a_generator_key_the_program_does_not_take_fails_loudly(name):
     config["generator"] = {"no_such_argument": 1}
     with pytest.raises(TypeError, match="no_such_argument"):
         run.build_dataset(config, 4, jax.devices()[:1], seed=7)
+
+
+# ---------------------------- a configuration of another source, as data only
+
+#: what a later PR would add for a deployment of another source: the LIBSVM
+#: criteo shape (sizes from memory, as ISSUE 30 has them), its scale cut to
+#: six eighths, and a ``generator`` mapping (the keys are the stand-in's below:
+#: no test here may say what the program's generator takes or lacks)
+OTHER_SOURCE = {
+    "name": "other-sparse",
+    "source": "rehearsal: a public sparse set of another shape",
+    "kind": "sparse",
+    "n": 34_380_463, "d": 1_000_000, "nnz_per_row": 39,
+    "published": {"n": 45_840_617, "d": 1_000_000, "nnz_per_row": 39},
+    "storage_dtype": "bfloat16", "noise": 0.01,
+    "generator": {"value_dtype": "bfloat16", "labels": "zero_one"},
+    "solver": "asgd", "loss": "logistic", "num_workers": 8,
+    "batch_rate": 0.05, "bucket_ratio": 0.7, "target_fraction": 0.5,
+    "gamma": 1.0, "printer_freq": 10,
+    "pins": {},
+    "reduced": ["n", "storage_dtype"],
+    "deployment": "34,380,463 of the 45,840,617 rows (six eighths): a whole "
+                  "run has to fit the budget of a run",
+    "assumed": {"gamma": "-", "data": "-", "noise": "-"},
+}
+
+
+def _scratch_manifest(root, config):
+    """A manifest with one more configuration and a cell on it, added as
+    files and entries under a scratch root: no file of the tree is edited."""
+    import shutil
+
+    doc = json.loads(json.dumps(MANIFEST))
+    rel = f"benchmark/configs/{config['name']}.json"
+    os.makedirs(root / "benchmark" / "configs")
+    (root / rel).write_text(json.dumps(config))
+    shutil.copytree(os.path.join(ROOT, "benchmark", "traffic"),
+                    root / "benchmark" / "traffic")
+    doc["configs"].append({
+        "name": config["name"], "source": config["source"], "file": rel,
+        "reduced": config["reduced"], "why": "rehearsal",
+    })
+    doc["workloads"].append({
+        "name": config["name"] + ".steady", "config": config["name"],
+        "traffic": "steady", "chips": 1, "why": "rehearsal",
+    })
+    path = root / "BENCHMARK.json"
+    path.write_text(json.dumps(doc))
+    return manifest_mod.Manifest(str(path), root=str(root))
+
+
+def test_a_configuration_of_another_source_is_added_as_data(
+        tmp_path, monkeypatch):
+    """Another published shape, a cut ``n`` that is listed and accounted
+    for, and a ``generator`` mapping: the cell resolves to a plan and passes
+    what every cell and configuration of the tree is held to."""
+    from asyncframework_tpu.data.sparse import SparseShardedDataset
+
+    man = _scratch_manifest(tmp_path, OTHER_SOURCE)
+    _cell_resolves_to_a_plan_from_its_files(man, "other-sparse.steady")
+    config = man.config("other-sparse")
+    calls = []
+
+    def recording(cls, n, d, nnz_per_row, num_workers, devices=None, seed=42,
+                  noise=0.01, value_dtype="float32", labels="planted"):
+        calls.append((n, d, nnz_per_row, num_workers, seed, noise,
+                      value_dtype, labels))
+
+    monkeypatch.setattr(SparseShardedDataset, "generate_on_device",
+                        classmethod(recording))
+    assert _generator_takes_the_configurations_call(config) == [
+        "labels", "value_dtype"]
+    assert calls == []  # bound, not built
+    generate, args, kwargs = run.generator_call(config, 8, None, seed=3)
+    generate(*args, **kwargs)
+    assert calls == [(34_380_463, 1_000_000, 39, 8, 3, 0.01, "bfloat16",
+                      "zero_one")]
+    # a key the generator does not take, and one the harness passes itself
+    config["generator"] = {"no_such_argument": 1}
+    with pytest.raises(AssertionError, match="no_such_argument"):
+        _generator_takes_the_configurations_call(config)
+    config["generator"] = {"seed": 7}
+    with pytest.raises(TypeError, match="seed"):
+        _generator_takes_the_configurations_call(config)
+
+
+@pytest.mark.parametrize("fault,change", [
+    ("d is a width", {"d": 500_000}),
+    ("non-zeros a row", {"nnz_per_row": 32}),
+    ("not listed", {"reduced": ["storage_dtype"]}),
+    ("by how much", {"deployment": "six eighths of the rows"}),
+    ("over the published", {"n": 50_000_000,
+                "deployment": "50,000,000 rows where 45,840,617 are published"}),
+    ("published", {"published": None}),
+])
+def test_a_configuration_that_cuts_its_sources_shape_is_refused(
+        tmp_path, fault, change):
+    config = {**OTHER_SOURCE, **change}
+    if config["published"] is None:
+        del config["published"]  # nothing in the test stands for a source
+    man = _scratch_manifest(tmp_path, config)
+    with pytest.raises((AssertionError, KeyError), match=fault):
+        _cell_resolves_to_a_plan_from_its_files(man, "other-sparse.steady")
+
+
+def test_the_published_key_reaches_no_run():
+    """``published`` is for the test above: the plan does not carry it and
+    the generator's call is the one made without it."""
+    man = manifest_mod.Manifest()
+    for cell in MANIFEST["workloads"]:
+        config = man.config(cell["config"])
+        bare = {k: v for k, v in config.items() if k != "published"}
+        mix = man.traffic(cell["traffic"])
+        assert plan_mod.resolve(config, mix) == plan_mod.resolve(bare, mix)
+        told, plain = (run.generator_call(c, 8, None, seed=5)
+                       for c in (config, bare))
+        assert told[1:] == plain[1:]
 
 
 # ------------------------------------------------------------ the rehearsal
@@ -377,6 +542,49 @@ def test_traced_rehearsal_reports_per_layer_metrics(
     assert all(record["checks"].values()), record["checks"]
     assert last["correct"] is True
     assert list(last)[-1] == "compared"
+
+
+def test_a_run_says_how_long_it_took_against_the_budget(
+        tiny_manifest, on_cpu, capsys, monkeypatch):
+    """``wall_s`` (process start to the result line) on the last info line,
+    beside the ``seconds + RUN_OVERHEAD_S`` a full check reckons a run at;
+    over it the run says so on stderr, before the compared numbers, and is
+    reported as any other."""
+    on_cpu(1)
+    monkeypatch.setattr(run, "T0", run.time.monotonic())
+    rc = run.main(["--workload", "tiny-sparse.steady", "--seed", "11",
+                   "--seconds", "1.5"], manifest_path=tiny_manifest)
+    io = capsys.readouterr()
+    lines = [json.loads(ln) for ln in io.out.splitlines() if ln.strip()]
+    assert rc == 0 and lines[-1]["correct"] is True
+    said = lines[-2]["info"]
+    assert set(said) == {"wall_s", "budget_s", "trajectory_eval_s"}
+    assert said["budget_s"] == 1.5 + run.RUN_OVERHEAD_S
+    record = [ln["info"] for ln in lines[:-1] if "checks" in ln["info"]][0]
+    spans, elapsed = record["spans"], record["result"]["elapsed_s"]
+    assert said["wall_s"] >= spans["setup_s"] + elapsed + spans["post_s"]
+    assert 0 <= said["trajectory_eval_s"] == spans["trajectory_eval_s"]
+    assert said["trajectory_eval_s"] == pytest.approx(
+        record["run_wall_s"] - elapsed)
+    assert said["wall_s"] < said["budget_s"] and "took" not in io.err
+
+
+def test_a_run_over_the_budget_says_so_and_is_reported_as_before(
+        tiny_manifest, on_cpu, capsys, monkeypatch):
+    on_cpu(1)
+    monkeypatch.setattr(run, "RUN_OVERHEAD_S", 0)
+    monkeypatch.setattr(run, "T0", run.time.monotonic())
+    rc = run.main(["--workload", "tiny-dense-f32.steady", "--seed", "12",
+                   "--seconds", "1.5"], manifest_path=tiny_manifest)
+    io = capsys.readouterr()
+    last = json.loads(io.out.splitlines()[-1])
+    assert rc == 0 and last["correct"] is True and set(last) == RESULT_KEYS
+    err = io.err.splitlines()
+    over = [i for i, ln in enumerate(err) if "over the 1.5 s" in ln]
+    assert len(over) == 1 and "RUN_OVERHEAD_S" in err[over[0]]
+    # the compared numbers stay the last lines of stderr
+    assert all(ln.startswith("compared ") for ln in err[over[0] + 1:])
+    assert len(err) - over[0] - 1 == len(last["compared"])
 
 
 def test_a_host_that_holds_every_thread_costs_the_run_no_worker(

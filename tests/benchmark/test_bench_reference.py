@@ -121,13 +121,13 @@ def test_objective_matches_the_programs_trajectory_eval(kind):
 
 def test_data_pins_hold_for_both_generators():
     dense = ShardedDataset.generate_on_device(4096, 64, 4, jax.devices()[:1], seed=11)
-    pins, f0 = reference.data_pins([dense.shard(i) for i in range(4)], 64)
+    pins, f0 = reference.data_pins([dense.shard(i) for i in range(4)])
     assert abs(pins["row_second_moment"] - 1.0) < 0.01
     assert abs(f0 - pins["label_second_moment"]) < 1e-6 * f0
     sparse = SparseShardedDataset.generate_on_device(
         4096, 512, 12, 4, jax.devices()[:1], seed=11
     )
-    pins, _f0 = reference.data_pins([sparse.shard(i) for i in range(4)], 512)
+    pins, _f0 = reference.data_pins([sparse.shard(i) for i in range(4)])
     assert pins["nnz_per_row"] == 12
     assert abs(pins["row_second_moment"] - 1.0) < 0.02
 
@@ -325,3 +325,73 @@ def test_the_padded_ell_sums_block_builds_no_gradient(loss):
         *args, weights, start, block=block, d=d, loss=loss
     ).compile().as_text()
     assert "scatter" in grad
+
+
+# ------------------------------------------------- the pins gather nothing
+#
+# Until PR 30 ``data_pins`` walked the dataset with a model of zeros: for
+# padded ELL a ``w[cols]`` gather of every stored slot, multiplied by zeros.
+# The parent's pass is still here: it is ``dataset_sums`` at that model.
+
+
+def _parent_data_pins(shards, d, loss):
+    tot = reference.dataset_sums(shards, np.zeros(d, np.float32), loss)
+    n = tot["rows"]
+    pins = {"row_second_moment": tot["xx"] / n,
+            "label_second_moment": tot["yy"] / n}
+    if tot["nnz"]:
+        pins["nnz_per_row"] = tot["nnz"] / n
+    return pins, tot["loss"] / n
+
+
+@pytest.mark.parametrize("loss", ["least_squares", "logistic"])
+@pytest.mark.parametrize("name", ["tiny-dense-f32", "tiny-dense-bf16",
+                                  "tiny-sparse"])
+def test_data_pins_without_a_model_equal_the_parents_to_the_bit(name, loss):
+    """A margin of exact zeros gives the same ``per_row`` whether it was
+    multiplied out or written down: the pins and the objective at ``w = 0``
+    are the parent's bits, whole shards and ragged last block alike."""
+    config, ds = _tiny_dataset(name)
+    shards = [ds.shard(i) for i in range(ds.num_workers)]
+    assert reference.data_pins(shards, loss) == _parent_data_pins(
+        shards, config["d"], loss)
+    zeros = np.zeros(config["d"], np.float32)
+    for sh in shards:
+        assert int(sh.y.shape[0]) % 384
+        got = reference.shard_sums(sh, None, loss, block_rows=384)
+        want = reference.shard_sums(sh, zeros, loss, block_rows=384)
+        assert got == want and got["loss"] > 0
+
+
+@pytest.mark.parametrize("loss", ["least_squares", "logistic"])
+def test_the_pins_blocks_gather_and_multiply_no_model(loss):
+    """Read on the CPU's compiled program, beside the test above it: with
+    no model the padded-ELL block holds no ``gather`` (the objective's
+    block does), and the dense block no ``dot``."""
+    rows, width, d, block = 1366, 16, 1000, 384
+    cols, vals, y, w = (
+        jax.ShapeDtypeStruct((rows, width), jnp.int32),
+        jax.ShapeDtypeStruct((rows, width), jnp.float32),
+        jax.ShapeDtypeStruct((rows,), jnp.float32),
+        jax.ShapeDtypeStruct((d,), jnp.float32),
+    )
+    start = jax.ShapeDtypeStruct((), jnp.int32)
+
+    def ops(fn, *args):
+        text = fn.lower(*args, start, block=block,
+                        loss=loss).compile().as_text()
+        found = {m["op"] for m in _HLO.finditer(text)}
+        assert len(found) > 5
+        return found
+
+    def has(found, word):
+        return sorted(op for op in found if word in op)
+
+    pins = ops(reference._ell_sums, cols, vals, y, None)
+    assert not has(pins, "gather") and not has(pins, "scatter")
+    assert has(ops(reference._ell_sums, cols, vals, y, w), "gather")
+    X = jax.ShapeDtypeStruct((rows, d), jnp.float32)
+    pins = ops(reference._dense_sums, X, y, None)
+    assert not has(pins, "dot") and not has(pins, "custom-call")
+    objective = ops(reference._dense_sums, X, y, w)
+    assert has(objective, "dot") or has(objective, "custom-call")
