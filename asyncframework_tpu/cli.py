@@ -56,7 +56,7 @@ CONF_TO_FIELD: Dict[str, str] = {
     "async.delay.coeff": "coeff",
     "async.seed": "seed",
     # engine knobs (spark.speculation / dynamicAllocation analogs)
-    "async.drain.batch": "drain_batch",
+    "async.drain.batch": "drain_batch",  # no reader (solvers/base.py says why)
     "async.speculation.quantile": "speculation_quantile",
     "async.speculation.multiplier": "speculation_multiplier",
     "async.speculation.min.ms": "speculation_min_ms",

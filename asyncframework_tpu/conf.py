@@ -168,14 +168,17 @@ SEED = ConfigEntry("async.seed", 42, int, "Root PRNG seed.")
 # async.mode, async.updater.drain.max, async.heartbeat.interval and
 # async.heartbeat.timeout were declared here for reference parity but
 # never read (async-lint conf-dead-knob): mode is selected by driver
-# alias (asgd vs asgd-sync), drain batching rides async.drain.batch, and
+# alias (asgd vs asgd-sync), the updater drains what is queued, and
 # executor heartbeats ride async.heartbeat.timeout.ms -- deleted rather
 # than left as operator-facing no-ops.
 MODEL_VERSIONS = ConfigEntry("async.broadcast.versions", 4, int,
                              "Model versions kept live in the versioned store "
                              "(SolverConfig.max_live_versions).")
 DRAIN_BATCH = ConfigEntry("async.drain.batch", 1, int,
-                          "Queued gradients folded into one device dispatch.")
+                          "No reader since PR 31: ASGD's updater folds "
+                          "whatever is queued into one device dispatch by "
+                          "itself (SolverConfig.drain_batch says why the "
+                          "key is still here).")
 UI_PORT = ConfigEntry("async.ui.port", -1, int,
                       "Live dashboard HTTP port (0 = ephemeral, -1 = off) "
                       "-- spark.ui.port analog.")

@@ -313,37 +313,39 @@ def make_saga_table_delta():
     return saga_table_delta
 
 
-def make_asgd_apply_batch(
-    gamma: float, batch_rate: float, n: int, num_workers: int, m: int
+def make_asgd_apply_fold(
+    gamma: float, batch_rate: float, n: int, num_workers: int
 ):
-    """jit (w, G (m, d), mask (m,), k) -> (w', k') -- ``m`` queued gradients
-    applied in ONE dispatch.
+    """jit (w, gs, m, k) -> (w', k + m) -- the first ``m`` of a drain's
+    gradient handles applied in ONE dispatch.
 
-    Exactness: the sequential accept path is ``w <- w - c_j g_j`` with step
-    sizes ``c_j = (gamma / sqrt(k_j/P + 1)) / parRecs`` that do not depend on
-    ``w``, so a drained batch folds into one masked weighted sum --
-    numerically the same model (up to float addition order) at 1/m the
-    dispatch cost.  The reference drains its whole queue per updater wake for
-    the same reason (``SparkASGDThread.scala:154-158``); here the drain is
-    also one device op.  ``mask`` marks accepted entries (stale slots are 0);
-    ``k`` advances by the number accepted.
+    ``gs`` is a tuple of FIXED length (the engine's updater pads a short
+    drain with one cached zero handle to ``num_workers``), and how many of
+    its slots count is data (``m``, a device f32 scalar like ``k``), so
+    there is one executable whatever the drain's size.  Exactness: the serial accept path is ``w <- w - c_j
+    g_j`` with step sizes ``c_j = (gamma / sqrt(k_j/P + 1)) / parRecs``
+    that do not depend on ``w``, so a drain folds into one chain of the
+    same subtractions in the same order, ``k_j`` advancing over the live
+    slots; a slot past ``m`` subtracts ``0 * g``.  The reference drains
+    its whole queue per updater wake for the same reason
+    (``SparkASGDThread.scala:154-158``); here the drain is also one device
+    op.  ``w`` is never donated (an old handle is a model version, see
+    :func:`make_asgd_apply`) and neither are the gradients: the padding
+    repeats one buffer, and a buffer may not be donated twice.
     """
     par_recs = batch_rate * n / num_workers
 
-    # only k is donated: no output matches G/mask shapes, so donating them
-    # would just emit unusable-buffer warnings
     @functools.partial(jax.jit, donate_argnums=(3,))
-    def apply_batch(w, G, mask, k):
+    def apply_fold(w, gs, m, k):
         with jax.named_scope("apply"):
-            # per-slot accepted count
-            accepted_before = jnp.cumsum(mask) - mask
-            kk = k + accepted_before
-            lr = gamma / jnp.sqrt(kk / num_workers + 1.0)
-            coeff = (lr / par_recs) * mask
-            return w - coeff @ G, k + jnp.sum(mask)
+            j = jnp.arange(len(gs), dtype=jnp.float32)
+            lr = gamma / jnp.sqrt((k + j) / num_workers + 1.0)
+            coeff = jnp.where(j < m, lr / par_recs, 0.0)
+            for c, g in zip(coeff, gs):
+                w = w - c * g
+            return w, k + m
 
-    del m  # shape is carried by G itself; kept in the signature for intent
-    return _prof.wrap_dispatch(apply_batch, "kernel.dispatch", "asgd_apply_batch")
+    return _prof.wrap_dispatch(apply_fold, "kernel.dispatch", "asgd_apply_fold")
 
 
 def make_asgd_apply_merge(
@@ -354,10 +356,10 @@ def make_asgd_apply_merge(
     gradients applied in ONE device dispatch, **bit-identical** to running
     :func:`make_asgd_apply` serially over the masked slots.
 
-    Unlike :func:`make_asgd_apply_batch` (the in-process updater's masked
-    weighted sum, exact only up to float addition order), this folds the
-    slots through a ``lax.scan`` whose body is the serial apply expression
-    verbatim -- same per-element operation sequence, so the DCN merge
+    Unlike :func:`make_asgd_apply_fold` (the in-process updater's fold
+    over a tuple of handles, held to the serial path within a tolerance),
+    this folds the slots of a stacked ``G`` through a ``lax.scan`` whose
+    body is the serial apply expression verbatim -- same per-element operation sequence, so the DCN merge
     queue's fused apply can be asserted equal to the serial path bit for
     bit.  One compile per (m, d) shape; the PS pads short batches to its
     merge bound so only one shape ever exists.

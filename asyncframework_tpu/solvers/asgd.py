@@ -22,10 +22,11 @@ dispatches, not two transfers.
 
 from __future__ import annotations
 
+import itertools
 import operator
 import queue
 import time
-from typing import Dict, Optional
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -46,13 +47,6 @@ from asyncframework_tpu.solvers.base import (
 from asyncframework_tpu.metrics import trace
 from asyncframework_tpu.solvers.engine_loop import EngineRun, EngineSolver
 from asyncframework_tpu.solvers.instrumentation import on_device, worker_task
-
-
-# minimum drained-batch size for the stacked one-dispatch apply: below
-# this, the stack copy costs more than the dispatches it saves.  Shared by
-# the runtime drain and the warm-up gate so the pre-compile always covers
-# the path the updater actually takes.
-BATCH_DRAIN_MIN = 3
 
 
 class ASGD(EngineSolver):
@@ -94,6 +88,9 @@ class ASGD(EngineSolver):
         self._apply = steps.make_asgd_apply(
             config.gamma, config.batch_rate, self.ds.n, config.num_workers
         )
+        self._apply_fold = steps.make_asgd_apply_fold(
+            config.gamma, config.batch_rate, self.ds.n, config.num_workers
+        )
         self._sync_apply = steps.make_sync_apply(
             config.gamma, config.batch_rate, self.ds.n
         )
@@ -119,27 +116,22 @@ class ASGD(EngineSolver):
             jnp.float32(state["k"]), self.driver_device
         )
         run.start_monitors()
-        apply_batch = steps.make_asgd_apply_batch(
-            cfg.gamma, cfg.batch_rate, self.ds.n, cfg.num_workers,
-            cfg.drain_batch,
-        )
-        self._warm_hot_path(apply_batch, max(cfg.drain_batch, 1))
+        self._warm_hot_path()
+        nw, freq = cfg.num_workers, cfg.printer_freq
+        # what the fold takes beside the drain's gradients, resident
+        # before the clock starts so that a drain transfers nothing: the
+        # ONE zero handle that pads a short drain's tuple to the fold's
+        # arity, and every count of live slots as a device scalar (a
+        # Python int is a 0.2 ms transfer a dispatch on the v5e: 0.53 ms
+        # a fold against 0.34, PERF.md section 6, PR 31)
+        zeros = (jax.device_put(jnp.zeros(d, jnp.float32),
+                                self.driver_device),) * nw
+        counts = [jax.device_put(jnp.float32(m), self.driver_device)
+                  for m in range(nw + 1)]
         run.start_clock()
         snapshots, now_ms = run.snapshots, run.now_ms
 
-        # per-accepted-count mask cache: rebuilt host constants would cost
-        # an extra transfer per drain.  Short drains pad the gradient LIST
-        # with this cached zero handle so the stacked G is always exactly
-        # (max_drain, d) -- ONE stack shape, ONE compile (a per-mcount
-        # stack/concat would compile a fresh executable for every distinct
-        # drain size, inside the timed loop)
-        _mask_cache: Dict[int, jax.Array] = {}
-        _zero_g = jax.device_put(
-            jnp.zeros(d, jnp.float32), self.driver_device
-        )
-
         def updater():
-            max_drain = max(cfg.drain_batch, 1)
             clock = inst.updater_clock
             while not stop.is_set():
                 with state_lock:
@@ -152,23 +144,21 @@ class ASGD(EngineSolver):
                     continue
                 finally:
                     clock.works()
-                # opportunistic drain: everything already queued, up to the
-                # batch cap, folds into one device dispatch below
-                while len(results) < max_drain:
-                    try:
-                        results.append(ctx.collect_all(timeout=0))
-                    except queue.Empty:
-                        break
+                # the drain takes what is there: every result already
+                # queued, up to the arity the fold below is compiled for
+                # (the submitter's backlog bound keeps the queue near nw;
+                # a rest waits for the next wake)
+                results.extend(itertools.islice(ctx.drain(), nw - 1))
                 do_save = False
                 # the drain's sampled updates (metrics/trace.py; () in an
                 # untraced run): their result.queue and compute end here;
-                # merge.queue is the lock and the filter, merge.apply the
+                # merge.queue is the lock and the filter, merge.apply a
                 # dispatch below
                 uts = inst.on_drained(results)
                 merge_queue = trace.span(trace.MERGE_QUEUE, uts).begin()
                 with state_lock:
                     k = state["k"]
-                    # never apply past the iteration budget: trim the batch
+                    # never apply past the iteration budget: trim the drain
                     room = cfg.num_iterations - k
                     merged = []
                     accepted_g = []
@@ -192,60 +182,57 @@ class ASGD(EngineSolver):
                         else:
                             state["dropped"] += 1
                     merge_queue.end()
-                    if uts:
-                        # what each sampled update's merge.apply carries
-                        uts = inst.apply_attrs((m[0], m[1]) for m in merged)
-                    t_apply = time.perf_counter_ns()
-                    with trace.span(trace.MERGE_APPLY, uts,
-                                    batch=len(accepted_g)):
-                        if len(accepted_g) >= BATCH_DRAIN_MIN:
-                            # stack+apply = 2 dispatches replacing m.  The
-                            # list is padded with the cached zero handle to
-                            # the fixed max_drain length and masked, so
-                            # stack AND apply_batch each compile ONCE,
-                            # never per drained batch size.
-                            mcount = len(accepted_g)
-                            padded = accepted_g + [_zero_g] * (
-                                max_drain - mcount
+                    m = len(accepted_g)
+                    # ONE dispatch a drain, split only where a snapshot is
+                    # due: snapshot j holds the model after update
+                    # j * printer_freq + 1, folded or not (a reader of the
+                    # trajectory reckons its updates so, benchmark/
+                    # target.py), so a dispatch ends ON that update
+                    ends = [j + 1 for j in range(-k % freq, m, freq)]
+                    if not ends or ends[-1] < m:
+                        ends.append(m)
+                    lo = 0
+                    for hi in ends:
+                        n = hi - lo
+                        in_it = uts
+                        if uts:
+                            # the sampled updates of THIS dispatch, each
+                            # with what its merge.apply carries; a dropped
+                            # one rides with the slot it was filtered
+                            # before, or with the last
+                            top = hi if hi < m else m + 1
+                            in_it = inst.apply_attrs(
+                                (r, acc) for r, acc, at_k, _ in merged
+                                if lo <= at_k - k < top
                             )
-                            G = jnp.stack(padded)
-                            mask = _mask_cache.get(mcount)
-                            if mask is None:
-                                mask = jax.device_put(
-                                    jnp.asarray(
-                                        [1.0] * mcount
-                                        + [0.0] * (max_drain - mcount),
-                                        jnp.float32,
-                                    ),
-                                    self.driver_device,
-                                )
-                                _mask_cache[mcount] = mask
-                            state["w"], state["k_dev"] = apply_batch(
-                                state["w"], G, mask, state["k_dev"]
-                            )
-                        else:
-                            for g in accepted_g:
+                        t_apply = time.perf_counter_ns()
+                        with trace.span(trace.MERGE_APPLY, in_it, batch=n):
+                            if n == 1:
                                 state["w"], state["k_dev"] = self._apply(
-                                    state["w"], g, state["k_dev"]
+                                    state["w"], accepted_g[lo], state["k_dev"]
                                 )
-                    inst.updater_apply_ns += time.perf_counter_ns() - t_apply
-                    if accepted_g:
-                        k_new = k + len(accepted_g)
-                        state["k"] = k_new
-                        state["accepted"] += len(accepted_g)
-                        # snapshot when the batch crossed a printer boundary
-                        # (the single-apply path snapshotted at each
-                        # k % printer_freq == 0; a batch may cover several)
-                        if any(
-                            (k + j) % cfg.printer_freq == 0
-                            for j in range(len(accepted_g))
-                        ):
-                            with trace.span(trace.SNAPSHOT):
-                                snapshots.append((now_ms(), state["w"]))
-                                inst.on_snapshot(state["accepted"])
-                        # range check: a batch jumping over a checkpoint
+                            elif n:
+                                state["w"], state["k_dev"] = self._apply_fold(
+                                    state["w"],
+                                    tuple(accepted_g[lo:hi]) + zeros[n:],
+                                    counts[n], state["k_dev"],
+                                )
+                        inst.updater_apply_ns += (
+                            time.perf_counter_ns() - t_apply
+                        )
+                        if n:
+                            inst.apply_dispatches += 1
+                            state["k"] = k + hi
+                            state["accepted"] += n
+                            if (k + hi - 1) % freq == 0:
+                                with trace.span(trace.SNAPSHOT):
+                                    snapshots.append((now_ms(), state["w"]))
+                                    inst.on_snapshot(state["accepted"])
+                        lo = hi
+                    if m:
+                        # range check: a drain jumping over a checkpoint
                         # boundary must still save
-                        do_save = ckpt.should_save_range(k, k_new)
+                        do_save = ckpt.should_save_range(k, k + m)
                         save_k, save_w = state["k"], state["w"]
                 # outside the lock, as ever: the events and the counters
                 for res, accepted, at_k, task_ms in merged:
@@ -440,21 +427,19 @@ class ASGD(EngineSolver):
         return run.result()
 
     # ---------------------------------------------------------------- helpers
-    def _warm_hot_path(
-        self, apply_batch=None, max_drain: int = 0, sync: bool = False
-    ) -> None:
+    def _warm_hot_path(self, sync: bool = False) -> None:
         """Compile this mode's hot-path executables before the trajectory
         clock starts.
 
         Parity: the reference's first iteration always blocks precisely to
         warm Spark's caches (``DAGScheduler.scala:641-656`` ``first_iter``);
         the TPU analog is XLA compilation of the worker step, the accept
-        path, and the batched drain, which would otherwise land inside the
+        path, and the folded drain, which would otherwise land inside the
         timed region on their first invocation (~1 s on a real chip).
 
         jit caches per input SHAPE, so every distinct shard shape is warmed
         (shards differ by one row when ``n % num_workers != 0``).  Async
-        warms ``_apply`` + ``apply_batch``; sync warms ``_sync_apply`` +
+        warms ``_apply`` + ``_apply_fold``; sync warms ``_sync_apply`` +
         ``add_grads``.  All dummies are fresh device buffers, so donated
         arguments never touch live state.
         """
@@ -490,16 +475,13 @@ class ASGD(EngineSolver):
             wd, kd = self._sync_apply(wd, acc, kd)
         else:
             wd, kd = self._apply(wd, g, kd)
-            if apply_batch is not None and max_drain >= BATCH_DRAIN_MIN:
-                # stack of max_drain vectors, exactly like the drain path
-                # builds G -- warms the stack executable too, not just
-                # apply_batch
-                zero = jax.device_put(jnp.zeros(d, jnp.float32), drv)
-                G = jnp.stack([zero] * max_drain)
-                mask = jax.device_put(
-                    jnp.zeros((max_drain,), jnp.float32), drv
-                )
-                wd, kd = apply_batch(wd, G, mask, kd)
+            # the fold as the updater calls it: ONE arity, the count as
+            # data, so no drain's size compiles inside the window
+            zero = jax.device_put(jnp.zeros(d, jnp.float32), drv)
+            wd, kd = self._apply_fold(
+                wd, (zero,) * self.cfg.num_workers,
+                jax.device_put(jnp.float32(2.0), drv), kd,
+            )
         wd.block_until_ready()
 
     def _make_task(self, wid: int, w_pub, key, delay_model: DelayModel,

@@ -296,11 +296,14 @@ class SolverConfig:
     calibration_iters: Optional[int] = None  # default 100 * num_workers
     collect_timeout_s: float = 0.05
     run_timeout_s: float = 600.0
-    # updater drain batching (SparkASGDThread.scala:154-158 drains the whole
-    # queue per wake; with drain_batch > 1 a drained batch also folds into
-    # ONE device dispatch -- exact for ASGD's w-independent step sizes).
-    # Default 1: the stack copy can outweigh the saved dispatches.  Which
-    # side wins on the local chip is not measured (ROADMAP Speed 6).
+    # NO READER (PR 31).  ASGD's updater folds whatever is queued when it
+    # wakes into ONE device dispatch and reads no knob for it: on the v5e
+    # a fold over a tuple of handles costs 0.34 ms of host time at any
+    # drain size, serial applies 0.23 ms each, the stacked form this field
+    # once selected 9.7 ms (PERF.md section 6, PR 31).  The field, the
+    # key async.drain.batch (conf.py) and its cli.py mapping wait for
+    # tests/benchmark/test_bench_harness.py's `cfg.drain_batch == 1` to
+    # go (a benchmark PR's), then for a simplicity PR (ROADMAP Design 2).
     drain_batch: int = 1
     # DCN data plane (parallel/ps_dcn.py).  pull_mode: None = resolve from
     # conf async.pull.mode ('full' ships the whole model per PULL,

@@ -184,6 +184,10 @@ class RunInstruments:
         #: the part of the updater's busy time spent inside its apply
         #: dispatches, which block while the device's queue is full
         self.updater_apply_ns = 0
+        #: device dispatches the updater made to apply what it accepted
+        #: (ASGD's engine run counts them, one a drain where it folds; 0
+        #: from a run that does not count)
+        self.apply_dispatches = 0
         self.submit_empty_polls = 0   # submitter turns that found no cohort
         self.drains = 0               # updater wakes that merged something
         self.drain_items_max = 0
@@ -460,6 +464,7 @@ class RunInstruments:
         out.update({
             "drains": self.drains,
             "drain_items_max": self.drain_items_max,
+            "apply_dispatches": self.apply_dispatches,
             "task_retries": int(task_retries),
             "compiles_in_run": compiles_so_far() - self._compiles0,
         })

@@ -59,6 +59,32 @@ def no_compile_cache():
     cc.reset_cache()
 
 
+@pytest.fixture()
+def held_updater(monkeypatch):
+    """``held_updater(want)``: from now on an engine updater's blocking
+    collect returns only once ``want`` results are queued (or 2 s have
+    passed: a run's last results may be fewer).  With ``want`` a whole
+    fleet the submitter's backlog bound stops there, and the updater wakes
+    to a backlog it drains at once: how a test builds a folded drain."""
+    import time
+
+    from asyncframework_tpu.context import AsyncContext
+
+    real = AsyncContext.collect_all
+
+    def hold(want):
+        def collect_all(self, timeout=None):
+            if timeout:  # the blocking take; ctx.drain() never comes here
+                deadline = time.monotonic() + 2.0
+                while self.size() < want and time.monotonic() < deadline:
+                    time.sleep(0.0005)
+            return real(self, timeout=timeout)
+
+        monkeypatch.setattr(AsyncContext, "collect_all", collect_all)
+
+    return hold
+
+
 @pytest.fixture(scope="session")
 def devices8():
     import jax

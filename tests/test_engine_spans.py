@@ -108,27 +108,37 @@ def test_every_update_is_one_trace_whose_compute_has_four_children(
 
 
 def test_a_drain_records_one_apply_span_per_update_with_the_batch(
-        problem, tmp_path):
-    """With ``drain_batch`` the updater applies several results in one
-    dispatch: every sampled update of the drain gets the drain's
-    ``merge.apply`` (same start and duration, ``batch`` = accepted in
-    it), never its duration divided by the batch."""
+        problem, tmp_path, held_updater):
+    """Under a backlog the updater applies several results in one
+    dispatch: every sampled update of that dispatch gets ITS
+    ``merge.apply`` (same start and duration, ``batch`` = the slots of
+    that dispatch), never its duration divided by the batch; and every
+    sampled update appears in exactly one ``merge.apply``, also where a
+    snapshot split its drain in two."""
     log = tmp_path / "drain.jsonl"
-    res = _run(ASGD, "run", problem, trace_sample=1.0, drain_batch=4,
+    held_updater(8)
+    res = _run(ASGD, "run", problem, trace_sample=1.0,
                num_workers=8, num_iterations=96, event_log=str(log))
     spans, _ = trace.load_trace_events(log)
     applies = [s for s in spans if s.stage == trace.MERGE_APPLY]
-    assert applies and all(1 <= s.batch <= 4 for s in applies if s.accepted)
-    assert res.extras["drain_items_max"] <= 4
+    assert applies and all(1 <= s.batch <= 8 for s in applies if s.accepted)
+    assert 1 < res.extras["drain_items_max"] <= 8
+    assert len({s.trace_id for s in applies}) == len(applies)
+    assert len([s for s in applies if s.accepted]) == res.accepted
     by_start = {}
     for s in applies:
         by_start.setdefault((s.start_ms, s.dur_ms), []).append(s)
+    # one interval a dispatch
+    assert len(by_start) == res.extras["apply_dispatches"]
     shared = [g for g in by_start.values() if len(g) > 1]
-    if res.extras["drain_items_max"] > 1:
-        assert shared  # a drain of several results shares one interval
-    for group in shared:
-        assert len({s.trace_id for s in group}) == len(group)
+    assert shared  # a dispatch of several results shares one interval
+    for group in by_start.values():
         assert len({s.batch for s in group}) == 1
+        assert group[0].batch == len(group)  # sampled 1 in 1: all of them
+    # a drain split at a snapshot's update: the first dispatch ends ON it
+    sizes = [g[0].batch for _k, g in sorted(by_start.items())]
+    ends = set(np.cumsum(sizes))
+    assert all(j * 10 + 1 in ends for j in range(10))
 
 
 # ------------------------------------------- the stages on the profiler's clock
@@ -311,17 +321,20 @@ def test_staleness_hist_counts_every_merged_result(solver_cls, taw, problem):
                    if s > taw) == res.dropped
 
 
-@pytest.mark.parametrize("drain_batch", [1, 4])
+@pytest.mark.parametrize("backlog", [False, True])
 def test_snapshot_updates_are_the_accepted_counts_behind_the_trajectory(
-        drain_batch, problem):
-    res = _run(ASGD, "run", problem, drain_batch=drain_batch, num_workers=8,
+        backlog, problem, held_updater):
+    if backlog:
+        held_updater(8)
+    res = _run(ASGD, "run", problem, num_workers=8,
                num_iterations=64, printer_freq=5)
     ups = res.snapshot_updates
     assert len(ups) == len(res.trajectory)
     assert ups[0] == 0 and ups[-1] == res.accepted == 64
     assert ups == sorted(ups)
-    if drain_batch == 1:
-        assert ups[1:-1] == [j * 5 + 1 for j in range(len(ups) - 2)]
+    # folded or not: the model after update j * printer_freq + 1
+    assert ups[1:-1] == [j * 5 + 1 for j in range(len(ups) - 2)]
+    assert (res.extras["drain_items_max"] > 1) or not backlog
 
 
 # ------------------------------------------------------------- the host gauge

@@ -167,48 +167,82 @@ class TestCollectives:
         np.testing.assert_allclose(np.asarray(g), expected, rtol=2e-4, atol=1e-2)
 
 
-class TestBatchedApply:
-    def test_batch_apply_matches_sequential(self):
+#: the fold against the serial path: the same subtractions in the same
+#: order, so what may differ is how a compiler contracts ``w - c * g``
+#: inside one fused chain: under 1e-6 of the model's largest element (f32
+#: eps is 1.2e-7; bit-equal on the CPU backend, 3e-8 of 4.1 measured on
+#: the v5e over 32 slots, PR 31)
+FOLD_RTOL = 1e-6
+
+
+class TestFoldedApply:
+    ARITY = 8
+
+    def _problem(self, seed, count):
+        rs = np.random.default_rng(seed)
+        d = 32
+        w0 = rs.normal(size=d).astype(np.float32)
+        gs = [jnp.asarray(rs.normal(size=d).astype(np.float32))
+              for _ in range(count)]
+        return w0, gs, jnp.zeros(d, jnp.float32)
+
+    @pytest.mark.parametrize("m", range(ARITY + 1))
+    def test_fold_matches_serial(self, m):
+        """The first ``m`` slots of the tuple applied in one dispatch give
+        the serial path's model and, to the bit, its counter; the slots
+        past ``m`` (the updater's zero padding) change nothing."""
         from asyncframework_tpu.ops import steps
 
-        rs = np.random.default_rng(0)
-        d, m = 32, 6
-        gamma, b, n, nw = 0.7, 0.1, 10_000, 8
-        w0 = rs.normal(size=d).astype(np.float32)
-        G = rs.normal(size=(m, d)).astype(np.float32)
-
+        gamma, b, n, nw = 0.7, 0.1, 10_000, self.ARITY
+        w0, gs, zero = self._problem(m, m)
         apply_one = steps.make_asgd_apply(gamma, b, n, nw)
-        w_seq = jnp.asarray(w0)
-        k = jnp.float32(5.0)
-        for i in range(m):
-            w_seq, k = apply_one(w_seq, jnp.asarray(G[i]), k)
+        w_seq, k = jnp.asarray(w0), jnp.float32(37.0)
+        for g in gs:
+            w_seq, k = apply_one(w_seq, jnp.array(g), k)  # g is donated
 
-        apply_many = steps.make_asgd_apply_batch(gamma, b, n, nw, m)
-        w_bat, k_bat = apply_many(
-            jnp.asarray(w0), jnp.asarray(G),
-            jnp.ones(m, jnp.float32), jnp.float32(5.0),
+        fold = steps.make_asgd_apply_fold(gamma, b, n, nw)
+        w_fold, k_fold = fold(
+            jnp.asarray(w0), tuple(gs) + (zero,) * (nw - m), jnp.float32(m),
+            jnp.float32(37.0),
         )
-        np.testing.assert_allclose(np.asarray(w_bat), np.asarray(w_seq),
-                                   rtol=1e-5, atol=1e-6)
-        assert float(k_bat) == float(k)
+        np.testing.assert_allclose(
+            np.asarray(w_fold), np.asarray(w_seq), rtol=0,
+            atol=FOLD_RTOL * float(np.max(np.abs(w_seq))),
+        )
+        assert float(k_fold) == float(k) == 37.0 + m
 
-    def test_batch_apply_mask_skips_slots(self):
+    def test_fold_ignores_what_lies_past_the_count(self):
+        """Which slots count is DATA: the same full tuple with a smaller
+        count applies only its first slots, through the same executable."""
         from asyncframework_tpu.ops import steps
 
-        rs = np.random.default_rng(1)
-        d = 16
-        w0 = rs.normal(size=d).astype(np.float32)
-        G = rs.normal(size=(4, d)).astype(np.float32)
-        mask = jnp.asarray([1.0, 0.0, 1.0, 0.0])
-
-        apply_many = steps.make_asgd_apply_batch(0.5, 0.1, 1000, 4, 4)
-        w_bat, k_bat = apply_many(
-            jnp.asarray(w0), jnp.asarray(G), mask, jnp.float32(0.0)
-        )
-        apply_one = steps.make_asgd_apply(0.5, 0.1, 1000, 4)
+        nw = self.ARITY
+        w0, gs, _zero = self._problem(1, nw)
+        fold = steps.make_asgd_apply_fold(0.5, 0.1, 1000, nw)
+        apply_one = steps.make_asgd_apply(0.5, 0.1, 1000, nw)
         w_seq, k = jnp.asarray(w0), jnp.float32(0.0)
-        for i in (0, 2):
-            w_seq, k = apply_one(w_seq, jnp.asarray(G[i]), k)
-        np.testing.assert_allclose(np.asarray(w_bat), np.asarray(w_seq),
-                                   rtol=1e-5, atol=1e-6)
-        assert float(k_bat) == 2.0
+        for g in gs[:3]:
+            w_seq, k = apply_one(w_seq, jnp.array(g), k)
+        w_fold, k_fold = fold(
+            jnp.asarray(w0), tuple(gs), jnp.float32(3), jnp.float32(0.0)
+        )
+        np.testing.assert_allclose(
+            np.asarray(w_fold), np.asarray(w_seq), rtol=0,
+            atol=FOLD_RTOL * float(np.max(np.abs(w_seq))),
+        )
+        assert float(k_fold) == 3.0
+
+    def test_fold_never_donates_the_model(self):
+        from asyncframework_tpu.ops import steps
+
+        nw = self.ARITY
+        w0, gs, _zero = self._problem(2, nw)
+        fold = steps.make_asgd_apply_fold(0.5, 0.1, 1000, nw)
+        w = jnp.asarray(w0)
+        fold(w, tuple(gs), jnp.float32(nw), jnp.float32(0.0))
+        # an old handle is a model version: still readable, as are the
+        # gradients (the padding repeats one buffer)
+        np.testing.assert_array_equal(np.asarray(w), w0)
+        assert not any(g.is_deleted() for g in gs)
+
+

@@ -383,13 +383,17 @@ def test_submitter_does_not_outrun_the_updater(devices8, problem,
     cfg = small_cfg(num_workers=nw, num_iterations=120, printer_freq=40,
                     calibration_iters=4)
     engine = solver(X, y, cfg, devices=devices8[:1])
-    real_apply = engine._apply
+    def slow(real):
+        def dispatch(*args):
+            time.sleep(0.004)  # an updater far slower than the steps
+            return real(*args)
 
-    def slow_apply(*args):
-        time.sleep(0.004)  # an updater far slower than the steps
-        return real_apply(*args)
+        return dispatch
 
-    engine._apply = slow_apply
+    # whichever dispatch the updater makes: ASGD folds a backlog into one
+    engine._apply = slow(engine._apply)
+    if solver is ASGD:
+        engine._apply_fold = slow(engine._apply_fold)
     sizes = []
     real_merge = AsyncContext.merge_result
 

@@ -230,22 +230,30 @@ class TestHBMPlanWiring:
 
 
 class TestDrainBatch:
-    def test_batched_drain_run_converges(self, devices8, problem):
+    """The updater folds the backlog it finds into one dispatch: these
+    runs build one (``held_updater``, conftest.py)."""
+
+    def test_batched_drain_run_converges(self, devices8, problem,
+                                         held_updater):
         X, y, _ = problem
-        cfg = cfg_with(num_iterations=300, drain_batch=8)
+        cfg = cfg_with(num_iterations=300)
+        held_updater(cfg.num_workers)
         res = ASGD(X, y, cfg, devices=devices8).run()
         assert res.accepted == 300
         assert res.dropped == 0
+        assert res.accepted / res.extras["apply_dispatches"] > 1
         assert res.trajectory[-1][1] < res.trajectory[0][1] * 0.5
 
     def test_batched_drain_checkpoints_across_boundary(self, devices8, problem,
-                                                       tmp_path):
+                                                       tmp_path, held_updater):
         X, y, _ = problem
-        cfg = cfg_with(num_iterations=250, drain_batch=8,
+        cfg = cfg_with(num_iterations=250,
                        checkpoint_dir=str(tmp_path / "ck"),
                        checkpoint_freq=100)
+        held_updater(cfg.num_workers)
         res = ASGD(X, y, cfg, devices=devices8).run()
         assert res.accepted == 250
+        assert res.extras["drain_items_max"] > 1
         from asyncframework_tpu.checkpoint import CheckpointManager
 
         steps_saved = CheckpointManager(tmp_path / "ck").all_steps()
