@@ -23,7 +23,7 @@ parameter server needs dense anyway.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Mapping, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
@@ -33,6 +33,58 @@ from asyncframework_tpu.data.sharded import balanced_sizes
 
 def _round_up(k: int, mult: int = 8) -> int:
     return max(mult, ((k + mult - 1) // mult) * mult)
+
+
+def _zipf_ranks(key, shape, d: int, s: float):
+    """Ranks ``0 .. d-1`` (uint32) with ``P(rank r) ~ (r + 1)^-s``, by the
+    inverse of the continuous law ``x^-s`` on ``[1/2, d + 1/2)`` rounded to
+    the nearest rank: one uniform draw a slot and no table, so that 917M
+    slots cost no gather.  At ``s`` = 1 that is ``x = (2 d + 1)^u / 2``:
+    ``P(r) = ln((2 r + 3) / (2 r + 1)) / ln(2 d + 1)``, within 9% of
+    Zipf's ``1 / ((r + 1) H_d)`` at the hottest rank and 1.5% from the
+    second on (d = 1e6)."""
+    import jax.numpy as jnp
+
+    u = jax.random.uniform(key, shape, jnp.float32)
+    lo, hi = 0.5, d + 0.5
+    if s == 1.0:
+        x = lo * jnp.exp(u * np.log(hi / lo))
+    else:
+        a, b = lo ** (1.0 - s), hi ** (1.0 - s)
+        x = (a + u * (b - a)) ** (1.0 / (1.0 - s))
+    return jnp.clip(jnp.floor(x + 0.5), 1, d).astype(jnp.uint32) - 1
+
+
+def _zipf_share(ranks, d: int, s: float):
+    """``P(rank)`` of :func:`_zipf_ranks`, f32: the law's mass on
+    ``[rank + 1/2, rank + 3/2)``."""
+    import jax.numpy as jnp
+
+    lo, hi = 0.5, d + 0.5
+    a, b = ranks.astype(jnp.float32) + 0.5, ranks.astype(jnp.float32) + 1.5
+    if s == 1.0:
+        return jnp.log(b / a) / np.log(hi / lo)
+    e = 1.0 - s
+    return (b ** e - a ** e) / (hi ** e - lo ** e)
+
+
+def _column_bijection(d: int, seed: int) -> Tuple[int, int]:
+    """``(mult, shift)`` of the seeded bijection ``r -> (mult * r + shift)
+    mod d`` that scatters the Zipf ranks over the columns (a hashed
+    feature's column says nothing of its frequency): ``mult`` is coprime
+    to ``d`` and small enough that ``mult * r + shift`` fits 32 unsigned
+    bits, so the map is elementwise arithmetic on the device and not a
+    gather of a ``d``-entry permutation (7.3 ns a slot on the v5e)."""
+    import math
+
+    top = (2**32 - d) // d
+    if top < 1:
+        raise ValueError(f"d = {d} is too wide for 32-bit column arithmetic")
+    rs = np.random.default_rng(seed)
+    mult = int(rs.integers(top // 2 + 1, top + 1))
+    while math.gcd(mult, d) != 1:
+        mult -= 1  # 1 is coprime to every d
+    return mult, int(rs.integers(0, d))
 
 
 @dataclass
@@ -64,6 +116,9 @@ class SparseShardedDataset:
         devices: Optional[Sequence] = None,
         seed: int = 42,
         noise: float = 0.01,
+        column_skew: float = 0.0,
+        unit_values: bool = False,
+        bernoulli_labels: Optional[Mapping[str, float]] = None,
     ) -> "SparseShardedDataset":
         """Synthesize a planted rcv1-shaped sparse problem directly in HBM.
 
@@ -73,6 +128,32 @@ class SparseShardedDataset:
         across bench configs.  Labels are ``x . w* + noise`` computed on
         device.  Rows are padded to a lane multiple exactly like the CSR
         path; padding slots carry ``col=0, val=0``.
+
+        Three arguments give the shape of a hashed one-hot click log (LIBSVM
+        ``criteo``) in place of rcv1's; at their defaults the arrays are the
+        ones above, byte for byte:
+
+        - ``column_skew`` ``s`` > 0: a slot's column is Zipf(``s``) over a
+          seeded bijection of the ``d`` columns, drawn in closed form on
+          the device (:func:`_zipf_ranks`; at ``s`` = 1 and ``d`` = 1e6
+          the hottest column takes 7.6% of all slots, so a row may hold a
+          column more than once, which padded ELL adds up like any other).
+          Under skew a planted weight shrinks with its column's share
+          ``p`` of the slots, ``w*_c ~ N(0, 1) / sqrt(max(1, d p))``: no
+          column carries more of the margins' variance than a column of
+          average frequency would (a feature most rows hold cannot move
+          every row's log-odds by a whole unit, and with N(0, 1) on the
+          few hottest columns the whole descent hangs on their draw:
+          four seeds crossed one target after 9 to 47 updates).
+        - ``unit_values``: every live slot holds ``1 / sqrt(nnz_per_row)``,
+          so each row's stored values have unit length.
+        - ``bernoulli_labels`` ``{"scale", "positive_share"}``: labels in
+          {0, 1}, ``y ~ Bernoulli(sigmoid(scale * (x . w* + noise) +
+          bias))``, one ``bias`` for the whole dataset, found on the device
+          so that ``positive_share`` of shard 0's labels are 1 in
+          expectation (the planted margins' mean moves with the hot
+          columns' weights, seed by seed; a fixed bias would move the share
+          with it).
         """
         import functools
 
@@ -86,14 +167,36 @@ class SparseShardedDataset:
         obj.partition_cum = [int(c) for c in cum]
         K = _round_up(int(nnz_per_row))
 
-        @functools.partial(jax.jit, static_argnums=(2,))
-        def gen_shard(key, w_true, size):
+        live = (jnp.arange(K) < nnz_per_row)[None, :]
+        scale = positive = None
+        if bernoulli_labels is not None:
+            scale = float(bernoulli_labels["scale"])
+            positive = float(bernoulli_labels["positive_share"])
+        # the seeded bijection's two numbers reach the device as DATA: baked
+        # into the program they would make every seed its own executable
+        # (12 s of compile a run at a criteo shard, v5e, PR 32)
+        bijection = jnp.asarray(
+            _column_bijection(d, seed) if column_skew else (1, 0), jnp.uint32
+        )
+
+        def to_columns(ranks, bijection):
+            return (ranks * bijection[0] + bijection[1]) % jnp.uint32(d)
+
+        @functools.partial(jax.jit, static_argnums=(3,))
+        def gen_shard(key, w_true, bijection, size):
+            """``(cols, vals, margins)``: the planted ``x . w* + noise``."""
             kc, kv, kn = jax.random.split(key, 3)
-            cols = jax.random.randint(kc, (size, K), 0, d, jnp.int32)
-            vals = jax.random.normal(kv, (size, K), jnp.float32) / jnp.sqrt(
-                float(nnz_per_row)
-            )
-            live = (jnp.arange(K) < nnz_per_row)[None, :]
+            if column_skew:
+                ranks = _zipf_ranks(kc, (size, K), d, column_skew)
+                cols = to_columns(ranks, bijection).astype(jnp.int32)
+            else:
+                cols = jax.random.randint(kc, (size, K), 0, d, jnp.int32)
+            if unit_values:
+                vals = jnp.full((size, K), nnz_per_row ** -0.5, jnp.float32)
+            else:
+                vals = jax.random.normal(
+                    kv, (size, K), jnp.float32
+                ) / jnp.sqrt(float(nnz_per_row))
             cols = jnp.where(live, cols, 0)
             vals = jnp.where(live, vals, 0.0)
             yp = jnp.sum(vals * w_true[cols], axis=1) + noise * (
@@ -101,18 +204,52 @@ class SparseShardedDataset:
             )
             return cols, vals, yp
 
+        @jax.jit
+        def draw_labels(key, margins, bias):
+            p = jax.nn.sigmoid(scale * margins + bias)
+            return jax.random.bernoulli(key, p).astype(jnp.float32)
+
+        @jax.jit
+        def find_bias(margins):
+            """Bisection: ``mean(sigmoid(scale * m + bias)) = positive``."""
+            def halve(_i, lo_hi):
+                lo, hi = lo_hi
+                mid = 0.5 * (lo + hi)
+                low = jnp.mean(
+                    jax.nn.sigmoid(scale * margins + mid)) < positive
+                return jnp.where(low, mid, lo), jnp.where(low, hi, mid)
+
+            lo, hi = jax.lax.fori_loop(
+                0, 40, halve, (jnp.float32(-40.0), jnp.float32(40.0))
+            )
+            return 0.5 * (lo + hi)
+
         obj.row_perm = np.arange(n)
         root = jax.random.fold_in(jax.random.PRNGKey(seed), 0x53505253)  # "SPRS"
         w_true = jax.random.normal(
             jax.random.fold_in(root, 2**30), (d,), jnp.float32
         )
+        if column_skew:
+            ranks = jnp.arange(d, dtype=jnp.uint32)
+            share = _zipf_share(ranks, d, column_skew)
+            w_true = jnp.zeros(d, jnp.float32).at[
+                to_columns(ranks, bijection)
+            ].set(w_true / jnp.sqrt(jnp.maximum(1.0, d * share)))
         obj.shards = {}
+        bias = None
         for w in range(num_workers):
             dev = devs[w % len(devs)]
             key = jax.device_put(jax.random.fold_in(root, w), dev)
             cols, vals, yp = gen_shard(
-                key, jax.device_put(w_true, dev), sizes[w]
+                key, jax.device_put(w_true, dev),
+                jax.device_put(bijection, dev), sizes[w]
             )
+            if bernoulli_labels is not None:
+                if bias is None:
+                    bias = find_bias(yp)
+                yp = draw_labels(
+                    jax.random.fold_in(key, 1), yp, jax.device_put(bias, dev)
+                )
             obj.shards[w] = SparseShard(
                 worker_id=w, cols=cols, vals=vals, y=yp,
                 start=obj.partition_cum[w], size=sizes[w],

@@ -57,6 +57,9 @@ black hole.  This module makes one update's life observable end to end:
                                   delta + history commit dispatched
   snapshot,        work updater   annotation only                      -
   checkpoint
+  trajectory.eval  work main      after the run's fence: the objective -
+                                  of every snapshot (ONE span a run,
+                                  ``batch`` = snapshots; traced runs)
   ================ ==== ========= ==================================== =======
 
   ``task.inbox + task.dispatch + task.device_wait + result.queue`` cover
@@ -126,6 +129,8 @@ TASK_DEVICE_WAIT = "task.device_wait"
 RESULT_QUEUE = "result.queue"
 SNAPSHOT = "snapshot"
 CHECKPOINT = "checkpoint"
+#: after a run's clock has stopped: the objective of every snapshot
+TRAJECTORY_EVAL = "trajectory.eval"
 
 STAGES = (PULL_WAIT, PULL_RTT, PIPELINE, SUBMIT, COMPUTE, TASK_INBOX,
           TASK_DISPATCH, TASK_DEVICE_WAIT, RESULT_QUEUE, PUSH_WAIT, PUSH_RTT,
@@ -134,7 +139,8 @@ STAGES = (PULL_WAIT, PULL_RTT, PIPELINE, SUBMIT, COMPUTE, TASK_INBOX,
 #: these on the profiler's clock.  Everything else is a wait (or spans
 #: threads, like ``compute``) and is never annotated.
 WORK_STAGES = frozenset((SUBMIT, TASK_DISPATCH, TASK_MODEL_COPY, MERGE_QUEUE,
-                         MERGE_APPLY, MERGE_HISTORY, SNAPSHOT, CHECKPOINT))
+                         MERGE_APPLY, MERGE_HISTORY, SNAPSHOT, CHECKPOINT,
+                         TRAJECTORY_EVAL))
 #: the four children that must cover ``compute``
 COMPUTE_CHILDREN = (TASK_INBOX, TASK_DISPATCH, TASK_DEVICE_WAIT,
                     RESULT_QUEUE)
@@ -544,6 +550,11 @@ class TraceRecorder:
         return UpdateTrace(
             TraceContext(_new_id(16), worker_id), self._record
         )
+
+    def start_run(self) -> UpdateTrace:
+        """The handle of a span that belongs to a whole run and to no
+        update (``trajectory.eval``): never sampled away, worker id -1."""
+        return UpdateTrace(TraceContext(_new_id(16), -1), self._record)
 
     def _record(self, span: Span) -> None:
         if self._sink is not None:

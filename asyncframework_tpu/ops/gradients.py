@@ -246,11 +246,13 @@ def make_sparse_grad_sum(d: int):
     """jit (cols, vals, coeff) -> dense (d,) gradient via SORTED scatter-add.
 
     ``g = sum_i coeff_i * x_i`` -- the sparse analog of ``X.T @ coeff``.
-    The updates are sorted by destination column first: TPU XLA executes an
-    unsorted colliding scatter nearly serially, while a bitonic argsort +
-    ``indices_are_sorted=True`` scatter runs vectorized (measured on v5e at
-    rcv1's compacted shape, 349k updates into d=47,236: ~110 ms unsorted ->
-    ~5 ms sorted, ~20x).
+    The updates are sorted by destination column first and scattered with
+    ``indices_are_sorted=True``.  On the v5e that buys nothing (PERF.md
+    section 6, PR 29 and PR 30: at 5.8M updates into d = 1,000,000 the
+    sorted scatter-add costs 8.7 ns a slot where an unsorted colliding one
+    costs 6.7, and carrying ``cols`` and ``vals`` into sorted order plus
+    the argsort is 122 ms of a 248 ms step); the sort stays until a PR
+    removes it against a cell that times this step (ROADMAP Speed 6).
     """
 
     @jax.jit

@@ -599,12 +599,17 @@ def sparse_step_capacity(batch_rate: float, n_rows: int) -> int:
     return min(cap, n_rows)
 
 
-def _sparse_compacted_gradient(cols, vals, y, w, sub, batch_rate, grad_sum):
-    """Shared core of the compacted sparse least-squares step: Bernoulli(b)
-    sample packed to static capacity, only those rows gathered/scattered.
+def _sparse_compacted_gradient(cols, vals, y, w, sub, batch_rate, grad_sum,
+                               loss="least_squares"):
+    """Shared core of the compacted sparse step: Bernoulli(b) sample
+    packed to static capacity, only those rows gathered/scattered; the
+    rows' coefficient is ``m - y`` (least squares) or ``sigmoid(m) - y``
+    (logistic) of the margin ``m = x . w``, f32 throughout.
     ONE definition, used by the engine worker step AND the fused rounds --
     the fused path's sampling-parity claim depends on these staying
     bit-identical."""
+    if loss not in ("least_squares", "logistic"):
+        raise ValueError(f"unknown loss {loss!r}")
     n_rows = y.shape[0]  # static at trace time
     cap = sparse_step_capacity(batch_rate, n_rows)
     with jax.named_scope("sample"):
@@ -616,19 +621,27 @@ def _sparse_compacted_gradient(cols, vals, y, w, sub, batch_rate, grad_sum):
         c_sel = cols[idx]
         v_sel = vals[idx] * valid[:, None]  # unfilled slots contribute 0
     with jax.named_scope("residual"):
-        r = jnp.sum(v_sel * w[c_sel], axis=1) - y[idx] * valid
+        m = jnp.sum(v_sel * w[c_sel], axis=1)
+        if loss == "least_squares":
+            r = m - y[idx] * valid
+        else:  # an unfilled slot's margin is 0: sigmoid(0) is not
+            r = (jax.nn.sigmoid(m) - y[idx]) * valid
     return grad_sum(c_sel, v_sel, r)
 
 
-def make_sparse_asgd_worker_step(batch_rate: float, d: int):
+def make_sparse_asgd_worker_step(batch_rate: float, d: int,
+                                 loss: str = "least_squares"):
     """jit (cols, vals, y, w, key) -> (g_sum (d,), new_key).
 
     The sparse analog of :func:`make_asgd_worker_step` for padded-ELL shards
     (rcv1-class data), with **masked-row compaction**: a Bernoulli(b) sample
     touches only ~b of the shard's rows, so gathering/scattering the FULL
-    (n_p, K) arrays wastes (1-b) of the memory traffic (measured on v5e:
-    ~47 ms gather + ~47 ms scatter at 87k x 80, dominated by padded volume,
-    not useful work).  Instead the sampled row ids are compacted into a
+    (n_p, K) arrays wastes (1-b) of the work: the v5e pays by the SLOT,
+    6.6 to 7.3 ns a gathered ``w[col]`` and 8.7 ns a scatter-added one as
+    this step sorts them (6.7 unsorted; PERF.md section 6, PR 29, PR 30
+    and PR 32: 43 ns a sampled slot in all), so a step over all 2,865,039
+    x 40 slots of a criteo shard would take 5 s where its sampled
+    twentieth takes 0.25.  Instead the sampled row ids are compacted into a
     static-capacity index vector (``jnp.nonzero(size=...)`` -- static
     shapes, jit-stable), and only those rows' cols/vals are gathered and
     scatter-added: ~b of the traffic for the identical gradient.  The
@@ -643,7 +656,7 @@ def make_sparse_asgd_worker_step(batch_rate: float, d: int):
     def step(cols, vals, y, w, key):
         key, sub = jax.random.split(key)
         g = _sparse_compacted_gradient(
-            cols, vals, y, w, sub, batch_rate, grad_sum
+            cols, vals, y, w, sub, batch_rate, grad_sum, loss
         )
         return g, key
 
@@ -748,21 +761,86 @@ def make_sparse_table_delta(d: int):
     return sparse_saga_table_delta
 
 
-def make_sparse_trajectory_loss_eval():
-    """jit (cols, vals, y, W (S,d)) -> (S,) per-snapshot loss sums.
+#: rows a block of the blocked sparse evaluation holds, and snapshots one
+#: gather serves.  Eight snapshots are one sublane tile: the v5e keeps the
+#: ``(8, d)`` table in VMEM, rows minor, and gathers all eight per index
+#: into ``(8, K, rows)``, rows minor like the stored shard, so nothing is
+#: relaid (a ninth snapshot pads the tile to 16 and the compiler then
+#: copies the gathered block to another tiling: 2.0 GB of temporaries at
+#: 18 snapshots, compiled for a described v5e).  On the chip (PERF.md
+#: section 6, PR 32; one 2,865,039 x 40 shard, seconds a call of eight):
+#: 65,536 rows 0.392 (3.4 ns a gathered slot: eight snapshots for half of
+#: what ONE ``w[cols]`` pass costs, 0.826), 131,072 and 262,144 rows 0.577,
+#: 16,384 and 32,768 rows 1.56; sixteen snapshots in one call 1.16.  65,536
+#: rows x 40 slots x 8 snapshots x 4 B = 84 MB a gathered block, which the
+#: compiler keeps in VMEM (11 MB of temporaries in HBM; at 262,144 rows
+#: 336 MB of them): the size is what the v5e's 128 MiB of VMEM holds.
+SPARSE_EVAL_BLOCK_ROWS = 65_536
+SPARSE_EVAL_SNAPSHOTS = 8
 
-    Scans over snapshots so peak memory stays one (n_p, K) gather, not
-    (S, n_p, K).
+
+def make_sparse_trajectory_loss_eval(loss: str = "least_squares"):
+    """jit (cols, vals, y, W (S,d)) -> (S,) per-snapshot loss sums, in ONE
+    pass over the shard in row blocks.
+
+    Per block of ``SPARSE_EVAL_BLOCK_ROWS`` rows, ``W[:, cols_block]`` is
+    gathered once for up to ``SPARSE_EVAL_SNAPSHOTS`` snapshots (more are
+    taken eight at a time inside the block), the margins ``(S, rows)``
+    are the f32 sum over the slots, and the block adds ``sum (m - y)^2``
+    or ``sum log(1 + e^m) - y m`` (the stable ``logaddexp`` form).  The
+    last block is clamped to the shard's end and the rows it shares with
+    the block before are masked, so a ragged shard compiles nothing else.
+    The shard is read where it lies (a width-40 shard is stored rows
+    minor: its transposed block is a ``bitcast``); no row-major copy of
+    ``cols`` and ``vals`` is made (that copy was 1,697 B a shard row,
+    which decided the ``n`` a chip could hold: PERF.md section 6, PR 30).
+
+    ``eval_shard.snapshots_per_call``: what the engine stacks a call's
+    ``W`` to, so that one executable serves every trajectory length;
+    ``eval_shard.blocks(n_rows)``: the row blocks of one call.
     """
+    if loss not in ("least_squares", "logistic"):
+        raise ValueError(f"unknown loss {loss!r}")
+    tile = SPARSE_EVAL_SNAPSHOTS
+
+    def block_rows(n_rows):
+        return min(SPARSE_EVAL_BLOCK_ROWS, n_rows)
+
+    def blocks(n_rows):
+        return -(-n_rows // block_rows(n_rows))
 
     @jax.jit
     def eval_shard(cols, vals, y, W):
-        def one(w):
-            r = jnp.sum(vals * w[cols], axis=1) - y
-            return jnp.sum(r * r)
+        n_rows, n_snap = y.shape[0], W.shape[0]
+        rows = block_rows(n_rows)
 
-        return jax.lax.map(one, W)
+        def one_block(i, acc):
+            start = i * rows
+            at = jnp.minimum(start, n_rows - rows)
+            fresh = (at + jnp.arange(rows) >= start).astype(jnp.float32)
+            # (K, rows): how a narrow shard is stored
+            cb = jax.lax.dynamic_slice_in_dim(cols, at, rows).T
+            vb = jax.lax.dynamic_slice_in_dim(vals, at, rows).T
+            yb = jax.lax.dynamic_slice_in_dim(y, at, rows)
+            sums = []
+            for lo in range(0, n_snap, tile):
+                with jax.named_scope("gather"):
+                    picked = W[lo:lo + tile][:, cb]  # (<= tile, K, rows)
+                m = jnp.sum(picked * vb[None].astype(jnp.float32), axis=1)
+                if loss == "least_squares":
+                    per_row = jnp.square(m - yb)
+                else:
+                    per_row = jnp.logaddexp(0.0, m) - yb * m
+                sums.append(jnp.sum(per_row * fresh, axis=1))
+            return acc + jnp.concatenate(sums)
 
+        return jax.lax.fori_loop(
+            0, blocks(n_rows), one_block, jnp.zeros(n_snap, jnp.float32)
+        )
+
+    eval_shard.snapshots_per_call = tile
+    eval_shard.blocks = blocks
+    eval_shard.block_rows = block_rows
     return eval_shard
 
 

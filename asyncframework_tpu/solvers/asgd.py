@@ -66,25 +66,33 @@ class ASGD(EngineSolver):
         self.driver_device = self.devices[0]
         self._sparse = bool(getattr(self.ds, "is_sparse", False))
         if self._sparse:
-            if config.loss != "least_squares":
-                raise ValueError(
-                    "sparse shards currently support least_squares only"
-                )
             self._step = steps.make_sparse_asgd_worker_step(
-                config.batch_rate, self.ds.d
+                config.batch_rate, self.ds.d, config.loss
             )
-            self._eval = steps.make_sparse_trajectory_loss_eval()
+            self._eval = steps.make_sparse_trajectory_loss_eval(config.loss)
         else:
             self._step = steps.make_asgd_worker_step(
                 config.batch_rate, config.loss
             )
             self._eval = steps.make_trajectory_loss_eval(config.loss)
         self._task_rows = self._step.task_rows  # flop accounting
-        # which program a dense step is here, for every result's extras:
-        # every shard has one width and dtype, so shard 0 speaks for all
-        self._path_extras = {} if self._sparse else {
-            "dense_step_path": dense_step_path(self.ds.shard(0).X)
-        }
+        # for every result's extras.  A sparse step's size: the rows its
+        # compaction holds and the slots it gathers and scatter-adds
+        # (capacity x ELL width), on the largest shard.  Which program a
+        # dense step is here: every shard has one width and dtype, so
+        # shard 0 speaks for all
+        if self._sparse:
+            rows = max(self.ds.partition_sizes().values())
+            capacity = self._task_rows(rows)
+            self._path_extras = {
+                "sparse_step_capacity": capacity,
+                "sampled_slots_per_step":
+                    capacity * int(self.ds.shard(0).cols.shape[1]),
+            }
+        else:
+            self._path_extras = {
+                "dense_step_path": dense_step_path(self.ds.shard(0).X)
+            }
         self._apply = steps.make_asgd_apply(
             config.gamma, config.batch_rate, self.ds.n, config.num_workers
         )

@@ -107,24 +107,62 @@ class EngineSolver(FlopsAccountingMixin):
         return handler
 
     def _evaluate_trajectory(
-        self, snapshots: List[Tuple[float, jax.Array]]
+        self, snapshots: List[Tuple[float, jax.Array]], ut=None,
+        counters: Optional[Dict[str, object]] = None,
     ) -> List[Tuple[float, float]]:
         """One-pass objective evaluation for all snapshots (optVars parity):
-        stack snapshots into (S, d); per shard one matmul gives (S,) losses."""
-        W = jnp.stack([h for (_t, h) in snapshots])
-        totals = np.zeros(len(snapshots), np.float64)
-        for wid in range(self.cfg.num_workers):
-            shard = self._recovery.shard(wid)  # follows re-homed shards
-            Wd = W
-            if Wd.device != shard.device:
-                Wd = jax.device_put(W, shard.device)
-            if self._sparse:
-                part = self._eval(shard.cols, shard.vals, shard.y, Wd)
-            else:
-                part = self._eval(shard.X, shard.y, Wd)
-            totals += np.asarray(part, np.float64)
-        totals /= self.ds.n
+        stack snapshots into (S, d); per shard one matmul gives (S,) losses.
+        A padded-ELL shard is walked in row blocks, one gather a block for
+        ``_eval.snapshots_per_call`` snapshots: the stack is cut to that
+        many a call (the last padded with the first snapshot again), so
+        one executable serves every trajectory length.
+
+        It is the stage ``trajectory.eval`` (``ut``: the run's trace handle
+        in a traced run).  ``counters`` (a run's ``extras``) is told what it
+        cost: ``trajectory_eval_s`` on the host's clock, to the read-back of
+        the last shard's sums; ``eval_blocks``, the gathers made (one a row
+        block a call; a dense shard is one block); ``eval_snapshots``; and
+        for padded ELL ``eval_slots``, the slots those gathers picked (a
+        clamped last block counted whole)."""
+        t0 = time.perf_counter()
+        handles = [h for (_t, h) in snapshots]
+        per_call = getattr(self._eval, "snapshots_per_call", len(handles))
+        blocks = slots = 0
+        with trace.span(trace.TRAJECTORY_EVAL, ut, batch=len(handles)):
+            stacks = []
+            for lo in range(0, len(handles), per_call):
+                group = handles[lo:lo + per_call]
+                group += handles[:1] * (per_call - len(group))
+                stacks.append(jnp.stack(group))
+            totals = np.zeros(len(stacks) * per_call, np.float64)
+            for wid in range(self.cfg.num_workers):
+                shard = self._recovery.shard(wid)  # follows re-homed shards
+                if self._sparse:
+                    arrays = (shard.cols, shard.vals, shard.y)
+                    n_blocks = len(stacks) * self._eval.blocks(shard.size)
+                    slots += (n_blocks * self._eval.block_rows(shard.size)
+                              * shard.cols.shape[1])
+                else:
+                    arrays = (shard.X, shard.y)
+                    n_blocks = len(stacks)
+                blocks += n_blocks
+                parts = [
+                    self._eval(*arrays, W if W.device == shard.device
+                               else jax.device_put(W, shard.device))
+                    for W in stacks
+                ]
+                totals += np.concatenate(
+                    [np.asarray(p, np.float64) for p in parts]
+                )
+        totals = totals[:len(handles)] / self.ds.n
         traj = [(t, float(l)) for (t, _), l in zip(snapshots, totals)]
+        if counters is not None:
+            counters.update(
+                trajectory_eval_s=time.perf_counter() - t0,
+                eval_blocks=blocks, eval_snapshots=len(handles),
+            )
+            if self._sparse:
+                counters["eval_slots"] = slots
         # continuous telemetry: the finished run's loss-vs-wallclock curve
         # lands in the process-global convergence history (the /api/status
         # `convergence` section the in-process live UI serves)
@@ -407,11 +445,15 @@ class EngineRun:
         extras = {
             **inst.engine_counters(sched.task_retries, one_thread=self.sync),
             **inst.extras(), **self.solver._path_extras,
+            # what every result, snapshot and apply moves: the f32 model
+            "model_bytes": 4 * self.solver.ds.d,
         }
         if self.ckpt is not None and self.ckpt.enabled:
             self.save(final_k, final_w_dev,
                       **(checkpoint() if checkpoint is not None else {}))
-        traj = self.solver._evaluate_trajectory(self.snapshots)
+        traj = self.solver._evaluate_trajectory(
+            self.snapshots, inst.run_trace(), extras
+        )
         if self._spec is not None:
             extras["speculated"] = self._spec.speculated_count()
             extras["speculation_wins"] = sched.speculative_wins()

@@ -301,6 +301,11 @@ class RunInstruments:
                 out[wid] = ut
         return out
 
+    def run_trace(self) -> Optional["trace_mod.UpdateTrace"]:
+        """The handle of a span that belongs to the run and to no update
+        (``trajectory.eval``): None when tracing is off."""
+        return None if self.tracer is None else self.tracer.start_run()
+
     @staticmethod
     def begin_compute(uts, model_version: int) -> None:
         """The cohort's sampled updates leave the submitter: ``compute``

@@ -233,3 +233,99 @@ def test_mesh_step_runs_the_kernel_on_each_device_rows(
     assert not [i for i in entry if i[2] in ("copy", "transpose")
                 and held & set(i[3])]
     assert [i for i in entry if i[2].startswith("all-reduce")]
+
+
+# ------------------------------------------------- padded ELL (PR 29 to PR 32)
+# The criteo deployment's shard: 2,865,039 rows x 40 slots, f32 values and
+# int32 columns, stored rows minor (``{0,1}``: 40 is no multiple of the
+# 128-lane tile).  What walks all of it must read it where it lies: a
+# row-major copy of ``cols`` and ``vals`` pads 40 lanes to 128 (1,697 B of
+# temporaries a shard row, which ``peak_bytes_in_use`` does not count: it
+# decided the ``n`` a chip could hold until PR 32).
+
+ELL_ROWS, ELL_WIDTH, ELL_D = 2_865_039, 40, 1_000_000
+
+
+def _ell_specs(one_chip):
+    def spec(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    return (spec((ELL_ROWS, ELL_WIDTH), jnp.int32),
+            spec((ELL_ROWS, ELL_WIDTH), jnp.float32),
+            spec((ELL_ROWS,), jnp.float32)), spec
+
+
+def _makes_a_whole_shard(text):
+    """Instructions (fused bodies included) whose RESULT is as large as the
+    shard, other than the parameters and the loop's plumbing."""
+    shard = f"[{ELL_ROWS},{ELL_WIDTH}]"
+    return [
+        (name, op) for name, t, op, _ in _instructions(text)
+        if shard in t and op not in ("parameter", "get-tuple-element",
+                                     "tuple", "while", "bitcast")
+    ]
+
+
+@pytest.mark.parametrize("loss", ["least_squares", "logistic"])
+def test_blocked_sparse_evaluation_reads_the_shard_where_it_lies(
+    one_chip, no_compile_cache, loss
+):
+    """``make_sparse_trajectory_loss_eval`` at the cell's shard and eight
+    snapshots: per block ONE gather for all eight, nothing as large as the
+    shard is made, and the gathered block (65,536 x 40 x 8 x 4 B = 84 MB)
+    fits the chip's VMEM: 11 MB of temporaries in HBM where the whole-shard
+    gather a snapshot wanted 4.86 GB (at 262,144 rows a block the gathered
+    block is 336 MB of them, and a call takes 0.58 s where this takes 0.39:
+    PERF.md section 6, PR 32)."""
+    (cols, vals, y), spec = _ell_specs(one_chip)
+    ev = steps.make_sparse_trajectory_loss_eval(loss)
+    assert ev.blocks(ELL_ROWS) == 44 and ev.snapshots_per_call == 8
+    compiled = ev.lower(cols, vals, y, spec((8, ELL_D), jnp.float32)).compile()
+    text = compiled.as_text()
+    entry = text[text.index("ENTRY"):]
+    stored = [t for _n, t, op, _ in _instructions(entry)
+              if op == "parameter" and f"[{ELL_ROWS},{ELL_WIDTH}]" in t]
+    assert len(stored) == 2
+    for t in stored:  # rows minor, as the device stores a width-40 shard
+        assert re.search(r"\[\d+,\d+\]\{0,1", t), t
+    assert not _makes_a_whole_shard(text), _makes_a_whole_shard(text)
+    gathers = [i for i in _instructions(text) if i[2] == "gather"]
+    assert len(gathers) == 1, gathers
+    # eight snapshots an index: (8, 40, 65536), rows minor
+    assert "f32[8,40,65536]" in gathers[0][1], gathers[0][1]
+    assert not [i for i in _instructions(text) if i[2] in ("scatter", "sort")]
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 64e6, f"{temp} bytes of temporaries"
+
+
+@pytest.mark.parametrize("model", ["w", "none"])
+def test_reference_padded_ell_sums_build_no_gradient(
+    one_chip, no_compile_cache, model
+):
+    """The benchmark's reference after every run (``benchmark/reference.py:
+    _ell_sums``, a block of 65,536 rows): the sums-only block holds one
+    ``w[cols]`` gather and no scatter and no ``(d,)`` array; with no model
+    (the pins' pass at ``w = 0``) no gather either and no temporaries."""
+    from benchmark import reference
+
+    (cols, vals, y), spec = _ell_specs(one_chip)
+    w = spec((ELL_D,), jnp.float32) if model == "w" else None
+    start = spec((), jnp.int32)
+    compiled = reference._ell_sums.lower(
+        cols, vals, y, w, start, block=reference.BLOCK_ROWS, loss="logistic"
+    ).compile()
+    text = compiled.as_text()
+    instrs = _instructions(text)
+    assert not _makes_a_whole_shard(text), _makes_a_whole_shard(text)
+    assert not [i for i in instrs if i[2] in ("scatter", "sort")]
+    assert f"f32[{ELL_D}]" not in "".join(
+        t for _n, t, op, _ in instrs if op not in ("parameter", "copy-start",
+                                                   "copy-done"))
+    gathers = [i for i in instrs if i[2] == "gather"]
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    if model == "w":
+        assert len(gathers) == 1
+        assert temp < 64e6, f"{temp} bytes of temporaries"
+    else:
+        assert not gathers and "gather" not in text
+        assert temp < 1 << 20, f"{temp} bytes of temporaries"
