@@ -243,26 +243,30 @@ def sparse_residual(
 
 
 def make_sparse_grad_sum(d: int):
-    """jit (cols, vals, coeff) -> dense (d,) gradient via SORTED scatter-add.
+    """jit (cols, vals, coeff) -> dense (d,) gradient via ONE scatter-add.
 
-    ``g = sum_i coeff_i * x_i`` -- the sparse analog of ``X.T @ coeff``.
-    The updates are sorted by destination column first and scattered with
-    ``indices_are_sorted=True``.  On the v5e that buys nothing (PERF.md
-    section 6, PR 29 and PR 30: at 5.8M updates into d = 1,000,000 the
-    sorted scatter-add costs 8.7 ns a slot where an unsorted colliding one
-    costs 6.7, and carrying ``cols`` and ``vals`` into sorted order plus
-    the argsort is 122 ms of a 248 ms step); the sort stays until a PR
-    removes it against a cell that times this step (ROADMAP Speed 6).
+    ``g = sum_i coeff_i * x_i`` -- the sparse analog of ``X.T @ coeff``:
+    every slot's product ``vals * coeff`` is added into ``g`` at its column,
+    in the order the slots are stored.  Padding slots add 0 to column 0 and
+    a column id outside ``[0, d)`` is dropped.
+
+    Nothing puts the slots in order first.  On the v5e a sort is cheap and
+    an element-wise gather or scatter is dear (PERF.md section 6, PR 33;
+    5,818,880 slots into d = 1,000,000): this scatter-add costs 6.9 ns a
+    slot, with Zipf(1) columns (7.4% of the slots on one column) as with
+    uniform ones; sorting the slots by column in front of it (an argsort
+    and two permutation gathers, then ``indices_are_sorted=True``) cost
+    30.3, and carrying the products through ``lax.sort`` as a payload
+    11.5.  The scatter adds a column's terms in the order they are stored,
+    which is the order a stable sort left them in: ``g`` is the sorted
+    form's to the bit.
     """
 
     @jax.jit
     def grad_sum(cols, vals, coeff):
         with jax.named_scope("grad"):
-            contrib = (vals * coeff[:, None]).ravel()
-            flat = cols.ravel()
-            order = jnp.argsort(flat)
-            return jnp.zeros(d, vals.dtype).at[flat[order]].add(
-                contrib[order], indices_are_sorted=True, mode="drop"
+            return jnp.zeros(d, vals.dtype).at[cols.ravel()].add(
+                (vals * coeff[:, None]).ravel(), mode="drop"
             )
 
     return grad_sum

@@ -599,24 +599,53 @@ def sparse_step_capacity(batch_rate: float, n_rows: int) -> int:
     return min(cap, n_rows)
 
 
+def _pack_rows(mask, cap: int):
+    """``(idx, valid)`` of a boolean row mask packed to ``cap`` slots: the
+    set rows' ids ascending, the first ``cap`` kept on overflow, 0 in the
+    unfilled tail; ``valid`` (bool) marks the filled slots.  ``idx`` is to
+    the bit what ``jnp.nonzero(mask, size=cap, fill_value=0)`` returns, by
+    ONE single-operand sort of the keys ``row id if set else n_rows``.
+    The keys of set rows are distinct, so the sort need not be stable (a
+    stable one carries an iota as a second operand on the TPU).  On the
+    v5e, 2,865,039 rows (PERF.md section 6, PR 33): 3.2 ms, 1.1 ns a row;
+    ``jnp.nonzero``'s ``bincount(cumsum(mask))``, a scatter-add of as many
+    ones, 27.0 ms.
+    """
+    n_rows = mask.shape[0]
+    rows = jnp.arange(n_rows, dtype=jnp.int32)
+    keys = jax.lax.sort(jnp.where(mask, rows, n_rows), is_stable=False)[:cap]
+    valid = keys < n_rows
+    return jnp.where(valid, keys, 0), valid
+
+
+def _sampled_rows(sub, batch_rate, n_rows: int, dtype):
+    """The compacted sparse steps' sample: a Bernoulli(b) draw over the
+    shard's rows packed to the static capacity
+    (:func:`sparse_step_capacity`, :func:`_pack_rows`); ``valid`` comes
+    back as 0/1 of ``dtype``.  ONE definition for the ASGD and the ASAGA
+    core, and through them for the fused rounds: the same key samples the
+    same rows everywhere."""
+    cap = sparse_step_capacity(batch_rate, n_rows)
+    with jax.named_scope("sample"):
+        mask = jax.random.bernoulli(sub, batch_rate, (n_rows,))
+    with jax.named_scope("compact"):
+        idx, valid = _pack_rows(mask, cap)
+    return idx, valid.astype(dtype)
+
+
 def _sparse_compacted_gradient(cols, vals, y, w, sub, batch_rate, grad_sum,
                                loss="least_squares"):
     """Shared core of the compacted sparse step: Bernoulli(b) sample
-    packed to static capacity, only those rows gathered/scattered; the
-    rows' coefficient is ``m - y`` (least squares) or ``sigmoid(m) - y``
+    packed to static capacity (:func:`_sampled_rows`), only those rows
+    gathered and scatter-added, in the order they are stored; the rows'
+    coefficient is ``m - y`` (least squares) or ``sigmoid(m) - y``
     (logistic) of the margin ``m = x . w``, f32 throughout.
     ONE definition, used by the engine worker step AND the fused rounds --
     the fused path's sampling-parity claim depends on these staying
     bit-identical."""
     if loss not in ("least_squares", "logistic"):
         raise ValueError(f"unknown loss {loss!r}")
-    n_rows = y.shape[0]  # static at trace time
-    cap = sparse_step_capacity(batch_rate, n_rows)
-    with jax.named_scope("sample"):
-        mask = jax.random.bernoulli(sub, batch_rate, (n_rows,))
-    with jax.named_scope("compact"):
-        (idx,) = jnp.nonzero(mask, size=cap, fill_value=0)
-        valid = (jnp.arange(cap) < jnp.sum(mask)).astype(vals.dtype)
+    idx, valid = _sampled_rows(sub, batch_rate, y.shape[0], vals.dtype)
     with jax.named_scope("gather"):
         c_sel = cols[idx]
         v_sel = vals[idx] * valid[:, None]  # unfilled slots contribute 0
@@ -637,13 +666,13 @@ def make_sparse_asgd_worker_step(batch_rate: float, d: int,
     (rcv1-class data), with **masked-row compaction**: a Bernoulli(b) sample
     touches only ~b of the shard's rows, so gathering/scattering the FULL
     (n_p, K) arrays wastes (1-b) of the work: the v5e pays by the SLOT,
-    6.6 to 7.3 ns a gathered ``w[col]`` and 8.7 ns a scatter-added one as
-    this step sorts them (6.7 unsorted; PERF.md section 6, PR 29, PR 30
-    and PR 32: 43 ns a sampled slot in all), so a step over all 2,865,039
-    x 40 slots of a criteo shard would take 5 s where its sampled
-    twentieth takes 0.25.  Instead the sampled row ids are compacted into a
-    static-capacity index vector (``jnp.nonzero(size=...)`` -- static
-    shapes, jit-stable), and only those rows' cols/vals are gathered and
+    6.9 ns a gathered ``w[col]`` and 6.9 ns a scatter-added one (PERF.md
+    section 6, PR 33: 15.6 ns a sampled slot in all, with the row gathers
+    and the packing), so a step over all 2,865,039 x 40 slots of a criteo
+    shard would take 1.6 s where its sampled twentieth takes 0.091.
+    Instead the sampled row ids are packed into a
+    static-capacity index vector (:func:`_pack_rows` -- static shapes,
+    jit-stable), and only those rows' cols/vals are gathered and
     scatter-added: ~b of the traffic for the identical gradient.  The
     returned gradient is dense because the parameter server applies dense
     updates (the reference's driver-side axpy is dense too).
@@ -668,18 +697,13 @@ def make_sparse_asgd_worker_step(batch_rate: float, d: int,
 def _sparse_saga_compacted(cols, vals, y, w, alpha, sub, batch_rate,
                            grad_sum):
     """Shared core of the compacted sparse ASAGA worker computation
-    (sampling, gather, candidate scalars, history-corrected gradient).
+    (the ASGD core's sample, :func:`_sampled_rows`; gather, candidate
+    scalars, history-corrected gradient).
     ONE definition, used by the engine worker step AND the fused rounds --
     the fused path's sampling-parity claim depends on these staying
     bit-identical (same discipline as :func:`_sparse_compacted_gradient`).
     """
-    n_rows = y.shape[0]  # static at trace time
-    cap = sparse_step_capacity(batch_rate, n_rows)
-    with jax.named_scope("sample"):
-        mask = jax.random.bernoulli(sub, batch_rate, (n_rows,))
-    with jax.named_scope("compact"):
-        (idx,) = jnp.nonzero(mask, size=cap, fill_value=0)
-        valid = (jnp.arange(cap) < jnp.sum(mask)).astype(vals.dtype)
+    idx, valid = _sampled_rows(sub, batch_rate, y.shape[0], vals.dtype)
     with jax.named_scope("gather"):
         c_sel = cols[idx]
         v_sel = vals[idx] * valid[:, None]  # unfilled slots contribute 0
@@ -694,8 +718,9 @@ def _sparse_saga_commit_expr(alpha, diff_sel, idx, valid):
     jitted engine commit and the fused scan): ``alpha[idx_j] <- diff_sel_j``
     for valid slots; padding slots scatter OUT OF BOUNDS and drop --
     routing them anywhere real would race a valid write at the same index.
-    ``idx`` is ascending (``jnp.nonzero`` order) with padding at the tail,
-    so the scatter runs with ``indices_are_sorted``."""
+    ``idx`` is ascending (:func:`_pack_rows` sorts the sampled row ids)
+    with padding at the tail, so the scatter runs with
+    ``indices_are_sorted``."""
     n = alpha.shape[0]
     tgt = jnp.where(valid > 0, idx, n)
     return alpha.at[tgt].set(diff_sel, indices_are_sorted=True, mode="drop")

@@ -5,13 +5,14 @@ trajectory evaluation, and an engine run on them, each held to
 no program code) on seeded data.
 
 Tolerances.  ``STEP_TOL``: the step's gradient off the reference's, over
-the reference's largest entry.  Both are float32 sums of the same products;
-the step sorts its scatter-add and the reference does not, which on the CPU
-(a stable sort, sequential scatters) is the same order of sums: 0.0 seen
-here, 1e-7 a term to be expected where the order differs.  A margin from a
-bf16 model reads 5e-5 to 1e-4 under the logistic link (its slope of at most
-1/4 damps the margin's error) and 5e-4 to 9e-4 under least squares, and a
-missing sigmoid 0.3: 1e-5 fails all of them (tested below).  ``EVAL_TOL``:
+the reference's largest entry.  Both are float32 sums of the same products,
+scatter-added in the order the slots are stored (the step sorted them by
+column first until PR 33: a stable sort, so the same order of sums a
+column): 0.0 seen here, 1e-7 a term to be expected where the order
+differs.  A margin from a bf16 model reads 5e-5 to 1e-4 under the logistic
+link (its slope of at most 1/4 damps the margin's error) and 5e-4 to 9e-4
+under least squares, and a missing sigmoid 0.3: 1e-5 fails all of them
+(tested below).  ``EVAL_TOL``:
 the evaluation's objective off the reference's, relative: float32 sums of
 at most 2,001 rows, 2e-7 seen; a bf16 model reads 2e-5 or more."""
 

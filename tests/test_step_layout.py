@@ -329,3 +329,47 @@ def test_reference_padded_ell_sums_build_no_gradient(
     else:
         assert not gathers and "gather" not in text
         assert temp < 1 << 20, f"{temp} bytes of temporaries"
+
+
+def test_sparse_step_moves_no_slot_to_put_it_in_order(
+    one_chip, no_compile_cache
+):
+    """The sparse ASGD step at the criteo cell's shard (``b`` 0.05, the
+    logistic link; ISSUE 33).  FORM A was kept: the scatter-add takes the
+    5,818,880 sampled slots in the order they are stored, so the program
+    holds no sort over them and no gather whose result is a permutation of
+    the columns or of the products (the parent carried both into sorted
+    order: 124 of its 251 ms on the chip).  The one sort left packs the
+    sampled row ids: ONE operand, the 2,865,039 row keys, where
+    ``jnp.nonzero`` scattered as many ones."""
+    (cols, vals, y), spec = _ell_specs(one_chip)
+    batch_rate = 0.05
+    step = steps.make_sparse_asgd_worker_step(batch_rate, ELL_D, "logistic")
+    cap = steps.sparse_step_capacity(batch_rate, ELL_ROWS)
+    slots = cap * ELL_WIDTH
+    assert (cap, slots) == (145_472, 5_818_880)
+    compiled = step.lower(cols, vals, y, spec((ELL_D,), jnp.float32),
+                          spec((2,), jnp.uint32)).compile()
+    text = compiled.as_text()
+    instrs = _instructions(text)
+    assert not _makes_a_whole_shard(text), _makes_a_whole_shard(text)
+
+    sorts = [t for _n, t, op, _ in instrs if op == "sort"]
+    # a single operand: the result is one array of row keys, not a tuple
+    assert len(sorts) == 1 and sorts[0].startswith(f"s32[{ELL_ROWS}]"), sorts
+
+    # the gathers are the mathematics' own: the sampled rows of cols and
+    # vals, their w[c_sel] and their labels; none makes a flat [slots]
+    # array (``flat[order]``, ``contrib[order]``)
+    gathers = sorted(t.split("{")[0] for _n, t, op, _ in instrs
+                     if op == "gather")
+    assert gathers == sorted([
+        f"s32[{cap},{ELL_WIDTH}]", f"f32[{cap},{ELL_WIDTH}]",
+        f"f32[{cap},{ELL_WIDTH}]", f"f32[{cap}]"]), gathers
+    scatters = [ln for ln in text.splitlines() if " scatter(" in ln]
+    assert len(scatters) == 1 and f" f32[{ELL_D}]" in scatters[0], scatters
+    assert "indices_are_sorted=true" not in scatters[0]
+    # the gathered rows, their products and the keys' scratch: 122.7 MB
+    # (the sorting step's 121.8: its permutations reused those buffers)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 160e6, f"{temp} bytes of temporaries"
