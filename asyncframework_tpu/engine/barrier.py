@@ -51,4 +51,7 @@ def bucket_predicate(ctx: AsyncContext, num_workers: int, bucket_ratio: float):
     def pred(_ws: WorkerState) -> bool:
         return ctx.available_workers() >= threshold
 
+    #: what the submitter's account reads: fewer available than this, and
+    #: the bucket holds them back
+    pred.threshold = threshold
     return pred

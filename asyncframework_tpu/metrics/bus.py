@@ -178,6 +178,12 @@ class ListenerBus:
         )
         self._thread.start()
 
+    @property
+    def heard(self) -> bool:
+        """Whether a posted event can reach anybody: the bus is started or
+        has a listener.  A hot thread asks before it BUILDS an event."""
+        return self._started or bool(self._listeners)
+
     def post(self, event: Event) -> None:
         self.posted_events += 1
         if not self._started:

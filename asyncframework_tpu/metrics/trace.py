@@ -35,11 +35,25 @@ black hole.  This module makes one update's life observable end to end:
   on the device's own clock and an idle gap of the chip can be put down
   to a phase of the program.  *Wait* stages are a thread blocked on a
   queue or on the device: recorded as spans, never annotated (32
-  executors blocked in ``block_until_ready`` would own every gap).
+  executors blocked in ``block_until_ready`` would own every gap).  The
+  two *holds* are the exception: waits of ONE thread, the submitter, with
+  a cause the program knows, so they are annotated and a gap in which the
+  recipe held workers back carries the recipe's name.
 
   ================ ==== ========= ==================================== =======
   stage            kind thread    from -> to                           parent
   ================ ==== ========= ==================================== =======
+  worker.idle      wait ex -> sub a worker's last result -> the        -
+                                  ``submit`` that takes it (it ends
+                                  where that ``submit`` begins; none
+                                  before a worker's first task)
+  hold.barrier,    hold submitter an empty poll's 1 ms sleep: workers  (annotation
+  hold.backlog                    available but fewer than the bucket  only)
+                                  asks for / the updater a fleet of
+                                  results behind.  With no worker
+                                  available (``wait.workers``) nothing
+                                  is annotated; each of the three has
+                                  its sum in ``TrainResult.extras``
   submit           work submitter cohort chosen -> ``run_job`` returned -
   compute          -    -         submit -> drained by the updater     submit
   task.inbox       wait sub -> ex ``compute``'s start -> ``fn()`` in   compute
@@ -98,7 +112,7 @@ import threading
 import time
 import uuid
 from collections import OrderedDict, defaultdict, deque
-from dataclasses import dataclass
+from dataclasses import dataclass, fields
 from typing import Callable, Dict, List, Optional, Sequence
 
 # stage names, in canonical critical-path order
@@ -131,8 +145,17 @@ SNAPSHOT = "snapshot"
 CHECKPOINT = "checkpoint"
 #: after a run's clock has stopped: the objective of every snapshot
 TRAJECTORY_EVAL = "trajectory.eval"
+#: a worker between its result and the submit that takes it again
+WORKER_IDLE = "worker.idle"
+#: why an empty poll of the submitter sent nothing: the recipe's bucket
+#: holds the available workers back / the updater is a fleet of results
+#: behind / no worker is available (everything is in flight)
+HOLD_BARRIER = "hold.barrier"
+HOLD_BACKLOG = "hold.backlog"
+WAIT_WORKERS = "wait.workers"
 
-STAGES = (PULL_WAIT, PULL_RTT, PIPELINE, SUBMIT, COMPUTE, TASK_INBOX,
+STAGES = (PULL_WAIT, PULL_RTT, PIPELINE, WORKER_IDLE, SUBMIT, COMPUTE,
+          TASK_INBOX,
           TASK_DISPATCH, TASK_DEVICE_WAIT, RESULT_QUEUE, PUSH_WAIT, PUSH_RTT,
           MERGE_QUEUE, MERGE_APPLY, MERGE_HISTORY)
 #: engine stages in which a host thread WORKS: :func:`span` annotates
@@ -141,6 +164,9 @@ STAGES = (PULL_WAIT, PULL_RTT, PIPELINE, SUBMIT, COMPUTE, TASK_INBOX,
 WORK_STAGES = frozenset((SUBMIT, TASK_DISPATCH, TASK_MODEL_COPY, MERGE_QUEUE,
                          MERGE_APPLY, MERGE_HISTORY, SNAPSHOT, CHECKPOINT,
                          TRAJECTORY_EVAL))
+#: the submitter's two waits with a cause: annotated like work (one thread,
+#: so they cannot crowd a gap as 32 blocked executors would)
+HOLD_STAGES = frozenset((HOLD_BARRIER, HOLD_BACKLOG))
 #: the four children that must cover ``compute``
 COMPUTE_CHILDREN = (TASK_INBOX, TASK_DISPATCH, TASK_DEVICE_WAIT,
                     RESULT_QUEUE)
@@ -150,7 +176,8 @@ PARENT = {COMPUTE: SUBMIT, MERGE_QUEUE: COMPUTE, MERGE_APPLY: COMPUTE,
           **{st: COMPUTE for st in COMPUTE_CHILDREN}}
 #: what a work stage is called in a profiler trace
 ANNOTATION_PREFIX = "async."
-_ANNOTATION_NAME = {st: ANNOTATION_PREFIX + st for st in WORK_STAGES}
+_ANNOTATION_NAME = {st: ANNOTATION_PREFIX + st
+                    for st in WORK_STAGES | HOLD_STAGES}
 #: stages recorded client-side (worker process) vs server-side (PS)
 CLIENT_STAGES = (PULL_RTT, PIPELINE, COMPUTE, PUSH_WAIT, PUSH_RTT)
 SERVER_STAGES = (PULL_WAIT, MERGE_QUEUE, MERGE_APPLY)
@@ -235,6 +262,13 @@ class Span:
             v = kw.get(name)
             kw[name] = 0.0 if v is None else float(v)
         return cls(**kw)
+
+
+#: the attributes a :class:`Span` has a field for (its optional ones); any
+#: other attribute of a :func:`span` call goes on the annotation alone
+_SPAN_ATTRS = frozenset(
+    f.name for f in fields(Span) if f.default is None
+)
 
 
 class TraceContext:
@@ -416,11 +450,11 @@ def span(stage: str, ut=None, **attrs):
         with span(TASK_DISPATCH, ut):
             g, key = step(X, y, w, key)
 
-    - a *work* stage (``WORK_STAGES``) opens a profiler annotation
-      ``async.<stage>`` whenever a ``jax.profiler`` session is open, so
-      the stage shows on the device trace's clock; a wait stage never
-      does.  With no session open (one static call to find out, 20 ns)
-      there is no annotation;
+    - a *work* stage (``WORK_STAGES``) and the submitter's two holds
+      (``HOLD_STAGES``) open a profiler annotation ``async.<stage>``
+      whenever a ``jax.profiler`` session is open, so the stage shows on
+      the device trace's clock; a wait stage never does.  With no session
+      open (one static call to find out, 20 ns) there is no annotation;
     - with ``ut`` it also records a real :class:`Span` per update: start
       and end read here, ``parent_id`` from ``PARENT``, one ``trace_id``
       per update.  ``ut`` is the update's :class:`UpdateTrace`, or several
@@ -434,7 +468,9 @@ def span(stage: str, ut=None, **attrs):
     profiler session, it hands out one shared no-op and allocates nothing.
 
     Integer ``attrs`` go on the annotation (they show as the event's
-    arguments in XProf/Perfetto) and on every span.  ``with`` and
+    arguments in XProf/Perfetto) and, where a :class:`Span` has the field
+    (``batch``, ``staleness``...), on every span; ``worker=`` and ``chip=``
+    of a ``task.dispatch`` are the annotation's alone.  ``with`` and
     ``begin()`` / ``end()`` are the same pair; a stage that ends on
     another thread than it began on rides the handle
     (:meth:`UpdateTrace.begin`)."""
@@ -478,12 +514,14 @@ class _Span:
         if self._uts:
             end_ms = now_ms()
             parent = PARENT.get(self.stage)
+            attrs = {k: v for k, v in self._attrs.items()
+                     if k in _SPAN_ATTRS}
             for ut, own in self._uts.items():
                 ut.add(
                     self.stage, self.start_ms, end_ms,
                     span_id=ut.ids[self.stage],
                     parent_id=ut.ids.get(parent) if parent else None,
-                    **self._attrs, **(own or {}),
+                    **attrs, **(own or {}),
                 )
         if self._ann is not None:
             self._ann.__exit__(None, None, None)

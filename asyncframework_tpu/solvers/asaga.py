@@ -405,7 +405,6 @@ class ASAGA(EngineSolver):
             avg_delay_ms=0.0,
             updates_per_sec=accepted / elapsed if elapsed > 0 else 0.0,
             total_flops=flops,
-            waiting_time_ms={},
             extras={
                 "fused": True,
                 "rounds_per_call": min(16, total_rounds),
@@ -454,9 +453,11 @@ class ASAGA(EngineSolver):
             for k in range(cfg.num_iterations):
                 cohort = list(range(nw))
                 uts = inst.start_updates(cohort)
-                with trace.span(trace.SUBMIT, uts.values(), batch=nw):
+                with trace.span(trace.SUBMIT, uts.values(), batch=nw) as sub:
                     ts = ctx.get_current_time()
                     ctx.mark_busy(cohort)
+                    if inst.occupancy is not None:
+                        inst.on_busy(cohort, uts, sub.start_ms)
                     waiting.on_submit(cohort, now_ms())
                     if uts:
                         inst.begin_compute(uts, k)
@@ -728,4 +729,5 @@ class ASAGA(EngineSolver):
                 out = step(shard.X, shard.y, w_local, a_local, key_local)
             return (*out[:-1], slice_commits, out[-1])
 
-        return worker_task(dispatch, delay_model.delay_ms(wid), ut)
+        return worker_task(dispatch, delay_model.delay_ms(wid), ut,
+                           worker=wid, chip=dev.id)

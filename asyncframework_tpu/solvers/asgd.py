@@ -351,7 +351,6 @@ class ASGD(EngineSolver):
             avg_delay_ms=0.0,
             updates_per_sec=accepted / elapsed if elapsed > 0 else 0.0,
             total_flops=flops,
-            waiting_time_ms={},
             extras={"fused": True,
                     "rounds_per_call": min(16, total_rounds),
                     **self._path_extras},
@@ -384,9 +383,11 @@ class ASGD(EngineSolver):
             for k in range(cfg.num_iterations):
                 cohort = list(range(nw))
                 uts = inst.start_updates(cohort)
-                with trace.span(trace.SUBMIT, uts.values(), batch=nw):
+                with trace.span(trace.SUBMIT, uts.values(), batch=nw) as sub:
                     ts = ctx.get_current_time()
                     ctx.mark_busy(cohort)
+                    if inst.occupancy is not None:
+                        inst.on_busy(cohort, uts, sub.start_ms)
                     waiting.on_submit(cohort, now_ms())
                     if uts:
                         inst.begin_compute(uts, k)
@@ -508,7 +509,8 @@ class ASGD(EngineSolver):
                 return step(shard.cols, shard.vals, shard.y, w_local, key_local)
             return step(shard.X, shard.y, w_local, key_local)
 
-        return worker_task(dispatch, delay_model.delay_ms(wid), ut)
+        return worker_task(dispatch, delay_model.delay_ms(wid), ut,
+                           worker=wid, chip=dev.id)
 
     def _task_maker(self, run: EngineRun):
         """``make_tasks`` of this run (``EngineRun.drive``): a task captures

@@ -416,6 +416,8 @@ class TrainResult:
     # counted worker-gradient flops (utils/flops.py model; excludes the
     # post-hoc trajectory evaluation) -- the MFU numerator
     total_flops: float = 0.0
+    #: per worker, the milliseconds between a result of its and the submit
+    #: that took it again (``Occupancy``: a traced engine run; else empty)
     waiting_time_ms: Dict[int, float] = field(default_factory=dict)
     extras: Dict[str, object] = field(default_factory=dict)
     #: accepted updates behind every ``trajectory`` entry (0 for ``w = 0``,
@@ -432,36 +434,29 @@ class TrainResult:
 
 
 class WaitingTimeTable:
-    """Per-worker idle-gap bookkeeping.
+    """When each worker's task was submitted, and from that the task time
+    the delay calibrator reads.
 
-    Parity: ``WaitingTime`` / ``SubmitJobTime`` / ``FinishTimeTable``
-    (``SparkASGDThread.scala:112-115,328-335``): at submit, a worker's waiting
-    time grows by (submit wall time - its last finish wall time).
+    Parity: ``SubmitJobTime`` (``SparkASGDThread.scala:112-115,328-335``).
+    The reference's ``WaitingTime`` (a worker's idle gaps) is kept where
+    the gap begins, at the worker's result:
+    ``solvers/instrumentation.py: Occupancy``, which fills
+    ``TrainResult.waiting_time_ms`` in a traced run.
     """
 
     def __init__(self):
         self._lock = threading.Lock()
         self.submit_ms: Dict[int, float] = {}
-        self.finish_ms: Dict[int, float] = {}
-        self.waiting_ms: Dict[int, float] = {}
 
     def on_submit(self, worker_ids, now_ms: float) -> None:
         with self._lock:
             for wid in worker_ids:
-                gap = now_ms - self.finish_ms.get(wid, now_ms)
-                self.waiting_ms[wid] = self.waiting_ms.get(wid, 0.0) + gap
                 self.submit_ms[wid] = now_ms
 
     def on_finish(self, worker_id: int, now_ms: float) -> float:
-        """Record finish; returns (finish - submit) for delay calibration."""
+        """(finish - submit), for delay calibration."""
         with self._lock:
-            dt = now_ms - self.submit_ms.get(worker_id, now_ms)
-            self.finish_ms[worker_id] = now_ms
-            return dt
-
-    def snapshot(self) -> Dict[int, float]:
-        with self._lock:
-            return dict(self.waiting_ms)
+            return now_ms - self.submit_ms.get(worker_id, now_ms)
 
 
 class DelayCalibrator:

@@ -83,6 +83,7 @@ class EngineSolver(FlopsAccountingMixin):
         ctx, now_ms = run.ctx, run.now_ms
         worker_keys, key_lock = run.worker_keys, run.key_lock
         payload_of = self._result_payload
+        occupancy = run.inst.occupancy  # None unless the run is traced
         submit_wall = now_ms()
         par_recs = int(self.cfg.batch_rate * self.ds.n / self.cfg.num_workers)
 
@@ -93,6 +94,8 @@ class EngineSolver(FlopsAccountingMixin):
             with key_lock:
                 worker_keys[wid] = result[-1]
             ut = uts.get(wid) if uts else None
+            if occupancy is not None:
+                occupancy.leave(wid)
             if ut is not None:
                 ut.begin(trace.RESULT_QUEUE)
             ctx.merge_result(
@@ -181,6 +184,7 @@ class EngineRun:
         cfg = solver.cfg
         nw = cfg.num_workers
         self.solver, self.cfg, self.sync = solver, cfg, sync
+        self._built = time.monotonic()
         self.ctx: AsyncContext = AsyncContext()
         self.sched = JobScheduler(num_workers=nw, devices=solver.devices)
         # non-blocking submit in both modes: a sync run drains on the driver
@@ -195,7 +199,9 @@ class EngineRun:
             else cfg.effective_calibration_iters()
         )
         self.waiting = WaitingTimeTable()
-        self.inst = RunInstruments(cfg, nw)
+        self.inst = RunInstruments(
+            cfg, nw, chip_of=lambda wid: solver._shard_device(wid).id
+        )
         self.inst.register_queue_depth(self.ctx.size)
         self.ckpt: Optional[SolverCheckpointer] = None
         #: every worker's PRNG chain, on its shard's device; ``key_lock``
@@ -209,6 +215,10 @@ class EngineRun:
         self.state_lock = threading.Lock()
         self.stop = threading.Event()
         self._ft = self._spec = self._alloc = None
+        #: what :meth:`drive` read of its own end (a sync run has none),
+        #: and when its submitter loop left
+        self._tail: Optional[Dict[str, object]] = None
+        self._loop_exit = 0.0
 
     # ------------------------------------------------------------ the state
     def cold_start(self) -> None:
@@ -307,6 +317,7 @@ class EngineRun:
     def start_clock(self) -> None:
         """The trajectory's clock starts (after the solver's warm-up)."""
         self.start_wall = time.monotonic()
+        self._clock0 = self.ctx.get_current_time()  # results so far: none
         self.inst.on_run_start()
         self.snapshots: List[Tuple[float, jax.Array]] = [
             (0.0, self.state["w"])
@@ -342,6 +353,8 @@ class EngineRun:
         upd = threading.Thread(target=updater, name=thread_name, daemon=True)
         upd.start()
         waiters: deque = deque(maxlen=4 * nw)  # recent jobs, failure check
+        bucket = bucket_predicate(ctx, nw, ratio)
+        submitted = 0
         deadline = time.monotonic() + cfg.run_timeout_s
         run_ok = False
         try:
@@ -361,21 +374,34 @@ class EngineRun:
                 # workers at 0.6 ms a step, PERF.md section 6, PR 26) would
                 # otherwise fill the queue without bound, with gradients
                 # seconds old whose recorded staleness still reads under nw
-                cohort = [] if ctx.size() >= nw else partial_barrier(
-                    ctx, nw, bucket_predicate(ctx, nw, ratio)
-                )
+                behind = ctx.size() >= nw
+                cohort = [] if behind else partial_barrier(ctx, nw, bucket)
                 if not cohort:
+                    # the account of what the device was NOT given, where
+                    # that is decided: the sleep goes to what this turn
+                    # saw.  The updater a fleet behind; workers available
+                    # and the recipe's bucket holding them (fewer than its
+                    # threshold: a worker freed since the barrier looked
+                    # does not count); or everything in flight.  The two
+                    # holds show in a profiler session (metrics/trace.py)
+                    if behind:
+                        hold = trace.HOLD_BACKLOG
+                    elif 0 < ctx.available_workers() < bucket.threshold:
+                        hold = trace.HOLD_BARRIER
+                    else:
+                        hold = trace.WAIT_WORKERS
                     inst.submit_empty_polls += 1
-                    clock.waits()
-                    time.sleep(0.001)
-                    clock.works()
+                    with trace.span(hold):
+                        clock.waits()
+                        time.sleep(0.001)
+                        inst.submit_wait_ns[hold] += clock.works()
                     continue
                 # the sampling decision falls here, at submit: a sampled
                 # update's handle rides its task closure, the handler and
                 # the PartialResult to the updater
                 uts = inst.start_updates(cohort)
                 with trace.span(trace.SUBMIT, uts.values(),
-                                batch=len(cohort)):
+                                batch=len(cohort)) as sub:
                     with state_lock:
                         w_pub = state["w"]  # immutable handle = model version
                         model_version = state["k"]
@@ -396,6 +422,9 @@ class EngineRun:
                     ts = ctx.get_current_time()
                     ctx.set_last_time(ts)
                     ctx.mark_busy(cohort)
+                    submitted += len(cohort)
+                    if inst.occupancy is not None:
+                        inst.on_busy(cohort, uts, sub.start_ms)
                     waiting.on_submit(cohort, now_ms())
                     if uts:
                         inst.begin_compute(uts, model_version)
@@ -414,9 +443,23 @@ class EngineRun:
             run_ok = True
         finally:
             clock.waits()  # the loop's last busy stretch
+            # the run's last seconds: nothing is accepted from here on,
+            # and ``elapsed_s`` runs on to the fence (``result``)
+            t_exit = time.monotonic()
+            with state_lock:
+                merged = state["accepted"] + state["dropped"]
             stop.set()
             upd.join(timeout=10)
+            t_joined = time.monotonic()
             self.shutdown(run_ok)
+            self._loop_exit = t_exit
+            self._tail = {
+                "run_tail_join_s": t_joined - t_exit,
+                "run_tail_shutdown_s": time.monotonic() - t_joined,
+                # submitted and not merged when the loop left: their
+                # steps finish (or not) inside the tail, uncounted
+                "inflight_at_stop": submitted - merged,
+            }
 
     # ------------------------------------------------------------ the result
     def result(self, checkpoint: Optional[Callable[[], Dict]] = None,
@@ -430,13 +473,18 @@ class EngineRun:
         with self.state_lock:
             final_k, final_w_dev = state["k"], state["w"]
             accepted, rounds = state["accepted"], state["rounds"]
+            dropped = state["dropped"]
         # materialize BEFORE taking elapsed: the readback of the final
         # model is also the fence (it waits for every apply before it), so
         # elapsed/updates_per_sec cover the work actually done, not merely
         # dispatched.  Whether block_until_ready alone suffices here is
         # ROADMAP Design 8; the result needs final_w on the host anyway.
+        t_fence = time.monotonic()
         final_w = np.asarray(final_w_dev)
-        elapsed = time.monotonic() - self.start_wall
+        occupied = (inst.occupancy.close()
+                    if inst.occupancy is not None else {})
+        t_end = time.monotonic()
+        elapsed = t_end - self.start_wall
         self.snapshots.append((elapsed * 1e3, final_w_dev))
         inst.on_snapshot(accepted)
         # a sync run's one driver thread is clocked as the updater
@@ -447,10 +495,29 @@ class EngineRun:
             **inst.extras(), **self.solver._path_extras,
             # what every result, snapshot and apply moves: the f32 model
             "model_bytes": 4 * self.solver.ds.d,
+            **occupied,
+            # the call of run() to the clock's start: the run built, the
+            # checkpoint restored, the solver's warm-up of its hot path
+            "run_lead_s": self.start_wall - self._built,
         }
+        if self._tail is not None:
+            # drive()'s own end: from the submitter loop's exit to the
+            # fence's end nothing is accepted, and all of it lies inside
+            # elapsed_s.  results_unmerged: of the tasks in flight at the
+            # exit, those whose result came and was never applied
+            extras.update(
+                self._tail, run_tail_s=t_end - self._loop_exit,
+                run_tail_fence_s=t_end - t_fence,
+                results_unmerged=(
+                    self.ctx.get_current_time() - self._clock0
+                    - accepted - dropped
+                ),
+            )
+        t = time.monotonic()
         if self.ckpt is not None and self.ckpt.enabled:
             self.save(final_k, final_w_dev,
                       **(checkpoint() if checkpoint is not None else {}))
+        extras["checkpoint_s"] = time.monotonic() - t
         traj = self.solver._evaluate_trajectory(
             self.snapshots, inst.run_trace(), extras
         )
@@ -461,7 +528,9 @@ class EngineRun:
             extras["executors_added"], extras["executors_removed"] = (
                 self._alloc.counts()
             )
+        t = time.monotonic()
         inst.close(traj, cfg.printer_freq)
+        extras["close_s"] = time.monotonic() - t
         if more_extras is not None:
             extras = {**more_extras(), **extras}
         # one update a round in a sync run, one an accepted gradient else
@@ -471,13 +540,17 @@ class EngineRun:
             trajectory=traj,
             elapsed_s=elapsed,
             accepted=accepted,
-            dropped=state["dropped"],
+            dropped=dropped,
             rounds=rounds,
             max_staleness=self.ctx.max_staleness(),
             avg_delay_ms=self.calibrator.avg_delay_ms,
             updates_per_sec=updates / elapsed if elapsed > 0 else 0.0,
             total_flops=state["flops"],
-            waiting_time_ms=self.waiting.snapshot(),
+            waiting_time_ms=(
+                {} if inst.occupancy is None else
+                {wid: s * 1e3
+                 for wid, s in inst.occupancy.worker_idle_s.items()}
+            ),
             extras=extras,
             snapshot_updates=inst.snapshot_updates,
             staleness_hist=dict(sorted(inst.staleness_hist.items())),

@@ -106,16 +106,123 @@ class BusyClock:
         self.busy_ns += now - self._mark
         self._mark = now
 
-    def works(self) -> None:
+    def works(self) -> int:
+        """Back from the wait: the nanoseconds it took, for a caller that
+        keeps a sum of its own beside ``wait_ns``."""
         now = time.perf_counter_ns()
-        self.wait_ns += now - self._mark
+        waited = now - self._mark
+        self.wait_ns += waited
         self._mark = now
+        return waited
 
     def waited(self, ns: int) -> None:
         """``ns`` of what was counted as busy was spent blocked in a callee
         that keeps its own account (``JobScheduler.blocked_ns``)."""
         self.busy_ns -= ns
         self.wait_ns += ns
+
+
+class Occupancy:
+    """What each chip was given: every task from its submit (ENTER: the
+    submitter, after ``mark_busy``) to its result (LEAVE: the completing
+    executor's handler, before ``merge_result`` makes the worker available
+    again), on one clock under one small lock.  A traced run's account, for
+    EVERY update and not the sampled ones; an untraced run has none.
+
+    From the two events: ``inflight_task_s``, the integral of tasks in
+    flight; ``chip_empty_s``, per chip the seconds in which none of its
+    workers was between submit and result, so that nothing the engine had
+    handed out could run there (a chip never entered is empty for the
+    whole run); ``worker_idle_s``, per worker the seconds between a LEAVE
+    and the next ENTER, which is ``TrainResult.waiting_time_ms``.  A LEAVE
+    with no ENTER before it (a speculative copy's second result) and an
+    ENTER of a worker still in flight change nothing."""
+
+    def __init__(self, chip_of: Dict[int, int],
+                 clock: Callable[[], float] = time.monotonic):
+        self._chip_of = dict(chip_of)
+        self._clock = clock
+        self._lock = threading.Lock()
+        self.start()
+
+    def start(self) -> None:
+        """The run's clock starts: every chip is empty from here."""
+        now = self._clock()
+        chips = set(self._chip_of.values())
+        self._mark = now            # to where inflight_task_s is summed
+        self._closed = False
+        self._inflight = 0
+        self._on_chip = dict.fromkeys(chips, 0)
+        self._empty_since = dict.fromkeys(chips, now)
+        self._entered: Dict[int, int] = {}    # worker in flight -> chip
+        self._left_at: Dict[int, float] = {}  # worker -> its last result
+        self.inflight_task_s = 0.0
+        self.chip_empty_s = dict.fromkeys(chips, 0.0)
+        self.worker_idle_s: Dict[int, float] = {}
+
+    def _advance(self, now: float) -> None:
+        self.inflight_task_s += self._inflight * (now - self._mark)
+        self._mark = now
+
+    def enter(self, cohort) -> Dict[int, float]:
+        """The cohort's workers are submitted.  Returns, by worker, the
+        seconds since its last result (nothing for a worker's first
+        task)."""
+        idle: Dict[int, float] = {}
+        with self._lock:
+            if self._closed:
+                return idle
+            now = self._clock()
+            self._advance(now)
+            for wid in cohort:
+                if wid in self._entered:
+                    continue
+                chip = self._entered[wid] = self._chip_of[wid]
+                self._inflight += 1
+                if self._on_chip[chip] == 0:
+                    self.chip_empty_s[chip] += now - self._empty_since[chip]
+                self._on_chip[chip] += 1
+                left = self._left_at.pop(wid, None)
+                if left is None:
+                    self.worker_idle_s.setdefault(wid, 0.0)
+                else:
+                    idle[wid] = now - left
+                    self.worker_idle_s[wid] += now - left
+        return idle
+
+    def leave(self, wid: int) -> None:
+        """The worker's result has come."""
+        with self._lock:
+            chip = None if self._closed else self._entered.pop(wid, None)
+            if chip is None:
+                return
+            now = self._clock()
+            self._advance(now)
+            self._inflight -= 1
+            self._on_chip[chip] -= 1
+            if self._on_chip[chip] == 0:
+                self._empty_since[chip] = now
+            self._left_at[wid] = now
+
+    def close(self) -> Dict[str, object]:
+        """The run's clock stops: the sums up to here, as a run's
+        ``extras`` carry them (the dict for an operator, the two scalars
+        for a record that keeps scalars).  Later events change nothing."""
+        with self._lock:
+            if not self._closed:
+                self._closed = True
+                now = self._clock()
+                self._advance(now)
+                for chip, n in self._on_chip.items():
+                    if n == 0:
+                        self.chip_empty_s[chip] += now - self._empty_since[chip]
+            empty = list(self.chip_empty_s.values())
+            return {
+                "inflight_task_s": self.inflight_task_s,
+                "chip_empty_s": dict(self.chip_empty_s),
+                "chip_empty_max_s": max(empty),
+                "chip_empty_mean_s": sum(empty) / len(empty),
+            }
 
 
 def on_device(arr, device):
@@ -128,7 +235,8 @@ def on_device(arr, device):
 
 
 def worker_task(dispatch: Callable[[], tuple], delay_ms: float = 0.0,
-                ut: Optional["trace_mod.UpdateTrace"] = None):
+                ut: Optional["trace_mod.UpdateTrace"] = None,
+                worker: int = -1, chip: int = -1):
     """The closure every worker task is (ASGD and ASAGA, ``run`` and
     ``run_sync``): ``dispatch()`` moves what the step needs to the worker's
     chip and dispatches the step, returning its outputs, gradient first
@@ -138,6 +246,9 @@ def worker_task(dispatch: Callable[[], tuple], delay_ms: float = 0.0,
     here, on entry; only the first copy of the task to run finds it open
     and records the task stages (the engine itself knows nothing of
     tracing: a retry or a speculative copy runs this same closure).
+    ``worker`` and ``chip`` (the device's id) go on ``task.dispatch``'s
+    annotation: a reader of a device trace can tell a dispatch TO the chip
+    whose gap it is naming from one to another chip.
 
     The injected delay models a slow *machine*: only the first body to run
     it sleeps -- a speculative copy or a replacement executor is a
@@ -150,7 +261,8 @@ def worker_task(dispatch: Callable[[], tuple], delay_ms: float = 0.0,
         if delay_ms > 0 and not delay_fired.is_set():
             delay_fired.set()
             time.sleep(delay_ms / 1e3)
-        with trace_mod.span(trace_mod.TASK_DISPATCH, mine):
+        with trace_mod.span(trace_mod.TASK_DISPATCH, mine,
+                            worker=worker, chip=chip):
             out = dispatch()
         with trace_mod.span(trace_mod.TASK_DEVICE_WAIT, mine):
             out[0].block_until_ready()
@@ -167,7 +279,10 @@ class RunInstruments:
     append) and post typed events to the asynchronous bus (never blocks).
     """
 
-    def __init__(self, cfg, num_workers: int):
+    def __init__(self, cfg, num_workers: int,
+                 chip_of: Optional[Callable[[int], int]] = None):
+        """``chip_of(worker_id)``: the chip a worker's shard lives on, for
+        a traced run's :class:`Occupancy`."""
         self.cfg = cfg
         self._t0 = time.monotonic()
         self.bus = ListenerBus()
@@ -189,6 +304,12 @@ class RunInstruments:
         #: from a run that does not count)
         self.apply_dispatches = 0
         self.submit_empty_polls = 0   # submitter turns that found no cohort
+        #: the sleeps of those turns, by what the turn saw (the two holds
+        #: and ``wait.workers`` of metrics/trace.py): they sum to the
+        #: submitter's polling wait
+        self.submit_wait_ns = {trace_mod.HOLD_BACKLOG: 0,
+                               trace_mod.HOLD_BARRIER: 0,
+                               trace_mod.WAIT_WORKERS: 0}
         self.drains = 0               # updater wakes that merged something
         self.drain_items_max = 0
         #: staleness -> count over EVERY merged result (not a sample)
@@ -245,6 +366,13 @@ class RunInstruments:
                 # span fan-out runs on the dispatch thread
                 self.bus.add_listener(_GlobalTraceFold())
                 self.bus.start()
+        #: what each chip was given, in a traced run; None in any other,
+        #: which then pays one ``is None`` test a submit and a result
+        self.occupancy: Optional[Occupancy] = None
+        if self.tracer is not None and chip_of is not None:
+            self.occupancy = Occupancy(
+                {wid: chip_of(wid) for wid in range(num_workers)}
+            )
 
         metrics_csv = getattr(cfg, "metrics_csv", None)
         metrics_jsonl = getattr(cfg, "metrics_jsonl", None)
@@ -277,9 +405,11 @@ class RunInstruments:
     def on_round_submitted(
         self, round_idx: int, cohort, model_version: int
     ) -> None:
-        self.bus.post(
-            RoundSubmitted(self.now_ms(), round_idx, tuple(cohort), model_version)
-        )
+        if self.bus.heard:  # an event nobody hears is not built
+            self.bus.post(
+                RoundSubmitted(self.now_ms(), round_idx, tuple(cohort),
+                               model_version)
+            )
         if self.metrics is not None:
             self._c_rounds.inc()
 
@@ -316,11 +446,25 @@ class RunInstruments:
             ut.begin(trace_mod.COMPUTE)
             ut.begin(trace_mod.TASK_INBOX)
 
+    def on_busy(self, cohort, uts, submit_start_ms: float) -> None:
+        """The cohort is marked busy (a traced run: ``occupancy`` is
+        there): its workers enter their chips, and a sampled update whose
+        worker has had a result before gets its ``worker.idle``, the
+        account's own interval laid to end where the ``submit`` began."""
+        idle = self.occupancy.enter(cohort)
+        for wid, ut in uts.items():
+            if wid in idle:
+                ut.add(trace_mod.WORKER_IDLE,
+                       submit_start_ms - idle[wid] * 1e3, submit_start_ms)
+
     def on_run_start(self) -> None:
         """The run's clock starts (after the solver's warm-up): so do the
-        two threads' clocks and the count of compilations."""
+        two threads' clocks, the occupancy account and the count of
+        compilations."""
         self.updater_clock.start()
         self.submitter_clock.start()
+        if self.occupancy is not None:
+            self.occupancy.start()
         self._compiles0 = compiles_so_far()
 
     def on_drained(self, results) -> tuple:
@@ -365,12 +509,13 @@ class RunInstruments:
         self.staleness_hist[staleness] = (
             self.staleness_hist.get(staleness, 0) + 1
         )
-        self.bus.post(
-            GradientMerged(
-                self.now_ms(), res.worker_id, staleness, accepted, iteration,
-                res.batch_size,
+        if self.bus.heard:  # an event nobody hears is not built
+            self.bus.post(
+                GradientMerged(
+                    self.now_ms(), res.worker_id, staleness, accepted,
+                    iteration, res.batch_size,
+                )
             )
-        )
         if self.metrics is not None:
             (self._c_accepted if accepted else self._c_dropped).inc()
             self._h_staleness.update(float(staleness))
@@ -465,6 +610,12 @@ class RunInstruments:
                 "submitter_busy_s": self.submitter_clock.busy_ns * 1e-9,
                 "submitter_wait_s": self.submitter_clock.wait_ns * 1e-9,
                 "submit_empty_polls": self.submit_empty_polls,
+                "submit_hold_backlog_s":
+                    self.submit_wait_ns[trace_mod.HOLD_BACKLOG] * 1e-9,
+                "submit_hold_barrier_s":
+                    self.submit_wait_ns[trace_mod.HOLD_BARRIER] * 1e-9,
+                "submit_wait_workers_s":
+                    self.submit_wait_ns[trace_mod.WAIT_WORKERS] * 1e-9,
             })
         out.update({
             "drains": self.drains,

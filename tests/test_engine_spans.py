@@ -182,9 +182,13 @@ def test_work_stages_land_in_the_host_plane_and_wait_stages_do_not(
     for stage in (trace.TASK_INBOX, trace.TASK_DEVICE_WAIT,
                   trace.RESULT_QUEUE, trace.COMPUTE):
         assert trace.ANNOTATION_PREFIX + stage not in names, stage
+    # nothing but the work stages and the submitter's two holds (ISSUE 34:
+    # waits of one thread with a cause; tests/test_engine_account.py)
     assert names <= {
-        trace.ANNOTATION_PREFIX + st for st in trace.WORK_STAGES
+        trace.ANNOTATION_PREFIX + st
+        for st in trace.WORK_STAGES | trace.HOLD_STAGES
     }
+    assert trace.ANNOTATION_PREFIX + trace.WAIT_WORKERS not in names
 
 
 def test_span_records_nothing_and_reads_no_clock_without_a_handle(
