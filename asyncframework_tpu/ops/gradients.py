@@ -234,12 +234,131 @@ def saga_shard_step(
 # rcv1-class data in padded-ELL form (data/sparse.py): cols/vals are
 # (n, K) with zero padding; w stays dense (the PS applies dense updates).
 
+def row_blocks(n_rows: int, block_rows: int) -> Tuple[int, int]:
+    """``(rows, blocks)`` of a pass over ``n_rows`` rows in blocks of at
+    most ``block_rows``: ONE block shape, so a ragged count compiles
+    nothing else; the last block is clamped (:func:`clamped_block`)."""
+    rows = min(block_rows, n_rows)
+    return rows, -(-n_rows // rows)
+
+
+def clamped_block(i, rows: int, n_rows: int):
+    """``(start, at)`` of block ``i``: the block is read at ``at``, pulled
+    back so that it ends with the rows, and rows ``at .. start`` are the
+    block before's.  A pass that SUMS over rows masks them
+    (``steps.make_sparse_trajectory_loss_eval``); one that writes a value
+    a row writes the same value twice (:func:`sparse_margins`)."""
+    start = i * rows
+    return start, jnp.minimum(start, n_rows - rows)
+
+
+#: slots (rows x ELL width) of the sample one block of the eight-wide gather
+#: holds, and under which a sample keeps the element-wise one.  On the v5e
+#: the compiler keeps the ``(8, d / 8)`` table with its eight values padded
+#: to a 128-lane row (64 MB at d = 1,000,000: half its VMEM) and writes a
+#: block's gathered ``(slots, 8)`` rows padded alike, 512 B a slot.  The
+#: block is sized so that those rows can NOT fit VMEM too (168 MB): where
+#: they can, the compiler may keep THEM there and put the table out to HBM,
+#: and a gather from HBM costs 9.3 ns a slot where the element-wise one
+#: costs 6.9 (PERF.md section 6, PR 36; 145,472 x 40 slots, ms a pass, the
+#: element-wise gather 40.2: blocks of 1,024 to 2,560 rows run 12.1 to 12.4
+#: ALONE, table and rows both resident, and 54 inside the step, which has
+#: other tenants; 3,072 to 4,096 rows 54 either way; 8,192 to 36,480 rows
+#: 15.8 to 15.9 either way, the rows going to HBM and back, 168 to 747 MB
+#: of temporaries; the whole sample at once 13.5 with 3.2 GB of them).  A
+#: sample under one block is in that doubtful range and keeps the
+#: element-wise gather.  The clamped last block is work done twice, so a
+#: block is small beside the cell's sample (18 blocks, 1.4% over).
+SPARSE_GATHER_BLOCK_SLOTS = 327_680
+
+
+def sparse_gather_path(w, c_sel) -> str:
+    """``"rows8"`` or ``"elements"``: which program :func:`sparse_margins`
+    traces for the model ``w`` and the sampled columns ``c_sel`` (arrays,
+    tracers or ``ShapeDtypeStruct``), from what can be observed when the
+    step is built -- the backend, the model's rank, dtype and length, and
+    the sample's slot count.
+
+    The v5e's gather pays by the INDEX, not by the byte: 6.9 ns for one
+    f32 of a ``(d,)`` vector, with model, indices and output all in VMEM,
+    and 2.7 ns for a sublane tile of eight of an ``(8, d / 8)`` table
+    (PERF.md section 6, PR 36).  ``"rows8"`` is that table, where ``w`` can
+    be viewed as one (``d % 8 == 0``: criteo's 1,000,000 is, rcv1's 47,236
+    is not) and the sample fills at least one block
+    (``SPARSE_GATHER_BLOCK_SLOTS``, and why).  The CPU, and everything not
+    measured, keep the element-wise gather.
+    """
+    if (_on_tpu() and len(w.shape) == 1 and w.dtype == jnp.float32
+            and w.shape[0] % 8 == 0 and len(c_sel.shape) == 2
+            and c_sel.shape[0] * c_sel.shape[1] >= SPARSE_GATHER_BLOCK_SLOTS):
+        return "rows8"
+    return "elements"
+
+
+def _gather_rows8(table: jax.Array, c: jax.Array) -> jax.Array:
+    """``w[c]`` from ``table = w.reshape(8, d // 8)``, to the bit and for
+    every int32 ``c`` (a negative index counts from the end and one out
+    of range is clamped, as ``w[c]`` has it): ONE gather of the table's
+    column ``c % (d // 8)``, eight values an index, and the row ``c //
+    (d // 8)`` of it chosen by three selects on the row's bits."""
+    q = table.shape[1]
+    d = 8 * q
+    c = jnp.clip(jnp.where(c < 0, c + d, c), 0, d - 1)
+    row = c // q
+    with jax.named_scope("gather"):
+        x = table[:, c - row * q]  # (8,) + c.shape
+    x = jnp.where((row & 4) > 0, x[4:], x[:4])
+    x = jnp.where((row & 2) > 0, x[2:], x[:2])
+    return jnp.where((row & 1) > 0, x[1], x[0])
+
+
+def _margins_elements(c_sel, v_sel, w):
+    """One model value an index: the expression every step held."""
+    return jnp.sum(v_sel * w[c_sel], axis=1)
+
+
+def _margins_rows8(c_sel, v_sel, w):
+    """The margins in row blocks of the sample, ``(K, rows)`` with rows
+    minor as a narrow ``c_sel`` is stored (its transposed block is a
+    ``bitcast``): per block one eight-wide gather, the select, the
+    multiply and the sum over the slots."""
+    n_rows, width = c_sel.shape
+    table = w.reshape(8, w.shape[0] // 8)
+    rows, blocks = row_blocks(n_rows, SPARSE_GATHER_BLOCK_SLOTS // width)
+
+    def one_block(i, m):
+        _, at = clamped_block(i, rows, n_rows)
+        cb = jax.lax.dynamic_slice_in_dim(c_sel, at, rows).T
+        vb = jax.lax.dynamic_slice_in_dim(v_sel, at, rows).T
+        mb = jnp.sum(vb * _gather_rows8(table, cb), axis=0)
+        return jax.lax.dynamic_update_slice_in_dim(m, mb, at, 0)
+
+    return jax.lax.fori_loop(
+        0, blocks, one_block,
+        jnp.zeros(n_rows, jnp.result_type(v_sel.dtype, w.dtype)),
+    )
+
+
+def sparse_margins(c_sel: jax.Array, v_sel: jax.Array, w: jax.Array):
+    """``m_i = sum_k v_sel[i, k] * w[c_sel[i, k]]``: the ``(rows,)`` margins
+    ``x_i . w`` of padded-ELL rows (a padding slot's value is 0).
+
+    THE definition behind every sparse step's residual and the ONE place
+    its gather's program is chosen (:func:`sparse_gather_path`).  Both
+    programs gather the same values, to the bit; only the order of a
+    margin's ``K``-term sum may differ.
+    """
+    if sparse_gather_path(w, c_sel) == "rows8":
+        return _margins_rows8(c_sel, v_sel, w)
+    return _margins_elements(c_sel, v_sel, w)
+
+
 @jax.jit
 def sparse_residual(
     cols: jax.Array, vals: jax.Array, y: jax.Array, w: jax.Array
 ) -> jax.Array:
     """Per-sample ``x_i . w - y_i`` via gather: padding contributes 0."""
-    return jnp.sum(vals * w[cols], axis=1) - y
+    return sparse_margins(cols, vals, w) - y
 
 
 def make_sparse_grad_sum(d: int):
@@ -260,6 +379,13 @@ def make_sparse_grad_sum(d: int):
     11.5.  The scatter adds a column's terms in the order they are stored,
     which is the order a stable sort left them in: ``g`` is the sorted
     form's to the bit.
+
+    The eight-wide form that halved the gather (:func:`sparse_margins`)
+    does NOT pay here (PERF.md section 6, PR 36; the same slots, 40.2 ms
+    this way): updates as rows of eight added into an ``(8, d / 8)``
+    accumulator took 81.3 ms in blocks of 8,192 rows and 126.3 in blocks of
+    65,536, into an ``(8, d)`` one 246.5 and 328.5.  The scatter stays
+    element-wise.
     """
 
     @jax.jit

@@ -49,11 +49,15 @@ import numpy as np
 
 from asyncframework_tpu.metrics import profiler as _prof
 from asyncframework_tpu.ops.gradients import (
+    clamped_block,
     dense_masked_grad,
     least_squares_grad_sum,
     logistic_grad_sum,
     mm_f32,
+    row_blocks,
     saga_commit_history,  # re-exported: the solvers' committed-history op
+    sparse_gather_path,
+    sparse_margins,
 )
 
 
@@ -599,6 +603,26 @@ def sparse_step_capacity(batch_rate: float, n_rows: int) -> int:
     return min(cap, n_rows)
 
 
+def _sized_by_capacity(step, batch_rate: float, d: int):
+    """A compacted sparse step's size, for who asks the step it runs:
+    ``step.task_rows(n_rows)``, the rows its compaction holds
+    (:func:`_counts_rows`), and ``step.gather_path(n_rows, width)``, what
+    ``gradients.sparse_gather_path`` says of the sample it packs from an
+    ``(n_rows, width)`` shard against its ``(d,)`` float32 model: the
+    solvers' ``extras["sparse_gather_path"]``."""
+    def task_rows(n_rows):
+        return sparse_step_capacity(batch_rate, n_rows)
+
+    def gather_path(n_rows, width):
+        return sparse_gather_path(
+            jax.ShapeDtypeStruct((d,), jnp.float32),
+            jax.ShapeDtypeStruct((task_rows(n_rows), width), jnp.int32),
+        )
+
+    step.gather_path = gather_path
+    return _counts_rows(step, task_rows)
+
+
 def _pack_rows(mask, cap: int):
     """``(idx, valid)`` of a boolean row mask packed to ``cap`` slots: the
     set rows' ids ascending, the first ``cap`` kept on overflow, 0 in the
@@ -650,7 +674,7 @@ def _sparse_compacted_gradient(cols, vals, y, w, sub, batch_rate, grad_sum,
         c_sel = cols[idx]
         v_sel = vals[idx] * valid[:, None]  # unfilled slots contribute 0
     with jax.named_scope("residual"):
-        m = jnp.sum(v_sel * w[c_sel], axis=1)
+        m = sparse_margins(c_sel, v_sel, w)
         if loss == "least_squares":
             r = m - y[idx] * valid
         else:  # an unfilled slot's margin is 0: sigmoid(0) is not
@@ -666,10 +690,12 @@ def make_sparse_asgd_worker_step(batch_rate: float, d: int,
     (rcv1-class data), with **masked-row compaction**: a Bernoulli(b) sample
     touches only ~b of the shard's rows, so gathering/scattering the FULL
     (n_p, K) arrays wastes (1-b) of the work: the v5e pays by the SLOT,
-    6.9 ns a gathered ``w[col]`` and 6.9 ns a scatter-added one (PERF.md
-    section 6, PR 33: 15.6 ns a sampled slot in all, with the row gathers
-    and the packing), so a step over all 2,865,039 x 40 slots of a criteo
-    shard would take 1.6 s where its sampled twentieth takes 0.091.
+    2.7 ns a gathered model value (eight an index,
+    ``gradients.sparse_margins``; 6.9 one an index until PR 36) and 6.9 ns
+    a scatter-added one (PERF.md section 6, PR 36: 11.6 ns a sampled
+    slot in all, with the row gathers and the packing), so a step over all
+    2,865,039 x 40 slots of a criteo shard would take 1.3 s where its
+    sampled twentieth takes 0.068.
     Instead the sampled row ids are packed into a
     static-capacity index vector (:func:`_pack_rows` -- static shapes,
     jit-stable), and only those rows' cols/vals are gathered and
@@ -689,9 +715,7 @@ def make_sparse_asgd_worker_step(batch_rate: float, d: int,
         )
         return g, key
 
-    return _counts_rows(
-        step, lambda n_rows: sparse_step_capacity(batch_rate, n_rows)
-    )
+    return _sized_by_capacity(step, batch_rate, d)
 
 
 def _sparse_saga_compacted(cols, vals, y, w, alpha, sub, batch_rate,
@@ -708,7 +732,7 @@ def _sparse_saga_compacted(cols, vals, y, w, alpha, sub, batch_rate,
         c_sel = cols[idx]
         v_sel = vals[idx] * valid[:, None]  # unfilled slots contribute 0
     with jax.named_scope("residual"):
-        diff_sel = jnp.sum(v_sel * w[c_sel], axis=1) - y[idx] * valid
+        diff_sel = sparse_margins(c_sel, v_sel, w) - y[idx] * valid
     g = grad_sum(c_sel, v_sel, diff_sel - alpha[idx])
     return g, diff_sel, idx, valid, c_sel, v_sel
 
@@ -750,9 +774,7 @@ def make_sparse_saga_worker_step(batch_rate: float, d: int):
         )
         return g, diff_sel, idx, valid, c_sel, v_sel, key
 
-    return _counts_rows(
-        step, lambda n_rows: sparse_step_capacity(batch_rate, n_rows)
-    )
+    return _sized_by_capacity(step, batch_rate, d)
 
 
 def make_sparse_saga_commit():
@@ -795,11 +817,16 @@ def make_sparse_table_delta(d: int):
 #: 18 snapshots, compiled for a described v5e).  On the chip (PERF.md
 #: section 6, PR 32; one 2,865,039 x 40 shard, seconds a call of eight):
 #: 65,536 rows 0.392 (3.4 ns a gathered slot: eight snapshots for half of
-#: what ONE ``w[cols]`` pass costs, 0.826), 131,072 and 262,144 rows 0.577,
-#: 16,384 and 32,768 rows 1.56; sixteen snapshots in one call 1.16.  65,536
-#: rows x 40 slots x 8 snapshots x 4 B = 84 MB a gathered block, which the
-#: compiler keeps in VMEM (11 MB of temporaries in HBM; at 262,144 rows
-#: 336 MB of them): the size is what the v5e's 128 MiB of VMEM holds.
+#: what ONE element-wise ``w[cols]`` pass costs, 0.826), 131,072 and
+#: 262,144 rows 0.577, 16,384 and 32,768 rows 1.56; sixteen snapshots in
+#: one call 1.16.  65,536 rows x 40 slots x 8 snapshots x 4 B = 84 MB a
+#: gathered block, which the compiler keeps in VMEM (11 MB of temporaries
+#: in HBM; at 262,144 rows 336 MB of them): the size is what the v5e's 128
+#: MiB of VMEM holds.  The step's own eight-wide gather
+#: (``gradients.sparse_margins``, PR 36) is another program, with a block
+#: of its own: its table is the ONE model as ``(8, d / 8)``, which the
+#: compiler stores eight-minor, where this ``(8, d)`` table is too large for
+#: that and stays rows major.
 SPARSE_EVAL_BLOCK_ROWS = 65_536
 SPARSE_EVAL_SNAPSHOTS = 8
 
@@ -829,10 +856,10 @@ def make_sparse_trajectory_loss_eval(loss: str = "least_squares"):
     tile = SPARSE_EVAL_SNAPSHOTS
 
     def block_rows(n_rows):
-        return min(SPARSE_EVAL_BLOCK_ROWS, n_rows)
+        return row_blocks(n_rows, SPARSE_EVAL_BLOCK_ROWS)[0]
 
     def blocks(n_rows):
-        return -(-n_rows // block_rows(n_rows))
+        return row_blocks(n_rows, SPARSE_EVAL_BLOCK_ROWS)[1]
 
     @jax.jit
     def eval_shard(cols, vals, y, W):
@@ -840,8 +867,7 @@ def make_sparse_trajectory_loss_eval(loss: str = "least_squares"):
         rows = block_rows(n_rows)
 
         def one_block(i, acc):
-            start = i * rows
-            at = jnp.minimum(start, n_rows - rows)
+            start, at = clamped_block(i, rows, n_rows)
             fresh = (at + jnp.arange(rows) >= start).astype(jnp.float32)
             # (K, rows): how a narrow shard is stored
             cb = jax.lax.dynamic_slice_in_dim(cols, at, rows).T
@@ -1089,7 +1115,7 @@ def make_saga_dcn_sparse_worker_step(d: int):
             c_sel = cols[idx]
             v_sel = vals[idx] * valid[:, None]
         with jax.named_scope("residual"):
-            diff = (jnp.sum(v_sel * w[c_sel], axis=1) - y[idx]) * valid
+            diff = (sparse_margins(c_sel, v_sel, w) - y[idx]) * valid
         # invalid rows have v_sel == 0, so their (diff - alpha) is inert
         g = grad_sum(c_sel, v_sel, diff - alpha_sel)
         return g, diff

@@ -100,11 +100,21 @@ class ASAGA(EngineSolver):
             self._table_delta = steps.make_saga_table_delta()
             self._eval = steps.make_trajectory_loss_eval("least_squares")
         self._task_rows = self._step.task_rows  # flop accounting
-        # which program a dense step is here, for every result's extras:
-        # every shard has one width and dtype, so shard 0 speaks for all
-        self._path_extras = {} if self._sparse else {
-            "dense_step_path": dense_step_path(self.ds.shard(0).X)
-        }
+        # which program the step is here, for every result's extras: the
+        # gather of a sparse step's model, on the largest shard; a dense
+        # step's products (every shard has one width and dtype, so shard 0
+        # speaks for all)
+        if self._sparse:
+            self._path_extras = {
+                "sparse_gather_path": self._step.gather_path(
+                    max(self.ds.partition_sizes().values()),
+                    int(self.ds.shard(0).cols.shape[1]),
+                )
+            }
+        else:
+            self._path_extras = {
+                "dense_step_path": dense_step_path(self.ds.shard(0).X)
+            }
         self._apply = steps.make_saga_apply(
             config.gamma, config.batch_rate, self.ds.n, config.num_workers
         )

@@ -78,16 +78,17 @@ class ASGD(EngineSolver):
         self._task_rows = self._step.task_rows  # flop accounting
         # for every result's extras.  A sparse step's size: the rows its
         # compaction holds and the slots it gathers and scatter-adds
-        # (capacity x ELL width), on the largest shard.  Which program a
-        # dense step is here: every shard has one width and dtype, so
-        # shard 0 speaks for all
+        # (capacity x ELL width), on the largest shard, and which program
+        # gathers the model there.  Which program a dense step is here:
+        # every shard has one width and dtype, so shard 0 speaks for all
         if self._sparse:
             rows = max(self.ds.partition_sizes().values())
+            width = int(self.ds.shard(0).cols.shape[1])
             capacity = self._task_rows(rows)
             self._path_extras = {
                 "sparse_step_capacity": capacity,
-                "sampled_slots_per_step":
-                    capacity * int(self.ds.shard(0).cols.shape[1]),
+                "sampled_slots_per_step": capacity * width,
+                "sparse_gather_path": self._step.gather_path(rows, width),
             }
         else:
             self._path_extras = {

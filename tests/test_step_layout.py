@@ -37,6 +37,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -244,6 +245,9 @@ def test_mesh_step_runs_the_kernel_on_each_device_rows(
 # decided the ``n`` a chip could hold until PR 32).
 
 ELL_ROWS, ELL_WIDTH, ELL_D = 2_865_039, 40, 1_000_000
+#: what one block of ``gradients.sparse_margins`` may keep in HBM: its
+#: gathered ``(slots, 8)`` rows padded to 128 lanes, 512 B a slot (168 MB)
+TEMP_BOUND = 512 * gradients.SPARSE_GATHER_BLOCK_SLOTS + 32e6
 
 
 def _ell_specs(one_chip):
@@ -331,8 +335,74 @@ def test_reference_padded_ell_sums_build_no_gradient(
         assert temp < 1 << 20, f"{temp} bytes of temporaries"
 
 
+def _model_gathers(text):
+    """``(result type, slice sizes)`` of every gather whose operand is as
+    large as the model: ``f32[1000000]`` or a two-dimensional view of it."""
+    out = []
+    for ln in text.splitlines():
+        m = re.search(r"= (\S+) gather\(", ln)
+        if not m:
+            continue
+        operand = re.search(r"gather\(%([\w.\-]+)", ln).group(1)
+        held = re.search(
+            r"%" + re.escape(operand) + r" = f32\[([\d,]+)\]", text)
+        if held and np.prod([int(x) for x in held.group(1).split(",")]) == ELL_D:
+            out.append((m.group(1).split("{")[0],
+                        re.search(r"slice_sizes=\{([\d,]+)\}", ln).group(1)))
+    return out
+
+
+def _table_stays_in_vmem(text):
+    """The ``(8, d / 8)`` table the loop gathers from is made once, in
+    memory space 1.  Put out to HBM (which the compiler does where a
+    block's gathered rows fit VMEM in its place) the gather costs 9.3 ns a
+    slot, more than the element-wise one it replaced (PERF.md section 6,
+    PR 36)."""
+    tables = [t for _n, t, op, _ in _instructions(text[text.index("ENTRY"):])
+              if op == "copy" and t.startswith(f"f32[8,{ELL_D // 8}]")]
+    assert len(tables) == 1 and "S(1)" in tables[0], tables
+
+
+def test_sparse_margins_gather_eight_model_values_an_index(
+    one_chip, no_compile_cache, on_tpu
+):
+    """``gradients.sparse_margins`` alone at the criteo step's sample,
+    145,472 packed rows x 40 slots against ``d`` = 1,000,000 (ISSUE 36):
+    the chooser says ``rows8``, and the program compiled for the described
+    v5e gathers the model ONLY through an eight-wide slice, a block of
+    ``SPARSE_GATHER_BLOCK_SLOTS`` slots (8,192 rows) at a time, rows minor
+    as the sample is stored.  The table stays in VMEM (``S(1)``): a block's
+    gathered rows, padded to 128 lanes (512 B a slot), are too large to
+    take its place and go to HBM, where the block bounds them; the whole
+    sample at once wanted 3.2 GB."""
+    cap = steps.sparse_step_capacity(0.05, ELL_ROWS)
+    _, spec = _ell_specs(one_chip)
+    c_sel, v_sel = (spec((cap, ELL_WIDTH), jnp.int32),
+                    spec((cap, ELL_WIDTH), jnp.float32))
+    w = spec((ELL_D,), jnp.float32)
+    assert gradients.sparse_gather_path(w, c_sel) == "rows8"
+    compiled = jax.jit(gradients.sparse_margins).lower(
+        c_sel, v_sel, w).compile()
+    text = compiled.as_text()
+    entry = text[text.index("ENTRY"):]
+    stored = [t for _n, t, op, _ in _instructions(entry)
+              if op == "parameter" and f"[{cap},{ELL_WIDTH}]" in t]
+    assert len(stored) == 2
+    for t in stored:  # rows minor: a block's transpose is a bitcast
+        assert re.search(r"\[\d+,\d+\]\{0,1", t), t
+    rows = gradients.SPARSE_GATHER_BLOCK_SLOTS // ELL_WIDTH
+    assert rows == 8_192
+    assert _model_gathers(text) == [
+        (f"f32[8,{ELL_WIDTH},{rows}]", "8,1")], _model_gathers(text)
+    _table_stays_in_vmem(text)
+    assert [i for i in _instructions(text) if i[2] == "while"]
+    assert not [i for i in _instructions(text) if i[2] in ("scatter", "sort")]
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < TEMP_BOUND, f"{temp} bytes of temporaries"
+
+
 def test_sparse_step_moves_no_slot_to_put_it_in_order(
-    one_chip, no_compile_cache
+    one_chip, no_compile_cache, on_tpu
 ):
     """The sparse ASGD step at the criteo cell's shard (``b`` 0.05, the
     logistic link; ISSUE 33).  FORM A was kept: the scatter-add takes the
@@ -341,13 +411,16 @@ def test_sparse_step_moves_no_slot_to_put_it_in_order(
     the columns or of the products (the parent carried both into sorted
     order: 124 of its 251 ms on the chip).  The one sort left packs the
     sampled row ids: ONE operand, the 2,865,039 row keys, where
-    ``jnp.nonzero`` scattered as many ones."""
+    ``jnp.nonzero`` scattered as many ones.  Since ISSUE 36 the model is
+    gathered eight values an index (``gradients.sparse_margins``): no
+    single-element gather of ``w`` is left in the step."""
     (cols, vals, y), spec = _ell_specs(one_chip)
     batch_rate = 0.05
     step = steps.make_sparse_asgd_worker_step(batch_rate, ELL_D, "logistic")
     cap = steps.sparse_step_capacity(batch_rate, ELL_ROWS)
     slots = cap * ELL_WIDTH
     assert (cap, slots) == (145_472, 5_818_880)
+    assert step.gather_path(ELL_ROWS, ELL_WIDTH) == "rows8"
     compiled = step.lower(cols, vals, y, spec((ELL_D,), jnp.float32),
                           spec((2,), jnp.uint32)).compile()
     text = compiled.as_text()
@@ -359,17 +432,22 @@ def test_sparse_step_moves_no_slot_to_put_it_in_order(
     assert len(sorts) == 1 and sorts[0].startswith(f"s32[{ELL_ROWS}]"), sorts
 
     # the gathers are the mathematics' own: the sampled rows of cols and
-    # vals, their w[c_sel] and their labels; none makes a flat [slots]
-    # array (``flat[order]``, ``contrib[order]``)
+    # vals, their labels, and a block of the model's eight-row table; none
+    # makes a flat [slots] array (``flat[order]``, ``contrib[order]``) and
+    # none takes the model one element an index
+    rows = gradients.SPARSE_GATHER_BLOCK_SLOTS // ELL_WIDTH
     gathers = sorted(t.split("{")[0] for _n, t, op, _ in instrs
                      if op == "gather")
     assert gathers == sorted([
         f"s32[{cap},{ELL_WIDTH}]", f"f32[{cap},{ELL_WIDTH}]",
-        f"f32[{cap},{ELL_WIDTH}]", f"f32[{cap}]"]), gathers
+        f"f32[8,{ELL_WIDTH},{rows}]", f"f32[{cap}]"]), gathers
+    assert _model_gathers(text) == [
+        (f"f32[8,{ELL_WIDTH},{rows}]", "8,1")], _model_gathers(text)
+    _table_stays_in_vmem(text)
     scatters = [ln for ln in text.splitlines() if " scatter(" in ln]
     assert len(scatters) == 1 and f" f32[{ELL_D}]" in scatters[0], scatters
     assert "indices_are_sorted=true" not in scatters[0]
-    # the gathered rows, their products and the keys' scratch: 122.7 MB
-    # (the sorting step's 121.8: its permutations reused those buffers)
+    # the gathered rows, their products and the keys' scratch (122.7 MB
+    # until PR 36) and one block of the model's gathered rows
     temp = compiled.memory_analysis().temp_size_in_bytes
-    assert temp < 160e6, f"{temp} bytes of temporaries"
+    assert temp < 160e6 + TEMP_BOUND, f"{temp} bytes of temporaries"
