@@ -145,6 +145,10 @@ class DeviceExecutor:
             if self._killed:
                 return  # killed mid-task: never report (the monitor handles it)
             self._status_update(self, task, result, exc)
+            # a thread that waits for its next task holds nothing of its
+            # last one: the closure pins the model version it was handed
+            # and the result its gradient, 219 MB each at d = 54.7M
+            task = result = exc = None
 
 
 class ExecutorPool:

@@ -114,6 +114,7 @@ class TraceSpan(Event):
     accepted: Optional[bool] = None
     bytes: Optional[int] = None  # wire bytes of the RPC the span covers
     batch: Optional[int] = None  # updates the timed piece of work served
+    calls: Optional[int] = None  # trajectory.eval: stacks evaluated
 
 
 EVENT_TYPES: Dict[str, Type[Event]] = {

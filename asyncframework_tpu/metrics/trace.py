@@ -73,7 +73,8 @@ black hole.  This module makes one update's life observable end to end:
   checkpoint
   trajectory.eval  work main      after the run's fence: the objective -
                                   of every snapshot (ONE span a run,
-                                  ``batch`` = snapshots; traced runs)
+                                  ``batch`` = snapshots, ``calls`` =
+                                  stacks evaluated; traced runs)
   ================ ==== ========= ==================================== =======
 
   ``task.inbox + task.dispatch + task.device_wait + result.queue`` cover
@@ -230,13 +231,16 @@ class Span:
     #: cohort of a ``submit``, the accepted results of the drain behind a
     #: ``merge.apply`` (its duration is the whole drain's, undivided)
     batch: Optional[int] = None
+    #: evaluation calls a ``trajectory.eval`` made: the stacks of
+    #: ``snapshots_per_call`` snapshots it built, one at a time
+    calls: Optional[int] = None
 
     # wire format: short keys, Nones omitted -- spans ride PUSH headers
     _WIRE = (("s", "stage"), ("t", "trace_id"), ("i", "span_id"),
              ("p", "parent_id"), ("w", "worker_id"), ("v", "model_version"),
              ("b", "start_ms"), ("d", "dur_ms"), ("st", "staleness"),
              ("sm", "staleness_ms"), ("ac", "accepted"), ("by", "bytes"),
-             ("n", "batch"))
+             ("n", "batch"), ("c", "calls"))
 
     def to_wire(self) -> dict:
         out = {}
@@ -747,6 +751,7 @@ def span_event(span: Span, time_ms: float) -> "object":
         start_ms=span.start_ms, dur_ms=span.dur_ms,
         staleness=span.staleness, staleness_ms=span.staleness_ms,
         accepted=span.accepted, bytes=span.bytes, batch=span.batch,
+        calls=span.calls,
     )
 
 

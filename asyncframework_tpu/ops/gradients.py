@@ -272,12 +272,25 @@ def clamped_block(i, rows: int, n_rows: int):
 SPARSE_GATHER_BLOCK_SLOTS = 327_680
 
 
+#: bytes of model over which no form of it can live in the v5e's 128 MiB
+#: of VMEM: its gather then reads HBM by the index, whatever the program
+SPARSE_VMEM_BYTES = 128 * 2**20
+#: slots of the sample one block of the lane-row gather holds, and under
+#: which a sample keeps the element-wise one.  A block's gathered rows are
+#: 512 B a slot: 8 MB, which stay in VMEM beside the step's other tenants
+#: (the table is in HBM whatever the block).  On the v5e (PERF.md section
+#: 6, PR 37; 236,640 x 16 slots from d = 54,686,452, ms a pass alone, the
+#: element-wise gather 51.6): blocks of 1,024 rows 37.7, 8,192 rows 41.4,
+#: 65,536 rows 49.1.
+SPARSE_LANES_BLOCK_SLOTS = 16_384
+
+
 def sparse_gather_path(w, c_sel) -> str:
-    """``"rows8"`` or ``"elements"``: which program :func:`sparse_margins`
-    traces for the model ``w`` and the sampled columns ``c_sel`` (arrays,
-    tracers or ``ShapeDtypeStruct``), from what can be observed when the
-    step is built -- the backend, the model's rank, dtype and length, and
-    the sample's slot count.
+    """``"rows8"``, ``"lanes128"`` or ``"elements"``: which program
+    :func:`sparse_margins` traces for the model ``w`` and the sampled
+    columns ``c_sel`` (arrays, tracers or ``ShapeDtypeStruct``), from what
+    can be observed when the step is built -- the backend, the model's
+    rank, dtype and length, and the sample's slot count.
 
     The v5e's gather pays by the INDEX, not by the byte: 6.9 ns for one
     f32 of a ``(d,)`` vector, with model, indices and output all in VMEM,
@@ -285,14 +298,46 @@ def sparse_gather_path(w, c_sel) -> str:
     (PERF.md section 6, PR 36).  ``"rows8"`` is that table, where ``w`` can
     be viewed as one (``d % 8 == 0``: criteo's 1,000,000 is, rcv1's 47,236
     is not) and the sample fills at least one block
-    (``SPARSE_GATHER_BLOCK_SLOTS``, and why).  The CPU, and everything not
-    measured, keep the element-wise gather.
+    (``SPARSE_GATHER_BLOCK_SLOTS``, and why).  A model over
+    ``SPARSE_VMEM_BYTES`` (kdd2012's 54,686,452 columns: 219 MB) is read
+    from HBM by the index in any form, 13.6 ns for one f32 and 10.0 for
+    the 128-lane row that holds it (PERF.md section 6, PR 37):
+    ``"lanes128"``, whatever ``d % 8``, where the sample fills a block of
+    THAT form.  The CPU, and everything not measured, keep the element-wise
+    gather.
     """
-    if (_on_tpu() and len(w.shape) == 1 and w.dtype == jnp.float32
-            and w.shape[0] % 8 == 0 and len(c_sel.shape) == 2
-            and c_sel.shape[0] * c_sel.shape[1] >= SPARSE_GATHER_BLOCK_SLOTS):
+    if not (_on_tpu() and len(w.shape) == 1 and w.dtype == jnp.float32
+            and len(c_sel.shape) == 2):
+        return "elements"
+    slots = c_sel.shape[0] * c_sel.shape[1]
+    if 4 * w.shape[0] > SPARSE_VMEM_BYTES:
+        return "lanes128" if slots >= SPARSE_LANES_BLOCK_SLOTS else "elements"
+    if w.shape[0] % 8 == 0 and slots >= SPARSE_GATHER_BLOCK_SLOTS:
         return "rows8"
     return "elements"
+
+
+def _as_indexed(c: jax.Array, d: int) -> jax.Array:
+    """``c`` as ``w[c]`` reads it in a ``(d,)`` vector: a negative index
+    counts from the end, and one out of range is clamped."""
+    return jnp.clip(jnp.where(c < 0, c + d, c), 0, d - 1)
+
+
+def _margins_in_blocks(c_sel, v_sel, dtype, block_slots, block_margins):
+    """``(rows,)`` margins of a packed sample, ``block_margins(cb, vb)`` a
+    block of ``block_slots`` slots at a time (ONE block shape; the last
+    is clamped and writes the rows it shares with the one before twice)."""
+    n_rows, width = c_sel.shape
+    rows, blocks = row_blocks(n_rows, block_slots // width)
+
+    def one_block(i, m):
+        _, at = clamped_block(i, rows, n_rows)
+        cb = jax.lax.dynamic_slice_in_dim(c_sel, at, rows)
+        vb = jax.lax.dynamic_slice_in_dim(v_sel, at, rows)
+        return jax.lax.dynamic_update_slice_in_dim(
+            m, block_margins(cb, vb), at, 0)
+
+    return jax.lax.fori_loop(0, blocks, one_block, jnp.zeros(n_rows, dtype))
 
 
 def _gather_rows8(table: jax.Array, c: jax.Array) -> jax.Array:
@@ -302,8 +347,7 @@ def _gather_rows8(table: jax.Array, c: jax.Array) -> jax.Array:
     column ``c % (d // 8)``, eight values an index, and the row ``c //
     (d // 8)`` of it chosen by three selects on the row's bits."""
     q = table.shape[1]
-    d = 8 * q
-    c = jnp.clip(jnp.where(c < 0, c + d, c), 0, d - 1)
+    c = _as_indexed(c, 8 * q)
     row = c // q
     with jax.named_scope("gather"):
         x = table[:, c - row * q]  # (8,) + c.shape
@@ -322,20 +366,41 @@ def _margins_rows8(c_sel, v_sel, w):
     minor as a narrow ``c_sel`` is stored (its transposed block is a
     ``bitcast``): per block one eight-wide gather, the select, the
     multiply and the sum over the slots."""
-    n_rows, width = c_sel.shape
     table = w.reshape(8, w.shape[0] // 8)
-    rows, blocks = row_blocks(n_rows, SPARSE_GATHER_BLOCK_SLOTS // width)
+    return _margins_in_blocks(
+        c_sel, v_sel, jnp.result_type(v_sel.dtype, w.dtype),
+        SPARSE_GATHER_BLOCK_SLOTS,
+        lambda cb, vb: jnp.sum(vb.T * _gather_rows8(table, cb.T), axis=0),
+    )
 
-    def one_block(i, m):
-        _, at = clamped_block(i, rows, n_rows)
-        cb = jax.lax.dynamic_slice_in_dim(c_sel, at, rows).T
-        vb = jax.lax.dynamic_slice_in_dim(v_sel, at, rows).T
-        mb = jnp.sum(vb * _gather_rows8(table, cb), axis=0)
-        return jax.lax.dynamic_update_slice_in_dim(m, mb, at, 0)
 
-    return jax.lax.fori_loop(
-        0, blocks, one_block,
-        jnp.zeros(n_rows, jnp.result_type(v_sel.dtype, w.dtype)),
+def _gather_lanes128(table: jax.Array, c: jax.Array, d: int) -> jax.Array:
+    """``w[c]`` from ``table``, ``w`` padded to whole rows of 128 lanes, for
+    every int32 ``c`` (a negative index counts from the end and one out of
+    range is clamped, as ``w[c]`` has it): ONE gather of the row ``c //
+    128``, 512 B that lie together in HBM, and the lane ``c % 128`` of it
+    kept by a compare and a maximum over the lanes (every other lane reads
+    ``-inf``: the value itself, to the bit, a ``-0.0`` and a NaN too)."""
+    c = _as_indexed(c, d)
+    with jax.named_scope("gather"):
+        x = table[c >> 7]  # c.shape + (128,)
+    lane = jnp.arange(128, dtype=c.dtype)
+    return jnp.max(
+        jnp.where((c & 127)[..., None] == lane, x, -jnp.inf), axis=-1)
+
+
+def _margins_lanes128(c_sel, v_sel, w):
+    """The margins in row blocks of the sample from the model as ``(d /
+    128, 128)`` lane rows (one padded copy of ``w`` a call: 0.5 ms at 219
+    MB): per block one row gather, the lane's pick, the multiply and the
+    sum over the slots."""
+    d = w.shape[0]
+    q = -(-d // 128)
+    table = jnp.pad(w, (0, q * 128 - d)).reshape(q, 128)
+    return _margins_in_blocks(
+        c_sel, v_sel, jnp.result_type(v_sel.dtype, w.dtype),
+        SPARSE_LANES_BLOCK_SLOTS,
+        lambda cb, vb: jnp.sum(vb * _gather_lanes128(table, cb, d), axis=1),
     )
 
 
@@ -344,12 +409,15 @@ def sparse_margins(c_sel: jax.Array, v_sel: jax.Array, w: jax.Array):
     ``x_i . w`` of padded-ELL rows (a padding slot's value is 0).
 
     THE definition behind every sparse step's residual and the ONE place
-    its gather's program is chosen (:func:`sparse_gather_path`).  Both
-    programs gather the same values, to the bit; only the order of a
-    margin's ``K``-term sum may differ.
+    its gather's program is chosen (:func:`sparse_gather_path`).  Every
+    program gathers the same values; only the order of a margin's
+    ``K``-term sum may differ.
     """
-    if sparse_gather_path(w, c_sel) == "rows8":
+    path = sparse_gather_path(w, c_sel)
+    if path == "rows8":
         return _margins_rows8(c_sel, v_sel, w)
+    if path == "lanes128":
+        return _margins_lanes128(c_sel, v_sel, w)
     return _margins_elements(c_sel, v_sel, w)
 
 

@@ -826,7 +826,13 @@ def make_sparse_table_delta(d: int):
 #: (``gradients.sparse_margins``, PR 36) is another program, with a block
 #: of its own: its table is the ONE model as ``(8, d / 8)``, which the
 #: compiler stores eight-minor, where this ``(8, d)`` table is too large for
-#: that and stays rows major.
+#: that and stays rows major.  Where the table cannot be in VMEM at all
+#: (kdd2012's d = 54,686,452: 1.75 GB; PERF.md section 6, PR 37; one
+#: 4,676,222 x 16 shard) the block does not matter, 1.086 s a call at
+#: 65,536 rows and 1.077 at 8,192 (14.4 ns a slot, eight values 219 MB
+#: apart an index), and a table packed sixteen columns of eight snapshots
+#: a 128-lane row, one 512 B read an index, is slower (1.82 to 2.05): the
+#: form and the block stay.
 SPARSE_EVAL_BLOCK_ROWS = 65_536
 SPARSE_EVAL_SNAPSHOTS = 8
 

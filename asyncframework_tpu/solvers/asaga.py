@@ -197,6 +197,9 @@ class ASAGA(EngineSolver):
                 with state_lock:
                     state["flops"] += self._task_flops(res.worker_id)
                     k = state["k"]
+                    # the account of model-sized buffers: this result and
+                    # those queued behind it (EngineRun.count_copies)
+                    run.count_copies(1 + ctx.size())
                     # ASAGA acceptance quirk: k - staleness <= taw
                     accepted = k - res.staleness <= cfg.taw
                     merge_queue.end()

@@ -167,6 +167,10 @@ class ASGD(EngineSolver):
                 merge_queue = trace.span(trace.MERGE_QUEUE, uts).begin()
                 with state_lock:
                     k = state["k"]
+                    # the account of model-sized buffers, read where the
+                    # most results are held: this drain and what has come
+                    # since (engine_loop.EngineRun.count_copies)
+                    run.count_copies(len(results) + ctx.size())
                     # never apply past the iteration budget: trim the drain
                     room = cfg.num_iterations - k
                     merged = []

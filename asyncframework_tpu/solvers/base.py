@@ -85,24 +85,60 @@ def collect_checked(ctx, waiter, timeout_s: float, pool=None,
                 raise TimeoutError("sync drain timed out")
 
 
+#: an iteration budget that bounds nothing (what ``taw`` calls unbounded):
+#: such a run ends at its deadline, and how many snapshots it keeps is not
+#: known before it starts
+UNBOUNDED_ITERATIONS = 2**31 - 1
+
+
+def planned_snapshots(cfg: "SolverConfig") -> int:
+    """The snapshots a run of ``cfg`` keeps, counted before it starts: the
+    model at ``w = 0``, after update ``j * printer_freq + 1`` for every such
+    update the iteration budget allows, and the final model.  Under a
+    budget that bounds nothing only the two every run keeps are known."""
+    if cfg.num_iterations >= UNBOUNDED_ITERATIONS:
+        return 2
+    return 2 + -(-cfg.num_iterations // max(1, cfg.printer_freq))
+
+
+def planned_model_copies(cfg: "SolverConfig", sparse: bool) -> int:
+    """The model-sized device buffers (``d`` f32 each) a run of ``cfg``
+    may hold at once, beside its shards: the live model; one result a
+    worker, computed and not yet applied (a result is a DENSE ``d``-vector
+    whatever its batch touched; behind an updater that has stalled up to
+    two fleets more can queue, which the planner's headroom is for, not
+    this count); one pinned model version a worker (a task
+    holds the version it was handed until its result is back); the
+    snapshots (:func:`planned_snapshots`); one evaluation call's stack of
+    them (eight rows over padded ELL, all of them over a dense shard); and
+    the versioned store's ring where workers read stale versions.  What
+    ``TrainResult.extras["model_copies_peak"]`` counts in a run."""
+    from asyncframework_tpu.ops.steps import SPARSE_EVAL_SNAPSHOTS
+
+    snapshots = planned_snapshots(cfg)
+    ring = cfg.max_live_versions if cfg.stale_read_offset is not None else 0
+    stack = SPARSE_EVAL_SNAPSHOTS if sparse else snapshots
+    return 1 + 2 * cfg.num_workers + snapshots + stack + ring
+
+
 def check_hbm_plan(X, cfg: "SolverConfig", devices, history_table: bool) -> None:
     """Consult the HBM planner before committing to a run (VERDICT item 10):
     host arrays are planned from shape BEFORE placement; a pre-built dataset
-    has its actual residency measured.  Raises ``MemoryError`` with the
-    planner's accounting when the budget is oversubscribed."""
+    has its actual residency measured; the engine's model-sized state is
+    :func:`planned_model_copies`, free at 3 kB a copy and a third of the
+    chip at 219 MB.  Raises ``MemoryError`` with the planner's accounting
+    when the budget is oversubscribed."""
     from asyncframework_tpu.utils.hbm import plan_for_run
 
     num_devices = max(len(set(devices)), 1)
-    versions = (
-        cfg.max_live_versions if cfg.stale_read_offset is not None else 2
-    )
     target = (X.shape[0], X.shape[1]) if isinstance(X, np.ndarray) else X
     plan_for_run(
         target,
         cfg.num_workers,
         num_devices,
         history_table=history_table,
-        model_versions=versions,
+        model_versions=planned_model_copies(
+            cfg, bool(getattr(X, "is_sparse", False))),
         budget_bytes=cfg.hbm_budget_bytes,
     ).require_fits()
 

@@ -82,6 +82,7 @@ class EngineSolver(FlopsAccountingMixin):
         queue the step's payload for the updater."""
         ctx, now_ms = run.ctx, run.now_ms
         worker_keys, key_lock = run.worker_keys, run.key_lock
+        pinned = run.pinned
         payload_of = self._result_payload
         occupancy = run.inst.occupancy  # None unless the run is traced
         submit_wall = now_ms()
@@ -93,6 +94,7 @@ class EngineSolver(FlopsAccountingMixin):
             # this worker with its previous key and replay the same mask.
             with key_lock:
                 worker_keys[wid] = result[-1]
+                pinned.pop(wid, None)  # the task is back: it pins no model
             ut = uts.get(wid) if uts else None
             if occupancy is not None:
                 occupancy.leave(wid)
@@ -118,51 +120,61 @@ class EngineSolver(FlopsAccountingMixin):
         A padded-ELL shard is walked in row blocks, one gather a block for
         ``_eval.snapshots_per_call`` snapshots: the stack is cut to that
         many a call (the last padded with the first snapshot again), so
-        one executable serves every trajectory length.
+        one executable serves every trajectory length.  ONE call's stack is
+        alive at a time: it is built, every shard's sums of it are read
+        back, and it is dropped before the next is stacked (at d = 54.7M a
+        stack of eight is 1.75 GB beside the snapshots it copies).
 
         It is the stage ``trajectory.eval`` (``ut``: the run's trace handle
-        in a traced run).  ``counters`` (a run's ``extras``) is told what it
-        cost: ``trajectory_eval_s`` on the host's clock, to the read-back of
-        the last shard's sums; ``eval_blocks``, the gathers made (one a row
-        block a call; a dense shard is one block); ``eval_snapshots``; and
-        for padded ELL ``eval_slots``, the slots those gathers picked (a
-        clamped last block counted whole)."""
+        in a traced run; ``batch`` = snapshots, ``calls`` = stacks).
+        ``counters`` (a run's ``extras``) is told what it cost:
+        ``trajectory_eval_s`` on the host's clock, to the read-back of the
+        last shard's sums; ``eval_blocks``, the gathers made (one a row
+        block a call; a dense shard is one block); ``eval_snapshots``;
+        ``eval_calls`` and ``eval_stack_rows``, the stacks built and the
+        model-sized rows of one; and for padded ELL ``eval_slots``, the
+        slots those gathers picked (a clamped last block counted whole)."""
         t0 = time.perf_counter()
         handles = [h for (_t, h) in snapshots]
         per_call = getattr(self._eval, "snapshots_per_call", len(handles))
+        calls = -(-len(handles) // per_call)
         blocks = slots = 0
-        with trace.span(trace.TRAJECTORY_EVAL, ut, batch=len(handles)):
-            stacks = []
+        totals = np.zeros(calls * per_call, np.float64)
+        with trace.span(trace.TRAJECTORY_EVAL, ut, batch=len(handles),
+                        calls=calls):
             for lo in range(0, len(handles), per_call):
                 group = handles[lo:lo + per_call]
                 group += handles[:1] * (per_call - len(group))
-                stacks.append(jnp.stack(group))
-            totals = np.zeros(len(stacks) * per_call, np.float64)
-            for wid in range(self.cfg.num_workers):
-                shard = self._recovery.shard(wid)  # follows re-homed shards
-                if self._sparse:
-                    arrays = (shard.cols, shard.vals, shard.y)
-                    n_blocks = len(stacks) * self._eval.blocks(shard.size)
-                    slots += (n_blocks * self._eval.block_rows(shard.size)
-                              * shard.cols.shape[1])
-                else:
-                    arrays = (shard.X, shard.y)
-                    n_blocks = len(stacks)
-                blocks += n_blocks
-                parts = [
-                    self._eval(*arrays, W if W.device == shard.device
-                               else jax.device_put(W, shard.device))
-                    for W in stacks
-                ]
-                totals += np.concatenate(
-                    [np.asarray(p, np.float64) for p in parts]
-                )
+                W = jnp.stack(group)
+                on = {W.device: W}  # this call's (per_call, d), by device
+                parts = []
+                for wid in range(self.cfg.num_workers):
+                    shard = self._recovery.shard(wid)  # follows re-homed shards
+                    if self._sparse:
+                        arrays = (shard.cols, shard.vals, shard.y)
+                        n_blocks = self._eval.blocks(shard.size)
+                        slots += (n_blocks * self._eval.block_rows(shard.size)
+                                  * shard.cols.shape[1])
+                    else:
+                        arrays = (shard.X, shard.y)
+                        n_blocks = 1
+                    blocks += n_blocks
+                    if shard.device not in on:
+                        on[shard.device] = jax.device_put(W, shard.device)
+                    parts.append(self._eval(*arrays, on[shard.device]))
+                # the read-back is the fence: the stack is dead after it.
+                # Shard by shard in worker order, as the sums always were
+                # added: a trajectory is the same to the bit
+                for part in parts:
+                    totals[lo:lo + per_call] += np.asarray(part, np.float64)
+                del W, on, parts
         totals = totals[:len(handles)] / self.ds.n
         traj = [(t, float(l)) for (t, _), l in zip(snapshots, totals)]
         if counters is not None:
             counters.update(
                 trajectory_eval_s=time.perf_counter() - t0,
                 eval_blocks=blocks, eval_snapshots=len(handles),
+                eval_calls=calls, eval_stack_rows=per_call,
             )
             if self._sparse:
                 counters["eval_slots"] = slots
@@ -214,6 +226,18 @@ class EngineRun:
         self.state: Dict[str, object] = {}
         self.state_lock = threading.Lock()
         self.stop = threading.Event()
+        #: the account of model-sized device buffers (``d`` f32 each: 3 kB
+        #: in the dense cells, 219 MB at d = 54.7M) this run's engine
+        #: holds.  ``pinned``: the model version each task that is out was
+        #: handed, by worker, as the handle's ``id`` (the account itself
+        #: pins nothing), under ``key_lock``, and the most distinct ones at
+        #: a submit; the other maxima are the updater's readings
+        #: (:meth:`count_copies`), one a drain
+        self.pinned: Dict[int, int] = {}
+        self.copies = {"results_held_max": 0, "versions_pinned_max": 0,
+                       "model_copies_peak": 0}
+        self._snapshot_ids: set = set()
+        self._snapshots_counted = 0
         self._ft = self._spec = self._alloc = None
         #: what :meth:`drive` read of its own end (a sync run has none),
         #: and when its submitter loop left
@@ -326,6 +350,40 @@ class EngineRun:
     def now_ms(self) -> float:
         return (time.monotonic() - self.start_wall) * 1e3
 
+    # ------------------------------------------------- the model-sized state
+    def pin(self, cohort, w_pub) -> None:
+        """The submitter hands ``cohort`` the model version ``w_pub``: each
+        task holds it until its result is back (the handler unpins)."""
+        version = id(w_pub)
+        with self.key_lock:
+            for wid in cohort:
+                self.pinned[wid] = version
+            self.copies["versions_pinned_max"] = max(
+                self.copies["versions_pinned_max"],
+                len(set(self.pinned.values())))
+
+    def count_copies(self, results_held: int, stack_rows: int = 0) -> None:
+        """One reading of the account, by the thread that holds
+        ``state_lock`` (the updater at a drain; the main thread once the
+        run is over): ``results_held`` results computed and not yet
+        applied, and the distinct model-sized buffers in all: the live model, those
+        versions and the snapshots (one buffer may be all three), the
+        results, and ``stack_rows`` rows of an evaluation call's stack."""
+        with self.key_lock:
+            versions = set(self.pinned.values())
+        seen = self._snapshot_ids
+        for _t, handle in self.snapshots[self._snapshots_counted:]:
+            seen.add(id(handle))  # a snapshot lives as long as the run
+        self._snapshots_counted = len(self.snapshots)
+        copies = self.copies
+        copies["results_held_max"] = max(
+            copies["results_held_max"], results_held)
+        versions.add(id(self.state["w"]))
+        copies["model_copies_peak"] = max(
+            copies["model_copies_peak"],
+            len(seen) + len(versions - seen) + results_held + stack_rows,
+        )
+
     # -------------------------------------------------------- submitter loop
     def drive(self, updater: Callable[[], None], thread_name: str,
               make_tasks: Callable) -> None:
@@ -428,6 +486,7 @@ class EngineRun:
                     waiting.on_submit(cohort, now_ms())
                     if uts:
                         inst.begin_compute(uts, model_version)
+                    self.pin(cohort, w_pub)
                     fns = make_tasks(cohort, w_pub, uts)
                     with state_lock:
                         state["rounds"] += 1
@@ -521,6 +580,10 @@ class EngineRun:
         traj = self.solver._evaluate_trajectory(
             self.snapshots, inst.run_trace(), extras
         )
+        # the account's last reading: every snapshot and one call's stack
+        with self.state_lock:
+            self.count_copies(0, extras["eval_stack_rows"])
+        extras.update(self.copies, snapshots_held=len(self.snapshots))
         if self._spec is not None:
             extras["speculated"] = self._spec.speculated_count()
             extras["speculation_wins"] = sched.speculative_wins()

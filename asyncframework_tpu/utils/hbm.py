@@ -86,8 +86,10 @@ def plan_dataset(
 
     Accounts for: the data shards living on the device (workers sharing a
     device stack their shards), labels, the ASAGA history slice (one f32 per
-    sample) when ``history_table``, and ``model_versions`` live copies of
-    ``w`` (the versioned broadcast ring).  ``headroom`` reserves a fraction
+    sample) when ``history_table``, and ``model_versions`` model-sized
+    buffers (``d`` f32 each: the live model, results in flight, versions
+    pinned by tasks, snapshots, an evaluation's stack;
+    ``solvers.base.planned_model_copies``).  ``headroom`` reserves a fraction
     of the budget for XLA workspace/fusion temporaries.
     """
     if num_devices < 1 or num_workers < 1:

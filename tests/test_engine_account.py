@@ -414,3 +414,165 @@ def test_the_barriers_hold_and_the_dispatchs_chip_are_in_the_host_plane(
     assert trace.ANNOTATION_PREFIX + trace.WAIT_WORKERS not in names
     ids = [d.id for d in devices8[:2]]
     assert dispatched == {(wid, ids[wid % 2]) for wid in range(4)}
+
+
+# ------------------------------ the model-sized state (ISSUE 37), hand-counted
+COPIES = ("results_held_max", "versions_pinned_max", "model_copies_peak",
+          "snapshots_held")
+
+
+def _runs_built(monkeypatch):
+    """Every ``EngineRun`` built from now on, in order."""
+    from asyncframework_tpu.solvers import engine_loop
+
+    runs = []
+    real_init = engine_loop.EngineRun.__init__
+
+    def init(self, *a, **kw):
+        real_init(self, *a, **kw)
+        runs.append(self)
+
+    monkeypatch.setattr(engine_loop.EngineRun, "__init__", init)
+    return runs
+
+
+def _in_lockstep(monkeypatch):
+    """A cohort goes out only once every result of the cohorts before it
+    is APPLIED: with ``bucket_ratio`` 1.0 and an updater held until a whole
+    fleet is queued, a run is rounds of four in a fixed order."""
+    from asyncframework_tpu.solvers import engine_loop
+
+    runs = _runs_built(monkeypatch)
+    real_barrier = engine_loop.partial_barrier
+
+    def barrier(ctx, nw, bucket):
+        run = runs[-1]
+        with run.state_lock:
+            merged = run.state["accepted"] + run.state["dropped"]
+            if merged != nw * run.state["rounds"]:
+                return []
+        return real_barrier(ctx, nw, bucket)
+
+    monkeypatch.setattr(engine_loop, "partial_barrier", barrier)
+
+
+@pytest.mark.parametrize("sparse", [False, True], ids=["dense", "ell"])
+def test_the_count_of_model_copies_is_the_hand_count_of_a_lockstep_run(
+        sparse, problem, monkeypatch, held_updater):
+    """Two rounds of four, ``printer_freq`` 4.  Round 1 is handed ``w0``
+    (one version pinned; it is the live model and snapshot 0).  Drain 1
+    finds 4 results, nothing pinned, one snapshot: 1 + 4 = 5 buffers; its
+    two dispatches leave ``w1`` (a snapshot) and ``w4``.  Round 2 is handed
+    ``w4``.  Drain 2 finds 4 results, snapshots ``w0`` and ``w1`` and the
+    live ``w4``: 3 + 4 = 7.  The end: four snapshots (``w0``, ``w1``,
+    ``w5``, the final ``w8``, which is the live model) and one evaluation
+    stack beside them: all four rows over a dense shard, eight over padded
+    ELL."""
+    _in_lockstep(monkeypatch)
+    held_updater(4)
+    kw = dict(num_iterations=8, printer_freq=4, bucket_ratio=1.0)
+    if sparse:
+        import jax
+
+        from asyncframework_tpu.data.sparse import SparseShardedDataset
+
+        ds = SparseShardedDataset.generate_on_device(
+            2048, 40_004, 11, 4, jax.devices()[:1], seed=5)
+        solver = ASGD(ds, None, SolverConfig(
+            num_workers=4, taw=2**31 - 1, batch_rate=0.3, gamma=0.4, seed=3,
+            calibration_iters=8, run_timeout_s=60.0, **kw),
+            devices=jax.devices()[:1])
+    else:
+        solver = _solver(ASGD, problem, **kw)
+    res = solver.run()
+    assert res.accepted == 8 and res.rounds == 2
+    stack = 8 if sparse else 4
+    assert {k: res.extras[k] for k in COPIES} == {
+        "results_held_max": 4, "versions_pinned_max": 1,
+        "model_copies_peak": 4 + stack, "snapshots_held": 4,
+    }
+    assert res.extras["eval_calls"] == 1
+    assert res.extras["eval_stack_rows"] == stack
+
+
+def test_the_account_counts_a_buffer_once_whatever_holds_it(problem):
+    """The account itself, fed by hand: versions are distinct handles, a
+    buffer that is the live model, a snapshot and a pinned version at once
+    is one copy, and a finished task unpins."""
+    import jax.numpy as jnp
+
+    from asyncframework_tpu.solvers import engine_loop
+
+    solver = _solver(ASGD, problem)
+    run = engine_loop.EngineRun(solver)
+    try:
+        run.cold_start()
+        run.start_clock()  # snapshot 0 is the live w0
+        w0 = run.state["w"]
+        w1, w2 = jnp.ones(16), jnp.full(16, 2.0)
+        run.pin([0, 1], w0)
+        run.count_copies(0)
+        assert run.copies == {"results_held_max": 0, "versions_pinned_max": 1,
+                              "model_copies_peak": 1}
+        run.pin([2], w1)
+        run.pin([3], w2)
+        run.count_copies(3)  # w0 (live, snapshot, pinned), w1, w2, 3 results
+        assert run.copies == {"results_held_max": 3, "versions_pinned_max": 3,
+                              "model_copies_peak": 6}
+        # worker 2's task is back (what the handler does under the key
+        # lock); the model moves on to w2, which worker 3 still pins
+        with run.key_lock:
+            run.pinned.pop(2)
+        run.state["w"] = w2
+        run.snapshots.append((1.0, w2))
+        run.count_copies(1, stack_rows=8)  # w0, w2, 1 result, 8 rows
+        assert run.copies == {"results_held_max": 3, "versions_pinned_max": 3,
+                              "model_copies_peak": 11}
+    finally:
+        run.shutdown(run_ok=True)
+
+
+@SOLVERS
+def test_every_engine_run_reports_the_four_counts(solver_cls, problem):
+    res = _solver(solver_cls, problem).run()
+    ex = res.extras
+    assert ex["snapshots_held"] == len(res.trajectory)
+    assert 1 <= ex["results_held_max"] <= 3 * 4 - 1
+    assert 1 <= ex["versions_pinned_max"] <= 4
+    # at the end: every snapshot and the dense stack of all of them
+    assert ex["model_copies_peak"] >= 2 * ex["snapshots_held"]
+    sync = _solver(solver_cls, problem, num_iterations=6).run_sync().extras
+    # a sync run pins and holds nothing the account sees: its last reading
+    assert sync["results_held_max"] == 0 and sync["versions_pinned_max"] == 0
+    assert sync["model_copies_peak"] == 2 * sync["snapshots_held"]
+
+
+def test_the_pins_survive_sixteen_workers_on_a_short_switch_interval(
+        problem, monkeypatch):
+    """More workers than cores and a thread switch every 10 us: the
+    submitter pins and sixteen handlers unpin under one lock, so when the
+    run is over nothing is pinned but what was still out, and no reading
+    went over what the engine can hold."""
+    import sys
+
+    runs = _runs_built(monkeypatch)
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        res = _solver(ASGD, problem, num_workers=16, num_iterations=400,
+                      bucket_ratio=0.3, printer_freq=50,
+                      run_timeout_s=30.0).run()
+    finally:
+        sys.setswitchinterval(old)
+    ex = res.extras
+    assert res.accepted == 400
+    with runs[-1].key_lock:
+        assert len(runs[-1].pinned) <= ex["inflight_at_stop"]
+    assert 1 <= ex["versions_pinned_max"] <= 16
+    # a fleet drained and not yet applied, a fleet less one queued when
+    # the submitter last looked, and the fleet it then sent out
+    assert 1 <= ex["results_held_max"] <= 3 * 16 - 1
+    assert ex["snapshots_held"] == len(res.trajectory) == 10
+    # ten snapshots and the dense stack of them, at least; every reading
+    # under all the engine can hold at once
+    assert 20 <= ex["model_copies_peak"] <= 10 + 10 + 16 + 47

@@ -5,7 +5,9 @@ program, ``gradients.sparse_gather_path``.
 ``"elements"`` is the expression every step held until PR 36, one model
 value an index; ``"rows8"`` views the model as an ``(8, d / 8)`` table and
 gathers eight values an index in row blocks of the sample, then selects the
-one.  A gather is a copy: the two forms called directly give the same
+one; ``"lanes128"`` (ISSUE 37) views a model too large for VMEM as rows of
+128 lanes, gathers the row and keeps the lane.  A gather is a copy: the
+forms called directly give the same
 VALUES to the bit, for every int32 index ``w[c]`` takes, and the margins
 differ by the order of a ``K``-term float32 sum at most.  The chooser
 answers from the backend and the shapes alone, so the CPU suite runs
@@ -88,6 +90,38 @@ def test_the_two_forms_gather_the_same_values(monkeypatch, d, k, size, case):
         assert not np.asarray(m8)[rows - rows // 3:].any()
 
 
+@pytest.mark.parametrize("case", ["padding", "invalid_tail", "any_int32"])
+@pytest.mark.parametrize("size", list(_SIZES))
+@pytest.mark.parametrize("d,k", [(1_000_000, 40), (40_004, 16), (4_096, 7)])
+def test_the_lane_row_form_gathers_the_same_values(
+        monkeypatch, d, k, size, case):
+    """``"lanes128"`` (ISSUE 37): the model as rows of 128 lanes, whatever
+    ``d % 8`` (40,004 is no multiple of 8, nor of 128: the last row is
+    padded), the value picked by its lane: ``w[c]`` to the bit."""
+    monkeypatch.setattr(gradients, "SPARSE_LANES_BLOCK_SLOTS", BLOCK * k)
+    rows = _SIZES[size]
+    c, v, w = _sample(d, rows, k, case)
+    q = -(-d // 128)
+    table = jnp.pad(w, (0, q * 128 - d)).reshape(q, 128)
+    picked = gradients._gather_lanes128(table, c, d)
+    assert picked.shape == c.shape and picked.dtype == w.dtype
+    np.testing.assert_array_equal(_bits(picked), _bits(w[c]))
+
+    ml = jax.jit(gradients._margins_lanes128)(c, v, w)
+    m1 = jax.jit(gradients._margins_elements)(c, v, w)
+    assert ml.shape == m1.shape == (rows,) and ml.dtype == m1.dtype
+    terms = np.abs(np.asarray(v, np.float64) * np.asarray(w[c], np.float64))
+    ulp = np.finfo(np.float32).eps * terms.sum(axis=1)
+    assert np.all(np.abs(np.asarray(ml, np.float64) - np.asarray(m1)) <= 4 * ulp)
+    if case == "invalid_tail":  # an unfilled slot's margin is the exact 0
+        assert not np.asarray(ml)[rows - rows // 3:].any()
+    text = str(jax.make_jaxpr(gradients._margins_lanes128)(c, v, w))
+    block = min(rows, BLOCK)
+    assert f"f32[{block},{k},128]" in text and f"f32[{q},128]" in text
+    if rows > BLOCK:
+        assert "scan" in text and f"f32[{rows},{k},128]" not in text
+
+
 def test_a_sample_is_walked_in_clamped_blocks_of_one_shape(monkeypatch):
     """777 rows in blocks of 256: four blocks, the last read at row 521 so
     that it ends with the sample (the evaluation's arithmetic, shared)."""
@@ -128,11 +162,28 @@ def _spec(shape, dtype):
         (True, ((1_000_000,), jnp.bfloat16), (145_472, 40), "elements"),
         (True, ((8, 125_000), jnp.float32), (145_472, 40), "elements"),
         (True, ((1_000_000,), jnp.float32), (5_818_880,), "elements"),
+        # the kdd2012 cell's step: 236,640 packed rows of 16 slots against
+        # a 219 MB model, which no form of keeps in VMEM (ISSUE 37)
+        (True, ((54_686_452,), jnp.float32), (236_640, 16), "lanes128"),
+        (False, ((54_686_452,), jnp.float32), (236_640, 16), "elements"),
+        # one whole block of 16,384 slots of THAT form, and one row under
+        (True, ((54_686_452,), jnp.float32), (1_024, 16), "lanes128"),
+        (True, ((54_686_452,), jnp.float32), (1_023, 16), "elements"),
+        # over VMEM an eight-row view is no help: 40M columns, 160 MB
+        (True, ((40_000_000,), jnp.float32), (236_640, 16), "lanes128"),
+        # 128 MiB to the byte is not over it
+        (True, ((2**25,), jnp.float32), (236_640, 16), "rows8"),
+        (True, ((2**25 + 8,), jnp.float32), (236_640, 16), "lanes128"),
+        (True, ((54_686_452,), jnp.bfloat16), (236_640, 16), "elements"),
     ],
     ids=["tpu-criteo", "cpu-criteo", "tpu-rcv1-width", "tpu-one-block",
          "tpu-one-block-narrow", "tpu-under-a-block", "tpu-small",
          "tpu-bf16-model",
-         "tpu-2d-model", "tpu-flat-sample"],
+         "tpu-2d-model", "tpu-flat-sample",
+         "tpu-kdd2012", "cpu-kdd2012", "tpu-one-lane-block",
+         "tpu-under-a-lane-block", "tpu-over-vmem-multiple-of-8",
+         "tpu-vmem-to-the-byte", "tpu-eight-columns-over-vmem",
+         "tpu-bf16-wide-model"],
 )
 def test_the_chooser_answers_from_backend_and_shapes(
         monkeypatch, on_tpu, w, c_sel, want):
@@ -172,6 +223,35 @@ def test_where_the_chooser_says_rows8_the_helper_runs_it(monkeypatch):
     assert "scan" in text and f"f32[8,8,{BLOCK}]" in text
     g8, _ = step8(cols, vals, y, w, key)
     assert np.max(np.abs(np.asarray(g8) - np.asarray(g1))) <= (
+        1e-6 * np.max(np.abs(np.asarray(g1))))
+
+
+def test_where_the_chooser_says_lanes128_the_helper_runs_it(monkeypatch):
+    """A model over ``SPARSE_VMEM_BYTES`` (patched down to this test's
+    size) on a TPU: the helper's jaxpr holds the lane-row loop, and the
+    step's gradient is the element-wise step's within the order of a
+    margin's sum."""
+    monkeypatch.setattr(gradients, "SPARSE_LANES_BLOCK_SLOTS", BLOCK * 16)
+    d, n = 40_004, 1_500
+    rs = np.random.default_rng(4)
+    cols = jnp.asarray(rs.integers(0, d, (n, 16)), jnp.int32)
+    vals = jnp.asarray(rs.standard_normal((n, 16)), jnp.float32)
+    y = jnp.asarray(rs.integers(0, 2, n), jnp.float32)
+    w = jnp.asarray(0.1 * rs.standard_normal(d), jnp.float32)
+    key = jax.random.PRNGKey(1)
+    g1, _ = steps.make_sparse_asgd_worker_step(0.5, d, "logistic")(
+        cols, vals, y, w, key)
+    monkeypatch.setattr(gradients, "_on_tpu", lambda: True)
+    # under VMEM and no multiple of 8: the element-wise gather, as ever
+    assert steps.make_sparse_asgd_worker_step(0.5, d).gather_path(
+        n, 16) == "elements"
+    monkeypatch.setattr(gradients, "SPARSE_VMEM_BYTES", 4 * d - 1)
+    step = steps.make_sparse_asgd_worker_step(0.5, d, "logistic")
+    assert step.gather_path(n, 16) == "lanes128"
+    text = str(jax.make_jaxpr(step)(cols, vals, y, w, key))
+    assert "scan" in text and f"f32[{BLOCK},16,128]" in text
+    gl, _ = step(cols, vals, y, w, key)
+    assert np.max(np.abs(np.asarray(gl) - np.asarray(g1))) <= (
         1e-6 * np.max(np.abs(np.asarray(g1))))
 
 

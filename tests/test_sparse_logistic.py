@@ -39,6 +39,15 @@ LOSSES = ["least_squares", "logistic"]
 PARENT_BYTES = "852cb2ed1060101ef8ddc273c712e5d0e9f1703b95b2a2c983dab4f32b763a2d"
 CLICKS = dict(column_skew=1.0, unit_values=True,
               bernoulli_labels={"scale": 3.0, "positive_share": 0.256})
+#: the two deployments' shapes at a test's size: criteo's (12 non-zeros in
+#: 16 slots, a model of 512) and kdd2012's (ISSUE 37: 11 in 16, rare clicks,
+#: a width that is no multiple of 8 and over 16 times the slots a step of
+#: a 2,001-row shard samples)
+SHAPES = {
+    "criteo": dict(d=512, nnz=12),
+    "kdd2012": dict(d=200_004, nnz=11, bernoulli_labels={
+        "scale": 3.0, "positive_share": 0.045}),
+}
 
 
 def _digest(ds):
@@ -157,10 +166,12 @@ def _sampled(key, batch_rate, rows):
 
 @pytest.mark.parametrize("loss", LOSSES)
 @pytest.mark.parametrize("batch_rate", [0.3, 1.0])
+@pytest.mark.parametrize("shape", list(SHAPES))
 def test_sparse_step_matches_the_reference_on_its_own_sampled_rows(
-        loss, batch_rate):
-    d = 512
-    ds = _click_log(d=d)  # ragged shards, a hot column, duplicates in a row
+        loss, batch_rate, shape):
+    d = SHAPES[shape]["d"]
+    # ragged shards, a hot column, duplicates in a row
+    ds = _click_log(**SHAPES[shape])
     w = np.random.default_rng(2).standard_normal(d).astype(np.float32)
     step = steps.make_sparse_asgd_worker_step(batch_rate, d, loss)
     for wid in (0, 3):
@@ -221,15 +232,16 @@ def test_fused_sparse_rounds_still_refuse_the_logistic_loss():
 @pytest.mark.parametrize("loss", LOSSES)
 @pytest.mark.parametrize("snapshots", [1, 8, 11])
 @pytest.mark.parametrize("block_rows", [256, 2001, 4096])
+@pytest.mark.parametrize("shape", list(SHAPES))
 def test_blocked_evaluation_matches_the_reference(
-        monkeypatch, loss, snapshots, block_rows):
+        monkeypatch, loss, snapshots, block_rows, shape):
     """Blocks that do not divide the shard (2,001 rows in blocks of 256:
     the last is clamped and masked), one block exactly, a block larger than
     the shard; 1 snapshot, one tile of 8, and 11 (a tile and a ragged
-    one)."""
+    one); each at both deployments' shapes."""
     monkeypatch.setattr(steps, "SPARSE_EVAL_BLOCK_ROWS", block_rows)
-    d = 512
-    sh = _click_log(d=d).shard(0)
+    d = SHAPES[shape]["d"]
+    sh = _click_log(**SHAPES[shape]).shard(0)
     assert sh.size == 2001
     ev = steps.make_sparse_trajectory_loss_eval(loss)
     assert ev.blocks(sh.size) == -(-2001 // min(block_rows, 2001))
@@ -371,3 +383,86 @@ def test_trajectory_eval_is_a_span_of_a_traced_run_and_a_work_stage():
     ASGD(ds, None, _cfg(num_iterations=12), devices=jax.devices()[:1]).run()
     assert trace.TRAJECTORY_EVAL not in (
         trace.aggregator().snapshot()["stages_ms"])
+
+
+# ------------------------------------- the trajectory's memory (ISSUE 37)
+
+def _evaluated_all_at_once(engine, handles):
+    """The evaluation as it was until PR 37: EVERY call's stack built
+    first, then shard by shard over all of them."""
+    per_call = engine._eval.snapshots_per_call
+    stacks = []
+    for lo in range(0, len(handles), per_call):
+        group = handles[lo:lo + per_call]
+        group += handles[:1] * (per_call - len(group))
+        stacks.append(jnp.stack(group))
+    totals = np.zeros(len(stacks) * per_call, np.float64)
+    for wid in range(engine.cfg.num_workers):
+        sh = engine.ds.shard(wid)
+        totals += np.concatenate([
+            np.asarray(engine._eval(sh.cols, sh.vals, sh.y, W), np.float64)
+            for W in stacks])
+    return totals[:len(handles)] / engine.ds.n
+
+
+@pytest.mark.parametrize("shape", list(SHAPES))
+def test_seventeen_snapshots_are_evaluated_a_stack_at_a_time_to_the_bit(
+        monkeypatch, shape):
+    """Three calls of eight (the last padded with the first snapshot): the
+    trajectory is what the all-at-once evaluation gave, bit for bit, and
+    when a call's stack is built the one before it is gone."""
+    import gc
+    import weakref
+
+    d = SHAPES[shape]["d"]
+    ds = _click_log(**SHAPES[shape])
+    engine = ASGD(ds, None, _cfg(), devices=jax.devices()[:1])
+    rs = np.random.default_rng(17)
+    handles = [jnp.asarray((0.1 * j * rs.standard_normal(d)).astype(
+        np.float32)) for j in range(17)]
+    want = _evaluated_all_at_once(engine, list(handles))
+    stacks, alive_at_build = [], []
+    real_stack = jnp.stack
+
+    def stack(arrays, *a, **kw):
+        gc.collect()
+        alive_at_build.append(sum(r() is not None for r in stacks))
+        out = real_stack(arrays, *a, **kw)
+        stacks.append(weakref.ref(out))
+        return out
+
+    monkeypatch.setattr(engine_loop.jnp, "stack", stack)
+    counters = {}
+    traj = engine._evaluate_trajectory(
+        [(float(j), h) for j, h in enumerate(handles)], None, counters)
+    monkeypatch.undo()
+    assert [f for _t, f in traj] == [float(f) for f in want]
+    assert [t for t, _f in traj] == [float(j) for j in range(17)]
+    assert alive_at_build == [0, 0, 0]  # never two stacks
+    gc.collect()
+    assert not any(r() is not None for r in stacks)
+    assert counters["eval_calls"] == 3 and counters["eval_stack_rows"] == 8
+    assert counters["eval_snapshots"] == 17
+    assert counters["eval_blocks"] == 3 * 4  # one block a shard a call
+    assert counters["eval_slots"] == 3 * 8003 * 16
+
+
+def test_the_trajectory_eval_span_carries_its_calls(monkeypatch):
+    spans = []
+    real = trace.UpdateTrace.add
+
+    def add(self, stage, *args, **kw):
+        span = real(self, stage, *args, **kw)
+        if stage == trace.TRAJECTORY_EVAL:
+            spans.append(span)
+        return span
+
+    monkeypatch.setattr(trace.UpdateTrace, "add", add)
+    ds = _click_log(n=2048)
+    res = ASGD(ds, None, _cfg(num_iterations=30, trace_sample=0.5),
+               devices=jax.devices()[:1]).run()
+    (ev,) = spans
+    # w = 0, after updates 1, 4, ..., 28, the final model: 12 snapshots
+    assert ev.batch == len(res.trajectory) == 12
+    assert ev.calls == res.extras["eval_calls"] == 2
+    assert trace.Span.from_wire(ev.to_wire()).calls == 2

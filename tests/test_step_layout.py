@@ -451,3 +451,45 @@ def test_sparse_step_moves_no_slot_to_put_it_in_order(
     # until PR 36) and one block of the model's gathered rows
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < 160e6 + TEMP_BOUND, f"{temp} bytes of temporaries"
+
+
+# ----------------------------------------- the wide deployment (ISSUE 37)
+
+WIDE_ROWS, WIDE_WIDTH, WIDE_D = 4_676_222, 16, 54_686_452
+
+
+def test_sparse_margins_gather_a_lane_row_an_index_from_a_model_over_vmem(
+    one_chip, no_compile_cache, on_tpu
+):
+    """``gradients.sparse_margins`` alone at the kdd2012 step's sample,
+    236,640 packed rows x 16 slots against ``d`` = 54,686,452: 219 MB, which
+    no form of keeps in the v5e's VMEM.  The chooser says ``lanes128``, and
+    the program compiled for the described v5e gathers the model ONLY as
+    whole rows of 128 lanes (512 B that lie together in HBM), a block of
+    ``SPARSE_LANES_BLOCK_SLOTS`` slots (1,024 rows) at a time; the model's
+    one padded copy and a block's rows are all it keeps."""
+    def spec(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    cap = steps.sparse_step_capacity(0.05, WIDE_ROWS)
+    assert (cap, cap * WIDE_WIDTH) == (236_640, 3_786_240)
+    c_sel, v_sel = (spec((cap, WIDE_WIDTH), jnp.int32),
+                    spec((cap, WIDE_WIDTH), jnp.float32))
+    w = spec((WIDE_D,), jnp.float32)
+    assert WIDE_D % 8 == 4 and 4 * WIDE_D > gradients.SPARSE_VMEM_BYTES
+    assert gradients.sparse_gather_path(w, c_sel) == "lanes128"
+    compiled = jax.jit(gradients.sparse_margins).lower(
+        c_sel, v_sel, w).compile()
+    text = compiled.as_text()
+    rows = gradients.SPARSE_LANES_BLOCK_SLOTS // WIDE_WIDTH
+    assert rows == 1_024
+    gathers = [(m.group(1).split("{")[0],
+                re.search(r"slice_sizes=\{([\d,]+)\}", ln).group(1))
+               for ln in text.splitlines()
+               for m in [re.search(r"= (\S+) gather\(", ln)] if m]
+    assert gathers == [(f"f32[{rows},{WIDE_WIDTH},128]", "1,128")], gathers
+    assert [i for i in _instructions(text) if i[2] == "while"]
+    assert not [i for i in _instructions(text) if i[2] in ("scatter", "sort")]
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    # the padded model (219 MB) and one block's gathered rows (8 MB)
+    assert temp < 4 * WIDE_D + 64e6, f"{temp} bytes of temporaries"

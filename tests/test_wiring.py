@@ -221,6 +221,65 @@ class TestHBMPlanWiring:
         # a sane budget accepts the same dataset
         ASGD(ds, None, cfg_with(hbm_budget_bytes=1 << 30), devices=devices8)
 
+    def test_snapshots_are_counted_before_the_run_starts(self):
+        from asyncframework_tpu.solvers.base import (
+            planned_model_copies,
+            planned_snapshots,
+        )
+
+        # w = 0, after updates 1, 21, ..., 221, the final model
+        assert planned_snapshots(cfg_with(num_iterations=240,
+                                          printer_freq=20)) == 14
+        assert planned_snapshots(cfg_with(num_iterations=241,
+                                          printer_freq=20)) == 15
+        # a budget nothing reaches bounds nothing: the two every run keeps
+        unbounded = cfg_with(num_iterations=2**31 - 1, printer_freq=20)
+        assert planned_snapshots(unbounded) == 2
+        # live + a result and a pinned version a worker + snapshots + stack
+        assert planned_model_copies(unbounded, sparse=True) == 1 + 16 + 2 + 8
+        assert planned_model_copies(unbounded, sparse=False) == 1 + 16 + 2 + 2
+        ring = cfg_with(num_iterations=100, printer_freq=50,
+                        stale_read_offset=2, max_live_versions=4)
+        assert planned_model_copies(ring, sparse=False) == 1 + 16 + 4 + 4 + 4
+
+    @pytest.mark.parametrize("printer_freq,fits", [(20, True), (2, False)])
+    def test_the_plan_holds_results_and_snapshots_at_model_bytes(
+            self, printer_freq, fits):
+        """The kdd2012 cell's residency (eight placed shards of 4,676,222
+        x 16 slots: 4.94 GB) and a 219 MB model, on a 16 GiB chip of which
+        the planner uses 85%: the cell's 14 snapshots fit (39 copies, 8.5
+        GB), 122 do not (25 results and versions and 130 snapshot rows:
+        33.9 GB)."""
+        import types
+
+        import jax
+
+        from asyncframework_tpu.solvers.base import check_hbm_plan
+
+        dev = jax.devices()[0]
+
+        def arr(shape, dtype):
+            return types.SimpleNamespace(
+                shape=shape, dtype=np.dtype(dtype), device=dev)
+
+        rows, d = 4_676_222, 54_686_452
+        shard = types.SimpleNamespace(
+            cols=arr((rows, 16), np.int32), vals=arr((rows, 16), np.float32),
+            y=arr((rows,), np.float32))
+        ds = types.SimpleNamespace(
+            n=8 * rows, d=d, num_workers=8, is_sparse=True,
+            shard=lambda wid: shard)
+        cfg = cfg_with(num_iterations=240, printer_freq=printer_freq,
+                       hbm_budget_bytes=16 * 2**30)
+        if fits:
+            check_hbm_plan(ds, cfg, [dev], history_table=False)
+        else:
+            with pytest.raises(MemoryError, match="exceeds the"):
+                check_hbm_plan(ds, cfg, [dev], history_table=False)
+        # the same residency with a 3 kB model fits either way
+        small = types.SimpleNamespace(**{**vars(ds), "d": 784})
+        check_hbm_plan(small, cfg, [dev], history_table=False)
+
     def test_asaga_stale_read_offset_run(self, devices8, problem):
         X, y, _ = problem
         cfg = cfg_with(num_iterations=100, gamma=0.05, stale_read_offset=2)
