@@ -8,8 +8,16 @@ TPU-first representation: CSR's ragged rows defeat XLA's static-shape
 compilation, and densifying rcv1 is impossible (47k x 700k f32 = 131 GB).
 Each shard is stored as **padded ELL**: per-row fixed-width ``cols (n_p, K)``
 / ``vals (n_p, K)`` arrays where ``K`` is the shard's max row nnz rounded up
-to a lane multiple; padding entries have ``col=0, val=0`` so they contribute
-exactly zero to every product.  The worker step then needs no dynamic shapes:
+to a multiple of 8 (a sublane tile); padding entries have ``col=0, val=0``
+so they contribute exactly zero to every product.  A row's values are
+packed to the left, so the ELL columns from the shard's **live width** on
+(the most slots any of its rows fills: ``SparseShard.live_width``, recorded
+where the rows are packed) hold padding in EVERY row: kdd2012's 11 values
+a row live in 16 slots, criteo's 39 in 40.  The sparse programs are built
+with the dataset's live width (``SparseShardedDataset.live_width``) and
+read the shard's first ``live_width`` ELL columns, a contiguous prefix of
+an array stored rows minor; the stored arrays keep their shape and bytes.
+The worker step then needs no dynamic shapes:
 
 - residual: ``r_i = sum_k vals[i,k] * w[cols[i,k]] - y_i``  (gather + reduce)
 - gradient: ``g = scatter_add(zeros(d), cols, vals * coeff[:, None])``
@@ -95,6 +103,10 @@ class SparseShard:
     y: jax.Array     # (n_p,)
     start: int
     size: int
+    #: the most slots any row fills: ELL columns ``live_width .. K`` hold
+    #: ``col=0, val=0`` in every row (the builders pack a row's values to
+    #: the left and record this from the host integers they pack by)
+    live_width: int
 
     @property
     def device(self):
@@ -166,8 +178,10 @@ class SparseShardedDataset:
         cum = np.concatenate([[0], np.cumsum(sizes)])
         obj.partition_cum = [int(c) for c in cum]
         K = _round_up(int(nnz_per_row))
+        live_width = int(nnz_per_row)
+        assert 0 < live_width <= K, (live_width, K)
 
-        live = (jnp.arange(K) < nnz_per_row)[None, :]
+        live = (jnp.arange(K) < live_width)[None, :]
         scale = positive = None
         if bernoulli_labels is not None:
             scale = float(bernoulli_labels["scale"])
@@ -253,6 +267,7 @@ class SparseShardedDataset:
             obj.shards[w] = SparseShard(
                 worker_id=w, cols=cols, vals=vals, y=yp,
                 start=obj.partition_cum[w], size=sizes[w],
+                live_width=live_width,
             )
         return obj
 
@@ -303,7 +318,8 @@ class SparseShardedDataset:
             lo, hi = self.partition_cum[w], self.partition_cum[w + 1]
             rows = self.row_perm[lo:hi]
             row_nnz = all_nnz[rows]
-            K = _round_up(int(row_nnz.max()) if len(row_nnz) else 1)
+            live_width = max(1, int(row_nnz.max())) if len(row_nnz) else 1
+            K = _round_up(live_width)
             size = hi - lo
             cols = np.zeros((size, K), np.int32)
             vals = np.zeros((size, K), np.float32)
@@ -318,6 +334,7 @@ class SparseShardedDataset:
                     np.cumsum(row_nnz) - row_nnz, row_nnz
                 )
                 src = np.repeat(indptr[rows], row_nnz) + slots
+                assert int(slots.max()) < live_width <= K, (live_width, K)
                 cols[dst_rows, slots] = indices[src]
                 vals[dst_rows, slots] = values[src]
             dev = devs[w % len(devs)]
@@ -328,6 +345,7 @@ class SparseShardedDataset:
                 y=jax.device_put(y[rows], dev),
                 start=lo,
                 size=size,
+                live_width=live_width,
             )
         # the guard only *suggests* nnz_partition when it is off; with it on,
         # residual padding is inherent (a dense row among light rows in the
@@ -383,6 +401,34 @@ class SparseShardedDataset:
 
     def partition_sizes(self) -> Dict[int, int]:
         return {w: s.size for w, s in self.shards.items()}
+
+    @property
+    def live_width(self) -> int:
+        """The most slots any row of any shard fills: ONE integer for the
+        dataset, so that shards of one shape compile one step (a shard
+        stored narrower than this is read whole)."""
+        return max(s.live_width for s in self.shards.values())
+
+    def checked_live_width(self) -> int:
+        """:attr:`live_width`, after one pass on the device over what lies
+        beyond it: a program built with it never reads those ELL columns,
+        so a value there would silently leave every product.  Refused
+        here, where a solver builds its steps from the dataset."""
+        import jax.numpy as jnp
+
+        live = self.live_width
+        beyond = {  # every shard's pass dispatched before one is read back
+            w: jnp.any(s.vals[:, live:] != 0)
+            for w, s in self.shards.items() if live < s.vals.shape[1]
+        }
+        bad = [w for w, found in beyond.items() if bool(found)]
+        if bad:
+            raise ValueError(
+                f"shards {bad} hold a value in an ELL column at or beyond "
+                f"the live width {live} the dataset records: a step built "
+                f"from it would drop that value"
+            )
+        return live
 
     def nnz(self) -> int:
         """True non-padding entries across all shards (for HBM accounting

@@ -20,6 +20,7 @@ the shard list a worker currently owns.
 
 from __future__ import annotations
 
+import dataclasses
 import threading
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence
@@ -101,15 +102,11 @@ class ShardRecovery:
             # (or host-bounce) copy; from the host copy it is a fresh upload.
             # Either way the result lives on the adopting worker's device.
             if hasattr(cur, "cols"):  # padded-ELL sparse shard
-                from asyncframework_tpu.data.sparse import SparseShard
-
-                moved = SparseShard(
-                    worker_id=shard_id,
+                moved = dataclasses.replace(
+                    cur,
                     cols=jax.device_put(cur.cols, target_dev),
                     vals=jax.device_put(cur.vals, target_dev),
                     y=jax.device_put(cur.y, target_dev),
-                    start=cur.start,
-                    size=cur.size,
                 )
             else:
                 moved = Shard(

@@ -277,12 +277,25 @@ SPARSE_GATHER_BLOCK_SLOTS = 327_680
 SPARSE_VMEM_BYTES = 128 * 2**20
 #: slots of the sample one block of the lane-row gather holds, and under
 #: which a sample keeps the element-wise one.  A block's gathered rows are
-#: 512 B a slot: 8 MB, which stay in VMEM beside the step's other tenants
-#: (the table is in HBM whatever the block).  On the v5e (PERF.md section
-#: 6, PR 37; 236,640 x 16 slots from d = 54,686,452, ms a pass alone, the
+#: 512 B a slot, which stay in VMEM beside the step's other tenants (the
+#: table is in HBM whatever the block).  On the v5e (PERF.md section 6,
+#: PR 37; 236,640 x 16 slots from d = 54,686,452, ms a pass alone, the
 #: element-wise gather 51.6): blocks of 1,024 rows 37.7, 8,192 rows 41.4,
-#: 65,536 rows 49.1.
-SPARSE_LANES_BLOCK_SLOTS = 16_384
+#: 65,536 rows 49.1.  Re-measured at kdd2012's LIVE width, 236,640 x 11
+#: slots, INSIDE the step (PERF.md section 6, PR 38; device ms a step, of
+#: which the gather loop; rows are :func:`_block_rows`' whole tiles of 128):
+#: 4,096 slots (256 rows) 54.68 / 15.28, **8,192 (640 rows) 54.09 / 14.69**,
+#: 16,384 (1,408 rows) 57.51 / 18.12, 32,768 (2,944 rows) 56.38 / 16.98;
+#: beside them 20,480 (1,792 rows) 54.10 / 14.70, 6,144 (512 rows) 57.51 /
+#: 18.12 and 12,288 (1,024 rows) 64.46 / 25.06.  The loop is two programs
+#: with a quirk each: the row gather takes 4.2 ns a slot, but 8.5 where a
+#: block's slot count is a multiple of 1,024 (every block of the stored
+#: width 16 is: the 9.1 ns PR 37 measured at all four of its sizes); the
+#: lane's pick takes 1.9 ms a step at 256, 640 and 1,024 rows and 5.2 to 5.9
+#: at 512, 1,408 and 2,944.  At the stored width 16 (what a caller that
+#: passes no live width runs) 8,192 slots read 93.1 ms a step against
+#: 88.9 at 16,384 in PR 37's sweep: the constant follows the cell's width.
+SPARSE_LANES_BLOCK_SLOTS = 8_192
 
 
 def sparse_gather_path(w, c_sel) -> str:
@@ -323,12 +336,24 @@ def _as_indexed(c: jax.Array, d: int) -> jax.Array:
     return jnp.clip(jnp.where(c < 0, c + d, c), 0, d - 1)
 
 
+def _block_rows(block_slots: int, width: int) -> int:
+    """Rows of a block of at most ``block_slots`` slots of a sample
+    ``width`` slots wide: whole tiles of 128 rows where it holds one (a
+    block is sliced from the sample along its rows, which the device
+    tiles by 128: at the stored widths 16 and 40 the constants divide so
+    anyway; at the live widths 11 and 39 a block of 1,489 or 8,402 rows
+    cost the step 10.7 and 3.3 ms over one of 1,408 or 8,320; PERF.md
+    section 6, PR 38)."""
+    rows = block_slots // width
+    return rows - rows % 128 if rows > 128 else rows
+
+
 def _margins_in_blocks(c_sel, v_sel, dtype, block_slots, block_margins):
     """``(rows,)`` margins of a packed sample, ``block_margins(cb, vb)`` a
     block of ``block_slots`` slots at a time (ONE block shape; the last
     is clamped and writes the rows it shares with the one before twice)."""
     n_rows, width = c_sel.shape
-    rows, blocks = row_blocks(n_rows, block_slots // width)
+    rows, blocks = row_blocks(n_rows, _block_rows(block_slots, width))
 
     def one_block(i, m):
         _, at = clamped_block(i, rows, n_rows)
@@ -435,7 +460,11 @@ def make_sparse_grad_sum(d: int):
     ``g = sum_i coeff_i * x_i`` -- the sparse analog of ``X.T @ coeff``:
     every slot's product ``vals * coeff`` is added into ``g`` at its column,
     in the order the slots are stored.  Padding slots add 0 to column 0 and
-    a column id outside ``[0, d)`` is dropped.
+    a column id outside ``[0, d)`` is dropped.  It scatter-adds EVERY slot
+    it is given, 6.9 ns each whatever the value, so the steps hand it the
+    sample at the shard's live width (``steps._live_columns``): the ELL
+    columns that are padding in every row never reach it, and the padding
+    left is that of rows shorter than the longest.
 
     Nothing puts the slots in order first.  On the v5e a sort is cheap and
     an element-wise gather or scatter is dear (PERF.md section 6, PR 33;

@@ -607,9 +607,10 @@ def _sized_by_capacity(step, batch_rate: float, d: int):
     """A compacted sparse step's size, for who asks the step it runs:
     ``step.task_rows(n_rows)``, the rows its compaction holds
     (:func:`_counts_rows`), and ``step.gather_path(n_rows, width)``, what
-    ``gradients.sparse_gather_path`` says of the sample it packs from an
-    ``(n_rows, width)`` shard against its ``(d,)`` float32 model: the
-    solvers' ``extras["sparse_gather_path"]``."""
+    ``gradients.sparse_gather_path`` says of the sample it packs from
+    ``n_rows`` rows read ``width`` slots wide (the shard's live width
+    where the step was built with one) against its ``(d,)`` float32
+    model: the solvers' ``extras["sparse_gather_path"]``."""
     def task_rows(n_rows):
         return sparse_step_capacity(batch_rate, n_rows)
 
@@ -657,11 +658,43 @@ def _sampled_rows(sub, batch_rate, n_rows: int, dtype):
     return idx, valid.astype(dtype)
 
 
+def _live_width(stored: int, live_width) -> int:
+    """The ELL columns a program built with ``live_width`` reads of a shard
+    stored ``stored`` wide (``None``: all of them)."""
+    return stored if live_width is None else min(stored, live_width)
+
+
+def _live_columns(cols, vals, live_width):
+    """``(cols, vals)`` of a stored padded-ELL shard ``(n_p, K)`` at its
+    LIVE width: the first ``live_width`` ELL columns; ``None``, or a shard
+    stored no wider, is the shard as it is.
+
+    ``data/sparse.py`` packs a row's values to the left, so the ELL columns
+    from the live width on hold ``col=0, val=0`` in every row: a product of
+    theirs is ``0.0 * w[0]`` added into ``g[0]``.  The v5e pays a gather, a
+    sort and a scatter-add by the SLOT, so a step over ``(capacity,
+    live_width)`` costs ``live_width / K`` of one over the stored width
+    (kdd2012: 11 of 16; PERF.md section 6, PR 38).  Call it INSIDE the jit
+    that reads the shard, and as a plain slice: the TPU stores the shard
+    rows minor (``{0,1}``), so the first ``live_width`` columns are a
+    contiguous prefix and the slice is a ``bitcast`` there, no copy
+    (``tests/test_step_layout.py`` holds that in the compiled step).  A
+    gather that names the prefix by its slice size instead (``(1,
+    live_width)`` of the ``(n_p, K)`` operand) is NOT the same program: the
+    compiler expands it into a loop of one ``dynamic-slice`` a sampled row
+    (compiled for a described v5e, PR 38)."""
+    if _live_width(cols.shape[1], live_width) == cols.shape[1]:
+        return cols, vals
+    return cols[:, :live_width], vals[:, :live_width]
+
+
 def _sparse_compacted_gradient(cols, vals, y, w, sub, batch_rate, grad_sum,
-                               loss="least_squares"):
+                               loss="least_squares", live_width=None):
     """Shared core of the compacted sparse step: Bernoulli(b) sample
     packed to static capacity (:func:`_sampled_rows`), only those rows
-    gathered and scatter-added, in the order they are stored; the rows'
+    gathered and scatter-added, in the order they are stored and at the
+    shard's live width (:func:`_live_columns`: every gather, sort and
+    scatter downstream sees ``(capacity, live_width)``); the rows'
     coefficient is ``m - y`` (least squares) or ``sigmoid(m) - y``
     (logistic) of the margin ``m = x . w``, f32 throughout.
     ONE definition, used by the engine worker step AND the fused rounds --
@@ -669,6 +702,7 @@ def _sparse_compacted_gradient(cols, vals, y, w, sub, batch_rate, grad_sum,
     bit-identical."""
     if loss not in ("least_squares", "logistic"):
         raise ValueError(f"unknown loss {loss!r}")
+    cols, vals = _live_columns(cols, vals, live_width)
     idx, valid = _sampled_rows(sub, batch_rate, y.shape[0], vals.dtype)
     with jax.named_scope("gather"):
         c_sel = cols[idx]
@@ -683,7 +717,8 @@ def _sparse_compacted_gradient(cols, vals, y, w, sub, batch_rate, grad_sum,
 
 
 def make_sparse_asgd_worker_step(batch_rate: float, d: int,
-                                 loss: str = "least_squares"):
+                                 loss: str = "least_squares",
+                                 live_width: "int | None" = None):
     """jit (cols, vals, y, w, key) -> (g_sum (d,), new_key).
 
     The sparse analog of :func:`make_asgd_worker_step` for padded-ELL shards
@@ -695,7 +730,12 @@ def make_sparse_asgd_worker_step(batch_rate: float, d: int,
     a scatter-added one (PERF.md section 6, PR 36: 11.6 ns a sampled
     slot in all, with the row gathers and the packing), so a step over all
     2,865,039 x 40 slots of a criteo shard would take 1.3 s where its
-    sampled twentieth takes 0.068.
+    sampled twentieth takes 0.068.  For the same reason the step reads the
+    shard at its LIVE width: ``live_width`` is the dataset's
+    (``SparseShardedDataset.live_width``; ``None`` = the stored width), and
+    the ELL columns beyond it, padding in every row, are never gathered,
+    sorted or scatter-added (:func:`_live_columns`; kdd2012: 11 of 16
+    slots a row, PERF.md section 6, PR 38).
     Instead the sampled row ids are packed into a
     static-capacity index vector (:func:`_pack_rows` -- static shapes,
     jit-stable), and only those rows' cols/vals are gathered and
@@ -711,7 +751,7 @@ def make_sparse_asgd_worker_step(batch_rate: float, d: int,
     def step(cols, vals, y, w, key):
         key, sub = jax.random.split(key)
         g = _sparse_compacted_gradient(
-            cols, vals, y, w, sub, batch_rate, grad_sum, loss
+            cols, vals, y, w, sub, batch_rate, grad_sum, loss, live_width
         )
         return g, key
 
@@ -719,14 +759,17 @@ def make_sparse_asgd_worker_step(batch_rate: float, d: int,
 
 
 def _sparse_saga_compacted(cols, vals, y, w, alpha, sub, batch_rate,
-                           grad_sum):
+                           grad_sum, live_width=None):
     """Shared core of the compacted sparse ASAGA worker computation
     (the ASGD core's sample, :func:`_sampled_rows`; gather, candidate
     scalars, history-corrected gradient).
     ONE definition, used by the engine worker step AND the fused rounds --
     the fused path's sampling-parity claim depends on these staying
-    bit-identical (same discipline as :func:`_sparse_compacted_gradient`).
+    bit-identical (same discipline as :func:`_sparse_compacted_gradient`,
+    and the same live width: ``c_sel`` / ``v_sel`` come back
+    ``(capacity, live_width)``).
     """
+    cols, vals = _live_columns(cols, vals, live_width)
     idx, valid = _sampled_rows(sub, batch_rate, y.shape[0], vals.dtype)
     with jax.named_scope("gather"):
         c_sel = cols[idx]
@@ -750,7 +793,8 @@ def _sparse_saga_commit_expr(alpha, diff_sel, idx, valid):
     return alpha.at[tgt].set(diff_sel, indices_are_sorted=True, mode="drop")
 
 
-def make_sparse_saga_worker_step(batch_rate: float, d: int):
+def make_sparse_saga_worker_step(batch_rate: float, d: int,
+                                 live_width: "int | None" = None):
     """jit (cols, vals, y, w, alpha, key) ->
     (g, diff_sel, idx, valid, c_sel, v_sel, new_key) -- COMPACTED.
 
@@ -759,8 +803,9 @@ def make_sparse_saga_worker_step(batch_rate: float, d: int):
     index vector and only those rows' cols/vals/history are touched (~b of
     the full-shard gather/scatter volume).  ``diff_sel`` are the candidate
     history scalars FOR THE SELECTED ROWS; ``idx``/``valid`` say where they
-    go; ``c_sel``/``v_sel`` (validity-zeroed) ride along so the updater's
-    exact table delta needs no second row gather.
+    go; ``c_sel``/``v_sel`` (validity-zeroed, ``(capacity, live_width)``:
+    the shard is read at its live width as the ASGD step reads it) ride
+    along so the updater's exact table delta needs no second row gather.
     """
     from asyncframework_tpu.ops.gradients import make_sparse_grad_sum
 
@@ -770,7 +815,7 @@ def make_sparse_saga_worker_step(batch_rate: float, d: int):
     def step(cols, vals, y, w, alpha, key):
         key, sub = jax.random.split(key)
         g, diff_sel, idx, valid, c_sel, v_sel = _sparse_saga_compacted(
-            cols, vals, y, w, alpha, sub, batch_rate, grad_sum
+            cols, vals, y, w, alpha, sub, batch_rate, grad_sum, live_width
         )
         return g, diff_sel, idx, valid, c_sel, v_sel, key
 
@@ -794,7 +839,9 @@ def make_sparse_table_delta(d: int):
     The compacted analog of :func:`make_saga_table_delta`: the change the
     commit makes to the mean history gradient, computed against the CURRENT
     table slice (``alpha_cur[idx]``) at commit time -- see the dense
-    variant's docstring for why dispatch-time history drifts.
+    variant's docstring for why dispatch-time history drifts.  ``c_sel``
+    and ``v_sel`` are the step's own sample, ``(capacity, live_width)``
+    already: the delta scatter-adds as many slots as the step did.
     """
     from asyncframework_tpu.ops.gradients import make_sparse_grad_sum
 
@@ -837,9 +884,13 @@ SPARSE_EVAL_BLOCK_ROWS = 65_536
 SPARSE_EVAL_SNAPSHOTS = 8
 
 
-def make_sparse_trajectory_loss_eval(loss: str = "least_squares"):
+def make_sparse_trajectory_loss_eval(loss: str = "least_squares",
+                                     live_width: "int | None" = None):
     """jit (cols, vals, y, W (S,d)) -> (S,) per-snapshot loss sums, in ONE
-    pass over the shard in row blocks.
+    pass over the shard in row blocks, at the shard's live width
+    (``live_width``: the dataset's, ``None`` = the stored width; a block
+    is ``(rows, live_width)`` of the shard, so the ELL columns that hold
+    padding in every row are not gathered: :func:`_live_columns`).
 
     Per block of ``SPARSE_EVAL_BLOCK_ROWS`` rows, ``W[:, cols_block]`` is
     gathered once for up to ``SPARSE_EVAL_SNAPSHOTS`` snapshots (more are
@@ -855,7 +906,9 @@ def make_sparse_trajectory_loss_eval(loss: str = "least_squares"):
 
     ``eval_shard.snapshots_per_call``: what the engine stacks a call's
     ``W`` to, so that one executable serves every trajectory length;
-    ``eval_shard.blocks(n_rows)``: the row blocks of one call.
+    ``eval_shard.blocks(n_rows)``: the row blocks of one call;
+    ``eval_shard.width(K)``: the ELL columns it reads of a shard stored
+    ``K`` wide.
     """
     if loss not in ("least_squares", "logistic"):
         raise ValueError(f"unknown loss {loss!r}")
@@ -867,10 +920,14 @@ def make_sparse_trajectory_loss_eval(loss: str = "least_squares"):
     def blocks(n_rows):
         return row_blocks(n_rows, SPARSE_EVAL_BLOCK_ROWS)[1]
 
+    def width(stored):
+        return _live_width(stored, live_width)
+
     @jax.jit
     def eval_shard(cols, vals, y, W):
         n_rows, n_snap = y.shape[0], W.shape[0]
         rows = block_rows(n_rows)
+        cols, vals = _live_columns(cols, vals, live_width)
 
         def one_block(i, acc):
             start, at = clamped_block(i, rows, n_rows)
@@ -898,6 +955,7 @@ def make_sparse_trajectory_loss_eval(loss: str = "least_squares"):
     eval_shard.snapshots_per_call = tile
     eval_shard.blocks = blocks
     eval_shard.block_rows = block_rows
+    eval_shard.width = width
     return eval_shard
 
 
@@ -909,6 +967,7 @@ def make_fused_asgd_rounds(
     loss: str = "least_squares",
     rounds_per_call: int = 16,
     sparse_d: "int | None" = None,
+    live_width: "int | None" = None,
 ):
     """jit (w, k, keys (nw,2)) -> (w', k', keys', W_snap (R, d)) -- R full
     cohort rounds with ZERO host involvement (the device-resident accept
@@ -930,6 +989,8 @@ def make_fused_asgd_rounds(
     (cols, vals, y) padded-ELL -- device arrays, all resident on the SAME
     device (the PS chip); per-worker PRNG chains ride in ``keys`` exactly
     as the engine keeps them, so sampling parity per worker is preserved.
+    ``live_width``: the width the sparse shards are read at, as
+    :func:`make_sparse_asgd_worker_step` takes it.
     """
     grad_sum = _grad_sum_for(loss)
     nw = len(shards)
@@ -951,7 +1012,8 @@ def make_fused_asgd_rounds(
             key, sub = jax.random.split(key)
             cols, vals, y = shard
             g = _sparse_compacted_gradient(
-                cols, vals, y, w, sub, batch_rate, sp_grad_sum
+                cols, vals, y, w, sub, batch_rate, sp_grad_sum,
+                live_width=live_width,
             )
             return g, key
         X, y = shard
@@ -989,6 +1051,7 @@ def make_fused_saga_rounds(
     shards,
     rounds_per_call: int = 16,
     sparse_d: "int | None" = None,
+    live_width: "int | None" = None,
 ):
     """jit (w, ab, alphas, keys) -> (w', ab', alphas', keys', W_snap) --
     R full ASAGA cohort rounds fused on one device (the ASAGA face of the
@@ -1010,7 +1073,8 @@ def make_fused_saga_rounds(
     worker computation mirrors the engine's compacted sparse SAGA step
     (sampled rows gathered; candidate scalars committed by a scatter
     whose padding slots drop out of bounds; see
-    make_sparse_saga_worker_step / make_sparse_saga_commit).
+    make_sparse_saga_worker_step / make_sparse_saga_commit), at the same
+    ``live_width``.
     """
     nw = len(shards)
     par_recs = batch_rate * n / nw
@@ -1025,7 +1089,8 @@ def make_fused_saga_rounds(
         cols, vals, y = shard
         key, sub = jax.random.split(key)
         g, diff_sel, idx, valid, _c, _v = _sparse_saga_compacted(
-            cols, vals, y, w, alpha, sub, batch_rate, sp_grad_sum
+            cols, vals, y, w, alpha, sub, batch_rate, sp_grad_sum,
+            live_width,
         )
         alpha2 = _sparse_saga_commit_expr(alpha, diff_sel, idx, valid)
         return g, alpha2, key

@@ -86,15 +86,20 @@ class ASAGA(EngineSolver):
         self.ds = resolve_dataset(X, y, config.num_workers, self.devices)
         self.driver_device = self.devices[0]
         self._sparse = bool(getattr(self.ds, "is_sparse", False))
+        # the sparse programs read a shard at the dataset's live width
+        self._live_width = live = (
+            self.ds.checked_live_width() if self._sparse else None)
         if self._sparse:
             self._step = steps.make_sparse_saga_worker_step(
-                config.batch_rate, self.ds.d
+                config.batch_rate, self.ds.d, live_width=live
             )
             self._commit = steps.make_sparse_saga_commit()
             self._table_delta = steps.make_sparse_table_delta(self.ds.d)
             # X^T alpha over a whole padded-ELL shard (_history_drift)
             self._table_mean_grad = make_sparse_grad_sum(self.ds.d)
-            self._eval = steps.make_sparse_trajectory_loss_eval()
+            self._eval = steps.make_sparse_trajectory_loss_eval(
+                live_width=live
+            )
         else:
             self._step = steps.make_saga_worker_step(config.batch_rate)
             self._table_delta = steps.make_saga_table_delta()
@@ -105,11 +110,12 @@ class ASAGA(EngineSolver):
         # step's products (every shard has one width and dtype, so shard 0
         # speaks for all)
         if self._sparse:
+            live = min(int(self.ds.shard(0).cols.shape[1]), live)
             self._path_extras = {
+                "sparse_live_width": live,
                 "sparse_gather_path": self._step.gather_path(
-                    max(self.ds.partition_sizes().values()),
-                    int(self.ds.shard(0).cols.shape[1]),
-                )
+                    max(self.ds.partition_sizes().values()), live
+                ),
             }
         else:
             self._path_extras = {
@@ -373,6 +379,7 @@ class ASAGA(EngineSolver):
             rr = steps.make_fused_saga_rounds(
                 cfg.gamma, cfg.batch_rate, self.ds.n, shards,
                 rounds_per_call=length, sparse_d=sparse_d,
+                live_width=self._live_width,
             )
 
             def run(carry):

@@ -65,11 +65,16 @@ class ASGD(EngineSolver):
         self.ds = resolve_dataset(X, y, config.num_workers, self.devices)
         self.driver_device = self.devices[0]
         self._sparse = bool(getattr(self.ds, "is_sparse", False))
+        # the sparse programs read a shard at the dataset's live width
+        self._live_width = live = (
+            self.ds.checked_live_width() if self._sparse else None)
         if self._sparse:
             self._step = steps.make_sparse_asgd_worker_step(
-                config.batch_rate, self.ds.d, config.loss
+                config.batch_rate, self.ds.d, config.loss, live_width=live
             )
-            self._eval = steps.make_sparse_trajectory_loss_eval(config.loss)
+            self._eval = steps.make_sparse_trajectory_loss_eval(
+                config.loss, live_width=live
+            )
         else:
             self._step = steps.make_asgd_worker_step(
                 config.batch_rate, config.loss
@@ -77,18 +82,23 @@ class ASGD(EngineSolver):
             self._eval = steps.make_trajectory_loss_eval(config.loss)
         self._task_rows = self._step.task_rows  # flop accounting
         # for every result's extras.  A sparse step's size: the rows its
-        # compaction holds and the slots it gathers and scatter-adds
-        # (capacity x ELL width), on the largest shard, and which program
-        # gathers the model there.  Which program a dense step is here:
-        # every shard has one width and dtype, so shard 0 speaks for all
+        # compaction holds, the slots of them as they are STORED (capacity
+        # x ELL width) and the slots it gathers and scatter-adds (capacity
+        # x live width: equal where nothing was left out), on the largest
+        # shard, and which program gathers the model there.  Which program
+        # a dense step is here: every shard has one width and dtype, so
+        # shard 0 speaks for all
         if self._sparse:
             rows = max(self.ds.partition_sizes().values())
             width = int(self.ds.shard(0).cols.shape[1])
+            live = min(width, live)
             capacity = self._task_rows(rows)
             self._path_extras = {
                 "sparse_step_capacity": capacity,
                 "sampled_slots_per_step": capacity * width,
-                "sparse_gather_path": self._step.gather_path(rows, width),
+                "sparse_live_width": live,
+                "live_slots_per_step": capacity * live,
+                "sparse_gather_path": self._step.gather_path(rows, live),
             }
         else:
             self._path_extras = {
@@ -318,6 +328,7 @@ class ASGD(EngineSolver):
             rr = steps.make_fused_asgd_rounds(
                 cfg.gamma, cfg.batch_rate, self.ds.n, shards,
                 loss=cfg.loss, rounds_per_call=length, sparse_d=sparse_d,
+                live_width=self._live_width,
             )
 
             def run(carry):

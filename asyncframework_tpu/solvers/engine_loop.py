@@ -133,12 +133,15 @@ class EngineSolver(FlopsAccountingMixin):
         block a call; a dense shard is one block); ``eval_snapshots``;
         ``eval_calls`` and ``eval_stack_rows``, the stacks built and the
         model-sized rows of one; and for padded ELL ``eval_slots``, the
-        slots those gathers picked (a clamped last block counted whole)."""
+        slots of the blocks those gathers walked as they are STORED (a
+        clamped last block counted whole), and ``eval_live_slots``, the
+        slots they picked: the blocks at the width the evaluation reads
+        (equal where it was built at the stored width)."""
         t0 = time.perf_counter()
         handles = [h for (_t, h) in snapshots]
         per_call = getattr(self._eval, "snapshots_per_call", len(handles))
         calls = -(-len(handles) // per_call)
-        blocks = slots = 0
+        blocks = slots = live_slots = 0
         totals = np.zeros(calls * per_call, np.float64)
         with trace.span(trace.TRAJECTORY_EVAL, ut, batch=len(handles),
                         calls=calls):
@@ -153,8 +156,10 @@ class EngineSolver(FlopsAccountingMixin):
                     if self._sparse:
                         arrays = (shard.cols, shard.vals, shard.y)
                         n_blocks = self._eval.blocks(shard.size)
-                        slots += (n_blocks * self._eval.block_rows(shard.size)
-                                  * shard.cols.shape[1])
+                        walked = n_blocks * self._eval.block_rows(shard.size)
+                        slots += walked * shard.cols.shape[1]
+                        live_slots += walked * self._eval.width(
+                            shard.cols.shape[1])
                     else:
                         arrays = (shard.X, shard.y)
                         n_blocks = 1
@@ -178,6 +183,7 @@ class EngineSolver(FlopsAccountingMixin):
             )
             if self._sparse:
                 counters["eval_slots"] = slots
+                counters["eval_live_slots"] = live_slots
         # continuous telemetry: the finished run's loss-vs-wallclock curve
         # lands in the process-global convergence history (the /api/status
         # `convergence` section the in-process live UI serves)

@@ -166,9 +166,9 @@ def _spec(shape, dtype):
         # a 219 MB model, which no form of keeps in VMEM (ISSUE 37)
         (True, ((54_686_452,), jnp.float32), (236_640, 16), "lanes128"),
         (False, ((54_686_452,), jnp.float32), (236_640, 16), "elements"),
-        # one whole block of 16,384 slots of THAT form, and one row under
-        (True, ((54_686_452,), jnp.float32), (1_024, 16), "lanes128"),
-        (True, ((54_686_452,), jnp.float32), (1_023, 16), "elements"),
+        # one whole block of 8,192 slots of THAT form, and one row under
+        (True, ((54_686_452,), jnp.float32), (512, 16), "lanes128"),
+        (True, ((54_686_452,), jnp.float32), (511, 16), "elements"),
         # over VMEM an eight-row view is no help: 40M columns, 160 MB
         (True, ((40_000_000,), jnp.float32), (236_640, 16), "lanes128"),
         # 128 MiB to the byte is not over it
@@ -357,8 +357,9 @@ def test_an_engine_run_says_which_gather_it_ran(solver):
 def test_the_record_is_the_choosers_answer_for_the_steps_own_shapes(
         monkeypatch):
     """What the solver records is what the chooser says of the arrays the
-    step will hand it: the packed capacity x the ELL width, the model's
-    ``(d,)`` float32."""
+    step will hand it: the packed capacity x the LIVE width (12 of the 16
+    slots a row is stored in, since ISSUE 38), the model's ``(d,)``
+    float32."""
     asked = []
     real = gradients.sparse_gather_path
 
@@ -371,7 +372,7 @@ def test_the_record_is_the_choosers_answer_for_the_steps_own_shapes(
         4_099, 512, 12, 4, jax.devices()[:1], seed=7, noise=0.01)
     engine = ASGD(ds, None, _cfg(), devices=jax.devices()[:1])
     cap = steps.sparse_step_capacity(0.3, 1025)
-    assert asked == [((512,), jnp.float32, (cap, 16))]
+    assert asked == [((512,), jnp.float32, (cap, 12))]
     assert engine._path_extras["sparse_gather_path"] == "rows8"
 
 

@@ -260,13 +260,14 @@ def _ell_specs(one_chip):
 
 
 def _makes_a_whole_shard(text):
-    """Instructions (fused bodies included) whose RESULT is as large as the
-    shard, other than the parameters and the loop's plumbing."""
-    shard = f"[{ELL_ROWS},{ELL_WIDTH}]"
+    """Instructions (fused bodies included) whose RESULT is as tall as the
+    shard, at its stored width or a narrower one (its live width), other
+    than the parameters and the loop's plumbing."""
+    shard = re.compile(r"\[%d,\d+\]" % ELL_ROWS)
     return [
         (name, op) for name, t, op, _ in _instructions(text)
-        if shard in t and op not in ("parameter", "get-tuple-element",
-                                     "tuple", "while", "bitcast")
+        if shard.search(t) and op not in ("parameter", "get-tuple-element",
+                                          "tuple", "while", "bitcast")
     ]
 
 
@@ -401,8 +402,10 @@ def test_sparse_margins_gather_eight_model_values_an_index(
     assert temp < TEMP_BOUND, f"{temp} bytes of temporaries"
 
 
+@pytest.mark.parametrize("live_width", [None, 39],
+                         ids=["stored-40", "live-39"])
 def test_sparse_step_moves_no_slot_to_put_it_in_order(
-    one_chip, no_compile_cache, on_tpu
+    one_chip, no_compile_cache, on_tpu, live_width
 ):
     """The sparse ASGD step at the criteo cell's shard (``b`` 0.05, the
     logistic link; ISSUE 33).  FORM A was kept: the scatter-add takes the
@@ -413,14 +416,21 @@ def test_sparse_step_moves_no_slot_to_put_it_in_order(
     sampled row ids: ONE operand, the 2,865,039 row keys, where
     ``jnp.nonzero`` scattered as many ones.  Since ISSUE 36 the model is
     gathered eight values an index (``gradients.sparse_margins``): no
-    single-element gather of ``w`` is left in the step."""
+    single-element gather of ``w`` is left in the step.  Since ISSUE 38
+    the cell's step is built at the shard's live width, 39 of the 40
+    stored slots: the same program over 5,673,408 slots in blocks of 8,320
+    rows (whole tiles of 128), the ``(8, d / 8)`` table still in VMEM, and
+    of the shard's height nothing but a ``bitcast`` of its first 39
+    columns; ``stored-40`` is what ``live_width=None`` keeps building."""
     (cols, vals, y), spec = _ell_specs(one_chip)
     batch_rate = 0.05
-    step = steps.make_sparse_asgd_worker_step(batch_rate, ELL_D, "logistic")
+    step = steps.make_sparse_asgd_worker_step(
+        batch_rate, ELL_D, "logistic", live_width=live_width)
     cap = steps.sparse_step_capacity(batch_rate, ELL_ROWS)
-    slots = cap * ELL_WIDTH
-    assert (cap, slots) == (145_472, 5_818_880)
-    assert step.gather_path(ELL_ROWS, ELL_WIDTH) == "rows8"
+    width = live_width or ELL_WIDTH
+    slots = cap * width
+    assert (cap, slots) == (145_472, {40: 5_818_880, 39: 5_673_408}[width])
+    assert step.gather_path(ELL_ROWS, width) == "rows8"
     compiled = step.lower(cols, vals, y, spec((ELL_D,), jnp.float32),
                           spec((2,), jnp.uint32)).compile()
     text = compiled.as_text()
@@ -435,18 +445,24 @@ def test_sparse_step_moves_no_slot_to_put_it_in_order(
     # vals, their labels, and a block of the model's eight-row table; none
     # makes a flat [slots] array (``flat[order]``, ``contrib[order]``) and
     # none takes the model one element an index
-    rows = gradients.SPARSE_GATHER_BLOCK_SLOTS // ELL_WIDTH
+    rows = gradients._block_rows(gradients.SPARSE_GATHER_BLOCK_SLOTS, width)
+    assert rows == {40: 8_192, 39: 8_320}[width]
     gathers = sorted(t.split("{")[0] for _n, t, op, _ in instrs
                      if op == "gather")
     assert gathers == sorted([
-        f"s32[{cap},{ELL_WIDTH}]", f"f32[{cap},{ELL_WIDTH}]",
-        f"f32[8,{ELL_WIDTH},{rows}]", f"f32[{cap}]"]), gathers
+        f"s32[{cap},{width}]", f"f32[{cap},{width}]",
+        f"f32[8,{width},{rows}]", f"f32[{cap}]"]), gathers
     assert _model_gathers(text) == [
-        (f"f32[8,{ELL_WIDTH},{rows}]", "8,1")], _model_gathers(text)
+        (f"f32[8,{width},{rows}]", "8,1")], _model_gathers(text)
     _table_stays_in_vmem(text)
     scatters = [ln for ln in text.splitlines() if " scatter(" in ln]
     assert len(scatters) == 1 and f" f32[{ELL_D}]" in scatters[0], scatters
     assert "indices_are_sorted=true" not in scatters[0]
+    if live_width:  # the first 39 ELL columns, where they lie
+        views = [t for _n, t, op, _ in _instructions(text[text.index("ENTRY"):])
+                 if op == "bitcast" and f"[{ELL_ROWS},{width}]" in t]
+        assert len(views) == 2, views
+        assert f"[{cap * ELL_WIDTH}]" not in text
     # the gathered rows, their products and the keys' scratch (122.7 MB
     # until PR 36) and one block of the model's gathered rows
     temp = compiled.memory_analysis().temp_size_in_bytes
@@ -466,8 +482,9 @@ def test_sparse_margins_gather_a_lane_row_an_index_from_a_model_over_vmem(
     no form of keeps in the v5e's VMEM.  The chooser says ``lanes128``, and
     the program compiled for the described v5e gathers the model ONLY as
     whole rows of 128 lanes (512 B that lie together in HBM), a block of
-    ``SPARSE_LANES_BLOCK_SLOTS`` slots (1,024 rows) at a time; the model's
-    one padded copy and a block's rows are all it keeps."""
+    ``SPARSE_LANES_BLOCK_SLOTS`` slots at a time (512 rows of the stored
+    width 16 since ISSUE 38 sized the block at the live width 11); the
+    model's one padded copy and a block's rows are all it keeps."""
     def spec(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
@@ -481,8 +498,9 @@ def test_sparse_margins_gather_a_lane_row_an_index_from_a_model_over_vmem(
     compiled = jax.jit(gradients.sparse_margins).lower(
         c_sel, v_sel, w).compile()
     text = compiled.as_text()
-    rows = gradients.SPARSE_LANES_BLOCK_SLOTS // WIDE_WIDTH
-    assert rows == 1_024
+    rows = gradients._block_rows(gradients.SPARSE_LANES_BLOCK_SLOTS,
+                                 WIDE_WIDTH)
+    assert rows == 512
     gathers = [(m.group(1).split("{")[0],
                 re.search(r"slice_sizes=\{([\d,]+)\}", ln).group(1))
                for ln in text.splitlines()
@@ -493,3 +511,71 @@ def test_sparse_margins_gather_a_lane_row_an_index_from_a_model_over_vmem(
     temp = compiled.memory_analysis().temp_size_in_bytes
     # the padded model (219 MB) and one block's gathered rows (8 MB)
     assert temp < 4 * WIDE_D + 64e6, f"{temp} bytes of temporaries"
+
+
+@pytest.mark.parametrize("program", ["step", "evaluation"])
+def test_wide_sparse_programs_read_the_shard_at_its_live_width(
+    one_chip, no_compile_cache, on_tpu, program
+):
+    """kdd2012's shard is stored ``(4,676,222, 16)`` and holds 11 values a
+    row (ISSUE 38): the programs built with ``live_width=11`` take the
+    first 11 ELL columns of the stored arrays, which is a ``bitcast`` of a
+    shard stored rows minor and nothing else: no copy, slice or fusion
+    makes an array as tall as the shard.  The step then gathers ``(236,640,
+    11)`` columns and values, reads the model a lane row an index in
+    blocks of live slots, and sorts and scatter-adds 2,603,040 pairs where
+    the stored width made 3,786,240; the evaluation gathers ``(8, 11,
+    65,536)`` a block."""
+    def spec(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    live = 11
+    cols, vals, y = (spec((WIDE_ROWS, WIDE_WIDTH), jnp.int32),
+                     spec((WIDE_ROWS, WIDE_WIDTH), jnp.float32),
+                     spec((WIDE_ROWS,), jnp.float32))
+    cap = steps.sparse_step_capacity(0.05, WIDE_ROWS)
+    if program == "step":
+        step = steps.make_sparse_asgd_worker_step(
+            0.05, WIDE_D, "logistic", live_width=live)
+        assert step.gather_path(WIDE_ROWS, live) == "lanes128"
+        compiled = step.lower(cols, vals, y, spec((WIDE_D,), jnp.float32),
+                              spec((2,), jnp.uint32)).compile()
+    else:
+        ev = steps.make_sparse_trajectory_loss_eval("logistic",
+                                                    live_width=live)
+        compiled = ev.lower(cols, vals, y,
+                            spec((8, WIDE_D), jnp.float32)).compile()
+    text = compiled.as_text()
+    instrs = _instructions(text)
+    entry = _instructions(text[text.index("ENTRY"):])
+    stored = [t for _n, t, op, _ in entry
+              if op == "parameter" and f"[{WIDE_ROWS},{WIDE_WIDTH}]" in t]
+    assert len(stored) == 2
+    for t in stored:  # rows minor: the first 11 columns lie together
+        assert re.search(r"\[\d+,\d+\]\{0,1", t), t
+    tall = [(name, op) for name, t, op, _ in instrs
+            if re.match(r"[a-z0-9]+\[%d,\d+\]" % WIDE_ROWS, t)
+            and op not in ("parameter", "get-tuple-element", "bitcast")]
+    assert not tall, tall
+    views = [t for _n, t, op, _ in entry
+             if op == "bitcast" and f"[{WIDE_ROWS},{live}]" in t]
+    assert len(views) == 2, views
+    gathers = sorted(t.split("{")[0] for _n, t, op, _ in instrs
+                     if op == "gather")
+    if program == "step":
+        rows = gradients._block_rows(gradients.SPARSE_LANES_BLOCK_SLOTS, live)
+        assert rows % 128 == 0  # whole lane tiles of the sample's rows
+        assert gathers == sorted([
+            f"s32[{cap},{live}]", f"f32[{cap},{live}]", f"f32[{cap}]",
+            f"f32[{rows},{live},128]"]), gathers
+        slots = cap * live
+        assert slots == 2_603_040
+        scatters = [ln for ln in text.splitlines() if " scatter(" in ln]
+        assert len(scatters) == 1 and f" f32[{WIDE_D}]" in scatters[0]
+        pair_sorts = [t for _n, t, op, _ in instrs
+                      if op == "sort" and t.startswith("(")]
+        assert len(pair_sorts) == 1 and f"s32[{slots}]" in pair_sorts[0]
+        assert f"[{cap * WIDE_WIDTH}]" not in text
+    else:
+        assert gathers == [f"f32[8,{live},65536]"], gathers
+        assert compiled.memory_analysis().temp_size_in_bytes < 64e6
