@@ -28,10 +28,11 @@ longest row of the set.  A shard stays ONE ``(rows, K)`` pair of arrays, and
 lane tiles of 128 (:func:`_round_up`): the device stores such a shard
 row-major, a sampled row is one contiguous read, and the programs read it
 whole (``SparseShardedDataset.live_widths``: by stored width, what a
-program reads).  ASGD builds its step and its evaluation once for every
-shard SHAPE; the programs that are built for ONE shape (ASAGA's sparse step,
-``ps_dcn``'s steps) keep the dataset's ONE integer and read a narrower shard
-whole too.
+program reads), the ASGD step its packed sample up to each row tile's last
+non-zero (``ops.gradients.walk_tile``).  ASGD builds its step and its
+evaluation once for every shard SHAPE; the programs that are built for ONE
+shape (ASAGA's sparse step, ``ps_dcn``'s steps) keep the dataset's ONE
+integer and read a narrower shard whole too.
 The worker step then needs no dynamic shapes:
 
 - residual: ``r_i = sum_k vals[i,k] * w[cols[i,k]] - y_i``  (gather + reduce)
@@ -158,6 +159,10 @@ class SparseShard:
     #: the slots its rows fill, from the same integers: what the shard
     #: holds of the data (``rows * K`` is what it holds of the device)
     nnz: int
+    #: the slots EACH row fills, the same integers on the host (``None``:
+    #: every row fills ``live_width``): what a step that stops at a row
+    #: tile's last non-zero walks (``steps.sparse_walked_slots``)
+    row_lengths: Optional[np.ndarray] = None
 
     @property
     def device(self):
@@ -392,6 +397,7 @@ class SparseShardedDataset:
                 live_width=live_widths[w],
                 nnz=(sizes[w] * live_widths[w] if lengths is None
                      else int(lengths[w].sum())),
+                row_lengths=None if lengths is None else lengths[w],
             )
         return obj
 
@@ -474,6 +480,7 @@ class SparseShardedDataset:
                 size=size,
                 live_width=live_width,
                 nnz=total,
+                row_lengths=row_nnz,
             )
         # the guard only *suggests* nnz_partition when it is off; with it on,
         # residual padding is inherent (a dense row among light rows in the

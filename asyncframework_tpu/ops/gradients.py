@@ -38,6 +38,7 @@ product, one read) stay XLA's.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Tuple
 
@@ -397,6 +398,20 @@ def _gather_rows8(table: jax.Array, c: jax.Array) -> jax.Array:
     return jnp.where((row & 1) > 0, x[1], x[0])
 
 
+def _model_gather(w, path):
+    """``c -> w[c]`` for a block of columns of any shape, in the form
+    ``path`` names (:func:`sparse_gather_path`)."""
+    d = w.shape[0]
+    if path == "rows8":
+        table = w.reshape(8, d // 8)
+        return lambda c: _gather_rows8(table, c)
+    if path == "lanes128":
+        q = -(-d // 128)
+        table = jnp.pad(w, (0, q * 128 - d)).reshape(q, 128)
+        return lambda c: _gather_lanes128(table, c, d)
+    return lambda c: w[c]
+
+
 def _margins_elements(c_sel, v_sel, w):
     """One model value an index: the expression every step held."""
     return jnp.sum(v_sel * w[c_sel], axis=1)
@@ -407,11 +422,11 @@ def _margins_rows8(c_sel, v_sel, w):
     minor as a narrow ``c_sel`` is stored (its transposed block is a
     ``bitcast``): per block one eight-wide gather, the select, the
     multiply and the sum over the slots."""
-    table = w.reshape(8, w.shape[0] // 8)
+    gather = _model_gather(w, "rows8")
     return _margins_in_blocks(
         c_sel, v_sel, jnp.result_type(v_sel.dtype, w.dtype),
         SPARSE_GATHER_BLOCK_SLOTS,
-        lambda cb, vb: jnp.sum(vb.T * _gather_rows8(table, cb.T), axis=0),
+        lambda cb, vb: jnp.sum(vb.T * gather(cb.T), axis=0),
     )
 
 
@@ -435,26 +450,183 @@ def _margins_lanes128(c_sel, v_sel, w):
     128, 128)`` lane rows (one padded copy of ``w`` a call: 0.5 ms at 219
     MB): per block one row gather, the lane's pick, the multiply and the
     sum over the slots."""
-    d = w.shape[0]
-    q = -(-d // 128)
-    table = jnp.pad(w, (0, q * 128 - d)).reshape(q, 128)
+    gather = _model_gather(w, "lanes128")
     return _margins_in_blocks(
         c_sel, v_sel, jnp.result_type(v_sel.dtype, w.dtype),
         SPARSE_LANES_BLOCK_SLOTS,
-        lambda cb, vb: jnp.sum(vb * _gather_lanes128(table, cb, d), axis=1),
+        lambda cb, vb: jnp.sum(vb * gather(cb), axis=1),
     )
 
 
-def sparse_margins(c_sel: jax.Array, v_sel: jax.Array, w: jax.Array):
+#: ``(R, C)``, the block of the ragged walk (:func:`walk_tile`) where the
+#: step's ``(d,)`` accumulator stays in VMEM through the scatter-add's
+#: loops, and where it lies in HBM (:func:`walk_accumulator_resident`).  On
+#: the v5e (PERF.md section 6, PR 40; webspam's packed samples of 992 rows
+#: from shards 1,664 to 16,384 slots wide, ``d`` 16,609,143, fenced): the
+#: compiler sorts a block's (column, product) pairs in front of its
+#: scatter-add from 16,384 slots a block on and not below, and the
+#: UNSORTED scatter-add costs 68 to 73 ns a slot (blocks of 2,048 to 8,192
+#: slots; the one-shot form behind its sort of all pairs 10.1 to 10.4).  A
+#: block of 16,384 slots pays 8.9 to 9.8 ns a slot with the accumulator in
+#: VMEM (16 x 1,024, 32 x 512, 64 x 256 alike), 13.8 to 14.6 at 32,768
+#: slots, 10.5 to 12.7 from 65,536 on, where the accumulator is in HBM
+#: whatever the shard; and with the accumulator in HBM the 16,384-slot
+#: block pays about 19 (the narrowest shard's step 25.6 to 27.5 ms against
+#: 20.1 at 128 x 512 and 21.2 unwalked).  The lane-row gather costs 2.2 to
+#: 3.2 ns a slot at every block.  A chunk of 256 slots walks 1.05 to 1.10
+#: times a sample's non-zeros, one of 512 1.07 to 1.16; 64 x 256 and 32 x
+#: 512 take the same time a step from 3,840 slots a row on, and 64 x 256
+#: 7% less at 2,176.
+SPARSE_WALK_TILE = (64, 256)
+SPARSE_WALK_TILE_HBM = (128, 512)
+
+
+def walk_accumulator_resident(d: int, shard_rows: int, width: int) -> bool:
+    """Whether the compiler keeps the walked step's ``(d,)`` float32
+    accumulator in VMEM through the scatter-add's loops, from the two
+    things seen to decide it in the programs compiled for the v5e
+    (``tests/test_step_layout.py`` holds both): it fits beside a block's
+    operands (webspam's 66 MB does; half the VMEM is taken as the bound,
+    nothing larger is measured), and the shard's own arrays do NOT fit
+    VMEM: one that does (``shard_rows x width`` x 4 bytes under 128 MiB:
+    webspam's narrowest, 109 MB) is prefetched there across programs, for
+    the row gathers, and takes the accumulator's place."""
+    return (4 * d <= SPARSE_VMEM_BYTES // 2
+            and 4 * shard_rows * width > SPARSE_VMEM_BYTES)
+
+
+def walk_tile(n_rows: int, width: int,
+              resident: bool = True) -> "Tuple[int, int] | None":
+    """``(R, C)``, the block a packed sample of ``n_rows`` rows read
+    ``width`` slots wide is WALKED in, or ``None`` where it is read whole:
+    the ONE place the walk is chosen, from the sample's shape.
+
+    A shard stored in lane tiles (``width % 128 == 0``: what
+    ``data/sparse._round_up`` gives from 121 slots on, and what
+    ``SparseShardedDataset.live_widths`` reads whole) holds rows of
+    UNEQUAL length, dealt to it in ascending order of length: a tile of
+    consecutive packed rows has rows of nearly one length, the slots
+    behind its longest row hold ``col=0, val=0``, and so does every slot
+    of the unfilled tail of the capacity (``steps.sparse_step_capacity``:
+    mean + 6 sigma).  The v5e pays a gather and a scatter-add by the SLOT
+    whatever it holds, so the step stops at each tile's last non-zero
+    (:func:`sample_walk`).  A shard stored in sublane tiles (``width`` <
+    128: criteo 40, kdd2012 16, rcv1) has rows of one length and 1.2 to
+    1.5% of slack: it keeps the one-shot programs to the letter.
+
+    The block is ``SPARSE_WALK_TILE``, or ``SPARSE_WALK_TILE_HBM`` where
+    the accumulator is not ``resident`` in VMEM
+    (:func:`walk_accumulator_resident`), cut to the sample."""
+    if width % 128:
+        return None
+    rows, chunk = SPARSE_WALK_TILE if resident else SPARSE_WALK_TILE_HBM
+    return min(rows, n_rows), min(chunk, width)
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class SampleWalk:
+    """How far a packed sample is walked (:func:`sample_walk`): ``chunks``
+    ``(tiles,)`` int32, the chunks of ``chunk`` slots each tile of ``rows``
+    rows is walked for.  The block's two sizes are static: a program a
+    block shape."""
+
+    chunks: jax.Array
+    rows: int = dataclasses.field(metadata=dict(static=True))
+    chunk: int = dataclasses.field(metadata=dict(static=True))
+
+
+def sample_walk(v_sel: jax.Array, tile) -> "SampleWalk | None":
+    """The walk of the packed sample ``v_sel`` in blocks of ``tile``
+    (:func:`walk_tile`; ``None``: the sample is read whole, and so is the
+    answer).
+
+    A tile's bound is the position after the LAST non-zero of its rows,
+    read from the values themselves: a zero in the middle of a row lies
+    inside it, rows in any order are walked to their longest, and a tile
+    of the unfilled tail (``v_sel`` is validity-zeroed) reads 0.  One
+    compare and two maxima over the sample: vector work, not by the
+    index."""
+    if tile is None:
+        return None
+    rows, chunk = tile
+    n_rows, width = v_sel.shape
+    with jax.named_scope("walk.bounds"):
+        slot = jnp.arange(1, width + 1, dtype=jnp.int32)
+        length = jnp.max(jnp.where(v_sel != 0, slot, 0), axis=1)
+        _, tiles = row_blocks(n_rows, rows)
+        length = jnp.pad(length, (0, tiles * rows - n_rows))
+        chunks = -(-jnp.max(length.reshape(tiles, rows), axis=1) // chunk)
+    return SampleWalk(chunks, rows, chunk)
+
+
+def _walk(c_sel, v_sel, walk, one_tile, carry):
+    """The walk itself, once for the margins and once for the scatter-add:
+    ``carry = one_tile(carry, at, own_rows, over_chunks)`` for every row
+    tile of the packed sample, where ``over_chunks(f, init)`` folds ``f(acc,
+    cb, vb)`` over THAT tile's ``walk.chunks[t]`` column chunks (a loop with
+    a traced bound).  ONE block shape ``(R, C)``: the last tile and the last
+    chunk are pulled back so that they end with the sample
+    (:func:`clamped_block`); the rows such a tile shares with the one before
+    are not its own (``own_rows``), and the slots such a chunk shares read 0
+    in ``vb``."""
+    n_rows, width = c_sel.shape
+    rows, chunk = walk.rows, walk.chunk
+    slot = jnp.arange(chunk, dtype=jnp.int32)
+    row = jnp.arange(rows, dtype=jnp.int32)
+
+    def tile(t, carry):
+        start, at = clamped_block(t, rows, n_rows)
+
+        def over_chunks(f, init):
+            def one_chunk(j, acc):
+                start_c, at_c = clamped_block(j, chunk, width)
+                cb = jax.lax.dynamic_slice(c_sel, (at, at_c), (rows, chunk))
+                vb = jax.lax.dynamic_slice(v_sel, (at, at_c), (rows, chunk))
+                return f(acc, cb, jnp.where(at_c + slot >= start_c, vb, 0))
+
+            return jax.lax.fori_loop(0, walk.chunks[t], one_chunk, init)
+
+        return one_tile(carry, at, at + row >= start, over_chunks)
+
+    return jax.lax.fori_loop(0, walk.chunks.shape[0], tile, carry)
+
+
+def _margins_walked(c_sel, v_sel, w, walk, path):
+    """The margins of a walked sample: per ``(R, C)`` block the model's
+    gather in ``path``'s form, the product and the row sum, summed over a
+    tile's chunks and written a tile at a time.  A row's slots beyond its
+    tile's bound hold zeros and are not read."""
+    gather = _model_gather(w, path)
+    dtype = jnp.result_type(v_sel.dtype, w.dtype)
+
+    def one_tile(m, at, own_rows, over_chunks):
+        m_tile = over_chunks(
+            lambda acc, cb, vb: acc + jnp.sum(vb * gather(cb), axis=1),
+            jnp.zeros(walk.rows, dtype))
+        kept = jax.lax.dynamic_slice_in_dim(m, at, walk.rows)
+        return jax.lax.dynamic_update_slice_in_dim(
+            m, jnp.where(own_rows, m_tile, kept), at, 0)
+
+    return _walk(c_sel, v_sel, walk, one_tile,
+                 jnp.zeros(c_sel.shape[0], dtype))
+
+
+def sparse_margins(c_sel: jax.Array, v_sel: jax.Array, w: jax.Array,
+                   walk: "SampleWalk | None" = None):
     """``m_i = sum_k v_sel[i, k] * w[c_sel[i, k]]``: the ``(rows,)`` margins
     ``x_i . w`` of padded-ELL rows (a padding slot's value is 0).
 
     THE definition behind every sparse step's residual and the ONE place
     its gather's program is chosen (:func:`sparse_gather_path`).  Every
     program gathers the same values; only the order of a margin's
-    ``K``-term sum may differ.
+    ``K``-term sum may differ.  With ``walk`` (:func:`sample_walk` of a
+    PACKED sample) the sample is walked tile by tile up to each tile's
+    last non-zero, in the same form of gather.
     """
     path = sparse_gather_path(w, c_sel)
+    if walk is not None:
+        return _margins_walked(c_sel, v_sel, w, walk, path)
     if path == "rows8":
         return _margins_rows8(c_sel, v_sel, w)
     if path == "lanes128":
@@ -471,16 +643,23 @@ def sparse_residual(
 
 
 def make_sparse_grad_sum(d: int):
-    """jit (cols, vals, coeff) -> dense (d,) gradient via ONE scatter-add.
+    """jit (cols, vals, coeff[, walk]) -> dense (d,) gradient by
+    scatter-add: ONE over the whole sample, or one a block of the walk.
 
     ``g = sum_i coeff_i * x_i`` -- the sparse analog of ``X.T @ coeff``:
     every slot's product ``vals * coeff`` is added into ``g`` at its column,
     in the order the slots are stored.  Padding slots add 0 to column 0 and
-    a column id outside ``[0, d)`` is dropped.  It scatter-adds EVERY slot
-    it is given, 6.9 ns each whatever the value, so the steps hand it the
-    sample at the shard's live width (``steps._live_columns``): the ELL
-    columns that are padding in every row never reach it, and the padding
-    left is that of rows shorter than the longest.
+    a column id outside ``[0, d)`` is dropped.  A scatter-add pays for
+    every slot it is GIVEN, 6.7 to 8.8 ns each whatever the value, so the
+    steps give it as few empty ones as the storage lets them tell: a shard
+    stored in sublane tiles at its live width (``steps._live_columns``: the
+    ELL columns that are padding in every row never reach it; the padding
+    left is that of rows shorter than the longest), and a packed sample of a
+    shard stored in lane tiles with ``walk`` (:func:`sample_walk`): ``g``
+    is then the carry of the walk's loops and takes one ``(R, C)`` block a
+    scatter-add, each row tile up to its last non-zero, so the slots behind
+    it and the unfilled tail of the capacity are never given.  Only the
+    order of a column's terms differs from the one-shot form.
 
     Nothing puts the slots in order first.  On the v5e a sort is cheap and
     an element-wise gather or scatter is dear (PERF.md section 6, PR 33;
@@ -501,12 +680,23 @@ def make_sparse_grad_sum(d: int):
     element-wise.
     """
 
+    def add(g, cols, products):
+        return g.at[cols.ravel()].add(products.ravel(), mode="drop")
+
     @jax.jit
-    def grad_sum(cols, vals, coeff):
+    def grad_sum(cols, vals, coeff, walk=None):
         with jax.named_scope("grad"):
-            return jnp.zeros(d, vals.dtype).at[cols.ravel()].add(
-                (vals * coeff[:, None]).ravel(), mode="drop"
-            )
+            g = jnp.zeros(d, vals.dtype)
+            if walk is None:
+                return add(g, cols, vals * coeff[:, None])
+
+            def one_tile(g, at, own_rows, over_chunks):
+                r = jnp.where(own_rows, jax.lax.dynamic_slice_in_dim(
+                    coeff, at, walk.rows), 0)
+                return over_chunks(
+                    lambda g, cb, vb: add(g, cb, vb * r[:, None]), g)
+
+            return _walk(cols, vals, walk, one_tile, g)
 
     return grad_sum
 

@@ -56,8 +56,11 @@ from asyncframework_tpu.ops.gradients import (
     mm_f32,
     row_blocks,
     saga_commit_history,  # re-exported: the solvers' committed-history op
+    sample_walk,
     sparse_gather_path,
     sparse_margins,
+    walk_accumulator_resident,
+    walk_tile,
 )
 
 
@@ -603,6 +606,43 @@ def sparse_step_capacity(batch_rate: float, n_rows: int) -> int:
     return min(cap, n_rows)
 
 
+def sparse_walk_tile(batch_rate: float, d: int, n_rows: int, width: int):
+    """``(R, C)``, the block the compacted step of a shard of ``n_rows``
+    rows read ``width`` slots wide walks its packed sample in under a
+    ``(d,)`` model, or ``None`` where it reads the sample whole
+    (``gradients.walk_tile``: the chooser, from these shapes alone)."""
+    return walk_tile(sparse_step_capacity(batch_rate, n_rows), width,
+                     walk_accumulator_resident(d, n_rows, width))
+
+
+def sparse_walked_slots(batch_rate: float, d: int, n_rows: int, width: int,
+                        row_lengths=None) -> float:
+    """The slots a compacted sparse step gathers and scatter-adds on a
+    shard of ``n_rows`` rows read ``width`` slots wide, on average over the
+    Bernoulli draw and from the host's integers alone.  Where the sample
+    is read whole (:func:`sparse_walk_tile`: a shard stored in sublane
+    tiles), capacity x ``width``; where it is WALKED, ``R`` x ``C`` for
+    every chunk of every row tile up to the tile's longest row
+    (``row_lengths``: the slots each row of the shard fills, in the order
+    they are stored; ``None``: every row ``width``), the draw taken as
+    every ``1 / batch_rate``-th row and the capacity's tail as unfilled.
+    A count equal to capacity x ``width`` says the walk did not engage."""
+    cap = sparse_step_capacity(batch_rate, n_rows)
+    tile = sparse_walk_tile(batch_rate, d, n_rows, width)
+    if tile is None:
+        return float(cap * width)
+    rows, chunk = tile
+    lengths = (np.full(n_rows, width) if row_lengths is None
+               else np.asarray(row_lengths))
+    packed = np.arange(cap)
+    drawn = np.minimum((packed / batch_rate).astype(np.int64), n_rows - 1)
+    filled = np.where(packed < batch_rate * n_rows, lengths[drawn], 0)
+    tiles = -(-cap // rows)
+    longest = np.pad(filled, (0, tiles * rows - cap)).reshape(
+        tiles, rows).max(axis=1)
+    return float(rows * chunk * np.sum(-(-longest // chunk)))
+
+
 def _sized_by_capacity(step, batch_rate: float, d: int):
     """A compacted sparse step's size, for who asks the step it runs:
     ``step.task_rows(n_rows)``, the rows its compaction holds
@@ -701,7 +741,12 @@ def _sparse_compacted_gradient(cols, vals, y, w, sub, batch_rate, grad_sum,
     packed to static capacity (:func:`_sampled_rows`), only those rows
     gathered and scatter-added, in the order they are stored and at the
     shard's live width (:func:`_live_columns`: every gather, sort and
-    scatter downstream sees ``(capacity, live_width)``); the rows'
+    scatter downstream sees ``(capacity, live_width)`` at the most).  A
+    sample of a shard stored in lane tiles, whose rows are of unequal
+    length, is WALKED (``gradients.walk_tile``, chosen from the shape):
+    the model's gather and the scatter-add take it in ``(R, C)`` blocks,
+    each row tile up to its last non-zero, and never see the slots behind
+    it nor the unfilled tail of the capacity.  The rows'
     coefficient is ``m - y`` (least squares) or ``sigmoid(m) - y``
     (logistic) of the margin ``m = x . w``, f32 throughout.
     ONE definition, used by the engine worker step AND the fused rounds --
@@ -714,13 +759,15 @@ def _sparse_compacted_gradient(cols, vals, y, w, sub, batch_rate, grad_sum,
     with jax.named_scope("gather"):
         c_sel = cols[idx]
         v_sel = vals[idx] * valid[:, None]  # unfilled slots contribute 0
+    walk = sample_walk(v_sel, sparse_walk_tile(  # None: it is read whole
+        batch_rate, w.shape[0], y.shape[0], cols.shape[1]))
     with jax.named_scope("residual"):
-        m = sparse_margins(c_sel, v_sel, w)
+        m = sparse_margins(c_sel, v_sel, w, walk)
         if loss == "least_squares":
             r = m - y[idx] * valid
         else:  # an unfilled slot's margin is 0: sigmoid(0) is not
             r = (jax.nn.sigmoid(m) - y[idx]) * valid
-    return grad_sum(c_sel, v_sel, r)
+    return grad_sum(c_sel, v_sel, r, walk)
 
 
 def make_sparse_asgd_worker_step(batch_rate: float, d: int,

@@ -106,6 +106,12 @@ class ASGD(EngineSolver):
             #: ``width=``) and the non-zeros its step samples on average
             self._widths = [steps._live_width(k, live) for k in stored]
             self._step_nonzeros = [config.batch_rate * s.nnz for s in shards]
+            #: the slots its step gathers and scatter-adds on average: the
+            #: capacity x the width read, or what the ragged walk takes
+            self._step_walked = [
+                steps.sparse_walked_slots(
+                    config.batch_rate, self.ds.d, s.size, lw, s.row_lengths)
+                for s, lw in zip(shards, self._widths)]
             rows = max(s.size for s in shards)
             self._path_extras = {
                 "sparse_step_capacity": max(caps),
@@ -124,6 +130,11 @@ class ASGD(EngineSolver):
                 "sparse_stored_slots": sum(
                     s.size * k for s, k in zip(shards, stored)),
                 "sparse_nonzero_slots": sum(s.nnz for s in shards),
+                # the largest share of its capacity x STORED width a
+                # worker's step walks: 1.0 says the walk did not engage
+                "walked_slots_share_max": max(
+                    wk / (c * k) for wk, c, k in zip(
+                        self._step_walked, caps, stored)),
             }
         else:
             self._path_extras = {

@@ -55,9 +55,10 @@ class EngineSolver(FlopsAccountingMixin):
     """What the engine's solvers share below their run methods.  Hosts
     provide ``cfg``, ``devices``, ``ds``, ``_recovery``, ``_sparse``,
     ``_eval`` (the trajectory's loss evaluation), ``_path_extras``,
-    optionally ``_step_nonzeros`` (by worker, the non-zeros a padded-ELL
-    step samples on average) and ``_result_payload``: what of a worker
-    step's outputs ``(..., new_key)`` rides the ``PartialResult`` to the
+    optionally ``_step_nonzeros`` and ``_step_walked`` (by worker, the
+    non-zeros a padded-ELL step samples on average and the slots it
+    gathers and scatter-adds for them) and ``_result_payload``: what of a
+    worker step's outputs ``(..., new_key)`` rides the ``PartialResult`` to the
     updater (everything but the key)."""
 
     def _collect_checked(self, ctx: AsyncContext, waiter, timeout_s: float,
@@ -575,11 +576,14 @@ class EngineRun:
                      for wid in range(cfg.num_workers)]
         extras.update(accepted_by_worker_min=min(by_worker),
                       accepted_by_worker_max=max(by_worker))
-        step_nonzeros = getattr(self.solver, "_step_nonzeros", None)
-        if step_nonzeros and sum(by_worker):
-            extras["nonzero_slots_per_step_mean"] = sum(
-                c * z for c, z in zip(by_worker, step_nonzeros)
-            ) / sum(by_worker)
+        for name, by_shard in (
+                ("nonzero_slots_per_step_mean", "_step_nonzeros"),
+                ("walked_slots_per_step_mean", "_step_walked")):
+            per_step = getattr(self.solver, by_shard, None)
+            if per_step and sum(by_worker):
+                extras[name] = sum(
+                    c * z for c, z in zip(by_worker, per_step)
+                ) / sum(by_worker)
         if self._tail is not None:
             # drive()'s own end: from the submitter loop's exit to the
             # fence's end nothing is accepted, and all of it lies inside

@@ -272,9 +272,9 @@ def margins_spy(monkeypatch):
     calls = []
     real = gradients.sparse_margins
 
-    def spy(c_sel, v_sel, w):
+    def spy(c_sel, v_sel, w, walk=None):
         calls.append((tuple(c_sel.shape), tuple(w.shape)))
-        return real(c_sel, v_sel, w)
+        return real(c_sel, v_sel, w, walk)
 
     # the steps call the name they imported; the residual its module's
     monkeypatch.setattr(steps, "sparse_margins", spy)

@@ -337,6 +337,170 @@ def test_a_lane_block_narrower_than_a_row_is_one_row(monkeypatch):
         atol=1e-5)
 
 
+# ------------------------------------- (b') the walk of a packed sample
+def _hand_shard(lengths, K, seed=0):
+    """A padded-ELL shard made by hand: row ``i`` fills its first
+    ``lengths[i]`` slots (values never 0, columns anywhere in ``D``)."""
+    import types
+
+    rs = np.random.default_rng(seed)
+    n = len(lengths)
+    live = np.arange(K)[None, :] < np.asarray(lengths)[:, None]
+    cols = np.where(live, rs.integers(0, D, (n, K)), 0).astype(np.int32)
+    vals = np.where(live, np.exp(0.5 * rs.standard_normal((n, K))) / 8,
+                    0).astype(np.float32)
+    y = (rs.random(n) < 0.6).astype(np.float32)
+    return types.SimpleNamespace(
+        cols=jnp.asarray(cols), vals=jnp.asarray(vals), y=jnp.asarray(y),
+        size=n)
+
+
+def _walk_case(case):
+    """``(shard, (R, C), capacity or None)`` of one case of the walk."""
+    n, K = 600, 384
+    rs = np.random.default_rng(11)
+    ascending = np.sort(rs.integers(8, K - 40, n))
+    if case == "ascending":  # the order the data has
+        return _hand_shard(ascending, K), (8, 128), None
+    if case == "shuffled":  # correct for ANY order, only slower
+        return _hand_shard(rs.permutation(ascending), K), (8, 128), None
+    if case == "zero-inside-a-row":
+        s = _hand_shard(ascending, K)
+        vals = np.array(s.vals)
+        vals[:, 3] = 0.0
+        vals[np.arange(n), ascending // 2] = 0.0
+        s.vals = jnp.asarray(vals)
+        return s, (8, 128), None
+    if case == "a-row-fills-K":  # and 384 is no multiple of the chunk, and
+        # 112 packed rows none of the tile: both last blocks are pulled back
+        lengths = ascending.copy()
+        lengths[-60:] = K
+        return _hand_shard(lengths, K), (24, 256), None
+    if case == "overflow":  # more rows drawn than the capacity packs
+        return _hand_shard(ascending, K), (8, 128), 40
+    if case == "unfilled-last-tile":
+        return _hand_shard(ascending, K), (16, 128), None
+    if case == "one-lane-tile":
+        return _hand_shard(np.sort(rs.integers(1, 129, n)), 128), (8, 512), None
+    raise ValueError(case)
+
+
+@pytest.mark.parametrize("case", [
+    "ascending", "shuffled", "zero-inside-a-row", "a-row-fills-K",
+    "overflow", "unfilled-last-tile", "one-lane-tile"])
+def test_the_walked_step_gives_the_references_gradient(monkeypatch, case):
+    """A sample of a shard stored in lane tiles is WALKED (ISSUE 40): the
+    model's gather and the scatter-add take it in ``(R, C)`` blocks, each
+    row tile up to its last non-zero.  Every non-zero is still gathered
+    and added exactly once: the step's ``g`` is
+    ``reference.full_gradient``'s under the step's own Bernoulli draw, to
+    the tolerance the step on a ragged shard is held to above, whatever
+    the order of the rows, with a zero inside a row, a row that fills the
+    width, last blocks pulled back, a capacity that overflows and tiles
+    that hold nothing."""
+    s, (R, C), cap = _walk_case(case)
+    b, K = 0.1, s.cols.shape[1]
+    for tile in ("SPARSE_WALK_TILE", "SPARSE_WALK_TILE_HBM"):
+        monkeypatch.setattr(gradients, tile, (R, C))
+    if cap is not None:
+        monkeypatch.setattr(steps, "sparse_step_capacity", lambda b, n: cap)
+    cap = steps.sparse_step_capacity(b, s.size)
+    assert steps.sparse_walk_tile(b, D, s.size, K) == (R, min(C, K))
+    step = steps.make_sparse_asgd_worker_step(b, D, "logistic")
+    w = jnp.asarray(np.random.default_rng(7).standard_normal(D), jnp.float32)
+    key = jax.random.PRNGKey(5)
+    g, _key = step(s.cols, s.vals, s.y, w, key)
+    mask = np.array(
+        jax.random.bernoulli(jax.random.split(key)[1], b, (s.size,)))
+    drawn = np.flatnonzero(mask)
+    assert (len(drawn) > cap) == (case == "overflow")
+    mask[drawn[cap:]] = False  # the packing keeps the first ``cap``
+    want = reference.full_gradient(s, w, D, "logistic",
+                                   weights=mask.astype(np.float32))
+    off = np.max(np.abs(np.asarray(g, np.float64) - want))
+    assert off <= 2e-6 * np.max(np.abs(want)), off
+    # what the walk took of the capacity x width the one-shot forms take
+    idx = np.pad(drawn[:cap], (0, max(0, cap - len(drawn))))
+    filled = (np.arange(cap) < len(drawn))[:, None]
+    chunks = np.asarray(gradients.sample_walk(
+        jnp.where(filled, s.vals[idx], 0), (R, min(C, K))).chunks)
+    assert len(chunks) == -(-cap // R)
+    walked = int(chunks.sum()) * R * min(C, K)
+    if case == "unfilled-last-tile":
+        assert chunks[-1] == 0 and chunks[0] > 0
+    if case == "overflow":
+        assert chunks.min() > 0
+    if case in ("ascending", "zero-inside-a-row", "unfilled-last-tile"):
+        assert walked < 0.5 * cap * K, (walked, cap * K)
+        assert list(chunks) == sorted(chunks[chunks > 0]) + [0] * int(
+            (chunks == 0).sum())
+
+
+def test_a_sample_stored_in_sublane_tiles_is_read_whole():
+    """The chooser is the stored shape: ``K % 128 != 0`` (criteo 40,
+    kdd2012 16, rcv1) keeps the one-shot programs, whatever the rows."""
+    for width in (11, 16, 39, 40, 120, 200):
+        for resident in (True, False):
+            assert gradients.walk_tile(992, width, resident) is None
+        assert steps.sparse_walk_tile(0.05, 16_609_143, 16_406, width) is None
+    assert gradients.sample_walk(jnp.ones((16, 40)), None) is None
+    # a block of 16,384 slots where the (d,) accumulator stays in VMEM, of
+    # 65,536 where the shard's own arrays fit there and take its place
+    # (webspam's narrowest shard) or the model is too large to
+    assert gradients.walk_tile(992, 16_384) == (64, 256)
+    assert gradients.walk_tile(992, 16_384, resident=False) == (128, 512)
+    assert steps.sparse_walk_tile(0.05, 16_609_143, 16_406, 16_384) == (64, 256)
+    assert steps.sparse_walk_tile(0.05, 16_609_143, 16_406, 2_176) == (64, 256)
+    assert steps.sparse_walk_tile(0.05, 16_609_143, 16_407, 1_664) == (128, 512)
+    assert steps.sparse_walk_tile(0.05, 54_686_452, 16_406, 16_384) == (128, 512)
+    assert gradients.walk_tile(8, 128) == (8, 128)
+
+
+def test_walked_slots_are_what_a_seeded_step_walks(monkeypatch):
+    """``extras["walked_slots_per_step_mean"]`` is reckoned from the
+    shards' own row lengths, on the host: on the widest shard (stored in
+    lane tiles) it is what exact counts over seeded draws average, within
+    the draw's spread, and well under capacity x width; on a shard stored
+    in sublane tiles it IS capacity x the width read.  (Blocks of 16 x 128
+    slots: the chip's blocks would hold these 52 packed rows in one.)"""
+    for tile in ("SPARSE_WALK_TILE", "SPARSE_WALK_TILE_HBM"):
+        monkeypatch.setattr(gradients, tile, (16, 128))
+    ds = _ragged()
+    solver = ASGD(ds, None, _cfg(), devices=jax.devices()[:1])
+    s = ds.shard(7)
+    K = s.vals.shape[1]
+    assert K % 128 == 0 and np.array_equal(s.row_lengths, _filled(s))
+    cap = solver._task_rows(s.size)
+    R, C = steps.sparse_walk_tile(0.1, ds.d, s.size, K)
+    assert (R, C) == (16, 128)
+    counts = []
+    for k in range(24):
+        idx, valid = steps._sampled_rows(
+            jax.random.PRNGKey(k), 0.1, s.size, jnp.float32)
+        walk = gradients.sample_walk(s.vals[idx] * valid[:, None], (R, C))
+        counts.append(int(walk.chunks.sum()) * R * C)
+    said = steps.sparse_walked_slots(0.1, ds.d, s.size, K, s.row_lengths)
+    assert said == solver._step_walked[7]
+    assert abs(said - np.mean(counts)) < 2 * np.std(counts), (said, counts)
+    assert 0.1 * s.nnz < said < 0.8 * cap * K
+    narrow = ds.shard(0)
+    assert narrow.vals.shape[1] % 128
+    assert solver._step_walked[0] == (
+        solver._task_rows(narrow.size) * _read(narrow))
+    shares = [wk / (solver._task_rows(sh.size) * sh.vals.shape[1])
+              for wk, sh in zip(solver._step_walked, _shards(ds))]
+    assert solver._path_extras["walked_slots_share_max"] == max(shares)
+    assert shares[7] < 0.8 < max(shares) <= 1.0
+    # rows of ONE length in sublane tiles: every step walks what it reads
+    same = SparseShardedDataset.generate_on_device(
+        2_000, 512, 11, 4, jax.devices()[:1], seed=3, noise=0.01)
+    res = ASGD(same, None, _cfg(num_workers=4, num_iterations=4,
+                                loss="least_squares"),
+               devices=jax.devices()[:1]).run_sync()
+    assert res.extras["walked_slots_per_step_mean"] == (
+        res.extras["live_slots_per_step"])
+
+
 # ------------------------------------------------------ (c) the engine's run
 def test_a_short_asgd_run_agrees_with_the_reference_and_counts_its_shards(
         solved):

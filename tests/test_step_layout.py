@@ -583,7 +583,7 @@ def test_wide_sparse_programs_read_the_shard_at_its_live_width(
 
 # --------------------------------------- the ragged deployment (ISSUE 39)
 
-RAGGED_ROWS, RAGGED_D = 21_875, 16_609_143
+RAGGED_ROWS, RAGGED_D = 16_406, 16_609_143
 #: the narrowest and the widest of webspam's eight shard shapes, stored and
 #: read: whole lane tiles of 128 (``data/sparse.py: _round_up``), read
 #: whole (``SparseShardedDataset.live_widths``)
@@ -600,42 +600,65 @@ def _ragged_specs(one_chip, stored):
 
 
 def _tall_as_the_ragged_shard(text):
+    """What the program MAKES of the shard's height: a relayout or a copy
+    of it.  Its move to VMEM as it is (``copy-start`` / ``copy-done``: the
+    compiler prefetches a shard that fits there across programs, webspam's
+    narrowest at 16,406 rows) is none."""
     shard = re.compile(r"\[%d,\d+\]" % RAGGED_ROWS)
     return [
         (name, op) for name, t, op, _ in _instructions(text)
         if shard.search(t) and op not in ("parameter", "get-tuple-element",
-                                          "tuple", "while", "bitcast")
+                                          "tuple", "while", "bitcast",
+                                          "copy-start", "copy-done")
     ]
 
 
 @pytest.mark.parametrize("shape", sorted(RAGGED_SHAPES))
-def test_ragged_sparse_step_reads_each_shard_at_its_own_live_width(
+def test_ragged_sparse_step_walks_the_sample_in_blocks(
     one_chip, no_compile_cache, on_tpu, shape
 ):
-    """webspam's step (``b`` 0.05 of 21,875 rows, the logistic link, ``d``
+    """webspam's step (``b`` 0.05 of 16,406 rows, the logistic link, ``d``
     16,609,143) on the narrowest and on the widest shard, built as ASGD
     builds it: ONE step with the dataset's mapping of live widths, traced
     for the shape it is called with.  A shard stored a whole number of lane
     tiles wide is row-major, so a sampled row is one contiguous read: the
     two row gathers take ``(1, live)`` slices of the stored parameters
-    themselves, and nothing as tall as the shard is made.  What PR 33 and
-    PR 38 pinned holds at rows a hundred times as wide: one single-operand
-    sort of the row keys, no gather that permutes the slots, one scatter
-    into the model (the compiler sorts the pairs in front of it by itself
-    at this ``d``, as at kdd2012's), the shard read at its OWN live width.
-    The model (66 MB, ``d % 8 == 7``: no eight-row view, over
-    ``SPARSE_ELEMENTS_BYTES``) is read a lane row an index from HBM, in
-    blocks of ``SPARSE_LANES_BLOCK_SLOTS`` slots: four rows of 1,664, one
-    of 16,384 (``gradients._block_rows``); no element of it is gathered
-    alone and no copy of it is pinned in VMEM."""
+    themselves, by the ROW, and nothing as tall as the shard is made.  One
+    single-operand sort packs the row keys, as since PR 33.
+
+    Since ISSUE 40 the packed sample is WALKED (``gradients.walk_tile``):
+    the model's gather and the scatter-add sit in loops over row tiles and,
+    inside, over that tile's chunks, and take ``(R, C)`` blocks, so no
+    gather, sort or scatter of the sample's ``capacity x K`` slots is left
+    (the parent sorted 16,252,928 pairs in front of ONE scatter on the
+    widest shard); a block is large enough for the compiler to sort ITS
+    pairs in front of its scatter-add (the unsorted one costs seven times
+    as much a slot on the chip).  The ``(d,)`` accumulator is the loops'
+    carry and is updated IN PLACE: a copy of it inside them would be 66 MB
+    a block.  The model (66 MB, ``d % 8 == 7``: no eight-row view, over
+    ``SPARSE_ELEMENTS_BYTES``) is still read a lane row an index, no
+    element of it gathered alone; its lane rows are in VMEM through the
+    margins' loops.
+
+    WHERE the accumulator lives is what the block's size is chosen by
+    (``gradients.walk_accumulator_resident``), and the program is held to
+    the rule's two halves: on the widest shard (1.07 GB an array) it is in
+    VMEM (``S(1)``) through the scatter-add's loops and takes blocks of 64
+    x 256 slots; the narrowest shard's arrays (109 MB each) fit VMEM, the
+    compiler prefetches one of them there across programs, the accumulator
+    stays in HBM and the blocks are 128 x 512."""
     stored, live = RAGGED_SHAPES[shape]
     widths = {s: lw for s, lw in RAGGED_SHAPES.values()}
     (cols, vals, y), spec = _ragged_specs(one_chip, stored)
     step = steps.make_sparse_asgd_worker_step(
         0.05, RAGGED_D, "logistic", live_width=widths)
     cap = steps.sparse_step_capacity(0.05, RAGGED_ROWS)
-    assert cap == 1_288 and step.gather_path(RAGGED_ROWS, live) == "lanes128"
+    assert cap == 992 and step.gather_path(RAGGED_ROWS, live) == "lanes128"
     assert 4 * RAGGED_D < gradients.SPARSE_VMEM_BYTES and RAGGED_D % 8 == 7
+    resident = gradients.walk_accumulator_resident(RAGGED_D, RAGGED_ROWS, live)
+    assert resident == (shape == "widest")
+    R, C = steps.sparse_walk_tile(0.05, RAGGED_D, RAGGED_ROWS, live)
+    assert (R, C) == ((64, 256) if resident else (128, 512))
     compiled = step.lower(cols, vals, y, spec((RAGGED_D,), jnp.float32),
                           spec((2,), jnp.uint32)).compile()
     text = compiled.as_text()
@@ -648,28 +671,37 @@ def test_ragged_sparse_step_reads_each_shard_at_its_own_live_width(
         assert re.search(r"\[\d+,\d+\]\{1,0", t), t
     assert not _tall_as_the_ragged_shard(text), _tall_as_the_ragged_shard(text)
 
+    # nothing of the sample's size but the two row gathers: every other
+    # gather, the sort of pairs and the scatter take one block
+    assert f"[{cap * live}]" not in text
     sorts = [t for _n, t, op, _ in instrs if op == "sort"]
     keys = [t for t in sorts if not t.startswith("(")]
     pairs = [t for t in sorts if t.startswith("(")]
     assert len(keys) == 1 and keys[0].startswith(f"s32[{RAGGED_ROWS}]"), sorts
-    assert len(pairs) == 1 and f"s32[{cap * live}]" in pairs[0], sorts
-
+    assert len(pairs) == 1 and f"s32[{R * C}]" in pairs[0], sorts
     gathers = sorted(t.split("{")[0] for _n, t, op, _ in instrs
                      if op == "gather")
-    # the sampled rows of cols and vals, their labels, a block's lane rows
-    rows = gradients._block_rows(gradients.SPARSE_LANES_BLOCK_SLOTS, live)
-    assert rows == {1_664: 4, 16_384: 1}[live]
-    block = f"f32[{rows},{live},128]" if rows > 1 else f"f32[{live},128]"
     assert gathers == sorted([
-        f"s32[{cap},{live}]", f"f32[{cap},{live}]", block, f"f32[{cap}]"]), gathers
+        f"s32[{cap},{live}]", f"f32[{cap},{live}]", f"f32[{R},{C},128]",
+        f"f32[{cap}]"]), gathers
     rows_read = [ln for ln in text.splitlines()
                  if " gather(" in ln and f"slice_sizes={{1,{live}}}" in ln]
     assert len(rows_read) == 2, rows_read
-    assert f"[{cap},{stored}]" not in text or stored == live
-    assert not re.search(r"f32\[%d\]\{0:T\(1024\)S\(1\)\}" % RAGGED_D, text)
+    assert re.search(r"f32\[\d+,128\]\{1,0:T\(8,128\)S\(1\)\}", text)
     scatters = [ln for ln in text.splitlines() if " scatter(" in ln]
-    assert len(scatters) == 1 and f" f32[{RAGGED_D}]" in scatters[0], scatters
-    # five sample-sized arrays at the most (solvers/base.py plans 20 B a slot)
+    assert len(scatters) == 1 and "/while/body/" in scatters[0], scatters
+    in_vmem = f" f32[{RAGGED_D}]{{0:T(1024)S(1)}} scatter(" in scatters[0]
+    in_hbm = f" f32[{RAGGED_D}]{{0:T(1024)}} scatter(" in scatters[0]
+    assert (in_vmem, in_hbm) == (resident, not resident), scatters[0]
+    prefetched = [ln for ln in text.splitlines()
+                  if "cross_program_prefetch_index" in ln
+                  and f"[{RAGGED_ROWS},{stored}]" in ln]
+    assert bool(prefetched) == (not resident), prefetched
+    # the accumulator is copied nowhere, so not inside the loops either
+    assert not [(n, t) for n, t, op, _ in instrs
+                if op == "copy" and t.startswith(f"f32[{RAGGED_D}]")]
+    # the packed sample twice over (solvers/base.py plans 20 B a slot) and
+    # one block's lane rows
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < 24 * cap * stored + 32e6, f"{temp} bytes of temporaries"
 
