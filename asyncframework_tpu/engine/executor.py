@@ -67,6 +67,12 @@ class DeviceExecutor:
     def launch_task(self, task: TaskSpec) -> None:
         if not self._alive:
             raise RuntimeError(f"executor {self.worker_id} is not alive")
+        # a closure that wants to know when it was put here says so (a
+        # sampled update's ``task.wake`` starts in this instant; the
+        # executor itself knows nothing of tracing)
+        on_launch = getattr(task.fn, "on_launch", None)
+        if on_launch is not None:
+            on_launch()
         self._inbox.put(task)
 
     def kill(self) -> None:

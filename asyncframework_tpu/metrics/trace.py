@@ -58,10 +58,26 @@ black hole.  This module makes one update's life observable end to end:
   compute          -    -         submit -> drained by the updater     submit
   task.inbox       wait sub -> ex ``compute``'s start -> ``fn()`` in   compute
                                   (the rest of the submit, the inbox)
+  task.wake        wait sub -> ex this task put into the executor's    task.inbox
+                                  inbox -> ``fn()`` in: the thread's
+                                  wake-up (``task.inbox`` less it is
+                                  the submitter's work before the put)
   task.dispatch    work executor  ``fn()`` entered -> step returned    compute
-  task.model_copy  work executor  ``device_put`` of w/key to the       (annotation
-                                  worker's chip, inside task.dispatch  only)
+  task.turn        wait executor  the wait for this task's turn at the task.dispatch
+                                  chip's queue (``DispatchTurns``: a
+                                  cohort's order); a chip without
+                                  turns records it all the same, empty
+  task.model_copy  work executor  ONE ``device_put`` of w/key that     task.dispatch
+                                  really copies to the worker's chip
+  task.enqueue     work executor  the jitted step's call alone, in ->  task.dispatch
+                                  returned (no annotation: PJRT's
+                                  ``PjitFunction(step)`` is the same
+                                  interval in a device trace)
   task.device_wait wait executor  ``block_until_ready`` in -> out      compute
+  task.device_wait wait executor  the same interval a second time, for compute
+  .alone                          a task that was ALONE: when its
+                                  enqueue returned no other task's
+                                  step was out on that chip
   result.queue     wait ex -> upd ``merge_result`` put -> drained      compute
   merge.queue      work updater   drained -> apply starts (state lock, compute
                                   tau filter, cross-chip ``g`` copy)
@@ -82,10 +98,21 @@ black hole.  This module makes one update's life observable end to end:
   delay, the scheduler's status update, the handler, the key lock, GIL
   hand-offs) is ``compute``'s self time.  Only the first copy of a task
   to run records the task stages: a retry or a speculative copy finds
-  ``task.inbox`` closed and records nothing.  ``task.dispatch`` is work by
-  kind, but the step's enqueue blocks inside the call while the device's
-  queue is full (32 workers on one chip): its tail is then a wait on the
-  device, which PJRT's own ``PjitFunction(step)`` event shows the same.
+  ``task.inbox`` closed and records nothing.  ``task.dispatch`` is
+  ``task.turn + task.model_copy`` (one a copy) ``+ task.enqueue`` and its
+  own time: the closure's Python between them.  A turn's wait lies inside
+  ``task.dispatch``, as it always did, and ``task.turn`` is its name.
+  ``task.enqueue`` is work by kind, but the call blocks while the
+  device's queue is full (32 workers on one chip): its tail is then a
+  wait on the device.  For a task that was alone on its chip,
+  ``task.device_wait`` is the runtime's launch, the step and the
+  completion's way back to the host with no sibling's step in front:
+  ``task.device_wait.alone`` less the step's device time is the device's
+  side of what a chip is given late.  "Alone" is read from one count a
+  chip of the steps that are out (``instrumentation.StepsOut``: every
+  task of every run adds one when its enqueue returns and takes it off
+  when its wait does); the updater's applies on the driver's chip are not
+  in it.
 
 - workers record completed spans into a bounded **lock-light ring buffer**
   (sampled at ``async.trace.sample``, default 1/64, counter-based so the
@@ -138,9 +165,14 @@ MERGE_HISTORY = "merge.history"
 # in-process engine stages (module docstring: the second table)
 SUBMIT = "submit"
 TASK_INBOX = "task.inbox"
+TASK_WAKE = "task.wake"
 TASK_DISPATCH = "task.dispatch"
+TASK_TURN = "task.turn"
 TASK_MODEL_COPY = "task.model_copy"
+TASK_ENQUEUE = "task.enqueue"
 TASK_DEVICE_WAIT = "task.device_wait"
+#: ``task.device_wait`` again, of a task whose step was alone on its chip
+TASK_DEVICE_WAIT_ALONE = "task.device_wait.alone"
 RESULT_QUEUE = "result.queue"
 SNAPSHOT = "snapshot"
 CHECKPOINT = "checkpoint"
@@ -156,8 +188,10 @@ HOLD_BACKLOG = "hold.backlog"
 WAIT_WORKERS = "wait.workers"
 
 STAGES = (PULL_WAIT, PULL_RTT, PIPELINE, WORKER_IDLE, SUBMIT, COMPUTE,
-          TASK_INBOX,
-          TASK_DISPATCH, TASK_DEVICE_WAIT, RESULT_QUEUE, PUSH_WAIT, PUSH_RTT,
+          TASK_INBOX, TASK_WAKE,
+          TASK_DISPATCH, TASK_TURN, TASK_MODEL_COPY, TASK_ENQUEUE,
+          TASK_DEVICE_WAIT, TASK_DEVICE_WAIT_ALONE, RESULT_QUEUE,
+          PUSH_WAIT, PUSH_RTT,
           MERGE_QUEUE, MERGE_APPLY, MERGE_HISTORY)
 #: engine stages in which a host thread WORKS: :func:`span` annotates
 #: these on the profiler's clock.  Everything else is a wait (or spans
@@ -171,9 +205,14 @@ HOLD_STAGES = frozenset((HOLD_BARRIER, HOLD_BACKLOG))
 #: the four children that must cover ``compute``
 COMPUTE_CHILDREN = (TASK_INBOX, TASK_DISPATCH, TASK_DEVICE_WAIT,
                     RESULT_QUEUE)
+#: the four and the stages inside them: what a task's executor records
+TASK_STAGES = COMPUTE_CHILDREN + (TASK_WAKE, TASK_TURN, TASK_MODEL_COPY,
+                                  TASK_ENQUEUE, TASK_DEVICE_WAIT_ALONE)
 #: a span's parent, by stage (engine spans; the DCN plane's have none)
 PARENT = {COMPUTE: SUBMIT, MERGE_QUEUE: COMPUTE, MERGE_APPLY: COMPUTE,
-          MERGE_HISTORY: MERGE_APPLY,
+          MERGE_HISTORY: MERGE_APPLY, TASK_WAKE: TASK_INBOX,
+          TASK_TURN: TASK_DISPATCH, TASK_MODEL_COPY: TASK_DISPATCH,
+          TASK_ENQUEUE: TASK_DISPATCH, TASK_DEVICE_WAIT_ALONE: COMPUTE,
           **{st: COMPUTE for st in COMPUTE_CHILDREN}}
 #: what a work stage is called in a profiler trace
 ANNOTATION_PREFIX = "async."
@@ -334,7 +373,8 @@ class UpdateTrace:
     and ``PartialResult`` from the submitter to the updater; every span of
     the update is recorded against it by :func:`span`."""
 
-    __slots__ = ("ctx", "_sink", "spans", "born_ms", "ids", "_open")
+    __slots__ = ("ctx", "_sink", "spans", "born_ms", "ids", "_open",
+                 "_held", "_hold_lock")
 
     def __init__(self, ctx: TraceContext, sink: Callable[[Span], None]):
         self.ctx = ctx
@@ -346,11 +386,18 @@ class UpdateTrace:
         #: stage names as its parent, see PARENT)
         self.ids: Dict[str, str] = {}
         self._open: Dict[str, "_Span"] = {}
+        #: spans kept back from the sink (:meth:`hold`); None: none are
+        self._held: Optional[List[Span]] = None
+        self._hold_lock = threading.Lock()
 
     # ---- a stage that begins on one thread and ends on another (a wait
     # in a queue, ``compute``): its open span rides this handle
-    def begin(self, stage: str) -> None:
-        self._open[stage] = span(stage, self).begin()
+    def begin(self, stage: str, inside: Optional[str] = None) -> None:
+        """``inside``: only while that stage is open (a task launched again
+        after its first copy ran finds ``task.inbox`` closed and begins no
+        ``task.wake``)."""
+        if inside is None or inside in self._open:
+            self._open[stage] = span(stage, self).begin()
 
     def end(self, stage: str) -> bool:
         """Close what :meth:`begin` opened.  False when there was nothing
@@ -362,6 +409,27 @@ class UpdateTrace:
             return False
         sp.end()
         return True
+
+    def hold(self) -> None:
+        """Keep the spans recorded from here on (by any thread) back from
+        the sink until :meth:`release`.  A span's way to the sink wakes the
+        thread behind it, which then wants the interpreter: on a host with
+        ten busy threads that is 0.1 ms a span for the thread that recorded
+        it (PR 41, four chips: three spans inside ``task.dispatch`` made a
+        sampled dispatch 0.35 ms longer).  A task's executor holds its
+        update's spans from the closure's entry to the step's enqueue and
+        hands them over on the device's time."""
+        with self._hold_lock:
+            if self._held is None:
+                self._held = []
+
+    def release(self) -> None:
+        """Hand the sink what :meth:`hold` kept back, in the order it was
+        recorded; nothing where nothing is held."""
+        with self._hold_lock:
+            held, self._held = self._held, None
+        for sp in held or ():
+            self._sink(sp)
 
     def set_model_version(self, mv: int) -> None:
         """Learned from the pull reply; back-fills spans recorded before
@@ -382,6 +450,10 @@ class UpdateTrace:
             dur_ms=max(0.0, end_ms - start_ms), **attrs,
         )
         self.spans.append(sp)
+        with self._hold_lock:
+            if self._held is not None:
+                self._held.append(sp)
+                return sp
         self._sink(sp)
         return sp
 
@@ -892,7 +964,7 @@ def chrome_trace(spans) -> dict:
     stages from the PS-side stages of its updates."""
     events = []
     for sp in spans:
-        client = sp.stage in CLIENT_STAGES or sp.stage in COMPUTE_CHILDREN
+        client = sp.stage in CLIENT_STAGES or sp.stage in TASK_STAGES
         args = {"trace_id": sp.trace_id, "model_version": sp.model_version}
         if sp.parent_id:
             args["parent_id"] = sp.parent_id

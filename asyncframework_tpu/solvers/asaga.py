@@ -732,22 +732,23 @@ class ASAGA(EngineSolver):
         step = self._step
         sparse = self._sparse
 
-        def dispatch():
+        def dispatch(ut):
             # a slice/key captured around a concurrent shard re-home may
             # still live on the old device; normalize onto the shard's home
-            w_local = on_device(w_pub, dev)
-            a_local = on_device(alpha_slice, dev)
-            key_local = on_device(key, dev)
+            w_local = on_device(w_pub, dev, ut)
+            a_local = on_device(alpha_slice, dev, ut)
+            key_local = on_device(key, dev, ut)
             # (g, ...payload..., new_key) -- the payload arity differs
             # between the dense (diff, mask) and compacted sparse
             # (diff_sel, idx, valid, c_sel, v_sel) steps
-            if sparse:
-                out = step(
-                    shard.cols, shard.vals, shard.y, w_local, a_local, key_local
-                )
-            else:
-                out = step(shard.X, shard.y, w_local, a_local, key_local)
+            with trace.span(trace.TASK_ENQUEUE, ut):
+                if sparse:
+                    out = step(shard.cols, shard.vals, shard.y, w_local,
+                               a_local, key_local)
+                else:
+                    out = step(shard.X, shard.y, w_local, a_local, key_local)
             return (*out[:-1], slice_commits, out[-1])
 
         return worker_task(dispatch, delay_model.delay_ms(wid), ut,
-                           worker=wid, chip=dev.id)
+                           worker=wid, chip=dev.id,
+                           steps_out=self._steps_out.get(dev))

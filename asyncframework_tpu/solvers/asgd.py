@@ -561,21 +561,22 @@ class ASGD(EngineSolver):
         step = self._step
         sparse = self._sparse
 
-        def dispatch():
-            w_local = on_device(w_pub, dev)
-            key_local = on_device(key, dev)
-            if sparse:
-                return step(shard.cols, shard.vals, shard.y, w_local, key_local)
-            return step(shard.X, shard.y, w_local, key_local)
+        def dispatch(ut):
+            w_local = on_device(w_pub, dev, ut)
+            key_local = on_device(key, dev, ut)
+            with trace.span(trace.TASK_ENQUEUE, ut):
+                if sparse:
+                    return step(shard.cols, shard.vals, shard.y, w_local,
+                                key_local)
+                return step(shard.X, shard.y, w_local, key_local)
 
         # (an injected delay sleeps in front of the dispatch: a straggler
         # takes no turn, or the workers behind it would wait for its sleep)
         delay_ms = delay_model.delay_ms(wid)
-        turns = self._turns.get(dev)
-        if turns is not None and delay_ms <= 0:
-            dispatch = turns.in_turn(dispatch)
         return worker_task(dispatch, delay_ms, ut, worker=wid, chip=dev.id,
-                           width=self._widths[wid] if sparse else None)
+                           width=self._widths[wid] if sparse else None,
+                           turns=None if delay_ms > 0 else self._turns.get(dev),
+                           steps_out=self._steps_out.get(dev))
 
     def _task_maker(self, run: EngineRun):
         """``make_tasks`` of this run (``EngineRun.drive``): a task captures

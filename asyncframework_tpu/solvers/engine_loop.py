@@ -48,6 +48,7 @@ from asyncframework_tpu.solvers.base import (
 from asyncframework_tpu.solvers.instrumentation import (
     FaultTolerantRun,
     RunInstruments,
+    StepsOut,
 )
 
 
@@ -60,6 +61,11 @@ class EngineSolver(FlopsAccountingMixin):
     gathers and scatter-adds for them) and ``_result_payload``: what of a
     worker step's outputs ``(..., new_key)`` rides the ``PartialResult`` to the
     updater (everything but the key)."""
+
+    #: the steps out on each chip, by device (``instrumentation.StepsOut``:
+    #: what ``_make_task`` hands ``worker_task``): a run's own, set where
+    #: the run is built; none before a solver's first run
+    _steps_out: Dict = {}
 
     def _collect_checked(self, ctx: AsyncContext, waiter, timeout_s: float,
                          pool=None, cohort=None, collected=None):
@@ -212,6 +218,7 @@ class EngineRun:
         # non-blocking submit in both modes: a sync run drains on the driver
         self.sched.set_mode(ASYNC)
         solver.scheduler = self.sched  # exposed for fault-injection tests/tools
+        solver._steps_out = {dev: StepsOut() for dev in solver.devices}
         self.delay_model = DelayModel(cfg.coeff, nw, cfg.seed)
         # sync counts rounds, not accepted gradients: the reference's
         # k < 100*numPart window covers the first 100 full-drain rounds.

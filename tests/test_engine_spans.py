@@ -141,6 +141,244 @@ def test_a_drain_records_one_apply_span_per_update_with_the_batch(
     assert all(j * 10 + 1 in ends for j in range(10))
 
 
+# ------------------------------------ inside task.inbox and task.dispatch
+def _inside(child, parent):
+    return (child.start_ms >= parent.start_ms - EPS_MS
+            and child.start_ms + child.dur_ms
+            <= parent.start_ms + parent.dur_ms + EPS_MS)
+
+
+@pytest.mark.parametrize("solver_cls", [ASGD, ASAGA])
+def test_the_inbox_and_the_dispatch_have_their_own_children(
+        solver_cls, problem, tmp_path):
+    """ISSUE 41: on a sampled update ``task.wake`` lies inside
+    ``task.inbox``; ``task.turn``, every ``task.model_copy`` and
+    ``task.enqueue`` lie inside ``task.dispatch``, in that order, and do
+    not overlap.  Four workers on four of the CPU's devices: worker 0's
+    shard is on the driver's device and copies nothing, the others copy
+    the model."""
+    log = tmp_path / "run.jsonl"
+    gamma = 0.4 if solver_cls is ASGD else 0.05
+    res = _run(solver_cls, "run", problem, trace_sample=1.0, gamma=gamma,
+               event_log=str(log))
+    complete = copies = alone = 0
+    for spans in _traces(log).values():
+        by_stage = {}
+        for sp in spans:
+            by_stage.setdefault(sp.stage, []).append(sp)
+        if trace.MERGE_APPLY not in by_stage:
+            continue  # still in flight when the run stopped
+        complete += 1
+        (inbox,) = by_stage[trace.TASK_INBOX]
+        (wake,) = by_stage[trace.TASK_WAKE]
+        assert wake.parent_id == inbox.span_id and _inside(wake, inbox)
+        (dispatch,) = by_stage[trace.TASK_DISPATCH]
+        (turn,) = by_stage[trace.TASK_TURN]
+        (enqueue,) = by_stage[trace.TASK_ENQUEUE]
+        moved = by_stage.get(trace.TASK_MODEL_COPY, [])
+        assert bool(moved) == (inbox.worker_id != 0)
+        copies += len(moved)
+        inner = [turn, *sorted(moved, key=lambda sp: sp.start_ms), enqueue]
+        for sp in inner:
+            assert sp.parent_id == dispatch.span_id, sp.stage
+            assert _inside(sp, dispatch), sp.stage
+        for a, b in zip(inner, inner[1:]):
+            assert a.start_ms + a.dur_ms <= b.start_ms + EPS_MS
+        assert turn.dur_ms < 5.0  # dense shards: the chip keeps no turns
+        (wait,) = by_stage[trace.TASK_DEVICE_WAIT]
+        for sp in by_stage.get(trace.TASK_DEVICE_WAIT_ALONE, []):
+            alone += 1
+            (compute,) = by_stage[trace.COMPUTE]
+            assert sp.parent_id == compute.span_id
+            assert _inside(sp, wait) and wait.dur_ms - sp.dur_ms < 1.0
+    assert complete >= res.accepted - 8
+    assert copies >= complete // 2
+    assert alone >= 1  # one worker a device here: most tasks are alone
+
+
+@pytest.mark.parametrize("solver_cls", [ASGD, ASAGA])
+def test_a_retried_copy_records_none_of_the_inner_stages(
+        solver_cls, problem, tmp_path):
+    """The first copy of worker 2's first task runs the closure to its end
+    and then raises: the retry is launched through the same inbox and
+    enters the same closure, and records nothing a second time."""
+    X, y = problem
+    log = tmp_path / "retry.jsonl"
+    gamma = 0.4 if solver_cls is ASGD else 0.05
+    solver = solver_cls(X, y, _cfg(heartbeat=False, trace_sample=1.0,
+                                   gamma=gamma, event_log=str(log)))
+    real = solver._make_task
+    failed = []
+
+    def flaky(wid, *a, **kw):
+        fn = real(wid, *a, **kw)
+        if wid != 2 or failed:
+            return fn
+        failed.append(wid)
+
+        def once():
+            out = fn()
+            if len(failed) == 1:
+                failed.append("raised")
+                raise RuntimeError("injected task failure")
+            return out
+
+        once.on_launch = fn.on_launch
+        return once
+
+    solver._make_task = flaky
+    res = solver.run()
+    assert res.accepted == 48 and res.extras["task_retries"] == 1
+    seen = set()
+    for spans in _traces(log).values():
+        stages = [sp.stage for sp in spans]
+        seen.update(stages)
+        for st in trace.TASK_STAGES:
+            assert stages.count(st) <= 1, (st, stages)
+    assert set(trace.TASK_STAGES) <= seen
+
+
+class _FakeStep:
+    """A step's first output on a fake chip: complete when the test says."""
+
+    def __init__(self):
+        import threading
+
+        self.waited_for = threading.Event()
+        self.complete = threading.Event()
+
+    def block_until_ready(self):
+        self.waited_for.set()
+        assert self.complete.wait(timeout=10)
+
+
+def _sampled(inst, workers):
+    uts = inst.start_updates(workers)
+    with trace.span(trace.SUBMIT, uts.values(), batch=len(workers)):
+        inst.begin_compute(uts, 0)
+    return uts
+
+
+def test_a_task_is_alone_where_no_other_step_is_out_on_its_chip(tmp_path):
+    """Two workers on one fake chip: the first to enqueue is alone, the
+    second, enqueued while the first's step is out, is not; a task
+    enqueued after both completed is alone again."""
+    import threading
+
+    from asyncframework_tpu.solvers.instrumentation import (
+        StepsOut,
+        worker_task,
+    )
+
+    log = tmp_path / "alone.jsonl"
+    inst = RunInstruments(_cfg(trace_sample=1.0, event_log=str(log)), 3)
+    uts = _sampled(inst, [0, 1, 2])
+    chip = StepsOut()
+    steps = [_FakeStep() for _ in range(3)]
+    tasks = [worker_task(lambda mine, st=st: (st,), 0.0, uts[wid],
+                         worker=wid, steps_out=chip)
+             for wid, st in enumerate(steps)]
+    threads = [threading.Thread(target=t) for t in tasks]
+    threads[0].start()
+    assert steps[0].waited_for.wait(timeout=10)
+    threads[1].start()
+    assert steps[1].waited_for.wait(timeout=10)
+    time.sleep(0.005)
+    steps[0].complete.set()
+    steps[1].complete.set()
+    for th in threads[:2]:
+        th.join(timeout=10)
+        assert not th.is_alive()
+    steps[2].complete.set()
+    tasks[2]()
+    inst.close()
+    spans, _ = trace.load_trace_events(log)
+    alone = {sp.worker_id: sp for sp in spans
+             if sp.stage == trace.TASK_DEVICE_WAIT_ALONE}
+    waits = {sp.worker_id: sp for sp in spans
+             if sp.stage == trace.TASK_DEVICE_WAIT}
+    assert sorted(alone) == [0, 2] and sorted(waits) == [0, 1, 2]
+    assert alone[0].dur_ms >= 5.0
+    for wid, sp in alone.items():
+        assert _inside(sp, waits[wid])
+        assert waits[wid].dur_ms - sp.dur_ms < 1.0
+        assert sp.parent_id == waits[wid].parent_id  # both under compute
+
+
+def test_a_task_posts_no_span_in_front_of_its_enqueue(tmp_path):
+    """The stages a task records on its way to the chip (the wake-up, the
+    inbox, the turn, the copies) go to the sink behind the enqueue: a
+    span's post wakes the bus's thread, and on the chip that made a
+    sampled dispatch 0.35 ms longer than an unsampled one (PERF.md section
+    6, PR 41).  By the device's wait they have all been handed over."""
+    from asyncframework_tpu.solvers.instrumentation import worker_task
+
+    log = tmp_path / "held.jsonl"
+    inst = RunInstruments(_cfg(trace_sample=1.0, event_log=str(log)), 1)
+    (ut,) = _sampled(inst, [0]).values()
+    posted = []
+    real_sink = ut._sink
+    ut._sink = lambda sp: (posted.append(sp.stage), real_sink(sp))
+    seen_at = {}
+
+    class _Step(_FakeStep):
+        def block_until_ready(self):
+            seen_at["wait"] = list(posted)
+
+    def dispatch(mine):
+        with trace.span(trace.TASK_ENQUEUE, mine):
+            seen_at["enqueue"] = list(posted)
+        return (_Step(),)
+
+    task = worker_task(dispatch, 0.0, ut)
+    task.on_launch()
+    task()
+    inst.close()
+    assert seen_at["enqueue"] == []
+    assert seen_at["wait"] == [
+        trace.TASK_WAKE, trace.TASK_INBOX, trace.TASK_TURN,
+        trace.TASK_ENQUEUE, trace.TASK_DISPATCH]
+    assert posted[5:] == [trace.TASK_DEVICE_WAIT]
+
+
+def test_a_held_turn_shows_in_task_turn_and_not_in_task_enqueue(tmp_path):
+    import threading
+
+    from asyncframework_tpu.solvers.instrumentation import (
+        DispatchTurns,
+        worker_task,
+    )
+
+    log = tmp_path / "turn.jsonl"
+    inst = RunInstruments(_cfg(trace_sample=1.0, event_log=str(log)), 1)
+    (ut,) = _sampled(inst, [0]).values()
+    turns = DispatchTurns(patience_s=10.0)
+    ahead = turns.ticket()  # a task built first that has not dispatched
+
+    def dispatch(mine):
+        with trace.span(trace.TASK_ENQUEUE, mine):
+            time.sleep(0.002)
+        step = _FakeStep()
+        step.complete.set()
+        return (step,)
+
+    th = threading.Thread(target=worker_task(dispatch, 0.0, ut, turns=turns))
+    th.start()
+    time.sleep(0.03)
+    turns.served(ahead)
+    th.join(timeout=10)
+    assert not th.is_alive()
+    inst.close()
+    spans, _ = trace.load_trace_events(log)
+    by_stage = {sp.stage: sp for sp in spans}
+    turn, enqueue = by_stage[trace.TASK_TURN], by_stage[trace.TASK_ENQUEUE]
+    dispatch_span = by_stage[trace.TASK_DISPATCH]
+    assert turn.dur_ms >= 25.0 and 1.5 <= enqueue.dur_ms < 20.0
+    assert turn.start_ms + turn.dur_ms <= enqueue.start_ms + EPS_MS
+    assert _inside(turn, dispatch_span) and _inside(enqueue, dispatch_span)
+    assert dispatch_span.dur_ms >= turn.dur_ms + enqueue.dur_ms - EPS_MS
+
+
 # ------------------------------------------- the stages on the profiler's clock
 def _host_events(trace_dir):
     from jax.profiler import ProfileData

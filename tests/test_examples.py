@@ -4,6 +4,7 @@ Parity with the reference shipping runnable ``examples/`` alongside the
 framework; keeping them executed in CI prevents doc rot.
 """
 
+import json
 import sys
 from pathlib import Path
 
@@ -129,3 +130,35 @@ def test_sql_analytics_example():
     totals = np.asarray(heavy["total"])
     assert np.all(totals > 500)
     assert np.all(np.diff(totals) <= 0)  # ORDER BY total DESC
+
+
+@pytest.mark.parametrize("chips", [4, 1])
+def test_engine_sketch_reproduces_the_ledgers_cells(chips, capsys):
+    """The sketch fed the medians of the ledger's PR 40 lines (no chip here:
+    the numbers it is held to are the record's, and the bounds are wide,
+    a sketch's).  Four chips (`mnist8m-f32-asgd.steady`: 708.25 updates/s,
+    `device_idle` 28.1%): `compute` 8.31 ms = `task.dispatch` 1.40 + the
+    inbox and self time 1.9 + `task.device_wait` 4.72 + `result.queue`,
+    a step of 4.2414 ms, 0.3 ms from its end to the worker's return.  One
+    chip (`mnist8m-asgd.steady`: a step of 2.26 ms, `task.dispatch` 1.10):
+    eight workers on one queue never leave the chip empty."""
+    import engine_sketch
+
+    if chips == 4:
+        for seed in range(3):
+            got = engine_sketch.simulate(
+                workers=8, chips=4, bucket_ratio=0.7, step_ms=4.2414,
+                inbox_ms=1.9, dispatch_ms=1.40, notice_ms=0.3, seed=seed)
+            assert got["updates_per_s"] == pytest.approx(708.25, rel=0.10)
+            assert abs(got["device_idle"] - 28.1) <= 5.0
+            assert 5.0 <= got["inflight_mean"] <= 8.0
+    else:
+        got = engine_sketch.simulate(
+            workers=8, chips=1, step_ms=2.26, inbox_ms=1.5, dispatch_ms=1.10)
+        assert got["chip_starved"] < 0.1
+        assert got["device_idle"] < 1.0
+        assert got["updates_per_s"] == pytest.approx(1e3 / 2.26, rel=0.01)
+    assert engine_sketch.main(["--chips", str(chips), "--seeds", "2",
+                               "--seconds", "2"]) == 0
+    line = json.loads(capsys.readouterr().out)
+    assert set(line) == set(got) and all(lo <= hi for lo, hi in line.values())

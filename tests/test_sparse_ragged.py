@@ -540,19 +540,26 @@ def test_a_short_asgd_run_agrees_with_the_reference_and_counts_its_shards(
 
 
 def test_dispatch_turns_keep_the_order_the_tasks_were_built_in():
-    """Eight dispatches built in order and started in the reverse one, a
-    thread each: they run in the order they were built.  A ticket whose
-    task never runs holds the ones behind it ``patience_s`` and no longer,
-    and a dispatch run a second time (a retry) goes at once."""
+    """Eight tasks built in order and started in the reverse one, a
+    thread each: their dispatches run in the order they were built.  A
+    ticket whose task never runs holds the ones behind it ``patience_s``
+    and no longer, and a task run a second time (a retry) goes at once."""
     import threading
     import time
 
-    from asyncframework_tpu.solvers.instrumentation import DispatchTurns
+    from asyncframework_tpu.solvers.instrumentation import (
+        DispatchTurns,
+        worker_task,
+    )
+
+    class _Ready:
+        def block_until_ready(self):
+            pass
 
     turns = DispatchTurns(patience_s=5.0)
     ran = []
-    tasks = [turns.in_turn(lambda i=i: ran.append(i) or (i,))
-             for i in range(8)]
+    tasks = [worker_task(lambda mine, i=i: ran.append(i) or (_Ready(), i),
+                         turns=turns) for i in range(8)]
     threads = [threading.Thread(target=t) for t in tasks]
     for th in reversed(threads):
         th.start()
@@ -560,11 +567,11 @@ def test_dispatch_turns_keep_the_order_the_tasks_were_built_in():
     for th in threads:
         th.join(timeout=10)
     assert ran == list(range(8))
-    assert tasks[3]() == (3,) and ran[-1] == 3  # again: its turn is past
+    assert tasks[3]()[1] == 3 and ran[-1] == 3  # again: its turn is past
     lost = DispatchTurns(patience_s=0.05)
-    _never_run = lost.in_turn(lambda: (0,))
+    _never_run = worker_task(lambda mine: (_Ready(), 0), turns=lost)
     t0 = time.monotonic()
-    assert lost.in_turn(lambda: (1,))() == (1,)
+    assert worker_task(lambda mine: (_Ready(), 1), turns=lost)()[1] == 1
     assert 0.04 <= time.monotonic() - t0 < 2.0
 
 

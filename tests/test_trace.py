@@ -213,6 +213,30 @@ class TestSampling:
         assert again == drained        # same spans, same order
 
 
+    def test_held_spans_reach_the_sink_at_release_in_their_order(self):
+        """``UpdateTrace.hold``: what is recorded (by any thread) between a
+        hold and its release is handed over at the release, once, in the
+        order it was recorded; a release with nothing held hands nothing."""
+        seen = []
+        rec = trace.TraceRecorder(sample_rate=1.0, sink=seen.append)
+        ut = rec.start_update(0)
+        ut.add(trace.SUBMIT, 0.0, 1.0)
+        assert [sp.stage for sp in seen] == [trace.SUBMIT]
+        ut.hold()
+        ut.hold()                       # a second copy of the task: no-op
+        ut.add(trace.TASK_WAKE, 1.0, 2.0)
+        with trace.span(trace.TASK_ENQUEUE, ut):
+            pass
+        assert [sp.stage for sp in seen] == [trace.SUBMIT]
+        assert len(ut.spans) == 3       # the handle has them all the while
+        ut.release()
+        assert [sp.stage for sp in seen] == [
+            trace.SUBMIT, trace.TASK_WAKE, trace.TASK_ENQUEUE]
+        ut.release()
+        ut.add(trace.RESULT_QUEUE, 2.0, 3.0)
+        assert [sp.stage for sp in seen][3:] == [trace.RESULT_QUEUE]
+
+
 # --------------------------------------------- Histogram nearest-rank (sat 6)
 class TestHistogramPercentiles:
     def test_small_n_p95_is_not_max(self):
@@ -381,7 +405,8 @@ class TestSingleProcessTracing:
             def block_until_ready(self):
                 time.sleep(0.004)
 
-        task = worker_task(lambda: (_Ready(),), 0.0, ut)
+        task = worker_task(lambda mine: (_Ready(),), 0.0, ut)
+        task.on_launch()                        # submitter: the inbox's put
         task()                                  # executor: the closure
         task()                                  # a retry records nothing
         ut.begin(trace.RESULT_QUEUE)            # executor: the handler
@@ -397,8 +422,11 @@ class TestSingleProcessTracing:
         inst.close()
         spans, _ = trace.load_trace_events(log)
         by_stage = {s.stage: s for s in spans}
+        # the closure alone (no copy, no step of its own, no count of the
+        # chip's steps) adds the wake-up and an empty turn to the four
         assert sorted(s.stage for s in spans) == sorted(
             (trace.SUBMIT, trace.COMPUTE, *trace.COMPUTE_CHILDREN,
+             trace.TASK_WAKE, trace.TASK_TURN,
              trace.MERGE_QUEUE, trace.MERGE_APPLY))
         apply_span = by_stage[trace.MERGE_APPLY]
         assert apply_span.staleness == 2
