@@ -69,20 +69,26 @@ black hole.  This module makes one update's life observable end to end:
                                   turns records it all the same, empty
   task.model_copy  work executor  ONE ``device_put`` of w/key that     task.dispatch
                                   really copies to the worker's chip
+                                  (none in an ASGD run: its model
+                                  lives on every chip, a replica each)
   task.enqueue     work executor  the jitted step's call alone, in ->  task.dispatch
                                   returned (no annotation: PJRT's
                                   ``PjitFunction(step)`` is the same
                                   interval in a device trace)
-  task.device_wait wait executor  ``block_until_ready`` in -> out      compute
+  task.device_wait wait executor  ``block_until_ready`` in -> out;     compute
+                                  over several chips ASGD's task first
+                                  sends ``g`` to every chip, in here
   task.device_wait wait executor  the same interval a second time, for compute
   .alone                          a task that was ALONE: when its
                                   enqueue returned no other task's
                                   step was out on that chip
   result.queue     wait ex -> upd ``merge_result`` put -> drained      compute
   merge.queue      work updater   drained -> apply starts (state lock, compute
-                                  tau filter, cross-chip ``g`` copy)
-  merge.apply      work updater   the stack/apply dispatch of a drain; compute
-                                  ``batch`` = results accepted in it
+                                  tau filter; ASAGA: a cross-chip ``g``
+                                  copy)
+  merge.apply      work updater   the apply dispatch of a drain (one a compute
+                                  chip that holds a replica of the
+                                  model); ``batch`` = results in it
   merge.history    work updater   ASAGA, an accepted result: table     merge.apply
                                   delta + history commit dispatched
   snapshot,        work updater   annotation only                      -

@@ -590,7 +590,8 @@ class ASAGA(EngineSolver):
             # the instance
             return {
                 wid: self._make_task(
-                    wid, w_pub, *captured[wid], delay_model, uts.get(wid),
+                    wid, run.model_for(wid, w_pub), *captured[wid],
+                    delay_model, uts.get(wid),
                 )
                 for wid in cohort
             }

@@ -41,7 +41,11 @@ from asyncframework_tpu.solvers.base import (
     run_fused_plan,
 )
 from asyncframework_tpu.metrics import trace
-from asyncframework_tpu.solvers.engine_loop import EngineRun, EngineSolver
+from asyncframework_tpu.solvers.engine_loop import (
+    EngineRun,
+    EngineSolver,
+    ModelReplicas,
+)
 from asyncframework_tpu.solvers.instrumentation import on_device, worker_task
 
 
@@ -79,23 +83,37 @@ class ASGD(EngineSolver):
         calibrator, delay_model, ckpt = run.calibrator, run.delay_model, run.ckpt
         state, state_lock, stop = run.state, run.state_lock, run.stop
         d = self.ds.d
+        nw, freq = cfg.num_workers, cfg.printer_freq
+        # where the model lives: one buffer on the driver's chip, or,
+        # where the shards lie on several, a replica on each of ``chips``.
+        # The updater then applies every drain to every replica (the same
+        # arithmetic on the same operands: the same bits), a task reads
+        # the replica on its shard's chip and no step waits for a copy
+        # queued behind another chip's steps (PERF.md section 6, PR 47)
+        chips = run.replicate_model()
+
+        def resident(dev):
+            # what the fold takes beside the drain's gradients, resident
+            # before the clock starts so that a drain transfers nothing:
+            # the ONE zero handle that pads a short drain's tuple to the
+            # fold's arity, and every count of live slots as a device
+            # scalar (a Python int is a 0.2 ms transfer a dispatch on the
+            # v5e: 0.53 ms a fold against 0.34, PERF.md section 6, PR 31)
+            return (
+                (jax.device_put(jnp.zeros(d, jnp.float32), dev),) * nw,
+                [jax.device_put(jnp.float32(m), dev) for m in range(nw + 1)],
+            )
+
         # the on-device iteration counter resumes where k stopped
-        state["k_dev"] = jax.device_put(
-            jnp.float32(state["k"]), self.driver_device
-        )
+        k0 = jnp.float32(state["k"])
+        if chips is None:
+            state["k_dev"] = jax.device_put(k0, self.driver_device)
+            zeros, counts = resident(self.driver_device)
+        else:
+            state["k_dev"] = jax.device_put([k0] * len(chips), chips)
+            folds = [resident(dev) for dev in chips]
         run.start_monitors()
         self._warm_hot_path()
-        nw, freq = cfg.num_workers, cfg.printer_freq
-        # what the fold takes beside the drain's gradients, resident
-        # before the clock starts so that a drain transfers nothing: the
-        # ONE zero handle that pads a short drain's tuple to the fold's
-        # arity, and every count of live slots as a device scalar (a
-        # Python int is a 0.2 ms transfer a dispatch on the v5e: 0.53 ms
-        # a fold against 0.34, PERF.md section 6, PR 31)
-        zeros = (jax.device_put(jnp.zeros(d, jnp.float32),
-                                self.driver_device),) * nw
-        counts = [jax.device_put(jnp.float32(m), self.driver_device)
-                  for m in range(nw + 1)]
         run.start_clock()
         snapshots, now_ms = run.snapshots, run.now_ms
 
@@ -146,66 +164,92 @@ class ASGD(EngineSolver):
                             (res, accepted, k + len(accepted_g), task_ms)
                         )
                         if accepted:
-                            g = res.data
-                            if g.device != self.driver_device:
-                                g = jax.device_put(g, self.driver_device)
+                            # the step's gradient, on the model's one chip;
+                            # over several, its buffers by chip (the
+                            # task's thread sent them: worker_task)
                             calibrator.record(k + len(accepted_g), task_ms)
-                            accepted_g.append(g)
+                            accepted_g.append(res.data)
                         else:
                             state["dropped"] += 1
-                    merge_queue.end()
-                    m = len(accepted_g)
-                    # ONE dispatch a drain, split only where a snapshot is
-                    # due: snapshot j holds the model after update
-                    # j * printer_freq + 1, folded or not (a reader of the
-                    # trajectory reckons its updates so, benchmark/
-                    # target.py), so a dispatch ends ON that update
-                    ends = [j + 1 for j in range(-k % freq, m, freq)]
-                    if not ends or ends[-1] < m:
-                        ends.append(m)
-                    lo = 0
-                    for hi in ends:
-                        n = hi - lo
-                        in_it = uts
-                        if uts:
-                            # the sampled updates of THIS dispatch, each
-                            # with what its merge.apply carries; a dropped
-                            # one rides with the slot it was filtered
-                            # before, or with the last
-                            top = hi if hi < m else m + 1
-                            in_it = inst.apply_attrs(
-                                (r, acc) for r, acc, at_k, _ in merged
-                                if lo <= at_k - k < top
-                            )
-                        t_apply = time.perf_counter_ns()
-                        with trace.span(trace.MERGE_APPLY, in_it, batch=n):
-                            if n == 1:
-                                state["w"], state["k_dev"] = self._apply(
-                                    state["w"], accepted_g[lo], state["k_dev"]
-                                )
-                            elif n:
-                                state["w"], state["k_dev"] = self._apply_fold(
-                                    state["w"],
-                                    tuple(accepted_g[lo:hi]) + zeros[n:],
-                                    counts[n], state["k_dev"],
-                                )
-                        inst.updater_apply_ns += (
-                            time.perf_counter_ns() - t_apply
+                merge_queue.end()
+                m = len(accepted_g)
+                # ONE dispatch a drain (a chip), split only where a
+                # snapshot is due: snapshot j holds the model after update
+                # j * printer_freq + 1, folded or not (a reader of the
+                # trajectory reckons its updates so, benchmark/target.py),
+                # so a dispatch ends ON that update.  The dispatches are
+                # made OUTSIDE the state lock: this thread alone writes
+                # the model and the counter, and over several chips a
+                # drain is a dispatch a chip, 1.5 ms in which the
+                # submitter, which takes the lock at every poll and twice
+                # a cohort, would stand still (PERF.md section 6, PR 47);
+                # what a dispatch made is published under the lock, the
+                # model and its count together
+                ends = [j + 1 for j in range(-k % freq, m, freq)]
+                if not ends or ends[-1] < m:
+                    ends.append(m)
+                w_new, k_dev = state["w"], state["k_dev"]
+                lo = 0
+                for hi in ends:
+                    n = hi - lo
+                    in_it = uts
+                    if uts:
+                        # the sampled updates of THIS dispatch, each with
+                        # what its merge.apply carries; a dropped one
+                        # rides with the slot it was filtered before, or
+                        # with the last
+                        top = hi if hi < m else m + 1
+                        in_it = inst.apply_attrs(
+                            (r, acc) for r, acc, at_k, _ in merged
+                            if lo <= at_k - k < top
                         )
-                        if n:
-                            inst.apply_dispatches += 1
+                    t_apply = time.perf_counter_ns()
+                    with trace.span(trace.MERGE_APPLY, in_it, batch=n):
+                        if chips is not None:
+                            if n:
+                                # the same dispatch a chip, each on its
+                                # own buffer of every operand
+                                rows = accepted_g[lo:hi]
+                                ws = list(w_new)
+                                for c, (pad, count_of) in enumerate(folds):
+                                    if n == 1:
+                                        ws[c], k_dev[c] = self._apply(
+                                            ws[c], rows[0][c], k_dev[c])
+                                    else:
+                                        ws[c], k_dev[c] = self._apply_fold(
+                                            ws[c],
+                                            tuple(r[c] for r in rows)
+                                            + pad[n:],
+                                            count_of[n], k_dev[c],
+                                        )
+                                w_new = ModelReplicas(ws)
+                        elif n == 1:
+                            w_new, k_dev = self._apply(
+                                w_new, accepted_g[lo], k_dev)
+                        elif n:
+                            w_new, k_dev = self._apply_fold(
+                                w_new, tuple(accepted_g[lo:hi]) + zeros[n:],
+                                counts[n], k_dev,
+                            )
+                    inst.updater_apply_ns += (
+                        time.perf_counter_ns() - t_apply
+                    )
+                    if n:
+                        inst.apply_dispatches += 1
+                        with state_lock:
+                            state["w"], state["k_dev"] = w_new, k_dev
                             state["k"] = k + hi
                             state["accepted"] += n
                             if (k + hi - 1) % freq == 0:
                                 with trace.span(trace.SNAPSHOT):
-                                    snapshots.append((now_ms(), state["w"]))
+                                    snapshots.append((now_ms(), w_new))
                                     inst.on_snapshot(state["accepted"])
-                        lo = hi
-                    if m:
-                        # range check: a drain jumping over a checkpoint
-                        # boundary must still save
-                        do_save = ckpt.should_save_range(k, k + m)
-                        save_k, save_w = state["k"], state["w"]
+                    lo = hi
+                if m:
+                    # range check: a drain jumping over a checkpoint
+                    # boundary must still save
+                    do_save = ckpt.should_save_range(k, k + m)
+                    save_k, save_w = state["k"], w_new
                 # outside the lock, as ever: the events and the counters
                 for res, accepted, at_k, task_ms in merged:
                     inst.on_gradient_merged(res, accepted, at_k, task_ms)
@@ -409,7 +453,6 @@ class ASGD(EngineSolver):
         arguments never touch live state.
         """
         d = self.ds.d
-        drv = self.driver_device
         g = None
         seen = set()
         for wid in range(self.cfg.num_workers):
@@ -425,23 +468,27 @@ class ASGD(EngineSolver):
             w0 = jax.device_put(jnp.zeros(d, jnp.float32), dev)
             key = jax.device_put(jax.random.PRNGKey(0), dev)
             g, _ = self._step(*shard.operands, w0, key)
-        if g.device != drv:
+        # the accept path on every chip the updater will call it on: the
+        # driver's, or each that holds a replica of the model
+        for drv in self._spread or [self.driver_device]:
             g = jax.device_put(g, drv)
-        wd = jax.device_put(jnp.zeros(d, jnp.float32), drv)
-        kd = jax.device_put(jnp.float32(0.0), drv)
-        if sync:
-            acc = jax.device_put(jnp.zeros(d, jnp.float32), drv)
-            acc = steps.add_grads(acc, g)
-            wd, kd = self._sync_apply(wd, acc, kd)
-        else:
-            wd, kd = self._apply(wd, g, kd)
-            # the fold as the updater calls it: ONE arity, the count as
-            # data, so no drain's size compiles inside the window
-            zero = jax.device_put(jnp.zeros(d, jnp.float32), drv)
-            wd, kd = self._apply_fold(
-                wd, (zero,) * self.cfg.num_workers,
-                jax.device_put(jnp.float32(2.0), drv), kd,
-            )
+            wd = jax.device_put(jnp.zeros(d, jnp.float32), drv)
+            kd = jax.device_put(jnp.float32(0.0), drv)
+            if sync:
+                acc = jax.device_put(jnp.zeros(d, jnp.float32), drv)
+                acc = steps.add_grads(acc, g)
+                wd, kd = self._sync_apply(wd, acc, kd)
+            else:
+                # (``g`` is donated: the next chip's is a copy of ``wd``)
+                wd, kd = self._apply(wd, g, kd)
+                # the fold as the updater calls it: ONE arity, the count
+                # as data, so no drain's size compiles inside the window
+                zero = jax.device_put(jnp.zeros(d, jnp.float32), drv)
+                wd, kd = self._apply_fold(
+                    wd, (zero,) * self.cfg.num_workers,
+                    jax.device_put(jnp.float32(2.0), drv), kd,
+                )
+            g = wd
         wd.block_until_ready()
 
     def _make_task(self, wid: int, w_pub, key, delay_model: DelayModel,
@@ -466,7 +513,8 @@ class ASGD(EngineSolver):
         return worker_task(dispatch, delay_ms, ut, worker=wid, chip=dev.id,
                            width=self._programs.widths[wid],
                            turns=None if delay_ms > 0 else self._turns.get(dev),
-                           steps_out=self._steps_out.get(dev))
+                           steps_out=self._steps_out.get(dev),
+                           spread=self._spread.get(dev))
 
     def _task_maker(self, run: EngineRun):
         """``make_tasks`` of this run (``EngineRun.drive``): a task captures
@@ -481,7 +529,8 @@ class ASGD(EngineSolver):
             # the instance
             return {
                 wid: self._make_task(
-                    wid, w_pub, keys[wid], delay_model, uts.get(wid)
+                    wid, run.model_for(wid, w_pub), keys[wid], delay_model,
+                    uts.get(wid)
                 )
                 for wid in cohort
             }

@@ -104,8 +104,8 @@ def planned_snapshots(cfg: "SolverConfig") -> int:
 def planned_model_copies(cfg: "SolverConfig",
                          eval_stack_rows: Optional[int] = None) -> int:
     """The model-sized device buffers (``d`` f32 each) a run of ``cfg``
-    may hold at once, beside its shards: the live model; one result a
-    worker, computed and not yet applied (a result is a DENSE ``d``-vector
+    may hold at once ON ONE CHIP, beside its shards: the live model; one
+    result a worker, computed and not yet applied (a result is a DENSE ``d``-vector
     whatever its batch touched; behind an updater that has stalled up to
     two fleets more can queue, which the planner's headroom is for, not
     this count); one pinned model version a worker (a task
@@ -114,7 +114,15 @@ def planned_model_copies(cfg: "SolverConfig",
     them (``eval_stack_rows``, the dataset's programs' own: eight rows
     over padded ELL; ``None``, all of them, over a dense shard); and
     the versioned store's ring where workers read stale versions.  What
-    ``TrainResult.extras["model_copies_peak"]`` counts in a run."""
+    ``TrainResult.extras["model_copies_peak"]`` counts in a run, by the
+    HANDLE.  Over several chips the count holds for EVERY chip: each holds
+    a replica of the live model, a buffer of every pinned version and of
+    every snapshot (a version is one handle with a buffer a chip,
+    ``engine_loop.ModelReplicas``) and receives every result, so
+    :func:`check_hbm_plan` charges the whole count to each device, the
+    one with the most shard bytes included (until the model lived on
+    every chip only the driver's chip held it all, and the plan was
+    pessimistic for the others)."""
     snapshots = planned_snapshots(cfg)
     ring = cfg.max_live_versions if cfg.stale_read_offset is not None else 0
     stack = snapshots if eval_stack_rows is None else eval_stack_rows
@@ -129,7 +137,8 @@ def check_hbm_plan(X, cfg: "SolverConfig", devices, history_table: bool,
     residency measured and comes with its ``programs``
     (``ops.steps.worker_programs``), which say what an evaluation call
     stacks and what a fleet of steps holds in temporaries; the engine's
-    model-sized state is :func:`planned_model_copies`, free at 3 kB a copy
+    model-sized state is :func:`planned_model_copies`, counted a chip (over
+    several chips every one holds it), free at 3 kB a copy
     and a third of the chip at 219 MB.  Raises ``MemoryError``
     with the planner's accounting when the budget is oversubscribed."""
     from asyncframework_tpu.utils.hbm import plan_for_run

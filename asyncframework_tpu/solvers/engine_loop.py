@@ -71,6 +71,12 @@ class EngineSolver:
     #: own, set where the run is built; none before a solver's first run
     _steps_out: Dict = {}
     _turns: Dict = {}
+    #: where the run's model lives on several chips: by each chip that
+    #: holds a replica, in the replicas' order, what sends the gradient of
+    #: a step that ran there to all of them
+    #: (``EngineRun.replicate_model``; empty where the model is one buffer
+    #: on the driver's chip)
+    _spread: Dict = {}
 
     def _place(self, X, y: Optional[np.ndarray], config: SolverConfig,
                devices: Optional[list], history: bool) -> None:
@@ -189,7 +195,11 @@ class EngineSolver:
         slots they picked: the blocks at the width the evaluation reads
         (equal where it was built at the stored width)."""
         t0 = time.perf_counter()
-        handles = [h for (_t, h) in snapshots]
+        # a replicated version (ModelReplicas) is read through its first
+        # buffer: a view, nothing new is held, and the stack below lies on
+        # ONE device, which keys its copies to the shards' chips
+        handles = [h[0] if type(h) is ModelReplicas else h
+                   for (_t, h) in snapshots]
         programs = self._programs
         evaluate = programs.evaluate
         per_call = programs.eval_stack_rows or len(handles)
@@ -285,6 +295,41 @@ class DispatchTurns:
             self._cv.notify_all()
 
 
+class ModelReplicas(tuple):
+    """One model version where a run's shards lie on several chips: a
+    buffer a chip, in the order of the run's ``chips``
+    (:meth:`EngineRun.replicate_model`), every one the same bits (each
+    chip runs the same apply on the same operands).  It is the handle the
+    submitter pins and hands out, a snapshot keeps and the account counts
+    ONCE (``EngineRun.count_copies`` counts handles); a task's step is
+    called with the buffer on its shard's chip (``EngineRun.model_for``).
+    Read back, it is its first buffer."""
+
+    __slots__ = ()
+
+    def __array__(self, dtype=None, copy=None):
+        return np.asarray(self[0], dtype=dtype)
+
+
+def _spreader(chips: List, home):
+    """``spread(g)`` for the steps that run on ``home``: the gradient ``g``
+    on every one of ``chips``, in their order (``g`` itself at ``home``'s
+    place), as the updater's applies take it.  The thread that called the
+    step calls this at once, in front of its wait for ``g``: the copies
+    are queued behind the step on the device's side, their host calls
+    (0.26 ms each on the v5e's host) fall into the step's own time, and
+    no other chip's queue is held (PERF.md section 6, PR 47)."""
+    others = [dev for dev in chips if dev != home]
+    at = chips.index(home)
+
+    def spread(g):
+        row = jax.device_put([g] * len(others), others)
+        row.insert(at, g)
+        return tuple(row)
+
+    return spread
+
+
 class EngineRun:
     """One run of an :class:`EngineSolver`, asynchronous or (``sync``) one
     driver thread that submits to all and drains all each round.
@@ -311,6 +356,7 @@ class EngineRun:
         ragged = len(set(solver._programs.widths)) > 1
         solver._turns = (
             {dev: DispatchTurns() for dev in solver.devices} if ragged else {})
+        solver._spread = {}
         self.delay_model = DelayModel(cfg.coeff, nw, cfg.seed)
         # sync counts rounds, not accepted gradients: the reference's
         # k < 100*numPart window covers the first 100 full-drain rounds.
@@ -345,6 +391,11 @@ class EngineRun:
         self.pinned: Dict[int, int] = {}
         self.copies = {"results_held_max": 0, "versions_pinned_max": 0,
                        "model_copies_peak": 0}
+        #: the chips that hold a replica of the model each, where the
+        #: solver's updater applies to replicas (:meth:`replicate_model`);
+        #: None where the model is one buffer on the driver's chip
+        self.chips: Optional[List] = None
+        self._chip_index: Dict = {}
         self._snapshot_ids: set = set()
         self._snapshots_counted = 0
         self._ft = self._spec = self._alloc = None
@@ -459,6 +510,47 @@ class EngineRun:
     def now_ms(self) -> float:
         return (time.monotonic() - self.start_wall) * 1e3
 
+    # ------------------------------------------------- where the model lives
+    def replicate_model(self) -> Optional[List]:
+        """The choice between one buffer and a replica a chip, made once,
+        from what the run can observe: the devices its shards lie on.  On
+        one device nothing is done and None is returned: the model is the
+        one buffer :meth:`restore` placed.  On several, ``state["w"]``
+        becomes a :class:`ModelReplicas` over them (a re-homed shard goes
+        to a chip of the same set, ``ShardRecovery``) and they are
+        returned: the solver's updater then applies every drain to every
+        replica, and a task's result is its gradient on every chip
+        (``solver._spread``, which ``worker_task`` calls).  A solver whose
+        updater applies on the driver's chip alone does not call this, and
+        its tasks copy the model as ever."""
+        solver = self.solver
+        on = {solver._recovery.shard(wid).device
+              for wid in range(self.cfg.num_workers)}
+        if len(on) < 2:
+            return None
+        self.chips = [dev for dev in solver.devices if dev in on]
+        self._chip_index = {dev: i for i, dev in enumerate(self.chips)}
+        solver._spread = {dev: _spreader(self.chips, dev)
+                          for dev in self.chips}
+        self.state["w"] = ModelReplicas(
+            jax.device_put([self.state["w"]] * len(self.chips), self.chips))
+        return self.chips
+
+    def model_for(self, wid: int, w_pub):
+        """What worker ``wid``'s task is handed of version ``w_pub``, on the
+        thread that builds a cohort's tasks: of a replicated version the
+        buffer on its shard's chip (the recovery view's: a re-homed shard
+        reads its new chip's replica); anything else as it is (one buffer
+        on the driver's chip, a version of the ``VersionedModelStore``, a
+        test's plain array), which the task copies where it lies on
+        another chip (``instrumentation.on_device``).  Counted either way:
+        ``model_reads_local`` / ``model_reads_copied``."""
+        dev = self.solver._recovery.shard(wid).device
+        if type(w_pub) is ModelReplicas:
+            w_pub = w_pub[self._chip_index[dev]]
+        self.inst.on_model_read(w_pub.device == dev)
+        return w_pub
+
     # ------------------------------------------------- the model-sized state
     def pin(self, cohort, w_pub) -> None:
         """The submitter hands ``cohort`` the model version ``w_pub``: each
@@ -475,9 +567,14 @@ class EngineRun:
         """One reading of the account, by the thread that holds
         ``state_lock`` (the updater at a drain; the main thread once the
         run is over): ``results_held`` results computed and not yet
-        applied, and the distinct model-sized buffers in all: the live model, those
-        versions and the snapshots (one buffer may be all three), the
-        results, and ``stack_rows`` rows of an evaluation call's stack."""
+        applied, and the distinct model-sized HANDLES in all: the live
+        model, those versions and the snapshots (one handle may be all
+        three), the
+        results, and ``stack_rows`` rows of an evaluation call's stack.
+        A handle is one buffer, or over several chips a
+        :class:`ModelReplicas` and a result sent to every chip: each
+        chip then holds one buffer of each, so the count is also what ONE
+        chip holds (``base.planned_model_copies`` plans it a chip)."""
         with self.key_lock:
             versions = set(self.pinned.values())
         seen = self._snapshot_ids
@@ -650,6 +747,8 @@ class EngineRun:
         # dispatched.  Whether block_until_ready alone suffices here is
         # ROADMAP Design 8; the result needs final_w on the host anyway.
         t_fence = time.monotonic()
+        if self.chips is not None:
+            jax.block_until_ready(tuple(final_w_dev))  # every chip's applies
         final_w = np.asarray(final_w_dev)
         occupied = (inst.occupancy.close()
                     if inst.occupancy is not None else {})

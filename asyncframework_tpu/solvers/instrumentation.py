@@ -228,7 +228,12 @@ class Occupancy:
 def on_device(arr, device, ut=None):
     """``arr`` on the worker's chip; a copy from another chip is one
     ``task.model_copy`` (inside ``task.dispatch``; ``ut``: the handle of a
-    sampled update whose task stages this copy of the task records)."""
+    sampled update whose task stages this copy of the task records).  What
+    still copies: a model that is one buffer on the driver's chip (ASAGA's,
+    a synchronous run's, a version of the ``VersionedModelStore``, a
+    test's plain array) and what followed a re-homed shard late (a key, a
+    history slice).  ASGD's model lives on every chip and is handed to a
+    task as the buffer on its chip (``EngineRun.model_for``)."""
     if arr.device != device:
         with trace_mod.span(trace_mod.TASK_MODEL_COPY, ut):
             arr = jax.device_put(arr, device)
@@ -266,7 +271,8 @@ def worker_task(dispatch: Callable[[Optional["trace_mod.UpdateTrace"]], tuple],
                 worker: int = -1, chip: int = -1,
                 width: Optional[int] = None,
                 turns=None,
-                steps_out: Optional[StepsOut] = None):
+                steps_out: Optional[StepsOut] = None,
+                spread: Optional[Callable] = None):
     """The closure every worker task is (ASGD and ASAGA, ``run`` and
     ``run_sync``): ``dispatch(mine)`` moves what the step needs to the
     worker's chip and dispatches the step, returning its outputs, gradient
@@ -289,6 +295,13 @@ def worker_task(dispatch: Callable[[Optional["trace_mod.UpdateTrace"]], tuple],
     children everywhere).  ``steps_out``: the chip's :class:`StepsOut`,
     kept for every task; a sampled one that was alone there records its
     wait a second time as ``task.device_wait.alone``.
+
+    ``spread``: where the run's model lives on several chips
+    (``engine_loop.EngineRun.replicate_model``), what sends the step's
+    gradient to every one of them; it is called once the step is enqueued,
+    in front of the wait for it (inside ``task.device_wait``: the copies'
+    host time is time this thread would wait anyway), and the task's
+    result then carries the gradient's buffers by chip in place of ``g``.
 
     ``worker`` and ``chip`` (the device's id) go on ``task.dispatch``'s
     annotation: a reader of a device trace can tell a dispatch TO the chip
@@ -337,11 +350,13 @@ def worker_task(dispatch: Callable[[Optional["trace_mod.UpdateTrace"]], tuple],
             with trace_mod.span(trace_mod.TASK_DEVICE_WAIT, mine), \
                     trace_mod.span(trace_mod.TASK_DEVICE_WAIT_ALONE,
                                    mine if alone else None):
+                if spread is not None:
+                    everywhere = spread(out[0])
                 out[0].block_until_ready()
         finally:
             if steps_out is not None:
                 steps_out.completed()
-        return out
+        return out if spread is None else (everywhere, *out[1:])
 
     if ut is not None:
         def on_launch():
@@ -383,6 +398,12 @@ class RunInstruments:
         #: (ASGD's engine run counts them, one a drain where it folds; 0
         #: from a run that does not count)
         self.apply_dispatches = 0
+        #: tasks whose step took the model from a buffer already on its
+        #: chip, and tasks that paid a ``device_put`` for it: counted where
+        #: a cohort's tasks are built, on that one thread
+        #: (``EngineRun.model_for``)
+        self.model_reads_local = 0
+        self.model_reads_copied = 0
         self.submit_empty_polls = 0   # submitter turns that found no cohort
         #: the sleeps of those turns, by what the turn saw (the two holds
         #: and ``wait.workers`` of metrics/trace.py): they sum to the
@@ -615,6 +636,12 @@ class RunInstruments:
     def on_snapshot(self, accepted: int) -> None:
         self.snapshot_updates.append(int(accepted))
 
+    def on_model_read(self, local: bool) -> None:
+        if local:
+            self.model_reads_local += 1
+        else:
+            self.model_reads_copied += 1
+
     def on_worker_lost(self, worker_id: int, reason: str) -> None:
         with self._lock:
             self.workers_lost += 1
@@ -708,6 +735,8 @@ class RunInstruments:
             "drains": self.drains,
             "drain_items_max": self.drain_items_max,
             "apply_dispatches": self.apply_dispatches,
+            "model_reads_local": self.model_reads_local,
+            "model_reads_copied": self.model_reads_copied,
             "task_retries": int(task_retries),
             "compiles_in_run": compiles_so_far() - self._compiles0,
         })

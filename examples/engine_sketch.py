@@ -22,15 +22,20 @@ jitter:
 
 ``inbox_ms`` is ``task.inbox`` (the submitter's work in front of the put
 and ``task.wake``), ``dispatch_ms`` is ``task.dispatch`` (``task.turn`` +
-``task.model_copy`` + ``task.enqueue`` + its own time), ``launch_ms`` +
+``task.model_copy``, which is nothing where the model lives on every chip,
++ ``task.enqueue`` + its own time), ``launch_ms`` +
 ``notice_ms`` is what ``task.device_wait.alone`` reads over the step's
 device time (``empty_chip_wait_excess_ms``).
 
 What it leaves out: the interpreter lock (eight executor threads and the
 two serial ones share ONE: here every task's host path runs beside the
 others'), the updater (a result makes its worker available the moment it
-is queued, as in the program, and nothing here applies it), the backlog
-bound, host stalls, steps of unequal length.  It reads the same account
+is queued, as in the program, and nothing here applies it) and with it
+every lock the two serial threads share (PR 47: the submitter stood
+behind the updater's dispatches under the state lock, which no stage's
+median shows as such; the sketch priced the model on every chip at +5.7%
+and the chip read -4.5% with that lock held and +14% without), the
+backlog bound, host stalls, steps of unequal length.  It reads the same account
 the program keeps (``instrumentation.Occupancy``): a task is in flight
 from its submit to its result, a chip is empty while none of its workers
 is.

@@ -63,15 +63,19 @@ def engine_runs(monkeypatch):
 class Dispatches:
     """Spies on ``engine._apply`` and ``engine._apply_fold``: one record a
     dispatch of the timed run, in order: ``(kind, gradients that counted,
-    k before, w after, k after)``, all on the host."""
+    k before, w after, k after)``, all on the host.  Where the model lives
+    on several devices every drain is the same dispatch on each of them:
+    the records are the driver's device's (that the others hold the same
+    bits is ``tests/test_model_replicas.py``'s)."""
 
     def __init__(self, engine, monkeypatch):
         self.records = []
         self.armed = False
         real_apply, real_fold = engine._apply, engine._apply_fold
+        driver = engine.driver_device
 
         def apply(w, g, k):
-            if not self.armed:
+            if not self.armed or w.device != driver:
                 return real_apply(w, g, k)
             g_host, k0 = np.array(g), float(k)  # g and k are donated
             w2, k2 = real_apply(w, g, k)
@@ -81,7 +85,7 @@ class Dispatches:
             return w2, k2
 
         def fold(w, gs, m, k):
-            if not self.armed:
+            if not self.armed or w.device != driver:
                 return real_fold(w, gs, m, k)
             assert len(gs) == engine.cfg.num_workers  # ONE arity
             live, k0 = [np.array(g) for g in gs[:int(m)]], float(k)
@@ -257,7 +261,10 @@ def test_the_fold_compiles_once_for_every_drain_size(problem, monkeypatch):
     X, y = problem
     nw = 8
     cfg = _cfg(num_workers=nw, num_iterations=400, printer_freq=1000)
-    engine = ASGD(X, y, cfg)
+    # (one device: a drain is ONE dispatch there, so the sizes asked for
+    # below are the sizes folded; over several a drain is a dispatch a
+    # device and more results queue behind it)
+    engine = ASGD(X, y, cfg, devices=jax.devices()[:1])
     spies = Dispatches(engine, monkeypatch)
     sizes = iter(list(range(2, nw + 1)) * 6)
     real = AsyncContext.collect_all

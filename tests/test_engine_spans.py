@@ -154,9 +154,12 @@ def test_the_inbox_and_the_dispatch_have_their_own_children(
     """ISSUE 41: on a sampled update ``task.wake`` lies inside
     ``task.inbox``; ``task.turn``, every ``task.model_copy`` and
     ``task.enqueue`` lie inside ``task.dispatch``, in that order, and do
-    not overlap.  Four workers on four of the CPU's devices: worker 0's
-    shard is on the driver's device and copies nothing, the others copy
-    the model."""
+    not overlap.  Four workers on four of the CPU's devices.  ASAGA's
+    model is one buffer on the driver's device: worker 0's shard lies
+    there and copies nothing, the others copy the model.  ASGD's model
+    lives on every device its shards lie on (ISSUE 47): no task copies
+    anything, and ``task.turn`` and ``task.enqueue`` are still inside
+    ``task.dispatch``, in order."""
     log = tmp_path / "run.jsonl"
     gamma = 0.4 if solver_cls is ASGD else 0.05
     res = _run(solver_cls, "run", problem, trace_sample=1.0, gamma=gamma,
@@ -176,7 +179,7 @@ def test_the_inbox_and_the_dispatch_have_their_own_children(
         (turn,) = by_stage[trace.TASK_TURN]
         (enqueue,) = by_stage[trace.TASK_ENQUEUE]
         moved = by_stage.get(trace.TASK_MODEL_COPY, [])
-        assert bool(moved) == (inbox.worker_id != 0)
+        assert bool(moved) == (solver_cls is ASAGA and inbox.worker_id != 0)
         copies += len(moved)
         inner = [turn, *sorted(moved, key=lambda sp: sp.start_ms), enqueue]
         for sp in inner:
@@ -192,7 +195,13 @@ def test_the_inbox_and_the_dispatch_have_their_own_children(
             assert sp.parent_id == compute.span_id
             assert _inside(sp, wait) and wait.dur_ms - sp.dur_ms < 1.0
     assert complete >= res.accepted - 8
-    assert copies >= complete // 2
+    assert copies >= complete // 2 if solver_cls is ASAGA else copies == 0
+    tasks = res.extras["model_reads_local"] + res.extras["model_reads_copied"]
+    assert tasks >= res.accepted
+    if solver_cls is ASGD:
+        assert res.extras["model_reads_copied"] == 0
+    else:  # three workers in four lie off the driver's device
+        assert res.extras["model_reads_copied"] >= tasks // 2
     assert alone >= 1  # one worker a device here: most tasks are alone
 
 
@@ -235,7 +244,10 @@ def test_a_retried_copy_records_none_of_the_inner_stages(
         seen.update(stages)
         for st in trace.TASK_STAGES:
             assert stages.count(st) <= 1, (st, stages)
-    assert set(trace.TASK_STAGES) <= seen
+    # (ASGD's tasks copy no model: it lives on every device, ISSUE 47)
+    copied = {trace.TASK_MODEL_COPY} if solver_cls is ASGD else set()
+    assert set(trace.TASK_STAGES) - copied <= seen
+    assert not copied & seen
 
 
 class _FakeStep:

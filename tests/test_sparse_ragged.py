@@ -695,6 +695,40 @@ def test_the_plan_counts_the_wide_steps_temporaries():
     solver_base.check_hbm_plan(ds, roomy, devs, False, programs)
 
 
+@pytest.mark.parametrize("chips", [1, 4])
+def test_the_plan_charges_every_chip_the_model_sized_state(chips):
+    """ISSUE 47: shards of unequal width dealt over ``chips`` devices: the
+    plan charges the device with the most shard bytes its shards, the
+    steps' temporaries AND the whole of ``planned_model_copies``, because
+    every chip holds the live model, each worker's pinned version, each
+    result and each snapshot; over one device that is the parent's plan."""
+    import dataclasses
+
+    from asyncframework_tpu.utils.hbm import dataset_residency_bytes
+
+    devs = jax.devices()[:chips]
+    ds = SparseShardedDataset.generate_on_device(
+        1_027, D, 96, 4, devs, seed=3, noise=0.0, column_skew=0.5,
+        row_nnz=LAW, row_values=VALUES, bernoulli_labels=LABELS)
+    cfg = _cfg(num_workers=4)
+    programs = steps.worker_programs(ds, cfg.batch_rate, cfg.loss)
+    per_dev = dataset_residency_bytes(ds)
+    assert len(per_dev) == chips
+    copies = solver_base.planned_model_copies(cfg, programs.eval_stack_rows)
+    assert copies == 1 + 2 * 4 + solver_base.planned_snapshots(cfg) + (
+        programs.eval_stack_rows)
+    held = (max(per_dev.values()) + copies * 4 * D
+            + programs.workspace_bytes)
+    fits = dataclasses.replace(cfg, hbm_budget_bytes=int(held / 0.85) + 1)
+    solver_base.check_hbm_plan(ds, fits, devs, False, programs)
+    # no room for the live model and a pinned version a worker on the
+    # fullest chip: refused, although three other chips would hold them
+    short = dataclasses.replace(
+        cfg, hbm_budget_bytes=int((held - (1 + 4) * 4 * D) / 0.85))
+    with pytest.raises(MemoryError):
+        solver_base.check_hbm_plan(ds, short, devs, False, programs)
+
+
 # ------------------------------------------------ on the profiler's clock
 def test_the_dispatchs_annotation_says_the_shards_width(tmp_path):
     from jax.profiler import ProfileData

@@ -7,6 +7,7 @@ experiment -- each exercised through an actual training run, not a unit
 harness.
 """
 
+import dataclasses
 import threading
 import time
 
@@ -280,6 +281,49 @@ class TestHBMPlanWiring:
         # the same residency with a 3 kB model fits either way
         small = types.SimpleNamespace(**{**vars(ds), "d": 784})
         check_hbm_plan(small, cfg, [dev], False, programs)
+
+    @pytest.mark.parametrize("chips", [1, 4])
+    def test_the_plan_counts_the_model_sized_state_once_a_chip(self, chips):
+        """ISSUE 47: over several chips the model lives on every one, so
+        EVERY chip holds the live model, a buffer of each worker's pinned
+        version, of each result and of each snapshot: the plan charges
+        :func:`planned_model_copies` to each device beside ITS shards.
+        kdd2012's eight shards and 219 MB model, on one chip and dealt
+        over four: over one the count and the charge are the parent's."""
+        import types
+
+        import jax
+
+        from asyncframework_tpu.solvers.base import (
+            check_hbm_plan,
+            planned_model_copies,
+        )
+
+        devs = jax.devices()[:chips]
+        rows, d, nw = 4_676_222, 54_686_452, 8
+        shard_bytes = rows * 16 * (4 + 4) + rows * 4
+        ds = types.SimpleNamespace(
+            n=nw * rows, d=d, num_workers=nw,
+            shard=lambda wid: types.SimpleNamespace(
+                device=devs[wid % chips], nbytes=shard_bytes))
+        programs = types.SimpleNamespace(eval_stack_rows=8, workspace_bytes=0)
+        cfg = cfg_with(num_iterations=240, printer_freq=20, batch_rate=0.05)
+        copies = planned_model_copies(cfg, 8)
+        # live + a result and a pinned version a worker + 14 snapshots + a
+        # stack of eight: the parent's count, whatever the chips
+        assert copies == 1 + 2 * nw + 14 + 8
+        a_chip = (nw // chips) * shard_bytes + copies * 4 * d
+
+        def budget(held):
+            return dataclasses.replace(
+                cfg, hbm_budget_bytes=int(held / 0.85) + 1)
+
+        check_hbm_plan(ds, budget(a_chip), devs, False, programs)
+        # a budget with no room for the live model and the workers' pinned
+        # versions ON THIS CHIP is refused, on four chips as on one
+        with pytest.raises(MemoryError, match="exceeds the"):
+            check_hbm_plan(ds, budget(a_chip - (1 + nw) * 4 * d), devs,
+                           False, programs)
 
     def test_asaga_stale_read_offset_run(self, devices8, problem):
         X, y, _ = problem
