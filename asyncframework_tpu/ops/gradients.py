@@ -16,10 +16,11 @@ per-sample JNI BLAS):
 TPU mapping: a whole shard's sampled mini-batch gradient is ``r = X @ w - y``
 then ``g = X^T @ (mask * r)``.  Sampling is a Bernoulli *mask* (static
 shapes; no dynamic gather), so a "sampled subset" costs one elementwise
-multiply instead of a shape-changing filter -- and on the TPU the filter
-would be no saving at all: a dense shard whose ``d`` is no multiple of 128
-is stored column-major there (rows minor, PERF.md section 3), so picking
-rows means relaying all of it and no step reads less than the whole shard.
+multiply instead of a shape-changing filter -- and on the TPU a filter by
+the ROW would be no saving at all: a dense shard whose ``d`` is no multiple
+of 128 is stored column-major there (rows minor, PERF.md section 3), so
+picking rows means relaying all of it; what can be left out is a whole
+lane tile of 128 rows none of which was drawn (below).
 
 The byte model: ONE read of the shard a step where :func:`dense_step_path`
 says ``"onepass"`` (a TPU, a column-major shard): the Pallas kernel
@@ -33,7 +34,12 @@ lane-aligned widths (``d % 128 == 0``: the shard is stored row-major and
 ``X.T`` would be a real transpose), and the oracle the kernel is tested
 against.  Callers that need a bare ``X w`` (the losses, the trajectory
 evaluation) and ASAGA's table delta (``steps.make_saga_table_delta``: one
-product, one read) stay XLA's.
+product, one read) stay XLA's.  LESS than one read where the draw is thin
+(``"onepass_tiles"``, PR 49): the device's granule is a lane tile of 128
+rows, a tile with no sampled row adds exact zeros, and at ``b`` = 0.01
+more than a quarter of the tiles hold none:
+``pallas_kernels.dense_onepass_tiles`` fetches the others, tile by tile,
+and runs the same arithmetic over them.
 """
 
 from __future__ import annotations
@@ -114,45 +120,88 @@ def _kernels():
     return pallas_kernels
 
 
-def dense_step_path(X) -> str:
-    """``"onepass"`` or ``"two_products"``: which program
-    :func:`dense_masked_grad` traces for the shard ``X`` (an array, a
-    tracer or a ``ShapeDtypeStruct``), from what can be observed when the
-    step is built -- the backend, the rank, the width and the dtype.
+#: rows of the shard one lane tile of ``X.T`` holds: the granule of the
+#: device's storage, and of the tile-list kernel's fetch
+_TILE_ROWS = 128
+#: expected share of a shard's lane tiles that hold a sampled row under
+#: which the tile-list kernel (``pallas_kernels.dense_onepass_tiles``) is
+#: chosen over the whole-shard one.  On the v5e (1.0M x 784 bf16, ASAGA's
+#: step both ways over a sweep of rates; PERF.md section 6, PR 49) the two
+#: break even between a share of 0.961 (``b`` 0.025: 2.309 ms against
+#: 2.339) and 0.980 (0.03: 2.351): a listed tile costs 4% more than a
+#: streamed one (its DMA's descriptor, a wait of its own) and the list
+#: 0.09 ms a step.  Set where the gain is 2% or more (0.946: 2.275 ms);
+#: at ASAGA's 0.724 it is 24%
+DENSE_TILES_BREAK_EVEN = 0.95
 
-    The one-pass kernel is right only where ``X.T`` is a ``bitcast``: on
+
+def dense_tiles_share(batch_rate) -> float:
+    """Expected share of a shard's 128-row lane tiles that hold a sampled
+    row under a Bernoulli draw at ``batch_rate``: ``1 - (1 - b)^128``
+    (0.724 at ASAGA's 0.01, 1.0 to six places at ASGD's 0.1); 1.0 where no
+    rate is known."""
+    if batch_rate is None:
+        return 1.0
+    return 1.0 - (1.0 - batch_rate) ** _TILE_ROWS
+
+
+def dense_step_path(X, batch_rate=None) -> str:
+    """``"onepass"``, ``"onepass_tiles"`` or ``"two_products"``: which
+    program :func:`dense_masked_grad` traces for the shard ``X`` (an
+    array, a tracer or a ``ShapeDtypeStruct``), from what can be observed
+    when the step is built -- the backend, the rank, the width, the dtype
+    and the rate of the draw.
+
+    The one-pass kernels are right only where ``X.T`` is a ``bitcast``: on
     the TPU, whose compiler stores an ``(n, d)`` array with ``d % 128 !=
     0`` column-major (784, 2000, 64: ``{0,1}``) and one with ``d % 128 ==
     0`` row-major (``tests/test_step_layout.py`` reads both off compiled
-    programs).  What the kernel itself takes (f32 or bf16, ``d`` a whole
-    number of sublane tiles, a block that fits VMEM) is
-    ``pallas_kernels.onepass_takes``.  On the v5e it won at every shape timed
-    (PERF.md section 6, PR 26: 1.0M and 253k x 784 bf16, 1.0M x 784 and
-    50k x 2,000 f32), so nothing else is asked.
+    programs).  What the kernels take (f32 or bf16, ``d`` a whole number
+    of sublane tiles, a block that fits VMEM) is
+    ``pallas_kernels.onepass_takes``.  On the v5e one read won at every
+    shape timed (PERF.md section 6, PR 26: 1.0M and 253k x 784 bf16, 1.0M
+    x 784 and 50k x 2,000 f32).  WHICH one-pass kernel is the draw's
+    doing: one algorithm whose fetch wants another granule at another
+    rate.  Where the draw leaves enough lane tiles without a sampled row
+    (:func:`dense_tiles_share` under :data:`DENSE_TILES_BREAK_EVEN`:
+    ASAGA's ``b`` 0.01, and an ASGD recipe at that rate alike) the kernel
+    over the list of the tiles that hold one; else, and where the caller
+    knows no rate, the kernel over the whole shard.
     """
     if (_on_tpu() and len(X.shape) == 2 and X.shape[1] % 128 != 0
             and _kernels().onepass_takes(X.shape[1], X.dtype)):
+        if dense_tiles_share(batch_rate) < DENSE_TILES_BREAK_EVEN:
+            return "onepass_tiles"
         return "onepass"
     return "two_products"
 
 
-def dense_masked_grad(X, y, w, mask, alpha=None, logistic: bool = False):
+def dense_masked_grad(X, y, w, mask, alpha=None, logistic: bool = False,
+                      batch_rate=None):
     """``(g, diff)`` of a dense worker step over a whole shard: ``diff =
     link(X w) - y`` (``link`` the identity, or the sigmoid with
     ``logistic``) and ``g = X^T (mask * (diff [- alpha]))``.
 
     THE definition behind both dense losses' gradient sums and the ASAGA
     step, and the ONE place the program is chosen (:func:`dense_step_path`):
-    one read of the shard through the Pallas kernel, or the two XLA
-    products.  With ``alpha`` (ASAGA: ``diff`` are the candidate history
-    scalars) ``g`` keeps its f32 vector and promotes the shard on either
-    path, as ``steps.make_saga_table_delta`` does and for its reason;
-    without it the two-product path is ``mm_f32``'s, as ever.
+    one read of the shard, or of its lane tiles that hold a sampled row,
+    through a Pallas kernel, or the two XLA products.  ``batch_rate``: the
+    rate ``mask`` was drawn at, where the caller holds one (a Python
+    number: it picks the program, it is no operand).  With ``alpha``
+    (ASAGA: ``diff`` are the candidate history scalars) ``g`` keeps its
+    f32 vector and promotes the shard on every path, as
+    ``steps.make_saga_table_delta`` does and for its reason; without it
+    the two-product path is ``mm_f32``'s, as ever.  ``diff`` at a row
+    ``mask`` does not mark is the row's own value on two of the paths and
+    0 or that on the third (a tile with no sampled row is never read):
+    callers select or weigh it by ``mask``.
     """
-    if dense_step_path(X) == "onepass":
+    path = dense_step_path(X, batch_rate)
+    if path != "two_products":
+        kernel = (_kernels().dense_onepass_tiles if path == "onepass_tiles"
+                  else _kernels().dense_onepass)
         with jax.named_scope("grad"):
-            return _kernels().dense_onepass(
-                X, y, w, mask, alpha, logistic=logistic)
+            return kernel(X, y, w, mask, alpha, logistic=logistic)
     with jax.named_scope("residual"):
         margin = shard_matvec(X, w)
         diff = (jax.nn.sigmoid(margin) if logistic else margin) - y
@@ -162,16 +211,19 @@ def dense_masked_grad(X, y, w, mask, alpha=None, logistic: bool = False):
         return X.T @ (mask * (diff - alpha)), diff
 
 
-@jax.jit
+@functools.partial(jax.jit, static_argnames=("batch_rate",))
 def least_squares_grad_sum(
-    X: jax.Array, y: jax.Array, w: jax.Array, mask: jax.Array
+    X: jax.Array, y: jax.Array, w: jax.Array, mask: jax.Array,
+    batch_rate=None,
 ) -> jax.Array:
     """Sum over masked samples of ``(x_i . w - y_i) x_i``.
 
     ``mask`` is {0,1} (or weights) of shape ``(n,)``; equivalent to the
     reference's sample-then-map-then-reduce with vector-add comOp.
+    ``batch_rate``: the rate it was drawn at, where the caller holds one
+    (:func:`dense_masked_grad`).
     """
-    return dense_masked_grad(X, y, w, mask)[0]
+    return dense_masked_grad(X, y, w, mask, batch_rate=batch_rate)[0]
 
 
 @jax.jit
@@ -186,16 +238,18 @@ def least_squares_loss(X: jax.Array, y: jax.Array, w: jax.Array) -> jax.Array:
     return jnp.sum(r * r)
 
 
-@jax.jit
+@functools.partial(jax.jit, static_argnames=("batch_rate",))
 def logistic_grad_sum(
-    X: jax.Array, y: jax.Array, w: jax.Array, mask: jax.Array
+    X: jax.Array, y: jax.Array, w: jax.Array, mask: jax.Array,
+    batch_rate=None,
 ) -> jax.Array:
     """Sum over masked samples of the logistic-loss gradient.
 
     Parity: ``LogisticGradient`` (binary case) -- labels in {0,1};
     ``grad_i = (sigmoid(x_i.w) - y_i) x_i``.
     """
-    return dense_masked_grad(X, y, w, mask, logistic=True)[0]
+    return dense_masked_grad(
+        X, y, w, mask, logistic=True, batch_rate=batch_rate)[0]
 
 
 @jax.jit

@@ -24,6 +24,20 @@ cannot do:
   ``gradients.dense_step_path`` sends to the two XLA products (the CPU,
   lane-aligned widths), and ASAGA's table delta, a product of its own on
   the accept path.
+- :func:`dense_onepass_tiles` -- the same step from a read of the lane
+  tiles that hold a sampled row, and of no other.  ``X.T`` lies in HBM as
+  tiles of 16 features x 128 ROWS of the shard (4 KB of bf16), and a tile
+  none of whose rows was drawn adds exact zeros to ``g``: under a
+  Bernoulli draw at ``b`` that is ``(1 - b)^128`` of them, 27.6% at
+  ASAGA's 0.01 and nothing at ASGD's 0.1.  XLA lists the other tiles in
+  front of the kernel (a flag a tile, one sort, row gathers of 128 lanes
+  for the vectors: 0.06 ms), the kernel fetches each listed tile's ``(d,
+  128)`` window by a DMA of its own (49 pieces of 4 KB, 32 MB apart: 748
+  GB/s where the contiguous block reads 757) and runs the SAME block
+  arithmetic (:func:`_block_step`): 1.68 ms where the whole shard takes
+  2.23 (v5e, 1.0M x 784 bf16, PERF.md section 6, PR 49).  Which of the
+  two runs is ``gradients.dense_step_path``'s choice, from the draw's
+  rate.
 - :func:`chunk_attention` -- block attention with local softmax stats for
   the long-context path: a flash-style forward tiled over (query block,
   key block) with the running (m, l, o) in VMEM scratch, returning the
@@ -37,9 +51,9 @@ cannot do:
 
 ``interpret`` is an explicit argument everywhere: the CPU tests pass
 ``interpret=True``, every other caller gets the Mosaic-compiled kernel
-(``chip_smoke.py`` phase E runs both at full shapes on the chip against
-precision "highest"; ``tests/test_step_layout.py`` compiles the steps that
-hold :func:`dense_onepass` for a described v5e).
+(``chip_smoke.py`` phase E runs all three at full shapes on the chip
+against precision "highest"; ``tests/test_step_layout.py`` compiles the
+steps that hold the two one-pass kernels for a described v5e).
 """
 
 from __future__ import annotations
@@ -96,15 +110,20 @@ def _lanes(c):
     return pl.ds(pl.multiple_of(c * _LANE, _LANE), _LANE)
 
 
-def _margins(xt_ref, wb_ref, r_ref, cols: int):
-    """``r = w . Xb``, one 128-lane chunk at a time: the products of a row
-    group are added register by register down to one ``(8, 128)``
-    register, so a chunk costs ONE cross-sublane reduction.  Columns
-    beyond ``cols`` in the last chunk give garbage the caller selects
-    out: a column's margin depends on that column alone."""
+def _margins(xt_ref, wb_ref, r_ref, chunks, before=None):
+    """``r = w . Xb`` over the block's first ``chunks`` 128-lane chunks (a
+    Python integer, or a traced one where the block is a list of tiles),
+    one chunk at a time: the products of a row group are added register
+    by register down to one ``(8, 128)`` register, so a chunk costs ONE
+    cross-sublane reduction.  Columns of the last chunk that lie beyond
+    the shard give garbage the caller selects out: a column's margin
+    depends on that column alone.  ``before(c)``, where given, runs in
+    front of chunk ``c``'s reads (the tile-list kernel's DMA traffic)."""
     groups = _row_groups(xt_ref.shape[0])
 
     def chunk(c, carry):
+        if before is not None:
+            before(c)
         lanes = _lanes(c)
         acc = None
         for lo, size in groups:
@@ -115,16 +134,17 @@ def _margins(xt_ref, wb_ref, r_ref, cols: int):
         r_ref[:, lanes] = jnp.sum(acc, axis=0, keepdims=True)
         return carry
 
-    jax.lax.fori_loop(0, pl.cdiv(cols, _LANE), chunk, 0)
+    jax.lax.fori_loop(0, chunks, chunk, 0)
 
 
-def _accumulate_grad(xt_ref, v_ref, g_ref, cols: int):
-    """``g += Xb . v``: a row group's ``(rows, 128)`` partial sums stay in
+def _accumulate_grad(xt_ref, v_ref, g_ref, n_full, tail: int = 0):
+    """``g += Xb . v`` over the block's first ``n_full`` chunks (an integer
+    or a traced one, as :func:`_margins` takes it) and the ``tail``
+    columns after them: a row group's ``(rows, 128)`` partial sums stay in
     registers across the block's chunks, and lanes are reduced once, after
-    the call.  Columns at and beyond ``cols`` (the ragged tail of the last
-    block) are not read, but in the chunk that straddles ``cols``, where
-    they are selected out of ``Xb``: ``0 * NaN`` is ``NaN``."""
-    n_full, tail = divmod(cols, _LANE)
+    the call.  Columns beyond those (the ragged tail of the last block)
+    are not read, but in the chunk that straddles the end, where they are
+    selected out of ``Xb``: ``0 * NaN`` is ``NaN``."""
     for lo, size in _row_groups(xt_ref.shape[0]):
         rows = slice(lo, lo + size)
 
@@ -135,7 +155,7 @@ def _accumulate_grad(xt_ref, v_ref, g_ref, cols: int):
 
         acc = jax.lax.fori_loop(
             0, n_full, chunk, jnp.zeros((size, _LANE), jnp.float32))
-        if tail:
+        if tail:  # only ever behind a Python n_full
             lanes = slice(n_full * _LANE, (n_full + 1) * _LANE)
             lane = jax.lax.broadcasted_iota(jnp.int32, (size, _LANE), 1)
             x = jnp.where(lane < tail,
@@ -144,16 +164,43 @@ def _accumulate_grad(xt_ref, v_ref, g_ref, cols: int):
         g_ref[rows, :] += acc
 
 
+def _block_step(xt_ref, wb_ref, y_ref, m_ref, a_ref, g_ref, diff_ref,
+                r_ref, v_ref, *, logistic: bool, chunks, cols=None,
+                before=None):
+    """The arithmetic of one block ``Xb`` in VMEM, for both one-pass
+    kernels: the margins of its columns, the masked per-row scalar and
+    ``g``'s partial sums.  ``r`` and ``v`` live in ``(1, block)`` scratch.
+    ``chunks``: how many 128-lane chunks of the block hold columns;
+    ``cols``: how many columns, where the last chunk is ragged (a Python
+    integer; ``None``: every chunk is whole).  ``a_ref`` and ``diff_ref``
+    are ``None`` outside ASAGA's form; ``before`` is :func:`_margins`'s."""
+    _margins(xt_ref, wb_ref, r_ref, chunks, before)
+    r = r_ref[:]
+    diff = (jax.nn.sigmoid(r) if logistic else r) - y_ref[:]
+    if a_ref is not None:
+        diff_ref[:] = diff
+        diff = diff - a_ref[:]
+    v = m_ref[:] * diff
+    if cols is not None:
+        # select, never multiply: what lies beyond n may be NaN
+        col = jax.lax.broadcasted_iota(jnp.int32, v.shape, 1)
+        v = jnp.where(col < cols, v, 0.0)
+    v_ref[:] = v
+    if cols is None:
+        _accumulate_grad(xt_ref, v_ref, g_ref, chunks)
+    else:
+        _accumulate_grad(xt_ref, v_ref, g_ref, *divmod(cols, _LANE))
+
+
 def _onepass_kernel(*refs, n: int, block: int, logistic: bool, saga: bool):
-    """One grid step over ``Xb = X.T[:, i*block:(i+1)*block]``: the
-    margins of its columns, the masked per-row scalar, and ``g``'s partial
-    sums, all from the block in VMEM.  ``r`` and ``v`` live in ``(1,
-    block)`` scratch; ``g_ref`` is the ``(d, 128)`` output block every grid
-    step revisits."""
+    """One grid step over ``Xb = X.T[:, i*block:(i+1)*block]``, the block
+    in VMEM by the pipeline's own fetch; ``g_ref`` is the ``(d, 128)``
+    output block every grid step revisits."""
     if saga:
         xt_ref, wb_ref, y_ref, m_ref, a_ref, g_ref, diff_ref, r_ref, v_ref = refs
     else:
         xt_ref, wb_ref, y_ref, m_ref, g_ref, r_ref, v_ref = refs
+        a_ref = diff_ref = None
     i = pl.program_id(0)
     last = pl.num_programs(0) - 1
     rem = n - (pl.cdiv(n, block) - 1) * block  # columns of the last block
@@ -163,19 +210,10 @@ def _onepass_kernel(*refs, n: int, block: int, logistic: bool, saga: bool):
         g_ref[:] = jnp.zeros_like(g_ref)
 
     def body(cols: int):
-        _margins(xt_ref, wb_ref, r_ref, cols)
-        r = r_ref[:]
-        diff = (jax.nn.sigmoid(r) if logistic else r) - y_ref[:]
-        if saga:
-            diff_ref[:] = diff
-            diff = diff - a_ref[:]
-        v = m_ref[:] * diff
-        if cols < block:
-            # select, never multiply: what lies beyond n may be NaN
-            col = jax.lax.broadcasted_iota(jnp.int32, v.shape, 1)
-            v = jnp.where(col < cols, v, 0.0)
-        v_ref[:] = v
-        _accumulate_grad(xt_ref, v_ref, g_ref, cols)
+        _block_step(xt_ref, wb_ref, y_ref, m_ref, a_ref, g_ref, diff_ref,
+                    r_ref, v_ref, logistic=logistic,
+                    chunks=pl.cdiv(cols, _LANE),
+                    cols=cols if cols < block else None)
 
     if rem == block:
         body(block)
@@ -253,6 +291,192 @@ def dense_onepass(X, y, w, mask, alpha=None, *, logistic: bool = False,
         *(v.astype(f32)[None, :] for v in vectors),
     )
     return out[0].sum(axis=1), (out[1].reshape(n) if saga else None)
+
+
+#: most list entries one grid step of :func:`dense_onepass_tiles` takes:
+#: each window has a DMA semaphore of its own in each of the two buffers,
+#: and the v5e holds 512 (a 2 KB space; a narrow shard's block of 212,992
+#: columns would ask for 3,328)
+_TILES_MAX_ENTRIES = 128
+
+
+def _tiles_kernel(ids_ref, cnt_ref, *refs, tail: int, per_step: int,
+                  logistic: bool, saga: bool):
+    """One grid step over the next ``per_step`` entries of the tile list:
+    ``ids_ref`` holds the ids of the lane tiles that hold a sampled row,
+    ascending; ``cnt_ref`` how many there are and how many of them are
+    fetched from ``xt_hbm`` (all but the shard's ragged last tile).  Entry
+    ``e``'s ``(d, 128)`` window of ``X.T`` goes by a DMA of its own, with
+    a semaphore of its own, into lane chunk ``e % per_step`` of buffer
+    ``(e // per_step) % 2``.  The margins' loop walks the block a chunk at
+    a time, and in front of chunk ``c`` it starts the NEXT grid step's
+    window ``c`` and waits for its own: the descriptors' scalar work rides
+    in the loop's bundles (issued in a loop of their own, 32 starts and 32
+    waits a step, it cost 6% of the kernel: PERF.md section 6, PR 49), and
+    a chunk is awaited when it is needed, not the block when its first is.
+    Grid steps beyond the list do nothing."""
+    xt_hbm, *refs = refs
+    xtail_ref = refs.pop(0) if tail else None
+    *blocks, buf, sem, r_ref, v_ref = refs
+    if saga:
+        wb_ref, y_ref, m_ref, a_ref, g_ref, diff_ref = blocks
+    else:
+        wb_ref, y_ref, m_ref, g_ref = blocks
+        a_ref = diff_ref = None
+    i = pl.program_id(0)
+    count, fetched = cnt_ref[0], cnt_ref[1]
+    lo, nxt, slot = i * per_step, (i + 1) * per_step, i % 2
+    entries = jnp.clip(count - lo, 0, per_step)
+    xb_ref = buf.at[slot]
+
+    def window(slot, entry, c):
+        at = pl.multiple_of(ids_ref[entry] * _LANE, _LANE)
+        return pltpu.make_async_copy(
+            xt_hbm.at[:, pl.ds(at, _LANE)], buf.at[slot, :, _lanes(c)],
+            sem.at[slot, c])
+
+    @pl.when(i == 0)
+    def _():
+        g_ref[:] = jnp.zeros_like(g_ref)
+        jax.lax.fori_loop(
+            0, jnp.minimum(fetched, per_step),
+            lambda c, carry: (window(0, c, c).start(), carry)[1], 0)
+
+    def before(c):
+        @pl.when(nxt + c < fetched)
+        def _():
+            window(1 - slot, nxt + c, c).start()
+
+        @pl.when(lo + c < fetched)
+        def _():
+            window(slot, lo + c, c).wait()
+
+    def block(chunks):
+        if tail:
+            # the ragged last tile, where it is on the list, is its last
+            # entry: it comes through the pipeline (the block at the
+            # array's edge), what it holds beyond n selected out of it:
+            # 0 * NaN is NaN
+            @pl.when((fetched < count) & (count <= nxt))
+            def _():
+                lane = jax.lax.broadcasted_iota(jnp.int32, xtail_ref.shape, 1)
+                xb_ref[:, _lanes(entries - 1)] = jnp.where(
+                    lane < tail, xtail_ref[:].astype(jnp.float32), 0.0
+                ).astype(xb_ref.dtype)
+
+        _block_step(xb_ref, wb_ref, y_ref, m_ref, a_ref, g_ref, diff_ref,
+                    r_ref, v_ref, logistic=logistic, chunks=chunks,
+                    before=before)
+
+    # a whole block keeps the loops' Python bounds; the list's last block
+    # walks as many chunks as it has entries
+    pl.when(entries == per_step)(lambda: block(per_step))
+    pl.when((entries > 0) & (entries < per_step))(lambda: block(entries))
+
+
+def dense_onepass_tiles(X, y, w, mask, alpha=None, *, logistic: bool = False,
+                        block: Optional[int] = None, interpret=False):
+    """:func:`dense_onepass` over the lane tiles that hold a sampled row,
+    and over no other: the same ``(g, diff)`` from a read of that share of
+    the shard.
+
+    ``X.T`` lies in HBM as tiles of 128 ROWS of the shard (PERF.md section
+    3), and a tile none of whose rows ``mask`` marks adds exact zeros to
+    ``g``: at a rate ``b`` that is ``(1 - b)^128`` of them, 27.6% at
+    ASAGA's 0.01.  In front of the kernel, in XLA: one flag a tile, the
+    ids of the flagged tiles in ascending order (one sort of ``tiles``
+    keys: no ``nonzero``, no scatter), and ``y``, ``mask`` and ``alpha``
+    brought to the list's order by row gathers of 128 lanes.  The kernel
+    (:func:`_tiles_kernel`) takes ``block // 128`` entries a grid step,
+    each window by a DMA of its own (748 GB/s where the pipeline's
+    contiguous block reads 757: v5e, PERF.md section 6, PR 49), and
+    shares the block's arithmetic with :func:`dense_onepass` (a shard
+    under one tile IS that kernel's): ``g`` is the same terms lane by
+    lane in the same order less exact zeros, and differs from it only
+    where a block's partial sums are cut.  ``diff`` comes back in the
+    list's order and is expanded by a row gather; at the rows of a tile
+    with no sampled row it is 0 (nobody reads it there: the commit and
+    the table delta select and weigh by ``mask``), at every other row
+    :func:`dense_onepass`'s value.  ``gradients.dense_step_path`` says
+    where this pays."""
+    n, d = X.shape
+    if n < _LANE:  # no whole tile to window
+        return dense_onepass(X, y, w, mask, alpha, logistic=logistic,
+                             block=block, interpret=interpret)
+    f32 = jnp.float32
+    itemsize = jnp.dtype(X.dtype).itemsize
+    block = onepass_block(d, itemsize) if block is None else block
+    block = min(block, _LANE * pl.cdiv(n, _LANE), _LANE * _TILES_MAX_ENTRIES)
+    per_step = block // _LANE
+    tiles, tail = pl.cdiv(n, _LANE), n % _LANE
+    steps = pl.cdiv(tiles, per_step)
+    saga = alpha is not None
+    vma = getattr(jax.typeof(X), "vma", None)
+    kw = {"vma": vma} if vma else {}
+    vectors = jax.lax.optimization_barrier(
+        [y, mask] + ([alpha] if saga else []))
+
+    # each vector as one row of 128 lanes a tile
+    by_tile = [
+        jnp.pad(v.astype(f32), (0, tiles * _LANE - n)).reshape(tiles, _LANE)
+        for v in vectors]
+    flag = jnp.any(by_tile[1] != 0, axis=1)
+    count = jnp.sum(flag, dtype=jnp.int32)
+    # the flagged ids ascending, then `tiles` (no tile) to the list's end:
+    # an entry the kernel never fetches, and the gathers clip
+    ids = jnp.sort(
+        jnp.where(flag, jnp.arange(tiles, dtype=jnp.int32), tiles),
+        stable=False)
+    ids = jnp.pad(ids, (0, steps * per_step - tiles), constant_values=tiles)
+    counts = jnp.stack([count, count - flag[-1]] if tail else [count, count])
+
+    row = pl.BlockSpec((1, block), lambda i, ids, cnt: (0, i))
+    resident = pl.BlockSpec((d, _LANE), lambda i, ids, cnt: (0, 0))
+    in_specs = [pl.BlockSpec(memory_space=pl.ANY)]
+    operands = [X.T]
+    if tail:
+        in_specs.append(
+            pl.BlockSpec((d, _LANE), lambda i, ids, cnt: (0, tiles - 1)))
+        operands.append(X.T)
+    out_shape = [jax.ShapeDtypeStruct((d, _LANE), f32, **kw)]
+    out_specs = [resident]
+    if saga:
+        out_shape.append(
+            jax.ShapeDtypeStruct((1, steps * block), f32, **kw))
+        out_specs.append(row)
+    out = pl.pallas_call(
+        functools.partial(_tiles_kernel, tail=tail, per_step=per_step,
+                          logistic=logistic, saga=saga),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(steps,),
+            in_specs=in_specs + [resident] + [row] * len(vectors),
+            out_specs=out_specs,
+            scratch_shapes=[pltpu.VMEM((2, d, block), X.dtype),
+                            pltpu.SemaphoreType.DMA((2, per_step))]
+            + [pltpu.VMEM((1, block), f32)] * 2,
+        ),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=2 * d * block * itemsize + (8 << 20),
+        ),
+        name="dense_onepass_tiles",
+        interpret=interpret,
+    )(
+        ids, counts, *operands,
+        jnp.broadcast_to(w.astype(f32)[:, None], (d, _LANE)),
+        *(jnp.take(v, ids, axis=0, mode="clip").reshape(1, -1)
+          for v in by_tile),
+    )
+    if not saga:
+        return out[0].sum(axis=1), None
+    # a listed tile's place on the list; any row will do for the others
+    at = jnp.cumsum(flag, dtype=jnp.int32) - 1
+    diff = jnp.where(
+        flag[:, None],
+        jnp.take(out[1].reshape(-1, _LANE), at, axis=0, mode="clip"), 0.0)
+    return out[0].sum(axis=1), diff.reshape(-1)[:n]
 
 
 # --------------------------------------------------------------- attention

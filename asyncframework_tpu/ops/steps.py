@@ -26,9 +26,10 @@ Parity notes per builder:
   ``jax.named_scope`` (``sample``, ``residual``, ``grad``; the sparse
   steps also ``compact`` and ``gather``; ``apply``): an HLO op's
   ``op_name`` then says which phase it belongs to when a trace is opened
-  in XProf or Perfetto.  Where the dense step is the one-pass kernel
+  in XProf or Perfetto.  Where the dense step is a one-pass kernel
   (``gradients.dense_step_path``) ``residual`` and ``grad`` are one
-  custom call, named ``dense_onepass`` under ``grad``.  Metadata only:
+  custom call, named ``dense_onepass`` or ``dense_onepass_tiles`` under
+  ``grad``.  Metadata only:
   the compiled program and its compile-cache key are unchanged, and the
   jitted functions keep their Python names (the benchmark matches
   ``jit_step``).
@@ -54,6 +55,7 @@ from asyncframework_tpu.ops.gradients import (
     clamped_block,
     dense_masked_grad,
     dense_step_path,
+    dense_tiles_share,
     least_squares_grad_sum,
     logistic_grad_sum,
     make_sparse_grad_sum,
@@ -132,7 +134,7 @@ def _dense_sampled_gradient(X, y, w, key, batch_rate, grad_sum):
         mask = jax.random.bernoulli(
             sub, batch_rate, (X.shape[0],)
         ).astype(jnp.float32)
-    return grad_sum(X, y, w, mask), key
+    return grad_sum(X, y, w, mask, batch_rate=batch_rate), key
 
 
 def make_asgd_worker_step(batch_rate: float, loss: str = "least_squares"):
@@ -155,7 +157,11 @@ def make_asgd_worker_step(batch_rate: float, loss: str = "least_squares"):
     took 4.24 ms at 755 GB/s each (v5e, PERF.md section 6, PR 26).  Two
     reads remain where ``gradients.dense_step_path`` says
     ``"two_products"``: off the TPU, and at lane-aligned widths, where the
-    shard is stored row-major and ``X.T`` would be a real transpose.  The
+    shard is stored row-major and ``X.T`` would be a real transpose.  What
+    CAN be left unread is a lane tile of 128 rows none of which was drawn:
+    the step hands ``batch_rate`` to the chooser, which at a thin draw
+    (0.01: 27.6% of the tiles) picks the kernel over the list of the
+    others, and at this recipe's 0.1 (one tile in a million) does not.  The
     sparse (padded-ELL) step does compact: its gather saves real traffic.
     """
     grad_sum = _grad_sum_for(loss)
@@ -222,7 +228,11 @@ def make_saga_worker_step(batch_rate: float):
     The byte model is :func:`make_asgd_worker_step`'s: ONE read of the
     shard on the TPU (the one-pass kernel takes ``alpha`` in and writes
     ``diff`` out beside ``g``: 12 bytes a row on top of the shard), two
-    where ``gradients.dense_step_path`` says so.  An accepted update pays
+    where ``gradients.dense_step_path`` says so, and at the recipe's own
+    ``batch_rate`` of 0.01 LESS than one: the kernel over the list of the
+    lane tiles that hold a sampled row reads 72% of the shard (``diff``
+    is then 0 at the rows of the other tiles: the commit selects by
+    ``mask`` and the delta weighs by it).  An accepted update pays
     a second read on the updater's side only where the slice moved on
     while the step was in flight (``ASAGA.run`` counts both kinds): the
     table delta is a product against the history AT COMMIT, which no
@@ -236,7 +246,8 @@ def make_saga_worker_step(batch_rate: float):
             mask = jax.random.bernoulli(
                 sub, batch_rate, (X.shape[0],)
             ).astype(jnp.float32)
-        g, diff = dense_masked_grad(X, y, w, mask, alpha=alpha)
+        g, diff = dense_masked_grad(
+            X, y, w, mask, alpha=alpha, batch_rate=batch_rate)
         return g, diff, mask, key
 
     return _counts_rows(
@@ -1188,7 +1199,8 @@ def make_fused_saga_rounds(
             mask = jax.random.bernoulli(
                 sub, batch_rate, (X.shape[0],)
             ).astype(jnp.float32)
-            g, diff = dense_masked_grad(X, y, w, mask, alpha=alphas[i])
+            g, diff = dense_masked_grad(
+                X, y, w, mask, alpha=alphas[i], batch_rate=batch_rate)
             gs.append(g)
             # commit the wave's candidate scalars into the slice
             new_alphas.append(jnp.where(mask > 0, diff, alphas[i]))
@@ -1465,7 +1477,7 @@ def worker_programs(ds, batch_rate: float, loss: str = "least_squares",
     beyond it): one step and one evaluation for every shard SHAPE (jit
     traces them by the arrays they are called with), shared by the shards
     that have it.  A dense dataset's shards have one width and dtype, so
-    shard 0 says which program the step is."""
+    shard 0 and the draw's rate say which program the step is."""
     d = ds.d
     shards = [ds.shard(w) for w in range(ds.num_workers)]
     padded_ell = bool(getattr(ds, "is_sparse", False))
@@ -1504,8 +1516,16 @@ def worker_programs(ds, batch_rate: float, loss: str = "least_squares",
             shards, step, evaluate, batch_rate, d, live, history)
     else:
         evaluate = make_trajectory_loss_eval(loss)
+        path = dense_step_path(shards[0].operands[0], batch_rate)
         account = dict(
-            extras={"dense_step_path": dense_step_path(shards[0].operands[0])},
+            extras={
+                "dense_step_path": path,
+                # the share of a shard's lane tiles a step fetches,
+                # expected over the draw (host arithmetic, no device
+                # read); 1.0 where the step reads the whole shard
+                "dense_tiles_read_share": dense_tiles_share(
+                    batch_rate if path == "onepass_tiles" else None),
+            },
             widths=(None,) * len(shards), step_nonzeros=(), step_walked=(),
             task_flops=lambda shard: _flops.dense_task_flops(
                 step.task_rows(shard.size), shard.shape[1]),

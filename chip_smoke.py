@@ -69,6 +69,9 @@ SIZES = {
                     bucket=0.7, pfreq=50, density=0.0016),
         mesh_iters=200,
         kernel_grad=(50_000, 2000),       # one epsilon shard
+        # one shard of mnist8m-asaga.steady and its rate: the tile-list
+        # kernel reads 72% of its 7,911 lane tiles
+        kernel_tiles=(1_012_500, 784, 0.01),
         # the block ring_attention feeds chunk_attention for T = 8,192 over
         # four devices: (B, T/4, H, D)
         kernel_attn=(1, 2048, 8, 128),
@@ -83,6 +86,7 @@ SIZES = {
                     pfreq=10, density=0.025),
         mesh_iters=50,
         kernel_grad=(300, 48),
+        kernel_tiles=(2_500, 48, 0.01),
         kernel_attn=(1, 64, 2, 16),
     ),
 }
@@ -91,7 +95,8 @@ SIZES = {
 #: matmul precision against float64 on the host (one run on the v5e: the
 #: XLA matvec showed 0.0 against precision "highest")
 GRAD_TOL = 1e-3
-#: dense_onepass (the dense worker step's one-pass kernel) against the same
+#: dense_onepass and dense_onepass_tiles (the dense worker step's one-pass
+#: kernels: the whole shard, and its listed lane tiles) against the same
 #: contraction at precision "highest", relative to max |g| (and for ASAGA's
 #: ``diff``, to max |diff|): f32 sums in another order, nothing rounded,
 #: whatever the shard's dtype (v5e, PR 26: 4.1e-7 on this shard in f32,
@@ -268,6 +273,36 @@ def phase_kernels(shard, w, size: dict, interpret: bool) -> dict:
                 np.max(np.abs(np.asarray(diff) - diff_ref))
                 / np.max(np.abs(diff_ref)))
 
+    # the tile-list kernel beside it, on a bf16 shard of its own at the
+    # rate that leaves lane tiles without a sampled row: ASAGA's form,
+    # ``diff`` held to the reference at the rows of the tiles it lists
+    # (0 at the others, by the kernel's contract)
+    t_rows, t_d, t_rate = size["kernel_tiles"]
+    kx, ky, ka, km = jax.random.split(jax.random.PRNGKey(6), 4)
+    Xt = jax.jit(lambda k: jax.random.normal(k, (t_rows, t_d), jnp.bfloat16)
+                 / jnp.bfloat16(np.sqrt(t_d)))(kx)
+    yt = jax.random.normal(ky, (t_rows,), jnp.float32)
+    at = jax.random.normal(ka, (t_rows,), jnp.float32)
+    mt = jax.random.bernoulli(km, t_rate, (t_rows,)).astype(jnp.float32)
+    wt = jnp.ones((t_d,), jnp.float32)
+    t0 = time.monotonic()
+    g, diff = jax.jit(functools.partial(
+        pk.dense_onepass_tiles, interpret=interpret))(Xt, yt, wt, mt, at)
+    g, diff = np.asarray(g), np.asarray(diff)
+    tiles_s = round(time.monotonic() - t0, 2)
+    with jax.default_matmul_precision("highest"):
+        g_ref, diff_ref = (np.asarray(a) for a in reference(
+            Xt, yt, wt, mt, at))
+    listed = np.pad(np.asarray(mt), (0, -t_rows % 128)).reshape(
+        -1, 128).any(axis=1)
+    at_rows = listed.repeat(128)[:t_rows]
+    tiles_err = {
+        "g": float(np.max(np.abs(g - g_ref)) / np.max(np.abs(g_ref))),
+        "diff": float(np.max(np.abs(diff - diff_ref)[at_rows])
+                      / np.max(np.abs(diff_ref))),
+    }
+    tiles_rest = float(np.max(np.abs(diff[~at_rows]), initial=0.0))
+
     B, T, H, D = size["kernel_attn"]
     q, k, v = (jax.random.normal(jax.random.PRNGKey(s), (B, T, H, D),
                                  jnp.float32) for s in (3, 4, 5))
@@ -290,6 +325,11 @@ def phase_kernels(shard, w, size: dict, interpret: bool) -> dict:
         "interpret": interpret,
         "dense_onepass": {"shape": [rows, d], "rel_err": grad_err,
                           "tolerance": KERNEL_GRAD_TOL, "seconds": grad_s},
+        "dense_onepass_tiles": {
+            "shape": [t_rows, t_d], "rate": t_rate,
+            "tiles_listed": int(listed.sum()), "tiles": int(listed.size),
+            "rel_err": tiles_err, "diff_off_the_list": tiles_rest,
+            "tolerance": KERNEL_GRAD_TOL, "seconds": tiles_s},
         "chunk_attention": {"shape": [B, T, H, D], "abs_err": attn_err,
                             "tolerance": KERNEL_ATTN_TOL,
                             "seconds": round(attn_s, 2)},
@@ -297,6 +337,10 @@ def phase_kernels(shard, w, size: dict, interpret: bool) -> dict:
     log(f"phase E: {json.dumps(rec)}")
     require(max(grad_err.values()) <= KERNEL_GRAD_TOL,
             f"E: dense_onepass off its reference by {grad_err}")
+    require(max(tiles_err.values()) <= KERNEL_GRAD_TOL and tiles_rest == 0.0
+            and 0 < listed.sum() < listed.size,
+            f"E: dense_onepass_tiles off its reference by {tiles_err}, "
+            f"{tiles_rest} off the list, {listed.sum()} tiles listed")
     require(max(attn_err.values()) <= KERNEL_ATTN_TOL,
             f"E: chunk_attention off its reference by {attn_err}")
     return rec

@@ -91,31 +91,154 @@ class TestDenseOnepass:
         real = pallas_kernels._accumulate_grad
         monkeypatch.setattr(
             pallas_kernels, "_accumulate_grad",
-            lambda xt, v, g, cols: real(xt, v, g, -(-cols // 128) * 128))
+            lambda xt, v, g, n_full, tail=0: real(xt, v, g,
+                                                  n_full + (tail > 0)))
         g, _ = pallas_kernels.dense_onepass(X, y, w, mask, block=1024,
                                             interpret=True)
         assert not np.isfinite(np.asarray(g)).all()
 
     @pytest.mark.parametrize(
-        "on_tpu,d,dtype,want",
+        "on_tpu,d,dtype,rate,want",
         [
-            (False, 784, jnp.bfloat16, "two_products"),  # this backend
-            (True, 784, jnp.bfloat16, "onepass"),
-            (True, 2000, jnp.float32, "onepass"),
-            (True, 1024, jnp.float32, "two_products"),   # stored row-major
-            (True, 100, jnp.bfloat16, "two_products"),   # 100 % 16 != 0
-            (True, 784, jnp.float16, "two_products"),
+            (False, 784, jnp.bfloat16, None, "two_products"),  # this backend
+            (True, 784, jnp.bfloat16, None, "onepass"),
+            (True, 2000, jnp.float32, None, "onepass"),
+            (True, 1024, jnp.float32, None, "two_products"),  # row-major
+            (True, 100, jnp.bfloat16, None, "two_products"),  # 100 % 16 != 0
+            (True, 784, jnp.float16, None, "two_products"),
+            # the draw's rate picks the one-pass kernel's granule, and
+            # nothing but it: ASAGA's 0.01 leaves 27.6% of the lane tiles
+            # without a sampled row, ASGD's 0.1 one in a million
+            (True, 784, jnp.bfloat16, 0.01, "onepass_tiles"),
+            (True, 784, jnp.float32, 0.01, "onepass_tiles"),
+            (True, 784, jnp.bfloat16, 0.1, "onepass"),
+            (False, 784, jnp.bfloat16, 0.01, "two_products"),
+            (True, 1024, jnp.float32, 0.01, "two_products"),
         ],
     )
-    def test_the_path_is_chosen_from_backend_width_and_dtype(
-        self, monkeypatch, on_tpu, d, dtype, want
+    def test_the_path_is_chosen_from_backend_width_dtype_and_rate(
+        self, monkeypatch, on_tpu, d, dtype, rate, want
     ):
         if on_tpu:
             monkeypatch.setattr(gradients, "_on_tpu", lambda: True)
         X = jax.ShapeDtypeStruct((4096, d), dtype)
-        assert gradients.dense_step_path(X) == want
+        assert gradients.dense_step_path(X, rate) == want
         assert gradients.dense_step_path(
-            jax.ShapeDtypeStruct((4096,), dtype)) == "two_products"
+            jax.ShapeDtypeStruct((4096,), dtype), rate) == "two_products"
+
+    def test_the_share_of_tiles_a_draw_hits(self):
+        assert gradients.dense_tiles_share(None) == 1.0
+        assert gradients.dense_tiles_share(0.0) == 0.0
+        assert gradients.dense_tiles_share(1.0) == 1.0
+        assert abs(gradients.dense_tiles_share(0.01) - 0.723748) < 1e-6
+        assert 1.0 - gradients.dense_tiles_share(0.1) < 2e-6
+        # ONE constant decides, between the two recipes' rates
+        assert (gradients.dense_tiles_share(0.01)
+                < gradients.DENSE_TILES_BREAK_EVEN
+                < gradients.dense_tiles_share(0.1))
+
+
+def _tiles_mask(rng, n, hit):
+    """A mask over ``n`` rows that marks one to three rows in each of the
+    lane tiles ``hit`` and none elsewhere."""
+    mask = np.zeros(n, np.float32)
+    for t in hit:
+        rows = np.arange(t * 128, min((t + 1) * 128, n))
+        mask[rng.choice(rows, size=min(3, rows.size), replace=False)] = 1.0
+    return mask
+
+
+class TestDenseOnepassTiles:
+    """``dense_onepass_tiles`` against ``dense_onepass`` on the same
+    inputs, both on the interpreter: ``g`` to the order of the sums (the
+    same terms lane by lane, cut into blocks elsewhere), ``diff`` EQUAL at
+    every row of a tile that holds a sampled row and 0 at the others.  A
+    block of 512 columns is four list entries a grid step; 2,500 rows are
+    19 whole tiles and a ragged one of 68 rows, which the interpreter
+    pads with NaN."""
+
+    @pytest.mark.parametrize(
+        "n,dtype,saga,logistic,hit",
+        [
+            (2500, jnp.float32, True, False, [0, 3, 7, 12]),   # count % 4 == 0
+            (2500, jnp.float32, True, False, [1, 2, 5, 9, 17]),  # one over
+            (2500, jnp.bfloat16, True, False, [0, 4, 19]),     # the ragged tile
+            (2500, jnp.float32, True, False, [19]),            # ... alone
+            (2500, jnp.bfloat16, False, False, [2, 3, 11, 18, 19]),
+            (2500, jnp.float32, False, True, [0, 1, 2, 3, 4, 5, 6, 19]),
+            (2500, jnp.float32, True, False, []),              # NO row drawn
+            (2500, jnp.bfloat16, False, False, []),
+            (2500, jnp.float32, True, False, list(range(20))),  # every tile
+            (2500, jnp.bfloat16, False, True, list(range(20))),
+            (2048, jnp.bfloat16, True, False, [0, 5, 15]),     # no ragged tile
+            (2048, jnp.float32, False, False, list(range(16))),
+            (100, jnp.float32, True, False, [0]),              # under one tile
+        ],
+        ids=["f32-saga-count4", "f32-saga-count5", "bf16-saga-ragged-hit",
+             "f32-saga-ragged-alone", "bf16-plain-ragged-hit",
+             "f32-logistic-count8", "f32-saga-none", "bf16-plain-none",
+             "f32-saga-all", "bf16-logistic-all", "bf16-saga-aligned",
+             "f32-plain-aligned-all", "f32-saga-small"],
+    )
+    def test_matches_the_whole_shard_kernel(
+        self, rng, n, dtype, saga, logistic, hit
+    ):
+        X, y, w, _, alpha = _onepass_case(rng, n, 48, dtype, saga)
+        mask = _tiles_mask(rng, n, hit)
+        want_g, want_diff = pallas_kernels.dense_onepass(
+            X, y, w, mask, alpha, logistic=logistic, block=512,
+            interpret=True)
+        g, diff = pallas_kernels.dense_onepass_tiles(
+            X, y, w, mask, alpha, logistic=logistic, block=512,
+            interpret=True)
+        assert g.dtype == jnp.float32 and g.shape == (48,)
+        assert np.isfinite(np.asarray(g)).all()
+        scale = max(float(np.max(np.abs(want_g))), 1e-30)
+        assert np.max(np.abs(np.asarray(g) - np.asarray(want_g))) <= (
+            2e-6 * scale)
+        if not hit:
+            assert not np.asarray(g).any()
+        if not saga:
+            assert diff is None
+            return
+        assert diff.dtype == jnp.float32 and diff.shape == (n,)
+        listed = np.zeros(-(-n // 128), bool)
+        listed[hit] = True
+        rows = listed.repeat(128)[:n]
+        assert np.array_equal(np.asarray(diff)[rows],
+                              np.asarray(want_diff)[rows])
+        assert not np.asarray(diff)[~rows].any()
+
+    def test_a_narrow_shard_takes_no_more_entries_than_semaphores(self, rng):
+        """A window has a DMA semaphore of its own, and the chip holds 512:
+        where the default block of a narrow shard would ask for more
+        (16 columns of f32: 106,496 columns, 832 entries a buffer) a grid
+        step takes ``_TILES_MAX_ENTRIES``, and the result is the same."""
+        n, d = 20_000, 16
+        X = jnp.asarray(rng.normal(size=(n, d)), jnp.float32)
+        y = rng.normal(size=n).astype(np.float32)
+        w = rng.normal(size=d).astype(np.float32)
+        mask = _tiles_mask(rng, n, range(0, 157, 2))
+        assert pallas_kernels.onepass_block(d, 4) // 128 > (
+            pallas_kernels._TILES_MAX_ENTRIES)
+        want_g, _ = pallas_kernels.dense_onepass(X, y, w, mask,
+                                                 interpret=True)
+        g, _ = pallas_kernels.dense_onepass_tiles(X, y, w, mask,
+                                                  interpret=True)
+        assert np.max(np.abs(np.asarray(g) - np.asarray(want_g))) <= (
+            2e-6 * np.max(np.abs(want_g)))
+
+    def test_a_weighted_mask_lists_every_tile_with_a_weight(self, rng):
+        """``mask`` may hold weights: a tile is listed where any is not 0."""
+        X, y, w, _, alpha = _onepass_case(rng, 2500, 48, jnp.float32, True)
+        mask = _tiles_mask(rng, 2500, [3, 8, 19]) * rng.normal(size=2500)
+        mask = mask.astype(np.float32)
+        want_g, _ = pallas_kernels.dense_onepass(
+            X, y, w, mask, alpha, block=512, interpret=True)
+        g, _ = pallas_kernels.dense_onepass_tiles(
+            X, y, w, mask, alpha, block=512, interpret=True)
+        assert np.max(np.abs(np.asarray(g) - np.asarray(want_g))) <= (
+            2e-6 * np.max(np.abs(want_g)))
 
 
 class TestMultihost:

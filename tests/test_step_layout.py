@@ -196,7 +196,79 @@ def test_dense_step_reads_the_shard_in_its_stored_layout(
         assert len(readers) == 1 and readers[0][1] == "custom-call", (
             f"the step reads the shard {len(readers)} times: {readers}"
         )
-        assert "dense_onepass" in readers[0][0], readers
+        # at b = 0.1 every lane tile holds a sampled row: ASGD's step and
+        # ASAGA's alike are the whole-shard kernel with the operands it
+        # has had since PR 26 (X.T, w, y, mask[, alpha]), and the list's
+        # row gathers are nowhere in the program (asserted above)
+        assert re.match(r"dense_onepass(\.\d+)?$", readers[0][0]), readers
+        assert "dense_onepass_tiles" not in text
+        call = [i for i in entry_instrs if i[0] == readers[0][0]][0]
+        assert len(call[3]) == (5 if program == "saga-step" else 4), call
+
+
+@pytest.mark.parametrize(
+    "program,n,d,dtype,batch_rate",
+    [
+        # mnist8m-asaga's rows; 40,000 are 312 lane tiles and 64 rows
+        ("saga-step", 40000, 784, jnp.bfloat16, 0.01),
+        ("saga-step", 39936, 784, jnp.float32, 0.01),   # whole tiles only
+        ("asgd-step", 40000, 784, jnp.bfloat16, 0.01),  # no solver's name
+    ],
+    ids=["saga-step-bf16-784", "saga-step-f32-784-aligned", "asgd-step-bf16"],
+)
+def test_a_thin_draw_reads_the_shard_by_the_tile_where_it_lies(
+    one_chip, no_compile_cache, on_tpu, program, n, d, dtype, batch_rate
+):
+    """At ``b`` 0.01 a quarter of a shard's 128-row lane tiles hold no
+    sampled row, and ``gradients.dense_step_path`` picks the kernel over
+    the list of the others (``pallas_kernels.dense_onepass_tiles``): the
+    shard parameter keeps ``{0,1}``, the kernel is the ONE instruction that
+    takes it (its ``bitcast``, twice where the last tile is ragged: the
+    windows by DMA, the array's edge through the pipeline), nothing as
+    large as the shard is copied, gathered or scattered, and what IS
+    gathered (``y``, ``mask``, ``alpha`` in, ``diff`` out, by rows of 128
+    lanes) is as large as a vector of the shard's rows, not as the shard."""
+    path = gradients.dense_step_path(
+        jax.ShapeDtypeStruct((n, d), dtype), batch_rate)
+    assert path == "onepass_tiles"
+    compiled = _compile(one_chip, program, n, d, dtype, batch_rate)
+    text = compiled.as_text()
+    instrs = _instructions(text)
+    prefix = {jnp.bfloat16: "bf16", jnp.float32: "f32"}[dtype]
+    shard, shard_t = f"{prefix}[{n},{d}]", f"{prefix}[{d},{n}]"
+    entry = text[text.index("ENTRY"):]
+    entry_instrs = _instructions(entry)
+    param0 = [t for _n, t, op, _ in entry_instrs
+              if op == "parameter" and t.startswith(shard)]
+    assert len(param0) == 1, entry[:2000]
+    assert re.match(re.escape(shard) + r"\{0,1", param0[0]), param0
+
+    whole = {name for name, t, _op, _ in instrs if shard in t or shard_t in t}
+    for name, t, op, operands in instrs:
+        if op in _MOVES_DATA or op in ("gather", "scatter"):
+            assert name not in whole and not whole & set(operands), (
+                f"%{name} = {op}(...) moves the whole shard ({t[:100]})")
+    # no index is scattered anywhere; the gathers are row gathers of
+    # (tiles, 128) f32 views of the row vectors, 4 bytes a shard row
+    assert not [i for i in instrs if i[2] == "scatter"]
+    assert "scatter-add" not in text
+    tiles = -(-n // 128)
+    for name, t, op, _ in instrs:
+        if op == "gather":
+            assert re.search(r"f32\[(%d|%d),128\]" % (
+                tiles, -(-tiles // 32) * 32), t), (name, t[:120])
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 1 << 20, f"{temp} bytes of temporaries"
+
+    held = {name for name, t, _op, _ in entry_instrs
+            if shard in t or shard_t in t}
+    readers = [(name, op, operands) for name, _t, op, operands in entry_instrs
+               if op != "bitcast" and held & set(operands)]
+    assert len(readers) == 1 and readers[0][1] == "custom-call", (
+        f"the step reads the shard {len(readers)} times: {readers}")
+    assert readers[0][0].startswith("dense_onepass_tiles"), readers
+    # the windows' operand, and with a ragged last tile its edge block
+    assert sum(o in held for o in readers[0][2]) == (2 if n % 128 else 1)
 
 
 def test_mesh_step_runs_the_kernel_on_each_device_rows(

@@ -140,10 +140,13 @@ def _ragged():
 
 #: ``solver._path_extras`` (and ASGD's ``_widths``, ``_step_nonzeros``,
 #: ``_step_walked``) as the PARENT (PR 41's tree) reckoned them on these
-#: fixtures at ``batch_rate`` 0.05, written down from its output
+#: fixtures at ``batch_rate`` 0.05, written down from its output (a dense
+#: record also says, since PR 49, what share of its lane tiles a step
+#: fetches: all of them on every path but the tile-list kernel's)
 PARENT = {
     "dense-784": dict(
-        extras={"dense_step_path": "two_products"},
+        extras={"dense_step_path": "two_products",
+                "dense_tiles_read_share": 1.0},
         widths=(None, None), nonzeros=(), walked=()),
     "ell-39-of-40": dict(
         extras={
