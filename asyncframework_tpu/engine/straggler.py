@@ -18,12 +18,16 @@ Delta from the reference: the per-round multipliers draw from a seeded
 ``numpy`` Generator instead of an unseeded ``java.util.Random``, so runs are
 reproducible; staleness on a real pod also arises naturally from compute-time
 variance -- this module only *adds* controlled skew.
+
+The model keeps an account of what it injected (:meth:`DelayModel.account`,
+a run's ``TrainResult.extras``): plain sums on the one thread that builds a
+run's tasks, nothing where it injects nothing.
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import List, Optional, Tuple
+from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
 
@@ -42,13 +46,25 @@ def build_cloud_stragglers(num_workers: int) -> Tuple[List[int], List[int]]:
 
 @dataclass
 class DelayModel:
-    """Computes the injected delay (ms) for a worker in one round."""
+    """Computes the injected delay (ms) for a worker in one round.
+
+    :meth:`delay_ms` is called where a task is built, by ONE thread (an
+    engine run's submitter): it draws from the seeded generator in call
+    order and keeps the account without a lock."""
 
     coeff: float
     num_workers: int
     seed: int = 42
     avg_delay_ms: float = 0.0
     calibrated: bool = False
+    #: the account: when the calibration ended (the run's accepted updates
+    #: and seconds, as :meth:`calibrate` is told), the tasks given a delay
+    #: and the sum of the sleeps they were given, by multiplier class
+    calibrated_at_update: int = 0
+    calibrated_at_s: float = 0.0
+    delayed_tasks: int = 0
+    sleep_ms: float = 0.0
+    sleep_long_tail_ms: float = 0.0
     _rng: np.random.Generator = field(default=None, repr=False)  # type: ignore
     _normal: List[int] = field(default_factory=list)
     _long_tail: List[int] = field(default_factory=list)
@@ -66,10 +82,25 @@ class DelayModel:
     def enabled(self) -> bool:
         return self.coeff != 0
 
-    def calibrate(self, avg_delay_ms: float) -> None:
-        """Fix the average-delay scale after the measurement phase."""
+    @property
+    def stragglers(self) -> List[int]:
+        """The workers this model ever delays, long tail first."""
+        if self.cloud_mode:
+            return self._long_tail + self._normal
+        return [0] if self.coeff > 0 else []
+
+    def long_tail(self, worker_id: int) -> bool:
+        """Whether the worker's multipliers are the long tail's."""
+        return worker_id in self._long_tail
+
+    def calibrate(self, avg_delay_ms: float, at_update: int = 0,
+                  at_s: float = 0.0) -> None:
+        """Fix the average-delay scale after the measurement phase
+        (``at_update``, ``at_s``: where the run stood, for the account)."""
         self.avg_delay_ms = avg_delay_ms
         self.calibrated = True
+        self.calibrated_at_update = at_update
+        self.calibrated_at_s = at_s
 
     def delay_ms(self, worker_id: int) -> float:
         """Delay to inject for this worker this round (0 before calibration)."""
@@ -77,12 +108,44 @@ class DelayModel:
             return 0.0
         if not self.cloud_mode:
             if worker_id == 0 and self.coeff > 0:
-                return float(round(self.coeff * self.avg_delay_ms))
+                return self._given(
+                    float(round(self.coeff * self.avg_delay_ms)), False)
             return 0.0
         if worker_id in self._long_tail:
             c = self._rng.random() * 7.5 + 2.5
-            return float(round(c * self.avg_delay_ms))
+            return self._given(float(round(c * self.avg_delay_ms)), True)
         if worker_id in self._normal:
             c = self._rng.random() + 1.5
-            return float(round(c * self.avg_delay_ms))
+            return self._given(float(round(c * self.avg_delay_ms)), False)
         return 0.0
+
+    def _given(self, delay_ms: float, long_tail: bool) -> float:
+        if delay_ms > 0:
+            self.delayed_tasks += 1
+            self.sleep_ms += delay_ms
+            if long_tail:
+                self.sleep_long_tail_ms += delay_ms
+        return delay_ms
+
+    def account(self, accepted_by_worker: Sequence[int]) -> Dict[str, object]:
+        """What was injected, as scalars (``accepted_by_worker``: the
+        run's accepted updates by worker id).  Every figure is 0 where the
+        model is off or the run ended inside the calibration.  A task is
+        counted where it is built: one that the run's end overtakes was
+        given its sleep and never took it (at most one a straggler)."""
+        on = self.enabled and self.calibrated
+        accepted = sum(accepted_by_worker)
+        return {
+            "avg_delay_ms": self.avg_delay_ms if on else 0.0,
+            "delay_calibrated_at_update":
+                self.calibrated_at_update if on else 0,
+            "delay_calibrated_at_s": self.calibrated_at_s if on else 0.0,
+            "straggler_workers": len(self.stragglers),
+            "delayed_tasks": self.delayed_tasks,
+            "delay_sleep_s": self.sleep_ms / 1e3,
+            "delay_sleep_long_tail_s": self.sleep_long_tail_ms / 1e3,
+            "accepted_from_stragglers": sum(
+                accepted_by_worker[w] for w in self.stragglers) if on else 0,
+            "accepted_after_calibration":
+                max(0, accepted - self.calibrated_at_update) if on else 0,
+        }

@@ -115,6 +115,7 @@ class TraceSpan(Event):
     bytes: Optional[int] = None  # wire bytes of the RPC the span covers
     batch: Optional[int] = None  # updates the timed piece of work served
     calls: Optional[int] = None  # trajectory.eval: stacks evaluated
+    delay_class: Optional[str] = None  # task.delay: normal | long_tail
 
 
 EVENT_TYPES: Dict[str, Type[Event]] = {

@@ -38,7 +38,10 @@ black hole.  This module makes one update's life observable end to end:
   executors blocked in ``block_until_ready`` would own every gap).  The
   two *holds* are the exception: waits of ONE thread, the submitter, with
   a cause the program knows, so they are annotated and a gap in which the
-  recipe held workers back carries the recipe's name.
+  recipe held workers back carries the recipe's name.  So is
+  ``task.delay``, an injected straggler's sleep: a wait with a cause, on
+  the few threads of the late workers, and a gap a sleeper leaves carries
+  its name.
 
   ================ ==== ========= ==================================== =======
   stage            kind thread    from -> to                           parent
@@ -62,7 +65,14 @@ black hole.  This module makes one update's life observable end to end:
                                   inbox -> ``fn()`` in: the thread's
                                   wake-up (``task.inbox`` less it is
                                   the submitter's work before the put)
-  task.dispatch    work executor  ``fn()`` entered -> step returned    compute
+  task.delay       wait executor  ``fn()`` entered -> the injected     compute
+                                  sleep's end (``DelayModel``): only a
+                                  task that was given a delay and whose
+                                  sleep fires, so never a retry or a
+                                  speculative copy; ``delay_class`` is
+                                  ``normal`` or ``long_tail``
+  task.dispatch    work executor  ``fn()`` entered (or its injected    compute
+                                  sleep over) -> step returned
   task.turn        wait executor  the wait for this task's turn at the task.dispatch
                                   chip's queue (``DispatchTurns``: a
                                   cohort's order); a chip without
@@ -104,10 +114,11 @@ black hole.  This module makes one update's life observable end to end:
                                   its seconds in every run)
   ================ ==== ========= ==================================== =======
 
-  ``task.inbox + task.dispatch + task.device_wait + result.queue`` cover
-  ``compute`` from its first instant; what is left (an injected straggler
-  delay, the scheduler's status update, the handler, the key lock, GIL
-  hand-offs) is ``compute``'s self time.  Only the first copy of a task
+  ``task.inbox + task.dispatch + task.device_wait + result.queue``, and
+  ``task.delay`` between the first two where a straggler sleeps, cover
+  ``compute`` from its first instant; what is left (the scheduler's
+  status update, the handler, the key lock, GIL hand-offs) is
+  ``compute``'s self time.  Only the first copy of a task
   to run records the task stages: a retry or a speculative copy finds
   ``task.inbox`` closed and records nothing.  ``task.dispatch`` is
   ``task.turn + task.model_copy`` (one a copy) ``+ task.enqueue`` and its
@@ -177,6 +188,9 @@ MERGE_HISTORY = "merge.history"
 SUBMIT = "submit"
 TASK_INBOX = "task.inbox"
 TASK_WAKE = "task.wake"
+#: an injected straggler's sleep in front of its dispatch
+#: (``engine/straggler.py: DelayModel``)
+TASK_DELAY = "task.delay"
 TASK_DISPATCH = "task.dispatch"
 TASK_TURN = "task.turn"
 TASK_MODEL_COPY = "task.model_copy"
@@ -202,7 +216,7 @@ HOLD_BACKLOG = "hold.backlog"
 WAIT_WORKERS = "wait.workers"
 
 STAGES = (PULL_WAIT, PULL_RTT, PIPELINE, WORKER_IDLE, SUBMIT, COMPUTE,
-          TASK_INBOX, TASK_WAKE,
+          TASK_INBOX, TASK_WAKE, TASK_DELAY,
           TASK_DISPATCH, TASK_TURN, TASK_MODEL_COPY, TASK_ENQUEUE,
           TASK_DEVICE_WAIT, TASK_DEVICE_WAIT_ALONE, RESULT_QUEUE,
           PUSH_WAIT, PUSH_RTT,
@@ -216,10 +230,12 @@ WORK_STAGES = frozenset((SUBMIT, TASK_DISPATCH, TASK_MODEL_COPY, MERGE_QUEUE,
 #: the submitter's two waits with a cause: annotated like work (one thread,
 #: so they cannot crowd a gap as 32 blocked executors would)
 HOLD_STAGES = frozenset((HOLD_BARRIER, HOLD_BACKLOG))
-#: the four children that must cover ``compute``
+#: the four children that must cover ``compute`` (with ``task.delay``,
+#: where a task has one)
 COMPUTE_CHILDREN = (TASK_INBOX, TASK_DISPATCH, TASK_DEVICE_WAIT,
                     RESULT_QUEUE)
-#: the four and the stages inside them: what a task's executor records
+#: the four and the stages inside them: what every task's executor
+#: records (a delayed one ``task.delay`` besides)
 TASK_STAGES = COMPUTE_CHILDREN + (TASK_WAKE, TASK_TURN, TASK_MODEL_COPY,
                                   TASK_ENQUEUE, TASK_DEVICE_WAIT_ALONE)
 #: a span's parent, by stage (engine spans; the DCN plane's have none)
@@ -227,11 +243,13 @@ PARENT = {COMPUTE: SUBMIT, MERGE_QUEUE: COMPUTE, MERGE_APPLY: COMPUTE,
           MERGE_HISTORY: MERGE_APPLY, TASK_WAKE: TASK_INBOX,
           TASK_TURN: TASK_DISPATCH, TASK_MODEL_COPY: TASK_DISPATCH,
           TASK_ENQUEUE: TASK_DISPATCH, TASK_DEVICE_WAIT_ALONE: COMPUTE,
+          TASK_DELAY: COMPUTE,
           **{st: COMPUTE for st in COMPUTE_CHILDREN}}
-#: what a work stage is called in a profiler trace
+#: what a work stage (a hold, an injected delay) is called in a profiler
+#: trace
 ANNOTATION_PREFIX = "async."
 _ANNOTATION_NAME = {st: ANNOTATION_PREFIX + st
-                    for st in WORK_STAGES | HOLD_STAGES}
+                    for st in WORK_STAGES | HOLD_STAGES | {TASK_DELAY}}
 #: stages recorded client-side (worker process) vs server-side (PS)
 CLIENT_STAGES = (PULL_RTT, PIPELINE, COMPUTE, PUSH_WAIT, PUSH_RTT)
 SERVER_STAGES = (PULL_WAIT, MERGE_QUEUE, MERGE_APPLY)
@@ -287,13 +305,16 @@ class Span:
     #: evaluation calls a ``trajectory.eval`` made: the stacks of
     #: ``snapshots_per_call`` snapshots it built, one at a time
     calls: Optional[int] = None
+    #: a ``task.delay``: which of the straggler model's two multiplier
+    #: classes the sleeper is of, ``normal`` or ``long_tail``
+    delay_class: Optional[str] = None
 
     # wire format: short keys, Nones omitted -- spans ride PUSH headers
     _WIRE = (("s", "stage"), ("t", "trace_id"), ("i", "span_id"),
              ("p", "parent_id"), ("w", "worker_id"), ("v", "model_version"),
              ("b", "start_ms"), ("d", "dur_ms"), ("st", "staleness"),
              ("sm", "staleness_ms"), ("ac", "accepted"), ("by", "bytes"),
-             ("n", "batch"), ("c", "calls"))
+             ("n", "batch"), ("c", "calls"), ("dc", "delay_class"))
 
     def to_wire(self) -> dict:
         out = {}
@@ -540,10 +561,11 @@ def span(stage: str, ut=None, **attrs):
         with span(TASK_DISPATCH, ut):
             g, key = step(X, y, w, key)
 
-    - a *work* stage (``WORK_STAGES``) and the submitter's two holds
-      (``HOLD_STAGES``) open a profiler annotation ``async.<stage>``
-      whenever a ``jax.profiler`` session is open, so the stage shows on
-      the device trace's clock; a wait stage never does.  With no session
+    - a *work* stage (``WORK_STAGES``), the submitter's two holds
+      (``HOLD_STAGES``) and an injected delay (``task.delay``) open a
+      profiler annotation ``async.<stage>`` whenever a ``jax.profiler``
+      session is open, so the stage shows on the device trace's clock; any
+      other wait stage never does.  With no session
       open (one static call to find out, 20 ns) there is no annotation;
     - with ``ut`` it also records a real :class:`Span` per update: start
       and end read here, ``parent_id`` from ``PARENT``, one ``trace_id``
@@ -838,7 +860,7 @@ def span_event(span: Span, time_ms: float) -> "object":
         start_ms=span.start_ms, dur_ms=span.dur_ms,
         staleness=span.staleness, staleness_ms=span.staleness_ms,
         accepted=span.accepted, bytes=span.bytes, batch=span.batch,
-        calls=span.calls,
+        calls=span.calls, delay_class=span.delay_class,
     )
 
 
@@ -978,7 +1000,8 @@ def chrome_trace(spans) -> dict:
     stages from the PS-side stages of its updates."""
     events = []
     for sp in spans:
-        client = sp.stage in CLIENT_STAGES or sp.stage in TASK_STAGES
+        client = (sp.stage in CLIENT_STAGES or sp.stage in TASK_STAGES
+                  or sp.stage == TASK_DELAY)
         args = {"trace_id": sp.trace_id, "model_version": sp.model_version}
         if sp.parent_id:
             args["parent_id"] = sp.parent_id

@@ -116,7 +116,7 @@ class ASAGA(EngineSolver):
         run = EngineRun(self)
         ck = run.restore("asaga")
         ctx, inst, waiting = run.ctx, run.inst, run.waiting
-        calibrator, delay_model, ckpt = run.calibrator, run.delay_model, run.ckpt
+        calibrator, ckpt = run.calibrator, run.ckpt
         state, state_lock, stop = run.state, run.state_lock, run.stop
         hot_lock = run.key_lock  # guards the alpha slots too
         if ck is not None:
@@ -294,7 +294,7 @@ class ASAGA(EngineSolver):
                     with trace.span(trace.CHECKPOINT):
                         run.save(save_k, save_w, **history_fields(save_ab))
                 if calibrator.maybe_finalize(state["k"]):
-                    delay_model.calibrate(calibrator.avg_delay_ms)
+                    run.delays_calibrated(state["accepted"])
             clock.waits()  # the loop's last busy stretch
             stop.set()
 
@@ -509,7 +509,7 @@ class ASAGA(EngineSolver):
                         snapshots.append((now_ms(), w))
                         inst.on_snapshot(rounds * nw)
                 if calibrator.maybe_finalize(k):
-                    run.delay_model.calibrate(calibrator.avg_delay_ms)
+                    run.delays_calibrated(rounds * nw)
             run_ok = True
         finally:
             clock.waits()  # the loop's last busy stretch
@@ -744,7 +744,9 @@ class ASAGA(EngineSolver):
         # (an injected delay sleeps in front of the dispatch: a straggler
         # takes no turn, ``ASGD._make_task``)
         delay_ms = delay_model.delay_ms(wid)
+        late = delay_ms > 0
         return worker_task(dispatch, delay_ms, ut, worker=wid, chip=dev.id,
                            width=self._programs.widths[wid],
-                           turns=None if delay_ms > 0 else self._turns.get(dev),
-                           steps_out=self._steps_out.get(dev))
+                           turns=None if late else self._turns.get(dev),
+                           steps_out=self._steps_out.get(dev),
+                           long_tail=late and delay_model.long_tail(wid))

@@ -80,7 +80,7 @@ class ASGD(EngineSolver):
         run = EngineRun(self)
         run.restore("asgd")
         ctx, inst, waiting = run.ctx, run.inst, run.waiting
-        calibrator, delay_model, ckpt = run.calibrator, run.delay_model, run.ckpt
+        calibrator, ckpt = run.calibrator, run.ckpt
         state, state_lock, stop = run.state, run.state_lock, run.stop
         d = self.ds.d
         nw, freq = cfg.num_workers, cfg.printer_freq
@@ -257,7 +257,7 @@ class ASGD(EngineSolver):
                     with trace.span(trace.CHECKPOINT):
                         run.save(save_k, save_w)
                 if calibrator.maybe_finalize(state["k"]):
-                    delay_model.calibrate(calibrator.avg_delay_ms)
+                    run.delays_calibrated(state["accepted"])
             clock.waits()  # the loop's last busy stretch
             stop.set()
 
@@ -427,7 +427,7 @@ class ASGD(EngineSolver):
                         snapshots.append((now_ms(), w))
                         inst.on_snapshot(rounds * nw)
                 if calibrator.maybe_finalize(k):
-                    run.delay_model.calibrate(calibrator.avg_delay_ms)
+                    run.delays_calibrated(rounds * nw)
             run_ok = True
         finally:
             clock.waits()  # the loop's last busy stretch
@@ -510,11 +510,13 @@ class ASGD(EngineSolver):
         # (an injected delay sleeps in front of the dispatch: a straggler
         # takes no turn, or the workers behind it would wait for its sleep)
         delay_ms = delay_model.delay_ms(wid)
+        late = delay_ms > 0
         return worker_task(dispatch, delay_ms, ut, worker=wid, chip=dev.id,
                            width=self._programs.widths[wid],
-                           turns=None if delay_ms > 0 else self._turns.get(dev),
+                           turns=None if late else self._turns.get(dev),
                            steps_out=self._steps_out.get(dev),
-                           spread=self._spread.get(dev))
+                           spread=self._spread.get(dev),
+                           long_tail=late and delay_model.long_tail(wid))
 
     def _task_maker(self, run: EngineRun):
         """``make_tasks`` of this run (``EngineRun.drive``): a task captures

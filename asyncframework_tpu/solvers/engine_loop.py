@@ -510,6 +510,15 @@ class EngineRun:
     def now_ms(self) -> float:
         return (time.monotonic() - self.start_wall) * 1e3
 
+    def delays_calibrated(self, accepted: int) -> None:
+        """The calibration has ended, once a run (the updater's thread,
+        where ``calibrator.maybe_finalize`` said so): the delay model gets
+        its scale, and where the run stood for its account: ``accepted``
+        updates of this run, and the run's clock."""
+        self.delay_model.calibrate(
+            self.calibrator.avg_delay_ms, at_update=accepted,
+            at_s=self.now_ms() / 1e3)
+
     # ------------------------------------------------- where the model lives
     def replicate_model(self) -> Optional[List]:
         """The choice between one buffer and a replica a chip, made once,
@@ -775,7 +784,9 @@ class EngineRun:
         by_worker = [inst.accepted_by_worker.get(wid, 0)
                      for wid in range(cfg.num_workers)]
         extras.update(accepted_by_worker_min=min(by_worker),
-                      accepted_by_worker_max=max(by_worker))
+                      accepted_by_worker_max=max(by_worker),
+                      # what the straggler model injected: zeros at coeff 0
+                      **self.delay_model.account(by_worker))
         programs = self.solver._programs
         for name, per_step in (
                 ("nonzero_slots_per_step_mean", programs.step_nonzeros),

@@ -272,7 +272,8 @@ def worker_task(dispatch: Callable[[Optional["trace_mod.UpdateTrace"]], tuple],
                 width: Optional[int] = None,
                 turns=None,
                 steps_out: Optional[StepsOut] = None,
-                spread: Optional[Callable] = None):
+                spread: Optional[Callable] = None,
+                long_tail: bool = False):
     """The closure every worker task is (ASGD and ASAGA, ``run`` and
     ``run_sync``): ``dispatch(mine)`` moves what the step needs to the
     worker's chip and dispatches the step, returning its outputs, gradient
@@ -312,8 +313,13 @@ def worker_task(dispatch: Callable[[Optional["trace_mod.UpdateTrace"]], tuple],
 
     The injected delay models a slow *machine*: only the first body to run
     it sleeps -- a speculative copy or a replacement executor is a
-    different (healthy) host path and must bypass the straggler.  It lies
-    outside the stages, in ``compute``'s self time."""
+    different (healthy) host path and must bypass the straggler.  The
+    sleep is the stage ``task.delay`` (a child of ``compute``, from the
+    closure's entry to the sleep's end; ``async.task.delay`` in a profiler
+    session), recorded where ``delay_ms > 0`` and the sleep fires and
+    nowhere else; ``long_tail``: the class of the sleeper's multipliers
+    (``DelayModel.long_tail``), which the span carries as ``delay_class``.
+    A task with no delay pays the one comparison."""
     delay_fired = threading.Event()
     where = {"worker": worker, "chip": chip}
     if width is not None:
@@ -332,7 +338,10 @@ def worker_task(dispatch: Callable[[Optional["trace_mod.UpdateTrace"]], tuple],
         try:
             if delay_ms > 0 and not delay_fired.is_set():
                 delay_fired.set()
-                time.sleep(delay_ms / 1e3)
+                with trace_mod.span(
+                        trace_mod.TASK_DELAY, mine, worker=worker,
+                        delay_class="long_tail" if long_tail else "normal"):
+                    time.sleep(delay_ms / 1e3)
             with trace_mod.span(trace_mod.TASK_DISPATCH, mine, **where):
                 with trace_mod.span(trace_mod.TASK_TURN, mine):
                     if ticket is not None:

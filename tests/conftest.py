@@ -37,15 +37,30 @@ import pytest
 _STALE_LAST_ENTRY = (
     "test_bench_model_read_local.py::"
     "test_the_manifest_appends_the_reader_behind_what_was_there")
+#: PR 51 (a ``model_config`` PR: one configuration, one cell, six readers)
+#: appends behind ``dense_tiles_read`` in turn, and ends the two assertions
+#: of ``test_bench_dense_tiles_read.py`` that count from the list's END
+#: ("mine is the last", "``model_read_local`` is the one before").  Marked
+#: the same way, for the same ``benchmark`` PR to take out; what the two
+#: hold beyond the position is held, as it stands, by
+#: ``test_bench_cloud.py::test_the_entries_in_front_of_them_stand_as_they_
+#: were``.
+_STALE_BY_POSITION = (
+    _STALE_LAST_ENTRY,
+    "test_bench_dense_tiles_read.py::"
+    "test_the_manifest_appends_the_reader_behind_what_was_there",
+    "test_bench_dense_tiles_read.py::"
+    "test_the_entry_in_front_of_it_stands_as_it_was",
+)
 
 
 def pytest_collection_modifyitems(items):
     for item in items:
-        if item.nodeid.endswith(_STALE_LAST_ENTRY):
+        if item.nodeid.endswith(_STALE_BY_POSITION):
             item.add_marker(pytest.mark.xfail(
-                reason="written while model_read_local was per_layer's "
-                       "last entry; dense_tiles_read (PR 49) is appended "
-                       "behind it: see tests/conftest.py",
+                reason="written while its metric was per_layer's last "
+                       "entry (or last but one); later PRs append behind "
+                       "it: see tests/conftest.py",
                 strict=True))
 
 

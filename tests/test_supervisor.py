@@ -517,18 +517,12 @@ class TestRunSyncFailFast:
         from asyncframework_tpu.solvers import engine_loop
         from asyncframework_tpu.solvers.base import DeadWorkerError
 
-        class SlowW2:
+        class SlowW2(engine_loop.DelayModel):
             """Worker 2's task holds the executor busy long enough for the
             kill to land mid-task deterministically."""
 
-            def __init__(self, *a, **k):
-                pass
-
             def delay_ms(self, wid):
                 return 3000.0 if wid == 2 else 0.0
-
-            def calibrate(self, avg_ms):
-                pass
 
         monkeypatch.setattr(engine_loop, "DelayModel", SlowW2)
         X = np.random.default_rng(0).normal(size=(256, 8)).astype(np.float32)
