@@ -696,14 +696,64 @@ def sparse_residual(
     return sparse_margins(cols, vals, w) - y
 
 
+#: slots a tile of the sorted-segment kernel's columns
+#: (``pallas_kernels.SEGMENT_TILE``, 4,096) must hold, on average over the
+#: tiles of ``g``, for the sum by sorted segments to be chosen over the
+#: scatter-add (:func:`sparse_scatter_path`): one group of the kernel, the
+#: least a tile with a slot is read for.  On the v5e (PERF.md section 6,
+#: PR 52; ms alone, scatter-add / segments): criteo's 5,673,408 pairs into
+#: 1,000,000 columns (23,157 a tile) 39.0 / 10.1, its 1,156,584 under
+#: ASAGA (4,721) 8.5 / 2.5, a whole shard's 55,868,280 (228,034) 376 /
+#: 164; 2,603,040 pairs with uniform columns into 4,000,000 columns (2,665
+#: a tile) 18.3 / 5.2, into 16,000,000 (666) 27.0 / 6.7, into kdd2012's
+#: 54,686,452 (195) 27.2 / 11.8.  The constant is NOT the break-even,
+#: which lies under 195: it keeps kdd2012's cell, whose faster step would
+#: buy snapshots its 14.15 of 16 GB have no room for, on the program it
+#: was admitted with until a ``benchmark`` PR has moved its
+#: ``printer_freq`` (ROADMAP Speed 1(a), Design 2)
+SPARSE_SEGMENT_TILE_SLOTS = 1_024
+
+
+def sparse_scatter_path(d: int, slots: int, walk=None,
+                        dtype=jnp.float32) -> str:
+    """``"segments"`` or ``"scatter"``: which program
+    :func:`make_sparse_grad_sum` traces to add ``slots`` (column, product)
+    pairs of ``dtype`` into a ``(d,)`` gradient, from what can be observed
+    when the step is built -- the backend, the dtype, whether the sample
+    is walked, and the mean run of slots a tile of ``g``.
+
+    The v5e's scatter-add pays by the INDEX, 6.7 to 10.5 ns a slot
+    whatever it holds and in whatever order, while a sort of the pairs
+    costs 2.0 ns a pair and the sum of a sorted run into a tile of columns
+    is dense work, 0.5 ns a slot (``pallas_kernels.segment_tiles_sum``;
+    PERF.md section 6, PR 52).  That kernel pays a grid step for every
+    tile of ``g`` and a group of 1,024 slots for every tile that holds
+    one, so it is chosen where the tiles' mean run reaches
+    :data:`SPARSE_SEGMENT_TILE_SLOTS` (and why there): criteo's 1,000,000
+    columns hold 23,157 slots a tile under ASGD and 4,721 under ASAGA,
+    kdd2012's 54,686,452 hold 195.  A walked sample (``walk``: the blocks
+    of a shard stored in lane tiles, webspam's) is added block by block
+    into a carry and keeps the scatter-add, and so do the CPU and every
+    other dtype.
+    """
+    if not (_on_tpu() and walk is None
+            and jnp.dtype(dtype) == jnp.dtype(jnp.float32)):
+        return "scatter"
+    tiles = -(-d // _kernels().SEGMENT_TILE)
+    return ("segments" if slots >= SPARSE_SEGMENT_TILE_SLOTS * tiles
+            else "scatter")
+
+
 def make_sparse_grad_sum(d: int):
-    """jit (cols, vals, coeff[, walk]) -> dense (d,) gradient by
-    scatter-add: ONE over the whole sample, or one a block of the walk.
+    """jit (cols, vals, coeff[, walk]) -> dense (d,) gradient: the sum of
+    every slot's product at its column, by ONE scatter-add over the whole
+    sample, by one a block of the walk, or by sorted segments
+    (:func:`sparse_scatter_path`: the ONE place the program is chosen).
 
     ``g = sum_i coeff_i * x_i`` -- the sparse analog of ``X.T @ coeff``:
-    every slot's product ``vals * coeff`` is added into ``g`` at its column,
-    in the order the slots are stored.  Padding slots add 0 to column 0 and
-    a column id outside ``[0, d)`` is dropped.  A scatter-add pays for
+    every slot's product ``vals * coeff`` is added into ``g`` at its
+    column.  Padding slots add 0 to column 0 and a column id outside
+    ``[0, d)`` is dropped.  A scatter-add pays for
     every slot it is GIVEN, 6.7 to 8.8 ns each whatever the value, so the
     steps give it as few empty ones as the storage lets them tell: a shard
     stored in sublane tiles at its live width (``steps._live_columns``: the
@@ -713,18 +763,23 @@ def make_sparse_grad_sum(d: int):
     is then the carry of the walk's loops and takes one ``(R, C)`` block a
     scatter-add, each row tile up to its last non-zero, so the slots behind
     it and the unfilled tail of the capacity are never given.  Only the
-    order of a column's terms differs from the one-shot form.
+    order of a column's terms differs between the forms.
 
-    Nothing puts the slots in order first.  On the v5e a sort is cheap and
-    an element-wise gather or scatter is dear (PERF.md section 6, PR 33;
-    5,818,880 slots into d = 1,000,000): this scatter-add costs 6.9 ns a
-    slot, with Zipf(1) columns (7.4% of the slots on one column) as with
-    uniform ones; sorting the slots by column in front of it (an argsort
-    and two permutation gathers, then ``indices_are_sorted=True``) cost
-    30.3, and carrying the products through ``lax.sort`` as a payload
-    11.5.  The scatter adds a column's terms in the order they are stored,
-    which is the order a stable sort left them in: ``g`` is the sorted
-    form's to the bit.
+    The scatter-adds take the slots in the order they are stored.  On the
+    v5e a sort is cheap and an element-wise gather or scatter is dear
+    (PERF.md section 6, PR 33; 5,818,880 slots into d = 1,000,000): the
+    scatter-add costs 6.9 ns a slot, with Zipf(1) columns (7.4% of the
+    slots on one column) as with uniform ones, and a sort in FRONT of it
+    buys nothing: the scatter behind an argsort and two permutation
+    gathers (``indices_are_sorted=True``) cost 30.3 ns a slot, behind
+    ``lax.sort`` with the products as payload 11.5.  What pays is a sort
+    with NO scatter behind it (``"segments"``; PERF.md section 6, PR 52):
+    the pairs sorted once by column, 2.0 ns a pair, and each tile of
+    ``g``'s columns summed from its contiguous run of them by a kernel,
+    0.5 ns a slot: 10.1 ms for criteo's 5,673,408 slots where the
+    scatter-add takes 39.0, and a column's terms summed by group of
+    1,024, not one after the other (3.4e-7 of ``max |g|`` off the float64
+    sum where the scatter-add is 6.2e-6 off).
 
     The eight-wide form that halved the gather (:func:`sparse_margins`)
     does NOT pay here (PERF.md section 6, PR 36; the same slots, 40.2 ms
@@ -740,6 +795,10 @@ def make_sparse_grad_sum(d: int):
     @jax.jit
     def grad_sum(cols, vals, coeff, walk=None):
         with jax.named_scope("grad"):
+            if sparse_scatter_path(
+                    d, cols.size, walk, vals.dtype) == "segments":
+                return _kernels().segment_tiles_sum(
+                    cols.ravel(), (vals * coeff[:, None]).ravel(), d)
             g = jnp.zeros(d, vals.dtype)
             if walk is None:
                 return add(g, cols, vals * coeff[:, None])

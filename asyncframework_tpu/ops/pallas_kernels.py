@@ -43,17 +43,28 @@ cannot do:
   key block) with the running (m, l, o) in VMEM scratch, returning the
   (o, m, l) triple so ``parallel/ring.py`` can merge ring steps with the
   cheap rescale (``ring_attention(..., block_kernel="pallas")``).
-- For rcv1-style sparse data the SURVEY-prescribed alternative (densify
-  per batch, then a dense kernel) lives in the data layer; a
-  scatter/gather CSR kernel is deliberately NOT attempted -- vector gather
-  does not map onto the VPU's strided units, padding to blocked-ELL
-  densifies anyway.
+- :func:`segment_tiles_sum` -- the sparse steps' ``g = sum of products
+  at their columns`` by SORTED SEGMENTS.  XLA's scatter-add touches ``g``
+  one index at a time, 6.7 to 8.8 ns a slot on the v5e in whatever order
+  (half to three quarters of the sparse cells' device time), while its
+  sort takes 2 ns a pair: so the (column, product) pairs are sorted
+  once, the slots of any tile of ``g``'s columns are then ONE contiguous
+  run of the list, and the kernel adds a run into its tile as dense work
+  (per 1,024 slots one product on the MXU: the products, in three exact
+  bf16 parts, placed by ``column // 128``, with the one-hot of ``column %
+  128``).  Where that pays is ``gradients.sparse_scatter_path``'s choice,
+  from the mean run of slots a tile (PERF.md section 6, PR 52).
+- The sparse steps' GATHERS stay XLA's (``gradients.sparse_margins``): a
+  vector gather does not map onto the VPU's strided units, and for
+  rcv1-style data the SURVEY-prescribed alternative (densify per batch,
+  then a dense kernel) lives in the data layer.
 
 ``interpret`` is an explicit argument everywhere: the CPU tests pass
 ``interpret=True``, every other caller gets the Mosaic-compiled kernel
-(``chip_smoke.py`` phase E runs all three at full shapes on the chip
-against precision "highest"; ``tests/test_step_layout.py`` compiles the
-steps that hold the two one-pass kernels for a described v5e).
+(``chip_smoke.py`` phases E and E.segments run all four at full shapes
+on the chip against precision "highest" or float64;
+``tests/test_step_layout.py`` compiles the steps that hold the two
+one-pass kernels and the segment kernel for a described v5e).
 """
 
 from __future__ import annotations
@@ -477,6 +488,196 @@ def dense_onepass_tiles(X, y, w, mask, alpha=None, *, logistic: bool = False,
         flag[:, None],
         jnp.take(out[1].reshape(-1, _LANE), at, axis=0, mode="clip"), 0.0)
     return out[0].sum(axis=1), diff.reshape(-1)[:n]
+
+
+# ------------------------------------------------------- sorted segments
+#: columns of ``g`` one grid step of :func:`segment_tiles_sum` sums: a
+#: multiple of 1,024 (the output block is whole ``(8, 128)`` registers).
+#: On the v5e (PERF.md section 6, PR 52; the kernel alone over criteo's
+#: 5,673,408 sorted pairs, ms): 1,024 columns 2.75, 2,048 2.59, 4,096
+#: 2.75, 8,192 3.43 (a tile's three parts are 192 rows of the product's
+#: left side there); over ASAGA's 1,156,584 with their sort 2.44 to 2.46
+#: at 4,096, 2.55 at 8,192, 2.78 at 16,384; over 2.6M pairs in 54.7M
+#: columns 11.8 at 4,096 and 9.3 at 16,384 (13,352 grid steps, or 3,338)
+SEGMENT_TILE = 4096
+#: rows of 128 sorted pairs one DMA of :func:`segment_tiles_sum` brings:
+#: a multiple of :data:`_SEGMENT_GROUP`.  It hardly matters (same probe:
+#: 8 rows 2.88 ms, 16 to 256 rows 2.70 to 2.79): the pairs lie in VMEM
+#: already where they fit it
+_SEGMENT_BLOCK_ROWS = 64
+#: rows of 128 sorted pairs one product of the kernel takes (one ``(8,
+#: 128)`` register of columns, one of products: 1,024 slots)
+_SEGMENT_GROUP = 8
+
+
+def _bf16_parts(p):
+    """``p`` (float32) as three float32 arrays, each exact in bfloat16,
+    that add up to ``p`` exactly: eight bits of the significand each, the
+    later two those of the remainder (exact in float32: a remainder has
+    16, then 8 significant bits)."""
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    hi = p.astype(bf16).astype(f32)
+    rest = p - hi
+    mid = rest.astype(bf16).astype(f32)
+    return hi, mid, (rest - mid).astype(bf16).astype(f32)
+
+
+def _segment_kernel(starts_ref, c_hbm, p_hbm, g_ref, cbuf, pbuf, sem, at_ref,
+                    *, tile: int, block_rows: int):
+    """One grid step: the sums of the ``tile`` columns from ``t * tile``
+    on, from the run ``starts_ref[t] .. starts_ref[t + 1]`` of the sorted
+    pairs, which lie in HBM as rows of 128.  The run is read in groups of
+    :data:`_SEGMENT_GROUP` rows, from the group that holds its first pair
+    to the one that holds its last: what such a group holds of a
+    neighbouring tile's columns matches none of this one's.  The groups
+    come in blocks of ``block_rows`` rows, each by ONE pair of DMAs into
+    buffer ``block % 2``.  Consecutive tiles read consecutive runs, so the
+    whole grid reads each block once, in ascending order: ``at_ref``
+    (SMEM, kept across grid steps) holds the last block started and the
+    last awaited, and the block after the one in hand is started before
+    the work on it begins, also where a later tile will be the first to
+    read it."""
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    group, sub = _SEGMENT_GROUP, tile // _LANE
+    t = pl.program_id(0)
+    first, end = starts_ref[t], starts_ref[t + 1]
+    slots = group * _LANE
+    # the blocks any tile reads: the last tile's run ends the valid pairs
+    blocks = pl.cdiv(starts_ref[pl.num_programs(0)], block_rows * _LANE)
+
+    @pl.when(t == 0)
+    def _():
+        at_ref[0] = -1  # started
+        at_ref[1] = -1  # awaited
+
+    def copies(k):
+        rows = pl.ds(pl.multiple_of(k * block_rows, block_rows), block_rows)
+        return (pltpu.make_async_copy(c_hbm.at[rows], cbuf.at[k % 2],
+                                      sem.at[0, k % 2]),
+                pltpu.make_async_copy(p_hbm.at[rows], pbuf.at[k % 2],
+                                      sem.at[1, k % 2]))
+
+    def start(k):
+        for copy in copies(k):
+            copy.start()
+        at_ref[0] = k
+
+    def wait(k):
+        for copy in copies(k):
+            copy.wait()
+        at_ref[1] = k
+
+    lane_id = jax.lax.broadcasted_iota(jnp.int32, (_LANE, _LANE), 0)
+    sub_id = jax.lax.broadcasted_iota(jnp.int32, (sub, _LANE), 0)
+
+    def one_group(j, acc):
+        k = j * group // block_rows
+        pl.when(k > at_ref[0])(lambda: start(k))
+        pl.when(k > at_ref[1])(lambda: wait(k))
+        pl.when((k + 1 < blocks) & (k + 1 > at_ref[0]))(lambda: start(k + 1))
+        rows = pl.ds(pl.multiple_of(j * group % block_rows, group), group)
+        local = cbuf[k % 2, rows, :] - t * tile
+        parts = _bf16_parts(pbuf[k % 2, rows, :])
+        # a column is 128 * hi + lo: g[hi, lo] = sum_k A[hi, k] B[lo, k],
+        # A the products at their hi (three exact bf16 parts, stacked), B
+        # the one-hot of lo; a pair of another tile has no hi in range
+        hi, lo = local >> 7, local & (_LANE - 1)
+        a, b = [], []
+        for r in range(group):
+            at = hi[r:r + 1] == sub_id
+            a.append(jnp.concatenate(
+                [jnp.where(at, part[r:r + 1], 0.0) for part in parts]))
+            b.append(jnp.where(lo[r:r + 1] == lane_id, 1.0, 0.0))
+        out = jax.lax.dot_general(
+            jnp.concatenate(a, axis=1).astype(bf16),
+            jnp.concatenate(b, axis=1).astype(bf16),
+            (((1,), (1,)), ((), ())), preferred_element_type=f32)
+        return acc + (out[:sub] + out[sub:2 * sub] + out[2 * sub:])
+
+    g_ref[:] = jax.lax.fori_loop(
+        first // slots, jnp.where(end > first, pl.cdiv(end, slots), 0),
+        one_group, jnp.zeros((sub, _LANE), f32))
+
+
+def segment_tiles_sum(cols, products, d: int, *, tile: Optional[int] = None,
+                      block_rows: Optional[int] = None, interpret=False):
+    """``g[j] = sum of products[k] over cols[k] == j``, ``(d,)`` float32:
+    a scatter-add by SORTED SEGMENTS, no read-modify-write an index.
+
+    ``cols`` (int32) and ``products`` (float32) are one-dimensional and in
+    any order; a column counts from the end where it is negative and is
+    dropped where it is then outside ``[0, d)``, as ``g.at[cols].add(
+    products, mode="drop")`` has it.  In XLA (:func:`_segment_sorted`):
+    ONE unstable two-operand sort of the pairs by column, a dropped pair
+    under a column beyond every tile.  Then (:func:`_segment_sums`) the
+    first pair of each tile of ``tile`` columns by one ``searchsorted`` of
+    the tiles' first columns, and the kernel (:func:`_segment_kernel`),
+    which takes those bounds scalar-prefetched and walks ``g`` a tile a
+    grid step: the slots of a tile's columns are ONE contiguous run of
+    the sorted list, and adding 1,024 of them into the tile is one
+    product on the MXU, of the pairs' products placed by ``column // 128``
+    with the one-hot of ``column % 128``.  The one-hot is exact in
+    bfloat16 and a product is split into three parts that are
+    (:func:`_bf16_parts`), so every term is the float32 product and every
+    sum float32: ``g`` differs from the scatter-add's by the order of a
+    column's terms alone.  (A product that is not finite reaches every
+    column of its group of 128 as NaN: ``0 * inf``; the scatter-add keeps
+    it to its own.)  On the v5e (PERF.md section 6, PR 52) criteo's
+    5,673,408 pairs take 10.1 ms (the kernel alone 2.75; the sort
+    alone, its outputs written out to HBM, 11.4) where the scatter-add
+    takes 39.0, and ``g`` is 3.4e-7 of ``max |g|`` off the float64 sum
+    where the scatter-add is 6.2e-6 off.
+    ``gradients.sparse_scatter_path`` says where this is chosen."""
+    tile = SEGMENT_TILE if tile is None else tile
+    block_rows = _SEGMENT_BLOCK_ROWS if block_rows is None else block_rows
+    if tile % (8 * _LANE) or block_rows % _SEGMENT_GROUP:
+        raise ValueError(f"tile {tile} or block_rows {block_rows}")
+    c, p = _segment_sorted(cols, products, d, tile, block_rows * _LANE)
+    return _segment_sums(c, p, d, tile, block_rows, interpret)
+
+
+def _segment_sorted(cols, products, d: int, tile: int, block: int):
+    """The pairs in ascending order of column, padded to whole blocks of
+    ``block`` pairs: a dropped pair and the padding under ``tile *
+    ceil(d / tile)``, a column of no tile, so they sort to the end."""
+    beyond = pl.cdiv(d, tile) * tile
+    c = jnp.where(cols < 0, cols + d, cols)
+    c = jnp.where((c < 0) | (c >= d), beyond, c)
+    pad = pl.cdiv(max(cols.shape[0], 1), block) * block - cols.shape[0]
+    return jax.lax.sort(
+        (jnp.pad(c, (0, pad), constant_values=beyond),
+         jnp.pad(products.astype(jnp.float32), (0, pad))),
+        num_keys=1, is_stable=False)
+
+
+def _segment_sums(c, p, d: int, tile: int, block_rows: int, interpret):
+    """``(d,)`` sums of the sorted, padded pairs ``(c, p)``
+    (:func:`_segment_sorted`): the tiles' bounds and the kernel."""
+    f32 = jnp.float32
+    tiles, sub = pl.cdiv(d, tile), tile // _LANE
+    starts = jnp.searchsorted(
+        c, jnp.arange(tiles + 1, dtype=jnp.int32) * tile).astype(jnp.int32)
+    vma = getattr(jax.typeof(p), "vma", None)
+    kw = {"vma": vma} if vma else {}
+    g = pl.pallas_call(
+        functools.partial(_segment_kernel, tile=tile, block_rows=block_rows),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(tiles,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 2,
+            out_specs=pl.BlockSpec((sub, _LANE), lambda t, starts: (t, 0)),
+            scratch_shapes=[pltpu.VMEM((2, block_rows, _LANE), jnp.int32),
+                            pltpu.VMEM((2, block_rows, _LANE), f32),
+                            pltpu.SemaphoreType.DMA((2, 2)),
+                            pltpu.SMEM((2,), jnp.int32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((tiles * sub, _LANE), f32, **kw),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        name="segment_tiles_sum",
+        interpret=interpret,
+    )(starts, c.reshape(-1, _LANE), p.reshape(-1, _LANE))
+    return g.reshape(-1)[:d]
 
 
 # --------------------------------------------------------------- attention

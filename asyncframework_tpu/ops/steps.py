@@ -65,6 +65,7 @@ from asyncframework_tpu.ops.gradients import (
     sample_walk,
     sparse_gather_path,
     sparse_margins,
+    sparse_scatter_path,
     walk_accumulator_resident,
     walk_tile,
 )
@@ -658,14 +659,18 @@ def sparse_walked_slots(batch_rate: float, d: int, n_rows: int, width: int,
     return float(rows * chunk * np.sum(-(-longest // chunk)))
 
 
-def _sized_by_capacity(step, batch_rate: float, d: int):
+def _sized_by_capacity(step, batch_rate: float, d: int, walks: bool):
     """A compacted sparse step's size, for who asks the step it runs:
     ``step.task_rows(n_rows)``, the rows its compaction holds
-    (:func:`_counts_rows`), and ``step.gather_path(n_rows, width)``, what
-    ``gradients.sparse_gather_path`` says of the sample it packs from
-    ``n_rows`` rows read ``width`` slots wide (the shard's live width
-    where the step was built with one) against its ``(d,)`` float32
-    model: the solvers' ``extras["sparse_gather_path"]``."""
+    (:func:`_counts_rows`), and what the two choosers say of the sample
+    it packs from ``n_rows`` rows read ``width`` slots wide (the shard's
+    live width where the step was built with one) against its ``(d,)``
+    float32 model: ``step.gather_path(n_rows, width)``
+    (``gradients.sparse_gather_path``) and ``step.scatter_path(n_rows,
+    width)`` (``gradients.sparse_scatter_path``; ``walks``: whether the
+    step walks a sample of a shard stored in lane tiles, as ASGD's does
+    and ASAGA's does not), the solvers' ``extras["sparse_gather_path"]``
+    and ``["sparse_scatter_path"]``."""
     def task_rows(n_rows):
         return sparse_step_capacity(batch_rate, n_rows)
 
@@ -675,7 +680,12 @@ def _sized_by_capacity(step, batch_rate: float, d: int):
             jax.ShapeDtypeStruct((task_rows(n_rows), width), jnp.int32),
         )
 
+    def scatter_path(n_rows, width):
+        walk = sparse_walk_tile(batch_rate, d, n_rows, width) if walks else None
+        return sparse_scatter_path(d, task_rows(n_rows) * width, walk)
+
     step.gather_path = gather_path
+    step.scatter_path = scatter_path
     return _counts_rows(step, task_rows)
 
 
@@ -823,7 +833,7 @@ def make_sparse_asgd_worker_step(batch_rate: float, d: int,
         )
         return g, key
 
-    return _sized_by_capacity(step, batch_rate, d)
+    return _sized_by_capacity(step, batch_rate, d, walks=True)
 
 
 def _sparse_saga_compacted(cols, vals, y, w, alpha, sub, batch_rate,
@@ -885,7 +895,7 @@ def make_sparse_saga_worker_step(batch_rate: float, d: int,
         )
         return g, diff_sel, idx, valid, c_sel, v_sel, key
 
-    return _sized_by_capacity(step, batch_rate, d)
+    return _sized_by_capacity(step, batch_rate, d, walks=False)
 
 
 def make_sparse_saga_commit():
@@ -1387,9 +1397,10 @@ def _padded_ell_account(shards, step, evaluate, batch_rate, d, live,
     STORED (capacity x ELL width) and the slots it gathers and
     scatter-adds (capacity x the width read: equal where nothing was left
     out), each the LARGEST over the workers' shards (shards of one shape:
-    every step's), and which program gathers the model (at every width
-    read, where they differ); what the shards hold of the device and of
-    the data, and how many step shapes they make.
+    every step's), and which program gathers the model and which adds the
+    products into ``g`` (at every width read, where they differ); what the
+    shards hold of the device and of the data, and how many step shapes
+    they make.
 
     The account is the ASGD step's and ASAGA's alike (``history``): both
     pack one sample (:func:`_sampled_rows`) and read it at the live width,
@@ -1435,6 +1446,9 @@ def _padded_ell_account(shards, step, evaluate, batch_rate, d, live,
                 c * lw for c, lw in zip(caps, widths)),
             "sparse_gather_path": "+".join(sorted({
                 step.gather_path(rows, lw) for lw in set(widths)})),
+            "sparse_scatter_path": "+".join(sorted({
+                step.scatter_path(s.size, lw)
+                for s, lw in zip(shards, widths)})),
             "sparse_width_min": min(widths),
             "sparse_width_max": max(widths),
             "sparse_step_shapes": len({(s.shape, s.device) for s in shards}),
@@ -1459,7 +1473,8 @@ def _padded_ell_account(shards, step, evaluate, batch_rate, d, live,
         # keys and products the scatter-add sorts (five f32-sized arrays
         # of capacity x width; compiled for a described v5e, a 21,875 x
         # 16,384 webspam shard's step holds 338 MB, PR 39).  Nothing at
-        # 11 to 39 slots a row (criteo: 116 MB a step); a third of a GB
+        # 11 to 39 slots a row (criteo: 116 MB a step; the pairs its sum
+        # by sorted segments sorts lie in VMEM, PR 52); a third of a GB
         # a step at thousands
         workspace_bytes=sum(20 * c * k for c, k in zip(caps, stored)),
     )

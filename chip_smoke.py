@@ -8,8 +8,9 @@ asyncframework_tpu.cluster`` for the multi-process path -- at the full width
 of the epsilon (400,000 x 2,000 f32) and rcv1 (697,641 x 47,236 padded-ELL)
 shapes, and checks what comes out: every requested update accepted, finite
 and at least halved objectives, the platform stamped ``tpu``, one gradient
-against float64, both Pallas kernels compiled natively against their
-references.  With more than one device it also asserts placement over all
+against float64, the Pallas kernels compiled natively against their
+references (E: the dense one-pass kernels and the attention block;
+E.segments: the sparse steps' sum by sorted segments at criteo's sample).  With more than one device it also asserts placement over all
 of them and runs one worker process per chip.
 
 Process model (a chip belongs to one process at a time): this parent never
@@ -72,6 +73,9 @@ SIZES = {
         # one shard of mnist8m-asaga.steady and its rate: the tile-list
         # kernel reads 72% of its 7,911 lane tiles
         kernel_tiles=(1_012_500, 784, 0.01),
+        # the packed sample of one criteo-logistic-asgd.steady shard: the
+        # (column, product) pairs the sorted-segment sum adds into g
+        kernel_segments=(145_472 * 39, 1_000_000),
         # the block ring_attention feeds chunk_attention for T = 8,192 over
         # four devices: (B, T/4, H, D)
         kernel_attn=(1, 2048, 8, 128),
@@ -87,6 +91,7 @@ SIZES = {
         mesh_iters=50,
         kernel_grad=(300, 48),
         kernel_tiles=(2_500, 48, 0.01),
+        kernel_segments=(300 * 8, 5_000),
         kernel_attn=(1, 64, 2, 16),
     ),
 }
@@ -102,6 +107,14 @@ GRAD_TOL = 1e-3
 #: whatever the shard's dtype (v5e, PR 26: 4.1e-7 on this shard in f32,
 #: 7.1e-7 on 1.0M x 784 in bf16; PR 25 read 7.8e-7 for f32 sums of 1M terms)
 KERNEL_GRAD_TOL = 5e-6
+#: segment_tiles_sum (the sparse steps' sum of (column, product) pairs by
+#: sorted segments) against the float64 sum on the host, relative to max
+#: |g|: every product float32 (three exact bf16 parts on the MXU), f32 sums
+#: by group of 1,024 slots (v5e, PR 52: 3.4e-7 on a criteo shard's packed
+#: sample, 427,277 of its 5,673,408 slots on the hottest column, and 6.4e-7
+#: on a whole shard's 55,868,280; the scatter-add it replaces, one term
+#: after the other, 6.2e-6 and 5.0e-5)
+KERNEL_SEGMENT_TOL = 2e-6
 #: chunk_attention against reference_attention at precision "highest",
 #: absolute on O(1) outputs (one run on the v5e: 8.8e-3 causal; XLA's own
 #: default-precision reference sat 1.1e-2 from "highest")
@@ -346,6 +359,45 @@ def phase_kernels(shard, w, size: dict, interpret: bool) -> dict:
     return rec
 
 
+def phase_segments(size: dict, interpret: bool) -> dict:
+    """E.segments: ``pallas_kernels.segment_tiles_sum`` compiled by Mosaic
+    (interpreted in the CPU dry run) at the criteo step's sample, Zipf(1)
+    columns (the hottest takes 7.6% of the slots) with every 97th pair out
+    of range, against the float64 sum on the host."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from asyncframework_tpu.ops import pallas_kernels as pk
+
+    n, d = size["kernel_segments"]
+    ku, kp = jax.random.split(jax.random.PRNGKey(8))
+    # rank = round((2d + 1)^u / 2), the generator's closed form
+    u = jax.random.uniform(ku, (n,), jnp.float32)
+    cols = jnp.clip(jnp.round(jnp.exp(u * np.log(2.0 * d + 1.0)) / 2.0)
+                    .astype(jnp.int32) - 1, 0, d - 1)
+    cols = jnp.where(jnp.arange(n) % 97 == 0, d + 5, cols)  # dropped
+    products = jax.random.normal(kp, (n,), jnp.float32)
+    kept = np.asarray(cols) < d
+    want = np.bincount(np.asarray(cols)[kept],
+                       weights=np.asarray(products, np.float64)[kept],
+                       minlength=d)
+    t0 = time.monotonic()
+    g = np.asarray(jax.jit(functools.partial(
+        pk.segment_tiles_sum, d=d, interpret=interpret))(cols, products))
+    rec = {"interpret": interpret, "slots": n, "d": d,
+           "hottest_column_slots": int(np.bincount(
+               np.asarray(cols)[kept]).max()),
+           "rel_err": float(np.max(np.abs(g - want)) / np.max(np.abs(want))),
+           "dropped_kept_out": bool(np.all(g[want == 0] == 0)),
+           "tolerance": KERNEL_SEGMENT_TOL,
+           "seconds": round(time.monotonic() - t0, 2)}
+    log(f"phase E.segments: {json.dumps(rec)}")
+    require(rec["rel_err"] <= KERNEL_SEGMENT_TOL and rec["dropped_kept_out"],
+            f"E.segments: segment_tiles_sum off the float64 sum: {rec}")
+    return rec
+
+
 def phase_engine_all_devices(size: dict, platform: str) -> dict:
     """F (engine path): phase A's recipe with every device, through the
     library so that placement can be asserted: shards on all devices,
@@ -423,6 +475,7 @@ def main_phases(dry_run: bool) -> int:
     phases["D"] = phase_gradient(shard, w)
     phases["E"] = phase_kernels(shard, w, size, interpret=dry_run)
     del shard, w
+    phases["E.segments"] = phase_segments(size, interpret=dry_run)
     if len(devs) > 1:
         phases["F.engine"] = phase_engine_all_devices(size, platform)
         mesh = dict(dense, iters=size["mesh_iters"])

@@ -45,13 +45,16 @@ class TestSmoke:
         assert out["device"] == {"platform": "cpu", "kind": "cpu",
                                  "count": 8}
         phases = out["phases"]
-        assert set(phases) == {"A", "B", "C", "D", "E", "F.engine",
-                               "F.mesh", "F.cluster"}
+        assert set(phases) == {"A", "B", "C", "D", "E", "E.segments",
+                               "F.engine", "F.mesh", "F.cluster"}
         for name in ("A", "B", "C", "F.engine", "F.mesh", "F.cluster"):
             p = phases[name]
             assert p["accepted"] == p["requested"], (name, p)
             assert p["last_objective"] <= 0.5 * p["first_objective"]
         assert phases["E"]["interpret"] is True
+        seg = phases["E.segments"]
+        assert seg["interpret"] is True
+        assert seg["rel_err"] <= seg["tolerance"]
         assert phases["F.engine"]["shard_devices"] == list(range(8))
         # every role record names the device its launcher assigned
         workers = phases["F.cluster"]["workers"]

@@ -192,6 +192,45 @@ def test_the_chooser_answers_from_backend_and_shapes(
     assert got == want
 
 
+@pytest.mark.parametrize(
+    "on_tpu,d,slots,walk,dtype,want",
+    [
+        # criteo under ASGD: 145,472 packed rows x 39 live slots, 23,000 a
+        # tile of 4,096 of its 1,000,000 columns; under ASAGA 29,656 rows
+        (True, 1_000_000, 145_472 * 39, None, jnp.float32, "segments"),
+        (True, 1_000_000, 29_656 * 39, None, jnp.float32, "segments"),
+        (False, 1_000_000, 145_472 * 39, None, jnp.float32, "scatter"),
+        (False, 1_000_000, 29_656 * 39, None, jnp.float32, "scatter"),
+        # the end-of-run check: every slot of an ASAGA shard
+        (True, 1_000_000, 1_432_520 * 39, None, jnp.float32, "segments"),
+        # kdd2012: 236,640 x 11 slots over 54,686,452 columns, 195 a tile
+        (True, 54_686_452, 236_640 * 11, None, jnp.float32, "scatter"),
+        (False, 54_686_452, 236_640 * 11, None, jnp.float32, "scatter"),
+        # webspam: the sample is walked in blocks, whatever it holds
+        (True, 16_609_143, 992 * 16_384, (64, 256), jnp.float32, "scatter"),
+        (True, 16_609_143, 992 * 1_664, (128, 512), jnp.float32, "scatter"),
+        (False, 16_609_143, 992 * 16_384, (64, 256), jnp.float32, "scatter"),
+        # the constant to the slot: 245 tiles of 1,024 slots
+        (True, 1_000_000, 245 * 1_024, None, jnp.float32, "segments"),
+        (True, 1_000_000, 245 * 1_024 - 1, None, jnp.float32, "scatter"),
+        (True, 1_000_000, 145_472 * 39, None, jnp.bfloat16, "scatter"),
+        (True, 1_000_000, 145_472 * 39, None, jnp.float64, "scatter"),
+    ],
+    ids=["tpu-criteo-asgd", "tpu-criteo-asaga", "cpu-criteo-asgd",
+         "cpu-criteo-asaga", "tpu-criteo-check", "tpu-kdd2012",
+         "cpu-kdd2012", "tpu-webspam-widest", "tpu-webspam-narrowest",
+         "cpu-webspam", "tpu-one-constant-a-tile", "tpu-a-slot-under",
+         "tpu-bf16", "tpu-f64"],
+)
+def test_the_scatter_chooser_answers_from_backend_and_shapes(
+        monkeypatch, on_tpu, d, slots, walk, dtype, want):
+    """``gradients.sparse_scatter_path`` at the four sparse cells' shapes
+    (ISSUE 52): one number every caller holds, the mean run of slots a
+    tile of ``g``, against ONE constant."""
+    monkeypatch.setattr(gradients, "_on_tpu", lambda: on_tpu)
+    assert gradients.sparse_scatter_path(d, slots, walk, dtype) == want
+
+
 def test_a_width_with_no_eight_row_view_keeps_todays_expression():
     """``d`` 47,236 through the helper: the element-wise gather's margins,
     to the bit, on whichever backend."""
@@ -202,11 +241,28 @@ def test_a_width_with_no_eight_row_view_keeps_todays_expression():
         _bits(m), _bits(jnp.sum(v * w[c], axis=1)))
 
 
-def test_where_the_chooser_says_rows8_the_helper_runs_it(monkeypatch):
+@pytest.fixture()
+def segments_interpreted(monkeypatch):
+    """A step traced as on a TPU adds its products by sorted segments
+    where ``gradients.sparse_scatter_path`` says so (ISSUE 52): a Pallas
+    kernel, which the CPU runs interpreted.  The test hands the kernel
+    that argument; the step's program is the TPU's otherwise."""
+    import functools
+
+    from asyncframework_tpu.ops import pallas_kernels
+
+    monkeypatch.setattr(
+        pallas_kernels, "segment_tiles_sum", functools.partial(
+            pallas_kernels.segment_tiles_sum, interpret=True))
+
+
+def test_where_the_chooser_says_rows8_the_helper_runs_it(
+        monkeypatch, segments_interpreted):
     """The CPU can run the blocked form (it is plain ``jax.numpy``): with
     the chooser steered, the helper's jaxpr holds the blocked loop, and
     the step's gradient is the element-wise step's within the order of a
-    margin's sum."""
+    margin's sum (and of a column's: 7,000 slots over one tile of 4,096
+    columns are summed by sorted segments)."""
     _small_blocks(monkeypatch, 8)
     d, n = 4_096, 1_500
     rs = np.random.default_rng(4)
@@ -226,7 +282,8 @@ def test_where_the_chooser_says_rows8_the_helper_runs_it(monkeypatch):
         1e-6 * np.max(np.abs(np.asarray(g1))))
 
 
-def test_where_the_chooser_says_lanes128_the_helper_runs_it(monkeypatch):
+def test_where_the_chooser_says_lanes128_the_helper_runs_it(
+        monkeypatch, segments_interpreted):
     """A model over ``SPARSE_VMEM_BYTES`` (patched down to this test's
     size) on a TPU: the helper's jaxpr holds the lane-row loop, and the
     step's gradient is the element-wise step's within the order of a

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from asyncframework_tpu.ops import gradients, steps
+from asyncframework_tpu.ops import gradients, pallas_kernels, steps
 
 ROWS, K, D = 96, 8, 40
 
@@ -51,9 +51,89 @@ def test_grad_sum_is_the_dense_product_in_any_slot_order(case):
     assert np.max(np.abs(g - want)) < 1e-5 * np.max(np.abs(want))
 
 
+def _segment_case(case: str):
+    """``(cols, vals, dense X, d)``: the four blocks of :func:`_ell`, and
+    three shaped for the sorted-segment kernel at tiles of 1,024 columns
+    and groups of 1,024 slots (ISSUE 52)."""
+    if case in ("random", "colliding", "padding", "out_of_range"):
+        return _ell(case) + (D,)
+    rs = np.random.default_rng(52)
+    rows, k = 512, 8
+    if case == "long_column":  # one column's run spans more than two groups
+        d = 3_000
+        cols = rs.integers(0, d, (rows, k)).astype(np.int32)
+        cols[:, :5] = 1_500  # 2,560 of the 4,096 slots
+    elif case == "empty_tiles":  # tiles 1, 2 and 4 of five hold no slot
+        d = 5 * 1_024
+        cols = np.where(rs.random((rows, k)) < 0.5,
+                        rs.integers(0, 1_024, (rows, k)),
+                        rs.integers(3 * 1_024, 4 * 1_024, (rows, k))
+                        ).astype(np.int32)
+    elif case == "ragged_width":  # d % T != 0; a negative id counts back
+        d = 2_500
+        cols = rs.integers(0, d, (rows, k)).astype(np.int32)
+        cols[::7, 0] -= d
+        cols[3::7, 1] = -d - 1  # still outside: dropped
+    vals = rs.standard_normal((rows, k)).astype(np.float32)
+    X = np.zeros((rows, d), np.float64)
+    at = np.where(cols < 0, cols + d, cols)
+    kept = (at >= 0) & (at < d)
+    np.add.at(X, (np.nonzero(kept)[0], at[kept]), vals[kept])
+    return cols, vals, X, d
+
+
+@pytest.mark.parametrize(
+    "case", ["random", "colliding", "padding", "out_of_range",
+             "long_column", "empty_tiles", "ragged_width"])
+def test_segment_tiles_sum_is_the_dense_product(case):
+    """``pallas_kernels.segment_tiles_sum`` (interpreted: the CPU) against
+    ``X.T @ coeff`` in float64, and against the scatter-add it replaces on
+    a TPU: the same slots kept and dropped, every product float32."""
+    cols, vals, X, d = _segment_case(case)
+    coeff = np.random.default_rng(3).standard_normal(
+        cols.shape[0]).astype(np.float32)
+    products = (vals * coeff[:, None]).ravel()
+    g = np.asarray(pallas_kernels.segment_tiles_sum(
+        jnp.asarray(cols.ravel()), jnp.asarray(products), d,
+        tile=1_024, block_rows=8, interpret=True))
+    assert g.shape == (d,) and g.dtype == np.float32
+    want = X.T @ coeff.astype(np.float64)
+    assert np.max(np.abs(g - want)) < 2e-6 * np.max(np.abs(want))
+    scattered = np.asarray(jnp.zeros(d, jnp.float32).at[
+        jnp.asarray(cols.ravel())].add(jnp.asarray(products), mode="drop"))
+    assert np.max(np.abs(g - scattered)) < 1e-5 * np.max(np.abs(want))
+    np.testing.assert_array_equal(g == 0, scattered == 0)
+
+
+def test_a_product_is_its_three_bf16_parts_exactly():
+    """What makes the MXU's sum a float32 sum: each part is a bfloat16
+    value to the bit, and the three add up to the float32 product, over
+    twenty orders of magnitude and both signs."""
+    rs = np.random.default_rng(8)
+    p = (rs.standard_normal(4_096) * 10.0 ** rs.integers(-10, 10, 4_096)
+         ).astype(np.float32)
+    parts = [np.asarray(x) for x in
+             pallas_kernels._bf16_parts(jnp.asarray(p))]
+    for part in parts:
+        assert part.dtype == np.float32
+        np.testing.assert_array_equal(
+            np.asarray(jnp.asarray(part).astype(jnp.bfloat16).astype(
+                jnp.float32)), part)
+    total = sum(part.astype(np.float64) for part in parts)
+    np.testing.assert_array_equal(total, p.astype(np.float64))
+
+
+def test_segment_tiles_sum_refuses_a_tile_it_cannot_store():
+    with pytest.raises(ValueError):
+        pallas_kernels.segment_tiles_sum(
+            jnp.zeros(8, jnp.int32), jnp.zeros(8, jnp.float32), D, tile=256)
+
+
 def test_grad_sum_takes_the_slots_as_they_are_stored():
-    """ONE form in the tree (ISSUE 33): the lowered program holds a scatter
-    and no sort; nothing carries the slots into another order first."""
+    """The CPU's program (ISSUE 33; on a TPU ``sparse_scatter_path`` may
+    choose the sorted segments, ISSUE 52): the lowered program holds a
+    scatter and no sort; nothing carries the slots into another order
+    first."""
     text = gradients.make_sparse_grad_sum(D).lower(
         jnp.zeros((ROWS, K), jnp.int32), jnp.zeros((ROWS, K), jnp.float32),
         jnp.zeros(ROWS, jnp.float32)).as_text()

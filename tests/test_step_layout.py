@@ -474,26 +474,63 @@ def test_sparse_margins_gather_eight_model_values_an_index(
     assert temp < TEMP_BOUND, f"{temp} bytes of temporaries"
 
 
+def _segment_tiles(d):
+    from asyncframework_tpu.ops import pallas_kernels
+
+    return -(-d // pallas_kernels.SEGMENT_TILE)
+
+
+def _sums_by_sorted_segments(text, slots, d):
+    """The program adds ``slots`` products into a ``(d,)`` gradient by
+    sorted segments (ISSUE 52): ONE sort of two operands, the (column,
+    product) pairs padded to whole blocks of the kernel's DMA; the custom
+    call of ``pallas_kernels.segment_tiles_sum``, which takes the tiles'
+    bounds and the sorted pairs as rows of 128; and no scatter."""
+    from asyncframework_tpu.ops import pallas_kernels
+
+    block = 128 * pallas_kernels._SEGMENT_BLOCK_ROWS
+    padded = -(-slots // block) * block
+    pairs = [t for _n, t, op, _ in _instructions(text)
+             if op == "sort" and t.startswith("(")]
+    assert len(pairs) == 1, pairs
+    assert f"s32[{padded}]" in pairs[0] and f"f32[{padded}]" in pairs[0]
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 1 and "segment_tiles_sum" in calls[0], calls
+    for operand in (f"s32[{_segment_tiles(d) + 1}]",
+                    f"s32[{padded // 128},128]", f"f32[{padded // 128},128]"):
+        assert operand in calls[0], (operand, calls[0][:400])
+    assert " scatter(" not in text
+
+
 @pytest.mark.parametrize("live_width", [None, 39],
                          ids=["stored-40", "live-39"])
 def test_sparse_step_moves_no_slot_to_put_it_in_order(
     one_chip, no_compile_cache, on_tpu, live_width
 ):
     """The sparse ASGD step at the criteo cell's shard (``b`` 0.05, the
-    logistic link; ISSUE 33).  FORM A was kept: the scatter-add takes the
-    5,818,880 sampled slots in the order they are stored, so the program
-    holds no sort over them and no gather whose result is a permutation of
-    the columns or of the products (the parent carried both into sorted
-    order: 124 of its 251 ms on the chip).  The one sort left packs the
-    sampled row ids: ONE operand, the 2,865,039 row keys, where
-    ``jnp.nonzero`` scattered as many ones.  Since ISSUE 36 the model is
-    gathered eight values an index (``gradients.sparse_margins``): no
-    single-element gather of ``w`` is left in the step.  Since ISSUE 38
-    the cell's step is built at the shard's live width, 39 of the 40
-    stored slots: the same program over 5,673,408 slots in blocks of 8,320
-    rows (whole tiles of 128), the ``(8, d / 8)`` table still in VMEM, and
-    of the shard's height nothing but a ``bitcast`` of its first 39
-    columns; ``stored-40`` is what ``live_width=None`` keeps building."""
+    logistic link; ISSUE 33).  Nothing carries the sampled slots into
+    another order for a SCATTER's sake: the program holds no gather whose
+    result is a permutation of the columns or of the products (the parent
+    of ISSUE 33 carried both into sorted order in front of its scatter:
+    124 of its 251 ms on the chip).  One sort packs the sampled row ids:
+    ONE operand, the 2,865,039 row keys, where ``jnp.nonzero`` scattered
+    as many ones.  Since ISSUE 36 the model is gathered eight values an
+    index (``gradients.sparse_margins``): no single-element gather of
+    ``w`` is left in the step.  Since ISSUE 38 the cell's step is built at
+    the shard's live width, 39 of the 40 stored slots: the same program
+    over 5,673,408 slots in blocks of 8,320 rows (whole tiles of 128), the
+    ``(8, d / 8)`` table still in VMEM, and of the shard's height nothing
+    but a ``bitcast`` of its first 39 columns; ``stored-40`` is what
+    ``live_width=None`` keeps building.
+
+    Since ISSUE 52 the products are added into ``g`` by SORTED SEGMENTS
+    (``gradients.sparse_scatter_path``: 23,000 slots a tile of 4,096
+    columns): ONE two-operand sort of the (column, product) pairs, padded
+    to whole blocks of the kernel's DMA, the tiles' bounds by a
+    ``searchsorted`` (its one gather: 246 bounds an iteration), the
+    kernel's custom call, and NO scatter at all; the pairs and their
+    sorted copies lie in VMEM."""
     (cols, vals, y), spec = _ell_specs(one_chip)
     batch_rate = 0.05
     step = steps.make_sparse_asgd_worker_step(
@@ -509,9 +546,12 @@ def test_sparse_step_moves_no_slot_to_put_it_in_order(
     instrs = _instructions(text)
     assert not _makes_a_whole_shard(text), _makes_a_whole_shard(text)
 
+    assert step.scatter_path(ELL_ROWS, width) == "segments"
     sorts = [t for _n, t, op, _ in instrs if op == "sort"]
     # a single operand: the result is one array of row keys, not a tuple
-    assert len(sorts) == 1 and sorts[0].startswith(f"s32[{ELL_ROWS}]"), sorts
+    keys = [t for t in sorts if not t.startswith("(")]
+    assert len(keys) == 1 and keys[0].startswith(f"s32[{ELL_ROWS}]"), sorts
+    _sums_by_sorted_segments(text, slots, ELL_D)
 
     # the gathers are the mathematics' own: the sampled rows of cols and
     # vals, their labels, and a block of the model's eight-row table; none
@@ -523,13 +563,11 @@ def test_sparse_step_moves_no_slot_to_put_it_in_order(
                      if op == "gather")
     assert gathers == sorted([
         f"s32[{cap},{width}]", f"f32[{cap},{width}]",
-        f"f32[8,{width},{rows}]", f"f32[{cap}]"]), gathers
+        f"f32[8,{width},{rows}]", f"f32[{cap}]",
+        f"s32[{_segment_tiles(ELL_D) + 1}]"]), gathers
     assert _model_gathers(text) == [
         (f"f32[8,{width},{rows}]", "8,1")], _model_gathers(text)
     _table_stays_in_vmem(text)
-    scatters = [ln for ln in text.splitlines() if " scatter(" in ln]
-    assert len(scatters) == 1 and f" f32[{ELL_D}]" in scatters[0], scatters
-    assert "indices_are_sorted=true" not in scatters[0]
     if live_width:  # the first 39 ELL columns, where they lie
         views = [t for _n, t, op, _ in _instructions(text[text.index("ENTRY"):])
                  if op == "bitcast" and f"[{ELL_ROWS},{width}]" in t]
@@ -539,6 +577,54 @@ def test_sparse_step_moves_no_slot_to_put_it_in_order(
     # until PR 36) and one block of the model's gathered rows
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < 160e6 + TEMP_BOUND, f"{temp} bytes of temporaries"
+
+
+SAGA_ROWS = 1_432_520  # criteo-asaga's shard: 11,460,160 rows, 8 workers
+
+
+@pytest.mark.parametrize("program", ["step", "delta"])
+def test_sparse_saga_programs_sum_by_sorted_segments(
+    one_chip, no_compile_cache, on_tpu, program
+):
+    """criteo under ASAGA (``b`` 0.02 of 1,432,520 rows, 39 live slots of
+    40): the step's history-corrected gradient and the accept path's exact
+    table delta add the SAME 1,156,584 slots into ``g``, 4,700 a tile of
+    4,096 columns, and both do it by sorted segments (ISSUE 52): one
+    two-operand sort, the kernel's custom call, no scatter into
+    ``f32[1000000]`` (the commit's ``alpha.at[idx].set`` is another
+    program's).  The modules keep the names the benchmark's readers find
+    their device time by."""
+    def spec(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    live, batch_rate = 39, 0.02
+    cap = steps.sparse_step_capacity(batch_rate, SAGA_ROWS)
+    slots = cap * live
+    assert (cap, slots) == (29_656, 1_156_584)
+    f32 = jnp.float32
+    if program == "step":
+        step = steps.make_sparse_saga_worker_step(
+            batch_rate, ELL_D, live_width=live)
+        assert step.scatter_path(SAGA_ROWS, live) == "segments"
+        compiled = step.lower(
+            spec((SAGA_ROWS, ELL_WIDTH), jnp.int32),
+            spec((SAGA_ROWS, ELL_WIDTH), f32), spec((SAGA_ROWS,), f32),
+            spec((ELL_D,), f32), spec((SAGA_ROWS,), f32),
+            spec((2,), jnp.uint32)).compile()
+        name = "jit_step"
+    else:
+        compiled = steps.make_sparse_table_delta(ELL_D).lower(
+            spec((cap, live), jnp.int32), spec((cap, live), f32),
+            spec((cap,), f32), spec((SAGA_ROWS,), f32),
+            spec((cap,), jnp.int32)).compile()
+        name = "jit_sparse_saga_table_delta"
+    text = compiled.as_text()
+    assert text.startswith(f"HloModule {name},"), text[:80]
+    _sums_by_sorted_segments(text, slots, ELL_D)
+    tall = [(n, op) for n, t, op, _ in _instructions(text)
+            if re.search(r"\[%d,\d+\]" % SAGA_ROWS, t)
+            and op not in ("parameter", "get-tuple-element", "bitcast")]
+    assert not tall, tall
 
 
 # ----------------------------------------- the wide deployment (ISSUE 37)

@@ -152,7 +152,8 @@ PARENT = {
         extras={
             "sparse_step_capacity": 56, "sampled_slots_per_step": 2240,
             "sparse_live_width": 39, "live_slots_per_step": 2184,
-            "sparse_gather_path": "elements", "sparse_width_min": 39,
+            "sparse_gather_path": "elements",
+            "sparse_scatter_path": "scatter", "sparse_width_min": 39,
             "sparse_width_max": 39, "sparse_step_shapes": 2,
             "sparse_stored_slots": 40120, "sparse_nonzero_slots": 39117,
             "walked_slots_share_max": 0.975},
@@ -162,7 +163,8 @@ PARENT = {
         extras={
             "sparse_step_capacity": 56, "sampled_slots_per_step": 896,
             "sparse_live_width": 11, "live_slots_per_step": 616,
-            "sparse_gather_path": "elements", "sparse_width_min": 11,
+            "sparse_gather_path": "elements",
+            "sparse_scatter_path": "scatter", "sparse_width_min": 11,
             "sparse_width_max": 11, "sparse_step_shapes": 2,
             "sparse_stored_slots": 16048, "sparse_nonzero_slots": 11033,
             "walked_slots_share_max": 0.6875},
@@ -171,7 +173,8 @@ PARENT = {
         extras={
             "sparse_step_capacity": 168, "sampled_slots_per_step": 64512,
             "sparse_live_width": 384, "live_slots_per_step": 64512,
-            "sparse_gather_path": "elements", "sparse_width_min": 128,
+            "sparse_gather_path": "elements",
+            "sparse_scatter_path": "scatter", "sparse_width_min": 128,
             "sparse_width_max": 384, "sparse_step_shapes": 3,
             "sparse_stored_slots": 1572864, "sparse_nonzero_slots": 1105920,
             "walked_slots_share_max": 0.7619047619047619},
@@ -183,6 +186,8 @@ PARENT = {
 BUILD = {"dense-784": _dense, "ell-39-of-40": lambda: _ell(39),
          "ell-11-of-16": lambda: _ell(11), "ragged-3-shapes": _ragged}
 #: the parent's ASAGA reported two of a padded-ELL dataset's eleven keys
+#: (twelve since PR 52: ``sparse_scatter_path``, which program adds the
+#: products into ``g``, ``"scatter"`` on the CPU)
 PARENT_ASAGA_KEYS = {"sparse_live_width", "sparse_gather_path"}
 
 
@@ -212,8 +217,8 @@ def test_asaga_reports_what_asgd_reports_of_a_one_shape_dataset(name):
     ds = BUILD[name]()
     programs = ASAGA(ds, None, _cfg(ds), devices=DEV)._programs
     want = dict(PARENT[name]["extras"])
-    if "sparse_live_width" in want:  # eleven keys where it said two
-        assert PARENT_ASAGA_KEYS < set(want) and len(want) == 11
+    if "sparse_live_width" in want:  # every key where it said two
+        assert PARENT_ASAGA_KEYS < set(want) and len(want) == 12
         assert programs.compacted and programs.commit is not None
         # and, since PR 46, what a result carries beside ``g``: diff_sel,
         # idx and valid a packed row, a column and a value a live slot
