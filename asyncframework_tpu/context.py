@@ -116,10 +116,14 @@ class AsyncContext(Generic[T]):
     / ``hasNext``, ``setLastTime`` / ``isOld``.
     """
 
-    def __init__(self) -> None:
+    def __init__(self, lock=None) -> None:
+        """``lock``: the re-entrant lock that guards the clock and the STAT
+        table, for a caller that wants its own around an ``RLock`` (the
+        engine's run clocks the waits at it:
+        ``instrumentation.ClockedLock``); taken by ``with`` alone."""
         self._results: "queue.Queue[PartialResult[T]]" = queue.Queue()
         self._stat: Dict[int, WorkerState] = {}
-        self._lock = threading.RLock()
+        self._lock = lock if lock is not None else threading.RLock()
         self._clock = 0
         self._last_time = -(2**31)
         self._record_stat = False

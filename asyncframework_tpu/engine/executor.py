@@ -29,6 +29,7 @@ import threading
 from typing import Any, Callable, Dict, List, Optional
 
 from asyncframework_tpu.engine.job import TaskSpec
+from asyncframework_tpu.metrics.trace import EXECUTOR, set_role
 from asyncframework_tpu.utils.clock import Clock, SystemClock
 
 
@@ -106,6 +107,9 @@ class DeviceExecutor:
 
     # ------------------------------------------------------------ main loop
     def _run(self) -> None:
+        # this thread's role, said once: a task closure and a result
+        # handler that wait at a clocked lock wait as ``executor``
+        set_role(EXECUTOR)
         while True:
             try:
                 task = self._inbox.get(timeout=0.1)
@@ -172,7 +176,12 @@ class ExecutorPool:
         status_update,
         devices: Optional[List] = None,
         clock: Optional[Clock] = None,
+        lock=None,
     ):
+        """``lock``: what guards the executor tables, for a caller that
+        wants its own in a bare lock's place (the engine's run clocks the
+        waits at it, ``instrumentation.ClockedLock``: every launch and
+        every status update takes it); taken by ``with`` alone."""
         self.closed = False
         self._clock = clock or SystemClock()
         self._status_update = status_update
@@ -181,7 +190,7 @@ class ExecutorPool:
         else:
             device_of = lambda wid: None  # noqa: E731
         self._device_of = device_of
-        self._lock = threading.Lock()
+        self._lock = lock if lock is not None else threading.Lock()
         self.executors: Dict[int, DeviceExecutor] = {
             wid: DeviceExecutor(wid, status_update, device_of(wid), self._clock)
             for wid in range(num_workers)

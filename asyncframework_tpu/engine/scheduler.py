@@ -46,7 +46,10 @@ class JobScheduler:
         clock: Optional[Clock] = None,
         pool: Optional[ExecutorPool] = None,
         blacklist: Optional[BlacklistTracker] = None,
+        pool_lock=None,
     ):
+        """``pool_lock``: the lock of the pool this scheduler builds (see
+        ``ExecutorPool``: for a caller that clocks the waits at it)."""
         self.num_workers = num_workers
         self.max_task_failures = max_task_failures
         self._clock = clock or SystemClock()
@@ -67,7 +70,8 @@ class JobScheduler:
         self.blocked_ns = 0
         self.blacklist = blacklist
         self.pool = pool or ExecutorPool(
-            num_workers, self._status_update, devices=devices, clock=self._clock
+            num_workers, self._status_update, devices=devices,
+            clock=self._clock, lock=pool_lock,
         )
 
     @property

@@ -116,6 +116,8 @@ class TraceSpan(Event):
     batch: Optional[int] = None  # updates the timed piece of work served
     calls: Optional[int] = None  # trajectory.eval: stacks evaluated
     delay_class: Optional[str] = None  # task.delay: normal | long_tail
+    calls_in: Optional[int] = None  # task.enqueue: PJRT calls in progress
+    cpu_ms: Optional[float] = None  # task.enqueue: the thread's CPU time
 
 
 EVENT_TYPES: Dict[str, Type[Event]] = {

@@ -36,9 +36,11 @@ black hole.  This module makes one update's life observable end to end:
   to a phase of the program.  *Wait* stages are a thread blocked on a
   queue or on the device: recorded as spans, never annotated (32
   executors blocked in ``block_until_ready`` would own every gap).  The
-  two *holds* are the exception: waits of ONE thread, the submitter, with
-  a cause the program knows, so they are annotated and a gap in which the
-  recipe held workers back carries the recipe's name.  So is
+  *holds* are the exception: waits of ONE thread with a cause the program
+  knows, so they are annotated: the submitter's two, in which the recipe
+  held workers back, and a contended wait of the submitter or the updater
+  at one of the engine's locks (``lock.*``), so that a gap carries the
+  recipe's name, or the lock's.  So is
   ``task.delay``, an injected straggler's sleep: a wait with a cause, on
   the few threads of the late workers, and a gap a sleeper leaves carries
   its name.
@@ -57,6 +59,13 @@ black hole.  This module makes one update's life observable end to end:
                                   available (``wait.workers``) nothing
                                   is annotated; each of the three has
                                   its sum in ``TrainResult.extras``
+  lock.state,      hold submitter a CONTENDED enter of one of the     (annotation
+  lock.key,             or        engine's clocked locks              only)
+  lock.context,         updater   (``instrumentation.ClockedLock``):
+  lock.history,                   the non-blocking try failed -> the
+  lock.pool                       lock is taken.  An executor's wait
+                                  is never annotated; every thread's is
+                                  in ``extras`` (``lock_wait_*``)
   submit           work submitter cohort chosen -> ``run_job`` returned -
   compute          -    -         submit -> drained by the updater     submit
   task.inbox       wait sub -> ex ``compute``'s start -> ``fn()`` in   compute
@@ -84,7 +93,12 @@ black hole.  This module makes one update's life observable end to end:
   task.enqueue     work executor  the jitted step's call alone, in ->  task.dispatch
                                   returned (no annotation: PJRT's
                                   ``PjitFunction(step)`` is the same
-                                  interval in a device trace)
+                                  interval in a device trace);
+                                  ``calls_in``: the engine's PJRT calls
+                                  in progress when it was made
+                                  (``instrumentation.CallsIn``),
+                                  ``cpu_ms``: this thread's CPU time
+                                  inside it
   task.device_wait wait executor  ``block_until_ready`` in -> out;     compute
                                   over several chips ASGD's task first
                                   sends ``g`` to every chip, in here
@@ -117,8 +131,13 @@ black hole.  This module makes one update's life observable end to end:
   ``task.inbox + task.dispatch + task.device_wait + result.queue``, and
   ``task.delay`` between the first two where a straggler sleeps, cover
   ``compute`` from its first instant; what is left (the scheduler's
-  status update, the handler, the key lock, GIL hand-offs) is
-  ``compute``'s self time.  Only the first copy of a task
+  status update, the handler, GIL hand-offs) is ``compute``'s self time.
+  The key lock and the context's lock lie in it too, but no longer
+  unmeasured: what a task's thread stood at them is ``extras``'
+  ``lock_wait_executor_s``, over every update (wall less ``cpu_ms`` of a
+  ``task.enqueue`` is the time its thread was off the processor: the
+  interpreter lock, a lock of PJRT's, a full device queue; none of the
+  program's locks lies inside it).  Only the first copy of a task
   to run records the task stages: a retry or a speculative copy finds
   ``task.inbox`` closed and records nothing.  ``task.dispatch`` is
   ``task.turn + task.model_copy`` (one a copy) ``+ task.enqueue`` and its
@@ -161,6 +180,7 @@ import json
 import threading
 import time
 import uuid
+import weakref
 from collections import OrderedDict, defaultdict, deque
 from dataclasses import dataclass, fields
 from typing import Callable, Dict, List, Optional, Sequence
@@ -214,6 +234,10 @@ WORKER_IDLE = "worker.idle"
 HOLD_BARRIER = "hold.barrier"
 HOLD_BACKLOG = "hold.backlog"
 WAIT_WORKERS = "wait.workers"
+#: a contended wait of the submitter or the updater at one of the engine's
+#: clocked locks (``instrumentation.ClockedLock``), by the lock's name
+LOCK_NAMES = ("state", "key", "context", "history", "pool")
+LOCK_STAGES = {name: "lock." + name for name in LOCK_NAMES}
 
 STAGES = (PULL_WAIT, PULL_RTT, PIPELINE, WORKER_IDLE, SUBMIT, COMPUTE,
           TASK_INBOX, TASK_WAKE, TASK_DELAY,
@@ -227,9 +251,10 @@ STAGES = (PULL_WAIT, PULL_RTT, PIPELINE, WORKER_IDLE, SUBMIT, COMPUTE,
 WORK_STAGES = frozenset((SUBMIT, TASK_DISPATCH, TASK_MODEL_COPY, MERGE_QUEUE,
                          MERGE_APPLY, MERGE_HISTORY, SNAPSHOT, CHECKPOINT,
                          TRAJECTORY_EVAL, HISTORY_CHECK))
-#: the submitter's two waits with a cause: annotated like work (one thread,
-#: so they cannot crowd a gap as 32 blocked executors would)
-HOLD_STAGES = frozenset((HOLD_BARRIER, HOLD_BACKLOG))
+#: the waits of ONE thread with a cause the program knows: the submitter's
+#: two, and a serial thread's stand at a clocked lock.  Annotated like work
+#: (one thread, so they cannot crowd a gap as 32 blocked executors would)
+HOLD_STAGES = frozenset((HOLD_BARRIER, HOLD_BACKLOG, *LOCK_STAGES.values()))
 #: the four children that must cover ``compute`` (with ``task.delay``,
 #: where a task has one)
 COMPUTE_CHILDREN = (TASK_INBOX, TASK_DISPATCH, TASK_DEVICE_WAIT,
@@ -245,8 +270,8 @@ PARENT = {COMPUTE: SUBMIT, MERGE_QUEUE: COMPUTE, MERGE_APPLY: COMPUTE,
           TASK_ENQUEUE: TASK_DISPATCH, TASK_DEVICE_WAIT_ALONE: COMPUTE,
           TASK_DELAY: COMPUTE,
           **{st: COMPUTE for st in COMPUTE_CHILDREN}}
-#: what a work stage (a hold, an injected delay) is called in a profiler
-#: trace
+#: what a work stage (a hold, an injected delay, a lock's wait) is called in
+#: a profiler trace
 ANNOTATION_PREFIX = "async."
 _ANNOTATION_NAME = {st: ANNOTATION_PREFIX + st
                     for st in WORK_STAGES | HOLD_STAGES | {TASK_DELAY}}
@@ -308,13 +333,21 @@ class Span:
     #: a ``task.delay``: which of the straggler model's two multiplier
     #: classes the sleeper is of, ``normal`` or ``long_tail``
     delay_class: Optional[str] = None
+    #: a ``task.enqueue``: the engine's calls into PJRT that were in
+    #: progress, on any thread, when this one was made
+    #: (``instrumentation.CallsIn``), and the CPU time of the calling thread
+    #: inside the call (``time.thread_time_ns``): wall less CPU is what the
+    #: thread spent off the processor
+    calls_in: Optional[int] = None
+    cpu_ms: Optional[float] = None
 
     # wire format: short keys, Nones omitted -- spans ride PUSH headers
     _WIRE = (("s", "stage"), ("t", "trace_id"), ("i", "span_id"),
              ("p", "parent_id"), ("w", "worker_id"), ("v", "model_version"),
              ("b", "start_ms"), ("d", "dur_ms"), ("st", "staleness"),
              ("sm", "staleness_ms"), ("ac", "accepted"), ("by", "bytes"),
-             ("n", "batch"), ("c", "calls"), ("dc", "delay_class"))
+             ("n", "batch"), ("c", "calls"), ("dc", "delay_class"),
+             ("ci", "calls_in"), ("cp", "cpu_ms"))
 
     def to_wire(self) -> dict:
         out = {}
@@ -391,6 +424,55 @@ def set_current(ctx: Optional[TraceContext]) -> None:
 
 def current() -> Optional[TraceContext]:
     return getattr(_tls, "ctx", None)
+
+
+# A thread's role in the in-process engine: who waits for whom at a lock
+# (``instrumentation.ClockedLock`` books a wait to the pair of the waiter's
+# role and the holder's).  Said ONCE where a thread starts its part
+# (``EngineRun.drive``, the updater's entry, ``DeviceExecutor._run``); a
+# thread that never said is ``main`` (the caller's thread outside the
+# submitter loop, the monitors).  A thread reads its own from a
+# thread-local; a waiter asks for the HOLDER's by the holder's thread id
+# (:func:`role_of`), which the lock itself knows.  Neither is a lookup of a
+# thread's name, and neither happens where a lock is free.
+SUBMITTER, UPDATER, EXECUTOR, MAIN = "submitter", "updater", "executor", "main"
+ROLES = (SUBMITTER, UPDATER, EXECUTOR, MAIN)
+#: the "holder" of a wait whose lock was free again before it could be asked
+NOBODY = "nobody"
+
+
+class _Role(threading.local):
+    name = MAIN
+
+
+#: the calling thread's role is ``thread_role.name``
+thread_role = _Role()
+#: thread id -> (the role that thread said last, the thread): an id is
+#: handed to a new thread once its thread has ended, so a role counts only
+#: while the thread that said it lives
+_role_by_ident: Dict[int, tuple] = {}
+
+
+def set_role(role: str) -> None:
+    thread_role.name = role
+    _role_by_ident[threading.get_ident()] = (
+        role, weakref.ref(threading.current_thread()))
+
+
+def role() -> str:
+    return thread_role.name
+
+
+def role_of(ident: int) -> str:
+    """The role of the live thread with this id: ``main`` where it never
+    said one (or the thread that did has ended and left its id to
+    another)."""
+    said = _role_by_ident.get(ident)
+    if said is not None:
+        thread = said[1]()
+        if thread is not None and thread.is_alive():
+            return said[0]
+    return MAIN
 
 
 def wire_header() -> Optional[list]:
@@ -545,6 +627,9 @@ class _NoSpan:
     def end(self) -> None:
         pass
 
+    def note(self, **attrs) -> None:
+        pass
+
     __enter__ = begin
 
     def __exit__(self, *exc) -> None:
@@ -561,8 +646,9 @@ def span(stage: str, ut=None, **attrs):
         with span(TASK_DISPATCH, ut):
             g, key = step(X, y, w, key)
 
-    - a *work* stage (``WORK_STAGES``), the submitter's two holds
-      (``HOLD_STAGES``) and an injected delay (``task.delay``) open a
+    - a *work* stage (``WORK_STAGES``), a hold (``HOLD_STAGES``: the
+      submitter's two, and a serial thread's contended wait at a clocked
+      lock, ``LOCK_STAGES``) and an injected delay (``task.delay``) open a
       profiler annotation ``async.<stage>`` whenever a ``jax.profiler``
       session is open, so the stage shows on the device trace's clock; any
       other wait stage never does.  With no session
@@ -586,7 +672,9 @@ def span(stage: str, ut=None, **attrs):
     ``task.dispatch`` are the annotation's alone.  ``with`` and
     ``begin()`` / ``end()`` are the same pair; a stage that ends on
     another thread than it began on rides the handle
-    (:meth:`UpdateTrace.begin`)."""
+    (:meth:`UpdateTrace.begin`).  What is known only at the stage's end
+    (a ``task.enqueue``'s ``cpu_ms``) is told the open span:
+    ``sp.note(cpu_ms=...)``, span fields only."""
     name = _ANNOTATION_NAME.get(stage)
     if name is not None and not _profiling():
         name = None  # no profiler session is open: nothing to annotate
@@ -622,6 +710,11 @@ class _Span:
                 ut.ids[self.stage] = _new_id(8)
             self.start_ms = now_ms()
         return self
+
+    def note(self, **attrs) -> None:
+        """More of the stage's attributes, from inside it: they go on the
+        span(s) its end records (the annotation was opened without them)."""
+        self._attrs.update(attrs)
 
     def end(self) -> None:
         if self._uts:
@@ -745,6 +838,15 @@ class TraceRecorder:
 
 
 # ------------------------------------------------------------ aggregation
+#: ``task.enqueue``'s median is read by the calls in progress when it was
+#: made: alone, beside one, two, three to five, six and more
+CALLS_IN_BUCKETS = ("0", "1", "2", "3-5", "6+")
+
+
+def calls_in_bucket(calls_in: int) -> str:
+    return CALLS_IN_BUCKETS[min(calls_in, 3) if calls_in <= 5 else 4]
+
+
 class TraceAggregator:
     """Folds spans into per-stage latency histograms + staleness (versions
     AND milliseconds) distributions; the ``trace`` section of the live UI
@@ -758,23 +860,41 @@ class TraceAggregator:
         self._mk = lambda: Histogram(capacity)
         self._stages: Dict[str, "Histogram"] = {}
         self._stage_bytes: Dict[str, "Histogram"] = {}
+        self._stage_calls_in: Dict[str, "Histogram"] = {}
+        self._stage_cpu_ms: Dict[str, "Histogram"] = {}
+        #: ``task.enqueue``'s durations, and its thread's CPU times, by the
+        #: calls that were in progress when it was made (``CALLS_IN_BUCKETS``)
+        self._enqueue_by_calls_in: Dict[str, "Histogram"] = {}
+        self._enqueue_cpu_by_calls_in: Dict[str, "Histogram"] = {}
         self._staleness_v = self._mk()
         self._staleness_ms = self._mk()
         self.spans_total = 0
         self.traces_seen: "OrderedDict[str, None]" = OrderedDict()
 
+    def _fold(self, table: Dict[str, "Histogram"], key: str,
+              value: float) -> None:
+        h = table.get(key)
+        if h is None:
+            h = table[key] = self._mk()
+        h.update(value)
+
     def add(self, span: Span) -> None:
         with self._lock:
             self.spans_total += 1
-            h = self._stages.get(span.stage)
-            if h is None:
-                h = self._stages[span.stage] = self._mk()
-            h.update(span.dur_ms)
+            self._fold(self._stages, span.stage, span.dur_ms)
             if span.bytes is not None:
-                hb = self._stage_bytes.get(span.stage)
-                if hb is None:
-                    hb = self._stage_bytes[span.stage] = self._mk()
-                hb.update(float(span.bytes))
+                self._fold(self._stage_bytes, span.stage, float(span.bytes))
+            if span.cpu_ms is not None:
+                self._fold(self._stage_cpu_ms, span.stage, float(span.cpu_ms))
+            if span.calls_in is not None:
+                self._fold(self._stage_calls_in, span.stage,
+                           float(span.calls_in))
+                if span.stage == TASK_ENQUEUE:
+                    bucket = calls_in_bucket(span.calls_in)
+                    self._fold(self._enqueue_by_calls_in, bucket, span.dur_ms)
+                    if span.cpu_ms is not None:
+                        self._fold(self._enqueue_cpu_by_calls_in, bucket,
+                                   float(span.cpu_ms))
             if span.staleness is not None:
                 self._staleness_v.update(float(span.staleness))
             if span.staleness_ms is not None:
@@ -818,12 +938,37 @@ class TraceAggregator:
                     name: h.snapshot()
                     for name, h in self._stage_bytes.items()
                 }
+            # the calls in progress when a stage's call was made, the
+            # calling thread's CPU time inside it, and the relation read
+            # off: the call's wall time and its CPU time by calls in
+            # progress, with counts.  (The MEAN is the CPU time's
+            # statistic: where the host's thread clock ticks, 10 ms on the
+            # v5e's, a call reads 0 or a tick, and only the mean over many
+            # calls converges on what a call costs.)
+            for key, table in (("stages_calls_in", self._stage_calls_in),
+                               ("stages_cpu_ms", self._stage_cpu_ms)):
+                if table:
+                    out[key] = {name: h.snapshot()
+                                for name, h in table.items()}
+            for key, table, keep in (
+                    ("enqueue_ms_by_calls_in", self._enqueue_by_calls_in,
+                     ("count", "p50", "mean")),
+                    ("enqueue_cpu_ms_by_calls_in",
+                     self._enqueue_cpu_by_calls_in, ("count", "mean"))):
+                if table:
+                    by = ((b, table[b].snapshot())
+                          for b in CALLS_IN_BUCKETS if b in table)
+                    out[key] = {b: {k: h[k] for k in keep} for b, h in by}
             return out
 
     def reset(self) -> None:
         with self._lock:
             self._stages.clear()
             self._stage_bytes.clear()
+            self._stage_calls_in.clear()
+            self._stage_cpu_ms.clear()
+            self._enqueue_by_calls_in.clear()
+            self._enqueue_cpu_by_calls_in.clear()
             self._staleness_v = self._mk()
             self._staleness_ms = self._mk()
             self.spans_total = 0
@@ -861,6 +1006,7 @@ def span_event(span: Span, time_ms: float) -> "object":
         staleness=span.staleness, staleness_ms=span.staleness_ms,
         accepted=span.accepted, bytes=span.bytes, batch=span.batch,
         calls=span.calls, delay_class=span.delay_class,
+        calls_in=span.calls_in, cpu_ms=span.cpu_ms,
     )
 
 

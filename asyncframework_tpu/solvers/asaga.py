@@ -44,7 +44,11 @@ from asyncframework_tpu.solvers.base import (
 )
 from asyncframework_tpu.metrics import trace
 from asyncframework_tpu.solvers.engine_loop import EngineRun, EngineSolver
-from asyncframework_tpu.solvers.instrumentation import on_device, worker_task
+from asyncframework_tpu.solvers.instrumentation import (
+    enqueue_step,
+    on_device,
+    worker_task,
+)
 
 
 #: Every so-many-th accept of ``ASAGA.run`` pays the exact table delta
@@ -118,7 +122,8 @@ class ASAGA(EngineSolver):
         ctx, inst, waiting = run.ctx, run.inst, run.waiting
         calibrator, ckpt = run.calibrator, run.ckpt
         state, state_lock, stop = run.state, run.state_lock, run.stop
-        hot_lock = run.key_lock  # guards the alpha slots too
+        # the key lock by the name of what it guards here: the alpha slots
+        hot_lock = run.history_lock
         if ck is not None:
             # resume: the running history mean and the full per-worker
             # history table come back with the run's common fields
@@ -147,6 +152,9 @@ class ASAGA(EngineSolver):
         self._warm_hot_path()
         run.start_clock()
         snapshots, now_ms = run.snapshots, run.now_ms
+        # the accept path's dispatches are PJRT calls like a step's:
+        # counted among the run's calls in progress
+        calls_in = run.calls_in
 
         def history_fields(ab) -> Dict:
             with hot_lock:
@@ -201,7 +209,8 @@ class ASAGA(EngineSolver):
                             shard = self._recovery.shard(res.worker_id)
                             t_hist = time.perf_counter_ns()
                             with trace.span(trace.MERGE_HISTORY,
-                                            tuple(uts)), hot_lock:
+                                            tuple(uts)), hot_lock, \
+                                    calls_in:
                                 wid = res.worker_id
                                 alpha_cur = alpha[wid]
                                 # a shard re-homed while this result was in
@@ -255,24 +264,26 @@ class ASAGA(EngineSolver):
                             state["history_ns"] += (
                                 time.perf_counter_ns() - t_hist
                             )
-                            if g.device != self.driver_device:
-                                g = jax.device_put(g, self.driver_device)
-                            if reuse:
-                                state["reused"] += 1
-                                state["w"], state["ab"] = (
-                                    self._apply_g_is_delta(
-                                        state["w"], state["ab"], g, g
+                            with calls_in:
+                                if g.device != self.driver_device:
+                                    g = jax.device_put(
+                                        g, self.driver_device)
+                                if reuse:
+                                    state["reused"] += 1
+                                    state["w"], state["ab"] = (
+                                        self._apply_g_is_delta(
+                                            state["w"], state["ab"], g, g
+                                        )
                                     )
-                                )
-                            else:
-                                state["recomputed"] += 1
-                                if delta.device != self.driver_device:
-                                    delta = jax.device_put(
-                                        delta, self.driver_device
+                                else:
+                                    state["recomputed"] += 1
+                                    if delta.device != self.driver_device:
+                                        delta = jax.device_put(
+                                            delta, self.driver_device
+                                        )
+                                    state["w"], state["ab"] = self._apply(
+                                        state["w"], state["ab"], g, delta
                                     )
-                                state["w"], state["ab"] = self._apply(
-                                    state["w"], state["ab"], g, delta
-                                )
                         else:
                             state["dropped"] += 1
                     inst.updater_apply_ns += time.perf_counter_ns() - t_apply
@@ -414,7 +425,8 @@ class ASAGA(EngineSolver):
         run = EngineRun(self, sync=True)
         ctx, sched, inst = run.ctx, run.sched, run.inst
         waiting, calibrator = run.waiting, run.calibrator
-        hot_lock = run.key_lock  # guards the alpha slots too
+        # the key lock by the name of what it guards here: the alpha slots
+        hot_lock = run.history_lock
         sync_apply = steps.make_saga_apply(
             cfg.gamma, cfg.batch_rate, self.ds.n, 1,  # parRecs = b*N
             donate_g=False,  # the drain passes acc as both g and delta
@@ -560,7 +572,7 @@ class ASAGA(EngineSolver):
         """The run's hook for a re-homed shard: its history slice and PRNG
         chain follow it to the new device.  The slot is assigned, so its
         count moves on: a result in flight takes the exact table delta."""
-        hot_lock, worker_keys = run.key_lock, run.worker_keys
+        hot_lock, worker_keys = run.history_lock, run.worker_keys
 
         def on_shard_moved(shard_id, moved):
             with hot_lock:
@@ -577,7 +589,7 @@ class ASAGA(EngineSolver):
         """``make_tasks`` of this run (``EngineRun.drive``): a task captures
         its worker's key, history slice and the slice's commit count, read
         under one hold of the lock that guards all three."""
-        hot_lock, worker_keys = run.key_lock, run.worker_keys
+        hot_lock, worker_keys = run.history_lock, run.worker_keys
         delay_model = run.delay_model
 
         def make_tasks(cohort, w_pub, uts):
@@ -726,19 +738,19 @@ class ASAGA(EngineSolver):
         # HERE, once a task: not in ``dispatch``, on the executor's thread
         operands = self._recovery.shard(wid).operands
         dev = operands[0].device
-        step = self._step
+        step, calls = self._step, self._calls_in
 
         def dispatch(ut):
             # a slice/key captured around a concurrent shard re-home may
             # still live on the old device; normalize onto the shard's home
-            w_local = on_device(w_pub, dev, ut)
-            a_local = on_device(alpha_slice, dev, ut)
-            key_local = on_device(key, dev, ut)
+            w_local = on_device(w_pub, dev, ut, calls)
+            a_local = on_device(alpha_slice, dev, ut, calls)
+            key_local = on_device(key, dev, ut, calls)
             # (g, ...payload..., new_key) -- the payload arity differs
             # between the dense (diff, mask) and compacted sparse
             # (diff_sel, idx, valid, c_sel, v_sel) steps
-            with trace.span(trace.TASK_ENQUEUE, ut):
-                out = step(*operands, w_local, a_local, key_local)
+            out = enqueue_step(
+                step, (*operands, w_local, a_local, key_local), ut, calls)
             return (*out[:-1], slice_commits, out[-1])
 
         # (an injected delay sleeps in front of the dispatch: a straggler

@@ -46,7 +46,11 @@ from asyncframework_tpu.solvers.engine_loop import (
     EngineSolver,
     ModelReplicas,
 )
-from asyncframework_tpu.solvers.instrumentation import on_device, worker_task
+from asyncframework_tpu.solvers.instrumentation import (
+    enqueue_step,
+    on_device,
+    worker_task,
+)
 
 
 class ASGD(EngineSolver):
@@ -91,6 +95,7 @@ class ASGD(EngineSolver):
         # the replica on its shard's chip and no step waits for a copy
         # queued behind another chip's steps (PERF.md section 6, PR 47)
         chips = run.replicate_model()
+        calls_in = run.calls_in  # the applies are PJRT calls like a step's
 
         def resident(dev):
             # what the fold takes beside the drain's gradients, resident
@@ -204,7 +209,8 @@ class ASGD(EngineSolver):
                             if lo <= at_k - k < top
                         )
                     t_apply = time.perf_counter_ns()
-                    with trace.span(trace.MERGE_APPLY, in_it, batch=n):
+                    with trace.span(trace.MERGE_APPLY, in_it, batch=n), \
+                            calls_in:
                         if chips is not None:
                             if n:
                                 # the same dispatch a chip, each on its
@@ -499,13 +505,13 @@ class ASGD(EngineSolver):
         # ``dispatch``, on the executor's thread)
         operands = self._recovery.shard(wid).operands
         dev = operands[0].device
-        step = self._step
+        step, calls = self._step, self._calls_in
 
         def dispatch(ut):
-            w_local = on_device(w_pub, dev, ut)
-            key_local = on_device(key, dev, ut)
-            with trace.span(trace.TASK_ENQUEUE, ut):
-                return step(*operands, w_local, key_local)
+            w_local = on_device(w_pub, dev, ut, calls)
+            key_local = on_device(key, dev, ut, calls)
+            return enqueue_step(step, (*operands, w_local, key_local), ut,
+                                calls)
 
         # (an injected delay sleeps in front of the dispatch: a straggler
         # takes no turn, or the workers behind it would wait for its sleep)

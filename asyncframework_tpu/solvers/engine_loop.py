@@ -38,6 +38,7 @@ from asyncframework_tpu.engine.scheduler import ASYNC, JobScheduler
 from asyncframework_tpu.engine.speculation import SpeculationMonitor
 from asyncframework_tpu.engine.straggler import DelayModel
 from asyncframework_tpu.metrics import timeseries, trace
+from asyncframework_tpu.net import lockwatch
 from asyncframework_tpu.ops import steps
 from asyncframework_tpu.solvers.base import (
     DelayCalibrator,
@@ -50,6 +51,9 @@ from asyncframework_tpu.solvers.base import (
     resolve_dataset,
 )
 from asyncframework_tpu.solvers.instrumentation import (
+    UNCOUNTED,
+    CallsIn,
+    ClockedLock,
     FaultTolerantRun,
     RunInstruments,
     StepsOut,
@@ -77,6 +81,10 @@ class EngineSolver:
     #: (``EngineRun.replicate_model``; empty where the model is one buffer
     #: on the driver's chip)
     _spread: Dict = {}
+    #: the run's count of PJRT calls in progress
+    #: (``instrumentation.CallsIn``: what ``_make_task`` hands the step's
+    #: call and the task's copies); nobody's before a solver's first run
+    _calls_in: CallsIn = UNCOUNTED
 
     def _place(self, X, y: Optional[np.ndarray], config: SolverConfig,
                devices: Optional[list], history: bool) -> None:
@@ -311,23 +319,39 @@ class ModelReplicas(tuple):
         return np.asarray(self[0], dtype=dtype)
 
 
-def _spreader(chips: List, home):
+def _spreader(chips: List, home, calls: CallsIn):
     """``spread(g)`` for the steps that run on ``home``: the gradient ``g``
     on every one of ``chips``, in their order (``g`` itself at ``home``'s
     place), as the updater's applies take it.  The thread that called the
     step calls this at once, in front of its wait for ``g``: the copies
     are queued behind the step on the device's side, their host calls
     (0.26 ms each on the v5e's host) fall into the step's own time, and
-    no other chip's queue is held (PERF.md section 6, PR 47)."""
+    no other chip's queue is held (PERF.md section 6, PR 47).  They are
+    PJRT calls like the step's: counted among the run's calls in progress
+    (``calls``)."""
     others = [dev for dev in chips if dev != home]
     at = chips.index(home)
 
     def spread(g):
-        row = jax.device_put([g] * len(others), others)
+        with calls:
+            row = jax.device_put([g] * len(others), others)
         row.insert(at, g)
         return tuple(row)
 
     return spread
+
+
+def _engine_lock(name: str) -> ClockedLock:
+    """One of a run's hot-path locks, with the clock on the wait at it.
+    Inside, an ``RLock`` (it names its owner to a waiter); while the DCN
+    plane's watchdog is armed (``async.debug.lockwatch``, the chaos suite)
+    a ``lockwatch.WatchedLock`` in its place, so that the order graph and
+    the hold counts see the engine's locks too: a debug run, in which the
+    context's lock is not handed one (a ``WatchedLock`` is not
+    re-entrant) and a wait's holder reads ``nobody``."""
+    if name != "context" and lockwatch.enabled_for():
+        return ClockedLock(name, lockwatch.named_lock("engine." + name))
+    return ClockedLock(name)
 
 
 class EngineRun:
@@ -342,8 +366,14 @@ class EngineRun:
         nw = cfg.num_workers
         self.solver, self.cfg, self.sync = solver, cfg, sync
         self._built = time.monotonic()
-        self.ctx: AsyncContext = AsyncContext()
-        self.sched = JobScheduler(num_workers=nw, devices=solver.devices)
+        context_lock = _engine_lock("context")
+        self.ctx: AsyncContext = AsyncContext(lock=context_lock)
+        # (the pool's lock is clocked too: ``JobScheduler._lock`` and it
+        # were read once with the class, PERF.md section 6, PR 53, and the
+        # pool's executors stood at theirs 0.8% of a 32-worker run)
+        pool_lock = _engine_lock("pool")
+        self.sched = JobScheduler(num_workers=nw, devices=solver.devices,
+                                  pool_lock=pool_lock)
         # non-blocking submit in both modes: a sync run drains on the driver
         self.sched.set_mode(ASYNC)
         solver.scheduler = self.sched  # exposed for fault-injection tests/tools
@@ -357,6 +387,9 @@ class EngineRun:
         solver._turns = (
             {dev: DispatchTurns() for dev in solver.devices} if ragged else {})
         solver._spread = {}
+        #: the engine's calls into PJRT that are in progress, on any
+        #: thread: the tasks' (through the solver) and the updater's
+        self.calls_in = solver._calls_in = CallsIn()
         self.delay_model = DelayModel(cfg.coeff, nw, cfg.seed)
         # sync counts rounds, not accepted gradients: the reference's
         # k < 100*numPart window covers the first 100 full-drain rounds.
@@ -375,11 +408,19 @@ class EngineRun:
         #: guards the slots (a solver may keep further per-worker handle
         #: slots under the same lock)
         self.worker_keys: Dict[int, jax.Array] = {}
-        self.key_lock = threading.Lock()
+        self.key_lock = _engine_lock("key")
+        #: ``key_lock`` itself, under the name of what a solver guards with
+        #: it beside the keys (ASAGA's history slices and their commit
+        #: counts): the waits at those sites are booked to ``history``
+        self.history_lock = self.key_lock.alias("history")
         #: the model handle ``w``, the accepted count ``k`` and the run's
         #: counters, under ``state_lock``; the solver adds its own fields
         self.state: Dict[str, object] = {}
-        self.state_lock = threading.Lock()
+        self.state_lock = _engine_lock("state")
+        # the locks of the hot path, each with a clock on the wait at it
+        # (who stood there, behind whom): ``extras``' ``lock_wait_*``
+        self.inst.locks.extend((self.state_lock, self.key_lock, context_lock,
+                                self.history_lock, pool_lock))
         self.stop = threading.Event()
         #: the account of model-sized device buffers (``d`` f32 each: 3 kB
         #: in the dense cells, 219 MB at d = 54.7M) this run's engine
@@ -539,7 +580,7 @@ class EngineRun:
             return None
         self.chips = [dev for dev in solver.devices if dev in on]
         self._chip_index = {dev: i for i, dev in enumerate(self.chips)}
-        solver._spread = {dev: _spreader(self.chips, dev)
+        solver._spread = {dev: _spreader(self.chips, dev, self.calls_in)
                           for dev in self.chips}
         self.state["w"] = ModelReplicas(
             jax.device_put([self.state["w"]] * len(self.chips), self.chips))
@@ -623,7 +664,13 @@ class EngineRun:
             if cfg.stale_read_offset is not None
             else None
         )
-        upd = threading.Thread(target=updater, name=thread_name, daemon=True)
+
+        def as_updater():
+            trace.set_role(trace.UPDATER)  # who waits at a lock, for whom
+            updater()
+
+        upd = threading.Thread(target=as_updater, name=thread_name,
+                               daemon=True)
         upd.start()
         waiters: deque = deque(maxlen=4 * nw)  # recent jobs, failure check
         bucket = bucket_predicate(ctx, nw, ratio)
@@ -631,6 +678,7 @@ class EngineRun:
         deadline = time.monotonic() + cfg.run_timeout_s
         run_ok = False
         try:
+            trace.set_role(trace.SUBMITTER)  # this thread, for the loop
             while not stop.is_set() and time.monotonic() < deadline:
                 failed = next((x.failed for x in waiters if x.failed), None)
                 if failed is not None:
@@ -717,6 +765,7 @@ class EngineRun:
             run_ok = True
         finally:
             clock.waits()  # the loop's last busy stretch
+            trace.set_role(trace.MAIN)  # the caller's thread again
             # the run's last seconds: nothing is accepted from here on,
             # and ``elapsed_s`` runs on to the fence (``result``)
             t_exit = time.monotonic()

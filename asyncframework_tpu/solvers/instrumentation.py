@@ -18,6 +18,7 @@ configured the instruments are inert no-ops.
 
 from __future__ import annotations
 
+import re
 import threading
 import time
 from typing import Callable, Dict, List, Optional, Tuple
@@ -89,7 +90,11 @@ class BusyClock:
     Python, but also lock waits, GIL hand-offs and dispatches that block
     while the device's queue is full.  It bounds the thread's own work
     from above, so ``accepted / busy`` bounds the rate at which the
-    thread alone saturates from below."""
+    thread alone saturates from below.  The lock waits' part has a price
+    of its own: :class:`ClockedLock` books every contended wait at the
+    engine's locks to the waiting thread's role, and
+    ``lock_wait_submitter_s`` / ``lock_wait_updater_s`` of a run's
+    ``extras`` say how much of each thread's busy time was standing."""
 
     __slots__ = ("busy_ns", "wait_ns", "_mark")
 
@@ -120,6 +125,179 @@ class BusyClock:
         that keeps its own account (``JobScheduler.blocked_ns``)."""
         self.busy_ns -= ns
         self.wait_ns += ns
+
+
+#: the two serial threads: a contended wait of one of them at a clocked
+#: lock is a hold in ``metrics/trace.py``'s sense, annotated in a profiler
+#: session; an executor's never is
+_SERIAL_ROLES = frozenset((trace_mod.SUBMITTER, trace_mod.UPDATER))
+#: how CPython's ``RLock`` says who owns it: ``<locked _thread.RLock object
+#: owner=140... count=1 at 0x...>`` (``owner=0`` while it is free)
+_OWNER = re.compile(r"owner=(\d+)")
+
+
+class ClockedLock:
+    """A lock of the engine's hot path with a clock on the WAIT at it:
+    who stood there, behind whom, how long.  An ``RLock`` inside (it is
+    the lock that remembers its owner), taken by ``with`` alone, as the
+    bare lock it replaces was; a lock that was not re-entrant is not
+    entered again by this either.
+
+    Where nobody contends it costs one Python call and reads no clock:
+    enter is ``acquire(blocking=False)`` and nothing else, exit is the
+    inner lock's own ``__exit__``, in C (every lock is an instance of a
+    class of its own that holds it: ``with`` looks ``__exit__`` up on the
+    type).  Only where the try fails: the holder is asked of the inner
+    lock (its thread id, to ``trace.role_of``: the role that thread said
+    where it started), then ``perf_counter_ns``, the blocking acquire,
+    ``perf_counter_ns``, and the wait is booked to the pair (this thread's
+    role, the holder's) WHILE HOLDING the lock just taken, so the sums
+    need no lock of their own.  A lock that names nobody when the wait
+    begins is on its way to a thread that WAITED for it and has not had
+    the interpreter since (a lock released to a blocked thread is that
+    thread's at once; the thread writes itself in as owner when it next
+    runs): every thread that gets the lock by waiting leaves its role in
+    one cell while it books its wait, and the waiter that was told
+    "nobody" reads that cell when its own wait ends.  The holder is read
+    from the lock and not from a cell that every holder writes: the 11
+    enters of the context's lock that ONE poll of the submitter makes
+    would each pay the write and a Python ``__exit__`` (a four-chip run
+    read 1% slower so, PERF.md section 6, PR 53).  A contended wait of the
+    submitter or the updater also opens ``async.lock.<name>`` in a
+    profiler session (``trace.LOCK_STAGES``), so that a chip's gap can
+    carry the lock's name; an executor's never does.
+
+    It times WAITS, always on, for a run's ``extras``
+    (:func:`lock_wait_counters`).  ``net/lockwatch.WatchedLock`` is the
+    DCN plane's debug watchdog and times HOLDS (and keeps the order graph,
+    the I/O assertion): nothing of it is redone here.  A ``WatchedLock``
+    may be the inner lock (``EngineRun`` hands one in while the watchdog
+    is armed, so that it sees the engine's locks too); it names no owner,
+    and a wait at it stands behind the last thread that waited there."""
+
+    __slots__ = ("name", "waits_ns", "contended", "max_ns", "max_at",
+                 "_inner", "_acquire", "_stage", "_handed_to")
+
+    def __new__(cls, name: str, inner=None,
+                alias_of: Optional["ClockedLock"] = None):
+        if alias_of is not None:
+            inner = alias_of._inner
+        elif inner is None:
+            inner = threading.RLock()
+        mine = type(cls.__name__, (cls,), {
+            "__slots__": (), "__exit__": inner.__exit__})
+        lock = object.__new__(mine)
+        lock._inner, lock._acquire = inner, inner.acquire
+        #: one cell, shared with every :meth:`alias`: the role of the last
+        #: thread that got the lock by WAITING for it (written under it)
+        lock._handed_to = (alias_of._handed_to if alias_of is not None
+                           else [trace_mod.NOBODY])
+        return lock
+
+    def __init__(self, name: str, inner=None,
+                 alias_of: Optional["ClockedLock"] = None):
+        self.name = name
+        self._stage = trace_mod.LOCK_STAGES.get(name)
+        self._zero()
+
+    def _zero(self) -> None:
+        #: (waiter's role, holder's role) -> nanoseconds waited
+        self.waits_ns: Dict[Tuple[str, str], int] = {}
+        self.contended = 0
+        self.max_ns = 0
+        self.max_at: Optional[Tuple[str, str]] = None
+
+    def alias(self, name: str) -> "ClockedLock":
+        """The SAME lock under another name: the waits at the sites that
+        take it through the alias are booked to ``name`` (ASAGA takes the
+        key lock for its history slices: ``history``)."""
+        return ClockedLock(name, alias_of=self)
+
+    def __enter__(self) -> "ClockedLock":
+        if not self._acquire(False):
+            self._wait()
+        return self
+
+    def holder(self) -> str:
+        """The role of the thread that holds the lock now, as the lock
+        itself names its owner; ``nobody`` where it is free (or is no
+        ``RLock``)."""
+        owner = _OWNER.search(repr(self._inner))
+        ident = int(owner[1]) if owner else 0
+        return trace_mod.role_of(ident) if ident else trace_mod.NOBODY
+
+    def _wait(self) -> None:
+        me = trace_mod.role()
+        behind = self.holder()
+        hold = (trace_mod.span(self._stage).begin()
+                if me in _SERIAL_ROLES and self._stage else None)
+        t0 = time.perf_counter_ns()
+        self._acquire()
+        waited = time.perf_counter_ns() - t0
+        if hold is not None:
+            hold.end()
+        # the lock is held from here: no other thread writes these.  A lock
+        # that named nobody when the wait began was on its way to a thread
+        # that had waited for it and had not run yet: that thread has said
+        # who it is by now (below, under the lock, before it let go)
+        if behind is trace_mod.NOBODY:
+            behind = self._handed_to[0]
+        self._handed_to[0] = me
+        pair = (me, behind)
+        self.waits_ns[pair] = self.waits_ns.get(pair, 0) + waited
+        self.contended += 1
+        if waited > self.max_ns:
+            self.max_ns, self.max_at = waited, pair
+
+    def start(self) -> None:
+        """The run's clock starts: nothing has waited yet."""
+        with self:
+            self._zero()
+
+    def read(self) -> Tuple[Dict[Tuple[str, str], int], int, int,
+                            Optional[Tuple[str, str]]]:
+        """``(waits_ns, contended, max_ns, max_at)``, read under the lock
+        itself (they are written under it)."""
+        with self:
+            return (dict(self.waits_ns), self.contended, self.max_ns,
+                    self.max_at)
+
+
+def lock_wait_counters(locks) -> Dict[str, object]:
+    """What a run's clocked locks read, as ``extras`` carry it (scalars).
+    Every key but the pairs' is there in every run, 0 where nothing
+    waited: ``lock_wait_<lock>_s`` (all waiters) and
+    ``lock_contended_<lock>`` (waits counted) for each of
+    ``trace.LOCK_NAMES``; ``lock_wait_<role>_s`` (all locks) for each of
+    ``trace.ROLES``; the non-zero cells of the full table as
+    ``lock_wait_<lock>_<waiter>_behind_<holder>_s``; the longest single
+    wait ``lock_wait_max_ms`` and where it was, ``lock_wait_max_at``
+    (``"<lock>:<waiter>:<holder>"``, empty where nothing waited)."""
+    by_lock = dict.fromkeys(trace_mod.LOCK_NAMES, 0)
+    counted = dict.fromkeys(trace_mod.LOCK_NAMES, 0)
+    by_role = dict.fromkeys(trace_mod.ROLES, 0)
+    pairs: Dict[str, float] = {}
+    worst_ns, worst_at = 0, ""
+    for lock in locks:
+        waits_ns, contended, max_ns, max_at = lock.read()
+        counted[lock.name] = counted.get(lock.name, 0) + contended
+        by_lock.setdefault(lock.name, 0)
+        for (waiter, holder), ns in waits_ns.items():
+            by_lock[lock.name] += ns
+            by_role[waiter] = by_role.get(waiter, 0) + ns
+            key = f"lock_wait_{lock.name}_{waiter}_behind_{holder}_s"
+            pairs[key] = pairs.get(key, 0.0) + ns * 1e-9
+        if max_ns > worst_ns:
+            worst_ns, worst_at = max_ns, ":".join((lock.name, *max_at))
+    out: Dict[str, object] = {
+        f"lock_wait_{name}_s": ns * 1e-9 for name, ns in by_lock.items()}
+    out.update(
+        (f"lock_wait_{role}_s", ns * 1e-9) for role, ns in by_role.items())
+    out.update(pairs)
+    out.update((f"lock_contended_{name}", n) for name, n in counted.items())
+    out["lock_wait_max_ms"] = worst_ns * 1e-6
+    out["lock_wait_max_at"] = worst_at
+    return out
 
 
 class Occupancy:
@@ -225,17 +403,20 @@ class Occupancy:
             }
 
 
-def on_device(arr, device, ut=None):
+def on_device(arr, device, ut=None, calls: Optional["CallsIn"] = None):
     """``arr`` on the worker's chip; a copy from another chip is one
     ``task.model_copy`` (inside ``task.dispatch``; ``ut``: the handle of a
-    sampled update whose task stages this copy of the task records).  What
+    sampled update whose task stages this copy of the task records;
+    ``calls``: the run's count of PJRT calls in progress, which a copy is
+    one of).  What
     still copies: a model that is one buffer on the driver's chip (ASAGA's,
     a synchronous run's, a version of the ``VersionedModelStore``, a
     test's plain array) and what followed a re-homed shard late (a key, a
     history slice).  ASGD's model lives on every chip and is handed to a
     task as the buffer on its chip (``EngineRun.model_for``)."""
     if arr.device != device:
-        with trace_mod.span(trace_mod.TASK_MODEL_COPY, ut):
+        with trace_mod.span(trace_mod.TASK_MODEL_COPY, ut), \
+                calls or UNCOUNTED:
             arr = jax.device_put(arr, device)
     return arr
 
@@ -248,7 +429,11 @@ class StepsOut:
     to itself (``task.device_wait.alone``, metrics/trace.py).  One integer
     under a lock of its own: what an update that is not sampled pays.  A
     task whose executor is lost inside its wait never takes its one off:
-    from then on no task of that run on that chip reads as alone."""
+    from then on no task of that run on that chip reads as alone.
+    :class:`CallsIn` is the other count, and differs in both dimensions:
+    calls IN PROGRESS on the host, over the whole run (the interpreter
+    lock and the PJRT client are the process's), against steps out on ONE
+    chip, which a call that has returned still is."""
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -263,6 +448,70 @@ class StepsOut:
     def completed(self) -> None:
         with self._lock:
             self._n -= 1
+
+
+class CallsIn:
+    """The calls the engine has IN PROGRESS into PJRT, on any thread and to
+    any chip: one count a RUN, kept for every task of every run.  ``with
+    calls as found:`` adds one in front of a call and takes it off behind
+    it; ``found`` is the count on entry, the OTHER calls in progress when
+    this one was made.  Around the step's call (:func:`enqueue_step`), a
+    task's copies (:func:`on_device`; the gradient's spread over the chips,
+    ``engine_loop._spreader``) and the updater's dispatches (ASGD's apply a
+    chip; ASAGA's commit, table delta, copies and apply).  A sampled task
+    puts ``found`` on its ``task.enqueue`` span as ``calls_in``: n calls
+    made at once each take n times as long (PERF.md section 5), and the
+    aggregator reads ``task.enqueue``'s time by it.  The count is a list's
+    length, one entry a call in progress: ``append`` and ``pop`` are atomic
+    under the interpreter lock, so no call is ever lost from it and it
+    needs no lock of its own; ``found`` is read an instant before the
+    append, and two calls that enter in the same instant may find the same
+    number (a gauge for a histogram, not a ticket).  :class:`StepsOut` is
+    the other count, and says how the two differ.  A synchronous run's
+    driver applies between rounds, with no task out: its calls are not
+    counted."""
+
+    __slots__ = ("_in",)
+
+    def __init__(self):
+        self._in: list = []
+
+    def __enter__(self) -> int:
+        calls = self._in
+        found = len(calls)
+        calls.append(None)
+        return found
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        self._in.pop()
+
+
+#: the count of a caller that has no run's (a test's bare task, a closure
+#: built before a solver's first run): kept, read by nobody
+UNCOUNTED = CallsIn()
+
+
+def enqueue_step(step: Callable, operands: tuple,
+                 ut: Optional["trace_mod.UpdateTrace"],
+                 calls: CallsIn):
+    """The jitted worker step's call alone: the stage ``task.enqueue``
+    (``ut``: the handle of a sampled update whose task stages this copy of
+    the task records, else None).  Every task counts itself among the
+    run's calls in progress (``calls``); a sampled one also reads its
+    thread's CPU clock in and out and leaves both on the span:
+    ``calls_in``, the calls that were in progress when this one was made,
+    and ``cpu_ms``, of which the span's duration less it is the time the
+    thread was off the processor (the interpreter lock, a lock of PJRT's,
+    a full device queue).  No span is posted for either."""
+    with trace_mod.span(trace_mod.TASK_ENQUEUE, ut) as sp, calls as found:
+        if ut is None:
+            return step(*operands)
+        cpu0 = time.thread_time_ns()
+        try:
+            return step(*operands)
+        finally:
+            sp.note(calls_in=found,
+                    cpu_ms=(time.thread_time_ns() - cpu0) * 1e-6)
 
 
 def worker_task(dispatch: Callable[[Optional["trace_mod.UpdateTrace"]], tuple],
@@ -400,6 +649,10 @@ class RunInstruments:
         # the submitter are the two serial resources every update passes.
         self.updater_clock = BusyClock()
         self.submitter_clock = BusyClock()
+        #: the run's clocked locks (``EngineRun`` makes them and says so
+        #: here): their waits start with the run's clock and reach
+        #: ``extras`` through :meth:`engine_counters`
+        self.locks: List[ClockedLock] = []
         #: the part of the updater's busy time spent inside its apply
         #: dispatches, which block while the device's queue is full
         self.updater_apply_ns = 0
@@ -572,10 +825,12 @@ class RunInstruments:
 
     def on_run_start(self) -> None:
         """The run's clock starts (after the solver's warm-up): so do the
-        two threads' clocks, the occupancy account and the count of
-        compilations."""
+        two threads' clocks, the locks' waits, the occupancy account and
+        the count of compilations."""
         self.updater_clock.start()
         self.submitter_clock.start()
+        for lock in self.locks:
+            lock.start()
         if self.occupancy is not None:
             self.occupancy.start()
         self._compiles0 = compiles_so_far()
@@ -748,6 +1003,8 @@ class RunInstruments:
             "model_reads_copied": self.model_reads_copied,
             "task_retries": int(task_retries),
             "compiles_in_run": compiles_so_far() - self._compiles0,
+            # who stood at the engine's locks, behind whom, how long
+            **lock_wait_counters(self.locks),
         })
         if self.monitor is not None:
             out["host_stall_max_ms"] = self.monitor.stall_max_ms
