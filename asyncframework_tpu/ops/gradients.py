@@ -514,7 +514,10 @@ def _margins_lanes128(c_sel, v_sel, w):
 
 #: ``(R, C)``, the block of the ragged walk (:func:`walk_tile`) where the
 #: step's ``(d,)`` accumulator stays in VMEM through the scatter-add's
-#: loops, and where it lies in HBM (:func:`walk_accumulator_resident`).  On
+#: loops, and where it lies in HBM (:func:`walk_accumulator_resident`): the
+#: margins' lane-row gather takes the sample in it whichever program adds
+#: the products, and the scatter-add does where the sum is one a block
+#: (:func:`sparse_scatter_path`: the lists under the segments' bound).  On
 #: the v5e (PERF.md section 6, PR 40; webspam's packed samples of 992 rows
 #: from shards 1,664 to 16,384 slots wide, ``d`` 16,609,143, fenced): the
 #: compiler sorts a block's (column, product) pairs in front of its
@@ -530,7 +533,9 @@ def _margins_lanes128(c_sel, v_sel, w):
 #: 3.2 ns a slot at every block.  A chunk of 256 slots walks 1.05 to 1.10
 #: times a sample's non-zeros, one of 512 1.07 to 1.16; 64 x 256 and 32 x
 #: 512 take the same time a step from 3,840 slots a row on, and 64 x 256
-#: 7% less at 2,176.
+#: 7% less at 2,176.  Where the sum is by sorted segments the block is the
+#: margins' alone, and either serves (PERF.md section 6, PR 54: the
+#: narrowest shard's step 7.88 ms at 64 x 256, 8.20 at 128 x 512).
 SPARSE_WALK_TILE = (64, 256)
 SPARSE_WALK_TILE_HBM = (128, 512)
 
@@ -714,13 +719,13 @@ def sparse_residual(
 SPARSE_SEGMENT_TILE_SLOTS = 1_024
 
 
-def sparse_scatter_path(d: int, slots: int, walk=None,
-                        dtype=jnp.float32) -> str:
+def sparse_scatter_path(d: int, slots: int, dtype=jnp.float32) -> str:
     """``"segments"`` or ``"scatter"``: which program
     :func:`make_sparse_grad_sum` traces to add ``slots`` (column, product)
     pairs of ``dtype`` into a ``(d,)`` gradient, from what can be observed
-    when the step is built -- the backend, the dtype, whether the sample
-    is walked, and the mean run of slots a tile of ``g``.
+    when the step is built -- the backend, the dtype, and the mean run of
+    slots a tile of ``g``.  Whether the sample is walked no longer
+    decides (PR 54); it says which scatter-add ``"scatter"`` means.
 
     The v5e's scatter-add pays by the INDEX, 6.7 to 10.5 ns a slot
     whatever it holds and in whatever order, while a sort of the pairs
@@ -731,23 +736,50 @@ def sparse_scatter_path(d: int, slots: int, walk=None,
     one, so it is chosen where the tiles' mean run reaches
     :data:`SPARSE_SEGMENT_TILE_SLOTS` (and why there): criteo's 1,000,000
     columns hold 23,157 slots a tile under ASGD and 4,721 under ASAGA,
-    kdd2012's 54,686,452 hold 195.  A walked sample (``walk``: the blocks
-    of a shard stored in lane tiles, webspam's) is added block by block
-    into a carry and keeps the scatter-add, and so do the CPU and every
-    other dtype.
+    kdd2012's 54,686,452 hold 195.
+
+    A WALKED sample (the blocks of a shard stored in lane tiles,
+    webspam's) is held to the same bound since PR 54, ``slots`` its whole
+    ``capacity x width`` list: webspam's three widest shards (1,159,
+    1,534 and 4,008 a tile of 4,055) sort ONE list, its five narrowest
+    (407 to 939) add a block of the walk at a time.  For it too the bound
+    is NOT the break-even, which the chip read between 61 and 102 slots a
+    tile (PERF.md section 6, PR 54; the first rows of one packed sample,
+    ms alone, walked scatter-adds / segments: 1,664 slots wide 992 rows,
+    407 a tile, 12.59 / 4.68, 248 rows (102) 3.50 / 3.03, 128 (53) 1.94 /
+    2.89; 3,840 wide 992 rows (939) 29.67 / 7.78, 128 (121) 4.57 / 3.06,
+    64 (61) 2.62 / 2.79; 16,384 wide 992 rows (4,008) 79.23 / 29.62, 64
+    (259) 4.57 / 3.60).  What holds it is the SET-UP: every program that
+    holds the kernel costs a process 0.64 s of Python before its first
+    step (the kernel traced and lowered by Mosaic once a step SHAPE); all
+    eight of webspam's shapes on the segments read 59.8 updates/s for
+    28.4 and ``setup_s`` +14%, where the benchmark refuses a PR at +10%.
+    Three such programs fit that bound with room; the other five wait
+    until a process pays a step's tracing once a machine (ROADMAP Speed
+    1(a)(ii)).  The CPU and every other dtype keep the scatter-add.
     """
-    if not (_on_tpu() and walk is None
-            and jnp.dtype(dtype) == jnp.dtype(jnp.float32)):
+    if not (_on_tpu() and jnp.dtype(dtype) == jnp.dtype(jnp.float32)):
         return "scatter"
     tiles = -(-d // _kernels().SEGMENT_TILE)
     return ("segments" if slots >= SPARSE_SEGMENT_TILE_SLOTS * tiles
             else "scatter")
 
 
+def sparse_sorted_pairs(d: int, slots: int, dtype=jnp.float32) -> int:
+    """The (column, product) pairs :func:`make_sparse_grad_sum` SORTS to
+    add ``slots`` of them into a ``(d,)`` gradient, from the host's
+    integers: the whole list, padded to the kernel's blocks, where
+    :func:`sparse_scatter_path` says ``"segments"``; 0 where the program
+    holds a scatter-add and no sort of its own."""
+    if sparse_scatter_path(d, slots, dtype) != "segments":
+        return 0
+    return _kernels().segment_list_pairs(slots)
+
+
 def make_sparse_grad_sum(d: int):
     """jit (cols, vals, coeff[, walk]) -> dense (d,) gradient: the sum of
-    every slot's product at its column, by ONE scatter-add over the whole
-    sample, by one a block of the walk, or by sorted segments
+    every slot's product at its column, by sorted segments, by ONE
+    scatter-add over the whole sample, or by one a block of the walk
     (:func:`sparse_scatter_path`: the ONE place the program is chosen).
 
     ``g = sum_i coeff_i * x_i`` -- the sparse analog of ``X.T @ coeff``:
@@ -762,8 +794,14 @@ def make_sparse_grad_sum(d: int):
     shard stored in lane tiles with ``walk`` (:func:`sample_walk`): ``g``
     is then the carry of the walk's loops and takes one ``(R, C)`` block a
     scatter-add, each row tile up to its last non-zero, so the slots behind
-    it and the unfilled tail of the capacity are never given.  Only the
-    order of a column's terms differs between the forms.
+    it and the unfilled tail of the capacity are never given.  By sorted
+    segments a walked sample is ONE list, its ``capacity x width`` pairs
+    (PERF.md section 6, PR 54): a pair whose product is 0 -- behind a
+    row's end, in the unfilled tail -- goes under the column beyond every
+    tile, where a dropped pair goes, so that the kernel reads the live
+    pairs only and column 0's tile holds no run of zeros; the walk's
+    bounds are not read.  Only the order of a column's terms differs
+    between the forms.
 
     The scatter-adds take the slots in the order they are stored.  On the
     v5e a sort is cheap and an element-wise gather or scatter is dear
@@ -795,10 +833,13 @@ def make_sparse_grad_sum(d: int):
     @jax.jit
     def grad_sum(cols, vals, coeff, walk=None):
         with jax.named_scope("grad"):
-            if sparse_scatter_path(
-                    d, cols.size, walk, vals.dtype) == "segments":
+            if sparse_scatter_path(d, cols.size, vals.dtype) == "segments":
+                products = vals * coeff[:, None]
+                if walk is not None:
+                    # the slots behind a row's end and the unfilled tail
+                    cols = jnp.where(products != 0, cols, d)
                 return _kernels().segment_tiles_sum(
-                    cols.ravel(), (vals * coeff[:, None]).ravel(), d)
+                    cols.ravel(), products.ravel(), d)
             g = jnp.zeros(d, vals.dtype)
             if walk is None:
                 return add(g, cols, vals * coeff[:, None])

@@ -636,6 +636,14 @@ def segment_tiles_sum(cols, products, d: int, *, tile: Optional[int] = None,
     return _segment_sums(c, p, d, tile, block_rows, interpret)
 
 
+def segment_list_pairs(slots: int, block: Optional[int] = None) -> int:
+    """The pairs :func:`segment_tiles_sum` sorts for ``slots`` of them:
+    the list :func:`_segment_sorted` makes, whole blocks of ``block``
+    pairs (``None``: the kernel's own DMA block)."""
+    block = _SEGMENT_BLOCK_ROWS * _LANE if block is None else block
+    return pl.cdiv(max(slots, 1), block) * block
+
+
 def _segment_sorted(cols, products, d: int, tile: int, block: int):
     """The pairs in ascending order of column, padded to whole blocks of
     ``block`` pairs: a dropped pair and the padding under ``tile *
@@ -643,7 +651,7 @@ def _segment_sorted(cols, products, d: int, tile: int, block: int):
     beyond = pl.cdiv(d, tile) * tile
     c = jnp.where(cols < 0, cols + d, cols)
     c = jnp.where((c < 0) | (c >= d), beyond, c)
-    pad = pl.cdiv(max(cols.shape[0], 1), block) * block - cols.shape[0]
+    pad = segment_list_pairs(cols.shape[0], block) - cols.shape[0]
     return jax.lax.sort(
         (jnp.pad(c, (0, pad), constant_values=beyond),
          jnp.pad(products.astype(jnp.float32), (0, pad))),

@@ -66,6 +66,7 @@ from asyncframework_tpu.ops.gradients import (
     sparse_gather_path,
     sparse_margins,
     sparse_scatter_path,
+    sparse_sorted_pairs,
     walk_accumulator_resident,
     walk_tile,
 )
@@ -659,18 +660,20 @@ def sparse_walked_slots(batch_rate: float, d: int, n_rows: int, width: int,
     return float(rows * chunk * np.sum(-(-longest // chunk)))
 
 
-def _sized_by_capacity(step, batch_rate: float, d: int, walks: bool):
+def _sized_by_capacity(step, batch_rate: float, d: int):
     """A compacted sparse step's size, for who asks the step it runs:
     ``step.task_rows(n_rows)``, the rows its compaction holds
     (:func:`_counts_rows`), and what the two choosers say of the sample
     it packs from ``n_rows`` rows read ``width`` slots wide (the shard's
     live width where the step was built with one) against its ``(d,)``
     float32 model: ``step.gather_path(n_rows, width)``
-    (``gradients.sparse_gather_path``) and ``step.scatter_path(n_rows,
-    width)`` (``gradients.sparse_scatter_path``; ``walks``: whether the
-    step walks a sample of a shard stored in lane tiles, as ASGD's does
-    and ASAGA's does not), the solvers' ``extras["sparse_gather_path"]``
-    and ``["sparse_scatter_path"]``."""
+    (``gradients.sparse_gather_path``), ``step.scatter_path(n_rows,
+    width)`` (``gradients.sparse_scatter_path``: the same answer for a
+    sample that is walked, as ASGD's of a shard stored in lane tiles is,
+    and one read whole) and ``step.sorted_pairs(n_rows, width)``
+    (``gradients.sparse_sorted_pairs``: the pairs that program sorts), the
+    solvers' ``extras["sparse_gather_path"]``, ``["sparse_scatter_path"]``
+    and ``["sorted_pairs_per_step_mean"]``."""
     def task_rows(n_rows):
         return sparse_step_capacity(batch_rate, n_rows)
 
@@ -680,12 +683,11 @@ def _sized_by_capacity(step, batch_rate: float, d: int, walks: bool):
             jax.ShapeDtypeStruct((task_rows(n_rows), width), jnp.int32),
         )
 
-    def scatter_path(n_rows, width):
-        walk = sparse_walk_tile(batch_rate, d, n_rows, width) if walks else None
-        return sparse_scatter_path(d, task_rows(n_rows) * width, walk)
-
     step.gather_path = gather_path
-    step.scatter_path = scatter_path
+    step.scatter_path = lambda n_rows, width: sparse_scatter_path(
+        d, task_rows(n_rows) * width)
+    step.sorted_pairs = lambda n_rows, width: sparse_sorted_pairs(
+        d, task_rows(n_rows) * width)
     return _counts_rows(step, task_rows)
 
 
@@ -770,9 +772,11 @@ def _sparse_compacted_gradient(cols, vals, y, w, sub, batch_rate, grad_sum,
     scatter downstream sees ``(capacity, live_width)`` at the most).  A
     sample of a shard stored in lane tiles, whose rows are of unequal
     length, is WALKED (``gradients.walk_tile``, chosen from the shape):
-    the model's gather and the scatter-add take it in ``(R, C)`` blocks,
-    each row tile up to its last non-zero, and never see the slots behind
-    it nor the unfilled tail of the capacity.  The rows'
+    the model's gather takes it in ``(R, C)`` blocks, each row tile up to
+    its last non-zero, and never sees the slots behind it nor the unfilled
+    tail of the capacity; ``grad_sum`` is told so, and adds the products
+    by a scatter-add a block or sorts them as ONE list, dead pairs last
+    (``gradients.sparse_scatter_path``).  The rows'
     coefficient is ``m - y`` (least squares) or ``sigmoid(m) - y``
     (logistic) of the margin ``m = x . w``, f32 throughout.
     ONE definition, used by the engine worker step AND the fused rounds --
@@ -833,7 +837,7 @@ def make_sparse_asgd_worker_step(batch_rate: float, d: int,
         )
         return g, key
 
-    return _sized_by_capacity(step, batch_rate, d, walks=True)
+    return _sized_by_capacity(step, batch_rate, d)
 
 
 def _sparse_saga_compacted(cols, vals, y, w, alpha, sub, batch_rate,
@@ -895,7 +899,7 @@ def make_sparse_saga_worker_step(batch_rate: float, d: int,
         )
         return g, diff_sel, idx, valid, c_sel, v_sel, key
 
-    return _sized_by_capacity(step, batch_rate, d, walks=False)
+    return _sized_by_capacity(step, batch_rate, d)
 
 
 def make_sparse_saga_commit():
@@ -1360,11 +1364,13 @@ class WorkerPrograms:
     extras: Mapping[str, object]
     #: by worker: the width its shard is read at (``task.dispatch``'s
     #: ``width=``; ``None`` on a dense shard); the non-zeros its step
-    #: samples and the slots it gathers and scatter-adds for them, on
-    #: average (``()`` where the step reads whole rows of a dense shard)
+    #: samples, the slots it gathers for them and the (column, product)
+    #: pairs it sorts to add them into ``g``, on average (``()`` where
+    #: the step reads whole rows of a dense shard)
     widths: Tuple[Optional[int], ...]
     step_nonzeros: Tuple[float, ...]
     step_walked: Tuple[float, ...]
+    step_sorted: Tuple[float, ...]
     #: ``(shard)`` -> the counted flops of one step on it (utils/flops.py)
     task_flops: Callable
     #: ``(shard)`` -> what one evaluation call over it adds to a run's
@@ -1464,18 +1470,23 @@ def _padded_ell_account(shards, step, evaluate, batch_rate, d, live,
         widths=tuple(widths),
         step_nonzeros=tuple(batch_rate * s.nnz for s in shards),
         step_walked=tuple(walked),
+        step_sorted=tuple(
+            float(step.sorted_pairs(s.size, lw))
+            for s, lw in zip(shards, widths)),
         task_flops=lambda shard: _flops.sparse_task_flops(
             step.task_rows(shard.size), shard.shape[1]),
         eval_account=eval_account,
         eval_stack_rows=evaluate.snapshots_per_call,
         # each step's packed sample at its shard's width, columns and
         # values, the model values gathered for them, and the pair of
-        # keys and products the scatter-add sorts (five f32-sized arrays
-        # of capacity x width; compiled for a described v5e, a 21,875 x
-        # 16,384 webspam shard's step holds 338 MB, PR 39).  Nothing at
-        # 11 to 39 slots a row (criteo: 116 MB a step; the pairs its sum
-        # by sorted segments sorts lie in VMEM, PR 52); a third of a GB
-        # a step at thousands
+        # keys and products its sum sorts (five f32-sized arrays of
+        # capacity x width; compiled for a described v5e, a 21,875 x
+        # 16,384 webspam shard's one-shot step held 338 MB, PR 39; the
+        # 16,406 x 16,384 one's holds 130 MB, 8.0 B a slot, since its
+        # walked sample is one sorted list, PR 54: the sort works in the
+        # sample's own buffers).  Nothing at 11 to 39 slots a row
+        # (criteo: 116 MB a step; the pairs its sum by sorted segments
+        # sorts lie in VMEM, PR 52); a sixth of a GB a step at thousands
         workspace_bytes=sum(20 * c * k for c, k in zip(caps, stored)),
     )
 
@@ -1542,6 +1553,7 @@ def worker_programs(ds, batch_rate: float, loss: str = "least_squares",
                     batch_rate if path == "onepass_tiles" else None),
             },
             widths=(None,) * len(shards), step_nonzeros=(), step_walked=(),
+            step_sorted=(),
             task_flops=lambda shard: _flops.dense_task_flops(
                 step.task_rows(shard.size), shard.shape[1]),
             eval_account=lambda shard: {"eval_blocks": 1},

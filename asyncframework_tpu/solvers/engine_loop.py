@@ -839,7 +839,8 @@ class EngineRun:
         programs = self.solver._programs
         for name, per_step in (
                 ("nonzero_slots_per_step_mean", programs.step_nonzeros),
-                ("walked_slots_per_step_mean", programs.step_walked)):
+                ("walked_slots_per_step_mean", programs.step_walked),
+                ("sorted_pairs_per_step_mean", programs.step_sorted)):
             if per_step and sum(by_worker):
                 extras[name] = sum(
                     c * z for c, z in zip(by_worker, per_step)

@@ -147,3 +147,18 @@ def tiny_problem():
     w_true = rs.normal(size=(d,)).astype(np.float32)
     y = (X @ w_true + 0.01 * rs.normal(size=(n,))).astype(np.float32)
     return X, y, w_true
+
+
+@pytest.fixture()
+def segments_interpreted(monkeypatch):
+    """A step traced as on a TPU adds its products by sorted segments
+    where ``gradients.sparse_scatter_path`` says so (ISSUE 52): a Pallas
+    kernel, which the CPU runs interpreted.  The test hands the kernel
+    that argument; the step's program is the TPU's otherwise."""
+    import functools
+
+    from asyncframework_tpu.ops import pallas_kernels
+
+    monkeypatch.setattr(
+        pallas_kernels, "segment_tiles_sum", functools.partial(
+            pallas_kernels.segment_tiles_sum, interpret=True))

@@ -206,8 +206,10 @@ def test_the_chooser_answers_from_backend_and_shapes(
         # kdd2012: 236,640 x 11 slots over 54,686,452 columns, 195 a tile
         (True, 54_686_452, 236_640 * 11, None, jnp.float32, "scatter"),
         (False, 54_686_452, 236_640 * 11, None, jnp.float32, "scatter"),
-        # webspam: the sample is walked in blocks, whatever it holds
-        (True, 16_609_143, 992 * 16_384, (64, 256), jnp.float32, "scatter"),
+        # webspam: a WALKED sample's pairs in one list (ISSUE 54), held to
+        # the same constant: 4,008 slots a tile of its 4,055 on the widest
+        # shard, 407 on the narrowest, which keeps a scatter-add a block
+        (True, 16_609_143, 992 * 16_384, (64, 256), jnp.float32, "segments"),
         (True, 16_609_143, 992 * 1_664, (128, 512), jnp.float32, "scatter"),
         (False, 16_609_143, 992 * 16_384, (64, 256), jnp.float32, "scatter"),
         # the constant to the slot: 245 tiles of 1,024 slots
@@ -226,9 +228,12 @@ def test_the_scatter_chooser_answers_from_backend_and_shapes(
         monkeypatch, on_tpu, d, slots, walk, dtype, want):
     """``gradients.sparse_scatter_path`` at the four sparse cells' shapes
     (ISSUE 52): one number every caller holds, the mean run of slots a
-    tile of ``g``, against ONE constant."""
+    tile of ``g``, against ONE constant; since ISSUE 54 a walked sample
+    (``walk``: the block it is walked in) is asked the same question
+    (``tests/test_sparse_ragged.py`` holds webspam's eight widths)."""
+    del walk  # what the sample's margins are walked in: no longer asked
     monkeypatch.setattr(gradients, "_on_tpu", lambda: on_tpu)
-    assert gradients.sparse_scatter_path(d, slots, walk, dtype) == want
+    assert gradients.sparse_scatter_path(d, slots, dtype) == want
 
 
 def test_a_width_with_no_eight_row_view_keeps_todays_expression():
@@ -239,21 +244,6 @@ def test_a_width_with_no_eight_row_view_keeps_todays_expression():
     m = gradients.sparse_margins(c, v, w)
     np.testing.assert_array_equal(
         _bits(m), _bits(jnp.sum(v * w[c], axis=1)))
-
-
-@pytest.fixture()
-def segments_interpreted(monkeypatch):
-    """A step traced as on a TPU adds its products by sorted segments
-    where ``gradients.sparse_scatter_path`` says so (ISSUE 52): a Pallas
-    kernel, which the CPU runs interpreted.  The test hands the kernel
-    that argument; the step's program is the TPU's otherwise."""
-    import functools
-
-    from asyncframework_tpu.ops import pallas_kernels
-
-    monkeypatch.setattr(
-        pallas_kernels, "segment_tiles_sum", functools.partial(
-            pallas_kernels.segment_tiles_sum, interpret=True))
 
 
 def test_where_the_chooser_says_rows8_the_helper_runs_it(

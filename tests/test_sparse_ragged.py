@@ -386,22 +386,58 @@ def _walk_case(case):
         return _hand_shard(ascending, K), (16, 128), None
     if case == "one-lane-tile":
         return _hand_shard(np.sort(rs.integers(1, 129, n)), 128), (8, 512), None
+    if case == "a-column-twice-in-a-row":  # and in rows of one block
+        s = _hand_shard(ascending, K)
+        cols = np.array(s.cols)
+        cols[:, 5] = cols[:, 2]
+        cols[1::2, 1] = cols[0:-1:2, 1]
+        s.cols = jnp.asarray(cols)
+        return s, (8, 128), None
+    if case == "column-0-live":  # the column the dead slots are stored under
+        s = _hand_shard(ascending, K)
+        cols = np.array(s.cols)
+        cols[::3, 0] = 0
+        cols[:, 4] = np.where(ascending > 4, D - 1, 0)
+        s.cols = jnp.asarray(cols)
+        return s, (8, 128), None
     raise ValueError(case)
+
+
+@pytest.fixture(params=["scatter", "segments"])
+def sum_form(request, monkeypatch):
+    """The two programs that add a walked sample's products into ``g``
+    (``gradients.sparse_scatter_path``): the CPU's walked scatter-adds, and
+    the TPU's sum by sorted segments, traced as on a TPU with the kernel
+    interpreted (``conftest.segments_interpreted``; the jitted functions
+    of the modules forget what they traced under it)."""
+    if request.param == "segments":
+        monkeypatch.setattr(gradients, "_on_tpu", lambda: True)
+        request.getfixturevalue("segments_interpreted")
+    yield request.param
+    if request.param == "segments":
+        jax.clear_caches()
 
 
 @pytest.mark.parametrize("case", [
     "ascending", "shuffled", "zero-inside-a-row", "a-row-fills-K",
-    "overflow", "unfilled-last-tile", "one-lane-tile"])
-def test_the_walked_step_gives_the_references_gradient(monkeypatch, case):
+    "overflow", "unfilled-last-tile", "one-lane-tile",
+    "a-column-twice-in-a-row", "column-0-live"])
+def test_the_walked_step_gives_the_references_gradient(
+        monkeypatch, case, sum_form):
     """A sample of a shard stored in lane tiles is WALKED (ISSUE 40): the
-    model's gather and the scatter-add take it in ``(R, C)`` blocks, each
-    row tile up to its last non-zero.  Every non-zero is still gathered
-    and added exactly once: the step's ``g`` is
+    model's gather takes it in ``(R, C)`` blocks, each row tile up to its
+    last non-zero, and so does the scatter-add where the products are
+    added by one (the CPU); on the TPU they are added by sorted segments
+    (ISSUE 54): the ``capacity x K`` pairs in ONE list, a pair whose
+    product is 0 (behind a row's end, in the unfilled tail, a zero inside
+    a row) under the column beyond every tile.  Every non-zero is still
+    gathered and added exactly once: the step's ``g`` is
     ``reference.full_gradient``'s under the step's own Bernoulli draw, to
     the tolerance the step on a ragged shard is held to above, whatever
     the order of the rows, with a zero inside a row, a row that fills the
-    width, last blocks pulled back, a capacity that overflows and tiles
-    that hold nothing."""
+    width, last blocks pulled back, a capacity that overflows, tiles that
+    hold nothing, a column drawn twice in a row and in two rows of a
+    block, and column 0 (what a dead slot is stored under) live."""
     s, (R, C), cap = _walk_case(case)
     b, K = 0.1, s.cols.shape[1]
     for tile in ("SPARSE_WALK_TILE", "SPARSE_WALK_TILE_HBM"):
@@ -411,6 +447,7 @@ def test_the_walked_step_gives_the_references_gradient(monkeypatch, case):
     cap = steps.sparse_step_capacity(b, s.size)
     assert steps.sparse_walk_tile(b, D, s.size, K) == (R, min(C, K))
     step = steps.make_sparse_asgd_worker_step(b, D, "logistic")
+    assert step.scatter_path(s.size, K) == sum_form
     w = jnp.asarray(np.random.default_rng(7).standard_normal(D), jnp.float32)
     key = jax.random.PRNGKey(5)
     g, _key = step(s.cols, s.vals, s.y, w, key)
@@ -460,6 +497,51 @@ def test_a_sample_stored_in_sublane_tiles_is_read_whole():
     assert gradients.walk_tile(8, 128) == (8, 128)
 
 
+#: the four sparse cells' steps as ``(d, packed rows, width read, walked)``
+CRITEO, KDD2012, WEBSPAM = 1_000_000, 54_686_452, 16_609_143
+WEBSPAM_WIDTHS = (1_664, 2_176, 2_688, 3_200, 3_840, 4_736, 6_272, 16_384)
+
+
+@pytest.mark.parametrize("d,rows,width,want", [
+    (CRITEO, 145_472, 39, "segments"),   # 5.67M pairs, ASGD
+    (CRITEO, 29_656, 39, "segments"),    # 1.16M pairs, ASAGA
+    (KDD2012, 236_640, 11, "scatter"),   # 195 a tile: the constant
+    # webspam's eight walked samples: 407, 532, 658, 783 and 939 slots a
+    # tile of the list keep a scatter-add a block; 1,159, 1,534 and 4,008
+    # sort ONE list
+    *[(WEBSPAM, 992, k, "scatter") for k in WEBSPAM_WIDTHS[:5]],
+    *[(WEBSPAM, 992, k, "segments") for k in WEBSPAM_WIDTHS[5:]],
+    # the bound to the slot: 1,024 a tile of 4,055 tiles
+    (WEBSPAM, 4_055, 1_024, "segments"),
+    (WEBSPAM, 4_054, 1_024, "scatter"),
+], ids=lambda v: str(v))
+def test_the_sum_is_chosen_from_the_four_cells_shapes(
+        monkeypatch, d, rows, width, want):
+    """``gradients.sparse_scatter_path`` over the steps the four sparse
+    cells run (ISSUE 54): criteo's two by sorted segments, kdd2012's by
+    its scatter-add, webspam's three widest shards by sorted segments and
+    its five narrowest by their blocks' scatter-adds, each from the
+    backend, the dtype, the list's length and ``d`` against ONE constant
+    (``SPARSE_SEGMENT_TILE_SLOTS``: held to kdd2012's memory and to the
+    set-up a kernel's program costs webspam, not to a break-even); the
+    CPU keeps the scatter-add everywhere.  The next change to the chooser
+    cannot move a cell unseen."""
+    assert gradients.SPARSE_SEGMENT_TILE_SLOTS == 1_024
+    assert gradients.sparse_scatter_path(d, rows * width) == "scatter"
+    assert gradients.sparse_sorted_pairs(d, rows * width) == 0
+    monkeypatch.setattr(gradients, "_on_tpu", lambda: True)
+    assert gradients.sparse_scatter_path(d, rows * width) == want
+    pairs = gradients.sparse_sorted_pairs(d, rows * width)
+    if want == "scatter":
+        assert pairs == 0
+    else:  # the whole list, in blocks of the kernel's DMA
+        assert rows * width <= pairs < rows * width + 8_192
+        assert pairs % 8_192 == 0
+    for dtype in (jnp.bfloat16, jnp.float64):
+        assert gradients.sparse_scatter_path(
+            d, rows * width, dtype) == "scatter"
+
+
 def test_walked_slots_are_what_a_seeded_step_walks(monkeypatch):
     """``extras["walked_slots_per_step_mean"]`` is reckoned from the
     shards' own row lengths, on the host: on the widest shard (stored in
@@ -503,6 +585,59 @@ def test_walked_slots_are_what_a_seeded_step_walks(monkeypatch):
                devices=jax.devices()[:1]).run_sync()
     assert res.extras["walked_slots_per_step_mean"] == (
         res.extras["live_slots_per_step"])
+
+
+def test_sorted_pairs_are_what_a_seeded_steps_list_holds(
+        monkeypatch, sum_form):
+    """``extras["sorted_pairs_per_step_mean"]`` (ISSUE 54), beside
+    ``walked_slots_per_step_mean``: the (column, product) pairs a mean
+    accepted step SORTS, from the host's integers.  Traced as on a TPU, a
+    worker's count is the length of the ONE list its step's program sorts
+    and hands the kernel, the whole ``capacity x width`` in blocks of
+    8,192, on a walked shard and on one stored in sublane tiles alike; a
+    step that adds by a scatter-add sorts none, and on the CPU every step
+    does.  A lockstep run accepts every worker once a round, so its mean
+    is the workers' mean, to the digit."""
+    ds = _ragged(n=2_054, workers=4)
+    solver = ASGD(ds, None, _cfg(num_workers=4, num_iterations=3),
+                  devices=jax.devices()[:1])
+    programs = solver._programs
+    w = jnp.zeros(ds.d, jnp.float32)
+    assert len(programs.step_sorted) == 4
+    for wid, said in enumerate(programs.step_sorted):
+        s = ds.shard(wid)
+        slots = solver._task_rows(s.size) * _read(s)
+        text = str(jax.make_jaxpr(programs.step)(
+            *s.operands, w, jax.random.PRNGKey(wid)))
+        if solver._step.scatter_path(s.size, _read(s)) == "scatter":
+            assert said == 0 and "segment_tiles_sum" not in text
+            assert "scatter-add" in text or "scatter_add" in text
+            continue
+        assert sum_form == "segments"
+        assert said == -(-slots // 8_192) * 8_192
+        # the pair the sort takes and the rows of 128 the kernel reads
+        assert f"i32[{int(said)}]" in text and f"f32[{int(said)}]" in text
+        assert f"i32[{int(said) // 128},128]" in text
+        assert "scatter-add" not in text and "scatter_add" not in text
+    if sum_form == "scatter":
+        assert programs.step_sorted == (0.0,) * 4
+    else:
+        assert programs.extras["sparse_scatter_path"] in (
+            "segments", "scatter+segments")
+        assert max(programs.step_sorted) > 0
+    ex = solver.run_sync().extras
+    assert ex["accepted_by_worker_min"] == ex["accepted_by_worker_max"] == 3
+    assert ex["sorted_pairs_per_step_mean"] == pytest.approx(
+        sum(programs.step_sorted) / 4)
+    if sum_form == "segments":  # list length over non-zeros, from any run
+        assert ex["sorted_pairs_per_step_mean"] > (
+            ex["nonzero_slots_per_step_mean"])
+        return
+    dense = np.random.default_rng(0).standard_normal((64, 8)).astype("f4")
+    res = ASGD(dense, dense[:, 0].copy(), _cfg(
+        num_workers=4, num_iterations=8, loss="least_squares", gamma=0.1),
+        devices=jax.devices()[:1]).run()
+    assert "sorted_pairs_per_step_mean" not in res.extras
 
 
 # ------------------------------------------------------ (c) the engine's run

@@ -785,26 +785,37 @@ def test_ragged_sparse_step_walks_the_sample_in_blocks(
     single-operand sort packs the row keys, as since PR 33.
 
     Since ISSUE 40 the packed sample is WALKED (``gradients.walk_tile``):
-    the model's gather and the scatter-add sit in loops over row tiles and,
-    inside, over that tile's chunks, and take ``(R, C)`` blocks, so no
-    gather, sort or scatter of the sample's ``capacity x K`` slots is left
-    (the parent sorted 16,252,928 pairs in front of ONE scatter on the
-    widest shard); a block is large enough for the compiler to sort ITS
-    pairs in front of its scatter-add (the unsorted one costs seven times
-    as much a slot on the chip).  The ``(d,)`` accumulator is the loops'
-    carry and is updated IN PLACE: a copy of it inside them would be 66 MB
-    a block.  The model (66 MB, ``d % 8 == 7``: no eight-row view, over
-    ``SPARSE_ELEMENTS_BYTES``) is still read a lane row an index, no
-    element of it gathered alone; its lane rows are in VMEM through the
-    margins' loops.
+    the model's gather sits in loops over row tiles and, inside, over that
+    tile's chunks, and takes ``(R, C)`` blocks; the model (66 MB, ``d % 8
+    == 7``: no eight-row view, over ``SPARSE_ELEMENTS_BYTES``) is read a
+    lane row an index, no element of it gathered alone, its lane rows in
+    VMEM through the margins' loops.  How the products are added into
+    ``g`` is ``gradients.sparse_scatter_path``'s, from the list's length
+    (ISSUE 54), and the program is held to both of its answers:
 
-    WHERE the accumulator lives is what the block's size is chosen by
-    (``gradients.walk_accumulator_resident``), and the program is held to
-    the rule's two halves: on the widest shard (1.07 GB an array) it is in
-    VMEM (``S(1)``) through the scatter-add's loops and takes blocks of 64
-    x 256 slots; the narrowest shard's arrays (109 MB each) fit VMEM, the
-    compiler prefetches one of them there across programs, the accumulator
-    stays in HBM and the blocks are 128 x 512."""
+    THE WIDEST shard (4,008 slots a tile of ``g``) sums by SORTED
+    SEGMENTS, as a sample read whole does since ISSUE 52: the ``capacity x
+    K`` (column, product) pairs in ONE list, ONE two-operand sort of it
+    beside the single-operand sort of the row keys, the one custom call
+    of ``pallas_kernels.segment_tiles_sum``, and NO scatter; no ``f32[d]``
+    is a loop's carry and none is copied: ``g`` is written once, a tile at
+    a time, by the kernel.  Until ISSUE 54 its ``(d,)`` accumulator was
+    the carry of the walk's loops, in VMEM, and took a scatter-add a block
+    of 64 x 256 slots.
+
+    THE NARROWEST shard (407 a tile: under ``SPARSE_SEGMENT_TILE_SLOTS``,
+    which for a walked sample is held to the set-up a kernel's program
+    costs) keeps that form to the letter: the scatter-add
+    sits in the walk's loops and takes ``(R, C)`` blocks, a block large
+    enough for the compiler to sort ITS pairs in front of its scatter-add,
+    the ``(d,)`` accumulator the loops' carry, updated IN PLACE.  WHERE
+    the accumulator lives is what the block's size is chosen by
+    (``gradients.walk_accumulator_resident``): this shard's arrays (109 MB
+    each) fit VMEM, the compiler prefetches one of them there across
+    programs, the accumulator stays in HBM and the blocks are 128 x 512.
+
+    Either way the temporaries stay under what ``solvers/base.py`` plans a
+    slot."""
     stored, live = RAGGED_SHAPES[shape]
     widths = {s: lw for s, lw in RAGGED_SHAPES.values()}
     (cols, vals, y), spec = _ragged_specs(one_chip, stored)
@@ -817,6 +828,11 @@ def test_ragged_sparse_step_walks_the_sample_in_blocks(
     assert resident == (shape == "widest")
     R, C = steps.sparse_walk_tile(0.05, RAGGED_D, RAGGED_ROWS, live)
     assert (R, C) == ((64, 256) if resident else (128, 512))
+    segments = shape == "widest"
+    assert step.scatter_path(RAGGED_ROWS, live) == (
+        "segments" if segments else "scatter")
+    assert step.sorted_pairs(RAGGED_ROWS, live) == (
+        -(-cap * live // 8_192) * 8_192 if segments else 0)
     compiled = step.lower(cols, vals, y, spec((RAGGED_D,), jnp.float32),
                           spec((2,), jnp.uint32)).compile()
     text = compiled.as_text()
@@ -829,17 +845,14 @@ def test_ragged_sparse_step_walks_the_sample_in_blocks(
         assert re.search(r"\[\d+,\d+\]\{1,0", t), t
     assert not _tall_as_the_ragged_shard(text), _tall_as_the_ragged_shard(text)
 
-    # nothing of the sample's size but the two row gathers: every other
-    # gather, the sort of pairs and the scatter take one block
-    assert f"[{cap * live}]" not in text
     sorts = [t for _n, t, op, _ in instrs if op == "sort"]
     keys = [t for t in sorts if not t.startswith("(")]
     pairs = [t for t in sorts if t.startswith("(")]
     assert len(keys) == 1 and keys[0].startswith(f"s32[{RAGGED_ROWS}]"), sorts
-    assert len(pairs) == 1 and f"s32[{R * C}]" in pairs[0], sorts
     gathers = sorted(t.split("{")[0] for _n, t, op, _ in instrs
                      if op == "gather")
-    assert gathers == sorted([
+    bounds = f"s32[{_segment_tiles(RAGGED_D) + 1}]"  # ``searchsorted``'s
+    assert [t for t in gathers if t != bounds] == sorted([
         f"s32[{cap},{live}]", f"f32[{cap},{live}]", f"f32[{R},{C},128]",
         f"f32[{cap}]"]), gathers
     rows_read = [ln for ln in text.splitlines()
@@ -847,21 +860,32 @@ def test_ragged_sparse_step_walks_the_sample_in_blocks(
     assert len(rows_read) == 2, rows_read
     assert re.search(r"f32\[\d+,128\]\{1,0:T\(8,128\)S\(1\)\}", text)
     scatters = [ln for ln in text.splitlines() if " scatter(" in ln]
-    assert len(scatters) == 1 and "/while/body/" in scatters[0], scatters
-    in_vmem = f" f32[{RAGGED_D}]{{0:T(1024)S(1)}} scatter(" in scatters[0]
-    in_hbm = f" f32[{RAGGED_D}]{{0:T(1024)}} scatter(" in scatters[0]
-    assert (in_vmem, in_hbm) == (resident, not resident), scatters[0]
-    prefetched = [ln for ln in text.splitlines()
-                  if "cross_program_prefetch_index" in ln
-                  and f"[{RAGGED_ROWS},{stored}]" in ln]
-    assert bool(prefetched) == (not resident), prefetched
+    carried = [(n, t) for n, t, op, _ in instrs
+               if op == "while" and f"f32[{RAGGED_D}]" in t]
+    if segments:
+        # the ONE sort of pairs is the list's, the custom call takes it as
+        # rows of 128, nothing scatters, no (d,) accumulator is carried
+        _sums_by_sorted_segments(text, cap * live, RAGGED_D)
+        assert not scatters and not carried, (scatters, carried)
+    else:
+        # nothing of the sample's size but the two row gathers: the sort
+        # of pairs and the scatter take one block, in the walk's loops
+        assert f"[{cap * live}]" not in text and bounds not in gathers
+        assert len(pairs) == 1 and f"s32[{R * C}]" in pairs[0], sorts
+        assert len(scatters) == 1 and "/while/body/" in scatters[0], scatters
+        assert f" f32[{RAGGED_D}]{{0:T(1024)}} scatter(" in scatters[0]
+        assert carried
+        prefetched = [ln for ln in text.splitlines()
+                      if "cross_program_prefetch_index" in ln
+                      and f"[{RAGGED_ROWS},{stored}]" in ln]
+        assert prefetched
     # the accumulator is copied nowhere, so not inside the loops either
     assert not [(n, t) for n, t, op, _ in instrs
                 if op == "copy" and t.startswith(f"f32[{RAGGED_D}]")]
-    # the packed sample twice over (solvers/base.py plans 20 B a slot) and
-    # one block's lane rows
+    # the packed sample and its list (solvers/base.py plans 20 B a slot)
     temp = compiled.memory_analysis().temp_size_in_bytes
-    assert temp < 24 * cap * stored + 32e6, f"{temp} bytes of temporaries"
+    assert temp < 20 * cap * stored + (0 if segments else 32e6), (
+        f"{temp} bytes of temporaries")
 
 
 @pytest.mark.parametrize("shape", sorted(RAGGED_SHAPES))
