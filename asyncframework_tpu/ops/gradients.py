@@ -712,11 +712,16 @@ def sparse_residual(
 #: 164; 2,603,040 pairs with uniform columns into 4,000,000 columns (2,665
 #: a tile) 18.3 / 5.2, into 16,000,000 (666) 27.0 / 6.7, into kdd2012's
 #: 54,686,452 (195) 27.2 / 11.8.  The constant is NOT the break-even,
-#: which lies under 195: it keeps kdd2012's cell, whose faster step would
-#: buy snapshots its 14.15 of 16 GB have no room for, on the program it
-#: was admitted with until a ``benchmark`` PR has moved its
-#: ``printer_freq`` (ROADMAP Speed 1(a), Design 2)
-SPARSE_SEGMENT_TILE_SLOTS = 1_024
+#: which lies under 195 (between 61 and 102 for a walked sample): it
+#: stands between kdd2012's 195 slots a tile and the 407 of webspam's
+#: narrowest shard, and what it still holds is kdd2012's cell alone,
+#: whose faster step would buy snapshots its 14.15 of 16 GB have no room
+#: for, on the program it was admitted with until a ``benchmark`` PR has
+#: moved its ``printer_freq`` (ROADMAP Speed 1(a), Design 2).  Until PR 57
+#: it stood at 1,024 and held webspam's five narrowest shards too, by the
+#: set-up a kernel's program cost a process; a step is now built once a
+#: machine (``ops/program_store.py``)
+SPARSE_SEGMENT_TILE_SLOTS = 256
 
 
 def sparse_scatter_path(d: int, slots: int, dtype=jnp.float32) -> str:
@@ -740,23 +745,23 @@ def sparse_scatter_path(d: int, slots: int, dtype=jnp.float32) -> str:
 
     A WALKED sample (the blocks of a shard stored in lane tiles,
     webspam's) is held to the same bound since PR 54, ``slots`` its whole
-    ``capacity x width`` list: webspam's three widest shards (1,159,
-    1,534 and 4,008 a tile of 4,055) sort ONE list, its five narrowest
-    (407 to 939) add a block of the walk at a time.  For it too the bound
-    is NOT the break-even, which the chip read between 61 and 102 slots a
-    tile (PERF.md section 6, PR 54; the first rows of one packed sample,
+    ``capacity x width`` list: all eight of webspam's shards (407, 532,
+    658, 783, 939, 1,159, 1,534 and 4,008 a tile of 4,055) sort ONE list
+    since PR 57; a shorter list adds a block of the walk at a time.  For
+    it too the bound is NOT the break-even, which the chip read between
+    61 and 102 slots a tile (PERF.md section 6, PR 54; the first rows of one packed sample,
     ms alone, walked scatter-adds / segments: 1,664 slots wide 992 rows,
     407 a tile, 12.59 / 4.68, 248 rows (102) 3.50 / 3.03, 128 (53) 1.94 /
     2.89; 3,840 wide 992 rows (939) 29.67 / 7.78, 128 (121) 4.57 / 3.06,
     64 (61) 2.62 / 2.79; 16,384 wide 992 rows (4,008) 79.23 / 29.62, 64
-    (259) 4.57 / 3.60).  What holds it is the SET-UP: every program that
-    holds the kernel costs a process 0.64 s of Python before its first
-    step (the kernel traced and lowered by Mosaic once a step SHAPE); all
-    eight of webspam's shapes on the segments read 59.8 updates/s for
-    28.4 and ``setup_s`` +14%, where the benchmark refuses a PR at +10%.
-    Three such programs fit that bound with room; the other five wait
-    until a process pays a step's tracing once a machine (ROADMAP Speed
-    1(a)(ii)).  The CPU and every other dtype keep the scatter-add.
+    (259) 4.57 / 3.60).  What held five of webspam's shards off it until
+    PR 57 was the SET-UP: every program that holds the kernel costs 0.64 s
+    of Python before its first step (the kernel traced and lowered by
+    Mosaic once a step SHAPE), and all eight shapes on the segments read
+    59.8 updates/s for 28.4 and ``setup_s`` +14%, where the benchmark
+    refuses a PR at +10%.  A step now pays its tracing once a MACHINE
+    (``steps._sized_by_capacity``), so the bound answers for kdd2012's
+    memory alone.  The CPU and every other dtype keep the scatter-add.
     """
     if not (_on_tpu() and jnp.dtype(dtype) == jnp.dtype(jnp.float32)):
         return "scatter"

@@ -70,6 +70,7 @@ from asyncframework_tpu.ops.gradients import (
     walk_accumulator_resident,
     walk_tile,
 )
+from asyncframework_tpu.ops.program_store import LoadedByShape
 
 
 # ---------------------------------------------------------------- builders
@@ -660,8 +661,25 @@ def sparse_walked_slots(batch_rate: float, d: int, n_rows: int, width: int,
     return float(rows * chunk * np.sum(-(-longest // chunk)))
 
 
-def _sized_by_capacity(step, batch_rate: float, d: int):
-    """A compacted sparse step's size, for who asks the step it runs:
+def _sized_by_capacity(step, factory: str, batch_rate: float, d: int,
+                       **static):
+    """A compacted sparse step as the engine calls it, and its size.
+
+    The step is built once a MACHINE: the jitted ``step`` goes behind
+    :class:`program_store.LoadedByShape`, which loads the executable of a
+    call's shape from the store beside the compile cache under a key that
+    needs no trace (the ``factory``'s name, ``batch_rate``, ``d`` and its
+    other ``static`` arguments, the operands, the versions, the source),
+    and traces, compiles and stores it where there is none.
+    These steps alone: a solver has one a shard SHAPE (eight over
+    webspam's shards), each 0.5 to 1.2 s of tracing and lowering with the
+    device idle, and tasks of 8 to 330 ms, beside which a call of the
+    loaded executable costs the host what ``jit``'s does (v5e, PERF.md
+    section 6, PR 57); the dense steps, the applies and the evaluations
+    have one shape each and stay on ``jit``.
+    ``step.counts()``: what this object loaded, built and failed to load.
+
+    Its size, for who asks the step it runs:
     ``step.task_rows(n_rows)``, the rows its compaction holds
     (:func:`_counts_rows`), and what the two choosers say of the sample
     it packs from ``n_rows`` rows read ``width`` slots wide (the shard's
@@ -674,6 +692,9 @@ def _sized_by_capacity(step, batch_rate: float, d: int):
     (``gradients.sparse_sorted_pairs``: the pairs that program sorts), the
     solvers' ``extras["sparse_gather_path"]``, ``["sparse_scatter_path"]``
     and ``["sorted_pairs_per_step_mean"]``."""
+    step = LoadedByShape(step, factory,
+                         dict(batch_rate=batch_rate, d=d, **static))
+
     def task_rows(n_rows):
         return sparse_step_capacity(batch_rate, n_rows)
 
@@ -837,7 +858,8 @@ def make_sparse_asgd_worker_step(batch_rate: float, d: int,
         )
         return g, key
 
-    return _sized_by_capacity(step, batch_rate, d)
+    return _sized_by_capacity(step, "sparse_asgd_worker_step", batch_rate, d,
+                              loss=loss, live_width=live_width)
 
 
 def _sparse_saga_compacted(cols, vals, y, w, alpha, sub, batch_rate,
@@ -899,7 +921,8 @@ def make_sparse_saga_worker_step(batch_rate: float, d: int,
         )
         return g, diff_sel, idx, valid, c_sel, v_sel, key
 
-    return _sized_by_capacity(step, batch_rate, d)
+    return _sized_by_capacity(step, "sparse_saga_worker_step", batch_rate, d,
+                              live_width=live_width)
 
 
 def make_sparse_saga_commit():
@@ -1383,6 +1406,10 @@ class WorkerPrograms:
     #: steps in flight holds beyond the planner's headroom
     eval_stack_rows: Optional[int] = None
     workspace_bytes: int = 0
+    #: ``()`` -> what a padded-ELL step's store did over this record's
+    #: life, for a result's ``extras``: the shapes it loaded, built and
+    #: failed to load (``None``: a step that stays on ``jit``)
+    step_programs: Optional[Callable] = None
     # ``history=True``: ASAGA's own.  ``compacted`` says which PAYLOAD the
     # step returns, ``(diff, idx, valid, c_sel, v_sel)`` or ``(diff,
     # mask)``; ``table_delta`` takes it as the accept path spells it out,
@@ -1488,6 +1515,8 @@ def _padded_ell_account(shards, step, evaluate, batch_rate, d, live,
         # (criteo: 116 MB a step; the pairs its sum by sorted segments
         # sorts lie in VMEM, PR 52); a sixth of a GB a step at thousands
         workspace_bytes=sum(20 * c * k for c, k in zip(caps, stored)),
+        step_programs=lambda: {
+            f"step_programs_{what}": n for what, n in step.counts().items()},
     )
 
 

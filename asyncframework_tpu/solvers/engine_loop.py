@@ -837,6 +837,10 @@ class EngineRun:
                       # what the straggler model injected: zeros at coeff 0
                       **self.delay_model.account(by_worker))
         programs = self.solver._programs
+        if programs.step_programs is not None:
+            # over the solver OBJECT's life: the shapes load in its first
+            # run's warm-up, and every later run reports the same table
+            extras.update(programs.step_programs())
         for name, per_step in (
                 ("nonzero_slots_per_step_mean", programs.step_nonzeros),
                 ("walked_slots_per_step_mean", programs.step_walked),

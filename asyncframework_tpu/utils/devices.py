@@ -90,6 +90,20 @@ def _preload_kernels() -> None:
     ).start()
 
 
+def step_store_dir() -> Optional[str]:
+    """Where the padded-ELL worker steps' executables are stored by a key
+    computed without tracing (``ops/program_store.py``): ``step_programs/``
+    under the directory JAX's persistent compile cache uses in THIS
+    process, so it persists exactly as the cache does (and is cleared
+    with it: ``rm -rf <cache dir>/step_programs``); ``None`` in a process
+    that has no such directory, which then has no store.
+    :func:`cache_entries` keeps counting the cache's own files."""
+    import jax
+
+    path = jax.config.jax_compilation_cache_dir
+    return os.path.join(path, "step_programs") if path else None
+
+
 def cache_entries(path: Optional[str] = None) -> int:
     """Number of cached executables under ``path`` (0 for a missing dir)."""
     path = path or compile_cache_dir()

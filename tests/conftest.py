@@ -82,6 +82,38 @@ def _lockorder_gate():
     lockwatch.assert_no_cycles(include_history=True)
 
 
+@pytest.fixture(autouse=True)
+def _no_step_store(monkeypatch):
+    """Every test is off the store of the padded-ELL steps' executables
+    (``ops/program_store.py``): its key is computed WITHOUT tracing, from
+    the factory's arguments, the operands and the source's digest, so it
+    cannot see a module attribute a test replaces (``gradients._on_tpu``,
+    a block size, an interpreted kernel), and a step built under one test's
+    patches would be loaded by the next test of the same shapes.  With no
+    directory the steps are the ``jit`` they always were; a test of the
+    store takes :func:`step_store` below."""
+    from asyncframework_tpu.utils import devices
+
+    monkeypatch.setattr(devices, "step_store_dir", lambda: None)
+
+
+@pytest.fixture()
+def step_store(monkeypatch, tmp_path, no_compile_cache):
+    """A store of this test's own, empty: the directory the padded-ELL
+    steps built from here on load from and write to, on the CPU too
+    (``program_store.serializes_whole`` keeps the CPU off the store,
+    because XLA:CPU serializes an executable it LOADED from the compile
+    cache without its kernels; off that cache every build is compiled
+    afresh, and serializes whole)."""
+    from asyncframework_tpu.ops import program_store
+    from asyncframework_tpu.utils import devices
+
+    root = tmp_path / "step_programs"
+    monkeypatch.setattr(devices, "step_store_dir", lambda: str(root))
+    monkeypatch.setattr(program_store, "serializes_whole", lambda dev: True)
+    return root
+
+
 @pytest.fixture()
 def no_compile_cache():
     """For tests that time a fault (a kill, a silence) against a run that

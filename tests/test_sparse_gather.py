@@ -208,13 +208,13 @@ def test_the_chooser_answers_from_backend_and_shapes(
         (False, 54_686_452, 236_640 * 11, None, jnp.float32, "scatter"),
         # webspam: a WALKED sample's pairs in one list (ISSUE 54), held to
         # the same constant: 4,008 slots a tile of its 4,055 on the widest
-        # shard, 407 on the narrowest, which keeps a scatter-add a block
+        # shard, 407 on the narrowest, over the constant too since ISSUE 57
         (True, 16_609_143, 992 * 16_384, (64, 256), jnp.float32, "segments"),
-        (True, 16_609_143, 992 * 1_664, (128, 512), jnp.float32, "scatter"),
+        (True, 16_609_143, 992 * 1_664, (128, 512), jnp.float32, "segments"),
         (False, 16_609_143, 992 * 16_384, (64, 256), jnp.float32, "scatter"),
-        # the constant to the slot: 245 tiles of 1,024 slots
-        (True, 1_000_000, 245 * 1_024, None, jnp.float32, "segments"),
-        (True, 1_000_000, 245 * 1_024 - 1, None, jnp.float32, "scatter"),
+        # the constant to the slot: 245 tiles of 256 slots
+        (True, 1_000_000, 245 * 256, None, jnp.float32, "segments"),
+        (True, 1_000_000, 245 * 256 - 1, None, jnp.float32, "scatter"),
         (True, 1_000_000, 145_472 * 39, None, jnp.bfloat16, "scatter"),
         (True, 1_000_000, 145_472 * 39, None, jnp.float64, "scatter"),
     ],

@@ -304,7 +304,8 @@ def test_one_step_and_one_evaluation_are_traced_once_a_shard_shape(solved):
     shapes = {s.cols.shape for s in _shards(ds)}
     assert len(shapes) == 8
     solver._warm_hot_path()
-    assert solver._step._cache_size() == len(shapes)
+    # (off the store, as every test here: the step is its jitted function)
+    assert solver._step._jitted._cache_size() == len(shapes)
     assert solver._programs.extras["sparse_step_shapes"] == len(shapes)
     # shards of one shape share one: criteo's and kdd2012's ONE step
     same = SparseShardedDataset.generate_on_device(
@@ -506,27 +507,28 @@ WEBSPAM_WIDTHS = (1_664, 2_176, 2_688, 3_200, 3_840, 4_736, 6_272, 16_384)
     (CRITEO, 145_472, 39, "segments"),   # 5.67M pairs, ASGD
     (CRITEO, 29_656, 39, "segments"),    # 1.16M pairs, ASAGA
     (KDD2012, 236_640, 11, "scatter"),   # 195 a tile: the constant
-    # webspam's eight walked samples: 407, 532, 658, 783 and 939 slots a
-    # tile of the list keep a scatter-add a block; 1,159, 1,534 and 4,008
-    # sort ONE list
-    *[(WEBSPAM, 992, k, "scatter") for k in WEBSPAM_WIDTHS[:5]],
-    *[(WEBSPAM, 992, k, "segments") for k in WEBSPAM_WIDTHS[5:]],
-    # the bound to the slot: 1,024 a tile of 4,055 tiles
-    (WEBSPAM, 4_055, 1_024, "segments"),
-    (WEBSPAM, 4_054, 1_024, "scatter"),
+    # webspam's eight walked samples, 407, 532, 658, 783, 939, 1,159,
+    # 1,534 and 4,008 slots a tile of the list: all sort ONE list since
+    # ISSUE 57 (the five narrowest kept a scatter-add a block until a
+    # step was built once a machine)
+    *[(WEBSPAM, 992, k, "segments") for k in WEBSPAM_WIDTHS],
+    # the bound to the slot: 256 a tile of 4,055 tiles
+    (WEBSPAM, 4_055, 256, "segments"),
+    (WEBSPAM, 4_054, 256, "scatter"),
 ], ids=lambda v: str(v))
 def test_the_sum_is_chosen_from_the_four_cells_shapes(
         monkeypatch, d, rows, width, want):
     """``gradients.sparse_scatter_path`` over the steps the four sparse
-    cells run (ISSUE 54): criteo's two by sorted segments, kdd2012's by
-    its scatter-add, webspam's three widest shards by sorted segments and
-    its five narrowest by their blocks' scatter-adds, each from the
-    backend, the dtype, the list's length and ``d`` against ONE constant
-    (``SPARSE_SEGMENT_TILE_SLOTS``: held to kdd2012's memory and to the
-    set-up a kernel's program costs webspam, not to a break-even); the
-    CPU keeps the scatter-add everywhere.  The next change to the chooser
-    cannot move a cell unseen."""
-    assert gradients.SPARSE_SEGMENT_TILE_SLOTS == 1_024
+    cells run (ISSUE 54, ISSUE 57): criteo's two by sorted segments,
+    kdd2012's by its scatter-add, all eight of webspam's shards by sorted
+    segments, each from the backend, the dtype, the list's length and
+    ``d`` against ONE constant (``SPARSE_SEGMENT_TILE_SLOTS``: between
+    kdd2012's 195 slots a tile and webspam's narrowest 407, held to
+    kdd2012's memory, not to a break-even); the CPU keeps the scatter-add
+    everywhere.  The next change to the chooser cannot move a cell
+    unseen."""
+    assert gradients.SPARSE_SEGMENT_TILE_SLOTS == 256
+    assert 195 < gradients.SPARSE_SEGMENT_TILE_SLOTS <= 407
     assert gradients.sparse_scatter_path(d, rows * width) == "scatter"
     assert gradients.sparse_sorted_pairs(d, rows * width) == 0
     monkeypatch.setattr(gradients, "_on_tpu", lambda: True)

@@ -771,9 +771,11 @@ def _tall_as_the_ragged_shard(text):
     ]
 
 
-@pytest.mark.parametrize("shape", sorted(RAGGED_SHAPES))
+@pytest.mark.parametrize("shape,bound", [
+    ("narrowest", None), ("widest", None), ("narrowest", 1_024)],
+    ids=["narrowest", "widest", "narrowest-under-the-bound"])
 def test_ragged_sparse_step_walks_the_sample_in_blocks(
-    one_chip, no_compile_cache, on_tpu, shape
+    one_chip, no_compile_cache, on_tpu, monkeypatch, shape, bound
 ):
     """webspam's step (``b`` 0.05 of 16,406 rows, the logistic link, ``d``
     16,609,143) on the narrowest and on the widest shard, built as ASGD
@@ -793,19 +795,21 @@ def test_ragged_sparse_step_walks_the_sample_in_blocks(
     ``g`` is ``gradients.sparse_scatter_path``'s, from the list's length
     (ISSUE 54), and the program is held to both of its answers:
 
-    THE WIDEST shard (4,008 slots a tile of ``g``) sums by SORTED
-    SEGMENTS, as a sample read whole does since ISSUE 52: the ``capacity x
-    K`` (column, product) pairs in ONE list, ONE two-operand sort of it
-    beside the single-operand sort of the row keys, the one custom call
-    of ``pallas_kernels.segment_tiles_sum``, and NO scatter; no ``f32[d]``
+    THE WIDEST shard (4,008 slots a tile of ``g``) and, since ISSUE 57,
+    THE NARROWEST (407: every one of webspam's eight lies over
+    ``SPARSE_SEGMENT_TILE_SLOTS``, 256) sum by SORTED SEGMENTS, as a
+    sample read whole does since ISSUE 52: the ``capacity x K`` (column,
+    product) pairs in ONE list, ONE two-operand sort of it beside the
+    single-operand sort of the row keys, the one custom call of
+    ``pallas_kernels.segment_tiles_sum``, and NO scatter; no ``f32[d]``
     is a loop's carry and none is copied: ``g`` is written once, a tile at
-    a time, by the kernel.  Until ISSUE 54 its ``(d,)`` accumulator was
+    a time, by the kernel.  Until ISSUE 54 the ``(d,)`` accumulator was
     the carry of the walk's loops, in VMEM, and took a scatter-add a block
     of 64 x 256 slots.
 
-    THE NARROWEST shard (407 a tile: under ``SPARSE_SEGMENT_TILE_SLOTS``,
-    which for a walked sample is held to the set-up a kernel's program
-    costs) keeps that form to the letter: the scatter-add
+    A LIST UNDER THE BOUND (the narrowest shard with the constant where
+    it stood until ISSUE 57, 1,024: the form a shorter walked list keeps)
+    holds that form to the letter: the scatter-add
     sits in the walk's loops and takes ``(R, C)`` blocks, a block large
     enough for the compiler to sort ITS pairs in front of its scatter-add,
     the ``(d,)`` accumulator the loops' carry, updated IN PLACE.  WHERE
@@ -816,6 +820,8 @@ def test_ragged_sparse_step_walks_the_sample_in_blocks(
 
     Either way the temporaries stay under what ``solvers/base.py`` plans a
     slot."""
+    if bound is not None:
+        monkeypatch.setattr(gradients, "SPARSE_SEGMENT_TILE_SLOTS", bound)
     stored, live = RAGGED_SHAPES[shape]
     widths = {s: lw for s, lw in RAGGED_SHAPES.values()}
     (cols, vals, y), spec = _ragged_specs(one_chip, stored)
@@ -828,7 +834,7 @@ def test_ragged_sparse_step_walks_the_sample_in_blocks(
     assert resident == (shape == "widest")
     R, C = steps.sparse_walk_tile(0.05, RAGGED_D, RAGGED_ROWS, live)
     assert (R, C) == ((64, 256) if resident else (128, 512))
-    segments = shape == "widest"
+    segments = bound is None
     assert step.scatter_path(RAGGED_ROWS, live) == (
         "segments" if segments else "scatter")
     assert step.sorted_pairs(RAGGED_ROWS, live) == (
