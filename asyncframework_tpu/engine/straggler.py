@@ -21,13 +21,17 @@ variance -- this module only *adds* controlled skew.
 
 The model keeps an account of what it injected (:meth:`DelayModel.account`,
 a run's ``TrainResult.extras``): plain sums on the one thread that builds a
-run's tasks, nothing where it injects nothing.
+run's tasks, nothing where it injects nothing.  Beside it, for a solver that
+keeps per-worker state between a worker's commits (ASAGA's history slices),
+how old that state was when it was replaced, by whether its worker is one of
+the late ones (:meth:`DelayModel.book_history_age`): host integers on the
+updater's thread.
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Dict, List, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -65,6 +69,13 @@ class DelayModel:
     delayed_tasks: int = 0
     sleep_ms: float = 0.0
     sleep_long_tail_ms: float = 0.0
+    #: the age account (:meth:`book_history_age`): the updates between a
+    #: worker's commits of its per-worker state, summed and counted by
+    #: whether the worker is one of :attr:`stragglers`
+    history_age_late_sum: int = 0
+    history_age_late_n: int = 0
+    history_age_healthy_sum: int = 0
+    history_age_healthy_n: int = 0
     _rng: np.random.Generator = field(default=None, repr=False)  # type: ignore
     _normal: List[int] = field(default_factory=list)
     _long_tail: List[int] = field(default_factory=list)
@@ -92,6 +103,12 @@ class DelayModel:
     def long_tail(self, worker_id: int) -> bool:
         """Whether the worker's multipliers are the long tail's."""
         return worker_id in self._long_tail
+
+    def worker_class(self, worker_id: int) -> str:
+        """``long_tail``, ``normal`` (the two late classes) or ``healthy``."""
+        if worker_id in self._long_tail:
+            return "long_tail"
+        return "normal" if worker_id in self.stragglers else "healthy"
 
     def calibrate(self, avg_delay_ms: float, at_update: int = 0,
                   at_s: float = 0.0) -> None:
@@ -127,6 +144,24 @@ class DelayModel:
                 self.sleep_long_tail_ms += delay_ms
         return delay_ms
 
+    def book_history_age(self, worker_id: int, age: int) -> Optional[str]:
+        """An accepted update replaces state of ``worker_id`` that was
+        committed ``age`` accepted updates ago (ASAGA's updater: its
+        history slice; the updater's thread, under the run's state lock).
+        Booked only once somebody is late (before the calibration's end
+        the two classes are one): returns the worker's class where it
+        booked, None where it did not."""
+        if not (self.enabled and self.calibrated):
+            return None
+        booked_as = self.worker_class(worker_id)
+        if booked_as == "healthy":
+            self.history_age_healthy_sum += age
+            self.history_age_healthy_n += 1
+        else:
+            self.history_age_late_sum += age
+            self.history_age_late_n += 1
+        return booked_as
+
     def account(self, accepted_by_worker: Sequence[int]) -> Dict[str, object]:
         """What was injected, as scalars (``accepted_by_worker``: the
         run's accepted updates by worker id).  Every figure is 0 where the
@@ -148,4 +183,9 @@ class DelayModel:
                 accepted_by_worker[w] for w in self.stragglers) if on else 0,
             "accepted_after_calibration":
                 max(0, accepted - self.calibrated_at_update) if on else 0,
+            # 0 from a solver that books no age (ASGD, a sync run)
+            "history_age_late_sum": self.history_age_late_sum,
+            "history_age_late_n": self.history_age_late_n,
+            "history_age_healthy_sum": self.history_age_healthy_sum,
+            "history_age_healthy_n": self.history_age_healthy_n,
         }

@@ -115,9 +115,11 @@ class TraceSpan(Event):
     bytes: Optional[int] = None  # wire bytes of the RPC the span covers
     batch: Optional[int] = None  # updates the timed piece of work served
     calls: Optional[int] = None  # trajectory.eval: stacks evaluated
-    delay_class: Optional[str] = None  # task.delay: normal | long_tail
+    #: task.delay: normal | long_tail; merge.history: those or healthy
+    delay_class: Optional[str] = None
     calls_in: Optional[int] = None  # task.enqueue: PJRT calls in progress
     cpu_ms: Optional[float] = None  # task.enqueue: the thread's CPU time
+    history_age: Optional[int] = None  # merge.history: updates since commit
 
 
 EVENT_TYPES: Dict[str, Type[Event]] = {

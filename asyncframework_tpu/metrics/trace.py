@@ -114,7 +114,13 @@ black hole.  This module makes one update's life observable end to end:
                                   chip that holds a replica of the
                                   model); ``batch`` = results in it
   merge.history    work updater   ASAGA, an accepted result: table     merge.apply
-                                  delta + history commit dispatched
+                                  delta + history commit dispatched;
+                                  once somebody is late, of a worker
+                                  that has committed before:
+                                  ``delay_class`` (``healthy``,
+                                  ``normal``, ``long_tail``) and
+                                  ``history_age``, the accepted updates
+                                  since that commit
   snapshot,        work updater   annotation only                      -
   checkpoint
   trajectory.eval  work main      after the run's fence: the objective -
@@ -331,8 +337,13 @@ class Span:
     #: ``snapshots_per_call`` snapshots it built, one at a time
     calls: Optional[int] = None
     #: a ``task.delay``: which of the straggler model's two multiplier
-    #: classes the sleeper is of, ``normal`` or ``long_tail``
+    #: classes the sleeper is of, ``normal`` or ``long_tail``; a
+    #: ``merge.history`` under the tail: its worker's class, those two or
+    #: ``healthy`` (``DelayModel.worker_class``)
     delay_class: Optional[str] = None
+    #: a ``merge.history`` under the tail: the accepted updates since its
+    #: worker's previous commit, the age of the slice this one replaces
+    history_age: Optional[int] = None
     #: a ``task.enqueue``: the engine's calls into PJRT that were in
     #: progress, on any thread, when this one was made
     #: (``instrumentation.CallsIn``), and the CPU time of the calling thread
@@ -347,7 +358,7 @@ class Span:
              ("b", "start_ms"), ("d", "dur_ms"), ("st", "staleness"),
              ("sm", "staleness_ms"), ("ac", "accepted"), ("by", "bytes"),
              ("n", "batch"), ("c", "calls"), ("dc", "delay_class"),
-             ("ci", "calls_in"), ("cp", "cpu_ms"))
+             ("ci", "calls_in"), ("cp", "cpu_ms"), ("ha", "history_age"))
 
     def to_wire(self) -> dict:
         out = {}
@@ -1007,6 +1018,7 @@ def span_event(span: Span, time_ms: float) -> "object":
         accepted=span.accepted, bytes=span.bytes, batch=span.batch,
         calls=span.calls, delay_class=span.delay_class,
         calls_in=span.calls_in, cpu_ms=span.cpu_ms,
+        history_age=span.history_age,
     )
 
 

@@ -155,6 +155,11 @@ class ASAGA(EngineSolver):
         # the accept path's dispatches are PJRT calls like a step's:
         # counted among the run's calls in progress
         calls_in = run.calls_in
+        # k at each worker's last commit, kept only where somebody will be
+        # late: the age of the slice a commit replaces, by class of worker
+        # (DelayModel.book_history_age; a coeff 0 run pays the one test)
+        aged = run.delay_model if run.delay_model.enabled else None
+        last_commit_k: Dict[int, int] = {}
 
         def history_fields(ab) -> Dict:
             with hot_lock:
@@ -209,7 +214,7 @@ class ASAGA(EngineSolver):
                             shard = self._recovery.shard(res.worker_id)
                             t_hist = time.perf_counter_ns()
                             with trace.span(trace.MERGE_HISTORY,
-                                            tuple(uts)), hot_lock, \
+                                            tuple(uts)) as hist, hot_lock, \
                                     calls_in:
                                 wid = res.worker_id
                                 alpha_cur = alpha[wid]
@@ -261,6 +266,15 @@ class ASAGA(EngineSolver):
                                         alpha_cur, diff, mask
                                     )
                                 commits[wid] += 1
+                                if aged is not None:
+                                    before = last_commit_k.get(wid)
+                                    last_commit_k[wid] = k
+                                    if before is not None:
+                                        booked_as = aged.book_history_age(
+                                            wid, k - before)
+                                        if booked_as and uts:
+                                            hist.note(delay_class=booked_as,
+                                                      history_age=k - before)
                             state["history_ns"] += (
                                 time.perf_counter_ns() - t_hist
                             )

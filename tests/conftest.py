@@ -54,6 +54,42 @@ _STALE_BY_POSITION = (
 )
 
 
+#: PR 58 (a ``model_config`` PR: the configuration ``mnist8m-w32-asaga``, its
+#: one cell under the mix ``cloud``, eleven readers) ends four assertions
+#: that hold what is true only until the next cell, in files it may not
+#: edit: the manifest's size (``len(workloads) == 9 and len(configs) == 8``,
+#: twice) and "ONE cell runs under ``cloud``, and ``coeff != 0`` in that
+#: cell alone".  Each with its reason, strictly, for the ``benchmark`` PR
+#: that rewrites them to count by name; everything they hold beyond the
+#: count is held, as it stands, by ``tests/benchmark/
+#: test_bench_saga_cloud.py`` (the three tests named ``..._beyond_the_count``
+#: and the one named in the fourth entry).
+_STALE_BY_COUNT = {
+    "test_bench_step_programs.py::"
+    "test_the_four_sparse_cells_report_it_and_no_dense_one":
+        "asserts nine cells and eight configurations; PR 58 appends the "
+        "tenth and the ninth",
+    "test_bench_lock_clock.py::"
+    "test_the_entries_in_front_of_them_stand_as_they_were":
+        "asserts nine cells and eight configurations; PR 58 appends the "
+        "tenth and the ninth",
+    "test_bench_cloud.py::"
+    "test_the_manifest_appends_one_configuration_one_cell_six_metrics":
+        "asserts one cell under the mix cloud and coeff != 0 in it alone; "
+        "PR 58 appends the second, mnist8m-w32-asaga.cloud",
+    # the fourth is a count of another kind: "criteo-asaga is the ONE cell
+    # that states a history limit".  mnist8m-w32-asaga states
+    # history_drift_limit too (its two readings at its own size are in its
+    # file); test_bench_saga_cloud.py::
+    # test_only_the_two_history_deployments_state_history_limits holds
+    # what it held
+    "test_bench_sparse_asaga.py::"
+    "test_only_the_sparse_history_deployment_states_history_limits":
+        "asserts one cell states a history limit; mnist8m-w32-asaga "
+        "(PR 58) is the second",
+}
+
+
 def pytest_collection_modifyitems(items):
     for item in items:
         if item.nodeid.endswith(_STALE_BY_POSITION):
@@ -62,6 +98,10 @@ def pytest_collection_modifyitems(items):
                        "entry (or last but one); later PRs append behind "
                        "it: see tests/conftest.py",
                 strict=True))
+        for stale, reason in _STALE_BY_COUNT.items():
+            if item.nodeid.endswith(stale):
+                item.add_marker(pytest.mark.xfail(
+                    reason=reason + ": see tests/conftest.py", strict=True))
 
 
 @pytest.fixture(scope="session", autouse=True)

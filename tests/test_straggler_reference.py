@@ -32,7 +32,11 @@ from asyncframework_tpu.solvers.instrumentation import (  # noqa: E402
 ACCOUNT = ("avg_delay_ms", "delay_calibrated_at_update",
            "delay_calibrated_at_s", "straggler_workers", "delayed_tasks",
            "delay_sleep_s", "delay_sleep_long_tail_s",
-           "accepted_from_stragglers", "accepted_after_calibration")
+           "accepted_from_stragglers", "accepted_after_calibration",
+           # ISSUE 58: the age of a worker's history at its commit, by
+           # class (tests/test_asaga_cloud.py)
+           "history_age_late_sum", "history_age_late_n",
+           "history_age_healthy_sum", "history_age_healthy_n")
 EPS_MS = 0.05  # float noise of epoch milliseconds, not a tolerance of order
 
 
