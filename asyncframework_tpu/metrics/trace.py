@@ -108,13 +108,16 @@ black hole.  This module makes one update's life observable end to end:
                                   step was out on that chip
   result.queue     wait ex -> upd ``merge_result`` put -> drained      compute
   merge.queue      work updater   drained -> apply starts (state lock, compute
-                                  tau filter; ASAGA: a cross-chip ``g``
-                                  copy)
+                                  tau filter)
   merge.apply      work updater   the apply dispatch of a drain (one a compute
                                   chip that holds a replica of the
-                                  model); ``batch`` = results in it
+                                  model); ``batch`` = results in it;
+                                  ASAGA: with the history paths of
+                                  those results and their cross-chip
+                                  copies in front of it
   merge.history    work updater   ASAGA, an accepted result: table     merge.apply
-                                  delta + history commit dispatched;
+                                  delta + history commit dispatched
+                                  (no lock held) and published;
                                   once somebody is late, of a worker
                                   that has committed before:
                                   ``delay_class`` (``healthy``,

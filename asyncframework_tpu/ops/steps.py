@@ -1622,3 +1622,50 @@ def dcn_worker_programs(shards, d: int, batch_rate: float,
                 else make_trajectory_loss_eval(loss))
     return DcnPrograms(step, evaluate, sparse_gradients=padded_ell,
                        meshable=not padded_ell)
+
+
+# (ASAGA's fold stands at the END of this file: a program's compile-cache
+# key holds the line numbers of its source, so a function put among the
+# others would send every executable defined below it back to the compiler
+# on every machine, once: ROADMAP Speed 5(b))
+def make_saga_apply_fold(
+    gamma: float, batch_rate: float, n: int, num_workers: int
+):
+    """jit (w, alpha_bar, gs, deltas, m) -> (w', alpha_bar') -- the first
+    ``m`` accepts of an ASAGA drain applied in ONE dispatch.
+
+    ``gs`` and ``deltas`` are tuples of FIXED length (the engine's updater
+    pads a short drain with one cached zero handle to ``num_workers``) and
+    how many of their slots count is data (``m``, a device f32 scalar), so
+    there is one executable whatever the drain's size, as
+    :func:`make_asgd_apply_fold`.  Exactness: the serial accept path is
+    ``w <- w - (gamma / parRecs) g_j - gamma ab; ab <- ab + delta_j / N``, a
+    recurrence in ``(w, ab)`` whose step ``j`` reads ``ab`` as step
+    ``j - 1`` left it and nothing else of the run.  The fold IS that
+    recurrence: it calls what :func:`make_saga_apply` builds (looked up
+    when the fold is built, so whatever stands in for the serial apply
+    stands in here too: ``benchmark/check_saga.py --round-delta``), slot
+    after slot in the serial order inside one program, and a slot past
+    ``m`` selects both carries as they were.  What it may not do is move a
+    step's ``delta`` in front of another step's ``w``: ``delta_j`` is
+    computed by the updater against the table AS COMMIT ``j`` FINDS IT,
+    before this dispatch, so the table's mean after the dispatch is
+    ``ab'`` exactly as after ``m`` serial applies.  Where an accept reused
+    its step's ``g`` for the delta the SAME handle rides in both tuples,
+    so nothing in them is donated (nor may the padding's one buffer be,
+    twice); ``alpha_bar`` is donated as in the serial apply, ``w`` never
+    (an old handle is a model version).
+    """
+    apply_one = make_saga_apply(gamma, batch_rate, n, num_workers,
+                                donate_g=False)
+
+    @functools.partial(jax.jit, donate_argnums=(1,))
+    def apply_fold(w, alpha_bar, gs, deltas, m):
+        live = jnp.arange(len(gs), dtype=jnp.float32) < m
+        for keep, g, delta in zip(live, gs, deltas):
+            w2, ab2 = apply_one(w, alpha_bar, g, delta)
+            w = jnp.where(keep, w2, w)
+            alpha_bar = jnp.where(keep, ab2, alpha_bar)
+        return w, alpha_bar
+
+    return _prof.wrap_dispatch(apply_fold, "kernel.dispatch", "saga_apply_fold")

@@ -17,14 +17,27 @@ reference's driver-controlled ScalarMap merge, as an on-device
 ``where(mask, diff, alpha)``.
 
 Update rule on accept (``SparkASAGAThread.scala:210-213``):
-``w -= gamma * (g/parRecs + alpha_bar)``; ``alpha_bar += g/N``.
+``w -= gamma * (g/parRecs + alpha_bar)``; ``alpha_bar += delta/N``, with
+``delta`` the table's change at the commit (the step's ``g`` where the slice
+the step read still stands, the exact table delta where it does not:
+``steps.make_saga_table_delta``).  The engine's updater merges a DRAIN, as
+ASGD's does (``solvers/asgd.py``): the filter for everything queued under
+one hold of the state lock;
+then, outside it, each accepted result's history path in drain order and ONE
+apply for all of them (``steps.make_saga_apply_fold``: the rule above as a
+recurrence over the drain; a drain of one is the rule itself).  In a drain
+of several the paths hold the slices' lock only to read and to publish; a
+result that came alone keeps it over its path, so that its worker's next
+task, made about then, reads the committed slice.
 Staleness filter quirk preserved: ASAGA accepts iff ``k - staleness <= taw``
-(the ASGD driver tests ``staleness <= taw``) -- see the updater in
-``SparkASAGAThread.scala:184``.
+(the ASGD driver tests ``staleness <= taw``), ``k`` the update's own index --
+see the updater in ``SparkASAGAThread.scala:184``.
 """
 
 from __future__ import annotations
 
+import contextlib
+import itertools
 import operator
 import queue
 import time
@@ -108,6 +121,10 @@ class ASAGA(EngineSolver):
             config.gamma, config.batch_rate, self.ds.n, config.num_workers,
             donate_g=False,
         )
+        # a drain of several accepts: ONE dispatch (the updater in ``run``)
+        self._apply_fold = steps.make_saga_apply_fold(
+            config.gamma, config.batch_rate, self.ds.n, config.num_workers
+        )
 
     #: a task returns ``(g, ...payload..., commits, new_key)``, its step's
     #: outputs around the commit count of the history slice the step read
@@ -148,6 +165,15 @@ class ASAGA(EngineSolver):
         # start: a test patches the module's constants)
         exact_every = (EXACT_SPARSE_DELTA_EVERY if self._compacted
                        else EXACT_DELTA_EVERY)
+        nw, freq = cfg.num_workers, cfg.printer_freq
+        # what the fold takes beside a drain's handles, resident before
+        # the clock starts so that a drain transfers nothing: the ONE zero
+        # handle that pads a short drain's two tuples to the fold's arity,
+        # and every count of live slots as a device scalar (``ASGD.run``)
+        zeros = (jax.device_put(jnp.zeros(self.ds.d, jnp.float32),
+                                self.driver_device),) * nw
+        counts = [jax.device_put(jnp.float32(m), self.driver_device)
+                  for m in range(nw + 1)]
         run.start_monitors(self._history_follows(run, alpha, commits))
         self._warm_hot_path()
         run.start_clock()
@@ -160,11 +186,84 @@ class ASAGA(EngineSolver):
         # (DelayModel.book_history_age; a coeff 0 run pays the one test)
         aged = run.delay_model if run.delay_model.enabled else None
         last_commit_k: Dict[int, int] = {}
+        unlocked = contextlib.nullcontext()
 
         def history_fields(ab) -> Dict:
             with hot_lock:
                 alpha_h = {wid: np.asarray(a) for wid, a in alpha.items()}
             return {"alpha_bar": np.asarray(ab), "alpha": alpha_h}
+
+        def history_path(res, at_k, slot_lock):
+            """One accepted result's history path, update ``at_k`` of the
+            run: the table delta where the slice the step read no longer
+            stands, and the commit, dispatched between two short holds of
+            ``slot_lock`` (the slices' lock; nothing where the caller
+            holds it): the slice and its count read, then published
+            together.  Returns the exact delta (None where the step's
+            ``g`` is it) and what the dispatches read, for the drain to
+            keep until the next one."""
+            wid = res.worker_id
+            payload_in = res.data[1:-1]
+            while True:
+                with slot_lock:
+                    alpha_cur, count = alpha[wid], commits[wid]
+                # No assignment to the slot since the task captured its
+                # slice: the step's g IS the table's change
+                # (make_saga_worker_step) and the shard is not read again.
+                # Else the worker's last result was committed (earlier in
+                # this drain, even), or its shard re-homed, after the task
+                # was made: the exact delta against the slice at commit.  A
+                # resumed run starts like a cold one: the restored slices at
+                # count 0, nothing in flight.  The standing sample, by the
+                # update's OWN index, is on this side too.
+                reuse = (
+                    res.data[-1] == count
+                    and (at_k + 1) % exact_every != 0
+                )
+                delta = None
+                with calls_in:
+                    # a shard re-homed while this result was in flight
+                    # leaves the payload on the old device; normalize onto
+                    # the slice's current home
+                    home = alpha_cur.device
+                    payload = tuple(
+                        jax.device_put(a, home) if a.device != home else a
+                        for a in payload_in
+                    )
+                    # by the PAYLOAD, ASAGA's own format (``_compacted``)
+                    if self._compacted:
+                        diff, idx, valid, c_sel, v_sel = payload
+                        if not reuse:
+                            delta = self._table_delta(
+                                c_sel, v_sel, diff, alpha_cur, idx)
+                        alpha_new = self._commit(alpha_cur, diff, idx, valid)
+                    else:
+                        diff, mask = payload
+                        if not reuse:
+                            # (the recovery's view: a re-homed shard is
+                            # read where it lies now)
+                            delta = self._table_delta(
+                                self._recovery.shard(wid).X, diff, mask,
+                                alpha_cur)
+                        alpha_new = steps.saga_commit_history(
+                            alpha_cur, diff, mask)
+                # the slice and its count TOGETHER: a task's capture reads
+                # both under one hold (``_task_maker``)
+                with slot_lock:
+                    stands = commits[wid] == count
+                    if stands:
+                        alpha[wid] = alpha_new
+                        commits[wid] = count + 1
+                if stands:
+                    return delta, (payload, alpha_cur)
+                # the count moved since the read: the shard re-homed under
+                # this result (``_history_follows``; this thread is the
+                # slot's only other writer).  Again, against the slice as
+                # it now stands.  The dense commit donated ``diff``: the
+                # slice it made holds ``diff`` at every sampled row, which
+                # is all the delta and the commit read of it
+                if not self._compacted:
+                    payload_in = (alpha_new, mask)
 
         def updater():
             clock = inst.updater_clock
@@ -174,147 +273,186 @@ class ASAGA(EngineSolver):
                         break
                 clock.waits()
                 try:
-                    res = ctx.collect_all(timeout=cfg.collect_timeout_s)
+                    results = [ctx.collect_all(timeout=cfg.collect_timeout_s)]
                 except queue.Empty:
                     continue
                 finally:
                     clock.works()
-                # a sampled update (metrics/trace.py; () in an untraced
-                # run): its result.queue and compute end here; merge.queue
-                # is the state lock and the tau filter, merge.apply the
-                # accept path's dispatches: merge.history (the history
+                # the drain takes what is there: every result already
+                # queued, up to the arity the fold below is compiled for
+                # (ASGD's drain, ``solvers/asgd.py``)
+                results.extend(itertools.islice(ctx.drain(), nw - 1))
+                do_save = False
+                # the drain's sampled updates (metrics/trace.py; () in an
+                # untraced run): their result.queue and compute end here;
+                # merge.queue is the state lock and the tau filter,
+                # merge.apply a dispatch below with the history paths of
+                # its own results inside it: merge.history (the history
                 # commit, and the table delta where the slice moved),
                 # cross-chip copies, apply
-                uts = inst.on_drained((res,))
-                g = res.data[0]
-                task_ms = waiting.on_finish(res.worker_id, now_ms())
-                do_save = False
+                uts = inst.on_drained(results)
                 merge_queue = trace.span(trace.MERGE_QUEUE, uts).begin()
+                # ONE hold of the state lock for the drain's bookkeeping,
+                # and no dispatch inside it
                 with state_lock:
-                    state["flops"] += self._task_flops(res.worker_id)
                     k = state["k"]
-                    # the account of model-sized buffers: this result and
-                    # those queued behind it (EngineRun.count_copies)
-                    run.count_copies(1 + ctx.size())
-                    # ASAGA acceptance quirk: k - staleness <= taw
-                    accepted = k - res.staleness <= cfg.taw
-                    merge_queue.end()
-                    if uts:
-                        uts = inst.apply_attrs(((res, accepted),))
-                    t_apply = time.perf_counter_ns()
-                    # The accept path stays INLINE in this frame: its
-                    # temporaries (payload, delta, g) then live until the
-                    # next result overwrites them.  In a helper they die on
-                    # return, under the state lock, while the dispatches
-                    # that read them are still in flight -- measured on the
-                    # CPU rehearsal at a third of the update rate.
-                    with trace.span(trace.MERGE_APPLY, uts,
-                                    batch=int(accepted)):
+                    # the account of model-sized buffers: this drain and
+                    # what has come since (EngineRun.count_copies)
+                    run.count_copies(len(results) + ctx.size())
+                    # never apply past the iteration budget: trim the drain
+                    room = cfg.num_iterations - k
+                    merged = []
+                    live = []
+                    for res in results:
+                        state["flops"] += self._task_flops(res.worker_id)
+                        task_ms = waiting.on_finish(res.worker_id, now_ms())
+                        # ASAGA acceptance quirk: k - staleness <= taw, with
+                        # k the update's OWN index: the accepts in front of
+                        # it in this drain have moved it on
+                        at_k = k + len(live)
+                        accepted = at_k - res.staleness <= cfg.taw
+                        if accepted and len(live) >= room:
+                            continue
+                        merged.append((res, accepted, at_k, task_ms))
                         if accepted:
-                            shard = self._recovery.shard(res.worker_id)
-                            t_hist = time.perf_counter_ns()
-                            with trace.span(trace.MERGE_HISTORY,
-                                            tuple(uts)) as hist, hot_lock, \
-                                    calls_in:
-                                wid = res.worker_id
-                                alpha_cur = alpha[wid]
-                                # a shard re-homed while this result was in
-                                # flight leaves the payload on the old
-                                # device; normalize onto the slice's
-                                # current home
-                                home = alpha_cur.device
-                                payload = tuple(
-                                    jax.device_put(a, home)
-                                    if a.device != home else a
-                                    for a in res.data[1:-1]
-                                )
-                                # No assignment to the slot since the task
-                                # captured its slice: the step's g IS the
-                                # table's change (make_saga_worker_step)
-                                # and the shard is not read again.  Else
-                                # the worker's last result was committed,
-                                # or its shard re-homed (a payload moved
-                                # above is always on this side), after the
-                                # task was made: the exact delta against
-                                # the slice at commit.  A resumed run
-                                # starts like a cold one: the restored
-                                # slices at count 0, nothing in flight.
-                                # The standing sample is on this side too.
-                                reuse = (
-                                    res.data[-1] == commits[wid]
-                                    and (k + 1) % exact_every != 0
-                                )
-                                # by the PAYLOAD, ASAGA's own format
-                                # (``_compacted``), in this ONE frame
-                                if self._compacted:
-                                    diff, idx, valid, c_sel, v_sel = payload
-                                    if not reuse:
-                                        delta = self._table_delta(
-                                            c_sel, v_sel, diff, alpha_cur,
-                                            idx,
-                                        )
-                                    alpha[wid] = self._commit(
-                                        alpha_cur, diff, idx, valid
-                                    )
-                                else:
-                                    diff, mask = payload
-                                    if not reuse:
-                                        delta = self._table_delta(
-                                            shard.X, diff, mask, alpha_cur
-                                        )
-                                    alpha[wid] = steps.saga_commit_history(
-                                        alpha_cur, diff, mask
-                                    )
-                                commits[wid] += 1
-                                if aged is not None:
-                                    before = last_commit_k.get(wid)
-                                    last_commit_k[wid] = k
-                                    if before is not None:
-                                        booked_as = aged.book_history_age(
-                                            wid, k - before)
-                                        if booked_as and uts:
-                                            hist.note(delay_class=booked_as,
-                                                      history_age=k - before)
-                            state["history_ns"] += (
-                                time.perf_counter_ns() - t_hist
-                            )
-                            with calls_in:
-                                if g.device != self.driver_device:
-                                    g = jax.device_put(
-                                        g, self.driver_device)
-                                if reuse:
-                                    state["reused"] += 1
-                                    state["w"], state["ab"] = (
-                                        self._apply_g_is_delta(
-                                            state["w"], state["ab"], g, g
-                                        )
-                                    )
-                                else:
-                                    state["recomputed"] += 1
-                                    if delta.device != self.driver_device:
-                                        delta = jax.device_put(
-                                            delta, self.driver_device
-                                        )
-                                    state["w"], state["ab"] = self._apply(
-                                        state["w"], state["ab"], g, delta
-                                    )
+                            calibrator.record(at_k, task_ms)
+                            live.append(res)
                         else:
                             state["dropped"] += 1
-                    inst.updater_apply_ns += time.perf_counter_ns() - t_apply
-                    if accepted:
-                        state["k"] = k + 1
-                        state["accepted"] += 1
-                        calibrator.record(k, task_ms)
-                        if k % cfg.printer_freq == 0:
-                            with trace.span(trace.SNAPSHOT):
-                                snapshots.append((now_ms(), state["w"]))
-                                inst.on_snapshot(state["accepted"])
-                        do_save = ckpt.should_save(state["k"])
-                        save_k, save_w, save_ab = (
-                            state["k"], state["w"], state["ab"]
+                merge_queue.end()
+                m = len(live)
+                # ONE apply a drain, split only where a snapshot is due (a
+                # dispatch ends ON that update, ``solvers/asgd.py``), made
+                # OUTSIDE the state lock and, in a drain of several, the
+                # slices' lock: this thread alone writes the model,
+                # ``alpha_bar`` and (but for a re-homed shard's hook) the
+                # slices, and the submitter and every executor's handler
+                # take those locks (PERF.md section 6, PR 60).  The
+                # drain's temporaries (payloads, replaced slices, deltas,
+                # g) stay in this frame's lists until the next drain
+                # overwrites them (dropped while the dispatches that read
+                # them were still in flight, on a helper's return under
+                # the state lock, they once cost the CPU rehearsal two
+                # thirds of its update rate).
+                ends = [j + 1 for j in range(-k % freq, m, freq)]
+                if not ends or ends[-1] < m:
+                    ends.append(m)
+                w_new, ab = state["w"], state["ab"]
+                # A result that came ALONE is one the updater is keeping
+                # up with: its worker is being handed its next task about
+                # now (it is available since its result was queued), and
+                # a task made in front of the commit pays the second read
+                # of its shard.  So a drain of one keeps the slices' lock
+                # over its path, as every accept did until PR 60: the
+                # capture (``_task_maker``) waits the commit's one call
+                # out and finds the slice that stands (without it
+                # ``mnist8m-asaga.steady`` recomputed 6.3% of its accepts
+                # for 2.1% and lost 4.7% of its rate: PERF.md section 6,
+                # PR 60).  A drain of several is the updater behind its
+                # queue: those tasks are made already, and the lock would
+                # only stop the executors' handlers and the submitter.
+                if len(results) == 1:
+                    path_lock, slot_lock = hot_lock, unlocked
+                else:
+                    path_lock, slot_lock = unlocked, hot_lock
+                gs, deltas, held = [], [], []
+                history_ns = reused = 0
+                lo = 0
+                for hi in ends:
+                    n = hi - lo
+                    in_it = uts
+                    if uts:
+                        # the sampled updates of THIS dispatch (a dropped
+                        # one rides with the slot it was filtered before,
+                        # or with the last: ``solvers/asgd.py``)
+                        top = hi if hi < m else m + 1
+                        in_it = inst.apply_attrs(
+                            (r, acc) for r, acc, at_k, _ in merged
+                            if lo <= at_k - k < top
                         )
-                # outside the lock, as ever: the event and the counters
-                inst.on_gradient_merged(res, accepted, k, task_ms)
+                    t_apply = time.perf_counter_ns()
+                    with trace.span(trace.MERGE_APPLY, in_it, batch=n):
+                        # the history paths of the dispatch's own results
+                        # go with it, a result at a time in drain order:
+                        # the table and alpha_bar agree at every
+                        # publication below
+                        for res in live[lo:hi]:
+                            wid, at_k = res.worker_id, k + len(gs)
+                            t_hist = time.perf_counter_ns()
+                            with trace.span(trace.MERGE_HISTORY,
+                                            res.trace) as hist:
+                                with path_lock:
+                                    delta, kept = history_path(
+                                        res, at_k, slot_lock)
+                                if aged is not None:
+                                    before = last_commit_k.get(wid)
+                                    last_commit_k[wid] = at_k
+                                    if before is not None:
+                                        booked_as = aged.book_history_age(
+                                            wid, at_k - before)
+                                        if booked_as:
+                                            hist.note(
+                                                delay_class=booked_as,
+                                                history_age=at_k - before)
+                            history_ns += time.perf_counter_ns() - t_hist
+                            g = res.data[0]
+                            with calls_in:
+                                if g.device != self.driver_device:
+                                    g = jax.device_put(g, self.driver_device)
+                                if delta is None:
+                                    # the SAME handle through both tuples
+                                    delta = g
+                                    reused += 1
+                                elif delta.device != self.driver_device:
+                                    delta = jax.device_put(
+                                        delta, self.driver_device)
+                            gs.append(g)
+                            deltas.append(delta)
+                            held.append(kept)
+                        with calls_in:
+                            if n == 1:
+                                # a drain of one: the serial path's
+                                # program, by whether g is the delta too
+                                # (one buffer through two arguments may
+                                # not be donated)
+                                apply_one = (
+                                    self._apply_g_is_delta
+                                    if gs[lo] is deltas[lo] else self._apply)
+                                w_new, ab = apply_one(
+                                    w_new, ab, gs[lo], deltas[lo])
+                            elif n:
+                                w_new, ab = self._apply_fold(
+                                    w_new, ab,
+                                    tuple(gs[lo:hi]) + zeros[n:],
+                                    tuple(deltas[lo:hi]) + zeros[n:],
+                                    counts[n],
+                                )
+                    inst.updater_apply_ns += (
+                        time.perf_counter_ns() - t_apply
+                    )
+                    if n:
+                        inst.apply_dispatches += 1
+                        # what the dispatch made, published together
+                        with state_lock:
+                            state["w"], state["ab"] = w_new, ab
+                            state["k"] = k + hi
+                            state["accepted"] += n
+                            if (k + hi - 1) % freq == 0:
+                                with trace.span(trace.SNAPSHOT):
+                                    snapshots.append((now_ms(), w_new))
+                                    inst.on_snapshot(state["accepted"])
+                    lo = hi
+                if m:
+                    state["history_ns"] += history_ns
+                    state["reused"] += reused
+                    state["recomputed"] += m - reused
+                    # range check: a drain jumping over a checkpoint
+                    # boundary must still save
+                    do_save = ckpt.should_save_range(k, k + m)
+                    save_k, save_w, save_ab = state["k"], w_new, ab
+                # outside the lock, as ever: the events and the counters
+                for res, accepted, at_k, task_ms in merged:
+                    inst.on_gradient_merged(res, accepted, at_k, task_ms)
                 if do_save:
                     with trace.span(trace.CHECKPOINT):
                         run.save(save_k, save_w, **history_fields(save_ab))
@@ -690,13 +828,13 @@ class ASAGA(EngineSolver):
         jit caches per input SHAPE, so every distinct (shard shape, history
         slice size) pair is warmed -- shards differ by one row/sample when
         ``n % num_workers != 0``.  The async accept path uses the table
-        delta and both instances of the apply (an accept takes either
-        side); the sync drain instead accumulates with ``add_grads`` and
-        passes ``acc`` as both g and delta -- each mode warms only what it
-        runs.  Dummies are fresh buffers, so donated arguments never touch
+        delta, both instances of the apply (a drain of one accept takes
+        either side) and the fold (a drain of several); the sync drain
+        instead accumulates with ``add_grads`` and passes ``acc`` as both g
+        and delta -- each mode warms only what it runs.  Dummies are fresh buffers, so donated arguments never touch
         live state."""
         apply = apply if apply is not None else self._apply
-        d = self.ds.d
+        d, nw = self.ds.d, self.cfg.num_workers
         drv = self.driver_device
         g = delta = None
         seen = set()
@@ -736,7 +874,14 @@ class ASAGA(EngineSolver):
         else:
             if delta.device != drv:
                 delta = jax.device_put(delta, drv)
-            # both instances of the accept path; the donating one last
+            # the fold as the updater calls it: ONE arity, the count as
+            # data, so no drain's size compiles inside the window
+            zero = jax.device_put(jnp.zeros(d, jnp.float32), drv)
+            wd, ab = self._apply_fold(
+                wd, ab, (zero,) * nw, (zero,) * nw,
+                jax.device_put(jnp.float32(2.0), drv),
+            )
+            # both instances of a drain of one; the donating one last
             wd, ab = self._apply_g_is_delta(wd, ab, g, g)
             wd, ab = apply(wd, ab, g, delta)
         wd.block_until_ready()

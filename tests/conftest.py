@@ -196,6 +196,38 @@ def held_updater(monkeypatch):
     return hold
 
 
+@pytest.fixture()
+def serialised(monkeypatch):
+    """An engine run's submitter, serialised behind its updater: no cohort
+    is chosen while a submitted result is still unmerged, so no task is
+    made between its worker's last result and that result's commit.  The
+    runs built during the test are returned, in order."""
+    from asyncframework_tpu.solvers import engine_loop
+
+    runs, submitted = [], [0]
+    real_init = engine_loop.EngineRun.__init__
+    real_barrier = engine_loop.partial_barrier
+
+    def init(self, *a, **kw):
+        real_init(self, *a, **kw)
+        runs.append(self)
+        submitted[0] = 0
+
+    def gated(*a, **kw):
+        run = runs[-1]
+        with run.state_lock:
+            merged = run.state["accepted"] + run.state["dropped"]
+        if merged < submitted[0]:
+            return []
+        cohort = real_barrier(*a, **kw)
+        submitted[0] += len(cohort)
+        return cohort
+
+    monkeypatch.setattr(engine_loop.EngineRun, "__init__", init)
+    monkeypatch.setattr(engine_loop, "partial_barrier", gated)
+    return runs
+
+
 @pytest.fixture(scope="session")
 def devices8():
     import jax

@@ -174,6 +174,31 @@ def test_the_table_keeps_its_mean_and_the_ages_are_the_replays(
 
 
 @BOTH
+def test_under_a_backlog_the_ages_are_still_the_replays(
+        storage, heard, held_updater):
+    """The updater folds its drains (ISSUE 60): an age is reckoned from the
+    update's OWN index, not from its drain's first, so the four integers
+    are the replay of the accept order whatever the drains' sizes were."""
+    ds, solver = _solve(storage)
+    held_updater(NW // 2)
+    res = solver.run()
+    extras, order = res.extras, heard[-1].order
+    assert res.accepted == 420 == len(order)
+    assert extras["apply_dispatches"] < 420 // 2  # drains were folded
+    at = extras["delay_calibrated_at_update"]
+    assert 60 <= at < 420
+    want = reference_history_age.account(order, LATE, at)
+    assert {k: extras[k] for k in AGES} == want
+    assert want["history_age_late_n"] > 0 < want["history_age_healthy_n"]
+    shards = [ds.shard(w) for w in range(NW)]
+    alphas = [extras["alpha"][w] for w in range(NW)]
+    drift = reference_saga.history_drift(
+        shards, alphas, extras["alpha_bar"], N, block_rows=512,
+        **({"d": D} if storage is ELL else {}))
+    assert 0.0 <= drift <= DRIFT_TOL
+
+
+@BOTH
 def test_a_run_at_coeff_zero_reports_zeros_and_no_other_extra_moves(storage):
     _ds, late = _solve(storage, num_iterations=200)
     _ds, steady = _solve(storage, num_iterations=200, coeff=0.0)

@@ -3,7 +3,8 @@ the worker's history slice is the one the step read, and pays the exact
 delta (a second read of the shard) where it is not (``ASAGA.run``).
 
 The schedules here are made deterministic: the submitter is held until
-every result it submitted has been merged (no natural overlap), and an
+every result it submitted has been merged (no natural overlap:
+``serialised``, ``conftest.py``), and an
 overlap is then put where the test wants it, by a ``_make_task`` on the
 instance that hands a task the slice its worker's PREVIOUS task captured
 (what a task made before its worker's last commit holds).  The guarantee
@@ -24,7 +25,7 @@ from asyncframework_tpu.data import (
     make_sparse_regression,
 )
 from asyncframework_tpu.data.sharded import ShardedDataset
-from asyncframework_tpu.solvers import ASAGA, SolverConfig, engine_loop
+from asyncframework_tpu.solvers import ASAGA, SolverConfig
 
 N, D, NW, B, SEED = 2048, 32, 4, 0.2, 13
 KINDS = pytest.mark.parametrize("kind", ["dense", "padded-ell"])
@@ -68,35 +69,6 @@ def _drift(res, rows):
     unit = np.max(np.abs(sum(X.T @ y for X, y in rows) / N))
     ab = np.asarray(res.extras["alpha_bar"], np.float64)
     return float(np.max(np.abs(ab - mean)) / unit)
-
-
-@pytest.fixture()
-def serialised(monkeypatch):
-    """No cohort is chosen while a submitted result is still unmerged: no
-    task is made between its worker's last result and that result's
-    commit."""
-    runs, submitted = [], [0]
-    real_init = engine_loop.EngineRun.__init__
-    real_barrier = engine_loop.partial_barrier
-
-    def init(self, *a, **kw):
-        real_init(self, *a, **kw)
-        runs.append(self)
-        submitted[0] = 0
-
-    def gated(*a, **kw):
-        run = runs[-1]
-        with run.state_lock:
-            merged = run.state["accepted"] + run.state["dropped"]
-        if merged < submitted[0]:
-            return []
-        cohort = real_barrier(*a, **kw)
-        submitted[0] += len(cohort)
-        return cohort
-
-    monkeypatch.setattr(engine_loop.EngineRun, "__init__", init)
-    monkeypatch.setattr(engine_loop, "partial_barrier", gated)
-    return runs
 
 
 def _count_deltas(solver):
@@ -219,6 +191,26 @@ def test_an_overlapped_task_pays_the_exact_delta(kind, serialised):
 
 
 @KINDS
+def test_an_overlapped_task_pays_the_exact_delta_in_a_folded_drain(
+        kind, serialised, held_updater):
+    """The same schedule under a backlog (ISSUE 60): the updater wakes to
+    a whole cohort and applies it in one dispatch, and the stale tasks,
+    and only they, are recomputed, each against the slice at ITS commit."""
+    solver, rows = _solver(kind, jax.devices()[:1])
+    deltas = _count_deltas(solver)
+    stale = _overlap(solver)
+    held_updater(NW)
+    res = solver.run()
+    assert res.accepted == 80
+    assert 30 <= len(stale) <= 40
+    assert res.extras["history_recomputed"] == len(stale) == len(deltas)
+    assert res.extras["history_reused"] == 80 - len(stale)
+    assert 0 < res.extras["apply_dispatches"] < 40  # drains were folded
+    assert _drift(res, rows) <= DRIFT_TOL
+    assert res.extras["history_drift"] <= DRIFT_TOL
+
+
+@KINDS
 def test_the_same_schedule_with_g_for_every_delta_loses_the_table(
         kind, serialised):
     """The control: the overlap above is one the invariant can see."""
@@ -274,22 +266,32 @@ def test_a_shard_rehomed_under_a_result_in_flight_takes_the_exact_delta(
 def _sides_by_worker(solver):
     """For every result the updater merges, in order and by worker:
     whether its accept paid the exact delta.  (The updater counts a
-    result's flops, by worker, in front of its accept path, and calls the
-    table delta inside it: both on the instance.)"""
-    sides, current = {}, []
+    drain's flops, a result at a time by worker, in front of the drain's
+    accept paths, and calls the table delta inside a result's own: both on
+    the instance.  Whose path a delta belongs to is read from the slice it
+    is taken against, the fourth operand in either payload: the table's
+    slot of that worker, which ``_history_follows`` is handed.)"""
+    sides, tables = {}, []
     real_flops, real_delta = solver._task_flops, solver._table_delta
+    real_follows = solver._history_follows
 
     def task_flops(wid):
-        current[:] = [wid]
         sides.setdefault(wid, []).append(False)
         return real_flops(wid)
 
     def table_delta(*args):
         if threading.current_thread().name == "saga-updater":
-            sides[current[0]][-1] = True
+            (wid,) = [w for w, a in tables[-1].items() if a is args[3]]
+            # (one result of a worker is out at a time: its newest entry)
+            sides[wid][-1] = True
         return real_delta(*args)
 
+    def follows(run, alpha, commits):
+        tables.append(alpha)
+        return real_follows(run, alpha, commits)
+
     solver._task_flops, solver._table_delta = task_flops, table_delta
+    solver._history_follows = follows
     return sides
 
 
