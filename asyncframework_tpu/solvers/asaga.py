@@ -20,15 +20,17 @@ Update rule on accept (``SparkASAGAThread.scala:210-213``):
 ``w -= gamma * (g/parRecs + alpha_bar)``; ``alpha_bar += delta/N``, with
 ``delta`` the table's change at the commit (the step's ``g`` where the slice
 the step read still stands, the exact table delta where it does not:
-``steps.make_saga_table_delta``).  The engine's updater merges a DRAIN, as
-ASGD's does (``solvers/asgd.py``): the filter for everything queued under
-one hold of the state lock;
-then, outside it, each accepted result's history path in drain order and ONE
-apply for all of them (``steps.make_saga_apply_fold``: the rule above as a
-recurrence over the drain; a drain of one is the rule itself).  In a drain
-of several the paths hold the slices' lock only to read and to publish; a
-result that came alone keeps it over its path, so that its worker's next
-task, made about then, reads the committed slice.
+``steps.make_saga_table_delta``).  The updater's drain is the engine's
+(``EngineRun.updater``: the take, the filter under one hold of the state
+lock, the split at a snapshot, the publication, the events); what ``run``
+hands it is the filter's predicate and a segment's dispatches: each accepted
+result's history path in drain order and ONE apply for all of them
+(``steps.make_saga_apply_fold``: the rule above as a recurrence over the
+drain; a drain of one is the rule itself).  In a drain of several the paths
+hold the slices' lock only to read and to publish; a result that came alone
+keeps it over its path, so that its worker's next task, made about then,
+reads the committed slice.  ``run_sync``'s round is the engine's too
+(``EngineRun.drive_sync``), handed the commit a result and the apply.
 Staleness filter quirk preserved: ASAGA accepts iff ``k - staleness <= taw``
 (the ASGD driver tests ``staleness <= taw``), ``k`` the update's own index --
 see the updater in ``SparkASAGAThread.scala:184``.
@@ -37,9 +39,7 @@ see the updater in ``SparkASAGAThread.scala:184``.
 from __future__ import annotations
 
 import contextlib
-import itertools
 import operator
-import queue
 import time
 from typing import Dict, Optional
 
@@ -57,11 +57,7 @@ from asyncframework_tpu.solvers.base import (
 )
 from asyncframework_tpu.metrics import trace
 from asyncframework_tpu.solvers.engine_loop import EngineRun, EngineSolver
-from asyncframework_tpu.solvers.instrumentation import (
-    enqueue_step,
-    on_device,
-    worker_task,
-)
+from asyncframework_tpu.solvers.instrumentation import enqueue_step, on_device
 
 
 #: Every so-many-th accept of ``ASAGA.run`` pays the exact table delta
@@ -136,9 +132,7 @@ class ASAGA(EngineSolver):
         cfg = self.cfg
         run = EngineRun(self)
         ck = run.restore("asaga")
-        ctx, inst, waiting = run.ctx, run.inst, run.waiting
-        calibrator, ckpt = run.calibrator, run.ckpt
-        state, state_lock, stop = run.state, run.state_lock, run.stop
+        state = run.state
         # the key lock by the name of what it guards here: the alpha slots
         hot_lock = run.history_lock
         if ck is not None:
@@ -165,7 +159,7 @@ class ASAGA(EngineSolver):
         # start: a test patches the module's constants)
         exact_every = (EXACT_SPARSE_DELTA_EVERY if self._compacted
                        else EXACT_DELTA_EVERY)
-        nw, freq = cfg.num_workers, cfg.printer_freq
+        nw, taw = cfg.num_workers, cfg.taw
         # what the fold takes beside a drain's handles, resident before
         # the clock starts so that a drain transfers nothing: the ONE zero
         # handle that pads a short drain's two tuples to the fold's arity,
@@ -177,7 +171,6 @@ class ASAGA(EngineSolver):
         run.start_monitors(self._history_follows(run, alpha, commits))
         self._warm_hot_path()
         run.start_clock()
-        snapshots, now_ms = run.snapshots, run.now_ms
         # the accept path's dispatches are PJRT calls like a step's:
         # counted among the run's calls in progress
         calls_in = run.calls_in
@@ -187,11 +180,14 @@ class ASAGA(EngineSolver):
         aged = run.delay_model if run.delay_model.enabled else None
         last_commit_k: Dict[int, int] = {}
         unlocked = contextlib.nullcontext()
-
-        def history_fields(ab) -> Dict:
-            with hot_lock:
-                alpha_h = {wid: np.asarray(a) for wid, a in alpha.items()}
-            return {"alpha_bar": np.asarray(ab), "alpha": alpha_h}
+        # what the last segment's dispatches read (g, deltas; payloads and
+        # replaced slices), kept until the next one has made its own and
+        # overwrites it (``EngineRun.updater``, 1): under no lock, and for
+        # a lone result BEHIND its commit's publication (dropped in front
+        # of the path, the deletions hand the interpreter to the submitter,
+        # whose capture then reads the slice the commit replaces:
+        # ``tests/test_asaga_fold.py`` holds the reuse to it)
+        kept = None
 
         def history_path(res, at_k, slot_lock):
             """One accepted result's history path, update ``at_k`` of the
@@ -265,206 +261,87 @@ class ASAGA(EngineSolver):
                 if not self._compacted:
                     payload_in = (alpha_new, mask)
 
-        def updater():
-            clock = inst.updater_clock
-            while not stop.is_set():
-                with state_lock:
-                    if state["k"] >= cfg.num_iterations:
-                        break
-                clock.waits()
-                try:
-                    results = [ctx.collect_all(timeout=cfg.collect_timeout_s)]
-                except queue.Empty:
-                    continue
-                finally:
-                    clock.works()
-                # the drain takes what is there: every result already
-                # queued, up to the arity the fold below is compiled for
-                # (ASGD's drain, ``solvers/asgd.py``)
-                results.extend(itertools.islice(ctx.drain(), nw - 1))
-                do_save = False
-                # the drain's sampled updates (metrics/trace.py; () in an
-                # untraced run): their result.queue and compute end here;
-                # merge.queue is the state lock and the tau filter,
-                # merge.apply a dispatch below with the history paths of
-                # its own results inside it: merge.history (the history
-                # commit, and the table delta where the slice moved),
-                # cross-chip copies, apply
-                uts = inst.on_drained(results)
-                merge_queue = trace.span(trace.MERGE_QUEUE, uts).begin()
-                # ONE hold of the state lock for the drain's bookkeeping,
-                # and no dispatch inside it
-                with state_lock:
-                    k = state["k"]
-                    # the account of model-sized buffers: this drain and
-                    # what has come since (EngineRun.count_copies)
-                    run.count_copies(len(results) + ctx.size())
-                    # never apply past the iteration budget: trim the drain
-                    room = cfg.num_iterations - k
-                    merged = []
-                    live = []
-                    for res in results:
-                        state["flops"] += self._task_flops(res.worker_id)
-                        task_ms = waiting.on_finish(res.worker_id, now_ms())
-                        # ASAGA acceptance quirk: k - staleness <= taw, with
-                        # k the update's OWN index: the accepts in front of
-                        # it in this drain have moved it on
-                        at_k = k + len(live)
-                        accepted = at_k - res.staleness <= cfg.taw
-                        if accepted and len(live) >= room:
-                            continue
-                        merged.append((res, accepted, at_k, task_ms))
-                        if accepted:
-                            calibrator.record(at_k, task_ms)
-                            live.append(res)
-                        else:
-                            state["dropped"] += 1
-                merge_queue.end()
-                m = len(live)
-                # ONE apply a drain, split only where a snapshot is due (a
-                # dispatch ends ON that update, ``solvers/asgd.py``), made
-                # OUTSIDE the state lock and, in a drain of several, the
-                # slices' lock: this thread alone writes the model,
-                # ``alpha_bar`` and (but for a re-homed shard's hook) the
-                # slices, and the submitter and every executor's handler
-                # take those locks (PERF.md section 6, PR 60).  The
-                # drain's temporaries (payloads, replaced slices, deltas,
-                # g) stay in this frame's lists until the next drain
-                # overwrites them (dropped while the dispatches that read
-                # them were still in flight, on a helper's return under
-                # the state lock, they once cost the CPU rehearsal two
-                # thirds of its update rate).
-                ends = [j + 1 for j in range(-k % freq, m, freq)]
-                if not ends or ends[-1] < m:
-                    ends.append(m)
-                w_new, ab = state["w"], state["ab"]
-                # A result that came ALONE is one the updater is keeping
-                # up with: its worker is being handed its next task about
-                # now (it is available since its result was queued), and
-                # a task made in front of the commit pays the second read
-                # of its shard.  So a drain of one keeps the slices' lock
-                # over its path, as every accept did until PR 60: the
-                # capture (``_task_maker``) waits the commit's one call
-                # out and finds the slice that stands (without it
-                # ``mnist8m-asaga.steady`` recomputed 6.3% of its accepts
-                # for 2.1% and lost 4.7% of its rate: PERF.md section 6,
-                # PR 60).  A drain of several is the updater behind its
-                # queue: those tasks are made already, and the lock would
-                # only stop the executors' handlers and the submitter.
-                if len(results) == 1:
-                    path_lock, slot_lock = hot_lock, unlocked
+        def dispatch(live, at_k, alone):
+            """A segment's history paths, a result at a time in drain
+            order (the table and ``alpha_bar`` agree at every publication),
+            then ONE apply for all of them.  A result that came alone keeps
+            the slices' lock over its path (``EngineRun.updater``, 2): the
+            capture (``_task_maker``) waits the commit's one call out.  A
+            drain of several is the updater behind its queue: those tasks
+            are made already, and the paths hold the lock only to read and
+            to publish."""
+            nonlocal kept
+            if alone:
+                path_lock, slot_lock = hot_lock, unlocked
+            else:
+                path_lock, slot_lock = unlocked, hot_lock
+            w, ab = state["w"], state["ab"]
+            gs, deltas, held = [], [], []
+            history_ns = reused = 0
+            for at, res in enumerate(live, at_k):
+                wid = res.worker_id
+                t_hist = time.perf_counter_ns()
+                with trace.span(trace.MERGE_HISTORY, res.trace) as hist:
+                    with path_lock:
+                        delta, read = history_path(res, at, slot_lock)
+                    if aged is not None:
+                        before = last_commit_k.get(wid)
+                        last_commit_k[wid] = at
+                        if before is not None:
+                            booked_as = aged.book_history_age(
+                                wid, at - before)
+                            if booked_as:
+                                hist.note(delay_class=booked_as,
+                                          history_age=at - before)
+                history_ns += time.perf_counter_ns() - t_hist
+                g = res.data[0]
+                with calls_in:
+                    if g.device != self.driver_device:
+                        g = jax.device_put(g, self.driver_device)
+                    if delta is None:
+                        # the SAME handle through both tuples
+                        delta = g
+                        reused += 1
+                    elif delta.device != self.driver_device:
+                        delta = jax.device_put(delta, self.driver_device)
+                gs.append(g)
+                deltas.append(delta)
+                held.append(read)
+            n = len(live)
+            with calls_in:
+                if n == 1:
+                    # the serial path's program, by whether g is the
+                    # delta too (one buffer through two arguments may not
+                    # be donated)
+                    apply_one = (self._apply_g_is_delta
+                                 if gs[0] is deltas[0] else self._apply)
+                    w, ab = apply_one(w, ab, gs[0], deltas[0])
                 else:
-                    path_lock, slot_lock = unlocked, hot_lock
-                gs, deltas, held = [], [], []
-                history_ns = reused = 0
-                lo = 0
-                for hi in ends:
-                    n = hi - lo
-                    in_it = uts
-                    if uts:
-                        # the sampled updates of THIS dispatch (a dropped
-                        # one rides with the slot it was filtered before,
-                        # or with the last: ``solvers/asgd.py``)
-                        top = hi if hi < m else m + 1
-                        in_it = inst.apply_attrs(
-                            (r, acc) for r, acc, at_k, _ in merged
-                            if lo <= at_k - k < top
-                        )
-                    t_apply = time.perf_counter_ns()
-                    with trace.span(trace.MERGE_APPLY, in_it, batch=n):
-                        # the history paths of the dispatch's own results
-                        # go with it, a result at a time in drain order:
-                        # the table and alpha_bar agree at every
-                        # publication below
-                        for res in live[lo:hi]:
-                            wid, at_k = res.worker_id, k + len(gs)
-                            t_hist = time.perf_counter_ns()
-                            with trace.span(trace.MERGE_HISTORY,
-                                            res.trace) as hist:
-                                with path_lock:
-                                    delta, kept = history_path(
-                                        res, at_k, slot_lock)
-                                if aged is not None:
-                                    before = last_commit_k.get(wid)
-                                    last_commit_k[wid] = at_k
-                                    if before is not None:
-                                        booked_as = aged.book_history_age(
-                                            wid, at_k - before)
-                                        if booked_as:
-                                            hist.note(
-                                                delay_class=booked_as,
-                                                history_age=at_k - before)
-                            history_ns += time.perf_counter_ns() - t_hist
-                            g = res.data[0]
-                            with calls_in:
-                                if g.device != self.driver_device:
-                                    g = jax.device_put(g, self.driver_device)
-                                if delta is None:
-                                    # the SAME handle through both tuples
-                                    delta = g
-                                    reused += 1
-                                elif delta.device != self.driver_device:
-                                    delta = jax.device_put(
-                                        delta, self.driver_device)
-                            gs.append(g)
-                            deltas.append(delta)
-                            held.append(kept)
-                        with calls_in:
-                            if n == 1:
-                                # a drain of one: the serial path's
-                                # program, by whether g is the delta too
-                                # (one buffer through two arguments may
-                                # not be donated)
-                                apply_one = (
-                                    self._apply_g_is_delta
-                                    if gs[lo] is deltas[lo] else self._apply)
-                                w_new, ab = apply_one(
-                                    w_new, ab, gs[lo], deltas[lo])
-                            elif n:
-                                w_new, ab = self._apply_fold(
-                                    w_new, ab,
-                                    tuple(gs[lo:hi]) + zeros[n:],
-                                    tuple(deltas[lo:hi]) + zeros[n:],
-                                    counts[n],
-                                )
-                    inst.updater_apply_ns += (
-                        time.perf_counter_ns() - t_apply
+                    w, ab = self._apply_fold(
+                        w, ab, tuple(gs) + zeros[n:],
+                        tuple(deltas) + zeros[n:], counts[n],
                     )
-                    if n:
-                        inst.apply_dispatches += 1
-                        # what the dispatch made, published together
-                        with state_lock:
-                            state["w"], state["ab"] = w_new, ab
-                            state["k"] = k + hi
-                            state["accepted"] += n
-                            if (k + hi - 1) % freq == 0:
-                                with trace.span(trace.SNAPSHOT):
-                                    snapshots.append((now_ms(), w_new))
-                                    inst.on_snapshot(state["accepted"])
-                    lo = hi
-                if m:
-                    state["history_ns"] += history_ns
-                    state["reused"] += reused
-                    state["recomputed"] += m - reused
-                    # range check: a drain jumping over a checkpoint
-                    # boundary must still save
-                    do_save = ckpt.should_save_range(k, k + m)
-                    save_k, save_w, save_ab = state["k"], w_new, ab
-                # outside the lock, as ever: the events and the counters
-                for res, accepted, at_k, task_ms in merged:
-                    inst.on_gradient_merged(res, accepted, at_k, task_ms)
-                if do_save:
-                    with trace.span(trace.CHECKPOINT):
-                        run.save(save_k, save_w, **history_fields(save_ab))
-                if calibrator.maybe_finalize(state["k"]):
-                    run.delays_calibrated(state["accepted"])
-            clock.waits()  # the loop's last busy stretch
-            stop.set()
+            state["history_ns"] += history_ns
+            state["reused"] += reused
+            state["recomputed"] += n - reused
+            kept = gs, deltas, held
+            return {"w": w, "ab": ab}
 
-        run.drive(updater, "saga-updater",
-                  self._task_maker(run, alpha, commits))
+        def checkpoint() -> Dict:
+            # (the updater alone writes ``ab``: at a save, the drain's last)
+            with hot_lock:
+                alpha_h = {wid: np.asarray(a) for wid, a in alpha.items()}
+            return {"alpha_bar": np.asarray(state["ab"]), "alpha": alpha_h}
+
+        # ASAGA's acceptance quirk: k - staleness <= taw, with k the
+        # update's OWN index
+        run.drive(
+            run.updater(lambda res, at_k: at_k - res.staleness <= taw,
+                        dispatch, checkpoint),
+            "saga-updater",
+            self._task_maker(run, lambda wid: (alpha[wid], commits[wid])))
         return run.result(
-            checkpoint=lambda: history_fields(state["ab"]),
+            checkpoint=checkpoint,
             more_extras=lambda ut: {
                 **self._history_extras(alpha, state["ab"], ut),
                 "updater_history_s": state["history_ns"] * 1e-9,
@@ -539,8 +416,7 @@ class ASAGA(EngineSolver):
         final_w = np.asarray(w)  # fence BEFORE elapsed
         elapsed = time.monotonic() - start_wall
         accepted = done_rounds * nw
-        snapshots.append((elapsed * 1e3, w))
-        traj = self._evaluate_trajectory(snapshots)
+        traj = self._evaluate_trajectory([*snapshots, (elapsed * 1e3, w)])
         flops = sum(
             self._task_flops(wid) for wid in range(nw)
         ) * done_rounds
@@ -573,10 +449,7 @@ class ASAGA(EngineSolver):
         """SparkASAGASync parity: drain all workers per round, merge all
         histories, apply one accumulated update with ``parRecs = b*N``."""
         cfg = self.cfg
-        nw = cfg.num_workers
         run = EngineRun(self, sync=True)
-        ctx, sched, inst = run.ctx, run.sched, run.inst
-        waiting, calibrator = run.waiting, run.calibrator
         # the key lock by the name of what it guards here: the alpha slots
         hot_lock = run.history_lock
         sync_apply = steps.make_saga_apply(
@@ -584,101 +457,43 @@ class ASAGA(EngineSolver):
             donate_g=False,  # the drain passes acc as both g and delta
         )
         run.cold_start()
-        w = run.state["w"]
         alpha_bar, alpha = self._zero_history()
         # the tasks' commit counts (run()) have no reader here: a round's
         # tasks are all made before, and drained after, any of its commits
         commits = dict.fromkeys(alpha, 0)
         run.start_monitors(self._history_follows(run, alpha, commits))
-        make_tasks = self._task_maker(run, alpha, commits)
+        make_tasks = self._task_maker(
+            run, lambda wid: (alpha[wid], commits[wid]))
         self._warm_hot_path(apply=sync_apply, sync=True)
         run.start_clock()
-        snapshots, now_ms = run.snapshots, run.now_ms
 
-        rounds = 0
-        flops = 0.0
-        run_ok = False
-        # one driver thread submits and drains (see ASGD.run_sync)
-        clock = inst.updater_clock
-        try:
-            for k in range(cfg.num_iterations):
-                cohort = list(range(nw))
-                uts = inst.start_updates(cohort)
-                with trace.span(trace.SUBMIT, uts.values(), batch=nw) as sub:
-                    ts = ctx.get_current_time()
-                    ctx.mark_busy(cohort)
-                    if inst.occupancy is not None:
-                        inst.on_busy(cohort, uts, sub.start_ms)
-                    waiting.on_submit(cohort, now_ms())
-                    if uts:
-                        inst.begin_compute(uts, k)
-                    fns = make_tasks(cohort, w, uts)
-                    inst.on_round_submitted(k, cohort, model_version=k)
-                    waiter = sched.run_job(fns, self._handler(run, ts, uts))
-                acc = None
-                reported = set()
-                drained = []
-                for _ in range(nw):
-                    clock.waits()
-                    try:
-                        res = self._collect_checked(
-                            ctx, waiter, cfg.run_timeout_s, pool=sched.pool,
-                            cohort=cohort, collected=reported,
-                        )
-                    finally:
-                        clock.works()
-                    inst.on_drained((res,))
-                    drained.append((res, True))
-                    reported.add(res.worker_id)
-                    g = res.data[0]
-                    flops += self._task_flops(res.worker_id)
-                    task_ms = waiting.on_finish(res.worker_id, now_ms())
-                    calibrator.record(k, task_ms)
-                    inst.on_gradient_merged(res, True, k, task_ms)
-                    with hot_lock:
-                        alpha_cur = alpha[res.worker_id]
-                        # a shard re-homed mid-round leaves this result's
-                        # payload on the old device; commit on the slice's
-                        # current home.  The sync drain's commit needs only
-                        # diff/idx/valid -- never transfer the (cap, K)
-                        # c_sel/v_sel arrays it would just discard.
-                        home = alpha_cur.device
-                        needed = res.data[1:4 if self._compacted else 3]
-                        payload = tuple(
-                            jax.device_put(a, home) if a.device != home
-                            else a
-                            for a in needed
-                        )
-                        if self._compacted:  # by the payload, as in run()
-                            diff, idx, valid = payload
-                            alpha[res.worker_id] = self._commit(
-                                alpha_cur, diff, idx, valid
-                            )
-                        else:
-                            diff, mask = payload
-                            alpha[res.worker_id] = steps.saga_commit_history(
-                                alpha_cur, diff, mask
-                            )
-                    if g.device != self.driver_device:
-                        g = jax.device_put(g, self.driver_device)
-                    acc = g if acc is None else steps.add_grads(acc, g)
-                # sync drain has no dispatch overlap: table delta == g
-                with trace.span(trace.MERGE_APPLY,
-                                inst.apply_attrs(drained) if uts else None,
-                                batch=nw):
-                    w, alpha_bar = sync_apply(w, alpha_bar, acc, acc)
-                rounds += 1
-                if k % cfg.printer_freq == 0:
-                    with trace.span(trace.SNAPSHOT):
-                        snapshots.append((now_ms(), w))
-                        inst.on_snapshot(rounds * nw)
-                if calibrator.maybe_finalize(k):
-                    run.delays_calibrated(rounds * nw)
-            run_ok = True
-        finally:
-            clock.waits()  # the loop's last busy stretch
-            run.shutdown(run_ok)
-        run.state.update(w=w, accepted=rounds * nw, rounds=rounds, flops=flops)
+        def merge(res):
+            wid = res.worker_id
+            with hot_lock:
+                alpha_cur = alpha[wid]
+                # a shard re-homed mid-round leaves this result's
+                # payload on the old device; commit on the slice's
+                # current home.  The sync drain's commit needs only
+                # diff/idx/valid -- never transfer the (cap, K)
+                # c_sel/v_sel arrays it would just discard.
+                home = alpha_cur.device
+                payload = tuple(
+                    jax.device_put(a, home) if a.device != home else a
+                    for a in res.data[1:4 if self._compacted else 3]
+                )
+                # by the payload, as in run()
+                commit = (self._commit if self._compacted
+                          else steps.saga_commit_history)
+                alpha[wid] = commit(alpha_cur, *payload)
+            return res.data[0]
+
+        def apply_round(w, acc):
+            # sync drain has no dispatch overlap: table delta == g
+            nonlocal alpha_bar
+            w, alpha_bar = sync_apply(w, alpha_bar, acc, acc)
+            return w
+
+        run.drive_sync(make_tasks, merge, apply_round)
         return run.result(
             more_extras=lambda ut: self._history_extras(alpha, alpha_bar, ut)
         )
@@ -721,46 +536,18 @@ class ASAGA(EngineSolver):
 
     def _history_follows(self, run: EngineRun, alpha: Dict[int, jax.Array],
                          commits: Dict[int, int]):
-        """The run's hook for a re-homed shard: its history slice and PRNG
-        chain follow it to the new device.  The slot is assigned, so its
-        count moves on: a result in flight takes the exact table delta."""
-        hot_lock, worker_keys = run.history_lock, run.worker_keys
+        """The run's hook for a re-homed shard (``EngineRun.
+        start_monitors``, which moves the PRNG chain): its history slice
+        follows it to the new device.  The slot is assigned, so its count
+        moves on: a result in flight takes the exact table delta."""
+        hot_lock = run.history_lock
 
         def on_shard_moved(shard_id, moved):
             with hot_lock:
                 alpha[shard_id] = jax.device_put(alpha[shard_id], moved.device)
                 commits[shard_id] += 1
-                worker_keys[shard_id] = jax.device_put(
-                    worker_keys[shard_id], moved.device
-                )
 
         return on_shard_moved
-
-    def _task_maker(self, run: EngineRun, alpha: Dict[int, jax.Array],
-                    commits: Dict[int, int]):
-        """``make_tasks`` of this run (``EngineRun.drive``): a task captures
-        its worker's key, history slice and the slice's commit count, read
-        under one hold of the lock that guards all three."""
-        hot_lock, worker_keys = run.history_lock, run.worker_keys
-        delay_model = run.delay_model
-
-        def make_tasks(cohort, w_pub, uts):
-            with hot_lock:
-                captured = {
-                    wid: (worker_keys[wid], alpha[wid], commits[wid])
-                    for wid in cohort
-                }
-            # _make_task is looked up per cohort: a test may replace it on
-            # the instance
-            return {
-                wid: self._make_task(
-                    wid, run.model_for(wid, w_pub), *captured[wid],
-                    delay_model, uts.get(wid),
-                )
-                for wid in cohort
-            }
-
-        return make_tasks
 
     def _history_unit(self) -> float:
         """``max |sum_i y_i x_i|`` over the dataset: one pass over every
@@ -912,12 +699,4 @@ class ASAGA(EngineSolver):
                 step, (*operands, w_local, a_local, key_local), ut, calls)
             return (*out[:-1], slice_commits, out[-1])
 
-        # (an injected delay sleeps in front of the dispatch: a straggler
-        # takes no turn, ``ASGD._make_task``)
-        delay_ms = delay_model.delay_ms(wid)
-        late = delay_ms > 0
-        return worker_task(dispatch, delay_ms, ut, worker=wid, chip=dev.id,
-                           width=self._programs.widths[wid],
-                           turns=None if late else self._turns.get(dev),
-                           steps_out=self._steps_out.get(dev),
-                           long_tail=late and delay_model.long_tail(wid))
+        return self._worker_task(dispatch, wid, dev, delay_model, ut)

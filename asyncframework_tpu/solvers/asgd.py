@@ -18,13 +18,16 @@ counter); the model and snapshots are immutable device handles (old handle ==
 old model version -- the versioned-broadcast capability with zero copies).
 The host moves only handles and Python ints, so per-update cost is two
 dispatches, not two transfers.
+
+Both schedules are the engine's (``solvers/engine_loop.py``): ``run`` hands
+``EngineRun.updater`` the tau filter's predicate and what a segment of a
+drain dispatches (a chip), ``run_sync`` hands ``EngineRun.drive_sync`` a
+round's apply.
 """
 
 from __future__ import annotations
 
-import itertools
 import operator
-import queue
 import time
 from typing import Optional
 
@@ -40,17 +43,12 @@ from asyncframework_tpu.solvers.base import (
     TrainResult,
     run_fused_plan,
 )
-from asyncframework_tpu.metrics import trace
 from asyncframework_tpu.solvers.engine_loop import (
     EngineRun,
     EngineSolver,
     ModelReplicas,
 )
-from asyncframework_tpu.solvers.instrumentation import (
-    enqueue_step,
-    on_device,
-    worker_task,
-)
+from asyncframework_tpu.solvers.instrumentation import enqueue_step, on_device
 
 
 class ASGD(EngineSolver):
@@ -83,11 +81,7 @@ class ASGD(EngineSolver):
         cfg = self.cfg
         run = EngineRun(self)
         run.restore("asgd")
-        ctx, inst, waiting = run.ctx, run.inst, run.waiting
-        calibrator, ckpt = run.calibrator, run.ckpt
-        state, state_lock, stop = run.state, run.state_lock, run.stop
-        d = self.ds.d
-        nw, freq = cfg.num_workers, cfg.printer_freq
+        state, d, nw = run.state, self.ds.d, cfg.num_workers
         # where the model lives: one buffer on the driver's chip, or,
         # where the shards lie on several, a replica on each of ``chips``.
         # The updater then applies every drain to every replica (the same
@@ -120,154 +114,50 @@ class ASGD(EngineSolver):
         run.start_monitors()
         self._warm_hot_path()
         run.start_clock()
-        snapshots, now_ms = run.snapshots, run.now_ms
 
-        def updater():
-            clock = inst.updater_clock
-            while not stop.is_set():
-                with state_lock:
-                    if state["k"] >= cfg.num_iterations:
-                        break
-                clock.waits()
-                try:
-                    results = [ctx.collect_all(timeout=cfg.collect_timeout_s)]
-                except queue.Empty:
-                    continue
-                finally:
-                    clock.works()
-                # the drain takes what is there: every result already
-                # queued, up to the arity the fold below is compiled for
-                # (the submitter's backlog bound keeps the queue near nw;
-                # a rest waits for the next wake)
-                results.extend(itertools.islice(ctx.drain(), nw - 1))
-                do_save = False
-                # the drain's sampled updates (metrics/trace.py; () in an
-                # untraced run): their result.queue and compute end here;
-                # merge.queue is the lock and the filter, merge.apply a
-                # dispatch below
-                uts = inst.on_drained(results)
-                merge_queue = trace.span(trace.MERGE_QUEUE, uts).begin()
-                with state_lock:
-                    k = state["k"]
-                    # the account of model-sized buffers, read where the
-                    # most results are held: this drain and what has come
-                    # since (engine_loop.EngineRun.count_copies)
-                    run.count_copies(len(results) + ctx.size())
-                    # never apply past the iteration budget: trim the drain
-                    room = cfg.num_iterations - k
-                    merged = []
-                    accepted_g = []
-                    for res in results:
-                        state["flops"] += self._task_flops(res.worker_id)
-                        task_ms = waiting.on_finish(res.worker_id, now_ms())
-                        accepted = res.staleness <= cfg.taw
-                        if accepted and len(accepted_g) >= room:
-                            # beyond the iteration budget: ignored, like
-                            # the old per-result loop's break-at-limit
-                            continue
-                        merged.append(
-                            (res, accepted, k + len(accepted_g), task_ms)
-                        )
-                        if accepted:
-                            # the step's gradient, on the model's one chip;
-                            # over several, its buffers by chip (the
-                            # task's thread sent them: worker_task)
-                            calibrator.record(k + len(accepted_g), task_ms)
-                            accepted_g.append(res.data)
+        # over several chips a result is a buffer a chip, and a segment's
+        # rows are kept until the next one's dispatches drop them: dying
+        # with the frame's ``live`` under ``state_lock`` (``EngineRun.
+        # updater``, 1) they cost the four-chip cell 1.1% of its rate and
+        # the submitter 1.8 s of 20 at that lock (PERF.md section 6, PR 61)
+        rows = None
+
+        def dispatch(live, at_k, alone):
+            # ONE dispatch a segment (a chip): the serial path's program
+            # for one result, the fold for several
+            nonlocal rows
+            n = len(live)
+            w, k_dev = state["w"], state["k_dev"]
+            with calls_in:
+                if chips is not None:
+                    # the same dispatch a chip, each on its own buffer of
+                    # every operand (a result's buffers by chip: the
+                    # task's thread sent them, ``worker_task``)
+                    rows = [res.data for res in live]
+                    ws = list(w)
+                    for c, (pad, count_of) in enumerate(folds):
+                        if n == 1:
+                            ws[c], k_dev[c] = self._apply(
+                                ws[c], rows[0][c], k_dev[c])
                         else:
-                            state["dropped"] += 1
-                merge_queue.end()
-                m = len(accepted_g)
-                # ONE dispatch a drain (a chip), split only where a
-                # snapshot is due: snapshot j holds the model after update
-                # j * printer_freq + 1, folded or not (a reader of the
-                # trajectory reckons its updates so, benchmark/target.py),
-                # so a dispatch ends ON that update.  The dispatches are
-                # made OUTSIDE the state lock: this thread alone writes
-                # the model and the counter, and over several chips a
-                # drain is a dispatch a chip, 1.5 ms in which the
-                # submitter, which takes the lock at every poll and twice
-                # a cohort, would stand still (PERF.md section 6, PR 47);
-                # what a dispatch made is published under the lock, the
-                # model and its count together
-                ends = [j + 1 for j in range(-k % freq, m, freq)]
-                if not ends or ends[-1] < m:
-                    ends.append(m)
-                w_new, k_dev = state["w"], state["k_dev"]
-                lo = 0
-                for hi in ends:
-                    n = hi - lo
-                    in_it = uts
-                    if uts:
-                        # the sampled updates of THIS dispatch, each with
-                        # what its merge.apply carries; a dropped one
-                        # rides with the slot it was filtered before, or
-                        # with the last
-                        top = hi if hi < m else m + 1
-                        in_it = inst.apply_attrs(
-                            (r, acc) for r, acc, at_k, _ in merged
-                            if lo <= at_k - k < top
-                        )
-                    t_apply = time.perf_counter_ns()
-                    with trace.span(trace.MERGE_APPLY, in_it, batch=n), \
-                            calls_in:
-                        if chips is not None:
-                            if n:
-                                # the same dispatch a chip, each on its
-                                # own buffer of every operand
-                                rows = accepted_g[lo:hi]
-                                ws = list(w_new)
-                                for c, (pad, count_of) in enumerate(folds):
-                                    if n == 1:
-                                        ws[c], k_dev[c] = self._apply(
-                                            ws[c], rows[0][c], k_dev[c])
-                                    else:
-                                        ws[c], k_dev[c] = self._apply_fold(
-                                            ws[c],
-                                            tuple(r[c] for r in rows)
-                                            + pad[n:],
-                                            count_of[n], k_dev[c],
-                                        )
-                                w_new = ModelReplicas(ws)
-                        elif n == 1:
-                            w_new, k_dev = self._apply(
-                                w_new, accepted_g[lo], k_dev)
-                        elif n:
-                            w_new, k_dev = self._apply_fold(
-                                w_new, tuple(accepted_g[lo:hi]) + zeros[n:],
-                                counts[n], k_dev,
+                            ws[c], k_dev[c] = self._apply_fold(
+                                ws[c], tuple(r[c] for r in rows) + pad[n:],
+                                count_of[n], k_dev[c],
                             )
-                    inst.updater_apply_ns += (
-                        time.perf_counter_ns() - t_apply
+                    w = ModelReplicas(ws)
+                elif n == 1:
+                    w, k_dev = self._apply(w, live[0].data, k_dev)
+                else:
+                    w, k_dev = self._apply_fold(
+                        w, tuple(res.data for res in live) + zeros[n:],
+                        counts[n], k_dev,
                     )
-                    if n:
-                        inst.apply_dispatches += 1
-                        with state_lock:
-                            state["w"], state["k_dev"] = w_new, k_dev
-                            state["k"] = k + hi
-                            state["accepted"] += n
-                            if (k + hi - 1) % freq == 0:
-                                with trace.span(trace.SNAPSHOT):
-                                    snapshots.append((now_ms(), w_new))
-                                    inst.on_snapshot(state["accepted"])
-                    lo = hi
-                if m:
-                    # range check: a drain jumping over a checkpoint
-                    # boundary must still save
-                    do_save = ckpt.should_save_range(k, k + m)
-                    save_k, save_w = state["k"], w_new
-                # outside the lock, as ever: the events and the counters
-                for res, accepted, at_k, task_ms in merged:
-                    inst.on_gradient_merged(res, accepted, at_k, task_ms)
-                if do_save:
-                    with trace.span(trace.CHECKPOINT):
-                        run.save(save_k, save_w)
-                if calibrator.maybe_finalize(state["k"]):
-                    run.delays_calibrated(state["accepted"])
-            clock.waits()  # the loop's last busy stretch
-            stop.set()
+            return {"w": w, "k_dev": k_dev}
 
-        run.drive(updater, "ps-updater", self._task_maker(run))
+        taw = cfg.taw
+        run.drive(
+            run.updater(lambda res, at_k: res.staleness <= taw, dispatch),
+            "ps-updater", self._task_maker(run))
         return run.result()
 
     # ----------------------------------------------------------------- fused
@@ -341,8 +231,7 @@ class ASGD(EngineSolver):
         final_w = np.asarray(w)  # fence BEFORE elapsed (EngineRun.result)
         elapsed = time.monotonic() - start_wall
         accepted = done_rounds * nw
-        snapshots.append((elapsed * 1e3, w))
-        traj = self._evaluate_trajectory(snapshots)
+        traj = self._evaluate_trajectory([*snapshots, (elapsed * 1e3, w)])
         flops = sum(
             self._task_flops(wid) for wid in range(nw)
         ) * done_rounds
@@ -365,80 +254,20 @@ class ASGD(EngineSolver):
     # ------------------------------------------------------------------ sync
     def run_sync(self) -> TrainResult:
         """SparkASGDSync parity: submit to all, drain all, one update/round."""
-        cfg = self.cfg
-        nw = cfg.num_workers
         run = EngineRun(self, sync=True)
-        ctx, sched, inst = run.ctx, run.sched, run.inst
-        waiting, calibrator = run.waiting, run.calibrator
         run.cold_start()
-        w = run.state["w"]
         k_dev = jax.device_put(jnp.float32(0.0), self.driver_device)
         run.start_monitors()
         make_tasks = self._task_maker(run)
         self._warm_hot_path(sync=True)
         run.start_clock()
-        snapshots, now_ms = run.snapshots, run.now_ms
 
-        rounds = 0
-        flops = 0.0
-        run_ok = False
-        # one driver thread submits and drains: its time outside the
-        # blocking collect is the barrier's host work
-        clock = inst.updater_clock
-        try:
-            for k in range(cfg.num_iterations):
-                cohort = list(range(nw))
-                uts = inst.start_updates(cohort)
-                with trace.span(trace.SUBMIT, uts.values(), batch=nw) as sub:
-                    ts = ctx.get_current_time()
-                    ctx.mark_busy(cohort)
-                    if inst.occupancy is not None:
-                        inst.on_busy(cohort, uts, sub.start_ms)
-                    waiting.on_submit(cohort, now_ms())
-                    if uts:
-                        inst.begin_compute(uts, k)
-                    fns = make_tasks(cohort, w, uts)
-                    inst.on_round_submitted(k, cohort, model_version=k)
-                    waiter = sched.run_job(fns, self._handler(run, ts, uts))
-                acc = None
-                reported = set()
-                drained = []
-                for _ in range(nw):
-                    clock.waits()
-                    try:
-                        res = self._collect_checked(
-                            ctx, waiter, cfg.run_timeout_s, pool=sched.pool,
-                            cohort=cohort, collected=reported,
-                        )
-                    finally:
-                        clock.works()
-                    inst.on_drained((res,))
-                    drained.append((res, True))
-                    reported.add(res.worker_id)
-                    g = res.data
-                    flops += self._task_flops(res.worker_id)
-                    task_ms = waiting.on_finish(res.worker_id, now_ms())
-                    calibrator.record(k, task_ms)
-                    inst.on_gradient_merged(res, True, k, task_ms)
-                    if g.device != self.driver_device:
-                        g = jax.device_put(g, self.driver_device)
-                    acc = g if acc is None else steps.add_grads(acc, g)
-                with trace.span(trace.MERGE_APPLY,
-                                inst.apply_attrs(drained) if uts else None,
-                                batch=nw):
-                    w, k_dev = self._sync_apply(w, acc, k_dev)
-                rounds += 1
-                if k % cfg.printer_freq == 0:
-                    with trace.span(trace.SNAPSHOT):
-                        snapshots.append((now_ms(), w))
-                        inst.on_snapshot(rounds * nw)
-                if calibrator.maybe_finalize(k):
-                    run.delays_calibrated(rounds * nw)
-            run_ok = True
-        finally:
-            clock.waits()  # the loop's last busy stretch
-            run.shutdown(run_ok)
-        run.state.update(w=w, accepted=rounds * nw, rounds=rounds, flops=flops)
+        def apply_round(w, acc):
+            nonlocal k_dev
+            w, k_dev = self._sync_apply(w, acc, k_dev)
+            return w
+
+        run.drive_sync(make_tasks, operator.attrgetter("data"), apply_round)
         return run.result()
 
     # ---------------------------------------------------------------- helpers
@@ -513,34 +342,4 @@ class ASGD(EngineSolver):
             return enqueue_step(step, (*operands, w_local, key_local), ut,
                                 calls)
 
-        # (an injected delay sleeps in front of the dispatch: a straggler
-        # takes no turn, or the workers behind it would wait for its sleep)
-        delay_ms = delay_model.delay_ms(wid)
-        late = delay_ms > 0
-        return worker_task(dispatch, delay_ms, ut, worker=wid, chip=dev.id,
-                           width=self._programs.widths[wid],
-                           turns=None if late else self._turns.get(dev),
-                           steps_out=self._steps_out.get(dev),
-                           spread=self._spread.get(dev),
-                           long_tail=late and delay_model.long_tail(wid))
-
-    def _task_maker(self, run: EngineRun):
-        """``make_tasks`` of this run (``EngineRun.drive``): a task captures
-        its worker's key, read under the run's key lock."""
-        worker_keys, key_lock = run.worker_keys, run.key_lock
-        delay_model = run.delay_model
-
-        def make_tasks(cohort, w_pub, uts):
-            with key_lock:
-                keys = {wid: worker_keys[wid] for wid in cohort}
-            # _make_task is looked up per cohort: a test may replace it on
-            # the instance
-            return {
-                wid: self._make_task(
-                    wid, run.model_for(wid, w_pub), keys[wid], delay_model,
-                    uts.get(wid)
-                )
-                for wid in cohort
-            }
-
-        return make_tasks
+        return self._worker_task(dispatch, wid, dev, delay_model, ut)

@@ -3,23 +3,24 @@
 :class:`EngineRun` is one run: the context, scheduler, delay model,
 calibrator, waiting table and instruments; the heartbeat, speculation and
 allocation monitors; the restore of the checkpoint fields every solver
-saves; the submitter loop; the teardown; the fenced read-back and the
+saves; the submitter loop (:meth:`EngineRun.drive`) and the updater's drain
+(:meth:`EngineRun.updater`); the synchronous round
+(:meth:`EngineRun.drive_sync`); the teardown; the fenced read-back and the
 :class:`~asyncframework_tpu.solvers.base.TrainResult`.  A solver's ``run``
-and ``run_sync`` are what is left: its state, its updater, its extras.
+and ``run_sync`` are what is left: its state, what it dispatches, its
+extras.
 
 What differs between solvers reaches this module as a callable or a dict
-(the updater, what a cohort's tasks capture, the hook for a re-homed shard,
-the solver's own checkpoint and result fields); nothing here asks which
-solver it serves.  The updater in particular is taken whole and only
-started, joined and clocked: its body stays ONE frame in the solver,
-because an accept path behind a per-result call drops its temporaries under
-the state lock while the dispatches that read them are in flight (PERF.md
-section 6, PR 23 and PR 25: 15% to a third of the update rate on the CPU
-rehearsal).
+(the tau filter's predicate, a segment's dispatches, a round's merge and
+apply, what a cohort's tasks capture, the hook for a re-homed shard, the
+solver's own checkpoint and result fields); nothing here asks which solver
+it serves.
 """
 
 from __future__ import annotations
 
+import itertools
+import queue
 import threading
 import time
 from collections import Counter, deque
@@ -57,6 +58,7 @@ from asyncframework_tpu.solvers.instrumentation import (
     FaultTolerantRun,
     RunInstruments,
     StepsOut,
+    worker_task,
 )
 
 
@@ -175,6 +177,45 @@ class EngineSolver:
             )
 
         return handler
+
+    def _task_maker(self, run: "EngineRun",
+                    captured: Optional[Callable[[int], tuple]] = None):
+        """``make_tasks`` of this run (:meth:`EngineRun.drive`,
+        :meth:`EngineRun.drive_sync`): a task captures its worker's key and
+        ``captured(wid)``, whatever else the solver keeps a worker, read
+        under ONE hold of the run's key lock (taken as ``history`` where
+        the solver keeps such state: its waits are booked so)."""
+        worker_keys, delay_model = run.worker_keys, run.delay_model
+        lock = run.key_lock if captured is None else run.history_lock
+        more = captured or (lambda wid: ())
+
+        def make_tasks(cohort, w_pub, uts):
+            with lock:
+                held = {wid: (worker_keys[wid], *more(wid)) for wid in cohort}
+            # looked up a cohort: a test may replace it on the instance
+            make = self._make_task
+            return {
+                wid: make(wid, run.model_for(wid, w_pub), *held[wid],
+                          delay_model, uts.get(wid))
+                for wid in cohort
+            }
+
+        return make_tasks
+
+    def _worker_task(self, dispatch, wid: int, dev, delay_model: DelayModel,
+                     ut):
+        """What both ``_make_task`` end in: ``dispatch`` as worker ``wid``'s
+        task on ``dev``.  An injected delay sleeps in front of the
+        dispatch: a straggler takes no turn, or the workers behind it
+        would wait for its sleep."""
+        delay_ms = delay_model.delay_ms(wid)
+        late = delay_ms > 0
+        return worker_task(dispatch, delay_ms, ut, worker=wid, chip=dev.id,
+                           width=self._programs.widths[wid],
+                           turns=None if late else self._turns.get(dev),
+                           steps_out=self._steps_out.get(dev),
+                           spread=self._spread.get(dev),
+                           long_tail=late and delay_model.long_tail(wid))
 
     def _evaluate_trajectory(
         self, snapshots: List[Tuple[float, jax.Array]], ut=None,
@@ -505,16 +546,24 @@ class EngineRun:
     def start_monitors(self, on_moved: Optional[Callable] = None) -> None:
         """Heartbeat (executor replacement, shard re-homing), speculation
         and dynamic allocation, each where ``cfg`` asks for it.
-        ``on_moved(shard_id, moved_shard)``: what of the solver's state
-        follows a re-homed shard to its new device."""
+        A re-homed shard's PRNG chain follows it to its new device here;
+        ``on_moved(shard_id, moved_shard)`` adds what of the solver's own
+        state does."""
         cfg, sched, inst = self.cfg, self.sched, self.inst
         if cfg.heartbeat:
+            def shard_moved(shard_id, moved):
+                with self.key_lock:
+                    self.worker_keys[shard_id] = jax.device_put(
+                        self.worker_keys[shard_id], moved.device)
+                if on_moved is not None:
+                    on_moved(shard_id, moved)
+
             self._ft = FaultTolerantRun(
                 sched, self.solver._recovery, inst, cfg.num_workers,
                 heartbeat_timeout_ms=cfg.heartbeat_timeout_ms,
                 check_interval_s=cfg.heartbeat_interval_s,
                 max_slot_failures=cfg.max_slot_failures,
-                on_moved=on_moved,
+                on_moved=shard_moved,
             )
             self._ft.start()
         if cfg.speculation:
@@ -648,9 +697,8 @@ class EngineRun:
         ``run_timeout_s`` passes; then tear the run down.
 
         ``make_tasks(cohort, w_pub, uts)`` gives the cohort's task closures
-        by worker id: what a task captures (under which lock) is the
-        solver's.  The updater owns ``state`` past ``w`` and ``k``; it ends
-        with ``stop.set()``."""
+        by worker id (``EngineSolver._task_maker``).  ``updater``:
+        :meth:`updater`'s; it ends with ``stop.set()``."""
         cfg, ctx, sched, inst = self.cfg, self.ctx, self.sched, self.inst
         solver, waiting, now_ms = self.solver, self.waiting, self.now_ms
         state, state_lock, stop = self.state, self.state_lock, self.stop
@@ -784,8 +832,223 @@ class EngineRun:
                 "inflight_at_stop": submitted - merged,
             }
 
+    # ------------------------------------------------------------ the updater
+    def updater(self, accepts: Callable, dispatch: Callable,
+                checkpoint: Callable[[], Dict] = dict) -> Callable[[], None]:
+        """What :meth:`drive` starts on the updater's thread: the merge of
+        a DRAIN, until the budget is spent or the run stops.
+
+        A drain is the blocking take and whatever else is queued, up to
+        ``num_workers`` (the arity the solvers' folds are compiled for).
+        Under ONE hold of ``state_lock``: the account of model-sized
+        buffers, the flops, the waiting table, and the tau filter,
+        ``accepts(res, at_k)`` with ``at_k`` the update's OWN index (the
+        accepts in front of it in this drain have moved it on); an accept
+        past the iteration budget is ignored.  Then the accepted results
+        in SEGMENTS, split only where a snapshot is due: snapshot j holds
+        the model after update ``j * printer_freq + 1``
+        (``benchmark/target.py`` reckons a trajectory's updates so), so a
+        segment ends ON that update.  A segment is the span
+        ``merge.apply`` around ``dispatch(live, at_k, alone)`` (``live``:
+        its results in drain order, the first being update ``at_k``;
+        ``alone``: the drain held one result), which returns the fields of
+        ``state`` to publish with the count, ``"w"`` among them.  Then the
+        events, outside the lock, and a checkpoint where the drain crossed
+        one (``checkpoint()``: the solver's own fields, as :meth:`result`
+        takes them).
+
+        Three things that were learnt on the chip bind this shape:
+
+        1. What a drain's dispatches read stays referenced until the next
+           drain.  ``dispatch`` is called under no lock, and what was made
+           for a drain (``results``, ``merged``, ``live`` here; the
+           solver's temporaries in its own closure, until its next call)
+           is dropped by being overwritten at the next one, never on a
+           return inside a ``with``: temporaries that died under ``state_lock`` while the
+           dispatches that read them were in flight cost a third to two
+           thirds of the update rate (PERF.md section 6, PR 23 and PR 60).
+        2. A result that came ALONE is one the updater keeps up with: its
+           worker's next task is being made about now, so ASAGA keeps the
+           slices' lock over that result's path (``alone``; without it
+           ``mnist8m-asaga.steady`` recomputed 6.3% of its accepts for
+           2.1% and lost 4.7%, PR 60).
+        3. No dispatch inside ``state_lock`` (the submitter takes it at
+           every poll and twice a cohort), and the model and its count
+           published together under it (PR 47, PR 60)."""
+        cfg, ctx, inst, ckpt = self.cfg, self.ctx, self.inst, self.ckpt
+        waiting, calibrator, now_ms = self.waiting, self.calibrator, self.now_ms
+        state, state_lock, stop = self.state, self.state_lock, self.stop
+        task_flops = self.solver._task_flops
+        nw, budget, freq = cfg.num_workers, cfg.num_iterations, cfg.printer_freq
+
+        def drains():
+            clock = inst.updater_clock
+            snapshots = self.snapshots
+            while not stop.is_set():
+                with state_lock:
+                    if state["k"] >= budget:
+                        break
+                clock.waits()
+                try:
+                    results = [ctx.collect_all(timeout=cfg.collect_timeout_s)]
+                except queue.Empty:
+                    continue
+                finally:
+                    clock.works()
+                # (the submitter's backlog bound keeps the queue near nw; a
+                # rest waits for the next wake)
+                results.extend(itertools.islice(ctx.drain(), nw - 1))
+                # the drain's sampled updates (metrics/trace.py; () in an
+                # untraced run): their result.queue and compute end here
+                uts = inst.on_drained(results)
+                merge_queue = trace.span(trace.MERGE_QUEUE, uts).begin()
+                with state_lock:
+                    k = state["k"]
+                    # read where the most results are held: this drain and
+                    # what has come since
+                    self.count_copies(len(results) + ctx.size())
+                    room = budget - k
+                    merged, live = [], []
+                    for res in results:
+                        state["flops"] += task_flops(res.worker_id)
+                        task_ms = waiting.on_finish(res.worker_id, now_ms())
+                        at_k = k + len(live)
+                        accepted = accepts(res, at_k)
+                        if accepted and len(live) >= room:
+                            continue
+                        merged.append((res, accepted, at_k, task_ms))
+                        if accepted:
+                            calibrator.record(at_k, task_ms)
+                            live.append(res)
+                        else:
+                            state["dropped"] += 1
+                merge_queue.end()
+                m = len(live)
+                ends = [j + 1 for j in range(-k % freq, m, freq)]
+                if not ends or ends[-1] < m:
+                    ends.append(m)
+                alone = len(results) == 1
+                lo = 0
+                for hi in ends:
+                    n = hi - lo
+                    in_it = uts
+                    if uts:
+                        # the sampled updates of THIS segment, each with
+                        # what its merge.apply carries; a dropped one
+                        # rides with the slot it was filtered before, or
+                        # with the last
+                        top = hi if hi < m else m + 1
+                        in_it = inst.apply_attrs(
+                            (r, acc) for r, acc, at_k, _ in merged
+                            if lo <= at_k - k < top
+                        )
+                    t_apply = time.perf_counter_ns()
+                    with trace.span(trace.MERGE_APPLY, in_it, batch=n):
+                        if n:
+                            made = dispatch(live[lo:hi], k + lo, alone)
+                    inst.updater_apply_ns += (
+                        time.perf_counter_ns() - t_apply
+                    )
+                    if n:
+                        inst.apply_dispatches += 1
+                        with state_lock:
+                            state.update(made)
+                            state["k"] = k + hi
+                            state["accepted"] += n
+                            if (k + hi - 1) % freq == 0:
+                                with trace.span(trace.SNAPSHOT):
+                                    snapshots.append((now_ms(), made["w"]))
+                                    inst.on_snapshot(state["accepted"])
+                    lo = hi
+                for res, accepted, at_k, task_ms in merged:
+                    inst.on_gradient_merged(res, accepted, at_k, task_ms)
+                # (a range: a drain that jumps over a boundary still saves)
+                if m and ckpt.should_save_range(k, k + m):
+                    with trace.span(trace.CHECKPOINT):
+                        self.save(state["k"], state["w"], **checkpoint())
+                if calibrator.maybe_finalize(state["k"]):
+                    self.delays_calibrated(state["accepted"])
+            clock.waits()  # the loop's last busy stretch
+            stop.set()
+
+        return drains
+
+    # ------------------------------------------------------ the synchronous run
+    def drive_sync(self, make_tasks: Callable, merge: Callable,
+                   apply_round: Callable) -> None:
+        """A synchronous run's rounds, on this thread, and its teardown:
+        submit to all, drain all, one update a round (the barrier in the
+        driver).  ``merge(res)`` gives a drained result's gradient (and
+        commits what else of it the solver keeps);
+        ``apply_round(w, acc)`` the model after a round whose gradients
+        sum to ``acc`` on the driver's device."""
+        cfg, ctx, sched, inst = self.cfg, self.ctx, self.sched, self.inst
+        solver, waiting, now_ms = self.solver, self.waiting, self.now_ms
+        calibrator, snapshots = self.calibrator, self.snapshots
+        nw, driver = cfg.num_workers, solver.driver_device
+        w = self.state["w"]
+        rounds, flops, run_ok = 0, 0.0, False
+        # one driver thread submits and drains: its time outside the
+        # blocking collect is the barrier's host work
+        clock = inst.updater_clock
+        try:
+            for k in range(cfg.num_iterations):
+                cohort = list(range(nw))
+                uts = inst.start_updates(cohort)
+                with trace.span(trace.SUBMIT, uts.values(), batch=nw) as sub:
+                    ts = ctx.get_current_time()
+                    ctx.mark_busy(cohort)
+                    if inst.occupancy is not None:
+                        inst.on_busy(cohort, uts, sub.start_ms)
+                    waiting.on_submit(cohort, now_ms())
+                    if uts:
+                        inst.begin_compute(uts, k)
+                    fns = make_tasks(cohort, w, uts)
+                    inst.on_round_submitted(k, cohort, model_version=k)
+                    waiter = sched.run_job(fns, solver._handler(self, ts, uts))
+                acc = None
+                reported = set()
+                drained = []
+                for _ in range(nw):
+                    clock.waits()
+                    try:
+                        res = solver._collect_checked(
+                            ctx, waiter, cfg.run_timeout_s, pool=sched.pool,
+                            cohort=cohort, collected=reported,
+                        )
+                    finally:
+                        clock.works()
+                    inst.on_drained((res,))
+                    drained.append((res, True))
+                    reported.add(res.worker_id)
+                    flops += solver._task_flops(res.worker_id)
+                    task_ms = waiting.on_finish(res.worker_id, now_ms())
+                    calibrator.record(k, task_ms)
+                    inst.on_gradient_merged(res, True, k, task_ms)
+                    g = merge(res)
+                    if g.device != driver:
+                        g = jax.device_put(g, driver)
+                    acc = g if acc is None else steps.add_grads(acc, g)
+                with trace.span(trace.MERGE_APPLY,
+                                inst.apply_attrs(drained) if uts else None,
+                                batch=nw):
+                    w = apply_round(w, acc)
+                rounds += 1
+                if k % cfg.printer_freq == 0:
+                    with trace.span(trace.SNAPSHOT):
+                        snapshots.append((now_ms(), w))
+                        inst.on_snapshot(rounds * nw)
+                if calibrator.maybe_finalize(k):
+                    self.delays_calibrated(rounds * nw)
+            run_ok = True
+        finally:
+            clock.waits()  # the loop's last busy stretch
+            self.shutdown(run_ok)
+        self.state.update(w=w, accepted=rounds * nw, rounds=rounds,
+                          flops=flops)
+
     # ------------------------------------------------------------ the result
-    def result(self, checkpoint: Optional[Callable[[], Dict]] = None,
+    def result(self, checkpoint: Callable[[], Dict] = dict,
                more_extras: Optional[Callable[[object], Dict]] = None
                ) -> TrainResult:
         """Fence, stop the clock and assemble the result from ``state``.
@@ -864,8 +1127,7 @@ class EngineRun:
             )
         t = time.monotonic()
         if self.ckpt is not None and self.ckpt.enabled:
-            self.save(final_k, final_w_dev,
-                      **(checkpoint() if checkpoint is not None else {}))
+            self.save(final_k, final_w_dev, **checkpoint())
         extras["checkpoint_s"] = time.monotonic() - t
         run_ut = inst.run_trace()
         traj = self.solver._evaluate_trajectory(self.snapshots, run_ut, extras)

@@ -129,7 +129,36 @@ class TestFaultToleranceWiring:
         # convergence still happened
         assert res.trajectory[-1][1] < res.trajectory[0][1]
 
-    def test_repeated_death_rehomes_shard(self, devices8, problem, tmp_path):
+    @staticmethod
+    def _keys_after_a_move(monkeypatch):
+        """``(shard, its key's device, its new device)`` right behind every
+        call of the hook the heartbeat monitor was handed, read under the
+        run's key lock (held over the hook too: no handler comes between)."""
+        from asyncframework_tpu.solvers import engine_loop
+
+        seen = []
+        real_start = engine_loop.EngineRun.start_monitors
+        real_monitor = engine_loop.FaultTolerantRun
+
+        def start_monitors(run, *a, **kw):
+            def monitor(*args, on_moved=None, **kwargs):
+                def hook(shard_id, moved):
+                    with run.key_lock:
+                        on_moved(shard_id, moved)
+                        seen.append((shard_id,
+                                     run.worker_keys[shard_id].device,
+                                     moved.device))
+                return real_monitor(*args, on_moved=hook, **kwargs)
+
+            monkeypatch.setattr(engine_loop, "FaultTolerantRun", monitor)
+            return real_start(run, *a, **kw)
+
+        monkeypatch.setattr(engine_loop.EngineRun, "start_monitors",
+                            start_monitors)
+        return seen
+
+    def test_repeated_death_rehomes_shard(self, devices8, problem, tmp_path,
+                                          monkeypatch):
         log = tmp_path / "rehome.jsonl"
         cfg = cfg_with(
             num_iterations=2000,
@@ -138,10 +167,16 @@ class TestFaultToleranceWiring:
             heartbeat_interval_s=0.05,
             max_slot_failures=2,
         )
+        keys_after = self._keys_after_a_move(monkeypatch)
         res = self._run_async_with_kills(devices8, problem, kills=2, cfg=cfg)
         assert res.accepted == 2000
         assert res.extras.get("workers_lost", 0) >= 2
         assert res.extras.get("shards_moved", 0) >= 1
+        # the moved worker's PRNG chain went with its shard (ASGD hands
+        # the monitor no hook of its own: the engine moves the key)
+        assert keys_after and all(
+            shard_id == 3 and key_dev == shard_dev
+            for shard_id, key_dev, shard_dev in keys_after)
         # the re-homed shard lives on another worker's device now, and both
         # later rounds and the trajectory evaluation used it successfully
         assert np.isfinite(res.trajectory[-1][1])
